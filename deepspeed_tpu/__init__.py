@@ -12,11 +12,6 @@ __version__ = "0.5.0"   # keep in sync with version.txt (setup.py reads it)
 # __getattr__); "unknown" outside a git checkout
 __git_branch__ = "unknown"
 
-# must run before anything touches jax.shard_map: the pinned 0.4.x jaxlib
-# only ships the experimental spelling (see utils/jax_compat.py)
-from .utils import jax_compat as _jax_compat
-_jax_compat.install()
-
 from . import comm
 from . import utils
 from .accelerator import get_accelerator

@@ -29,14 +29,12 @@ def get_accelerator():
     if name is not None:
         _validate_accelerator_name(name)
     else:
-        # Auto-detect: prefer TPU when jax is on a TPU platform.  JAX_PLATFORMS
-        # is honored implicitly because jax.devices() reflects it.
-        try:
-            import jax
-            platforms = {d.platform for d in jax.devices()}
-            name = "tpu" if "tpu" in platforms else "cpu"
-        except Exception:
-            name = "cpu"
+        # Auto-detect: TPU when jax is on a TPU platform.  JAX_PLATFORMS is
+        # honored implicitly because jax.devices() reflects it; a backend
+        # that fails to come up is an error here, never a quiet "cpu".
+        import jax
+        platforms = {d.platform for d in jax.devices()}
+        name = "tpu" if "tpu" in platforms else "cpu"
 
     set_accelerator_name(name)
     return _accelerator

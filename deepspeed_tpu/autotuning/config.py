@@ -52,7 +52,7 @@ class AutotuningConfig(DeepSpeedConfigModel):
     tune_mesh: bool = False
     mesh_candidates: Optional[List[Dict]] = None
     # TPU addition: seed ModelBasedTuner with measured on-chip records from
-    # this directory (tools/bench_retry.sh artifacts).  Opt-in ("" = off):
+    # this directory (bench.py JSON records).  Opt-in ("" = off):
     # stale artifacts in a launch cwd must not silently bias a search.
     priors_path: str = ""
 

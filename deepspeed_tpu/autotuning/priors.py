@@ -2,10 +2,9 @@
 
 Reference ``autotuning/tuner/model_based_tuner.py:19`` starts its cost
 model cold — every tuning session re-measures points a previous on-chip
-sweep already paid for.  Here trustworthy records from ``.bench_runs/``
-(the ladder/sweep artifacts ``tools/bench_retry.sh`` +
-``tools/onchip_sweeps.sh`` write, summarized by ``tools/fold_sweeps.py``)
-seed ``ModelBasedTuner``'s regression, so TPU tuning starts from measured
+sweep already paid for.  Here trustworthy bench records from a runs
+directory (summarized by ``tools/fold_sweeps.py``) seed
+``ModelBasedTuner``'s regression, so TPU tuning starts from measured
 ground truth and its FIRST proposal is the best measured config.
 """
 
@@ -125,7 +124,7 @@ def seed_exps_with_priors(exps, priors):
             e["ds_config"].get("comm_optimizations") or {}, best))
 
 
-def load_measured_priors(runs_dir=".bench_runs"):
+def load_measured_priors(runs_dir="chiprun_out"):
     """Collect priors from every trustworthy record under ``runs_dir``
     (top-level ``*.json`` ladder legs + ``sweeps/*.json``)."""
     priors = []

@@ -167,9 +167,7 @@ def gspmd_region(body, *, mesh, in_specs, out_specs, axis_names=None,
     ``grad_transparent=True`` uses :func:`straight_through_constraint` for
     the boundary constraints — required when the island is differentiated
     (the qwZ gather), see that function's docstring.  ``axis_names``
-    restricts manual mode to a subset of mesh axes (partial-manual; the
-    caller owns the legacy-jax guard — ``jax_compat.is_legacy_shard_map``
-    aborts on manual subgroups)."""
+    restricts manual mode to a subset of mesh axes (partial-manual)."""
     from jax.sharding import NamedSharding
 
     def _is_multi(specs):

@@ -52,19 +52,18 @@ def _paged_attention(q, k_cache, v_cache, tables_t, positions, block_size,
     means the buffer is region-split by the batch builder: per-token kernel
     for the first ``decode_cap`` rows, atom-tiled kernel (``atom``
     same-sequence rows per tile — much better MXU occupancy for prefill)
-    for the rest.  Fallback: XLA gather of each token's block run with
-    position masking.
+    for the rest.  Off a TPU (and at ``use_kernel=False``): XLA gather of
+    each token's block run with position masking — chosen by
+    ``ops/_use_kernels.use_pallas_kernels``, the same gate as every other
+    kernel dispatch site.
 
     ``kv_scales=(k_scales, v_scales)`` ([num_blocks, bs, Hkv] f32 each) is
     the quantized-KV read path: the caches hold int8/fp8 rows and only the
     gathered context is dequantized (per-(token, head) scale applied inside
     the same f32 widening the math does anyway).  The Pallas kernel doesn't
     consume scales, so this path always takes the XLA gather."""
-    import os
-    if (use_kernel and kv_scales is None
-            and (jax.default_backend() == "tpu"
-                 or os.environ.get("DS_TPU_TEST_PAGED_INTERPRET"))
-            and not os.environ.get("DS_TPU_DISABLE_PALLAS_PAGED")):
+    from ...ops._use_kernels import use_pallas_kernels
+    if use_kernel and kv_scales is None and use_pallas_kernels():
         from ...ops.pallas.paged_attention import (paged_attention,
                                                    paged_attention_atoms)
         decode_cap, atom = layout

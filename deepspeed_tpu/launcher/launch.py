@@ -8,8 +8,12 @@ TPU-native: JAX wants **one process per host** that owns every local chip
 (SPMD), so the default is a single child per node with
 ``JAX_PROCESS_COUNT = num_nodes`` and ``COORDINATOR_ADDRESS`` rendezvous.
 ``--one_proc_per_device`` restores the reference's process-per-device layout
-(sets ``TPU_VISIBLE_DEVICES``/``TPU_PROCESS_BOUNDS`` per child) for tools
-that need it.  Both MASTER_* and COORDINATOR_ADDRESS spellings are exported.
+for tools that need it; on a TPU host each child is told which chip is its
+own (:func:`tpu_one_chip_env`), or the chips would all go to the first child
+to start.  Both MASTER_* and COORDINATOR_ADDRESS spellings are exported.
+
+This process never initialises a JAX backend — the chips belong to the
+workers it starts.
 """
 
 import argparse
@@ -20,9 +24,39 @@ import sys
 import time
 
 from ..utils.logging import logger
-from .runner import decode_world_info
+from .runner import decode_world_info, local_chip_count
 
 PID_FILE_BASEPATH = "/tmp"
+
+#: libtpu's grid of one-chip processes on one host, by the host's chip
+#: count.  Only layouts that have been run are listed: one chip, and the
+#: four chips (2x2) of a v5e host.
+TPU_PROCESS_BOUNDS = {1: "1,1,1", 4: "2,2,1"}
+TPU_PROCESS_PORT_BASE = 8476
+
+
+def tpu_one_chip_env(n, local_rank):
+    """What libtpu needs, beside ``TPU_VISIBLE_DEVICES``, to give this child
+    exactly one chip of a host whose ``n`` chips are shared out one per
+    process, while the processes still form one slice (they rendezvous with each other on
+    ``TPU_PROCESS_ADDRESSES``; jax.distributed sits on top as usual).
+    Run on a four-chip v5e host: each child sees one local and four global
+    devices.  ``jax.process_index()`` there follows the chip's position in
+    the slice, not RANK (ranks 0,1,2,3 came up as processes 0,2,3,1)."""
+    if n not in TPU_PROCESS_BOUNDS:
+        raise ValueError(
+            f"--one_proc_per_device on a TPU host is set up for "
+            f"{sorted(TPU_PROCESS_BOUNDS)} chips per host, not {n}: the "
+            "process grid libtpu needs for that layout has not been "
+            "established — run the default one-process-per-host layout")
+    return {
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": TPU_PROCESS_BOUNDS[n],
+        "TPU_PROCESS_ADDRESSES": ",".join(
+            f"localhost:{TPU_PROCESS_PORT_BASE + i}" for i in range(n)),
+        "TPU_PROCESS_PORT": str(TPU_PROCESS_PORT_BASE + local_rank),
+        "CLOUD_TPU_TASK_ID": str(local_rank),
+    }
 
 
 def parse_args(args=None):
@@ -93,7 +127,13 @@ def build_child_env(args, world_info, node_rank, local_rank, procs_per_node):
         env["JAX_PROCESS_ID"] = str(rank)
         slots = world_info[hosts[node_rank]]
         env["TPU_VISIBLE_DEVICES"] = str(slots[local_rank])
-        env["CUDA_VISIBLE_DEVICES"] = str(slots[local_rank])
+        if local_chip_count():
+            if num_nodes > 1:
+                raise ValueError(
+                    "--one_proc_per_device on TPU hosts is set up for one "
+                    "host: the cross-host process grid has not been "
+                    "established — run one process per host")
+            env.update(tpu_one_chip_env(len(slots), local_rank))
 
     if world_size > 1:
         env["COORDINATOR_ADDRESS"] = coordinator
@@ -138,7 +178,7 @@ def main(args=None):
             logger.warning(
                 "elastic training supervises one worker per node; "
                 "--one_proc_per_device (%d local devices) is ignored — the "
-                "worker owns all local chips via jax.local_devices()",
+                "worker owns all local chips",
                 procs_per_node)
         env = build_child_env(args, world_info, node_rank, 0, 1)
         agent = DSElasticAgent(child_cmd(), env, ds_config=None,

@@ -5,7 +5,10 @@ include/exclude filters :293, world-info encode :384, ``main`` :419 picks a
 multinode backend and ``exec``s it).
 
 TPU-native redesign: the unit of launch is a **host process driving all local
-chips** (JAX SPMD convention), not one process per device.  Rendezvous is
+chips** (JAX SPMD convention), not one process per device.  A chip belongs
+to one process at a time, so no launcher process (this runner, ``launch.py``,
+the multinode runners, the elastic agent) ever initialises a JAX backend —
+only the worker does.  Rendezvous is
 ``COORDINATOR_ADDRESS`` (``jax.distributed.initialize``) rather than
 MASTER_ADDR/MASTER_PORT NCCL rendezvous — the launcher sets both spellings so
 user scripts written against either work.  Single-node launches skip ssh and
@@ -14,6 +17,7 @@ exec ``launch.py`` directly.
 
 import argparse
 import base64
+import glob
 import json
 import os
 import shlex
@@ -172,12 +176,14 @@ def decode_world_info(encoded):
     return json.loads(base64.urlsafe_b64decode(encoded).decode())
 
 
-def _local_device_count():
-    try:
-        from ..accelerator import get_accelerator
-        return max(get_accelerator().device_count(), 1)
-    except Exception:
-        return 1
+def local_chip_count():
+    """TPU chips on this host, counted from the device nodes the driver
+    exposes (``/dev/accel*``, or ``/dev/vfio/<n>`` on newer hosts) — never
+    through JAX: a chip belongs to one process, and a launcher that asked
+    JAX for its devices would hold the chip its own worker needs.
+    0 on a host with no TPU."""
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
 
 
 def build_launch_command(args, active_resources):
@@ -235,7 +241,9 @@ def main(args=None):
 
     resource_pool = fetch_hostfile(args.hostfile)
     if resource_pool is None:
-        n = args.num_gpus if args.num_gpus > 0 else _local_device_count()
+        # slots matter only to --one_proc_per_device and the include/
+        # exclude filters: the default worker owns every local chip
+        n = args.num_gpus if args.num_gpus > 0 else max(local_chip_count(), 1)
         resource_pool = OrderedDict(localhost=n)
     active_resources = parse_inclusion_exclusion(resource_pool, args.include,
                                                  args.exclude)

@@ -21,7 +21,7 @@ Matched out of the box (by class name + submodule layout):
 Out-of-scope and deliberately NOT faked: the torch-diffusers pipeline
 path (torch in this stack is CPU-only — a torch module swap would not
 touch the TPU), and CUDA-graph wrapping (XLA jit covers whole-program
-capture).  See PARITY.md.
+capture).
 
 Usage::
 

@@ -581,6 +581,13 @@ def _quantized_dispatch_combine(x, combine, dispatch, expert_fn, opts, mesh,
     return _combine_region(out, cmask)
 
 
+
+def _inside_axis_context():
+    """True when traced inside a manual ``shard_map`` region (the values in
+    hand are then per-shard blocks, not global arrays)."""
+    return bool(jax.sharding.get_abstract_mesh().manual_axes)
+
+
 def dispatch_combine(x, combine, dispatch, expert_fn,
                      ep_axis=groups.EP_AXIS, mesh=None):
     """THE expert-dispatch point ``moe/layer.py`` routes through.
@@ -601,8 +608,7 @@ def dispatch_combine(x, combine, dispatch, expert_fn,
             mesh = groups.get_global_mesh()
         except Exception:
             mesh = None
-    from ..utils import jax_compat
-    if mesh is not None and jax_compat.inside_axis_context():
+    if mesh is not None and _inside_axis_context():
         n_tok = int(np.prod([mesh.shape.get(a, 1)
                              for a in groups.dp_axes()]))
         if n_tok > 1:
@@ -686,8 +692,7 @@ def record_routing(layer, k, combine, dispatch, exp_counts, l_aux):
     skipped there."""
     if not _telemetry.enabled:
         return
-    from ..utils import jax_compat
-    if jax_compat.inside_axis_context():
+    if _inside_axis_context():
         return  # per-shard values; the GSPMD path records the global view
     T = dispatch.shape[0]
     kept = jnp.sum(dispatch.astype(jnp.float32))

@@ -146,19 +146,14 @@ def _flash_bias_route(Q, K, V, bs):
             return None
     if pair_bias is None:
         return None
-    try:
-        from ..pallas.flash_bias import flash_attention_bias
-        out = flash_attention_bias(
-            Q.reshape(B * N, L, H, D), K.reshape(B * N, L, H, D),
-            V.reshape(B * N, L, H, D),
-            bias=pair_bias.reshape(B, H, L, L),    # Gb = N batch group
-            mask_bias=(None if mask_bias is None
-                       else mask_bias.reshape(B * N, 1, 1, L)),
-            causal=False)
-    except Exception as e:  # kernel construction can fail on real HW —
-        from ..attention import _warn_fallback  # same policy as attention_core
-        _warn_fallback(e)
-        return None
+    from ..pallas.flash_bias import flash_attention_bias
+    out = flash_attention_bias(
+        Q.reshape(B * N, L, H, D), K.reshape(B * N, L, H, D),
+        V.reshape(B * N, L, H, D),
+        bias=pair_bias.reshape(B, H, L, L),    # Gb = N batch group
+        mask_bias=(None if mask_bias is None
+                   else mask_bias.reshape(B * N, 1, 1, L)),
+        causal=False)
     return out.reshape(B, N, L, H, D)
 
 

@@ -178,7 +178,7 @@ def quantize_fp(x, q_bits=8, mantissa_bits=3, group_size=512,
         return jax.lax.bitcast_convert_type(q8, jnp.uint8), scale[:, 0], meta
 
     if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
+        use_pallas = not _interpret()
     if use_pallas:
         rows = tiles.shape[0]
         block = min(_pick_block(group_size), rows)

@@ -24,10 +24,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Block sizes swept on the real v5e chip (536M-param Llama bench, S=2048,
-# bf16): 128/128 → 0.364 MFU, 256/256 → 0.509, 256/512 → 0.534,
-# 512/256 → 0.531, 512/512 → 0.581.  Large tiles win: fewer grid steps and
-# better MXU occupancy beat the extra VMEM (~1.5 MB total at D=128).
+# Large tiles: fewer grid steps and better MXU occupancy for ~1.5 MB of
+# VMEM at D=128.  An earlier sweep (another jax, no surviving record)
+# preferred 512/512; on the installed stack the sweep is not measured.
 # Override per-run with DS_TPU_FLASH_BLOCK_Q/K.
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512

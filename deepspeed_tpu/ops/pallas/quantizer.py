@@ -76,7 +76,7 @@ def quantize_blockwise(x, num_bits=8, group_size=2048, use_pallas=None):
     tiles, n, groups = _group_view(x, group_size, _pick_block(group_size))
     meta = (x.shape, x.dtype, groups)
     if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
+        use_pallas = not _interpret()
     if not use_pallas:
         xf = tiles.astype(jnp.float32)
         absmax = jnp.max(jnp.abs(xf), axis=1, keepdims=True)
@@ -109,7 +109,7 @@ def dequantize_blockwise(q, scales, meta, use_pallas=None):
     for d in shape:
         n *= d
     if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
+        use_pallas = not _interpret()
     if not use_pallas:
         out = q.astype(jnp.float32) * scales[:, None]
     else:

@@ -20,13 +20,13 @@ telemetry spine (``mfu`` on step records, the compiled-programs table in
 ``tools/trace_report.py``) and a loud once-per-program OOM-margin warning
 fires when the static estimate approaches ``total_memory()``.
 
-Degradation contract (tier-1 runs on the pinned CPU jaxlib): when
-``cost_analysis()`` / ``memory_analysis()`` are absent or raise, the
-capture falls back to the analytic jaxpr flop walk below (the pre-PR-14
-``flops_profiler`` machinery, now canonically homed here) with a
-once-per-process warning — it never raises into a training step.
+A program that fails to lower, compile or accept a call raises: the AOT
+executable *is* the program that trains, so there is nothing to fall back to
+that would not be a second compile hiding a layout bug.
 
-``flops_profiler/`` is a façade over this module since PR 14.
+The analytic jaxpr walk below serves the per-module breakdown of
+``flops_profiler/`` (a façade over this module since PR 14) — XLA's cost
+model has no module tree.
 """
 
 import os
@@ -38,9 +38,10 @@ import numpy as np
 from ..utils.logging import logger
 
 # --------------------------------------------------------------- peak FLOPS
-#: per-chip peak dense FLOP/s by device kind (bf16 matmul peak — the MFU
-#: convention of TPU training reports).  Matched by lowercase substring,
-#: longest match wins; override with DS_TPU_PEAK_FLOPS (float, FLOP/s).
+#: THE per-chip peak dense bf16 FLOP/s table (Google Cloud TPU documentation,
+#: per-generation system-architecture pages), matched against
+#: ``jax.devices()[0].device_kind`` by lowercase substring, longest match
+#: wins.  A device kind that is not here is an error, not a default.
 PEAK_FLOPS_BY_KIND = (
     ("tpu v6", 918e12),      # Trillium / v6e
     ("tpu v5p", 459e12),
@@ -50,54 +51,45 @@ PEAK_FLOPS_BY_KIND = (
     ("tpu v4", 275e12),
     ("tpu v3", 123e12),
     ("tpu v2", 46e12),
-    # nominal host-CPU figure so CPU smoke runs report a *finite* MFU; a
-    # few AVX cores land within an order of magnitude of this.  Not a
-    # benchmarking claim — set DS_TPU_PEAK_FLOPS to calibrate.
+    # nominal host-CPU figure so the tier-1 telemetry tests (which run on
+    # the CPU mesh) get a finite MFU.  Not a device number: chip_smoke.py
+    # and bench.py refuse to run on a CPU before they could reach it.
     ("cpu", 1e11),
 )
 
 PEAK_FLOPS_ENV = "DS_TPU_PEAK_FLOPS"
 
-_DEFAULT_PEAK = 1e12   # unknown accelerator: nominal 1 TFLOP/s, warned once
-_peak_warned = False
 
-
-def peak_flops_per_chip():
-    """Per-chip peak FLOP/s from the device table, ``DS_TPU_PEAK_FLOPS``
-    winning over it.  Unknown device kinds get a nominal figure with a
-    once-per-process warning (MFU stays finite, never garbage-infinite)."""
-    global _peak_warned
-    env = os.environ.get(PEAK_FLOPS_ENV)
-    if env:
-        try:
-            v = float(env)
-            if v > 0:
-                return v
-        except ValueError:
-            pass
-        logger.warning("%s=%r is not a positive float — falling back to "
-                       "the device table", PEAK_FLOPS_ENV, env)
-    import jax
-    dev = jax.devices()[0]
-    kind = f"{dev.platform} {getattr(dev, 'device_kind', '')}".lower()
+def peak_flops_for_kind(device_kind):
+    """Table lookup for one ``device_kind`` string; unknown kinds raise."""
+    kind = str(device_kind).lower()
     best, best_len = None, -1
     for frag, peak in PEAK_FLOPS_BY_KIND:
         if frag in kind and len(frag) > best_len:
             best, best_len = peak, len(frag)
-    if best is not None:
-        return best
-    if not _peak_warned:
-        _peak_warned = True
-        logger.warning(
-            "no peak-FLOPS table entry for device kind %r — MFU uses a "
-            "nominal %g FLOP/s; set %s for a calibrated figure",
-            kind, _DEFAULT_PEAK, PEAK_FLOPS_ENV)
-    return _DEFAULT_PEAK
+    if best is None:
+        raise KeyError(
+            f"no peak-FLOPS entry for device kind {device_kind!r} — add it "
+            f"to cost_model.PEAK_FLOPS_BY_KIND with its source (or set "
+            f"{PEAK_FLOPS_ENV} for an unlisted part)")
+    return best
+
+
+def peak_flops_per_chip():
+    """Per-chip peak FLOP/s of the default device: ``DS_TPU_PEAK_FLOPS``
+    (a positive float) when set, else the table."""
+    env = os.environ.get(PEAK_FLOPS_ENV)
+    if env:
+        v = float(env)
+        if not v > 0:
+            raise ValueError(f"{PEAK_FLOPS_ENV}={env!r} must be positive")
+        return v
+    import jax
+    return peak_flops_for_kind(jax.devices()[0].device_kind)
 
 
 # ------------------------------------------------------ analytic jaxpr walk
-# (moved here from flops_profiler/profiler.py — the fallback when the
-# compiled cost model is unavailable, and the per-scope module breakdown)
+# (the per-scope module breakdown behind flops_profiler/)
 _ELEMENTWISE_1 = {
     "add", "sub", "mul", "div", "max", "min", "pow", "and", "or", "xor",
     "neg", "abs", "floor", "ceil", "round", "sign", "select_n",
@@ -222,8 +214,8 @@ def _walk_jaxpr(jaxpr, scale=1, scope="", acc=None):
 
 def jaxpr_flops(fn, *args, **kwargs):
     """(total_flops, total_macs, per_scope dict) for fn(*args) by analytic
-    jaxpr walk — the fallback flop counter and the per-module breakdown
-    (XLA's cost model has no module tree; flax name stacks do)."""
+    jaxpr walk — the per-module breakdown (XLA's cost model has no module
+    tree; flax name stacks do)."""
     import jax
     closed = jax.make_jaxpr(fn)(*args, **kwargs)
     acc = _walk_jaxpr(closed.jaxpr)
@@ -233,73 +225,50 @@ def jaxpr_flops(fn, *args, **kwargs):
 
 
 # ------------------------------------------------------------ compiled cost
-_absence_warned = set()   # which degradation classes warned already
-
-
-def _warn_absent(what, err=None):
-    """Once-per-process (per degradation class) note that the compiled cost
-    model is unavailable — the flop-counting fallback takes over."""
-    if what in _absence_warned:
-        return
-    _absence_warned.add(what)
-    logger.warning(
-        "compiled cost model: %s unavailable on this backend%s — "
-        "falling back to analytic flop counting (MFU/HBM figures degrade "
-        "to estimates or None; expected on older jaxlib/CPU pins)",
-        what, f" ({err})" if err else "")
-
-
 def analyze_compiled(compiled):
     """Extract {flops, bytes_accessed, *_bytes, peak_hbm_bytes} from a
     ``Compiled`` object.  Per-DEVICE numbers (the compiled executable is
-    the per-partition SPMD program).  Missing pieces come back None; never
-    raises."""
-    out = {"flops": None, "bytes_accessed": None, "argument_bytes": None,
-           "output_bytes": None, "temp_bytes": None,
-           "generated_code_bytes": None, "alias_bytes": None,
-           "peak_hbm_bytes": None, "source": None}
-    try:
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        if ca:
-            f = ca.get("flops")
-            if f is not None and f >= 0:
-                out["flops"] = float(f)
-                out["source"] = "xla"
-            b = ca.get("bytes accessed")
-            if b is not None and b >= 0:
-                out["bytes_accessed"] = float(b)
-    except Exception as e:
-        _warn_absent("cost_analysis()", e)
-    try:
-        ma = compiled.memory_analysis()
-        if ma is not None:
-            arg = int(getattr(ma, "argument_size_in_bytes", 0))
-            outb = int(getattr(ma, "output_size_in_bytes", 0))
-            tmp = int(getattr(ma, "temp_size_in_bytes", 0))
-            gen = int(getattr(ma, "generated_code_size_in_bytes", 0))
-            alias = int(getattr(ma, "alias_size_in_bytes", 0))
-            out.update(argument_bytes=arg, output_bytes=outb,
-                       temp_bytes=tmp, generated_code_bytes=gen,
-                       alias_bytes=alias)
-            # static peak estimate: everything resident at once, minus
-            # donated outputs that alias their argument buffers
-            out["peak_hbm_bytes"] = max(0, arg + outb + tmp + gen - alias)
-    except Exception as e:
-        _warn_absent("memory_analysis()", e)
+    the per-partition SPMD program).  A figure XLA does not report for this
+    program comes back None."""
+    out = {"flops": None, "bytes_accessed": None, "source": None}
+    ca = compiled.cost_analysis()
+    if isinstance(ca, (list, tuple)):
+        ca = ca[0] if ca else {}
+    ca = ca or {}
+    f = ca.get("flops")
+    if f is not None and f >= 0:
+        out["flops"] = float(f)
+        out["source"] = "xla"
+    b = ca.get("bytes accessed")
+    if b is not None and b >= 0:
+        out["bytes_accessed"] = float(b)
+    ma = compiled.memory_analysis()
+    arg = int(ma.argument_size_in_bytes)
+    outb = int(ma.output_size_in_bytes)
+    tmp = int(ma.temp_size_in_bytes)
+    gen = int(ma.generated_code_size_in_bytes)
+    alias = int(ma.alias_size_in_bytes)
+    out.update(argument_bytes=arg, output_bytes=outb, temp_bytes=tmp,
+               generated_code_bytes=gen, alias_bytes=alias,
+               # static peak estimate: everything resident at once, minus
+               # donated outputs that alias their argument buffers
+               peak_hbm_bytes=max(0, arg + outb + tmp + gen - alias))
     return out
 
 
 # --------------------------------------------------------------- the registry
 class CompiledProgram:
-    """One captured program: its XLA cost/memory analysis + call count."""
+    """One captured program: the executable, its XLA cost/memory analysis
+    and a call count.  ``compiled`` is the ``jax.stages.Compiled`` that
+    runs (``as_text()`` is its optimized HLO) — chip_smoke.py reads it to
+    see which kernels and collectives the program really holds."""
 
-    __slots__ = ("name", "analysis", "flops", "peak_hbm_bytes", "calls",
-                 "meta", "captured_at")
+    __slots__ = ("name", "compiled", "analysis", "flops", "peak_hbm_bytes",
+                 "calls", "meta", "captured_at")
 
-    def __init__(self, name, analysis, meta=None):
+    def __init__(self, name, analysis, meta=None, compiled=None):
         self.name = name
+        self.compiled = compiled
         self.analysis = dict(analysis)
         self.flops = self.analysis.get("flops")
         self.peak_hbm_bytes = self.analysis.get("peak_hbm_bytes")
@@ -326,8 +295,9 @@ class CostModelRegistry:
         self._programs = {}
         self.version = 0
 
-    def record(self, name, analysis, meta=None):
-        entry = CompiledProgram(name, analysis, meta=meta)
+    def record(self, name, analysis, meta=None, compiled=None):
+        entry = CompiledProgram(name, analysis, meta=meta,
+                                compiled=compiled)
         self._programs[name] = entry
         self.version += 1
         return entry
@@ -374,7 +344,6 @@ def registry():
 def reset():
     """Test hook: clear captured programs + once-per-process warn state."""
     _registry.reset()
-    _absence_warned.clear()
     _oom_warned.clear()
 
 
@@ -391,18 +360,11 @@ def check_oom_margin(name, peak_hbm_bytes):
     estimate is hearing about the OOM before the first step hits it."""
     if not peak_hbm_bytes or name in _oom_warned:
         return False
-    try:
-        from ..accelerator import get_accelerator
-        total = get_accelerator().total_memory()
-    except Exception:
+    from ..accelerator import get_accelerator
+    total = get_accelerator().total_memory()
+    if not total:   # a backend that reports no limit (CPU)
         return False
-    if not total:
-        return False
-    try:
-        frac = float(os.environ.get("DS_TPU_OOM_MARGIN",
-                                    OOM_MARGIN_FRACTION))
-    except ValueError:
-        frac = OOM_MARGIN_FRACTION
+    frac = float(os.environ.get("DS_TPU_OOM_MARGIN", OOM_MARGIN_FRACTION))
     if peak_hbm_bytes >= frac * total:
         _oom_warned.add(name)
         logger.warning(
@@ -419,9 +381,9 @@ def check_oom_margin(name, peak_hbm_bytes):
 
 # -------------------------------------------------------------- capture API
 #: force-capture switch for tools that want the registry populated without
-#: enabling the full telemetry spine (serve_bench); telemetry.enabled also
-#: arms capture at the opt-in call sites (serving) — the training engine
-#: captures unconditionally because its AOT path costs no extra compile.
+#: enabling the full telemetry spine (serve_bench, chip_smoke);
+#: telemetry.enabled also arms capture at the opt-in call sites (serving) —
+#: the training engine captures unconditionally.
 _force_capture = False
 
 
@@ -431,120 +393,52 @@ def enable_capture(on=True):
 
 
 def capturing():
-    """Should opt-in call sites (which pay an extra analysis compile)
-    capture right now?"""
+    """Should the opt-in call sites (serving) capture right now?"""
     if _force_capture:
         return True
     from .. import telemetry
     return telemetry.enabled
 
 
-class GuardedProgram:
-    """An AOT-compiled executable with a jit fallback.
-
-    The engine compiles its programs ahead-of-time (``lower().compile()``)
-    so the cost model reads the *exact* executable that trains — same
-    single compile as ``jit`` would do.  AOT calls validate input layouts
-    strictly; if a later call ever mismatches (re-placed state after an
-    offload round-trip on an exotic backend), this wrapper logs once and
-    permanently falls back to the plain jitted function rather than
-    killing the step.  Only pre-dispatch VALIDATION failures
-    (TypeError/ValueError) are absorbed — they fire before any donated
-    buffer is consumed, so the fallback re-call is safe.  Execution-time
-    errors (a real RESOURCE_EXHAUSTED OOM, runtime faults) propagate:
-    by then donated inputs may be gone, and re-running the fallback
-    would mask the true error behind a deleted-buffer traceback."""
-
-    __slots__ = ("compiled", "fallback", "name", "_failed")
-
-    def __init__(self, compiled, fallback, name):
-        self.compiled = compiled
-        self.fallback = fallback
-        self.name = name
-        self._failed = False
-
-    def __call__(self, *args):
-        if not self._failed:
-            try:
-                return self.compiled(*args)
-            except (TypeError, ValueError) as e:
-                self._failed = True
-                logger.warning(
-                    "cost model: AOT executable %r rejected a call (%s: "
-                    "%s) — re-dispatching through jit from now on",
-                    self.name, type(e).__name__, e)
-        return self.fallback(*args)
+def _compile_and_record(name, jitted, args, kwargs, meta):
+    compiled = jitted.lower(*args, **(kwargs or {})).compile()
+    entry = _registry.record(name, analyze_compiled(compiled), meta=meta,
+                             compiled=compiled)
+    check_oom_margin(name, entry.peak_hbm_bytes)
+    return entry
 
 
-def capture_jit(name, jitted, args=(), kwargs=None, fallback_flops=None,
-                meta=None):
+def capture_jit(name, jitted, args=(), kwargs=None, meta=None):
     """AOT-compile ``jitted`` for ``args`` and record its cost entry.
 
-    Returns ``(callable, entry)`` — the callable is the compiled
-    executable wrapped in :class:`GuardedProgram` (one compile total, the
-    same one jit would have done lazily), or the plain ``jitted`` when
-    lowering/compiling through the AOT path fails.  ``fallback_flops`` is
-    a zero-arg callable returning an analytic flop count used when (or for
-    backends where) ``cost_analysis`` has no answer."""
-    kwargs = kwargs or {}
-    analysis = None
-    fn = jitted
-    try:
-        compiled = jitted.lower(*args, **kwargs).compile()
-        analysis = analyze_compiled(compiled)
-        fn = GuardedProgram(compiled, jitted, name)
-    except Exception as e:
-        _warn_absent("ahead-of-time lower/compile", e)
-    if analysis is None:
-        analysis = {"flops": None, "peak_hbm_bytes": None, "source": None}
-    if analysis.get("flops") is None and fallback_flops is not None:
-        try:
-            analysis["flops"] = float(fallback_flops())
-            analysis["source"] = "analytic"
-        except Exception as e:
-            _warn_absent("analytic flop fallback", e)
-    entry = _registry.record(name, analysis, meta=meta)
-    check_oom_margin(name, entry.peak_hbm_bytes)
-    return fn, entry
+    Returns ``(compiled, entry)`` — ``compiled`` is the executable itself
+    (one compile total, the same one jit would have done lazily).  It
+    validates input layouts strictly: a call whose arguments are placed
+    differently from ``args`` raises instead of silently recompiling."""
+    entry = _compile_and_record(name, jitted, args, kwargs, meta)
+    return entry.compiled, entry
 
 
 def capture_jit_call(name, jitted, args=(), kwargs=None, meta=None):
     """Record the cost entry for a call signature of an existing jitted
     function WITHOUT replacing the callable (the serving engines keep
-    jit's own static-argument dispatch).  Costs one extra analysis compile
-    per distinct ``name`` — only do this under :func:`capturing`.  Always
-    returns the (possibly pre-existing) entry; increments its call count."""
-    entry = _registry.get(name)
-    if entry is None:
-        analysis = None
-        try:
-            compiled = jitted.lower(*args, **(kwargs or {})).compile()
-            analysis = analyze_compiled(compiled)
-        except Exception as e:
-            _warn_absent("ahead-of-time lower/compile", e)
-        if analysis is None:
-            analysis = {"flops": None, "peak_hbm_bytes": None,
-                        "source": None}
-        entry = _registry.record(name, analysis, meta=meta)
-        check_oom_margin(name, entry.peak_hbm_bytes)
+    jit's own static-argument dispatch; jit and the AOT path share one
+    executable cache, so this is not a second compile).  Only done under
+    :func:`capturing`.  Always returns the (possibly pre-existing) entry;
+    increments its call count."""
+    entry = _registry.get(name) or _compile_and_record(
+        name, jitted, args, kwargs, meta)
     entry.calls += 1
     return entry
 
 
 def analyze_fn(fn, *args, **kwargs):
     """One-shot analysis of ``fn(*args, **kwargs)`` (jitted here if not
-    already a jit wrapper).  Returns the analysis dict (values None when
-    the backend has no answer) — the flops_profiler façade and the bench
-    candidate rows use this."""
+    already a jit wrapper).  Returns the analysis dict — the flops_profiler
+    façade and the bench candidate rows use this."""
     import jax
     jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
-    try:
-        compiled = jitted.lower(*args, **kwargs).compile()
-        return analyze_compiled(compiled)
-    except Exception as e:
-        _warn_absent("ahead-of-time lower/compile", e)
-        return {"flops": None, "bytes_accessed": None,
-                "peak_hbm_bytes": None, "source": None}
+    return analyze_compiled(jitted.lower(*args, **kwargs).compile())
 
 
 def mfu(flops_per_chip_per_second, peak=None):

@@ -162,8 +162,7 @@ def stage_timing(D=1024, H=8, S=512, dtype=jnp.bfloat16, iters=20):
 
 def bias_attention_timing(B=2, N=8, L=512, H=4, D=32, iters=10):
     """Pallas bias-operand flash (dBias in-kernel) vs the chunked-XLA
-    evoformer path — value+grad step on a pair-biased MSA attention
-    (VERDICT r3 item 4 microbench)."""
+    evoformer path — value+grad step on a pair-biased MSA attention."""
     import os
     from ..ops.deepspeed4science.evoformer_attn import (
         DS4Sci_EvoformerAttention)

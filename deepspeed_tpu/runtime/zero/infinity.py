@@ -23,7 +23,7 @@ host-resident:
   them into a host stash (wire dtype at gas=1, fp32 when accumulating), and
   ``optimizer_sweep`` runs the host Adam/Adagrad/Lion kernel block-by-block —
   emitting the updated bf16 cache in the same pass (``bf16_out``), so updated
-  params never round-trip through HBM (VERDICT r3 missing #2).
+  params never round-trip through HBM.
 
 HBM never holds more than the executor's working set of blocks (the
 :class:`~deepspeed_tpu.runtime.infinity_engine.InfinityEngine` keeps ≤ 3:

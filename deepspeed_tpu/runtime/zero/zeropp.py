@@ -168,18 +168,6 @@ def build_manual_dp_micro(engine):
     # axis — GSPMD keeps inserting the tensor-parallel collectives inside
     # the body exactly as in the normal micro-step.
     manual_only = engine.mp_world_size > 1
-    if manual_only:
-        from ...utils import jax_compat
-        if jax_compat.is_legacy_shard_map():
-            # this jaxlib's SPMD partitioner CHECK-fails (native abort, takes
-            # the whole process) lowering partial-manual programs with
-            # collectives inside — refuse cleanly instead
-            raise ValueError(
-                "zero_quantized_gradients with tp > 1 needs the modern "
-                "jax.shard_map partial-manual lowering; this jax only has "
-                "the legacy experimental shard_map, whose partitioner "
-                "aborts on manual-subgroup sharding. Upgrade jax, or "
-                "disable zero_quantized_gradients / drop the tp axis")
     # With hpZ/MiCS the manual step runs over the reshaped hpz mesh, whose
     # (zp_outer, zp) axes tile the same device order as (dp, ep) on the
     # global mesh — full-dp specs are translated axis-for-axis.

@@ -152,22 +152,8 @@ class DistributedAttention:
             # CONTEXT abstract mesh, not the concrete global mesh — enables
             # pp×sp (BASELINE config-5 shape)
             cur = jax.sharding.get_abstract_mesh()
-            if getattr(cur, "manual_axes", ()):
-                mesh = cur
-            else:
-                from ..utils import jax_compat
-                if jax_compat.is_legacy_shard_map() and \
-                        jax_compat.inside_axis_context():
-                    # nested manual region on a jax without
-                    # get_abstract_mesh: we cannot resolve the context mesh
-                    # and the nested program CHECK-fails the legacy SPMD
-                    # partitioner (native abort) — refuse cleanly
-                    raise ValueError(
-                        "DistributedAttention called inside a manual "
-                        "shard_map region, but this legacy jax cannot "
-                        "resolve the context abstract mesh — upgrade jax "
-                        "for fused pp×sp, or run sp without pp")
-                mesh = groups.get_global_mesh()
+            mesh = cur if getattr(cur, "manual_axes", ()) \
+                else groups.get_global_mesh()
         a = self.sp_axis
         if mesh.shape.get(a, 1) == 1:
             key, value = self._align_gqa_local(query, key, value)
@@ -188,14 +174,9 @@ class DistributedAttention:
             def f(q, k, v):
                 return self.attend_local(q, k, v, **kwargs)
 
-            sm_kw = dict(mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, check_vma=False)
-            from ..utils import jax_compat
-            if not jax_compat.is_legacy_shard_map():
-                sm_kw["axis_names"] = frozenset({a})
-            # else FULL-manual: the legacy partitioner CHECK-fails (native
-            # abort) on manual-subgroup sharding, so eat the dead compute
-            cache[key_] = jax.jit(jax.shard_map(f, **sm_kw))
+            cache[key_] = jax.jit(jax.shard_map(
+                f, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+                axis_names=frozenset({a}), check_vma=False))
         return cache[key_](query, key, value)
 
 

@@ -38,16 +38,15 @@ logger = _create_logger(
 
 
 def _get_rank():
-    # Avoid importing jax at module import time; the launcher sets RANK before
-    # child processes import this package (launcher/launch.py analog).
+    """This process's rank WITHOUT bringing up a JAX backend: a launcher
+    parent that logs must not take the chip from the worker it is about to
+    start.  The launcher exports RANK to workers; a process started any
+    other way is rank 0 until it has joined ``jax.distributed``."""
     rank = os.environ.get("RANK")
     if rank is not None:
         return int(rank)
-    try:
-        import jax
-        return jax.process_index()
-    except Exception:
-        return 0
+    import jax
+    return jax.process_index() if jax.distributed.is_initialized() else 0
 
 
 def log_dist(message, ranks=None, level=logging.INFO):

@@ -21,10 +21,6 @@ import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-
 import numpy as np
 import flax.linen as nn
 import jax
@@ -44,6 +40,8 @@ class Net(nn.Module):
 
 
 def main():
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--steps", type=int, default=6)
     p.add_argument("--samples", type=int, default=96)

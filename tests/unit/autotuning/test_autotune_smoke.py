@@ -62,21 +62,3 @@ def test_autotune_smoke_gate(tmp_path, with_priors):
     cfg = deepspeed_tpu.DeepSpeedConfig(
         {"train_micro_batch_size_per_gpu": 1, **block})
     assert cfg is not None
-
-
-def test_ladder_row_record_schema(tmp_path, monkeypatch):
-    """The bench-ladder record rides the bench schema and marks CPU runs
-    untrusted (same gate update_ladder/fold_sweeps apply everywhere)."""
-    smoke = _load_smoke()
-    monkeypatch.setattr(smoke, "REPO", str(tmp_path))
-    rec = smoke._record_ladder_row({
-        "best_name": "z2_ladder", "best_step_ms": 4.0,
-        "default_step_ms": 5.0, "trials": 6})
-    assert rec["metric"] == "autotune_step_time_ms"
-    assert rec["vs_baseline"] == 1.25
-    assert "backend=cpu" in rec["unit"]        # CPU leg marks itself
-    on_disk = json.loads(
-        (tmp_path / ".bench_runs" / "autotune.json").read_text())
-    assert on_disk == rec
-    from deepspeed_tpu.autotuning.priors import untrustworthy
-    assert untrustworthy(rec) is not None      # refused by the trust gate

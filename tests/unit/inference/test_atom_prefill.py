@@ -105,7 +105,7 @@ def test_atom_generate_matches_flat_pallas_interpret():
                                         "..", "..", ".."))
     env = dict(os.environ)
     env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
-    env["DS_TPU_TEST_PAGED_INTERPRET"] = "1"
+    env["DS_TPU_FORCE_PALLAS"] = "1"
     proc = subprocess.run([sys.executable, "-c", _GEN_SNIPPET], env=env,
                           capture_output=True, text=True, timeout=300,
                           cwd=repo)

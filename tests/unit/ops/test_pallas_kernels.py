@@ -84,6 +84,43 @@ def test_flash_attention_grads(gqa):
         np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5)
 
 
+@pytest.mark.parametrize("batch", [8, 3])
+def test_attention_core_runs_the_kernel_per_device_under_a_mesh(
+        batch, monkeypatch):
+    """A Mosaic kernel cannot be partitioned by GSPMD (on >1 chip the
+    lowering raises), so under the global mesh ``attention_core`` wraps the
+    flash kernel in a shard_map: batch over the dp axes and heads over tp
+    where they divide (B=8 on dp4 x tp2), replicated work where they do not
+    (B=3).  Value and grads match the XLA formulation either way."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from deepspeed_tpu.ops import attention
+    from deepspeed_tpu.utils import groups
+
+    monkeypatch.setenv("DS_TPU_FORCE_PALLAS", "1")   # interpreted on CPU
+    mesh = groups.initialize_mesh(dp=4, tp=2).mesh
+    S, H, D = 32, 4, 32
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    sh = NamedSharding(mesh, P(groups.dp_axes() if batch % 4 == 0 else None))
+    q, k, v = (jax.device_put(_rand(kk, (batch, S, H, D)), sh) for kk in ks)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v, causal=True)))
+
+    jaxpr = str(jax.make_jaxpr(attention.attention_core)(q, k, v))
+    assert "shard_map" in jaxpr and "pallas_call" in jaxpr
+    got = jax.jit(jax.value_and_grad(loss(attention.attention_core),
+                                     argnums=(0, 1, 2)))(q, k, v)
+    want = jax.value_and_grad(loss(attention._xla_attention),
+                              argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(got[0], want[0], atol=1e-4, rtol=1e-4)
+    for a, b in zip(got[1], want[1]):
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5)
+    # no mesh in force: the kernel is called directly
+    groups.reset_mesh()
+    assert "shard_map" not in str(jax.make_jaxpr(       # (a fresh trace)
+        lambda *a: attention.attention_core(*a))(q, k, v))
+
+
 def test_flash_attention_dead_rows_no_nan():
     """Causal attention with sk < sq leaves leading q rows fully masked
     (lse hits the dead-row sentinel).  Regression: the packed-lse identity

@@ -1,7 +1,6 @@
-"""profiling/cost_model: compiled-cost capture, degradation contract
-(ISSUE-14 satellite: cost_analysis()/memory_analysis() absence on the
-pinned jaxlib/CPU backend must degrade to flop-counting with a once-per-
-process warning, never crash tier-1), peak-FLOPS table, OOM margin.
+"""profiling/cost_model: compiled-cost capture (the AOT executable is the
+program that runs — a rejected lower/compile/call raises), the one
+peak-FLOPS table (an unknown device kind is an error), OOM margin.
 
 The repo logger writes to its own stdout handler with propagate=False, so
 warning asserts attach a test-local handler (the ``warnlog`` fixture)."""
@@ -55,70 +54,32 @@ def test_analyze_fn_reports_flops_and_peak_on_cpu():
     assert a["argument_bytes"] >= 16 * 64 * 4
 
 
-def test_capture_jit_returns_runnable_guarded_program():
+def test_capture_jit_returns_the_executable_itself():
     fn, entry = cost_model.capture_jit("t/mm", jax.jit(_mm), ARGS)
-    assert isinstance(fn, cost_model.GuardedProgram)
+    assert isinstance(fn, jax.stages.Compiled)
     out = fn(*ARGS)
     assert np.isfinite(float(out))
     assert cost_model.registry().get("t/mm") is entry
+    assert entry.compiled is fn and "dot" in entry.compiled.as_text()
     assert entry.flops > 0
     d = cost_model.registry().describe()
     assert d[0]["name"] == "t/mm" and d[0]["source"] == "xla"
+    assert "compiled" not in d[0]        # describe() stays JSON-safe
 
 
-def test_guarded_program_falls_back_on_call_failure(warnlog):
-    fn, _ = cost_model.capture_jit("t/guard", jax.jit(_mm), ARGS)
-
-    class Boom:
-        def __call__(self, *a):
-            raise ValueError("sharding mismatch")
-
-    fn.compiled = Boom()
-    out = fn(*ARGS)   # falls back to the jitted path, once, loudly
-    assert np.isfinite(float(out))
-    assert fn._failed
-    assert "re-dispatching through jit" in warnlog.getvalue()
-    # subsequent calls go straight to the fallback
-    assert np.isfinite(float(fn(*ARGS)))
-
-
-class _NoCostCompiled:
-    """A Compiled whose analyses raise — the older-jaxlib shape."""
-
-    def cost_analysis(self):
-        raise NotImplementedError("not implemented on this backend")
-
-    def memory_analysis(self):
-        raise NotImplementedError("not implemented on this backend")
-
-
-def test_absent_cost_model_degrades_with_one_warning(warnlog):
-    a1 = cost_model.analyze_compiled(_NoCostCompiled())
-    a2 = cost_model.analyze_compiled(_NoCostCompiled())
-    assert a1["flops"] is None and a1["peak_hbm_bytes"] is None
-    assert a2["flops"] is None
-    out = warnlog.getvalue()
-    assert out.count("cost_analysis() unavailable") == 1, \
-        "absence must warn once per process, not per call"
-    assert out.count("memory_analysis() unavailable") == 1
-
-
-def test_capture_jit_lower_failure_uses_analytic_fallback(warnlog):
+def test_aot_rejections_raise_instead_of_redispatching():
+    """A lower/compile failure and a mis-placed call both propagate: no
+    second compile through jit that would hide a layout bug."""
     class BrokenJit:
         def lower(self, *a, **k):
             raise RuntimeError("no AOT on this backend")
 
-        def __call__(self, *a):
-            return _mm(*a)
-
-    fn, entry = cost_model.capture_jit(
-        "t/broken", BrokenJit(), ARGS,
-        fallback_flops=lambda: cost_model.jaxpr_flops(_mm, *ARGS)[0])
-    # never raises; callable still works; analytic flops recorded
-    assert np.isfinite(float(fn(*ARGS)))
-    assert entry.flops == cost_model.jaxpr_flops(_mm, *ARGS)[0]
-    assert entry.analysis["source"] == "analytic"
-    assert "lower/compile" in warnlog.getvalue()
+    with pytest.raises(RuntimeError, match="no AOT"):
+        cost_model.capture_jit("t/broken", BrokenJit(), ARGS)
+    assert cost_model.registry().get("t/broken") is None
+    fn, _ = cost_model.capture_jit("t/strict", jax.jit(_mm), ARGS)
+    with pytest.raises((TypeError, ValueError)):
+        fn(jnp.ones((8, 64), jnp.float32), ARGS[1])   # other shape
 
 
 def test_capture_jit_call_counts_invocations():
@@ -134,8 +95,16 @@ def test_peak_flops_env_override(monkeypatch):
     monkeypatch.setenv(cost_model.PEAK_FLOPS_ENV, "2.5e14")
     assert cost_model.peak_flops_per_chip() == 2.5e14
     monkeypatch.setenv(cost_model.PEAK_FLOPS_ENV, "not-a-float")
-    # bad override falls back to the table (cpu row on this backend)
-    assert cost_model.peak_flops_per_chip() > 0
+    with pytest.raises(ValueError):
+        cost_model.peak_flops_per_chip()
+
+
+def test_peak_flops_table_keys_by_device_kind_and_rejects_unknown():
+    assert cost_model.peak_flops_for_kind("TPU v5 lite") == 197e12
+    assert cost_model.peak_flops_for_kind("TPU v5") == 459e12   # v5p
+    assert cost_model.peak_flops_for_kind("TPU v4") == 275e12
+    with pytest.raises(KeyError, match="TPU v9 hyper"):
+        cost_model.peak_flops_for_kind("TPU v9 hyper")
 
 
 def test_mfu_refuses_on_unknown_flops():

@@ -11,15 +11,6 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
-from deepspeed_tpu.utils import jax_compat
-
-pytestmark = pytest.mark.skipif(
-    jax_compat.is_legacy_shard_map(),
-    reason="pp×sp nests the Ulysses shard_map inside the pipeline's "
-    "partial-manual region via the context abstract mesh, which this "
-    "legacy jax cannot resolve (DistributedAttention raises cleanly; the "
-    "would-be nested program aborts the old partitioner)")
-
 import deepspeed_tpu
 from deepspeed_tpu.runtime.pipe import LayerSpec, PipelineModule
 from deepspeed_tpu.utils import groups

@@ -188,7 +188,8 @@ def test_stage3_prefetch_emits_per_bucket_gathers():
         stable = lowered.as_text()
         engine2 = _engine(None)
         stable_off = _micro_artifacts(engine2)[1].as_text()
-        assert stable.count("@Sharding") > stable_off.count("@Sharding")
+        assert stable.count("sdy.sharding_constraint") > \
+            stable_off.count("sdy.sharding_constraint")
         # compiled collective structure: ≥2 distinct all-gathers survive
         # SPMD partitioning, interleaved with the layer dots
         hlo = lowered.compile().as_text()

@@ -253,11 +253,6 @@ def test_qgz_on_dp_tp_mesh():
     PARTIAL-manual mode (manual over dp, "tp" left auto so GSPMD keeps
     inserting the tensor-parallel collectives).  Round-2 limit: pure-DP
     meshes only."""
-    from deepspeed_tpu.utils import jax_compat
-    if jax_compat.is_legacy_shard_map():
-        pytest.skip("legacy experimental shard_map: partial-manual lowering "
-                    "aborts in this jaxlib's partitioner (guarded by a "
-                    "clean ValueError — see test_qgz_tp_rejected_on_legacy)")
     from deepspeed_tpu.models import llama
     cfg = llama.llama_tiny(dtype="float32", remat=False)
     losses = {}
@@ -287,32 +282,6 @@ def test_qgz_on_dp_tp_mesh():
     assert qgz[-1] < qgz[0] * 0.9, f"qgZ×tp diverged: {qgz}"
     # int8-quantized gradient traffic tracks the exact trajectory
     assert abs(qgz[-1] - ref[-1]) < 0.25 * abs(ref[0]), (ref, qgz)
-
-
-def test_qgz_tp_rejected_on_legacy_shard_map():
-    """On jaxes without native jax.shard_map, the partial-manual qgZ×tp
-    path must refuse with guidance (the legacy partitioner would otherwise
-    CHECK-fail and abort the whole process)."""
-    from deepspeed_tpu.utils import jax_compat
-    if not jax_compat.is_legacy_shard_map():
-        pytest.skip("modern shard_map: partial-manual qgZ×tp is supported")
-    from deepspeed_tpu.models import llama
-    cfg = llama.llama_tiny(dtype="float32", remat=False)
-    model = llama.LlamaModel(cfg)
-    engine, _, _, _ = deepspeed_tpu.initialize(
-        model=model, tp_rules=llama.tp_rules(cfg),
-        config={"train_micro_batch_size_per_gpu": 2,
-                "optimizer": {"type": "adam", "params": {"lr": 1e-3}},
-                "zero_optimization": {"stage": 2,
-                                      "zero_quantized_gradients": True},
-                "mesh": {"tp": 2, "dp": -1}})
-    rng = np.random.default_rng(0)
-    ids = rng.integers(0, cfg.vocab_size, size=(8, 16)).astype(np.int32)
-    with pytest.raises(ValueError, match="partial-manual"):
-        engine.initialize_parameters(0, ids, ids)
-        engine(ids, ids)
-    groups.reset_mesh()
-    deepspeed_tpu.comm.destroy_process_group()
 
 
 def test_qgz_rejects_sp_mesh():

@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.sequence.layer import DistributedAttention, _default_attention
-from deepspeed_tpu.utils import groups, jax_compat
+from deepspeed_tpu.utils import groups
 
 
 def _qkv(B=2, S=32, H=8, D=16, seed=0, kv_heads=None):
@@ -139,10 +139,6 @@ def test_ulysses_grads_flow():
                                rtol=1e-3)
 
 
-@pytest.mark.skipif(
-    jax_compat.is_legacy_shard_map(),
-    reason="legacy jax: DistributedAttention deliberately builds the "
-    "FULL-manual region (partial-manual aborts the old partitioner)")
 def test_ulysses_region_manual_over_sp_only():
     """The a2a shard_map must be PARTIAL-manual (manual_axes == {sp}): a
     full-manual region with P(None, 'sp') specs replicated the batch into

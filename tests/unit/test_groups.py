@@ -69,22 +69,26 @@ def test_hpz_must_divide_dp():
         groups.initialize_mesh(dp=8, zero_partition_size=3)
 
 
-def test_strict_locality_raises_when_hpz_requested(monkeypatch):
-    """When the config explicitly asks for hpZ's locality property, physical
-    mesh construction failure must raise, not silently degrade to linear
-    device order (round-2 review weak #9)."""
-    import jax
+def test_tpu_mesh_construction_failure_raises(monkeypatch):
+    """On TPU devices a physical mesh that cannot be built is an error —
+    never a linear device order behind a warning (the locality hpZ and the
+    tp/sp axes promise would be gone).  CPU devices have no topology and
+    take the plain reshape."""
+    import numpy as np
     from jax.experimental import mesh_utils
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     def boom(*a, **k):
         raise RuntimeError("topology query failed")
 
     monkeypatch.setattr(mesh_utils, "create_device_mesh", boom)
     monkeypatch.setattr(mesh_utils, "create_hybrid_device_mesh", boom)
-    with pytest.raises(RuntimeError, match="locality property"):
-        groups.initialize_mesh(dp=8, zero_partition_size=4)
-    # without the explicit request the same failure only warns
-    st = groups.initialize_mesh(dp=8)
-    assert st.mesh is not None
+
+    class FakeTpu:
+        platform = "tpu"
+
+    chips = np.array([FakeTpu() for _ in range(4)], dtype=object)
+    with pytest.raises(RuntimeError, match="topology query failed"):
+        groups._physical_device_grid((1, 4, 1, 1, 1), chips)
+    assert groups._physical_device_grid((1, 1, 1, 1, 1), chips[:1]).shape \
+        == (1, 1, 1, 1, 1)
+    assert groups.initialize_mesh(dp=8).mesh is not None    # CPU: reshape

@@ -15,10 +15,7 @@ End-to-end on the virtual 8-device CPU mesh (~1 min):
 3. asserts the chosen config passes the existing ``comm_smoke``
    loss-parity gate: a run with the tuned ``comm_optimizations`` block
    must track the flat baseline to the same 1e-2 final-loss tolerance
-   (tools/comm_smoke machinery — zero loss-parity regression);
-4. records the result as a bench-ladder row (``.bench_runs/autotune.json``
-   in the bench record schema) so ``tools/update_ladder.py`` can fold an
-   on-chip run into README's ladder table.
+   (tools/comm_smoke machinery — zero loss-parity regression).
 
 Run:  python tools/autotune_smoke.py [--trials N] [--priors PRIORS.json]
 Exit: 0 on PASS, 1 on any deviation.
@@ -69,7 +66,6 @@ def _smoke_autotuning_config(trials, results_dir, priors_file=""):
 def run_autotune_smoke(trials=8, results_dir=None, priors_file=""):
     """Run the gate in-process; returns a dict with the measurements and a
     ``pass`` verdict — the CLI and the unit test both key off it."""
-    import deepspeed_tpu  # noqa: F401  (jax_compat install)
     from deepspeed_tpu.autotuning.autotuner import (
         Autotuner, _synthetic_trial_model)
     import importlib.util
@@ -144,32 +140,6 @@ def run_autotune_smoke(trials=8, results_dir=None, priors_file=""):
     return result
 
 
-def _record_ladder_row(r):
-    """One bench-schema record → .bench_runs/autotune.json so
-    tools/update_ladder.py can fold a trustworthy on-chip run into the
-    README ladder (CPU runs carry backend=cpu and are refused there, same
-    trust gate as every other leg)."""
-    import jax
-    backend = jax.default_backend()
-    runs = os.path.join(REPO, ".bench_runs")
-    os.makedirs(runs, exist_ok=True)
-    vs = (r["default_step_ms"] / r["best_step_ms"]
-          if r["best_step_ms"] else 0.0)
-    rec = {
-        "metric": "autotune_step_time_ms",
-        "value": round(r["best_step_ms"], 3) if r["best_step_ms"] else None,
-        "unit": (f"ms/step (best={r['best_name']} "
-                 f"default={r['default_step_ms']:.3f}ms "
-                 f"trials={r['trials']} backend={backend}"
-                 + ("" if backend != "cpu" else " [cpu-fallback: smoke]")
-                 + ")"),
-        "vs_baseline": round(vs, 3),
-    }
-    with open(os.path.join(runs, "autotune.json"), "w") as f:
-        json.dump(rec, f)
-    return rec
-
-
 def main(argv=None):
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     flags = os.environ.get("XLA_FLAGS", "")
@@ -202,14 +172,8 @@ def main(argv=None):
     print(f"loss parity: delta {r['parity_delta']:.2e} "
           f"(tolerance {r['tolerance']}) converged={r['converged']}")
     if not r["pass"]:
-        # no ladder row for a failing run: a trusted-looking backend=tpu
-        # record from a FAILed gate must never be folded into the README
-        # ladder by tools/update_ladder.py
         print("FAIL: autotuned config does not beat the default at parity")
         return 1
-    rec = _record_ladder_row(r)
-    print(f"ladder row: {rec['value']} {rec['unit']} "
-          f"vs_baseline={rec['vs_baseline']}")
     print("PASS: autotuned config ≤ default step time with loss parity "
           f"(emitted block: {os.path.join(r['results_dir'], 'tuned_block.json')})")
     return 0

@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """Summarize on-chip runs: ladder legs + sweeps, ranked, with suggested
-default folds.  Run after tools/bench_retry.sh has chained the sweeps.
+default folds.
 
 Usage: python tools/fold_sweeps.py [--priors OUT.json]
 
@@ -252,7 +252,7 @@ def main(argv=None):
         if i + 1 >= len(argv):
             raise SystemExit("--priors needs an output path")
         priors_out = argv[i + 1]
-    runs = os.path.join(ROOT, ".bench_runs")
+    runs = os.path.join(ROOT, "chiprun_out")
     paths = sorted(glob.glob(os.path.join(runs, "*.json")) +
                    glob.glob(os.path.join(runs, "sweeps", "*.json")))
     if priors_out:
@@ -370,7 +370,7 @@ def main(argv=None):
             print()
     if not rows:
         if not overlap:
-            print("no recorded runs yet (.bench_runs empty)")
+            print("no recorded runs yet (chiprun_out empty)")
         return
     for name, rec, why in rows:
         flag = f"  [UNTRUSTED: {why}]" if why else ""
