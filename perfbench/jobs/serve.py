@@ -1,0 +1,324 @@
+"""Job ``serve``: a closed loop of sessions through ``ServingScheduler``.
+
+Set-up (all of it counted in ``setup_s``): seeded bf16 weights made on the
+device; the scheduler built through ``serving.build_serving_engine``; every
+member of the serving program family warmed by requests crafted to hit it;
+three seeded requests streamed through ``submit`` / ``step`` and compared with
+the plain float32 reference's teacher-forced logits; the sessions started and
+run until every session's first request has streamed its first token (the
+prefill wave of a cold start is set-up, not traffic).  Then the timed window.
+
+What the window reports.  ``serve_tokens_per_s`` is every token streamed
+inside it over its length: the one end-to-end metric, because a closed loop
+of more sessions than the system can keep busy is a saturation cell.  The
+tails are per-layer metrics there (``ttft_ms``, ``tpot_ms``, ``queue_ms`` in
+the record), taken over the window's own requests: time to first token over
+the requests SUBMITTED inside the window (one submitted during the ramp
+carries set-up waits and is left out; one still waiting at the close counts
+with the wait it has had), time per output token over every request that
+streamed two or more tokens inside the window, in flight at its close or not.
+"""
+
+import time
+
+import numpy as np
+
+#: The reference comparison, in standard deviations of the reference's logits
+#: at the position: (largest reference logit - reference logit of the engine's
+#: token) / std.  The rule: tolerance = TOL_FACTOR x the worst value measured
+#: on the chip over every seed run there, and never under TOL_FLOOR (the gap
+#: is exactly 0 wherever the engine's token is the reference's argmax, so a
+#: handful of seeds can measure 0).  tests/unit/perfbench applies the same
+#: rule to the error measured on the CPU at tiny size and shows what it
+#: rejects.
+TOL_FACTOR = 3.0
+TOL_FLOOR = 0.02
+#: worst gap over all seeds run on the chip (v5e, PR 23: 0.0151 over 34 runs
+#: of 12 seeds of mistral7b_serve_chat; the engine's token was the
+#: reference's argmax at 99 % of the 96 positions of a run)
+MEASURED_WORST_GAP = 0.0151
+LOGIT_GAP_TOL = max(TOL_FACTOR * MEASURED_WORST_GAP, TOL_FLOOR)
+#: the same at the tests' tiny size on the CPU (worst of 16 runs): 0.0014
+LOGIT_GAP_TOL_CPU_TINY = max(TOL_FACTOR * 0.0014, TOL_FLOOR)
+#: least share of generated positions where the engine's token IS the
+#: reference's argmax
+ARGMAX_SHARE_MIN = 0.8
+
+
+def build_scheduler(ctx, model, params):
+    """The scheduler as the configuration's ``program.serve.engine`` lays it
+    out (block size, token budget, burst, cache blocks, admission cap: the
+    deployment's settings), sized for the longest context of the traffic."""
+    from deepspeed_tpu.serving import build_serving_engine
+    t, eng = ctx.traffic, ctx.config["program"]["serve"]["engine"]
+    block = int(eng["block_size"])
+    longest = t["prompt_len"]["max"] + t["output_len"]["max"]
+    sm = {"max_tracked_sequences": 2 * int(eng["max_concurrent"]),
+          "max_ragged_sequence_count": int(eng["max_concurrent"]) + 1,
+          "max_context": -(-longest // block) * block,
+          "block_size": block,
+          "num_blocks": int(eng["num_blocks"]),
+          "max_ragged_batch_size": int(eng["token_budget"])}
+    if "prefill_atom_size" in eng:
+        sm["prefill_atom_size"] = int(eng["prefill_atom_size"])
+    engine_config = {"dtype": "bfloat16", "state_manager": sm,
+                     "decode_burst": int(eng["decode_burst"])}
+    return build_serving_engine(
+        model, params=params, engine_config=engine_config,
+        serving_config={"max_concurrent": int(eng["max_concurrent"])})
+
+
+def stream(sched, requests):
+    """Submit all, step until idle; the streamed tokens of each."""
+    out = [[] for _ in requests]
+    for i, (prompt, n) in enumerate(requests):
+        sched.submit(prompt, max_new_tokens=n,
+                     on_token=lambda t, done, i=i: out[i].append(t))
+    sched.drain()
+    return out
+
+
+def warm_programs(ctx, sched, vocab):
+    """Hit every member of the program family: the atom-tiled prefill layout
+    (a prompt longer than the budget), the flat layout (a lone decode step
+    with one token left), a decode burst of each power of two up to the cap,
+    and a mixed prefill + decode step."""
+    rng = np.random.default_rng(0)
+    eng = ctx.config["program"]["serve"]["engine"]
+    cap, budget = int(eng["decode_burst"]), int(eng["token_budget"])
+    longest = ctx.traffic["prompt_len"]["max"]
+    prompt = lambda n: rng.integers(0, vocab, size=min(n, longest)).tolist()
+    # 1 + (cap + cap/2 + ... + 2) new tokens: first token by the prefill
+    # step, then a burst of each power of two
+    bursts = 1 + sum(1 << k for k in range(1, max(cap, 1).bit_length()))
+    stream(sched, [(prompt(budget + budget // 6), bursts)])
+    stream(sched, [(prompt(40), 2)])                  # flat layout, k < 2
+    stream(sched, [(prompt(20), 8), (prompt(budget - 60), 3),
+                   (prompt(5), 4)])
+
+
+def logit_gaps(logits_at, params, sizes, prompts, produced):
+    """For each streamed request, against the reference's teacher-forced
+    logits at the generated positions: ``(prompt length, worst gap, positions
+    where the engine's token is the reference's argmax, positions)``.  The gap
+    is (largest reference logit - reference logit of the engine's token) /
+    std of the reference logits at that position."""
+    import jax.numpy as jnp
+    rows = []
+    for prompt, toks in zip(prompts, produced):
+        ids = np.asarray(prompt + toks[:-1], np.int32)
+        at = np.arange(len(prompt) - 1, len(prompt) - 1 + len(toks))
+        logits = logits_at(params, ids, at, sizes)
+        chosen = jnp.take_along_axis(
+            logits, jnp.asarray(toks, jnp.int32)[:, None], axis=-1)[:, 0]
+        gap = (jnp.max(logits, axis=-1) - chosen) / jnp.std(logits, axis=-1)
+        hit = np.asarray(jnp.argmax(logits, axis=-1)) == np.asarray(toks)
+        rows.append((len(prompt), float(np.max(np.asarray(gap, np.float64))),
+                     int(hit.sum()), len(toks)))
+    return rows
+
+
+def reference_check(ctx, sched, sizes):
+    from perfbench.traffic_gen import check_requests
+    new = int(ctx.traffic["check_new_tokens"])
+    prompts = check_requests(ctx.traffic, sizes["vocab_size"], ctx.seed)
+    produced = stream(sched, [(p, new) for p in prompts])
+    for p, toks in zip(prompts, produced):
+        ctx.checks.equal(f"serve.check_tokens_prompt{len(p)}", len(toks), new)
+    rows = logit_gaps(ctx.reference.logits_at, sched.engine.params, sizes,
+                      prompts, produced)
+    for n_prompt, gap, hits, n in rows:
+        ctx.checks.at_most(
+            f"serve.logit_gap_prompt{n_prompt}", gap,
+            LOGIT_GAP_TOL if ctx.on_tpu else LOGIT_GAP_TOL_CPU_TINY,
+            f"argmax at {hits}/{n} positions")
+    ctx.checks.at_most(
+        "serve.argmax_miss_share",
+        1.0 - sum(r[2] for r in rows) / sum(r[3] for r in rows),
+        1.0 - ARGMAX_SHARE_MIN)
+
+
+class Session:
+    __slots__ = ("t_submit", "want", "times", "uid")
+
+
+def run(ctx):
+    import jax
+    from perfbench import weights
+    from perfbench.harness import fold_seed, percentile
+    from perfbench.traffic_gen import RequestStream
+    from deepspeed_tpu.serving import AdmissionQueueFull
+
+    traffic, config = ctx.traffic, ctx.config
+    model, _ = ctx.arch.build(config, "serve")
+    sizes = ctx.arch.reference_sizes(config, "serve")
+    vocab = sizes["vocab_size"]
+
+    t0 = time.perf_counter()
+    params = weights.seeded_weights(ctx.arch.param_shapes(model),
+                                    fold_seed(ctx.seed))
+    jax.block_until_ready(params)
+    t_weights = time.perf_counter() - t0
+    n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
+    sched = build_scheduler(ctx, model, params)
+    del params
+
+    t0 = time.perf_counter()
+    warm_programs(ctx, sched, vocab)
+    t_warm = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    reference_check(ctx, sched, sizes)
+    t_check = time.perf_counter() - t0
+
+    # ---- the sessions
+    requests = RequestStream(traffic, vocab, ctx.seed)
+    n_sessions = int(traffic["sessions"])
+    clock = time.perf_counter
+    free = list(range(n_sessions))
+    live = {}            # session -> Session
+    sent = []            # every Session submitted, in order
+    done = []            # those whose reply is complete
+    refused = 0
+
+    streamed = [0]       # tokens handed to callers, all requests
+
+    def on_token(s):
+        def cb(tok, finished):
+            rec = live[s]
+            rec.times.append(clock())
+            streamed[0] += 1
+            if finished:
+                done.append(rec)
+                del live[s]
+                free.append(s)
+        return cb
+
+    def fill():
+        nonlocal refused
+        while free:
+            s = free.pop()
+            prompt, want = requests.next(s)
+            rec = Session()
+            rec.t_submit, rec.want, rec.times = clock(), want, []
+            live[s] = rec
+            try:
+                rec.uid = sched.submit(
+                    prompt, max_new_tokens=want, on_token=on_token(s))
+                sent.append(rec)
+            except AdmissionQueueFull:
+                refused += 1
+                del live[s]
+
+    t0 = time.perf_counter()
+    fill()
+    first_wave = list(live.values())
+    while any(not r.times for r in first_wave):       # the first prefill wave
+        sched.step()
+        fill()
+    t_ramp = time.perf_counter() - t0
+    ctx.info("setup", params_m=round(n_params / 1e6, 1),
+             depth=sizes["num_hidden_layers"], weights_s=round(t_weights, 2),
+             warm_s=round(t_warm, 2), reference_check_s=round(t_check, 2),
+             ramp_s=round(t_ramp, 2), compiles=ctx.compiles.summary())
+
+    # ---- the timed window
+    spans = ctx.spans
+    mark = ctx.compiles.mark()
+    done_before = len(done)
+    streamed_before = streamed[0]
+    refused_before = refused
+    preempt_before = sched.preemptions
+    step_ms = []
+    trace_s = float(traffic.get("trace_seconds", 3.0))
+    trace_at = min(2.0, ctx.seconds / 4) if ctx.trace else None
+    traced = None
+    raised = 0
+    last_step = None     # (start, end, tokens) of the newest scheduler step
+    t_window = clock()
+    setup_s = t_window - ctx.t_process_start
+    while True:
+        now = clock()
+        if ctx.trace and traced is None and now - t_window >= trace_at:
+            ctx.profiler.start()
+            traced = jax.profiler.TraceAnnotation("pb:traced")
+            traced.__enter__()
+        elif ctx.profiler.on and now - ctx.profiler.t_start >= trace_s:
+            traced.__exit__(None, None, None)
+            ctx.profiler.stop()
+        if now - t_window >= ctx.seconds and not ctx.profiler.on:
+            break
+        try:
+            t_s, n_s = clock(), streamed[0]
+            with spans.span("sched_step"):
+                emitted = sched.step()
+            last_step = (t_s, clock(), streamed[0] - n_s)
+            if emitted:
+                step_ms.append(1e3 * (last_step[1] - t_s))
+        except Exception as e:
+            print(f"sched.step raised {type(e).__name__}: {e}", flush=True)
+            raised += 1
+            if raised > 3:
+                break
+        with spans.span("submit"):
+            fill()
+    window_s = clock() - t_window
+    in_window = ctx.compiles.since(mark)
+
+    finished = done[done_before:]
+    wrong_len = sum(len(r.times) != r.want for r in finished)
+    failed = wrong_len + (refused - refused_before) + raised
+    attempted = len(finished) + (refused - refused_before) + raised
+    tokens = streamed[0] - streamed_before     # all tokens of the window
+    # The window closes at the first step boundary past --seconds, and a step
+    # lasts 0.3-0.7 s here: which boundary that is moves the plain quotient
+    # tokens / window_s by 1 % from run to run (PR 23 measured 182.1, 183.9 and
+    # 185.3 tokens/s for the same schedule).  So the step that straddles the
+    # nominal end counts pro rata, and the rate is over exactly --seconds.
+    rate = tokens / window_s
+    t_nominal = t_window + ctx.seconds
+    if last_step and last_step[0] < t_nominal < last_step[1]:
+        t0_, t1_, n_ = last_step
+        rate = (tokens - n_ * (t1_ - t_nominal) / (t1_ - t0_)) / ctx.seconds
+    t_close = t_window + window_s
+    mine = [r for r in sent if r.t_submit >= t_window]
+    waiting = sum(not r.times for r in mine)
+    ttft = [1e3 * ((r.times[0] if r.times else t_close) - r.t_submit)
+            for r in mine]
+    tpot = []
+    for r in sent:
+        inside = [t for t in r.times if t >= t_window]
+        if len(inside) > 1:
+            tpot.append(1e3 * (inside[-1] - inside[0]) / (len(inside) - 1))
+    queue_ms = []
+    for r in mine:
+        q = sched.query(r.uid)
+        if q is not None and q.t_admit is not None:
+            queue_ms.append(1e3 * (q.t_admit - q.t_submit))
+    ctx.checks.equal("serve.wrong_length_replies", wrong_len, 0)
+    ctx.checks.equal("serve.refused_or_raised", failed - wrong_len, 0)
+    ctx.checks.equal("serve.compilations_in_window", len(in_window), 0,
+                     str(in_window[:3]))
+    e2e = {"serve_tokens_per_s": rate, "setup_s": setup_s}
+    ctx.info("window", window_s=window_s, completed=len(finished),
+             requests_per_s=len(finished) / window_s, tokens=tokens,
+             tokens_of_completed=sum(len(r.times) for r in finished),
+             serve_tokens_per_s=rate, submitted_in_window=len(mine),
+             still_waiting_for_first_token=waiting,
+             ttft_ms_p50=percentile(ttft, 50),
+             ttft_ms_p95=percentile(ttft, 95), n_ttft=len(ttft),
+             tpot_ms_p50=percentile(tpot, 50),
+             tpot_ms_p95=percentile(tpot, 95), n_tpot=len(tpot),
+             queue_ms_p95=percentile(queue_ms, 95),
+             preemptions=sched.preemptions - preempt_before,
+             peak_running=sched.peak_running,
+             slowest_host_calls=spans.slowest(t_window),
+             compilations_in_window=len(in_window), setup_s=setup_s)
+    return {
+        "job": "serve", "attempted": attempted, "failed": failed,
+        "window_s": window_s, "completed": len(finished),
+        "n_chips": len(ctx.devices), "end_to_end": e2e,
+        "ttft_ms": ttft, "tpot_ms": tpot, "queue_ms": queue_ms,
+        "step_ms": step_ms,
+        "preemptions": sched.preemptions - preempt_before,
+        "trace": ctx.profiler.reduce(1) if ctx.trace else None,
+    }

@@ -1,0 +1,251 @@
+"""Lint of ``BENCHMARK.json`` against the contract, and of ``perfbench/``
+against its own rules (parts found by name, public names only, no CPU
+fallback)."""
+
+import json
+import os
+import re
+import subprocess
+import sys
+
+import pytest
+
+import pb_helpers as pb
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+
+
+@pytest.fixture(scope="module")
+def manifest():
+    return json.load(open(os.path.join(pb.ROOT, "BENCHMARK.json")))
+
+
+def _line(s, limit=200):
+    return 1 <= len(s) <= limit and "\n" not in s and "\t" not in s
+
+
+def test_top_level_keys_and_sizes(manifest):
+    assert set(manifest) == {"command", "paths", "run_seconds", "configs",
+                             "workloads", "end_to_end", "per_layer"}
+    assert os.path.getsize(os.path.join(pb.ROOT, "BENCHMARK.json")) < 65536
+    assert manifest["command"] == ["python3", "perfbench/run.py"]
+    assert manifest["paths"] == ["perfbench", "tests/unit/perfbench"]
+    assert isinstance(manifest["run_seconds"], int)
+    assert 1 <= manifest["run_seconds"] <= 51
+    # a full check of 24 cells fits the driver's budget
+    runs = 2 + 14 * 24
+    assert runs * (manifest["run_seconds"] + 60) + 24 * 180 + 1200 <= 43200
+
+
+def test_configs(manifest):
+    assert 1 <= len(manifest["configs"]) <= 24
+    files = set()
+    used = {w["config"] for w in manifest["workloads"]}
+    for c in manifest["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert NAME.match(c["name"]) and c["name"] in used
+        assert _line(c["source"]) and _line(c["why"])
+        assert PATH.match(c["file"]) and c["file"].startswith("perfbench/")
+        assert c["file"] not in files
+        files.add(c["file"])
+        body = json.load(open(os.path.join(pb.ROOT, c["file"])))
+        assert body["source"] == c["source"]
+        assert len(c["reduced"]) <= 16
+        for key in c["reduced"]:
+            assert NAME.match(key) and key in body
+            assert not re.search(r"(_dim|_rank|hidden_size|intermediate_size"
+                                 r"|head|experts_per_tok)$", key), key
+        # published widths, never cut
+        assert body["hidden_size"] == 4096
+        assert body["intermediate_size"] == 14336
+        assert body["num_attention_heads"] == 32
+        assert body["num_key_value_heads"] == 8
+        assert body["vocab_size"] == 32000
+        assert set(body["reduced"]) == set(c["reduced"])
+    assert len({c["name"] for c in manifest["configs"]}) == len(files)
+
+
+def test_workloads_resolve_by_name(manifest):
+    from perfbench import loader
+    cells = manifest["workloads"]
+    assert 1 <= len(cells) <= 24
+    assert len({w["name"] for w in cells}) == len(cells)
+    assert len({(w["config"], w["traffic"]) for w in cells}) == len(cells)
+    four = [w for w in cells if w["chips"] == 4]
+    assert len(four) <= max(1, len(cells) // 4)
+    for w in cells:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert NAME.match(w["name"]) and NAME.match(w["traffic"])
+        assert w["chips"] in (1, 4) and _line(w["why"])
+        assert not w["config"].startswith("tiny")
+        assert not w["traffic"].startswith("tiny")
+        entry = loader.find(manifest["configs"], w["config"], "config")
+        config = loader.load_json(os.path.join(pb.ROOT, entry["file"]))
+        traffic = loader.load_json(loader.part_path(
+            pb.ROOT, "traffic", w["traffic"], "json"))
+        for kind, name in (("jobs", traffic["job"]),
+                           ("models", config["arch"]),
+                           ("reference", config["arch"])):
+            assert os.path.isfile(loader.part_path(pb.ROOT, kind, name, "py"))
+        assert traffic["job"] in config["num_hidden_layers"]
+
+
+def test_metrics(manifest):
+    from perfbench import loader
+    cells = [w["name"] for w in manifest["workloads"]]
+    e2e = manifest["end_to_end"]
+    assert 1 <= len(e2e) <= 16 and 1 <= len(manifest["per_layer"]) <= 128
+    names = [m["name"] for m in e2e + manifest["per_layer"]]
+    assert len(set(names)) == len(names)
+    setup = loader.find(e2e, "setup_s", "metric")
+    assert "workloads" not in setup and setup["bound"] <= 0.1
+    for m in e2e:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "bound",
+                                          "source"}
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.1
+    for m in manifest["per_layer"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "source",
+                                          "layer", "moves"}
+        assert _line(m["layer"])
+        assert callable(loader.load_reader(pb.ROOT, m["name"]).read)
+        moved = loader.find(e2e, m["moves"], "end-to-end metric")
+        for cell in m.get("workloads", cells):
+            assert cell in moved.get("workloads", cells), (m["name"], cell)
+    for m in e2e + manifest["per_layer"]:
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"])
+        assert m["better"] in ("lower", "higher")
+        assert m["source"] in SOURCES
+        assert set(m.get("workloads", cells)) <= set(cells)
+    for cell in cells:
+        mine = loader.metrics_of_cell(manifest, "end_to_end", cell)
+        assert len(mine) >= 2 and "setup_s" in [m["name"] for m in mine]
+        assert loader.metrics_of_cell(manifest, "per_layer", cell)
+
+
+def test_one_reader_serves_a_quantity_split_by_what_it_moves(manifest):
+    from perfbench import loader
+    split = [m["name"] for m in manifest["per_layer"] if "." in m["name"]]
+    assert {"device_idle_share.train", "device_idle_share.serve"} <= \
+        set(split)
+    for name in split:
+        reader = loader.load_reader(pb.ROOT, name)
+        assert reader.__file__.endswith(name.split(".")[0] + ".py")
+    files = {f[:-3] for f in os.listdir(
+        os.path.join(pb.ROOT, "perfbench", "layer_metrics"))
+        if f.endswith(".py")}
+    # every reader is used, and none is a copy of another
+    assert files == {m["name"].split(".")[0] if m["name"] not in files
+                     else m["name"] for m in manifest["per_layer"]}
+    with pytest.raises(FileNotFoundError):
+        loader.load_reader(pb.ROOT, "no_such_metric.train")
+
+
+def test_saturated_serving_cells_are_judged_on_throughput_only(manifest):
+    """Tails of a closed loop above capacity swing with the smallest change:
+    they are per-layer metrics, with no bound."""
+    e2e = {m["name"] for m in manifest["end_to_end"]}
+    per_layer = {m["name"] for m in manifest["per_layer"]}
+    assert "serve_tokens_per_s" in e2e
+    assert {"serve_ttft_ms_p95", "serve_tpot_ms_p95"} <= per_layer - e2e
+    cell = [w for w in manifest["workloads"]
+            if w["name"] == "mistral7b_serve_chat"][0]
+    assert "saturation" in cell["why"]
+
+
+def test_engine_layout_is_the_configurations_not_the_traffics(manifest):
+    from perfbench import loader
+    knobs = {"block_size", "token_budget", "decode_burst", "num_blocks",
+             "prefill_atom_size", "max_concurrent"}
+    traffic_dir = os.path.join(pb.ROOT, "perfbench", "traffic")
+    for f in os.listdir(traffic_dir):
+        assert not knobs & set(loader.load_json(os.path.join(traffic_dir, f)))
+    for c in manifest["configs"]:
+        serve = loader.load_json(os.path.join(pb.ROOT, c["file"]))[
+            "program"].get("serve")
+        if serve:
+            assert knobs - {"prefill_atom_size"} <= set(serve["engine"])
+
+
+def test_files_under_paths_are_named_from_name_characters(manifest):
+    for base in manifest["paths"]:
+        for dirpath, dirs, files in os.walk(os.path.join(pb.ROOT, base)):
+            dirs[:] = [d for d in dirs if d != "__pycache__"]
+            for f in files:
+                rel = os.path.relpath(os.path.join(dirpath, f), pb.ROOT)
+                assert PATH.match(rel), rel
+
+
+def test_benchmark_uses_only_public_names_of_the_program():
+    import ast
+    own = {"self"}                     # the benchmark's own objects
+    for dirpath, dirs, files in os.walk(os.path.join(pb.ROOT, "perfbench")):
+        dirs[:] = [d for d in dirs if d != "__pycache__"]
+        for f in files:
+            if not f.endswith(".py"):
+                continue
+            src = open(os.path.join(dirpath, f)).read()
+            assert not re.search(r"^\s*(from|import) deepspeed_tpu\S*\._",
+                                 src, re.M)
+            assert not re.search(r"from deepspeed_tpu\S* import _", src)
+            for node in ast.walk(ast.parse(src)):
+                if isinstance(node, ast.Attribute) and \
+                        node.attr.startswith("_") and \
+                        not node.attr.startswith("__"):
+                    base = node.value
+                    while isinstance(base, (ast.Attribute, ast.Subscript,
+                                            ast.Call)):
+                        base = getattr(base, "value", None) or base.func
+                    assert isinstance(base, ast.Name) and base.id in own, \
+                        (f, node.lineno, node.attr)
+    # the references import nothing of the program
+    for name in os.listdir(os.path.join(pb.ROOT, "perfbench", "reference")):
+        src = open(os.path.join(pb.ROOT, "perfbench", "reference",
+                                name)).read()
+        assert "import deepspeed_tpu" not in src
+        assert "from deepspeed_tpu" not in src
+
+
+def test_command_refuses_a_cpu_and_prints_no_result():
+    r = subprocess.run(
+        [sys.executable, os.path.join(pb.ROOT, "perfbench", "run.py"),
+         "--workload", "mistral7b_train_4k", "--seed", "1", "--seconds", "1",
+         "--trace", "0"],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=pb.ROOT,
+        capture_output=True, text=True, timeout=300)
+    assert r.returncode != 0
+    assert "platform 'cpu'" in r.stderr
+    assert '"correct"' not in r.stdout
+
+
+def test_peaks_table_has_its_source():
+    peaks = json.load(open(os.path.join(pb.ROOT, "perfbench", "peaks.json")))
+    assert "cloud.google.com" in peaks["_source"]
+    v5e = peaks["TPU v5 lite"]
+    assert v5e["bf16_flops_per_s"] == 197e12
+    assert v5e["hbm_bytes_per_s"] == 819e9
+    assert v5e["ici_bits_per_s"] == 1600e9
+
+
+def test_flops_per_token_counts_the_head_not_the_embedding():
+    from perfbench import flops
+    cfg = dict(hidden_size=4096, intermediate_size=14336,
+               num_attention_heads=32, num_key_value_heads=8,
+               vocab_size=32000, sliding_window=4096)
+    layer = 4096 * 4096 * 2 + 2 * 4096 * 1024 + 3 * 4096 * 14336
+    attn = lambda s, w=4096: 2 * 2 * 32 * 128 * flops.mean_keys(s, w)
+    fwd = flops.forward_flops_per_token(cfg, 2, 4096)
+    assert fwd == 2 * (2 * layer + 4096 * 32000) + 2 * attn(4096)
+    assert flops.train_flops_per_token(cfg, 2, 4096) == 3 * fwd
+    assert flops.mean_keys(4096, 4096) == 4097 / 2        # causal half
+    assert flops.mean_keys(8192, 4096) == (4096 * 4097 / 2
+                                           + 4096 * 4096) / 8192
+    assert 0.21 < flops.lm_head_share(cfg, 2, 4096) < 0.23
+    moe = dict(cfg, num_local_experts=8, num_experts_per_tok=2,
+               sliding_window=None)
+    per_layer, _ = flops.matmul_params_per_token(moe, 1)
+    assert per_layer == (4096 * 4096 * 2 + 2 * 4096 * 1024
+                         + 2 * 3 * 4096 * 14336 + 4096 * 8)
