@@ -20,6 +20,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ... import telemetry as _telemetry
+from ...telemetry import names as _names
 from ...utils.logging import logger
 from .config_v2 import RaggedInferenceEngineConfig
 from .kv_codec import resolve_kv_dtype
@@ -87,6 +89,9 @@ class InferenceEngineV2:
                 from ..quant_serving import dequantize_tree
                 return base_step(dequantize_tree(params, meta, dt), *a,
                                  **kw)
+
+            dq_step.__name__ = dq_step.__qualname__ = \
+                _names.PROGRAM_RAGGED_STEP + "dequant"
 
             # jit the wrapper with the SAME statics AND the kv-cache
             # donation as the registered step (the inner jit's donation is
@@ -167,6 +172,10 @@ class InferenceEngineV2:
             dtype=jnp.dtype(config.dtype), kv_dtype=self._kv_dtype)
         self.state_manager = DSStateManager(sm, self.kv_cache)
         self._budget = int(sm.max_ragged_batch_size)
+        #: what the newest engine step held (``schedule_step`` or a decode
+        #: burst): the counts of ``names.SERVE_STEP_COUNTS`` that the batch
+        #: builder knows; the scheduler's ``ds:serve.step`` span carries them
+        self.last_step_counts = None
         # the device-side cache the step functions thread: a plain array
         # (fp path) or the (data, scales) pytree (quantized path)
         self._kv = self.kv_cache.data if self._kv_dtype is None \
@@ -283,6 +292,7 @@ class InferenceEngineV2:
         slots = np.zeros(T, np.int32)  # slot 0 → garbage block
         finishing = []  # (seq, buffer index of its last scheduled token)
         placed = 0
+        placed_decode = 0   # of placed: tokens of sequences in pure decode
         deferred = 0        # sequences the KV pool could not grow this step
         deferred_want = 0   # blocks those sequences needed and couldn't get
 
@@ -340,6 +350,8 @@ class InferenceEngineV2:
                 finishing.append((seq, start + take - 1))
             seq.seen_tokens += take
             placed += take
+            if len(pending) == 1:
+                placed_decode += 1
             if atom:
                 if start < decode_cap:   # landed in the decode region
                     d_cur += 1
@@ -364,7 +376,36 @@ class InferenceEngineV2:
         last_idx = np.zeros(sm.max_seqs, dtype=np.int32)
         for seq, idx in finishing:
             last_idx[seq.slot] = idx
+        grid_pages, live_pages = self._page_counts(pos, slots != 0, layout)
+        self.last_step_counts = {
+            "kind": _names.KIND_RAGGED, "token_budget": T,
+            "live_tokens": placed, "decode_tokens": placed_decode,
+            "prefill_tokens": placed - placed_decode,
+            "grid_pages": grid_pages, "live_pages": live_pages,
+            "burst_k": 0}
         return toks, pos, slots, last_idx, finishing, layout
+
+    def _page_counts(self, pos, live, layout=(0, 0)):
+        """``(grid_pages, live_pages)`` of one paged-attention call over the
+        rows at positions ``pos`` (``live``: which rows hold a token).
+        ``grid_pages``: the (row, page) steps the kernel's grid visits —
+        every row (every atom, in a prefill region) times every page of the
+        block table's width, whatever the rows hold.  ``live_pages``: of
+        those, the pages a live row's context really spans (its sliding
+        window's, where the model has one).  Their ratio is the share of the
+        kernel's grid that is useful work."""
+        bs = self.kv_cache.block_size
+        maxb = self.state_manager.block_table.shape[1]
+        window = int(getattr(self.model_config, "sliding_window", 0) or 0)
+        first = np.maximum(pos - window + 1, 0) // bs if window else 0
+        pages = np.where(live, pos // bs + 1 - first, 0)
+        decode_cap, atom = layout
+        if atom:
+            # one grid row an atom: it streams the pages of its deepest row
+            tiles = pages[decode_cap:].reshape(-1, atom).max(axis=1)
+            return ((decode_cap + len(tiles)) * maxb,
+                    int(pages[:decode_cap].sum() + tiles.sum()))
+        return pages.size * maxb, int(pages.sum())
 
     @staticmethod
     def _sample_row(row, temperature, top_k, top_p, rng):
@@ -405,35 +446,41 @@ class InferenceEngineV2:
                 # create once per distinct seed; advances across tokens/steps
                 self._rng = np.random.default_rng(rng)
                 self._rng_seed = rng
-        batch = self._build_batch()
+        self.last_step_counts = None
+        with _telemetry.scope(_names.SERVE_BUILD_BATCH):
+            batch = self._build_batch()
         if batch is None:
             return {}
         toks, pos, slots, last_idx, finishing, layout = batch
-        step_args = (self.params, self._kv, jnp.asarray(toks),
-                     jnp.asarray(pos), jnp.asarray(slots),
-                     jnp.asarray(self.state_manager.block_table),
-                     jnp.asarray(last_idx))
-        step_kw = dict(cfg=self.model_config,
-                       block_size=self.kv_cache.block_size, layout=layout,
-                       use_kernel=self._tp == 1, kv_dtype=self._kv_dtype)
-        from ...profiling import cost_model
-        if cost_model.capturing():
-            # compiled-cost capture of the serving prefill/decode program
-            # (one analysis compile per distinct layout, only while
-            # capture is armed — docs/observability.md "MFU & HBM");
-            # layout (0,0) is the flat/decode-heavy program, (d,a) the
-            # atom-tiled prefill one
-            cost_model.capture_jit_call(
-                f"serve/ragged_step[{layout[0]}x{layout[1]}]",
-                self._step_fn, step_args, step_kw,
-                meta={"layout": list(layout)})
-        logits, self._kv = self._step_fn(*step_args, **step_kw)
+        with _telemetry.scope(_names.SERVE_LAUNCH):
+            step_args = (self.params, self._kv, jnp.asarray(toks),
+                         jnp.asarray(pos), jnp.asarray(slots),
+                         jnp.asarray(self.state_manager.block_table),
+                         jnp.asarray(last_idx))
+            step_kw = dict(cfg=self.model_config,
+                           block_size=self.kv_cache.block_size,
+                           layout=layout, use_kernel=self._tp == 1,
+                           kv_dtype=self._kv_dtype)
+            from ...profiling import cost_model
+            if cost_model.capturing():
+                # compiled-cost capture of the serving prefill/decode
+                # program (one analysis compile per distinct layout, only
+                # while capture is armed — docs/observability.md "MFU &
+                # HBM"); layout (0,0) is the flat/decode-heavy program,
+                # (d,a) the atom-tiled prefill one
+                cost_model.capture_jit_call(
+                    f"serve/ragged_step[{layout[0]}x{layout[1]}]",
+                    self._step_fn, step_args, step_kw,
+                    meta={"layout": list(layout)})
+            logits, self._kv = self._step_fn(*step_args, **step_kw)
         out = {}
         if finishing:
+            # the fetch is the one place the host waits for the device
             if do_sample:
                 # fetch ONLY the finishing rows ([F, V]), not every slot
                 slots_f = jnp.asarray([seq.slot for seq, _ in finishing])
-                lg = np.asarray(logits[slots_f])
+                with _telemetry.scope(_names.SERVE_FETCH):
+                    lg = np.asarray(logits[slots_f])
                 for i, (seq, _) in enumerate(finishing):
                     out[seq.uid] = self._sample_row(
                         lg[i], temperature, top_k, top_p, self._rng)
@@ -441,7 +488,8 @@ class InferenceEngineV2:
                 # greedy: argmax on device, fetch one int per slot instead
                 # of [max_seqs, V] logits (the per-step device→host tax on
                 # a decode loop)
-                toks = np.asarray(jnp.argmax(logits, axis=-1))
+                with _telemetry.scope(_names.SERVE_FETCH):
+                    toks = np.asarray(jnp.argmax(logits, axis=-1))
                 for seq, _ in finishing:
                     out[seq.uid] = int(toks[seq.slot])
         return out
@@ -530,14 +578,24 @@ class InferenceEngineV2:
         # remaining-token count — pow2 bounds the variants to log2(cap)
         k = 1 << (k.bit_length() - 1)
         n = sm.max_seqs
-        tok0 = np.zeros(n, np.int32)
-        pos0 = np.zeros(n, np.int32)
-        act = np.zeros(n, bool)
-        for seq in seqs:
-            sm.ensure_capacity(seq, seq.seen_tokens + k)
-            tok0[seq.slot] = seq.tokens[seq.seen_tokens]
-            pos0[seq.slot] = seq.seen_tokens
-            act[seq.slot] = True
+        with _telemetry.scope(_names.SERVE_BUILD_BATCH):
+            tok0 = np.zeros(n, np.int32)
+            pos0 = np.zeros(n, np.int32)
+            act = np.zeros(n, bool)
+            for seq in seqs:
+                sm.ensure_capacity(seq, seq.seen_tokens + k)
+                tok0[seq.slot] = seq.tokens[seq.seen_tokens]
+                pos0[seq.slot] = seq.seen_tokens
+                act[seq.slot] = True
+            # k iterations over max_seqs rows each, one token a live row
+            grid_pages, live_pages = self._page_counts(
+                pos0[None, :] + np.arange(k)[:, None], act[None, :])
+            self.last_step_counts = {
+                "kind": _names.KIND_BURST, "token_budget": n * k,
+                "live_tokens": len(seqs) * k,
+                "decode_tokens": len(seqs) * k, "prefill_tokens": 0,
+                "grid_pages": grid_pages, "live_pages": live_pages,
+                "burst_k": k}
         from .ragged_forward import decode_burst
         if sample:
             if getattr(self, "_burst_key", None) is None or \
@@ -547,23 +605,26 @@ class InferenceEngineV2:
             self._burst_key, key = jax.random.split(self._burst_key)
         else:
             key = None
-        burst_args = (self.params, self._kv, jnp.asarray(tok0),
-                      jnp.asarray(pos0), jnp.asarray(act),
-                      jnp.asarray(sm.block_table))
-        burst_kw = dict(step_fn=self._step_fn, cfg=self.model_config,
-                        block_size=self.kv_cache.block_size, k=k,
-                        use_kernel=self._tp == 1, sample=sample, key=key,
-                        temperature=float(temperature), top_k=int(top_k),
-                        top_p=float(top_p), kv_dtype=self._kv_dtype)
-        from ...profiling import cost_model
-        if cost_model.capturing():
-            # k is static (pow2-quantized above), so the burst variants are
-            # a bounded program family worth tabulating per k
-            cost_model.capture_jit_call(
-                f"serve/decode_burst[k={k}]", decode_burst, burst_args,
-                burst_kw, meta={"k": int(k)})
-        toks_out, self._kv = decode_burst(*burst_args, **burst_kw)
-        toks_out = np.asarray(toks_out)      # ONE fetch for k×seqs tokens
+        with _telemetry.scope(_names.SERVE_LAUNCH):
+            burst_args = (self.params, self._kv, jnp.asarray(tok0),
+                          jnp.asarray(pos0), jnp.asarray(act),
+                          jnp.asarray(sm.block_table))
+            burst_kw = dict(step_fn=self._step_fn, cfg=self.model_config,
+                            block_size=self.kv_cache.block_size, k=k,
+                            use_kernel=self._tp == 1, sample=sample,
+                            key=key, temperature=float(temperature),
+                            top_k=int(top_k), top_p=float(top_p),
+                            kv_dtype=self._kv_dtype)
+            from ...profiling import cost_model
+            if cost_model.capturing():
+                # k is static (pow2-quantized above), so the burst variants
+                # are a bounded program family worth tabulating per k
+                cost_model.capture_jit_call(
+                    f"serve/decode_burst[k={k}]", decode_burst, burst_args,
+                    burst_kw, meta={"k": int(k)})
+            toks_out, self._kv = decode_burst(*burst_args, **burst_kw)
+        with _telemetry.scope(_names.SERVE_FETCH):
+            toks_out = np.asarray(toks_out)  # ONE fetch for k×seqs tokens
         self.burst_steps = getattr(self, "burst_steps", 0) + 1
         out = {}
         for seq in seqs:

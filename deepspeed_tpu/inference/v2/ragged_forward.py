@@ -17,12 +17,28 @@ prefill chunk is causal within itself and sees all earlier chunks, and a
 decode token sees the whole prefix.  Exactly FastGen's ragged semantics.
 """
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
 from ...models.llama import _rope_freqs
+from ...telemetry import names as _names
+
+
+def _program(name, **jit_kwargs):
+    """``jax.jit`` the decorated function as the program ``name``: jit names
+    the compiled module after ``__name__``, which is what a profiler's
+    module line shows (telemetry/names.py)."""
+    def wrap(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return jax.jit(fn, **jit_kwargs)
+    return wrap
+
+
+def _ragged_program(arch):
+    return _program(_names.PROGRAM_RAGGED_STEP + arch,
+                    static_argnames=("cfg", "block_size", "layout",
+                                     "use_kernel", "kv_dtype"),
+                    donate_argnums=(1, ))
 
 
 def _rotary(x, cos, sin, positions):
@@ -34,6 +50,7 @@ def _rotary(x, cos, sin, positions):
                            axis=-1).astype(x.dtype)
 
 
+@jax.named_scope(_names.SCOPE_NORM)
 def _rmsnorm(x, w, eps):
     x32 = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
@@ -129,6 +146,7 @@ def _attn_cfg_view(cfg, sliding_window=0):
         sliding_window=sliding_window, dtype=cfg.dtype)
 
 
+@jax.named_scope(_names.SCOPE_LM_HEAD)
 def _head_logits(params, x, last_token_idx, embed_key="embed_tokens"):
     """logits_gather epilogue shared by the zoo steps: gather each slot's
     last token, tied-embedding or lm_head projection (with optional bias)."""
@@ -144,6 +162,7 @@ def _head_logits(params, x, last_token_idx, embed_key="embed_tokens"):
     return logits
 
 
+@jax.named_scope(_names.SCOPE_KV_CACHE)
 def _kv_layer(kv_data, l):
     """Layer ``l`` view of the cache pytree: an array slice on the fp path,
     a ``(data_l, scales_l)`` pair on the quantized path."""
@@ -153,6 +172,7 @@ def _kv_layer(kv_data, l):
     return kv_data[l]
 
 
+@jax.named_scope(_names.SCOPE_KV_CACHE)
 def _kv_set(kv_data, l, kv_layer):
     """Write layer ``l`` back into the cache pytree (inverse of
     :func:`_kv_layer`)."""
@@ -163,6 +183,7 @@ def _kv_set(kv_data, l, kv_layer):
     return kv_data.at[l].set(kv_layer)
 
 
+@jax.named_scope(_names.SCOPE_ATTENTION)
 def _ragged_attention_block(lp_attn, h, kv_layer, blk, off, tables_t,
                             positions, cos, sin, *, cfg, block_size,
                             rotary=True, rotary_dim=None,
@@ -218,9 +239,7 @@ def _ragged_attention_block(lp_attn, h, kv_layer, blk, off, tables_t,
     return o, kv_layer
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "block_size", "layout",
-                                             "use_kernel", "kv_dtype"),
-                   donate_argnums=(1, ))
+@_ragged_program("llama")
 def llama_ragged_step(params, kv_data, token_ids, positions, seq_slots,
                       block_tables, last_token_idx, *, cfg, block_size,
                       layout=(0, 0), use_kernel=True, kv_dtype=None):
@@ -246,7 +265,8 @@ def llama_ragged_step(params, kv_data, token_ids, positions, seq_slots,
     cos = jnp.asarray(cos, jnp.float32)
     sin = jnp.asarray(sin, jnp.float32)
 
-    x = params["embed_tokens"]["embedding"][token_ids].astype(dtype)  # [T, D]
+    with jax.named_scope(_names.SCOPE_EMBED):
+        x = params["embed_tokens"]["embedding"][token_ids].astype(dtype)
     tables_t = block_tables[seq_slots]                       # [T, maxb]
     blk = tables_t[jnp.arange(token_ids.shape[0]),
                    positions // block_size]                  # [T]
@@ -265,14 +285,16 @@ def llama_ragged_step(params, kv_data, token_ids, positions, seq_slots,
         kv_data = _kv_set(kv_data, l, kv_layer)
         x = x + attn_out
         h2 = _rmsnorm(x, lp["post_attention_layernorm"]["weight"], eps)
-        gate = h2 @ mlp["gate_proj"]["kernel"].astype(dtype)
-        up = h2 @ mlp["up_proj"]["kernel"].astype(dtype)
-        x = x + (jax.nn.silu(gate) * up) @ mlp["down_proj"]["kernel"].astype(
-            dtype)
+        with jax.named_scope(_names.SCOPE_MLP):
+            gate = h2 @ mlp["gate_proj"]["kernel"].astype(dtype)
+            up = h2 @ mlp["up_proj"]["kernel"].astype(dtype)
+            x = x + (jax.nn.silu(gate) * up) @ mlp["down_proj"][
+                "kernel"].astype(dtype)
 
     return _lm_head(params, x, last_token_idx, cfg), kv_data
 
 
+@jax.named_scope(_names.SCOPE_LM_HEAD)
 def _lm_head(params, x, last_token_idx, cfg):
     """logits_gather analog: only each slot's last token reaches the head."""
     eps = cfg.rms_norm_eps
@@ -283,9 +305,7 @@ def _lm_head(params, x, last_token_idx, cfg):
     return xl @ params["lm_head"]["kernel"].astype(jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "block_size", "layout",
-                                             "use_kernel", "kv_dtype"),
-                   donate_argnums=(1, ))
+@_ragged_program("mixtral")
 def mixtral_ragged_step(params, kv_data, token_ids, positions, seq_slots,
                         block_tables, last_token_idx, *, cfg, block_size,
                       layout=(0, 0), use_kernel=True, kv_dtype=None):
@@ -302,7 +322,8 @@ def mixtral_ragged_step(params, kv_data, token_ids, positions, seq_slots,
     cos = jnp.asarray(cos, jnp.float32)
     sin = jnp.asarray(sin, jnp.float32)
 
-    x = params["embed_tokens"]["embedding"][token_ids].astype(dtype)
+    with jax.named_scope(_names.SCOPE_EMBED):
+        x = params["embed_tokens"]["embedding"][token_ids].astype(dtype)
     tables_t = block_tables[seq_slots]
     blk = tables_t[jnp.arange(token_ids.shape[0]),
                    positions // block_size]
@@ -318,28 +339,31 @@ def mixtral_ragged_step(params, kv_data, token_ids, positions, seq_slots,
         kv_data = _kv_set(kv_data, l, kv_layer)
         x = x + attn_out
         h2 = _rmsnorm(x, lp["post_attention_layernorm"]["weight"], eps)
-        moe = lp["moe"]
-        router_logits = (h2.astype(jnp.float32)
-                         @ moe["gate"]["kernel"].astype(jnp.float32))
-        moe_out = moe_apply(h2, router_logits,
-                            moe["w1"].astype(dtype), moe["w2"].astype(dtype),
-                            moe["w3"].astype(dtype), cfg.num_experts_per_tok,
-                            norm_topk=getattr(cfg, "norm_topk_prob", True))
-        if "shared_gate_proj" in moe:  # qwen2-moe dense shared expert
-            g = h2 @ moe["shared_gate_proj"]["kernel"].astype(dtype)
-            u = h2 @ moe["shared_up_proj"]["kernel"].astype(dtype)
-            sh = (jax.nn.silu(g) * u) @ moe["shared_down_proj"][
-                "kernel"].astype(dtype)
-            mix = jax.nn.sigmoid(
-                h2.astype(jnp.float32)
-                @ moe["shared_expert_gate"]["kernel"].astype(jnp.float32))
-            moe_out = moe_out + (mix * sh.astype(jnp.float32)).astype(
-                moe_out.dtype)
+        with jax.named_scope(_names.SCOPE_MLP):
+            moe = lp["moe"]
+            router_logits = (h2.astype(jnp.float32)
+                             @ moe["gate"]["kernel"].astype(jnp.float32))
+            moe_out = moe_apply(
+                h2, router_logits, moe["w1"].astype(dtype),
+                moe["w2"].astype(dtype), moe["w3"].astype(dtype),
+                cfg.num_experts_per_tok,
+                norm_topk=getattr(cfg, "norm_topk_prob", True))
+            if "shared_gate_proj" in moe:  # qwen2-moe dense shared expert
+                g = h2 @ moe["shared_gate_proj"]["kernel"].astype(dtype)
+                u = h2 @ moe["shared_up_proj"]["kernel"].astype(dtype)
+                sh = (jax.nn.silu(g) * u) @ moe["shared_down_proj"][
+                    "kernel"].astype(dtype)
+                mix = jax.nn.sigmoid(
+                    h2.astype(jnp.float32)
+                    @ moe["shared_expert_gate"]["kernel"].astype(jnp.float32))
+                moe_out = moe_out + (mix * sh.astype(jnp.float32)).astype(
+                    moe_out.dtype)
         x = x + moe_out
 
     return _lm_head(params, x, last_token_idx, cfg), kv_data
 
 
+@jax.named_scope(_names.SCOPE_NORM)
 def _layernorm(x, p, eps):
     x32 = x.astype(jnp.float32)
     mu = jnp.mean(x32, axis=-1, keepdims=True)
@@ -348,9 +372,7 @@ def _layernorm(x, p, eps):
             + p["bias"]).astype(x.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "block_size", "layout",
-                                             "use_kernel", "kv_dtype"),
-                   donate_argnums=(1, ))
+@_ragged_program("falcon")
 def falcon_ragged_step(params, kv_data, token_ids, positions, seq_slots,
                        block_tables, last_token_idx, *, cfg, block_size,
                       layout=(0, 0), use_kernel=True, kv_dtype=None):
@@ -365,7 +387,8 @@ def falcon_ragged_step(params, kv_data, token_ids, positions, seq_slots,
     cos = jnp.asarray(cos, jnp.float32)
     sin = jnp.asarray(sin, jnp.float32)
 
-    x = params["word_embeddings"]["embedding"][token_ids].astype(dtype)
+    with jax.named_scope(_names.SCOPE_EMBED):
+        x = params["word_embeddings"]["embedding"][token_ids].astype(dtype)
     tables_t = block_tables[seq_slots]
     blk = tables_t[jnp.arange(token_ids.shape[0]), positions // block_size]
     off = positions % block_size
@@ -389,8 +412,9 @@ def falcon_ragged_step(params, kv_data, token_ids, positions, seq_slots,
         if not cfg.parallel_attn:
             x = x + attn_out
             h_mlp = _layernorm(x, lp["post_attention_layernorm"], eps)
-        mlp = _lin(jax.nn.gelu(_lin(h_mlp, lp["dense_h_to_4h"], dtype)),
-                   lp["dense_4h_to_h"], dtype)
+        with jax.named_scope(_names.SCOPE_MLP):
+            mlp = _lin(jax.nn.gelu(_lin(h_mlp, lp["dense_h_to_4h"], dtype)),
+                       lp["dense_4h_to_h"], dtype)
         x = (x + attn_out + mlp) if cfg.parallel_attn else (x + mlp)
 
     x = _layernorm(x, params["ln_f"], eps)
@@ -398,9 +422,7 @@ def falcon_ragged_step(params, kv_data, token_ids, positions, seq_slots,
                         embed_key="word_embeddings"), kv_data
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "block_size", "layout",
-                                             "use_kernel", "kv_dtype"),
-                   donate_argnums=(1, ))
+@_ragged_program("opt")
 def opt_ragged_step(params, kv_data, token_ids, positions, seq_slots,
                     block_tables, last_token_idx, *, cfg, block_size,
                       layout=(0, 0), use_kernel=True, kv_dtype=None):
@@ -412,9 +434,10 @@ def opt_ragged_step(params, kv_data, token_ids, positions, seq_slots,
     dtype = jnp.dtype(cfg.dtype)
     eps = cfg.layer_norm_eps
 
-    x = (params["embed_tokens"]["embedding"][token_ids]
-         + params["embed_positions"]["embedding"][
-             positions + OPT_POSITION_OFFSET]).astype(dtype)
+    with jax.named_scope(_names.SCOPE_EMBED):
+        x = (params["embed_tokens"]["embedding"][token_ids]
+             + params["embed_positions"]["embedding"][
+                 positions + OPT_POSITION_OFFSET]).astype(dtype)
     tables_t = block_tables[seq_slots]
     blk = tables_t[jnp.arange(token_ids.shape[0]), positions // block_size]
     off = positions % block_size
@@ -436,8 +459,9 @@ def opt_ragged_step(params, kv_data, token_ids, positions, seq_slots,
             x = _layernorm(x, lp["self_attn_layer_norm"], eps)
         h = _layernorm(x, lp["final_layer_norm"], eps) \
             if cfg.do_layer_norm_before else x
-        x = x + _lin(jax.nn.relu(_lin(h, lp["fc1"], dtype)), lp["fc2"],
-                     dtype)
+        with jax.named_scope(_names.SCOPE_MLP):
+            x = x + _lin(jax.nn.relu(_lin(h, lp["fc1"], dtype)), lp["fc2"],
+                         dtype)
         if not cfg.do_layer_norm_before:
             x = _layernorm(x, lp["final_layer_norm"], eps)
 
@@ -446,9 +470,7 @@ def opt_ragged_step(params, kv_data, token_ids, positions, seq_slots,
     return _head_logits(params, x, last_token_idx), kv_data
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "block_size", "layout",
-                                             "use_kernel", "kv_dtype"),
-                   donate_argnums=(1, ))
+@_ragged_program("phi")
 def phi_ragged_step(params, kv_data, token_ids, positions, seq_slots,
                     block_tables, last_token_idx, *, cfg, block_size,
                       layout=(0, 0), use_kernel=True, kv_dtype=None):
@@ -462,7 +484,8 @@ def phi_ragged_step(params, kv_data, token_ids, positions, seq_slots,
     cos = jnp.asarray(cos, jnp.float32)
     sin = jnp.asarray(sin, jnp.float32)
 
-    x = params["embed_tokens"]["embedding"][token_ids].astype(dtype)
+    with jax.named_scope(_names.SCOPE_EMBED):
+        x = params["embed_tokens"]["embedding"][token_ids].astype(dtype)
     tables_t = block_tables[seq_slots]
     blk = tables_t[jnp.arange(token_ids.shape[0]), positions // block_size]
     off = positions % block_size
@@ -478,7 +501,9 @@ def phi_ragged_step(params, kv_data, token_ids, positions, seq_slots,
             cos, sin, cfg=acfg, block_size=block_size, rotary_dim=rd,
             layout=layout, use_kernel=use_kernel, kv_dtype=kv_dtype)
         kv_data = _kv_set(kv_data, l, kv_layer)
-        mlp = _lin(jax.nn.gelu(_lin(h, lp["fc1"], dtype)), lp["fc2"], dtype)
+        with jax.named_scope(_names.SCOPE_MLP):
+            mlp = _lin(jax.nn.gelu(_lin(h, lp["fc1"], dtype)), lp["fc2"],
+                       dtype)
         x = x + attn_out + mlp
 
     x = _layernorm(x, params["final_layernorm"], eps)
@@ -512,8 +537,8 @@ def _device_sample(logits, key, temperature, top_k, top_p):
     return jax.random.categorical(key, logits, axis=-1).astype(jnp.int32)
 
 
-@functools.partial(
-    jax.jit,
+@_program(
+    _names.PROGRAM_DECODE_BURST,
     static_argnames=("step_fn", "cfg", "block_size", "k", "use_kernel",
                      "sample", "top_k", "kv_dtype"),
     donate_argnums=(1, ))

@@ -30,6 +30,8 @@ import jax.numpy as jnp
 import flax.linen as nn
 from jax.sharding import PartitionSpec as P
 
+from ..telemetry import names as _names
+
 
 @dataclass(frozen=True)
 class LlamaConfig:
@@ -329,7 +331,8 @@ class LlamaModel(nn.Module):
         embed = nn.Embed(cfg.vocab_size, cfg.hidden_size,
                          param_dtype=jnp.float32, dtype=dtype,
                          name="embed_tokens")
-        x = embed(input_ids)
+        with jax.named_scope(_names.SCOPE_EMBED):
+            x = embed(input_ids)
 
         block = LlamaBlock
         if cfg.remat and not decode:
@@ -339,32 +342,36 @@ class LlamaModel(nn.Module):
             x = block(cfg, name=f"layers_{i}")(x, attention_mask, decode)
 
         x = RMSNorm(cfg.rms_norm_eps, dtype, name="norm")(x)
-        hd = jnp.dtype(cfg.head_dtype)
-        if cfg.loss_chunk_vocab and labels is not None and not decode:
-            # fused chunked head+loss: pull the head kernel and skip the
-            # monolithic [B, S, V] logits entirely
+        # the head and the loss under one scope, on both loss paths: a
+        # device trace then says which ops (forward, and through JAX's
+        # transpose( marker backward) are the lm-head's
+        with jax.named_scope(_names.SCOPE_LM_HEAD_LOSS):
+            hd = jnp.dtype(cfg.head_dtype)
+            if cfg.loss_chunk_vocab and labels is not None and not decode:
+                # fused chunked head+loss: pull the head kernel and skip the
+                # monolithic [B, S, V] logits entirely
+                if cfg.tie_word_embeddings:
+                    w = embed.variables["params"]["embedding"].T
+                else:
+                    head = nn.Dense(cfg.vocab_size, use_bias=False,
+                                    dtype=hd, param_dtype=jnp.float32,
+                                    name="lm_head")
+                    # one-row call creates/binds lm_head with the standard
+                    # {kernel} layout (checkpoint/HF-ingest compatible); the
+                    # unused output is dead code to XLA
+                    head(x[:, :1].astype(hd))
+                    w = head.variables["params"]["kernel"]
+                return _lm_loss_chunked(x, w, labels, attention_mask,
+                                        cfg.loss_chunk_vocab, hd)
             if cfg.tie_word_embeddings:
-                w = embed.variables["params"]["embedding"].T
+                logits = embed.attend(x.astype(hd))
             else:
-                head = nn.Dense(cfg.vocab_size, use_bias=False,
-                                dtype=hd, param_dtype=jnp.float32,
-                                name="lm_head")
-                # one-row call creates/binds lm_head with the standard
-                # {kernel} layout (checkpoint/HF-ingest compatible); the
-                # unused output is dead code to XLA
-                head(x[:, :1].astype(hd))
-                w = head.variables["params"]["kernel"]
-            return _lm_loss_chunked(x, w, labels, attention_mask,
-                                    cfg.loss_chunk_vocab, hd)
-        if cfg.tie_word_embeddings:
-            logits = embed.attend(x.astype(hd))
-        else:
-            logits = nn.Dense(cfg.vocab_size, use_bias=False,
-                              dtype=hd, param_dtype=jnp.float32,
-                              name="lm_head")(x.astype(hd))
-        if labels is None:
-            return logits
-        return _lm_loss(logits, labels, attention_mask)
+                logits = nn.Dense(cfg.vocab_size, use_bias=False,
+                                  dtype=hd, param_dtype=jnp.float32,
+                                  name="lm_head")(x.astype(hd))
+            if labels is None:
+                return logits
+            return _lm_loss(logits, labels, attention_mask)
 
     @nn.nowrap
     def streaming_parts(self):

@@ -108,6 +108,7 @@ def _fwd(q, k, v, idx, valid, block, causal, scale, sq):
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=_interpret(),
+        name="ds_block_sparse_fwd",
     )(idx, valid, q, k, v)
 
 

@@ -210,6 +210,7 @@ def _fwd(q, k, v, slopes, causal, scale, block_q, block_k, sq, sk,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=_interpret(),
+        name="ds_flash_fwd",
     )(q, k, v, slopes)
     return o, lse
 
@@ -340,6 +341,7 @@ def _bwd(q, k, v, o, lse, do, slopes, causal, scale, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=semantics,
         interpret=_interpret(),
+        name="ds_flash_bwd_dq",
     )(q, k, v, do, lse, delta, slopes)
 
     # dk/dv are produced per *query* head ([B,Hq,Sk,D]) and group-summed to
@@ -375,6 +377,7 @@ def _bwd(q, k, v, o, lse, do, slopes, causal, scale, block_q, block_k,
         ],
         compiler_params=semantics,
         interpret=_interpret(),
+        name="ds_flash_bwd_dkv",
     )(q, k, v, do, lse, delta, slopes)
     if Hq != Hkv:
         g = Hq // Hkv

@@ -278,6 +278,7 @@ def _fwd(q, k, v, bias, mask_bias, causal, scale, block_q, block_k, sq, sk):
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=_interpret(),
+        name="ds_flash_bias_fwd",
     )(q, k, v, bias, mask_op)
     return o, lse
 
@@ -308,6 +309,7 @@ def _bwd(q, k, v, o, lse, do, bias, mask_bias, causal, scale, block_q,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=sem4, interpret=_interpret(),
+        name="ds_flash_bias_bwd_dq",
     )(q, k, v, do, lse, delta, bias, mask_op)
 
     qspec2, kspec2, bias_spec2, mask_spec2, row_spec2 = _specs(
@@ -323,6 +325,7 @@ def _bwd(q, k, v, o, lse, do, bias, mask_bias, causal, scale, block_q,
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
         compiler_params=sem4, interpret=_interpret(),
+        name="ds_flash_bias_bwd_dkv",
     )(q, k, v, do, lse, delta, bias, mask_op)
 
     # dbias: grid walks bias tiles; the (batch, head) broadcast-group
@@ -364,6 +367,7 @@ def _bwd(q, k, v, o, lse, do, bias, mask_bias, causal, scale, block_q,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "parallel", "arbitrary", "arbitrary")),
         interpret=_interpret(),
+        name="ds_flash_bias_bwd_dbias",
     )(q, k, v, do, lse, delta, bias, mask_op)
     return dq, dk, dv, dbias
 

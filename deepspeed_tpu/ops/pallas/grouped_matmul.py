@@ -116,5 +116,6 @@ def gmm(x, w, group_sizes, *, block_m=128, block_n=128, block_k=128,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="ds_grouped_matmul",
     )(expert_of_tile, xp, w)
     return yp[dest]

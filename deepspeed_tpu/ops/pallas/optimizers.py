@@ -113,6 +113,7 @@ def fused_adam_step(grad, master, m, v, *, lr, beta1, beta2, eps,
         ],
         input_output_aliases={2: 0, 3: 1, 4: 2},
         interpret=_interpret(),
+        name="ds_fused_adam",
     )(hyper, gt, pt, mt, vt)
     p_new, m_new, v_new, bf16 = out
     shape = grad.shape
@@ -162,6 +163,7 @@ def fused_lion_step(grad, master, m, *, lr, beta1, beta2, weight_decay,
         ],
         input_output_aliases={2: 0, 3: 1},
         interpret=_interpret(),
+        name="ds_fused_lion",
     )(hyper, gt, pt, mt)
     shape = grad.shape
     return (_from_tiles(bf16, n, shape, out_dtype),
@@ -238,6 +240,7 @@ def fused_lamb_step(grad, master, m, v, *, lr, beta1, beta2, eps,
         ],
         input_output_aliases={3: 1, 4: 2},
         interpret=_interpret(),
+        name="ds_fused_lamb_phase1",
     )(hyper, gt, pt, mt, vt)
 
     p_norm = jnp.sqrt(p_sq[0, 0])
@@ -261,6 +264,7 @@ def fused_lamb_step(grad, master, m, v, *, lr, beta1, beta2, eps,
         ],
         input_output_aliases={1: 0},
         interpret=_interpret(),
+        name="ds_fused_lamb_phase2",
     )(scaled, pt, u)
     shape = grad.shape
     return (_from_tiles(bf16, n, shape, out_dtype),

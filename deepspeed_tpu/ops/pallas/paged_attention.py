@@ -158,5 +158,6 @@ def paged_attention_atoms(q, k_cache, v_cache, tables_t, positions,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
+        name="ds_paged_decode" if atom == 1 else "ds_paged_atom",
     )(tables_t, positions, q.reshape(n_atoms, atom, H, Dh),
       k_cache, v_cache).reshape(T, H, Dh)

@@ -98,6 +98,7 @@ def quantize_blockwise(x, num_bits=8, group_size=2048, use_pallas=None):
             jax.ShapeDtypeStruct((rows, _LANES), jnp.float32),
         ],
         interpret=_interpret(),
+        name="ds_quantize_blockwise",
     )(tiles)
     return q, s[:, 0], meta
 
@@ -125,5 +126,6 @@ def dequantize_blockwise(q, scales, meta, use_pallas=None):
             out_specs=spec,
             out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
             interpret=_interpret(),
+            name="ds_dequantize_blockwise",
         )(q, s_l)
     return out.reshape(-1)[:n].reshape(shape).astype(dtype)

@@ -409,18 +409,19 @@ class TelemetryMetricsConfig(DeepSpeedConfigModel):
 
 class TelemetryConfig(DeepSpeedConfigModel):
     """``"telemetry"`` JSON section — see docs/observability.md.  Off by
-    default = zero overhead: every emit site guards on the module-level
-    ``deepspeed_tpu.telemetry.enabled`` flag, so the step path makes no
-    telemetry allocations and losses are bit-identical to a build without
-    the subsystem."""
+    default: the recorder, its files, the registry and the boundary sync
+    all sit behind the module-level ``deepspeed_tpu.telemetry.enabled``
+    flag, and losses are bit-identical to a build without the subsystem.
+    The ``ds:`` profiler annotations (``telemetry.scope``) are written
+    either way and need no key here."""
     enabled: bool = False
     trace_dir: str = "telemetry"   # chrome trace + per-step JSONL land here
     trace_steps: int = Field(0, ge=0)  # stop step records after N; 0 = all
     # block on the accelerator at phase boundaries: CPU-accurate phase
     # attribution at the cost of serializing async dispatch
     fence: bool = False
-    # wrap spans/steps in jax.profiler annotations so xplane captures
-    # (engine.start_device_trace) carry the phase names
+    # accepted for old configs and ignored: spans are always written as
+    # jax.profiler annotations (telemetry.scope), enabled or not
     device_profiler: bool = False
     metrics: TelemetryMetricsConfig = TelemetryMetricsConfig()
 

@@ -22,6 +22,7 @@ subclasses; the optimizer is an optax-style transform from ``deepspeed_tpu.ops``
 """
 
 import os
+import re
 import tempfile
 from functools import partial
 
@@ -33,6 +34,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import comm as dist
 from .. import telemetry as _telemetry
+from ..telemetry import names as _names
 from ..accelerator import get_accelerator
 from ..utils import groups
 from ..utils.logging import log_dist, logger
@@ -128,6 +130,17 @@ def _is_flax_module(model):
         return isinstance(model, nn.Module)
     except ImportError:
         return False
+
+
+def _named_program(fn, name):
+    """``fn`` under a stable ``__name__``: jit names the compiled program
+    after it (``jit_ds_micro_flat``), which is how a profiler's module line
+    tells the micro-step from the optimizer step (telemetry/names.py)."""
+    def program(*args):
+        return fn(*args)
+
+    program.__name__ = program.__qualname__ = re.sub(r"\W", "_", name)
+    return program
 
 
 class DeepSpeedEngine:
@@ -1400,7 +1413,9 @@ class DeepSpeedEngine:
     def _get_compiled_micro(self, inputs):
         key = tuple((tuple(x.shape), str(x.dtype)) for x in inputs)
         if key not in self._compiled_micro:
-            micro = self._micro_step_fn()
+            micro = _named_program(
+                self._micro_step_fn(),
+                _names.PROGRAM_MICRO + self._micro_variant())
             # compile ahead-of-time (the same single compile jit would do
             # lazily) so XLA's cost/memory analysis of the EXACT training
             # executable lands in the cost-model registry — MFU/HBM
@@ -1427,7 +1442,8 @@ class DeepSpeedEngine:
         def acc(grad_acc, grads):
             return jax.tree_util.tree_map(
                 lambda a, g: a + g.astype(a.dtype), grad_acc, grads)
-        return jax.jit(acc, donate_argnums=(0, ))
+        return jax.jit(_named_program(acc, _names.PROGRAM_ACCUMULATE),
+                       donate_argnums=(0, ))
 
     def _apply_update_fn(self):
         """The boundary step: unscale, overflow, clip, optimizer, recast."""
@@ -1521,7 +1537,9 @@ class DeepSpeedEngine:
     def _get_compiled_apply(self, args=None):
         if self._compiled_apply is None:
             jitted = jax.jit(
-                self._apply_update_fn(), donate_argnums=(0, 1, 2, 3, 4))
+                _named_program(self._apply_update_fn(),
+                               _names.PROGRAM_APPLY),
+                donate_argnums=(0, 1, 2, 3, 4))
             if args is not None:
                 # AOT capture like the micro-step: the boundary update's
                 # executable is where ALL model states are live at once —
@@ -1589,13 +1607,15 @@ class DeepSpeedEngine:
         """Reference engine.py:1848.  In training mode, runs the fused
         loss+grad micro-step and stashes grads for ``backward``."""
         self._check_params()
-        inputs = self.shard_batch(*inputs)
         if not self.training:
-            return self._eval_forward(inputs, kwargs)
-        self.timers(FORWARD_GLOBAL_TIMER).start()
+            return self._eval_forward(self.shard_batch(*inputs), kwargs)
         if _telemetry.enabled:
             _telemetry.begin_step(self.global_steps)
-            _telemetry.begin_span(_telemetry.SPAN_FORWARD)
+        ids = {"step": self.global_steps, "micro_step": self.micro_steps}
+        with _telemetry.scope(_names.TRAIN_SHARD_BATCH, **ids):
+            inputs = self.shard_batch(*inputs)
+        self.timers(FORWARD_GLOBAL_TIMER).start()
+        if _telemetry.enabled:
             self._tel_step_tokens += self._count_batch_tokens(inputs)
         if self._moe_gating_tail:
             # per-step fold-in: same compiled program, fresh key each
@@ -1620,7 +1640,13 @@ class DeepSpeedEngine:
                 # no flop count for this program: MFU must refuse (None),
                 # not report garbage from a partial sum
                 self._tel_flops_incomplete = True
-        loss, grads = micro(self.params, self.scale_state.scale, inputs)
+        # the call of the compiled loss+grad program: it returns once the
+        # program is enqueued, or — on a full chip — once the device has
+        # freed the buffers it needs (the host then waits HERE for the
+        # previous step).  The recorder's "forward" phase is this span.
+        with _telemetry.scope(_names.TRAIN_MICRO,
+                              phase=_telemetry.SPAN_FORWARD, **ids):
+            loss, grads = micro(self.params, self.scale_state.scale, inputs)
         from ..utils.fault_injection import fault_point
         if fault_point("engine.poison", step=self.micro_steps):
             # injected data poisoning: NaN loss + grads, exactly what a bad
@@ -1631,8 +1657,6 @@ class DeepSpeedEngine:
                 lambda g: jnp.full_like(g, jnp.nan), grads)
         self._stashed_grads = grads
         self._micro_losses.append(loss)  # device scalar; synced only on report
-        if _telemetry.enabled:
-            _telemetry.end_span(_telemetry.SPAN_FORWARD)
         self.timers(FORWARD_GLOBAL_TIMER).stop()
         self._maybe_profile_flops(inputs)
         return loss
@@ -1708,37 +1732,38 @@ class DeepSpeedEngine:
             raise RuntimeError("backward() called without a prior forward() "
                                "in training mode")
         self.timers(BACKWARD_GLOBAL_TIMER).start()
-        if _telemetry.enabled:
-            _telemetry.begin_span(_telemetry.SPAN_BACKWARD)
-        offloaded = getattr(self, "_host_offloaded", None)
-        if offloaded and "grad_acc" in offloaded:
-            # grads offloaded mid-accumulation: restore BEFORE the None
-            # check or the prior micro-batches' gradients are silently lost
-            host, shardings = offloaded["grad_acc"]
-            self.grad_acc = jax.tree_util.tree_map(jax.device_put, host,
-                                                   shardings)
-            del offloaded["grad_acc"]
-        if _telemetry.enabled:
+        ids = {"step": self.global_steps, "micro_step": self.micro_steps}
+        # "backward" is a host-side fold: the gradients were computed by
+        # the micro-step program that forward() launched
+        with _telemetry.scope(_names.TRAIN_BACKWARD,
+                              phase=_telemetry.SPAN_BACKWARD, **ids):
+            offloaded = getattr(self, "_host_offloaded", None)
+            if offloaded and "grad_acc" in offloaded:
+                # grads offloaded mid-accumulation: restore BEFORE the None
+                # check or the prior micro-batches' gradients are silently
+                # lost
+                host, shardings = offloaded["grad_acc"]
+                self.grad_acc = jax.tree_util.tree_map(jax.device_put, host,
+                                                       shardings)
+                del offloaded["grad_acc"]
             # the fold that triggers the (GSPMD-lowered) DP grad reduction —
             # device-side reduce time lands inside this span under fence mode
-            _telemetry.begin_span(_telemetry.SPAN_GRAD_REDUCE)
-        if self.grad_acc is None:
-            self.grad_acc = self._stashed_grads
-        else:
-            if not hasattr(self, "_acc_fn"):
-                self._acc_fn = self._accumulate_fn()
-            self.grad_acc = self._acc_fn(self.grad_acc, self._stashed_grads)
-        if _telemetry.enabled:
-            _telemetry.end_span(_telemetry.SPAN_GRAD_REDUCE)
-        self._stashed_grads = None
-        if (self._nvme_swapper is not None and self._state_on_nvme
-                and self.is_gradient_accumulation_boundary()):
-            # last microbatch: start the async disk reads now so they overlap
-            # the backward compute tail (reference swap-in overlap,
-            # stage3.py:1926)
-            self._nvme_start_swap_in()
-        if _telemetry.enabled:
-            _telemetry.end_span(_telemetry.SPAN_BACKWARD)
+            with _telemetry.scope(_names.TRAIN_ACCUMULATE,
+                                  phase=_telemetry.SPAN_GRAD_REDUCE, **ids):
+                if self.grad_acc is None:
+                    self.grad_acc = self._stashed_grads
+                else:
+                    if not hasattr(self, "_acc_fn"):
+                        self._acc_fn = self._accumulate_fn()
+                    self.grad_acc = self._acc_fn(self.grad_acc,
+                                                 self._stashed_grads)
+            self._stashed_grads = None
+            if (self._nvme_swapper is not None and self._state_on_nvme
+                    and self.is_gradient_accumulation_boundary()):
+                # last microbatch: start the async disk reads now so they
+                # overlap the backward compute tail (reference swap-in
+                # overlap, stage3.py:1926)
+                self._nvme_start_swap_in()
         self.timers(BACKWARD_GLOBAL_TIMER).stop()
         return loss
 
@@ -1751,63 +1776,72 @@ class DeepSpeedEngine:
                     not getattr(self, "_host_offloaded", None):
                 raise RuntimeError("step() at a grad-accum boundary without "
                                    "any backward() since the last boundary")
-            if _telemetry.enabled:
-                _telemetry.begin_span(_telemetry.SPAN_OPTIMIZER)
-            host_gnorm = self._try_host_offload_step()
-            if host_gnorm is not None:
-                skipped = jnp.zeros((), jnp.bool_)
-                gnorm = host_gnorm
-            else:
-                # restore offloaded state FIRST — grads may live on host via
-                # offload_states(include=["lp_grads"])
-                self._ensure_state_resident()
-                if self.grad_acc is None:
-                    raise RuntimeError(
-                        "step() at a grad-accum boundary without any "
-                        "backward() since the last boundary")
-                apply_args = (self.params, self.master, self.opt_state,
-                              self.grad_acc, self.scale_state,
-                              self._spike_limit())
-                apply = self._get_compiled_apply(apply_args)
-                (self.params, self.master, self.opt_state,
-                 self.scale_state, skipped, gnorm) = apply(*apply_args)
-                if _telemetry.enabled and self._apply_cost is not None:
-                    # counted HERE (where the program ran, flops known or
-                    # not) — the host-offload branch above never executes
-                    # this executable
-                    self._apply_cost.calls += 1
-                self.grad_acc = None
-                if self._nvme_swapper is not None:
-                    # updated state back to disk (async; overlaps next fwd)
-                    self._nvme_swap_out()
-            if _telemetry.enabled:
-                _telemetry.end_span(_telemetry.SPAN_OPTIMIZER)
+            ids = {"step": self.global_steps,
+                   "micro_step": self.micro_steps}
+            # the call of the optimizer program (the recorder's
+            # "optimizer" phase); like the micro-step it only enqueues
+            # unless the chip is full
+            with _telemetry.scope(_names.TRAIN_APPLY,
+                                  phase=_telemetry.SPAN_OPTIMIZER, **ids):
+                host_gnorm = self._try_host_offload_step()
+                if host_gnorm is not None:
+                    skipped = jnp.zeros((), jnp.bool_)
+                    gnorm = host_gnorm
+                else:
+                    # restore offloaded state FIRST — grads may live on
+                    # host via offload_states(include=["lp_grads"])
+                    self._ensure_state_resident()
+                    if self.grad_acc is None:
+                        raise RuntimeError(
+                            "step() at a grad-accum boundary without any "
+                            "backward() since the last boundary")
+                    apply_args = (self.params, self.master, self.opt_state,
+                                  self.grad_acc, self.scale_state,
+                                  self._spike_limit())
+                    apply = self._get_compiled_apply(apply_args)
+                    (self.params, self.master, self.opt_state,
+                     self.scale_state, skipped, gnorm) = apply(*apply_args)
+                    if _telemetry.enabled and self._apply_cost is not None:
+                        # counted HERE (where the program ran, flops known
+                        # or not) — the host-offload branch above never
+                        # executes this executable
+                        self._apply_cost.calls += 1
+                    self.grad_acc = None
+                    if self._nvme_swapper is not None:
+                        # updated state back to disk (async; overlaps the
+                        # next forward)
+                        self._nvme_swap_out()
             if self._finite_guard.enabled:
                 self._account_guarded_step(skipped, gnorm)
-            self.global_steps += 1
-            self.global_samples += self.train_batch_size()
-            if self.progressive_layer_drop is not None:
-                self.progressive_layer_drop.update_state(self.global_steps)
-            if self._config.fp16_enabled:
-                # NO host sync here: the overflow flag accumulates on device
-                # and drains at steps_per_print (or on a skipped_steps read)
-                ov = skipped.astype(jnp.int32)
-                self._overflow_acc = (ov if self._overflow_acc is None
-                                      else self._overflow_acc + ov)
-            if self.lr_scheduler is not None and hasattr(self.lr_scheduler, "step"):
-                self.lr_scheduler.step()
-                self._scheduler_reclaims_lr()
-            if self.curriculum_scheduler is not None:
-                self.curriculum_scheduler.update_difficulty(self.global_steps)
-            for hook in self._post_step_hooks:
-                hook(self)
-            if self._micro_losses:
-                # the step's loss = mean over the gas window (reference
-                # engine.py:2029 logs the accumulated mean, not the last
-                # microbatch)
-                self._last_loss = self._micro_losses
-                self._micro_losses = []
-            self._report_step_metrics(gnorm)
+            with _telemetry.scope(_names.TRAIN_REPORT, **ids):
+                self.global_steps += 1
+                self.global_samples += self.train_batch_size()
+                if self.progressive_layer_drop is not None:
+                    self.progressive_layer_drop.update_state(
+                        self.global_steps)
+                if self._config.fp16_enabled:
+                    # NO host sync here: the overflow flag accumulates on
+                    # device and drains at steps_per_print (or on a
+                    # skipped_steps read)
+                    ov = skipped.astype(jnp.int32)
+                    self._overflow_acc = (ov if self._overflow_acc is None
+                                          else self._overflow_acc + ov)
+                if self.lr_scheduler is not None and \
+                        hasattr(self.lr_scheduler, "step"):
+                    self.lr_scheduler.step()
+                    self._scheduler_reclaims_lr()
+                if self.curriculum_scheduler is not None:
+                    self.curriculum_scheduler.update_difficulty(
+                        self.global_steps)
+                for hook in self._post_step_hooks:
+                    hook(self)
+                if self._micro_losses:
+                    # the step's loss = mean over the gas window (reference
+                    # engine.py:2029 logs the accumulated mean, not the last
+                    # microbatch)
+                    self._last_loss = self._micro_losses
+                    self._micro_losses = []
+                self._report_step_metrics(gnorm)
             if _telemetry.enabled:
                 self._telemetry_step_end(skipped, gnorm)
             if self._heartbeat is not None:

@@ -20,9 +20,10 @@ layer adds over the raw engine:
   token-identically;
 * **prefill/decode disaggregation** — the engine's two-layout atom
   machinery (``engine_v2._atom_layout``) packs the regions; the scheduler
-  classifies each iteration (``prefill`` / ``decode`` / ``mixed``) and
-  books it as a telemetry span, and fuses multi-token decode bursts when
-  every in-flight sequence is in pure decode;
+  books each iteration as a ``ds:serve.step`` span whose counts say what
+  it held (``prefill`` / ``decode`` / ``mixed`` in the recorder's phase
+  column), and fuses multi-token decode bursts when every in-flight
+  sequence is in pure decode;
 * **streaming** — per-token ``on_token(token, done)`` callbacks as tokens
   are produced, not when the request completes;
 * **observability + health** — per-request TTFT/TBT histograms,
@@ -35,6 +36,7 @@ import time
 from collections import deque
 
 from .. import telemetry
+from ..telemetry import names
 from ..elasticity.watchdog import HEARTBEAT_DIR_ENV, HeartbeatWriter
 from ..inference.v2.ragged import KVCacheExhausted
 from ..utils.logging import logger
@@ -165,6 +167,7 @@ class ServingScheduler:
             self._admit_ticket += 1
             self._running[req.uid] = req
             self.peak_running = max(self.peak_running, len(self._running))
+            telemetry.mark(names.SERVE_ADMITTED, uid=req.uid)
             if telemetry.enabled:
                 telemetry.counter("serving/requests_admitted",
                                   help="admission-queue → engine "
@@ -187,6 +190,7 @@ class ServingScheduler:
         self.preemptions += 1
         victim.transition(RequestState.QUEUED)
         self._queue.appendleft(victim)
+        telemetry.mark(names.SERVE_PREEMPTED, uid=victim.uid)
         logger.info(
             "serving: preempted uid %s (%d produced, %d prompt tokens) "
             "under KV pressure — requeued at front", victim.uid,
@@ -197,21 +201,6 @@ class ServingScheduler:
         return True
 
     # ----------------------------------------------------------------- steps
-    def _phase(self):
-        """Step classification for span attribution: what work is pending
-        across the in-flight batch right now."""
-        n_prefill = n_decode = 0
-        for uid in self._running:
-            seq = self.engine.state_manager.get_sequence(uid)
-            pending = len(seq.tokens) - seq.seen_tokens
-            if pending > 1:
-                n_prefill += 1
-            elif pending == 1:
-                n_decode += 1
-        if n_prefill and n_decode:
-            return "mixed"
-        return "prefill" if n_prefill else "decode"
-
     def _try_burst(self):
         """Fused multi-token decode when EVERY in-flight sequence is in
         pure decode (same eligibility as ``generate``'s burst path).
@@ -242,36 +231,42 @@ class ServingScheduler:
     def step(self):
         """One scheduler iteration: admit → run one engine step (preempting
         under KV exhaustion) → stream tokens.  Returns {uid: [tokens]}
-        emitted this step (empty when idle)."""
-        self._admit()
+        emitted this step (empty when idle).
+
+        A working step is one ``ds:serve.step`` span in any profiler capture
+        (``telemetry/names.py``), with the children admit / build_batch /
+        launch / fetch / dispatch and, as its counts, what the engine step
+        held (``InferenceEngineV2.last_step_counts``)."""
         self._step_index += 1
         if self._heartbeat is not None:
             self._heartbeat.beat(self._step_index)
-        if not self._running:
+        if self.idle:
             self._export_gauges()
             return {}
         if telemetry.enabled:
             telemetry.begin_step(self._step_index)
-        phase = self._phase()
+        with telemetry.scope(names.SERVE_STEP, cat="serve",
+                             step=self._step_index) as span:
+            with telemetry.scope(names.SERVE_ADMIT):
+                self._admit()
+            emitted = self._run_step(span) if self._running else {}
+        self._export_gauges(n_tokens=sum(len(v) for v in emitted.values()))
+        return emitted
+
+    def _run_step(self, span):
         t_launch = self._clock()     # before the engine call — _dispatch
         preempts = 0                 # amortizes burst wall time over tokens
         while True:
             try:
-                if telemetry.enabled:
-                    telemetry.begin_span(phase, cat="serve")
-                try:
-                    burst = self._try_burst()
-                    if burst is not None:
-                        results = burst
-                    else:
-                        cfg = self.config
-                        results = self.engine.schedule_step(
-                            do_sample=cfg.do_sample,
-                            temperature=cfg.temperature, top_k=cfg.top_k,
-                            top_p=cfg.top_p, rng=cfg.seed)
-                finally:
-                    if telemetry.enabled:
-                        telemetry.end_span(phase)
+                burst = self._try_burst()
+                if burst is not None:
+                    results = burst
+                else:
+                    cfg = self.config
+                    results = self.engine.schedule_step(
+                        do_sample=cfg.do_sample,
+                        temperature=cfg.temperature, top_k=cfg.top_k,
+                        top_p=cfg.top_p, rng=cfg.seed)
                 break
             except KVCacheExhausted as e:
                 preempts += 1
@@ -283,9 +278,16 @@ class ServingScheduler:
                         "request needs more blocks than the pool holds "
                         "(raise state_manager.num_blocks or lower "
                         "max_context)") from e
-        emitted = self._dispatch(results, t_launch)
-        self._export_gauges(n_tokens=sum(len(v) for v in emitted.values()))
-        return emitted
+        counts = self.engine.last_step_counts or {}
+        # the recorder's phase column keeps its prefill|decode|mixed name,
+        # now derived from what the step really held
+        phase = ("mixed" if counts.get("prefill_tokens")
+                 and counts.get("decode_tokens") else
+                 "prefill" if counts.get("prefill_tokens") else "decode")
+        span.set(phase=phase, running=len(self._running),
+                 queued=len(self._queue), preempts=preempts, **counts)
+        with telemetry.scope(names.SERVE_DISPATCH):
+            return self._dispatch(results, t_launch)
 
     def _dispatch(self, results, t_launch=None):
         """Book engine output into request records: streaming callbacks,
@@ -330,6 +332,8 @@ class ServingScheduler:
                     self.engine.flush([uid])
                     del self._running[uid]
                     self.completed += 1
+                    telemetry.mark(names.SERVE_FINISHED, uid=uid,
+                                   tokens=len(req.produced))
                     if telemetry.enabled:
                         telemetry.counter("serving/requests_completed",
                                           help="requests finished (EOS or "

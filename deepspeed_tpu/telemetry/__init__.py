@@ -11,19 +11,29 @@ One structured-event model for everything the stack can measure:
 * **live metrics** (:mod:`.metrics`): counters/gauges/histograms with the
   ``monitor/`` backends as sinks plus a Prometheus text endpoint.
 
-Disabled (the default) means **zero overhead**: every emit site in the hot
-path guards on the module-level :data:`enabled` flag —
+One span primitive, :func:`scope`: it ALWAYS writes a ``ds:<name>``
+``jax.profiler.TraceAnnotation`` (with its counts as the event's stats), so
+any profiler capture shows the program's own spans on the device trace's
+clock with no config — under a microsecond a span when no profiler session
+is open — and, only when :data:`enabled`, books the same span into the
+:class:`TraceRecorder`.  The names are constants in :mod:`.names`.
+
+Everything else stays behind the module-level :data:`enabled` flag, off by
+default: no recorder, no file, no registry lookup, no device sync —
 
     from deepspeed_tpu import telemetry
     if telemetry.enabled:
         telemetry.record_comm_event(...)
 
-one attribute read, no allocations, no dict churn.  ``configure()`` (called
-by the engine when the ``telemetry`` config block enables it) flips the
-flag and builds the recorder/registry; ``shutdown()`` flushes and flips it
-back.  This module must stay import-light: ``comm/comm.py`` imports it at
-module scope.
+one attribute read.  ``configure()`` (called by the engine when the
+``telemetry`` config block enables it) flips the flag and builds the
+recorder/registry; ``shutdown()`` flushes and flips it back.  This module
+must stay import-light: ``comm/comm.py`` imports it at module scope.
 """
+
+from jax.profiler import TraceAnnotation as _Annotation
+
+from . import names  # noqa: F401  (re-export)
 
 from .comm_attribution import (CommAttribution,  # noqa: F401  (re-export)
                                overlap_efficiency)
@@ -56,9 +66,10 @@ def get_registry():
 
 def configure(cfg, monitor=None, rank=0):
     """Enable telemetry from a ``TelemetryConfig``-shaped object (duck-typed:
-    ``trace_dir``/``trace_steps``/``fence``/``device_profiler`` plus a
-    ``metrics`` sub-object).  Reconfiguring tears the previous instance down
-    first.  Returns (recorder, registry)."""
+    ``trace_dir``/``trace_steps``/``fence`` plus a ``metrics`` sub-object;
+    ``device_profiler`` is accepted and does nothing — :func:`scope` always
+    annotates).  Reconfiguring tears the previous instance down first.
+    Returns (recorder, registry)."""
     global enabled, _recorder, _registry, _sinks, _endpoint, _rank
     shutdown()
     _rank = int(rank)
@@ -66,7 +77,6 @@ def configure(cfg, monitor=None, rank=0):
     _recorder = TraceRecorder(
         trace_dir,
         fence=getattr(cfg, "fence", False),
-        device_annotations=getattr(cfg, "device_profiler", False),
         trace_steps=getattr(cfg, "trace_steps", 0),
         rank=_rank)
     _registry = MetricsRegistry()
@@ -134,12 +144,76 @@ def end_span(name=None):
 
 
 def span(name, cat="compute", **args):
-    """Context-manager span for call sites with natural with-scoping
-    (checkpoint engine, tools); the engine hot path uses begin/end."""
+    """Recorder-only context-manager span (checkpoint engine, tools): no
+    profiler annotation.  The hot paths use :func:`scope`."""
     if _recorder is not None:
         return _recorder.span(name, cat=cat, **args)
     import contextlib
     return contextlib.nullcontext()
+
+
+class Scope(_Annotation):
+    """One span of the program: a ``ds:<name>`` profiler annotation (this
+    class IS the annotation, so the disabled path adds no wrapper).  A
+    context manager; ``begin()`` / ``end()`` are the same for linear call
+    sites.  ``set()`` adds counts that are only known once the work is
+    under way."""
+
+    __slots__ = ()
+
+    def begin(self):
+        self.__enter__()
+        return self
+
+    def end(self):
+        self.__exit__(None, None, None)
+
+    def set(self, phase=None, **counts):
+        self.set_metadata(**counts)
+
+
+class _RecordedScope(Scope):
+    """A :class:`Scope` that also books the span, with the same counts,
+    into the :class:`TraceRecorder` (telemetry enabled)."""
+
+    __slots__ = ("_span", )
+
+    def __init__(self, name, phase, cat, counts):
+        super().__init__(names.SPAN_PREFIX + name, **counts)
+        self._span = _recorder.span(phase or name, cat=cat, **counts)
+
+    def __enter__(self):
+        super().__enter__()
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._span.__exit__(*exc)
+        return super().__exit__(*exc)
+
+    def set(self, phase=None, **counts):
+        self.set_metadata(**counts)
+        span = self._span
+        if phase is not None:
+            span.name = phase
+        span.args = {**span.args, **counts} if span.args else counts
+
+
+def scope(name, phase=None, cat="compute", **counts):
+    """``with telemetry.scope(names.TRAIN_MICRO, step=3): ...`` — always a
+    ``ds:<name>`` ``jax.profiler.TraceAnnotation`` with the counts as its
+    stats; only when telemetry is enabled, the same span in the
+    :class:`TraceRecorder` too.  ``phase`` is the name the recorder books
+    it under (its documented phase columns predate the ``ds:`` names);
+    default the scope's own name.  Counts are ints or strings."""
+    if enabled and _recorder is not None:
+        return _RecordedScope(name, phase, cat, counts)
+    return Scope(names.SPAN_PREFIX + name, **counts)
+
+
+def mark(name, **counts):
+    """A span of no length: one request event (``ds:serve.admitted``)."""
+    scope(name, cat="event", **counts).begin().end()
 
 
 def record_comm_event(op, variant, msg_bytes, wire_bytes, latency_s,

@@ -1,0 +1,90 @@
+"""The names the program writes into a ``jax.profiler`` trace — one table
+that the emit sites, the docs and the readers (``perfbench/program_trace.py``,
+``tools``) all import.  Pure constants: importing this module imports nothing.
+
+Three kinds of name:
+
+* **host spans** (``telemetry.scope``): ``ds:<name>`` trace annotations on
+  the host's line, with their counts as the event's ``stats``.  Written
+  always; they cost well under a microsecond when no profiler session is
+  open.
+* **device ops**: every Pallas kernel is a ``pallas_call(name="ds_...")``,
+  so its HLO instruction (the device event's name) starts with that name;
+  ``jax.named_scope("ds.<layer>")`` puts the layer into the op's scope
+  path (``jit(ds_micro_flat)/jvp(ds.lm_head_loss)/dot_general``), where
+  JAX's own ``jvp(`` / ``transpose(`` / ``rematted_computation`` markers
+  tell forward, backward and recomputation apart.
+* **programs**: the jitted programs' ``__name__`` (the ``XLA Modules``
+  line shows ``jit_<name>(<id>)``).
+
+docs/observability.md and docs/kernels.md say what each covers.
+"""
+
+#: prefix of every host span the program writes
+SPAN_PREFIX = "ds:"
+
+# ---- training host spans (counts on each: step, micro_step)
+TRAIN_SHARD_BATCH = "train.shard_batch"   # host batch -> device arrays
+TRAIN_MICRO = "train.micro"               # call of the loss+grad program
+TRAIN_BACKWARD = "train.backward"         # the whole backward() call
+TRAIN_ACCUMULATE = "train.accumulate"     # fold grads into the accumulator
+TRAIN_APPLY = "train.apply"               # call of the optimizer program
+TRAIN_REPORT = "train.report"             # metrics, scheduler, hooks
+
+# ---- serving host spans
+SERVE_STEP = "serve.step"                 # one ServingScheduler.step
+SERVE_ADMIT = "serve.admit"               # admission gate
+SERVE_BUILD_BATCH = "serve.build_batch"   # pack the token budget (numpy)
+SERVE_LAUNCH = "serve.launch"             # host arrays -> device, jit call
+SERVE_FETCH = "serve.fetch"               # np.asarray of the tokens: the wait
+SERVE_DISPATCH = "serve.dispatch"         # callbacks, lifecycle, flush
+# one short span per request event (count: uid)
+SERVE_ADMITTED = "serve.admitted"
+SERVE_FINISHED = "serve.finished"         # + tokens
+SERVE_PREEMPTED = "serve.preempted"
+
+TRAIN_SPANS = (TRAIN_SHARD_BATCH, TRAIN_MICRO, TRAIN_BACKWARD,
+               TRAIN_ACCUMULATE, TRAIN_APPLY, TRAIN_REPORT)
+SERVE_STEP_CHILDREN = (SERVE_ADMIT, SERVE_BUILD_BATCH, SERVE_LAUNCH,
+                       SERVE_FETCH, SERVE_DISPATCH)
+
+#: ``kind`` of a ``ds:serve.step``: one ragged engine step, or a fused
+#: multi-token decode burst
+KIND_RAGGED = "ragged"
+KIND_BURST = "burst"
+#: the counts of a ``ds:serve.step`` (docs/observability.md says what each
+#: counts); the batch builder makes them, the scheduler's span carries them
+SERVE_STEP_COUNTS = ("step", "kind", "running", "queued", "token_budget",
+                     "live_tokens", "prefill_tokens", "decode_tokens",
+                     "grid_pages", "live_pages", "burst_k", "preempts")
+
+# ---- jitted programs (``XLA Modules`` events are ``jit_<name>(<id>)``)
+PROGRAM_MICRO = "ds_micro_"               # + the micro-step variant
+PROGRAM_APPLY = "ds_apply_update"
+PROGRAM_ACCUMULATE = "ds_accumulate"
+PROGRAM_RAGGED_STEP = "ds_ragged_step_"   # + the architecture
+PROGRAM_DECODE_BURST = "ds_decode_burst"
+
+# ---- named scopes inside the compiled programs.  The flax models name
+# attention and MLP themselves (module names in the scope path); the serving
+# steps are plain functions and set the two scopes.
+SCOPE_EMBED = "ds.embed"
+SCOPE_LM_HEAD_LOSS = "ds.lm_head_loss"    # training: head and loss, both paths
+SCOPE_LM_HEAD = "ds.lm_head"              # serving: final norm, last-token logits
+SCOPE_ATTENTION = "ds.attn"               # serving: qkv, rotary, cache, paged, o
+SCOPE_MLP = "ds.mlp"                      # serving: the MLP or expert block
+SCOPE_NORM = "ds.norm"                    # serving: rms / layer norms
+SCOPE_KV_CACHE = "ds.kv_cache"            # serving: a layer's slice of the paged
+#                                           cache taken out and written back
+MODULE_ATTENTION = "self_attn"            # flax module name (training)
+MODULE_MLP = "mlp"
+
+# ---- Pallas kernels: ``pallas_call(name=...)`` prefixes by family
+KERNEL_PREFIX = "ds_"
+KERNEL_FLASH = "ds_flash_"                # fwd, bwd_dq, bwd_dkv (+ _bias_)
+KERNEL_PAGED = "ds_paged_"                # decode (per token), atom (tiled)
+KERNEL_OPTIMIZER = "ds_fused_"            # adam, lion, lamb_phase1/2
+
+#: JAX's own markers in a scope path
+MARK_TRANSPOSE = "transpose("             # backward
+MARK_REMAT = "rematted_computation"       # recomputed forward

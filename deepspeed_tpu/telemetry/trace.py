@@ -17,10 +17,10 @@ Timing is host wall time (``time.perf_counter``).  With ``fence=True`` the
 recorder blocks on the accelerator at phase boundaries, so phase times are
 CPU-accurate attributions instead of async-dispatch shadows — the same
 trade ``comms_logger.sync_timing`` makes, documented in
-docs/observability.md.  With ``device_annotations=True`` spans additionally
-wrap ``jax.profiler`` annotations so an xplane capture
-(``engine.start_device_trace``) carries the phase names into the
-device-time view.
+docs/observability.md.  The recorder's clock is not the device's: for
+device-side truth read the ``ds:`` annotations that ``telemetry.scope``
+writes into any ``jax.profiler`` capture (they need no recorder and no
+config; ``telemetry/names.py``).
 """
 
 import atexit
@@ -74,7 +74,7 @@ def _sync_device():
 class _SpanHandle:
     """Context manager for one span; also usable via explicit begin/end."""
 
-    __slots__ = ("_rec", "name", "cat", "args", "_t0", "_annotation")
+    __slots__ = ("_rec", "name", "cat", "args", "_t0")
 
     def __init__(self, rec, name, cat, args):
         self._rec = rec
@@ -82,7 +82,6 @@ class _SpanHandle:
         self.cat = cat
         self.args = args
         self._t0 = None
-        self._annotation = None
 
     def __enter__(self):
         self._rec._begin(self)
@@ -95,12 +94,10 @@ class _SpanHandle:
 
 class TraceRecorder:
 
-    def __init__(self, trace_dir, fence=False, device_annotations=False,
-                 trace_steps=0, rank=0, max_events=200_000,
-                 sync_fn=_sync_device):
+    def __init__(self, trace_dir, fence=False, trace_steps=0, rank=0,
+                 max_events=200_000, sync_fn=_sync_device):
         self.trace_dir = os.path.abspath(trace_dir)
         self.fence = bool(fence)
-        self.device_annotations = bool(device_annotations)
         self.trace_steps = int(trace_steps)  # 0 = unbounded
         self.rank = int(rank)
         self.max_events = int(max_events)
@@ -115,7 +112,6 @@ class TraceRecorder:
         # per-step state
         self._step = None
         self._step_t0 = None
-        self._step_annotation = None
         self._phase_s = {}
         self._bucket_s = {}
         self._moe_s = {}             # layer → accumulated routing stats
@@ -179,13 +175,6 @@ class TraceRecorder:
     def _begin(self, h):
         if self.fence:
             self._sync()
-        if self.device_annotations:
-            try:
-                import jax
-                h._annotation = jax.profiler.TraceAnnotation(h.name)
-                h._annotation.__enter__()
-            except Exception:
-                h._annotation = None
         self._stack.append(h)
         h._t0 = time.perf_counter()
 
@@ -193,12 +182,6 @@ class TraceRecorder:
         if self.fence:
             self._sync()
         t1 = time.perf_counter()
-        if h._annotation is not None:
-            try:
-                h._annotation.__exit__(None, None, None)
-            except Exception:
-                pass
-            h._annotation = None
         try:
             depth = self._stack.index(h)
         except ValueError:
@@ -230,14 +213,6 @@ class TraceRecorder:
         self._moe_s = {}
         self._hbm = None
         self._step_comm.reset()
-        if self.device_annotations:
-            try:
-                import jax
-                self._step_annotation = jax.profiler.StepTraceAnnotation(
-                    "train_step", step_num=step)
-                self._step_annotation.__enter__()
-            except Exception:
-                self._step_annotation = None
 
     def end_step(self, metrics=None):
         """Close the step window: emit the chrome step event and append one
@@ -247,12 +222,6 @@ class TraceRecorder:
             return
         if self.fence:
             self._sync()
-        if self._step_annotation is not None:
-            try:
-                self._step_annotation.__exit__(None, None, None)
-            except Exception:
-                pass
-            self._step_annotation = None
         wall_s = time.perf_counter() - self._step_t0
         step = self._step
         self._step = None

@@ -746,10 +746,12 @@ def test_train_step_single_compile_across_steps():
     # stopped firing on this jaxlib's dispatch logger (the guard silently
     # counted 0 == "no recompile"), while the finish line fires on both the
     # lazy-jit and the AOT (lower().compile()) paths the engine now uses
-    n_micro = sum(1 for m in records
-                  if "Finished XLA compilation of jit(micro)" in m)
-    n_apply = sum(1 for m in records
-                  if "Finished XLA compilation of jit(apply)" in m)
+    # the programs carry the stable names of telemetry/names.py
+    from deepspeed_tpu.telemetry import names
+    n_micro = sum(1 for m in records if "Finished XLA compilation of "
+                  f"jit({names.PROGRAM_MICRO}flat)" in m)
+    n_apply = sum(1 for m in records if "Finished XLA compilation of "
+                  f"jit({names.PROGRAM_APPLY})" in m)
     assert n_micro == 1, f"micro compiled {n_micro}× across same-shape steps"
     assert n_apply == 1, f"apply compiled {n_apply}× across same-shape steps"
 

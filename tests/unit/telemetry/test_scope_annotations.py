@@ -1,0 +1,398 @@
+"""The program's own spans in a real ``jax.profiler`` trace (ISSUE 24):
+``telemetry.scope`` always writes a ``ds:<name>`` annotation with its counts
+as the event's stats; the engine loop and the serving step emit the names of
+``telemetry/names.py``; with telemetry disabled nothing else happens, with it
+enabled the ``TraceRecorder`` gets the same spans and counts."""
+
+import ast
+import glob
+import json
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import deepspeed_tpu
+from deepspeed_tpu import telemetry
+from deepspeed_tpu.inference.v2 import InferenceEngineV2
+from deepspeed_tpu.models import llama
+from deepspeed_tpu.serving import ServingScheduler
+from deepspeed_tpu.telemetry import names
+from deepspeed_tpu.telemetry.trace import TRACE_FILE
+from deepspeed_tpu.utils import groups
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))))
+
+
+class Traced:
+    """``with Traced(tmp_path) as t: ...`` then ``t.events``: the ``ds:``
+    events of the capture, ``(name, start_ns, end_ns, stats)`` by start."""
+
+    def __init__(self, tmp_path):
+        self.dir = str(tmp_path / "xplane")
+
+    def __enter__(self):
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(self.dir, profiler_options=options)
+        return self
+
+    def __exit__(self, *exc):
+        jax.profiler.stop_trace()
+        from jax.profiler import ProfileData
+        path, = glob.glob(os.path.join(self.dir, "plugins", "profile", "*",
+                                       "*.xplane.pb"))
+        self.events = sorted(
+            (ev.name[len(names.SPAN_PREFIX):], ev.start_ns,
+             ev.start_ns + ev.duration_ns, dict(ev.stats))
+            for plane in ProfileData.from_file(path).planes
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith(names.SPAN_PREFIX))
+        self.events.sort(key=lambda e: e[1])
+
+    def named(self, name):
+        return [e for e in self.events if e[0] == name]
+
+    def inside(self, child, parent):
+        return parent[1] <= child[1] and child[2] <= parent[2]
+
+
+# ------------------------------------------------------------ the primitive
+def test_scope_is_an_annotation_with_counts_and_nesting(tmp_path):
+    assert not telemetry.enabled
+    with Traced(tmp_path) as t:
+        with telemetry.scope(names.SERVE_STEP, step=7) as span:
+            with telemetry.scope(names.SERVE_ADMIT):
+                pass
+            span.set(phase="decode", kind=names.KIND_RAGGED, live_tokens=5)
+        linear = telemetry.scope(names.TRAIN_MICRO, step=1,
+                                 micro_step=2).begin()
+        linear.end()
+        telemetry.mark(names.SERVE_ADMITTED, uid="abc")
+    step, = t.named(names.SERVE_STEP)
+    assert step[3] == {"step": 7, "kind": "ragged", "live_tokens": 5}
+    assert t.inside(t.named(names.SERVE_ADMIT)[0], step)
+    assert t.named(names.TRAIN_MICRO)[0][3] == {"step": 1, "micro_step": 2}
+    assert t.named(names.SERVE_ADMITTED)[0][3] == {"uid": "abc"}
+    assert telemetry.get_recorder() is None
+
+
+def test_enabled_scope_books_the_same_span_in_the_recorder(tmp_path):
+    class Cfg:
+        trace_dir = str(tmp_path / "tel")
+        device_profiler = True          # accepted, does nothing
+
+    rec, _ = telemetry.configure(Cfg())
+    try:
+        assert not hasattr(rec, "device_annotations")
+        telemetry.begin_step(3)
+        with telemetry.scope(names.SERVE_STEP, cat="serve", step=3) as span:
+            with telemetry.scope(names.SERVE_ADMIT):
+                pass
+            span.set(phase="mixed", live_tokens=9)
+        with telemetry.scope(names.TRAIN_MICRO, phase="forward", step=3):
+            pass
+        record = telemetry.end_step()
+        assert set(record["phases"]) == {"mixed", names.SERVE_ADMIT,
+                                         "forward"}
+        events = {e["name"]: e for e in rec.chrome_trace()["traceEvents"]}
+        assert events["mixed"]["args"] == {"step": 3, "live_tokens": 9}
+        assert events["mixed"]["cat"] == "serve"
+        assert events["forward"]["args"] == {"step": 3}
+    finally:
+        telemetry.shutdown()
+
+
+def test_named_program_gives_jit_its_module_name():
+    from deepspeed_tpu.inference.v2 import ragged_forward
+    from deepspeed_tpu.runtime.engine import _named_program
+    fn = _named_program(lambda x: x + 1,
+                        names.PROGRAM_MICRO + "overlap+prefetch")
+    assert fn.__name__ == "ds_micro_overlap_prefetch"
+    assert "jit_ds_micro_overlap_prefetch" in \
+        jax.jit(fn).lower(jnp.zeros(2)).as_text()
+    assert ragged_forward.llama_ragged_step.__name__ == \
+        names.PROGRAM_RAGGED_STEP + "llama"
+    assert ragged_forward.decode_burst.__name__ == names.PROGRAM_DECODE_BURST
+    # decode_burst inlines the step through the jitted wrapper's function
+    assert callable(ragged_forward.llama_ragged_step.__wrapped__)
+
+
+# ------------------------------------------------------------------ serving
+@pytest.fixture(scope="module")
+def tiny():
+    cfg = llama.llama_tiny(dtype="float32", remat=False,
+                           num_key_value_heads=2)
+    model = llama.LlamaModel(cfg)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    return model, params
+
+
+def _scheduler(tiny, num_blocks=96, decode_burst=4, **serving):
+    model, params = tiny
+    sm = dict(max_tracked_sequences=16, max_ragged_batch_size=32,
+              max_ragged_sequence_count=12, max_context=64, block_size=8,
+              num_blocks=num_blocks)
+    engine = InferenceEngineV2(
+        model, params=params,
+        config=dict(dtype="float32", decode_burst=decode_burst,
+                    state_manager=sm))
+    return ServingScheduler(engine, serving or None)
+
+
+def test_serving_steps_in_a_real_trace(tiny, tmp_path):
+    sched = _scheduler(tiny)
+    rng = np.random.default_rng(0)
+    uids = [sched.submit(rng.integers(1, 96, size=n).tolist(),
+                         max_new_tokens=6) for n in (40, 9, 17, 5)]
+    n_steps = 0
+    with Traced(tmp_path) as t:
+        while not sched.idle:
+            sched.step()
+            n_steps += 1
+        assert sched.step() == {}      # idle: no span
+    steps = t.named(names.SERVE_STEP)
+    assert len(steps) == n_steps
+    for name in names.SERVE_STEP_CHILDREN:
+        spans = t.named(name)
+        assert spans, name
+        assert all(any(t.inside(s, step) for step in steps) for s in spans)
+    kinds = set()
+    for step in steps:
+        c = step[3]
+        assert set(names.SERVE_STEP_COUNTS) <= set(c), c
+        kinds.add(c["kind"])
+        assert 0 < c["live_tokens"] <= c["token_budget"]
+        assert c["live_tokens"] == c["prefill_tokens"] + c["decode_tokens"]
+        assert 0 < c["live_pages"] <= c["grid_pages"]
+        assert (c["burst_k"] >= 2) == (c["kind"] == names.KIND_BURST)
+    assert kinds == {names.KIND_RAGGED, names.KIND_BURST}
+    # live_tokens is what the engine step consumed: every prompt token and
+    # every generated token but each request's last went through a step
+    total = sum(len(sched.query(u).prompt) + len(sched.query(u).produced) - 1
+                for u in uids)
+    assert sum(s[3]["live_tokens"] for s in steps) == total
+    # the grid: every budget row (flat layout), or every decode row and
+    # every prefill atom (a prefill-heavy step), times every page of the table
+    ragged = [s[3] for s in steps if s[3]["kind"] == names.KIND_RAGGED]
+    decode_cap, atom = sched.engine._atom_layout()
+    assert {c["grid_pages"] for c in ragged} == {
+        32 * (64 // 8), (decode_cap + (32 - decode_cap) // atom) * (64 // 8)}
+    assert {c["token_budget"] for c in ragged} == {32}
+    admitted = [e[3]["uid"] for e in t.named(names.SERVE_ADMITTED)]
+    assert sorted(admitted) == sorted(uids)
+    finished = {e[3]["uid"]: e[3]["tokens"]
+                for e in t.named(names.SERVE_FINISHED)}
+    assert finished == {u: 6 for u in uids}
+    assert not hasattr(sched, "_phase")
+
+
+def test_preemption_is_an_event_with_its_uid(tiny, tmp_path):
+    sched = _scheduler(tiny, num_blocks=15, decode_burst=0)
+    rng = np.random.default_rng(0)
+    for _ in range(8):
+        sched.submit(rng.integers(1, 96, size=8).tolist(), max_new_tokens=16)
+    with Traced(tmp_path) as t:
+        sched.drain()
+    assert sched.preemptions >= 1
+    events = t.named(names.SERVE_PREEMPTED)
+    assert len(events) == sched.preemptions
+    assert sum(s[3]["preempts"] for s in t.named(names.SERVE_STEP)) == \
+        sched.preemptions
+    # a preempted request is admitted again
+    assert len(t.named(names.SERVE_ADMITTED)) == 8 + sched.preemptions
+
+
+def test_page_counts_follow_the_layout_and_the_window(tiny):
+    engine = _scheduler(tiny).engine
+    pos = np.array([0, 7, 8, 30, 0, 0, 0, 0], np.int32)
+    live = np.array([1, 1, 1, 1, 0, 0, 0, 0], bool)
+    # flat: 8 rows x 8 pages; contexts span 1, 1, 2 and 4 pages
+    assert engine._page_counts(pos, live) == (64, 8)
+    # atoms of 2 behind 2 decode rows: 2 + 3 grid rows; an atom streams the
+    # pages of its deepest row
+    assert engine._page_counts(pos, live, layout=(2, 2)) == (40, 2 + 4)
+    # a burst: k rows of positions
+    assert engine._page_counts(pos[None, :4] + np.arange(2)[:, None],
+                               live[None, :4]) == (64, 1 + 2 + 2 + 4 + 8)
+
+
+# ----------------------------------------------------------------- training
+def _train_engine(extra=None):
+    groups.reset_mesh()
+    cfg = llama.llama_tiny(dtype="float32", remat=False)
+    config = {"train_micro_batch_size_per_gpu": 2,
+              "gradient_accumulation_steps": 2,
+              "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
+              "zero_optimization": {"stage": 2},
+              "mesh": {"dp": jax.device_count()}}
+    config.update(extra or {})
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=llama.LlamaModel(cfg), config=config)
+    ids = np.random.default_rng(0).integers(
+        0, cfg.vocab_size,
+        size=(2 * jax.device_count(), 16)).astype(np.int32)
+    engine.initialize_parameters(jax.random.PRNGKey(0), ids, ids)
+    return engine, ids
+
+
+def _train(engine, ids, micro_steps):
+    losses = []
+    for _ in range(micro_steps):
+        loss = engine(ids, ids)
+        engine.backward(loss)
+        engine.step()
+        losses.append(np.asarray(loss).tobytes())
+    return losses
+
+
+def test_engine_steps_in_a_real_trace_and_nothing_else_when_disabled(
+        tmp_path, monkeypatch):
+    monkeypatch.chdir(tmp_path)
+    from deepspeed_tpu.accelerator import get_accelerator
+    syncs = []
+    monkeypatch.setattr(type(get_accelerator()), "synchronize",
+                        lambda self, *a, **k: syncs.append(1))
+    engine, ids = _train_engine()
+    plain = _train(engine, ids, 2)          # compiles; one optimizer step
+    syncs.clear()
+    with Traced(tmp_path) as t:
+        traced = _train(engine, ids, 6)     # three optimizer steps
+    assert not telemetry.enabled and telemetry.get_recorder() is None
+    assert syncs == []                      # no device sync was bought
+    assert os.listdir(str(tmp_path)) == ["xplane"]      # and no file
+    for name in (names.TRAIN_SHARD_BATCH, names.TRAIN_MICRO,
+                 names.TRAIN_BACKWARD, names.TRAIN_ACCUMULATE):
+        spans = t.named(name)
+        assert [s[3]["micro_step"] for s in spans] == list(range(2, 8)), name
+        assert [s[3]["step"] for s in spans] == [1, 1, 2, 2, 3, 3]
+    for name in (names.TRAIN_APPLY, names.TRAIN_REPORT):
+        assert [(s[3]["step"], s[3]["micro_step"]) for s in t.named(name)] \
+            == [(1, 3), (2, 5), (3, 7)], name
+    assert set(names.TRAIN_SPANS) == {e[0] for e in t.events}
+    for acc, bwd in zip(t.named(names.TRAIN_ACCUMULATE),
+                        t.named(names.TRAIN_BACKWARD)):
+        assert t.inside(acc, bwd)
+    # the annotations change no number: the same engine, rebuilt, gives the
+    # same losses bit for bit with no profiler session open
+    engine2, _ = _train_engine()
+    assert _train(engine2, ids, 8) == plain + traced
+
+
+def test_enabled_recorder_gets_the_engine_spans_under_its_phase_names(
+        tmp_path):
+    tel = {"telemetry": {"enabled": True, "trace_dir": str(tmp_path),
+                         "device_profiler": True}}
+    engine, ids = _train_engine(tel)
+    try:
+        with_tel = _train(engine, ids, 4)
+    finally:
+        telemetry.shutdown()
+    engine2, _ = _train_engine()
+    assert _train(engine2, ids, 4) == with_tel
+    trace = json.load(open(os.path.join(str(tmp_path), TRACE_FILE)))
+    by_name = {}
+    for e in trace["traceEvents"]:
+        by_name.setdefault(e["name"], []).append(e)
+    # the documented phase columns, fed by the ds: spans, with their counts
+    for phase, n in (("forward", 4), ("backward", 4), ("grad_reduce", 4),
+                     ("optimizer", 2), (names.TRAIN_SHARD_BATCH, 4),
+                     (names.TRAIN_REPORT, 2)):
+        assert len(by_name[phase]) == n, phase
+        assert set(by_name[phase][0]["args"]) == {"step", "micro_step"}
+    assert [e["args"]["step"] for e in by_name["optimizer"]] == [0, 1]
+
+
+def test_enabled_recorder_gets_the_serving_step_with_its_counts(
+        tiny, tmp_path):
+    class Cfg:
+        trace_dir = str(tmp_path)
+
+    rec, _ = telemetry.configure(Cfg())
+    try:
+        sched = _scheduler(tiny, decode_burst=0)
+        sched.submit(list(range(1, 20)), max_new_tokens=3)
+        sched.drain()
+        events = rec.chrome_trace()["traceEvents"]
+    finally:
+        telemetry.shutdown()
+    steps = [e for e in events if e["cat"] == "serve"]
+    # the prompt may take two chunks (the prefill region of an atom layout)
+    phases = [e["name"] for e in steps]
+    assert phases[0] == "prefill" and phases[-2:] == ["decode", "decode"]
+    assert set(phases) == {"prefill", "decode"}
+    assert sum(e["args"]["prefill_tokens"] for e in steps) == 19
+    assert set(names.SERVE_STEP_COUNTS) <= set(steps[0]["args"])
+    children = {e["name"] for e in events} - {e["name"] for e in steps}
+    assert set(names.SERVE_STEP_CHILDREN) <= children
+
+
+# ------------------------------------------------------------------ kernels
+def _pallas_call_names():
+    found = []
+    base = os.path.join(ROOT, "deepspeed_tpu", "ops", "pallas")
+    for f in sorted(os.listdir(base)):
+        if not f.endswith(".py"):
+            continue
+        for node in ast.walk(ast.parse(open(os.path.join(base, f)).read())):
+            if isinstance(node, ast.Call) and \
+                    getattr(node.func, "attr", "") == "pallas_call":
+                kw = {k.arg: k.value for k in node.keywords}
+                assert "name" in kw, (f, node.lineno)
+                value = kw["name"]
+                consts = ([value.body, value.orelse]
+                          if isinstance(value, ast.IfExp) else [value])
+                for c in consts:
+                    assert isinstance(c, ast.Constant), (f, node.lineno)
+                    found.append((f, c.value))
+    return found
+
+
+def test_every_pallas_call_has_a_ds_name_listed_in_the_docs():
+    found = _pallas_call_names()
+    assert len({f + str(i) for i, (f, _) in enumerate(found)}) >= 16
+    kernel_names = [n for _, n in found]
+    assert len(set(kernel_names)) == len(kernel_names)
+    doc = open(os.path.join(ROOT, "docs", "kernels.md")).read()
+    for name in kernel_names:
+        assert name.startswith(names.KERNEL_PREFIX), name
+        assert f"`{name}`" in doc, f"{name} missing from docs/kernels.md"
+    assert sum(n.startswith(names.KERNEL_FLASH) for n in kernel_names) == 7
+    assert sum(n.startswith(names.KERNEL_PAGED) for n in kernel_names) == 2
+    assert sum(n.startswith(names.KERNEL_OPTIMIZER)
+               for n in kernel_names) == 4
+
+
+def test_names_reach_the_compiled_program_of_the_tiny_model():
+    """The scope path of the loss ops and of the attention kernel, as the
+    compiled program's text has them (the device trace carries the same)."""
+    cfg = llama.llama_tiny(dtype="float32", remat=True)
+    model = llama.LlamaModel(cfg)
+    ids = jnp.zeros((1, 16), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+
+    def loss(p):
+        return model.apply({"params": p}, ids, ids)
+
+    import re
+    text = jax.jit(jax.grad(loss)).lower(params).compile().as_text()
+    paths = set(re.findall(r'op_name="([^"]*)"', text))
+
+    def has(component, backward=False, remat=False):
+        return any(f"/{component}/" in p
+                   and (names.MARK_TRANSPOSE in p) == backward
+                   and (names.MARK_REMAT in p) == remat for p in paths)
+
+    for scope in (names.SCOPE_LM_HEAD_LOSS, names.SCOPE_EMBED):
+        assert has(scope) and has(scope, backward=True), scope
+    # the flax module names give attention and MLP; remat marks the
+    # recomputed forward (it runs inside the backward pass)
+    for module in (names.MODULE_ATTENTION, names.MODULE_MLP):
+        assert has(module) and has(module, backward=True), module
+        assert has(module, backward=True, remat=True), module
