@@ -376,36 +376,52 @@ class InferenceEngineV2:
         last_idx = np.zeros(sm.max_seqs, dtype=np.int32)
         for seq, idx in finishing:
             last_idx[seq.slot] = idx
-        grid_pages, live_pages = self._page_counts(pos, slots != 0, layout)
+        grid_pages, live_pages, row_pages = self._page_counts(pos, slots,
+                                                              layout)
         self.last_step_counts = {
             "kind": _names.KIND_RAGGED, "token_budget": T,
             "live_tokens": placed, "decode_tokens": placed_decode,
             "prefill_tokens": placed - placed_decode,
             "grid_pages": grid_pages, "live_pages": live_pages,
-            "burst_k": 0}
+            "row_pages": row_pages, "burst_k": 0}
         return toks, pos, slots, last_idx, finishing, layout
 
-    def _page_counts(self, pos, live, layout=(0, 0)):
-        """``(grid_pages, live_pages)`` of one paged-attention call over the
-        rows at positions ``pos`` (``live``: which rows hold a token).
-        ``grid_pages``: the (row, page) steps the kernel's grid visits —
-        every row (every atom, in a prefill region) times every page of the
-        block table's width, whatever the rows hold.  ``live_pages``: of
-        those, the pages a live row's context really spans (its sliding
-        window's, where the model has one).  Their ratio is the share of the
-        kernel's grid that is useful work."""
+    def _page_counts(self, pos, slots, layout=(0, 0)):
+        """``(grid_pages, live_pages, row_pages)`` of one paged-attention
+        call over the rows at positions ``pos`` in slots ``slots`` (0: a
+        dead row); ``[k, rows]`` arrays are the ``k`` calls of a burst.
+        ``grid_pages``: the K/V page loads the kernel's loops perform —
+        the run-tiled kernel's items (``paged_attention.run_plan``, the same
+        function the step program takes its loop bounds from), or, for an
+        atom region and for a shape left on the per-token kernel, every
+        grid row times every page of the block table.  ``live_pages``: of
+        those, the loads that hold a key some live row may see (all of the
+        run-tiled kernel's).  ``row_pages``: the (row, page) pairs the live
+        rows' contexts (their sliding windows) span — ``row_pages /
+        grid_pages`` is how many rows share one page load."""
+        from ...ops.pallas import paged_attention as _pa
         bs = self.kv_cache.block_size
         maxb = self.state_manager.block_table.shape[1]
-        window = int(getattr(self.model_config, "sliding_window", 0) or 0)
+        cfg = self.model_config
+        window = int(getattr(cfg, "sliding_window", 0) or 0)
+        pos, slots = np.atleast_2d(pos), np.atleast_2d(slots)
         first = np.maximum(pos - window + 1, 0) // bs if window else 0
-        pages = np.where(live, pos // bs + 1 - first, 0)
+        pages = np.where(slots != 0, pos // bs + 1 - first, 0)
         decode_cap, atom = layout
+        cut = decode_cap if atom else pos.shape[1]
+        if _pa.run_tiled(cfg.num_key_value_heads, cfg.head_dim,
+                         self.kv_cache.data.dtype):
+            tq = _pa.tile_rows(cfg.num_attention_heads,
+                               cfg.num_key_value_heads, cut)
+            grid = live = _pa.page_loads(slots[:, :cut], pos[:, :cut], tq,
+                                         bs, window)
+        else:
+            grid, live = pages[:, :cut].size * maxb, pages[:, :cut].sum()
         if atom:
             # one grid row an atom: it streams the pages of its deepest row
-            tiles = pages[decode_cap:].reshape(-1, atom).max(axis=1)
-            return ((decode_cap + len(tiles)) * maxb,
-                    int(pages[:decode_cap].sum() + tiles.sum()))
-        return pages.size * maxb, int(pages.sum())
+            tiles = pages[:, cut:].reshape(-1, atom).max(axis=1)
+            grid, live = grid + len(tiles) * maxb, live + tiles.sum()
+        return int(grid), int(live), int(pages.sum())
 
     @staticmethod
     def _sample_row(row, temperature, top_k, top_p, rng):
@@ -587,15 +603,17 @@ class InferenceEngineV2:
                 tok0[seq.slot] = seq.tokens[seq.seen_tokens]
                 pos0[seq.slot] = seq.seen_tokens
                 act[seq.slot] = True
-            # k iterations over max_seqs rows each, one token a live row
-            grid_pages, live_pages = self._page_counts(
-                pos0[None, :] + np.arange(k)[:, None], act[None, :])
+            # k iterations over max_seqs rows each, one token a live row:
+            # row i is slot i (ragged_forward.decode_burst)
+            grid_pages, live_pages, row_pages = self._page_counts(
+                pos0[None, :] + np.arange(k)[:, None],
+                np.broadcast_to(np.where(act, np.arange(n), 0), (k, n)))
             self.last_step_counts = {
                 "kind": _names.KIND_BURST, "token_budget": n * k,
                 "live_tokens": len(seqs) * k,
                 "decode_tokens": len(seqs) * k, "prefill_tokens": 0,
                 "grid_pages": grid_pages, "live_pages": live_pages,
-                "burst_k": k}
+                "row_pages": row_pages, "burst_k": k}
         from .ragged_forward import decode_burst
         if sample:
             if getattr(self, "_burst_key", None) is None or \

@@ -57,22 +57,25 @@ def _rmsnorm(x, w, eps):
     return (x32 * jax.lax.rsqrt(var + eps) * w).astype(x.dtype)
 
 
-def _paged_attention(q, k_cache, v_cache, tables_t, positions, block_size,
-                     window=0, layout=(0, 0), use_kernel=True,
+def _paged_attention(q, k_cache, v_cache, block_tables, seq_slots, positions,
+                     block_size, window=0, layout=(0, 0), use_kernel=True,
                      kv_scales=None):
-    """q: [T, H, Dh]; caches: [num_blocks, bs, Hkv, Dh]; tables_t: [T, maxb];
-    positions: [T]; window: sliding-window size (0 → full causal).
-    Returns [T, H, Dh].
+    """q: [T, H, Dh]; caches: [num_blocks, bs, Hkv, Dh]; block_tables:
+    [max_seqs, maxb]; seq_slots, positions: [T]; window: sliding-window size
+    (0 → full causal).  Returns [T, H, Dh].
 
-    On TPU: the Pallas paged kernel (block pages streamed through VMEM via
-    scalar-prefetched table indices).  ``layout=(decode_cap, atom)`` > (0,0)
-    means the buffer is region-split by the batch builder: per-token kernel
-    for the first ``decode_cap`` rows, atom-tiled kernel (``atom``
-    same-sequence rows per tile — much better MXU occupancy for prefill)
-    for the rest.  Off a TPU (and at ``use_kernel=False``): XLA gather of
+    On TPU: the Pallas paged kernels.  The flat layout takes the run-tiled
+    kernel (``ops/pallas/paged_attention.paged_attention``): the rows of one
+    sequence share each page load, and only live pages are visited.
+    ``layout=(decode_cap, atom)`` > (0,0) means the buffer is region-split
+    by the batch builder: the same kernel for the first ``decode_cap`` rows,
+    the atom-tiled kernel (``atom`` same-sequence rows per tile) for the
+    rest.  Off a TPU (and at ``use_kernel=False``): XLA gather of
     each token's block run with position masking — chosen by
     ``ops/_use_kernels.use_pallas_kernels``, the same gate as every other
-    kernel dispatch site.
+    kernel dispatch site.  (A dead row, slot 0, comes back zero from the
+    run-tiled kernel and as attention over the garbage block from the
+    others; nothing reads it.)
 
     ``kv_scales=(k_scales, v_scales)`` ([num_blocks, bs, Hkv] f32 each) is
     the quantized-KV read path: the caches hold int8/fp8 rows and only the
@@ -84,17 +87,17 @@ def _paged_attention(q, k_cache, v_cache, tables_t, positions, block_size,
         from ...ops.pallas.paged_attention import (paged_attention,
                                                    paged_attention_atoms)
         decode_cap, atom = layout
-        if atom and q.shape[0] > decode_cap:
-            out_d = paged_attention(q[:decode_cap], k_cache, v_cache,
-                                    tables_t[:decode_cap],
-                                    positions[:decode_cap], window=window) \
-                if decode_cap else q[:0]
-            out_p = paged_attention_atoms(
-                q[decode_cap:], k_cache, v_cache, tables_t[decode_cap:],
-                positions[decode_cap:], atom, window=window)
-            return jnp.concatenate([out_d, out_p], axis=0)
-        return paged_attention(q, k_cache, v_cache, tables_t, positions,
-                               window=window)
+        cut = decode_cap if atom and q.shape[0] > decode_cap else None
+        out = paged_attention(q[:cut], k_cache, v_cache, block_tables,
+                              seq_slots[:cut], positions[:cut],
+                              window=window)
+        if cut is None:
+            return out
+        out_p = paged_attention_atoms(
+            q[cut:], k_cache, v_cache, block_tables[seq_slots[cut:]],
+            positions[cut:], atom, window=window)
+        return jnp.concatenate([out, out_p], axis=0)
+    tables_t = block_tables[seq_slots]
     T, H, Dh = q.shape
     Hkv = k_cache.shape[2]
     maxb = tables_t.shape[1]
@@ -184,8 +187,8 @@ def _kv_set(kv_data, l, kv_layer):
 
 
 @jax.named_scope(_names.SCOPE_ATTENTION)
-def _ragged_attention_block(lp_attn, h, kv_layer, blk, off, tables_t,
-                            positions, cos, sin, *, cfg, block_size,
+def _ragged_attention_block(lp_attn, h, kv_layer, blk, off, block_tables,
+                            seq_slots, positions, cos, sin, *, cfg, block_size,
                             rotary=True, rotary_dim=None,
                             layout=(0, 0), use_kernel=True, kv_dtype=None):
     """Shared attention sub-block: qkv → rotary → cache scatter → paged
@@ -227,7 +230,7 @@ def _ragged_attention_block(lp_attn, h, kv_layer, blk, off, tables_t,
         kv_layer = (data, scales)
         k_cache, v_cache = data[0], data[1]
         kv_scales = (scales[0], scales[1])
-    out = _paged_attention(q, k_cache, v_cache, tables_t,
+    out = _paged_attention(q, k_cache, v_cache, block_tables, seq_slots,
                            positions, block_size,
                            window=getattr(cfg, "sliding_window", 0),
                            layout=layout, use_kernel=use_kernel,
@@ -267,9 +270,7 @@ def llama_ragged_step(params, kv_data, token_ids, positions, seq_slots,
 
     with jax.named_scope(_names.SCOPE_EMBED):
         x = params["embed_tokens"]["embedding"][token_ids].astype(dtype)
-    tables_t = block_tables[seq_slots]                       # [T, maxb]
-    blk = tables_t[jnp.arange(token_ids.shape[0]),
-                   positions // block_size]                  # [T]
+    blk = block_tables[seq_slots, positions // block_size]   # [T]
     off = positions % block_size
 
     for l in range(cfg.num_hidden_layers):
@@ -279,9 +280,9 @@ def llama_ragged_step(params, kv_data, token_ids, positions, seq_slots,
         # scatter this batch's K/V into the paged cache (linear_blocked_kv_
         # rotary analog), then attend against the updated pages
         attn_out, kv_layer = _ragged_attention_block(
-            lp["self_attn"], h, _kv_layer(kv_data, l), blk, off, tables_t, positions,
-            cos, sin, cfg=cfg, block_size=block_size, layout=layout,
-            use_kernel=use_kernel, kv_dtype=kv_dtype)
+            lp["self_attn"], h, _kv_layer(kv_data, l), blk, off, block_tables,
+            seq_slots, positions, cos, sin, cfg=cfg, block_size=block_size,
+            layout=layout, use_kernel=use_kernel, kv_dtype=kv_dtype)
         kv_data = _kv_set(kv_data, l, kv_layer)
         x = x + attn_out
         h2 = _rmsnorm(x, lp["post_attention_layernorm"]["weight"], eps)
@@ -324,18 +325,16 @@ def mixtral_ragged_step(params, kv_data, token_ids, positions, seq_slots,
 
     with jax.named_scope(_names.SCOPE_EMBED):
         x = params["embed_tokens"]["embedding"][token_ids].astype(dtype)
-    tables_t = block_tables[seq_slots]
-    blk = tables_t[jnp.arange(token_ids.shape[0]),
-                   positions // block_size]
+    blk = block_tables[seq_slots, positions // block_size]
     off = positions % block_size
 
     for l in range(cfg.num_hidden_layers):
         lp = params[f"layers_{l}"]
         h = _rmsnorm(x, lp["input_layernorm"]["weight"], eps)
         attn_out, kv_layer = _ragged_attention_block(
-            lp["self_attn"], h, _kv_layer(kv_data, l), blk, off, tables_t, positions,
-            cos, sin, cfg=cfg, block_size=block_size, layout=layout,
-            use_kernel=use_kernel, kv_dtype=kv_dtype)
+            lp["self_attn"], h, _kv_layer(kv_data, l), blk, off, block_tables,
+            seq_slots, positions, cos, sin, cfg=cfg, block_size=block_size,
+            layout=layout, use_kernel=use_kernel, kv_dtype=kv_dtype)
         kv_data = _kv_set(kv_data, l, kv_layer)
         x = x + attn_out
         h2 = _rmsnorm(x, lp["post_attention_layernorm"]["weight"], eps)
@@ -389,8 +388,7 @@ def falcon_ragged_step(params, kv_data, token_ids, positions, seq_slots,
 
     with jax.named_scope(_names.SCOPE_EMBED):
         x = params["word_embeddings"]["embedding"][token_ids].astype(dtype)
-    tables_t = block_tables[seq_slots]
-    blk = tables_t[jnp.arange(token_ids.shape[0]), positions // block_size]
+    blk = block_tables[seq_slots, positions // block_size]
     off = positions % block_size
     acfg = _attn_cfg_view(cfg)
 
@@ -404,10 +402,9 @@ def falcon_ragged_step(params, kv_data, token_ids, positions, seq_slots,
         attn_params = {"q_proj": lp["q_proj"], "k_proj": lp["k_proj"],
                        "v_proj": lp["v_proj"], "o_proj": lp["dense"]}
         attn_out, kv_layer = _ragged_attention_block(
-            attn_params, h_attn, _kv_layer(kv_data, l), blk, off, tables_t,
-            positions,
-            cos, sin, cfg=acfg, block_size=block_size, layout=layout,
-            use_kernel=use_kernel, kv_dtype=kv_dtype)
+            attn_params, h_attn, _kv_layer(kv_data, l), blk, off, block_tables,
+            seq_slots, positions, cos, sin, cfg=acfg, block_size=block_size,
+            layout=layout, use_kernel=use_kernel, kv_dtype=kv_dtype)
         kv_data = _kv_set(kv_data, l, kv_layer)
         if not cfg.parallel_attn:
             x = x + attn_out
@@ -438,8 +435,7 @@ def opt_ragged_step(params, kv_data, token_ids, positions, seq_slots,
         x = (params["embed_tokens"]["embedding"][token_ids]
              + params["embed_positions"]["embedding"][
                  positions + OPT_POSITION_OFFSET]).astype(dtype)
-    tables_t = block_tables[seq_slots]
-    blk = tables_t[jnp.arange(token_ids.shape[0]), positions // block_size]
+    blk = block_tables[seq_slots, positions // block_size]
     off = positions % block_size
     acfg = _attn_cfg_view(cfg)
 
@@ -450,9 +446,10 @@ def opt_ragged_step(params, kv_data, token_ids, positions, seq_slots,
         attn_params = {"q_proj": lp["q_proj"], "k_proj": lp["k_proj"],
                        "v_proj": lp["v_proj"], "o_proj": lp["out_proj"]}
         attn_out, kv_layer = _ragged_attention_block(
-            attn_params, h, _kv_layer(kv_data, l), blk, off, tables_t, positions,
-            None, None, cfg=acfg, block_size=block_size, rotary=False,
-            layout=layout, use_kernel=use_kernel, kv_dtype=kv_dtype)
+            attn_params, h, _kv_layer(kv_data, l), blk, off, block_tables,
+            seq_slots, positions, None, None, cfg=acfg, block_size=block_size,
+            rotary=False, layout=layout, use_kernel=use_kernel,
+            kv_dtype=kv_dtype)
         kv_data = _kv_set(kv_data, l, kv_layer)
         x = x + attn_out
         if not cfg.do_layer_norm_before:
@@ -486,8 +483,7 @@ def phi_ragged_step(params, kv_data, token_ids, positions, seq_slots,
 
     with jax.named_scope(_names.SCOPE_EMBED):
         x = params["embed_tokens"]["embedding"][token_ids].astype(dtype)
-    tables_t = block_tables[seq_slots]
-    blk = tables_t[jnp.arange(token_ids.shape[0]), positions // block_size]
+    blk = block_tables[seq_slots, positions // block_size]
     off = positions % block_size
     acfg = _attn_cfg_view(cfg)
 
@@ -497,8 +493,9 @@ def phi_ragged_step(params, kv_data, token_ids, positions, seq_slots,
         attn_params = {"q_proj": lp["q_proj"], "k_proj": lp["k_proj"],
                        "v_proj": lp["v_proj"], "o_proj": lp["dense"]}
         attn_out, kv_layer = _ragged_attention_block(
-            attn_params, h, _kv_layer(kv_data, l), blk, off, tables_t, positions,
-            cos, sin, cfg=acfg, block_size=block_size, rotary_dim=rd,
+            attn_params, h, _kv_layer(kv_data, l), blk, off, block_tables,
+            seq_slots, positions, cos, sin, cfg=acfg, block_size=block_size,
+            rotary_dim=rd,
             layout=layout, use_kernel=use_kernel, kv_dtype=kv_dtype)
         kv_data = _kv_set(kv_data, l, kv_layer)
         with jax.named_scope(_names.SCOPE_MLP):

@@ -2,14 +2,24 @@
 analog (reference ``inference/v2/kernels/ragged_ops/blocked_flash`` +
 ``linear_blocked_kv_rotary``).
 
-One grid row per ragged-batch token; the token's KV *pages* are streamed
-through VMEM in block-table order using scalar-prefetched indices (the
-``PrefetchScalarGridSpec`` pattern: the block index map reads the table, so
-the pipeline DMAs exactly the pages this token owns), with the online-softmax
-state in VMEM scratch.  GQA is expressed in the index math (no repeated KV).
+:func:`paged_attention` is the flat layout's kernel.  It tiles the token
+buffer by RUNS: the rows one sequence gets in a step are contiguous and their
+positions consecutive (``engine_v2._build_batch``), so every row of a run
+attends to a K/V page from ONE load of it.  The grid walks Q tiles of ``TQ``
+buffer rows; inside a tile a loop with a dynamic trip count visits only the
+(run, page) items that hold a key some live row may see, K/V stay in HBM and
+each page arrives by a double-buffered DMA whose block id is read from the
+scalar-prefetched block table.  The online-softmax state is per row and lives
+across the items of a tile, so a tile of 32 decode rows of 32 sequences is as
+exact as one prefill chunk.  GQA is expressed in the index math (no repeated
+KV): the wrapper hands the kernel ``q`` as ``[Hkv, TQ * g, Dh]``.
+
+:func:`paged_attention_atoms` is the older grid of one row (or one aligned
+atom of rows) times every page of the table; the shapes the run-tiled kernel
+does not take (:func:`run_tiled`) keep it with ``atom == 1``.
 
 The XLA fallback (``inference/v2/ragged_forward._paged_attention``) computes
-the same math by gather; this kernel replaces it on TPU where the gather's
+the same math by gather; the kernels replace it on TPU where the gather's
 HBM blowup ([T, max_ctx, ...]) matters.
 """
 
@@ -17,6 +27,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -26,15 +37,251 @@ _NEG_INF = float("-inf")
 from ._common import interpret_mode as _interpret
 
 
-def paged_attention(q, k_cache, v_cache, tables_t, positions,
-                    block_size=None, window=0):
-    """q: [T, H, Dh]; caches: [num_blocks, bs, Hkv, Dh];
-    tables_t: [T, maxb] int32; positions: [T] int32 → [T, H, Dh].
+# ---------------------------------------------------- run-tiled (flat) path
+def run_tiled(kv_heads, head_dim, kv_dtype):
+    """Whether the run-tiled kernel takes this shape — by the shape alone
+    (docs/kernels.md lists what is left on the per-token kernel).  A K/V page
+    ``[bs, Hkv, Dh]`` is read per KV head by a sublane-strided load of its
+    ``[bs * Hkv, Dh]`` view; a 16-bit cache packs two heads a sublane, so the
+    heads have to pair up and the packed view has to tile."""
+    if kv_dtype not in (jnp.float32, jnp.bfloat16):
+        return False
+    sublanes, odd = divmod(kv_heads, 4 // jnp.dtype(kv_dtype).itemsize)
+    return not odd and head_dim % 128 == 0 and (
+        sublanes in (1, 2, 4, 8) or sublanes % 8 == 0)
 
-    One token per grid row — exactly the atom-tiled kernel with atom=1
-    (one shared online-softmax implementation; see _atom_kernel)."""
-    return paged_attention_atoms(q, k_cache, v_cache, tables_t,
-                                 positions, 1, window=window)
+
+def tile_rows(heads, kv_heads, tokens):
+    """``TQ``: the buffer rows of one Q tile, from the shapes alone.  Every
+    item of a tile pays for all its ``TQ * g`` MXU rows per KV head, and an
+    item's cost is mostly fixed, so small tiles win although they load a
+    long run's pages more often (docs/kernels.md has the v5e readings)."""
+    g = heads // kv_heads
+    return min(max(32, 64 // g // 8 * 8), -(-tokens // 8) * 8)
+
+
+def run_plan(xp, seq_slots, positions, tq, block_size, window=0):
+    """The loop bounds of the run-tiled kernel, as arrays — with ``xp`` numpy
+    on the host (``InferenceEngineV2._page_counts``) and jax.numpy inside the
+    step program, so that what is counted is what runs.
+
+    ``seq_slots``/``positions``: ``[T]`` (or ``[B, T]``: B calls).  A RUN is
+    a stretch of live rows (slot != 0) inside one tile with one slot and
+    consecutive positions.  Returns, per tile: ``pos``, ``rid [n, tq]`` each
+    row's position and run (-1: dead row), and per run ``run_slot``,
+    ``first_page``, ``n_pages [n, tq]`` (runs compacted to the front, 0
+    pages past the last run)."""
+    T = seq_slots.shape[-1]
+    pad = -T % tq
+    slots, pos = (xp.pad(a.reshape(-1, T).astype(xp.int32),
+                         ((0, 0), (0, pad))).reshape(-1, tq)
+                  for a in (seq_slots, positions))
+    live = slots != 0
+    edge = xp.full((slots.shape[0], 1), -1, xp.int32)
+    start = live & ((slots != xp.concatenate([edge, slots[:, :-1]], 1))
+                    | (pos != xp.concatenate([edge, pos[:, :-1]], 1) + 1))
+    rid = xp.where(live, xp.cumsum(start, axis=1) - 1, -1).astype(xp.int32)
+    member = rid[:, None, :] == xp.arange(tq, dtype=xp.int32)[None, :, None]
+    first = member & start[:, None, :]
+    n_rows = member.sum(-1).astype(xp.int32)
+    run_slot = (first * slots[:, None, :]).sum(-1).astype(xp.int32)
+    first_pos = (first * pos[:, None, :]).sum(-1).astype(xp.int32)
+    first_page = (xp.maximum(first_pos - window + 1, 0) // block_size
+                  if window else xp.zeros_like(first_pos))
+    n_pages = xp.where(n_rows > 0, (first_pos + n_rows - 1) // block_size
+                       + 1 - first_page, 0).astype(xp.int32)
+    return pos, rid, run_slot, first_page.astype(xp.int32), n_pages
+
+
+def page_loads(seq_slots, positions, tq, block_size, window=0):
+    """Host-side count of the K/V page loads one :func:`paged_attention`
+    call performs (each brings one K and one V page)."""
+    return int(run_plan(np, np.asarray(seq_slots), np.asarray(positions),
+                        tq, block_size, window)[-1].sum())
+
+
+def _head_pages(buf, kv_heads, block_size):
+    """The float32 ``[bs, Dh]`` page of every KV head, from the VMEM page
+    ``buf [bs, Hkv, Dh]`` — one sublane-strided load a head; a bfloat16 page
+    is read as uint32 words that hold two heads each, and a bfloat16 IS the
+    high half of its float32."""
+    rows = buf.reshape(block_size * kv_heads, buf.shape[-1])
+    if buf.dtype == jnp.float32:
+        if kv_heads == 1:
+            return [rows[...]]
+        return [rows[pl.ds(h, block_size, stride=kv_heads), :]
+                for h in range(kv_heads)]
+    words = rows.bitcast(jnp.uint32)
+    out = []
+    for j in range(kv_heads // 2):
+        w = (words[...] if kv_heads == 2 else
+             words[pl.ds(j, block_size, stride=kv_heads // 2), :])
+        out.append(pltpu.bitcast(w << 16, jnp.float32))
+        out.append(pltpu.bitcast(w & jnp.uint32(0xFFFF0000), jnp.float32))
+    return out
+
+
+def _run_kernel(tables_ref, slot_ref, first_ref, npages_ref, total_ref,
+                q_ref, pos_ref, rid_ref, k_hbm, v_hbm, o_ref, *rest,
+                tq, block_size, maxb, scale, window, count_loads):
+    """One Q tile: ``q_ref [1, Hkv, M, Dh]`` (``M = tq * g`` rows, row
+    ``t * g + gi``), ``pos_ref``/``rid_ref [1, M, 1]`` each row's position
+    and run, against the tile's items ``(run k, page p)``."""
+    if count_loads:
+        loads_ref, *rest = rest
+    k_buf, v_buf, sem, acc_ref, m_ref, l_ref = rest
+    i = pl.program_id(0)
+    base = i * tq
+    total = total_ref[i]
+    kv_heads, M = acc_ref.shape[:2]
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    def copies(k, p, buf):
+        blk = tables_ref[slot_ref[base + k] * maxb + first_ref[base + k] + p]
+        return (pltpu.make_async_copy(k_hbm.at[blk], k_buf.at[buf],
+                                      sem.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[blk], v_buf.at[buf],
+                                      sem.at[1, buf]))
+
+    @pl.when(total > 0)
+    def _first():
+        for c in copies(0, 0, 0):
+            c.start()
+
+    def item(it, carry):
+        k, p, loaded = carry
+        buf = it % 2
+        last = p + 1 == npages_ref[base + k]
+        k_next = jnp.where(last, k + 1, k)
+        p_next = jnp.where(last, 0, p + 1)
+
+        @pl.when(it + 1 < total)
+        def _prefetch():
+            for c in copies(k_next, p_next, 1 - buf):
+                c.start()
+
+        for c in copies(k, p, buf):
+            c.wait()
+
+        pos = pos_ref[0]                                   # [M, 1]
+        col = (first_ref[base + k] + p) * block_size + \
+            jax.lax.broadcasted_iota(jnp.int32, (M, block_size), 1)
+        mask = jnp.logical_and(rid_ref[0] == k, col <= pos)
+        if window:  # sliding window: only the last `window` positions
+            mask = jnp.logical_and(mask, col > pos - window)
+        k_pages = _head_pages(k_buf.at[buf], kv_heads, block_size)
+        v_pages = _head_pages(v_buf.at[buf], kv_heads, block_size)
+        for h in range(kv_heads):
+            q = q_ref[0, h].astype(jnp.float32)            # [M, Dh]
+            s = jax.lax.dot_general(
+                q, k_pages[h], (((1, ), (1, )), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [M, bs]
+            s = jnp.where(mask, s, _NEG_INF)
+            m_prev = m_ref[h, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            m_safe = jnp.where(m_new == _NEG_INF, 0.0, m_new)
+            e = jnp.where(mask, jnp.exp(s - m_safe), 0.0)
+            alpha = jnp.where(m_prev == _NEG_INF, 0.0,
+                              jnp.exp(m_prev - m_safe))
+            l_new = alpha * l_ref[h, :, :1] + jnp.sum(e, axis=1,
+                                                      keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
+                e, v_pages[h], preferred_element_type=jnp.float32)
+            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+        return k_next, p_next, loaded + 1
+
+    *_, loaded = jax.lax.fori_loop(0, total, item, (jnp.int32(0), ) * 3)
+    if count_loads:
+        loads_ref[0, 0] = loaded
+
+    for h in range(kv_heads):
+        l = l_ref[h, :, :1]
+        o_ref[0, h] = (acc_ref[h] / jnp.where(l == 0.0, 1.0, l)) \
+            .astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "count_loads"))
+def paged_attention(q, k_cache, v_cache, block_tables, seq_slots, positions,
+                    window=0, count_loads=False):
+    """q: [T, H, Dh]; caches: [num_blocks, bs, Hkv, Dh]; block_tables:
+    [max_seqs, maxb] int32; seq_slots, positions: [T] int32 → [T, H, Dh].
+
+    Row ``t`` attends to the keys at positions ``<= positions[t]`` (inside
+    ``window``, if any) of the sequence in slot ``seq_slots[t]``.  Slot 0 is
+    the dead row's: it attends to nothing and comes back zero.  Exact for
+    any rows; FAST when the rows of a sequence are contiguous with
+    consecutive positions, since a run shares each page load
+    (:func:`run_plan`).  ``count_loads=True`` also returns the page loads
+    each tile performed (``[n_tiles]``; tests compare :func:`page_loads`).
+
+    A shape :func:`run_tiled` refuses keeps one grid row a token."""
+    T, H, Dh = q.shape
+    _, bs, Hkv, _ = k_cache.shape
+    if not run_tiled(Hkv, Dh, k_cache.dtype):
+        if count_loads:
+            raise ValueError("count_loads needs the run-tiled kernel")
+        out = paged_attention_atoms(q, k_cache, v_cache,
+                                    block_tables[seq_slots], positions, 1,
+                                    window=window)
+        return jnp.where((seq_slots != 0)[:, None, None], out, 0)
+    maxb = block_tables.shape[1]
+    g = H // Hkv
+    tq = tile_rows(H, Hkv, T)
+    M = tq * g
+    pos, rid, run_slot, first_page, n_pages = run_plan(
+        jnp, seq_slots, positions, tq, bs, int(window))
+    n = rid.shape[0]
+
+    def rows(a):            # [n, tq] → [n, M, 1]: a token's g rows adjacent
+        return jnp.repeat(a, g, axis=1)[:, :, None]
+
+    qt = jnp.pad(q, ((0, n * tq - T), (0, 0), (0, 0))) \
+        .reshape(n, tq, Hkv, g, Dh).transpose(0, 2, 1, 3, 4) \
+        .reshape(n, Hkv, M, Dh)
+    tile = lambda *block: pl.BlockSpec(
+        (1, ) + block, lambda i, *_: (i, ) + (0, ) * len(block))
+    out_shape = [jax.ShapeDtypeStruct((n, Hkv, M, Dh), q.dtype)]
+    out_specs = [tile(Hkv, M, Dh)]
+    if count_loads:
+        out_shape.append(jax.ShapeDtypeStruct((n, 1), jnp.int32))
+        out_specs.append(pl.BlockSpec((1, 1), lambda i, *_: (i, 0),
+                                      memory_space=pltpu.SMEM))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(n, ),
+        in_specs=[tile(Hkv, M, Dh), tile(M, 1), tile(M, 1),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=out_specs,
+        scratch_shapes=[
+            pltpu.VMEM((2, bs, Hkv, Dh), k_cache.dtype),
+            pltpu.VMEM((2, bs, Hkv, Dh), v_cache.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((Hkv, M, Dh), jnp.float32),
+            pltpu.VMEM((Hkv, M, 128), jnp.float32),
+            pltpu.VMEM((Hkv, M, 128), jnp.float32),
+        ],
+    )
+    out, *loads = pl.pallas_call(
+        functools.partial(_run_kernel, tq=tq, block_size=bs, maxb=maxb,
+                          scale=Dh**-0.5, window=int(window),
+                          count_loads=count_loads),
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", )),
+        interpret=_interpret(),
+        name="ds_paged_runs",
+    )(block_tables.reshape(-1).astype(jnp.int32), run_slot.reshape(-1),
+      first_page.reshape(-1), n_pages.reshape(-1), n_pages.sum(-1),
+      qt, rows(pos), rows(rid), k_cache, v_cache)
+    out = out.reshape(n, Hkv, tq, g, Dh).transpose(0, 2, 1, 3, 4) \
+        .reshape(n * tq, H, Dh)[:T]
+    return (out, loads[0][:, 0]) if count_loads else out
 
 
 # ------------------------------------------------------- atom (prefill) path

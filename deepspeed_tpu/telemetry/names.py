@@ -56,7 +56,8 @@ KIND_BURST = "burst"
 #: counts); the batch builder makes them, the scheduler's span carries them
 SERVE_STEP_COUNTS = ("step", "kind", "running", "queued", "token_budget",
                      "live_tokens", "prefill_tokens", "decode_tokens",
-                     "grid_pages", "live_pages", "burst_k", "preempts")
+                     "grid_pages", "live_pages", "row_pages", "burst_k",
+                     "preempts")
 
 # ---- jitted programs (``XLA Modules`` events are ``jit_<name>(<id>)``)
 PROGRAM_MICRO = "ds_micro_"               # + the micro-step variant
@@ -82,7 +83,7 @@ MODULE_MLP = "mlp"
 # ---- Pallas kernels: ``pallas_call(name=...)`` prefixes by family
 KERNEL_PREFIX = "ds_"
 KERNEL_FLASH = "ds_flash_"                # fwd, bwd_dq, bwd_dkv (+ _bias_)
-KERNEL_PAGED = "ds_paged_"                # decode (per token), atom (tiled)
+KERNEL_PAGED = "ds_paged_"                # runs (flat), decode (per token), atom
 KERNEL_OPTIMIZER = "ds_fused_"            # adam, lion, lamb_phase1/2
 
 #: JAX's own markers in a scope path

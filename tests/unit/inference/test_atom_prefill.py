@@ -161,8 +161,11 @@ def test_atom_kernel_matches_per_token():
     pos = np.array([8, 9, 10, 11, 12, 13, 0, 0, 0, 1, 2, 3], np.int32)
     out_atom = paged_attention_atoms(q, kc, vc, jnp.asarray(tables),
                                      jnp.asarray(pos), atom)
-    out_tok = paged_attention(q, kc, vc, jnp.asarray(tables),
-                              jnp.asarray(pos))
+    # the flat kernel takes the table by slot: rows 0-7 are slot 1's,
+    # rows 8-11 slot 2's (the pads too: they are masked out below)
+    out_tok = paged_attention(
+        q, kc, vc, jnp.asarray(tables[[0, 0, 8]]),
+        jnp.asarray([1] * 8 + [2] * 4, jnp.int32), jnp.asarray(pos))
     real = np.ones(T, bool)
     real[6:8] = False  # intra-atom pads
     np.testing.assert_allclose(np.asarray(out_atom)[real],
@@ -187,8 +190,9 @@ def test_paged_kernel_sliding_window(atom):
     pos = np.arange(28, 36).astype(np.int32)
     out_k = paged_attention_atoms(q, kc, vc, jnp.asarray(tables),
                                   jnp.asarray(pos), atom, window=W)
-    ref = _paged_attention(q, kc, vc, jnp.asarray(tables),
-                           jnp.asarray(pos), block_size=bs, window=W)
+    ref = _paged_attention(q, kc, vc, jnp.asarray(tables[:2]),
+                           jnp.ones(T, jnp.int32), jnp.asarray(pos),
+                           block_size=bs, window=W)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
 

@@ -172,10 +172,12 @@ def test_pallas_paged_attention_matches_fallback():
     q = jnp.asarray(rng.standard_normal((T, H, Dh)), jnp.float32)
     kc = jnp.asarray(rng.standard_normal((nb, bs, Hkv, Dh)), jnp.float32)
     vc = jnp.asarray(rng.standard_normal((nb, bs, Hkv, Dh)), jnp.float32)
-    tables = jnp.asarray(rng.integers(1, nb, (T, maxb)), jnp.int32)
+    # row t is the one token of slot t + 1 (slot 0 is the dead row's)
+    tables = jnp.asarray(rng.integers(1, nb, (T + 1, maxb)), jnp.int32)
+    slots = jnp.arange(1, T + 1, dtype=jnp.int32)
     positions = jnp.asarray([0, 3, 7, 10, 15, 23], jnp.int32)
-    out_k = paged_attention(q, kc, vc, tables, positions)
-    out_x = _paged_attention(q, kc, vc, tables, positions, bs)
+    out_k = paged_attention(q, kc, vc, tables, slots, positions)
+    out_x = _paged_attention(q, kc, vc, tables, slots, positions, bs)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_x),
                                atol=2e-5, rtol=2e-5)
 

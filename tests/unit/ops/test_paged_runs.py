@@ -1,0 +1,193 @@
+"""The run-tiled paged-attention kernel (``ops/pallas/paged_attention.
+paged_attention``, interpret mode): equal to the XLA gather on every row a
+sequence owns, zero on dead rows, and its page loads are the ones
+``run_plan`` counts: the count ``InferenceEngineV2._page_counts`` reports."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.inference.v2 import InferenceEngineV2
+from deepspeed_tpu.inference.v2 import ragged_forward
+from deepspeed_tpu.inference.v2.ragged_forward import _paged_attention
+from deepspeed_tpu.models import llama
+from deepspeed_tpu.ops.pallas.paged_attention import (page_loads,
+                                                      paged_attention,
+                                                      run_tiled, tile_rows)
+
+MAX_SEQS = 8
+
+
+def _case(heads, kv_heads, runs, T, bs=8, maxb=8, window=0,
+          dtype=jnp.float32, seed=0):
+    """``runs``: (slot, first position, rows, first buffer row) each; every
+    other row is dead (slot 0, position 0)."""
+    rng = np.random.default_rng(seed)
+    nb = 1 + MAX_SEQS * maxb
+    tables = np.zeros((MAX_SEQS, maxb), np.int32)
+    perm = rng.permutation(np.arange(1, nb))
+    slots, pos = np.zeros(T, np.int32), np.zeros(T, np.int32)
+    for slot, p0, n, at in runs:
+        # only the pages the sequence has reached are in its table
+        used = (p0 + n - 1) // bs + 1
+        tables[slot, :used] = perm[slot * maxb:slot * maxb + used]
+        slots[at:at + n] = slot
+        pos[at:at + n] = np.arange(p0, p0 + n)
+    q = jnp.asarray(rng.standard_normal((T, heads, 128)), dtype)
+    kc, vc = (jnp.asarray(rng.standard_normal((nb, bs, kv_heads, 128)), dtype)
+              for _ in range(2))
+    return q, kc, vc, jnp.asarray(tables), slots, pos
+
+
+CASES = {
+    # name: (heads, kv_heads, runs, T, kwargs, expected page loads)
+    # tiles of 32 rows; 40 rows at positions 3..42: pages 0-4, then 0-5
+    "gqa_32_8_prefill_crosses_a_tile": (
+        32, 8, [(1, 3, 40, 0)], 40, {}, 5 + 6),
+    "gqa_4_1": (4, 1, [(1, 0, 20, 0), (2, 17, 1, 20)], 24, {}, 3 + 3),
+    # tiles of 64 rows: MHA feeds the MXU one row a token
+    "mha_crosses_a_tile": (
+        4, 4, [(1, 0, 66, 0), (2, 9, 1, 66)], 72, {"maxb": 12}, 8 + 9 + 2),
+    "two_runs_and_dead_rows_in_one_tile": (
+        8, 2, [(1, 5, 9, 2), (2, 30, 6, 14)], 32, {}, 2 + 5),
+    "decode_rows_of_different_sequences": (
+        8, 2, [(s, 7 * s, 1, s - 1) for s in range(1, 8)], 8, {},
+        sum(7 * s // 8 + 1 for s in range(1, 8))),
+    "tokens_not_a_multiple_of_the_tile": (
+        8, 2, [(3, 0, 37, 0), (4, 11, 1, 37)], 43, {}, 4 + 5 + 2),
+    # window 11: positions 28..35 see keys 18..35 = pages 2..4 of 0..4
+    "window_kills_leading_pages": (
+        8, 2, [(1, 28, 8, 0), (2, 50, 1, 8)], 16, {"window": 11}, 3 + 2),
+    "table_with_unused_trailing_entries": (
+        8, 2, [(1, 0, 5, 0), (2, 9, 1, 5)], 8, {"maxb": 27}, 1 + 2),
+    # decode_burst: row i is slot i, idle slots are dead rows in between
+    "decode_burst_layout": (
+        8, 2, [(s, 5 * s, 1, s) for s in (1, 2, 4, 7)], MAX_SEQS, {},
+        sum(5 * s // 8 + 1 for s in (1, 2, 4, 7))),
+    "bfloat16_cache_two_heads_a_word": (
+        32, 8, [(1, 3, 40, 0), (2, 20, 1, 40)], 48,
+        {"dtype": jnp.bfloat16}, 5 + 6 + 3),
+    "bfloat16_cache_one_word_a_row": (
+        4, 2, [(1, 3, 10, 0), (2, 20, 1, 10)], 16,
+        {"dtype": jnp.bfloat16}, 2 + 3),
+}
+
+
+@pytest.mark.parametrize("name", list(CASES))
+def test_run_tiled_kernel_matches_the_gather(name):
+    heads, kv_heads, runs, T, kw, want_loads = CASES[name]
+    q, kc, vc, tables, slots, pos = _case(heads, kv_heads, runs, T, **kw)
+    bs, window = kc.shape[1], kw.get("window", 0)
+    assert run_tiled(kv_heads, 128, kc.dtype)
+    out, loads = paged_attention(q, kc, vc, tables, jnp.asarray(slots),
+                                 jnp.asarray(pos), window=window,
+                                 count_loads=True)
+    ref = _paged_attention(q, kc, vc, tables, jnp.asarray(slots),
+                           jnp.asarray(pos), bs, window=window,
+                           use_kernel=False)
+    live = slots != 0
+    tol = 2e-5 if kc.dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32)[live],
+                               np.asarray(ref, np.float32)[live],
+                               atol=tol, rtol=tol)
+    assert not np.asarray(out, np.float32)[~live].any()
+    # what the loops loaded is what the host counts, and no more than the
+    # pages that hold a key some row of the run may see
+    tq = tile_rows(heads, kv_heads, T)
+    assert int(loads.sum()) == want_loads == page_loads(slots, pos, tq, bs,
+                                                        window)
+
+
+def test_shapes_the_run_tiled_kernel_leaves_to_the_per_token_one():
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    assert run_tiled(8, 128, bf16) and run_tiled(32, 128, bf16)
+    assert run_tiled(4, 128, bf16) and run_tiled(1, 128, f32)
+    # head sizes that are not whole lanes; heads that do not pair up or tile
+    for kv_heads, head_dim, dtype in (
+            (32, 80, bf16), (12, 64, bf16), (1, 128, bf16), (40, 128, bf16),
+            (12, 128, f32), (8, 128, jnp.float16)):
+        assert not run_tiled(kv_heads, head_dim, dtype)
+    # ... and such a shape still answers, one grid row a token
+    q, kc, vc, tables, slots, pos = _case(4, 2, [(1, 3, 10, 0)], 12)
+    q, kc, vc = q[..., :16], kc[..., :16], vc[..., :16]
+    out = paged_attention(q, kc, vc, tables, jnp.asarray(slots),
+                          jnp.asarray(pos))
+    ref = _paged_attention(q, kc, vc, tables, jnp.asarray(slots),
+                           jnp.asarray(pos), 8, use_kernel=False)
+    np.testing.assert_allclose(np.asarray(out)[:10], np.asarray(ref)[:10],
+                               atol=2e-5, rtol=2e-5)
+    assert not np.asarray(out)[10:].any()
+
+
+# ------------------------------------------------------------- engine level
+def _tiny_mistral(budget=48):
+    """Mistral's shape at toy width: 4 query / 2 KV heads of 128, a sliding
+    window that binds, the flat layout."""
+    cfg = llama.llama_tiny(dtype="float32", remat=False, hidden_size=512,
+                           sliding_window=24)
+    model = llama.LlamaModel(cfg)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    sm = dict(max_tracked_sequences=8, max_ragged_batch_size=budget,
+              max_ragged_sequence_count=8, max_context=128, block_size=16,
+              num_blocks=40, prefill_atom_size=0)
+    return InferenceEngineV2(model, params=params, config=dict(
+        dtype="float32", decode_burst=4, state_manager=sm))
+
+
+def test_flat_batch_is_runs_of_consecutive_positions():
+    """What the kernel is fast on, and the counts rest on: the rows a
+    sequence gets in one step are contiguous, their positions consecutive;
+    dead rows carry slot 0 and position 0."""
+    eng = _tiny_mistral()
+    assert eng.model_config.head_dim == 128
+    rng = np.random.default_rng(0)
+    eng.put(range(4), [rng.integers(1, 96, size=n).tolist()
+                       for n in (30, 1, 7, 29)])
+    steps = 0
+    while (batch := eng._build_batch()) is not None:
+        toks, pos, slots, _, _, layout = batch
+        assert layout == (0, 0)
+        for s in set(slots[slots != 0].tolist()):
+            rows = np.flatnonzero(slots == s)
+            assert (np.diff(rows) == 1).all()
+            assert (np.diff(pos[rows]) == 1).all()
+        assert not pos[slots == 0].any() and not toks[slots == 0].any()
+        c = eng.last_step_counts
+        bs, T = eng.kv_cache.block_size, len(slots)
+        assert c["grid_pages"] == c["live_pages"] == page_loads(
+            slots, pos, tile_rows(4, 2, T), bs, window=24)
+        assert c["row_pages"] == sum(
+            p // bs + 1 - max(p - 24 + 1, 0) // bs for p in pos[slots != 0])
+        assert c["row_pages"] >= c["live_pages"] > 0
+        steps += 1
+    assert steps == 2       # 48 rows of 67 prompt tokens, then the rest
+    eng.flush(range(4))
+
+
+def test_tiny_mistral_streams_the_same_tokens_with_and_without_the_kernel(
+        monkeypatch):
+    """Greedy tokens of the flat layout, ragged steps and decode bursts: the
+    run-tiled kernel (interpret mode) against ``use_kernel=False``.  Each
+    engine gets its own jit of the step, so the suite's cached programs
+    (traced without the kernel gate) are neither used nor replaced."""
+    monkeypatch.setenv("DS_TPU_FORCE_PALLAS", "1")
+    inner = ragged_forward.llama_ragged_step.__wrapped__
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, 96, size=n).tolist() for n in (41, 9, 2, 23)]
+    outs = []
+    for use_kernel in (True, False):
+        eng = _tiny_mistral()
+
+        def step(*a, _use=use_kernel, **kw):
+            return inner(*a, **{**kw, "use_kernel": _use})
+
+        eng._step_fn = jax.jit(
+            step, static_argnames=("cfg", "block_size", "layout",
+                                   "use_kernel", "kv_dtype"),
+            donate_argnums=(1, ))
+        outs.append(eng.generate(prompts, max_new_tokens=10))
+        eng.flush(range(len(prompts)))
+    assert outs[0] == outs[1]
+    assert all(len(o) == 10 for o in outs[0])

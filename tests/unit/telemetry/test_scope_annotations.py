@@ -8,6 +8,7 @@ import ast
 import glob
 import json
 import os
+import types
 
 import numpy as np
 import pytest
@@ -171,6 +172,7 @@ def test_serving_steps_in_a_real_trace(tiny, tmp_path):
         assert 0 < c["live_tokens"] <= c["token_budget"]
         assert c["live_tokens"] == c["prefill_tokens"] + c["decode_tokens"]
         assert 0 < c["live_pages"] <= c["grid_pages"]
+        assert c["row_pages"] > 0
         assert (c["burst_k"] >= 2) == (c["kind"] == names.KIND_BURST)
     assert kinds == {names.KIND_RAGGED, names.KIND_BURST}
     # live_tokens is what the engine step consumed: every prompt token and
@@ -179,11 +181,14 @@ def test_serving_steps_in_a_real_trace(tiny, tmp_path):
                 for u in uids)
     assert sum(s[3]["live_tokens"] for s in steps) == total
     # the grid: every budget row (flat layout), or every decode row and
-    # every prefill atom (a prefill-heavy step), times every page of the table
+    # every prefill atom (a prefill-heavy step), times every page of the
+    # table: the tiny model's heads of 16 stay on the per-token kernel
+    # (tests/unit/ops/test_paged_runs.py counts the run-tiled kernel's loads)
     ragged = [s[3] for s in steps if s[3]["kind"] == names.KIND_RAGGED]
     decode_cap, atom = sched.engine._atom_layout()
     assert {c["grid_pages"] for c in ragged} == {
         32 * (64 // 8), (decode_cap + (32 - decode_cap) // atom) * (64 // 8)}
+    assert all(c["row_pages"] >= c["live_pages"] for c in ragged)
     assert {c["token_budget"] for c in ragged} == {32}
     admitted = [e[3]["uid"] for e in t.named(names.SERVE_ADMITTED)]
     assert sorted(admitted) == sorted(uids)
@@ -212,15 +217,28 @@ def test_preemption_is_an_event_with_its_uid(tiny, tmp_path):
 def test_page_counts_follow_the_layout_and_the_window(tiny):
     engine = _scheduler(tiny).engine
     pos = np.array([0, 7, 8, 30, 0, 0, 0, 0], np.int32)
-    live = np.array([1, 1, 1, 1, 0, 0, 0, 0], bool)
-    # flat: 8 rows x 8 pages; contexts span 1, 1, 2 and 4 pages
-    assert engine._page_counts(pos, live) == (64, 8)
+    slots = np.array([1, 2, 3, 4, 0, 0, 0, 0], np.int32)
+    # heads of 16: the per-token kernel.  Flat: 8 rows x 8 pages; contexts
+    # span 1, 1, 2 and 4 pages
+    assert engine._page_counts(pos, slots) == (64, 8, 8)
     # atoms of 2 behind 2 decode rows: 2 + 3 grid rows; an atom streams the
     # pages of its deepest row
-    assert engine._page_counts(pos, live, layout=(2, 2)) == (40, 2 + 4)
+    assert engine._page_counts(pos, slots, layout=(2, 2)) == (40, 2 + 4, 8)
     # a burst: k rows of positions
     assert engine._page_counts(pos[None, :4] + np.arange(2)[:, None],
-                               live[None, :4]) == (64, 1 + 2 + 2 + 4 + 8)
+                               np.broadcast_to(slots[:4], (2, 4))) == (
+        64, 1 + 2 + 2 + 4 + 8, 1 + 2 + 2 + 4 + 8)
+    # heads of 128: the run-tiled kernel loads a run's pages once, and only
+    # live ones.  Rows 0-2 are one run of slot 1 (positions 6, 7, 8: pages
+    # 0-1), row 3 a decode row at 30 (pages 0-3)
+    engine.model_config = types.SimpleNamespace(
+        num_attention_heads=4, num_key_value_heads=2, head_dim=128)
+    pos = np.array([6, 7, 8, 30, 0, 0, 0, 0], np.int32)
+    slots = np.array([1, 1, 1, 4, 0, 0, 0, 0], np.int32)
+    assert engine._page_counts(pos, slots) == (2 + 4, 2 + 4, 1 + 1 + 2 + 4)
+    # a window of 8 keeps position 30's pages 2-3 and position 8's 0-1
+    engine.model_config.sliding_window = 8
+    assert engine._page_counts(pos, slots) == (2 + 2, 2 + 2, 1 + 1 + 2 + 2)
 
 
 # ----------------------------------------------------------------- training
@@ -364,7 +382,7 @@ def test_every_pallas_call_has_a_ds_name_listed_in_the_docs():
         assert name.startswith(names.KERNEL_PREFIX), name
         assert f"`{name}`" in doc, f"{name} missing from docs/kernels.md"
     assert sum(n.startswith(names.KERNEL_FLASH) for n in kernel_names) == 7
-    assert sum(n.startswith(names.KERNEL_PAGED) for n in kernel_names) == 2
+    assert sum(n.startswith(names.KERNEL_PAGED) for n in kernel_names) == 3
     assert sum(n.startswith(names.KERNEL_OPTIMIZER)
                for n in kernel_names) == 4
 

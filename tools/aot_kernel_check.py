@@ -129,13 +129,30 @@ def main():
     bt = sds((T, maxb), jnp.int32)
     pos = sds((T, ), jnp.int32)
     results.append(check(
-        "paged_attention(decode)",
-        lambda q, k, v, t, l: paged_attention(q, k, v, t, l),
+        "paged_attention_atoms(per token)",
+        lambda q, k, v, t, l: paged_attention_atoms(q, k, v, t, l, 1),
         pq, kc, kc, bt, pos))
     results.append(check(
         "paged_attention_atoms(prefill)",
         lambda q, k, v, t, l: paged_attention_atoms(q, k, v, t, l, atom),
         pq, kc, kc, bt, pos))
+    # the flat layout's run-tiled kernel: the serving cell's own shape
+    # (Mistral-7B, 768-token budget, 27-page table, window 4096), an MHA
+    # shape, and head sizes of the zoo that stay on the per-token kernel
+    for name, T, heads, kv_heads, head_dim, maxb, window in (
+            ("GQA 32/8, the cell", 768, 32, 8, 128, 27, 4096),
+            ("MHA 32/32", 768, 32, 32, 128, 16, 0),
+            ("GQA 28/4, Qwen2", 256, 28, 4, 128, 16, 0),
+            ("MHA 32/32 x 80: per token", 64, 32, 32, 80, 16, 0),
+            ("MQA 71/1 x 64: per token", 64, 71, 1, 64, 16, 0)):
+        kc = sds((64, 128, kv_heads, head_dim), bf16)
+        results.append(check(
+            f"paged_attention({name})",
+            lambda q, k, v, t, s, l, window=window: paged_attention(
+                q, k, v, t, s, l, window=window),
+            sds((T, heads, head_dim), bf16), kc, kc,
+            sds((65, maxb), jnp.int32),
+            sds((T, ), jnp.int32), sds((T, ), jnp.int32)))
 
     from deepspeed_tpu.ops.pallas.grouped_matmul import gmm
     results.append(check(
