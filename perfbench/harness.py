@@ -247,6 +247,21 @@ class Context:
         print(f"INFO {title}: " + json.dumps(fields, default=float),
               flush=True)
 
+    def measured_worst(self, check):
+        """The worst error measured for ``check`` over this configuration's
+        own runs: ``measured_worst[check]["value"]`` of its file.  The jobs
+        hold the rule (a factor, a floor); what was measured is data of the
+        configuration.  One without the entry for a check its job runs is an
+        error that names the key, never a default."""
+        where = getattr(self, "config_file", "the configuration")
+        try:
+            return float(self.config["measured_worst"][check]["value"])
+        except KeyError:
+            raise KeyError(
+                f"{where} has no measured_worst[{check!r}][\"value\"]: run "
+                "the check over a dozen seeds, write the worst error there "
+                "(perfbench/README.md)") from None
+
 
 def run_cell(workload, seed, seconds, trace, *, root=loader.ROOT,
              require_tpu=True, t_process_start=None, out=sys.stdout):
@@ -259,7 +274,8 @@ def run_cell(workload, seed, seconds, trace, *, root=loader.ROOT,
     manifest = loader.load_manifest(root)
     cell = loader.find(manifest["workloads"], workload, "workload")
     config_entry = loader.find(manifest["configs"], cell["config"], "config")
-    config = loader.load_json(os.path.join(root, config_entry["file"]))
+    config_file = config_entry["file"]
+    config = loader.load_json(os.path.join(root, config_file))
     traffic = loader.load_json(
         loader.part_path(root, "traffic", cell["traffic"], "json"))
     peaks_all = loader.load_json(os.path.join(root, "perfbench", "peaks.json"))
@@ -308,7 +324,8 @@ def run_cell(workload, seed, seconds, trace, *, root=loader.ROOT,
 
     with WarningCollector() as warnings, CompileLog() as compiles:
         ctx = Context(
-            root=root, cell=cell, config=config, traffic=traffic,
+            root=root, cell=cell, config=config, config_file=config_file,
+            traffic=traffic,
             seed=int(seed), seconds=float(seconds), trace=bool(trace),
             arch=arch, reference=reference, peaks=peaks, devices=devices,
             compiles=compiles, checks=Checks(),

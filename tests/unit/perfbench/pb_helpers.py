@@ -61,3 +61,61 @@ def run(root, cell, seed=1, seconds=1.0, trace=0):
                                   require_tpu=False, out=out)
     last = out.getvalue().strip().splitlines()[-1] if rc == 0 else None
     return rc, result, last
+
+
+# ----------------------------------------------- the parts, without a run
+def parts(config_name):
+    """``(configuration, architecture, reference)`` of a preset, by name."""
+    from perfbench import loader
+    config = loader.load_json(os.path.join(
+        ROOT, "perfbench", "configs", config_name + ".json"))
+    arch = loader.load_part(ROOT, "models", config["arch"])
+    ref = loader.load_part(ROOT, "reference", config["arch"])
+    return config, arch, ref
+
+
+def serve_ctx(config_name, traffic_name="tiny_chat"):
+    """A context as far as the serve job's comparison reads it."""
+    from perfbench import harness, loader
+    config, _, ref = parts(config_name)
+    traffic = loader.load_json(loader.part_path(
+        ROOT, "traffic", traffic_name, "json"))
+    return harness.Context(
+        config=config, traffic=traffic, reference=ref,
+        config_file=f"perfbench/configs/{config_name}.json")
+
+
+def streamed(config_name, seed, mutate=None, traffic_name="tiny_chat"):
+    """The serve job's own check requests through its own scheduler:
+    ``(serve job, reference, seeded weights, sizes, prompts, tokens)``.
+    ``mutate`` changes the weights the ENGINE serves, not the reference's."""
+    from perfbench import harness, loader, traffic_gen, weights
+    config, arch, ref = parts(config_name)
+    serve = loader.load_part(ROOT, "jobs", "serve")
+    model, _ = arch.build(config, "serve")
+    sizes = arch.reference_sizes(config, "serve")
+    params = weights.seeded_weights(arch.param_shapes(model),
+                                    harness.fold_seed(seed))
+    served = mutate(params) if mutate else params
+    ctx = serve_ctx(config_name, traffic_name)
+    sched = serve.build_scheduler(ctx, model, served)
+    prompts = traffic_gen.check_requests(ctx.traffic, sizes["vocab_size"],
+                                         seed)
+    new = ctx.traffic["check_new_tokens"]
+    produced = serve.stream(sched, [(p, new) for p in prompts])
+    return serve, ref, params, sizes, prompts, produced
+
+
+def rounded_to(bits):
+    """Weights rounded to ``bits`` bits (one scale a matrix), norms kept."""
+    import jax
+    import jax.numpy as jnp
+    levels = 2 ** (bits - 1) - 1
+
+    def q(x):
+        if x.ndim < 2:
+            return x
+        scale = jnp.max(jnp.abs(x.astype(jnp.float32))) / levels
+        return (jnp.round(x.astype(jnp.float32) / scale)
+                * scale).astype(x.dtype)
+    return lambda params: jax.tree_util.tree_map(q, params)

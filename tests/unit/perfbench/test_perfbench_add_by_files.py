@@ -7,6 +7,8 @@ import hashlib
 import json
 import os
 
+import pytest
+
 import pb_helpers as pb
 
 
@@ -89,3 +91,103 @@ def test_add_config_traffic_arch_and_metric_as_files(tmp_path, capsys):
     # the cell that was there still runs, and does not report the new metric
     rc, result, _ = pb.run(root, "t_serve", seed=12, trace=1)
     assert rc == 0 and "new_metric.x" not in result["metrics"]
+
+
+#: a routed configuration's own ``measured_worst``, as far as serving reads it
+ROUTED_BLOCK = {
+    "serve.logit_gap": {"value": 0.03, "where": "this test"},
+    "serve.router_margin": {"value": 0.02, "where": "this test"},
+    "serve.routed_two_answer_share": {"value": 0.3, "where": "this test"},
+    "serve.routed_left_out_share": {"value": 0.04, "where": "this test"}}
+
+
+def _add_routed_config(root, name, **changes):
+    """A routed configuration of the ``mixtral`` architecture as ONE new file
+    and one appended entry; returns the cell's name."""
+    bench = os.path.join(root, "perfbench")
+    config = json.load(open(os.path.join(bench, "configs",
+                                         "tiny_mixtral.json")))
+    config.update(num_hidden_layers={"serve": 2}, **changes)
+    config["published"] = dict(config["published"],
+                               num_hidden_layers={"serve": 2})
+    json.dump(config, open(os.path.join(bench, "configs", name + ".json"),
+                           "w"))
+    manifest = pb.read_manifest(root)
+    manifest["configs"].append(
+        {"name": name, "source": "test",
+         "file": f"perfbench/configs/{name}.json", "reduced": [],
+         "why": "test"})
+    cell = name + "_cell"
+    manifest["workloads"].append(
+        {"name": cell, "config": name, "traffic": "tiny_chat", "chips": 1,
+         "why": "test"})
+    for m in manifest["end_to_end"] + manifest["per_layer"]:
+        if "workloads" in m and "t_serve" in m["workloads"]:
+            m["workloads"].append(cell)
+    pb.write_manifest(root, manifest)
+    return cell
+
+
+def test_add_a_routed_configuration_with_its_own_tolerances_as_files(
+        tmp_path, capsys):
+    """Not Mistral-shaped, not dense: its widths, what it says was published
+    and the worst errors measured for it are data of its own file, and the
+    serving comparison it gets is the routed one."""
+    root = pb.tiny_root(tmp_path, [("t_serve", "tiny_mistral", "tiny_chat",
+                                    "serve")])
+    before = _digest(root)
+    cell = _add_routed_config(root, "new_routed",
+                              measured_worst=dict(ROUTED_BLOCK))
+    after = _digest(root)
+    assert {k: v for k, v in after.items() if k in before} == before
+    assert len(after) == len(before) + 1
+
+    rc, result, _ = pb.run(root, cell, seed=12, trace=0)
+    printed = capsys.readouterr().out
+    assert rc == 0 and result["correct"], printed
+    # its own tolerances, by the jobs' rule, and where they came from
+    setup = [l for l in printed.splitlines()
+             if l.startswith("INFO setup: ")][-1]
+    setup = json.loads(setup.split(": ", 1)[1])
+    assert setup["tolerances"] == pytest.approx(
+        {"serve.logit_gap": 0.09, "serve.router_margin": 0.06,
+         "serve.routed_two_answer_share": 0.9,
+         "serve.routed_left_out_share": 0.12})
+    assert setup["tolerances_from"] == "perfbench/configs/new_routed.json"
+    assert "CHECK serve.routed_two_answer_share" in printed
+    assert "CHECK serve.routed_left_out_share" in printed
+    assert "must be <= 0.09" in printed
+    # the dense cell beside it is compared as before: no routed line
+    rc, result, _ = pb.run(root, "t_serve", seed=12, trace=0)
+    printed = capsys.readouterr().out
+    assert rc == 0 and result["correct"], printed
+    assert "serve.routed" not in printed
+    assert "must be <= 0.02:" in printed          # 3 x 0.0014 < the floor
+
+
+@pytest.mark.parametrize("missing", ["serve.router_margin",
+                                     "serve.logit_gap",
+                                     "serve.routed_two_answer_share",
+                                     "serve.routed_left_out_share",
+                                     "measured_worst"])
+def test_a_configuration_without_its_measured_worst_fails_by_name(
+        tmp_path, missing):
+    """Never a default: the run stops before anything is built and names the
+    key and the file."""
+    root = pb.tiny_root(tmp_path, [("t_serve", "tiny_mistral", "tiny_chat",
+                                    "serve")])
+    block = dict(ROUTED_BLOCK)
+    block.pop(missing, None)
+    cell = _add_routed_config(
+        root, "no_block",
+        **({} if missing == "measured_worst" else {"measured_worst": block}))
+    if missing == "measured_worst":
+        path = os.path.join(root, "perfbench", "configs", "no_block.json")
+        config = json.load(open(path))
+        del config["measured_worst"]
+        json.dump(config, open(path, "w"))
+        missing = "serve.logit_gap"
+    with pytest.raises(KeyError) as err:
+        pb.run(root, cell, seed=12, trace=0)
+    assert missing in str(err.value)
+    assert "perfbench/configs/no_block.json" in str(err.value)

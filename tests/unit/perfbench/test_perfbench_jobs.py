@@ -7,7 +7,9 @@ import pytest
 import pb_helpers as pb
 
 CELLS = [("t_train", "tiny_mistral", "tiny_train", "train"),
-         ("t_serve", "tiny_mistral", "tiny_chat", "serve")]
+         ("t_serve", "tiny_mistral", "tiny_chat", "serve"),
+         ("x_train", "tiny_mixtral", "tiny_train", "train"),
+         ("x_serve", "tiny_mixtral", "tiny_chat", "serve")]
 E2E = {"train": {"train_tokens_per_s_per_chip", "setup_s"},
        "serve": {"serve_tokens_per_s", "setup_s"}}
 SPAN_METRICS = {"train": {"train_host_ms_per_step"},
@@ -18,7 +20,7 @@ SPAN_METRICS = {"train": {"train_host_ms_per_step"},
 @pytest.mark.parametrize("cell", CELLS, ids=[c[0] for c in CELLS])
 @pytest.mark.parametrize("trace", [0, 1])
 def test_job_runs_end_to_end_at_tiny_size(tmp_path, cell, trace, capsys):
-    name, _, _, job = cell
+    name, config, _, job = cell
     root = pb.tiny_root(tmp_path, [cell])
     rc, result, last = pb.run(root, name, seed=3_000_000_019, trace=trace)
     printed = capsys.readouterr().out
@@ -45,6 +47,18 @@ def test_job_runs_end_to_end_at_tiny_size(tmp_path, cell, trace, capsys):
     checks = [l for l in printed.splitlines() if l.startswith("CHECK ")]
     assert len(checks) >= 6 and all("must be" in l for l in checks)
     assert "compilations_in_window: observed 0" in printed
+    # the tolerances a run used, and the file they came from, once
+    setup = [l for l in printed.splitlines() if l.startswith("INFO setup: ")]
+    assert len(setup) == 1
+    setup = json.loads(setup[0].split(": ", 1)[1])
+    assert setup["tolerances_from"] == f"perfbench/configs/{config}.json"
+    assert all(f"must be <= {tol:g}" in printed
+               for key, tol in setup["tolerances"].items()
+               if key != "serve.router_margin")
+    # only a reference that states its routing is compared by the routed rule
+    routed = job == "serve" and config == "tiny_mixtral"
+    assert ("CHECK serve.routed_left_out_share" in printed) == routed
+    assert ("serve.router_margin" in setup["tolerances"]) == routed
     # the window names its slowest host calls, so that a stall explains itself
     window = [l for l in printed.splitlines()
               if l.startswith("INFO window: ")][-1]

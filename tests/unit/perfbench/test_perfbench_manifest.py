@@ -40,6 +40,62 @@ def test_top_level_keys_and_sizes(manifest):
     assert runs * (manifest["run_seconds"] + 60) + 24 * 180 + 1200 <= 43200
 
 
+#: a key of a configuration that ``reduced`` may never name: a width (hidden,
+#: intermediate, latent, state or projection size, a head size or count, an
+#: expansion factor, the experts a token uses or a router spans, a window, the
+#: vocabulary).  Depth is the only cut.
+WIDTH_KEY = re.compile(r"(_dim|_rank|hidden_size|intermediate_size"
+                       r"|experts_per_tok|vocab_size)$"
+                       r"|^(num_local_experts|num_experts|n_routed_experts"
+                       r"|n_shared_experts|d_model|d_ff)$|head|window")
+
+
+WIDTHS = ("hidden_size", "intermediate_size", "moe_intermediate_size",
+          "num_attention_heads", "num_key_value_heads", "num_heads",
+          "head_dim", "qk_rope_head_dim", "v_head_dim", "kv_lora_rank",
+          "q_lora_rank", "num_experts_per_tok", "num_local_experts",
+          "num_experts", "n_routed_experts", "n_shared_experts", "d_model",
+          "d_ff", "sliding_window", "vocab_size")
+CUTS = ("num_hidden_layers", "max_position_embeddings", "rope_theta",
+        "rms_norm_eps", "first_k_dense_replace")
+
+
+@pytest.mark.parametrize("key", WIDTHS + CUTS)
+def test_width_key_names_every_width_and_no_depth(key):
+    assert bool(WIDTH_KEY.search(key)) == (key in WIDTHS)
+
+
+def lint_config(body, reduced):
+    """The faults of one configuration file against its OWN statement of what
+    was published: every key of ``published`` is run at the published value
+    unless ``reduced`` names it, and ``reduced`` names no width.  Returns the
+    faults as strings (none = "published widths, never cut" holds)."""
+    faults = []
+    if set(body.get("reduced", reduced)) != set(reduced):
+        faults.append("the file's `reduced` is not the manifest's")
+    published = {k: v for k, v in body.get("published", {}).items()
+                 if not k.startswith("_")}
+    if not published:
+        faults.append("no `published` block")
+    for key in reduced:
+        if not NAME.match(key) or key not in body:
+            faults.append(f"reduced key {key!r} is not a key of the file")
+        if key not in published:
+            faults.append(f"reduced key {key!r} is not in `published`")
+        if WIDTH_KEY.search(key):
+            faults.append(f"reduced names the width {key!r}")
+    for key, value in published.items():
+        if key in reduced:
+            if body.get(key) == value:
+                faults.append(f"{key!r} is in `reduced` and not changed")
+        elif key not in body:
+            faults.append(f"published key {key!r} is left out")
+        elif body[key] != value:
+            faults.append(f"{key!r}: run {body[key]!r}, published {value!r}, "
+                          "not in `reduced`")
+    return faults
+
+
 def test_configs(manifest):
     assert 1 <= len(manifest["configs"]) <= 24
     files = set()
@@ -54,18 +110,82 @@ def test_configs(manifest):
         body = json.load(open(os.path.join(pb.ROOT, c["file"])))
         assert body["source"] == c["source"]
         assert len(c["reduced"]) <= 16
-        for key in c["reduced"]:
-            assert NAME.match(key) and key in body
-            assert not re.search(r"(_dim|_rank|hidden_size|intermediate_size"
-                                 r"|head|experts_per_tok)$", key), key
-        # published widths, never cut
-        assert body["hidden_size"] == 4096
-        assert body["intermediate_size"] == 14336
-        assert body["num_attention_heads"] == 32
-        assert body["num_key_value_heads"] == 8
-        assert body["vocab_size"] == 32000
-        assert set(body["reduced"]) == set(c["reduced"])
+        # published widths, never cut: against the file's own `published`
+        assert lint_config(body, c["reduced"]) == [], c["name"]
     assert len({c["name"] for c in manifest["configs"]}) == len(files)
+
+
+def test_every_configuration_file_states_what_was_published():
+    """The tiny presets too (they state themselves): the lint has no
+    configuration it cannot read."""
+    folder = os.path.join(pb.ROOT, "perfbench", "configs")
+    for f in sorted(os.listdir(folder)):
+        body = json.load(open(os.path.join(folder, f)))
+        assert lint_config(body, list(body.get("reduced", []))) == [], f
+        assert all(isinstance(v.get("value"), float) and v.get("where")
+                   for k, v in body["measured_worst"].items()
+                   if not k.startswith("_")), f
+
+
+#: OLMoE-1B-7B-0125-Instruct as the model-configs guide's catalog has it
+#: (https://huggingface.co/allenai/OLMoE-1B-7B-0125-Instruct/blob/main/config.json):
+#: not Mistral's widths, 64 experts, 8 a token
+OLMOE = {
+    "attention_bias": False, "clip_qkv": None, "hidden_act": "silu",
+    "hidden_size": 2048, "intermediate_size": 1024,
+    "max_position_embeddings": 4096, "model_type": "olmoe",
+    "norm_topk_prob": False, "num_attention_heads": 16, "num_experts": 64,
+    "num_experts_per_tok": 8, "num_hidden_layers": 16,
+    "num_key_value_heads": 16, "rms_norm_eps": 1e-05, "rope_scaling": None,
+    "rope_theta": 10000, "tie_word_embeddings": False, "vocab_size": 50304}
+
+
+def _olmoe(**changes):
+    body = dict(OLMOE, published=dict(OLMOE), arch="olmoe",
+                num_hidden_layers={"serve": 8},
+                reduced={"num_hidden_layers": "16 published"})
+    body.update(changes)
+    return body
+
+
+@pytest.mark.parametrize("changes,reduced,fault", [
+    ({}, ["num_hidden_layers"], None),
+    ({"intermediate_size": 512}, ["num_hidden_layers"], "intermediate_size"),
+    ({"intermediate_size": 512,
+      "reduced": {"num_hidden_layers": "", "intermediate_size": ""}},
+     ["num_hidden_layers", "intermediate_size"], "names the width"),
+    ({"num_experts": 8, "reduced": {"num_hidden_layers": "",
+                                    "num_experts": ""}},
+     ["num_hidden_layers", "num_experts"], "names the width"),
+    ({"num_attention_heads": 8,
+      "reduced": {"num_hidden_layers": "", "num_attention_heads": ""}},
+     ["num_hidden_layers", "num_attention_heads"], "names the width"),
+    ({"num_key_value_heads": 4,
+      "reduced": {"num_hidden_layers": "", "num_key_value_heads": ""}},
+     ["num_hidden_layers", "num_key_value_heads"], "names the width"),
+    ({"num_attention_heads": 8}, ["num_hidden_layers"],
+     "num_attention_heads"),
+    ({"num_experts_per_tok": 2}, ["num_hidden_layers"],
+     "num_experts_per_tok"),
+    ({"vocab_size": 32000}, ["num_hidden_layers"], "vocab_size"),
+    ({"rope_theta": None}, ["num_hidden_layers"], "rope_theta"),
+    ({"num_hidden_layers": 16}, ["num_hidden_layers"], "not changed"),
+    ({"published": {}}, ["num_hidden_layers"], "no `published`"),
+], ids=["olmoe_as_published", "expert_width_halved_unlisted",
+        "expert_width_listed", "experts_listed", "heads_listed",
+        "kv_heads_listed", "heads_cut_unlisted", "experts_per_token_cut",
+        "vocabulary_cut", "key_nulled", "reduced_but_unchanged",
+        "nothing_published"])
+def test_published_widths_are_held_to_the_files_own_statement(
+        changes, reduced, fault):
+    """A configuration that is not Mistral-shaped passes the lint at its own
+    published sizes, and fails it the moment a width differs from what the
+    file itself says was published."""
+    faults = lint_config(_olmoe(**changes), reduced)
+    if fault is None:
+        assert faults == []
+    else:
+        assert any(fault in f for f in faults), faults
 
 
 def test_workloads_resolve_by_name(manifest):
