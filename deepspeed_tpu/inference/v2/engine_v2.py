@@ -25,7 +25,8 @@ from ...telemetry import names as _names
 from ...utils.logging import logger
 from .config_v2 import RaggedInferenceEngineConfig
 from .kv_codec import resolve_kv_dtype
-from .ragged import BlockedKVCache, DSStateManager, KVCacheExhausted
+from .ragged import (BlockedKVCache, DSStateManager, KVCacheExhausted,
+                     window_row_positions)
 from .ragged_forward import RAGGED_FORWARDS
 
 
@@ -166,10 +167,15 @@ class InferenceEngineV2:
             num_blocks = 1 + max(sm.max_ragged_sequence_count,
                                  (sm.max_tracked_sequences *
                                   max_blocks_per_seq) // 2)
+        # a window-plus-summary cache (EvaByte) is the MODEL's statement:
+        # its config says how long a token's exact K/V live
+        eva = getattr(cfg, "attention_class", None) == "eva"
         self.kv_cache = BlockedKVCache(
             cfg.num_hidden_layers, num_blocks, block_size,
             cfg.num_key_value_heads, cfg.head_dim,
-            dtype=jnp.dtype(config.dtype), kv_dtype=self._kv_dtype)
+            dtype=jnp.dtype(config.dtype), kv_dtype=self._kv_dtype,
+            window_size=cfg.window_size if eva else 0,
+            chunk_size=cfg.chunk_size if eva else 0)
         self.state_manager = DSStateManager(sm, self.kv_cache)
         self._budget = int(sm.max_ragged_batch_size)
         #: what the newest engine step held (``schedule_step`` or a decode
@@ -386,6 +392,71 @@ class InferenceEngineV2:
             "row_pages": row_pages, "burst_k": 0}
         return toks, pos, slots, last_idx, finishing, layout
 
+    def _table_snapshot(self):
+        """The block table as the launched program is to see it.  A COPY:
+        the CPU backend may alias a numpy buffer instead of copying it, the
+        step runs asynchronously, and the host rewrites rows of the table
+        (a flush, a preemption, a window's close) while building the next
+        step."""
+        return jnp.asarray(self.state_manager.block_table.copy())
+
+    def _count_cache(self, pos, slots, layout=(0, 0)):
+        """Add to ``last_step_counts`` what the cache holds once this step
+        (or burst: ``[k, rows]``) is scheduled — the tokens of the running
+        sequences' contexts (``context_tokens``) and the blocks of
+        ``block_size`` rows they hold (``held_blocks``) — and, of a
+        window-plus-summary cache, the summary pages the step loads and the
+        chunks and windows whose last token is among its rows.  Called once
+        the program is launched: the counting then runs while the device
+        works, not between two steps."""
+        kv = self.kv_cache
+        seqs = [s for s in self.state_manager.tracked_sequences.values()
+                if not s.done]
+        ends = pos[slots != 0] + 1 if kv.window_size else None
+        self.last_step_counts.update(
+            context_tokens=sum(s.seen_tokens for s in seqs),
+            held_blocks=sum(len(s.blocks) for s in seqs),
+            block_size=kv.block_size,
+            summary_pages=self._summary_pages(pos, slots, layout),
+            chunks_closed=0 if ends is None else int(
+                (ends % kv.chunk_size == 0).sum()),
+            windows_closed=0 if ends is None else int(
+                (ends % kv.window_size == 0).sum()))
+
+    def _summary_pages(self, pos, slots, layout=(0, 0)):
+        """Of ``grid_pages``, the loads of summary blocks: every run (every
+        atom, every row on the per-token kernel) loads all the summary pages
+        of the windows its sequence has closed."""
+        from ...ops.pallas import paged_attention as _pa
+        kv, cfg = self.kv_cache, self.model_config
+        if not kv.window_size:
+            return 0
+        pos, slots = np.atleast_2d(pos), np.atleast_2d(slots)
+        closed = np.where(slots != 0,
+                          pos // kv.window_size * kv.summary_blocks, 0)
+        decode_cap, atom = layout
+        cut = decode_cap if atom else pos.shape[1]
+        total = closed[:, cut:].reshape(-1, atom).max(axis=1).sum() \
+            if atom else 0
+        if not _pa.run_tiled(cfg.num_key_value_heads, cfg.head_dim,
+                             kv.data.dtype):
+            return int(total + closed[:, :cut].sum())
+        tq = _pa.tile_rows(cfg.num_attention_heads, cfg.num_key_value_heads,
+                           cut)
+        rid = _pa.run_plan(np, slots[:, :cut], self._row_positions(
+            pos[:, :cut]), tq, kv.block_size)[1]
+        per_row = np.pad(closed[:, :cut], ((0, 0), (0, -cut % tq))) \
+            .reshape(-1, tq)
+        runs = rid[:, None, :] == np.arange(tq)[None, :, None]
+        return int(total + (runs * per_row[:, None, :]).max(-1).sum())
+
+    def _row_positions(self, pos):
+        """Positions inside the block-table row (``ragged.py``)."""
+        kv = self.kv_cache
+        if not kv.window_size:
+            return pos
+        return window_row_positions(pos, kv.window_size, kv.chunk_size)
+
     def _page_counts(self, pos, slots, layout=(0, 0)):
         """``(grid_pages, live_pages, row_pages)`` of one paged-attention
         call over the rows at positions ``pos`` in slots ``slots`` (0: a
@@ -405,6 +476,7 @@ class InferenceEngineV2:
         cfg = self.model_config
         window = int(getattr(cfg, "sliding_window", 0) or 0)
         pos, slots = np.atleast_2d(pos), np.atleast_2d(slots)
+        pos = self._row_positions(pos)
         first = np.maximum(pos - window + 1, 0) // bs if window else 0
         pages = np.where(slots != 0, pos // bs + 1 - first, 0)
         decode_cap, atom = layout
@@ -471,7 +543,7 @@ class InferenceEngineV2:
         with _telemetry.scope(_names.SERVE_LAUNCH):
             step_args = (self.params, self._kv, jnp.asarray(toks),
                          jnp.asarray(pos), jnp.asarray(slots),
-                         jnp.asarray(self.state_manager.block_table),
+                         self._table_snapshot(),
                          jnp.asarray(last_idx))
             step_kw = dict(cfg=self.model_config,
                            block_size=self.kv_cache.block_size,
@@ -489,6 +561,7 @@ class InferenceEngineV2:
                     self._step_fn, step_args, step_kw,
                     meta={"layout": list(layout)})
             logits, self._kv = self._step_fn(*step_args, **step_kw)
+        self._count_cache(pos, slots, layout)
         out = {}
         if finishing:
             # the fetch is the one place the host waits for the device
@@ -585,6 +658,9 @@ class InferenceEngineV2:
                 max(0, sm.kv_cache.blocks_for(s.seen_tokens + kk)
                     - len(s.blocks)) for s in seqs)
 
+        # a burst, like a step, ends at the nearest window's end
+        rooms = [sm.kv_cache.run_room(s.seen_tokens) for s in seqs]
+        k = min([k] + [r for r in rooms if r is not None])
         while k >= 2 and _new_blocks(k) > sm.free_blocks:
             k //= 2
         if k < 2:
@@ -605,9 +681,12 @@ class InferenceEngineV2:
                 act[seq.slot] = True
             # k iterations over max_seqs rows each, one token a live row:
             # row i is slot i (ragged_forward.decode_burst)
-            grid_pages, live_pages, row_pages = self._page_counts(
-                pos0[None, :] + np.arange(k)[:, None],
-                np.broadcast_to(np.where(act, np.arange(n), 0), (k, n)))
+            pos_k = pos0[None, :] + np.arange(k)[:, None]
+            slots_k = np.broadcast_to(np.where(act, np.arange(n), 0), (k, n))
+            for seq in seqs:        # as the cache stands when the burst ends
+                seq.seen_tokens += k
+            grid_pages, live_pages, row_pages = self._page_counts(pos_k,
+                                                                  slots_k)
             self.last_step_counts = {
                 "kind": _names.KIND_BURST, "token_budget": n * k,
                 "live_tokens": len(seqs) * k,
@@ -626,7 +705,7 @@ class InferenceEngineV2:
         with _telemetry.scope(_names.SERVE_LAUNCH):
             burst_args = (self.params, self._kv, jnp.asarray(tok0),
                           jnp.asarray(pos0), jnp.asarray(act),
-                          jnp.asarray(sm.block_table))
+                          self._table_snapshot())
             burst_kw = dict(step_fn=self._step_fn, cfg=self.model_config,
                             block_size=self.kv_cache.block_size, k=k,
                             use_kernel=self._tp == 1, sample=sample,
@@ -641,6 +720,7 @@ class InferenceEngineV2:
                     f"serve/decode_burst[k={k}]", decode_burst, burst_args,
                     burst_kw, meta={"k": int(k)})
             toks_out, self._kv = decode_burst(*burst_args, **burst_kw)
+        self._count_cache(pos_k, slots_k)
         with _telemetry.scope(_names.SERVE_FETCH):
             toks_out = np.asarray(toks_out)  # ONE fetch for k×seqs tokens
         self.burst_steps = getattr(self, "burst_steps", 0) + 1
@@ -649,7 +729,6 @@ class InferenceEngineV2:
             # k tokens scheduled on device: t0 (the pending one) + the k-1
             # fed-back generations; invariant len(tokens) == seen + 1 holds
             # with the newest generation left pending for the next round
-            seq.seen_tokens += k
             col = toks_out[:, seq.slot]
             seq.tokens.extend(int(t) for t in col)
             out[seq.uid] = [int(t) for t in col]

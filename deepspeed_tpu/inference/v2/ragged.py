@@ -7,6 +7,17 @@ TPU shape discipline: the cache is ONE array per model —
 ``[L, 2, num_blocks, block_size, Hkv, Dh]`` — and every sequence owns a row
 of a fixed-width block table ``[max_seqs, max_blocks_per_seq]``; the jitted
 ragged forward only ever sees static shapes (the "ragged" part is metadata).
+
+Two kinds of state in one cache (``attention_class == "eva"``, EvaByte): a
+sequence keeps the exact K/V of its CURRENT window of ``window_size`` tokens
+and, of every window before it, one summary (key, value) row a chunk of
+``chunk_size`` tokens.  Its block-table row is ``[summary blocks of the
+closed windows | blocks of the current window | ... | the summary block in
+the making]``: a step addresses and masks by the position inside that row,
+so the summaries of the current window (its last columns) are beyond every
+query's position until the window closes.  How many blocks ``n`` tokens
+hold is ``BlockedKVCache.blocks_for`` for every architecture; capacity,
+deferral, the row's width and the scheduler's admission claims all ask it.
 """
 
 from dataclasses import dataclass, field
@@ -15,6 +26,15 @@ from typing import Dict, List, Optional
 import numpy as np
 
 import jax.numpy as jnp
+
+
+def window_row_positions(positions, window_size, chunk_size):
+    """Positions inside a window-plus-summary block-table row, ``[the closed
+    windows' summaries | the current window]``, of tokens at ``positions``
+    (numpy on the host, jax.numpy inside the step program): what the cache is
+    addressed and the causal mask taken by."""
+    return positions // window_size * (window_size // chunk_size) \
+        + positions % window_size
 
 
 class KVCacheExhausted(RuntimeError):
@@ -70,8 +90,12 @@ class DSSequenceDescriptor:
     slot: int                       # row in the block table
     tokens: List[int] = field(default_factory=list)  # full token history
     seen_tokens: int = 0            # tokens already in the KV cache
-    blocks: List[int] = field(default_factory=list)
+    blocks: List[int] = field(default_factory=list)   # every block held
     done: bool = False
+    # window-plus-summary caches only: ``blocks`` by kind
+    summary_blocks: List[int] = field(default_factory=list)  # closed windows
+    window_blocks: List[int] = field(default_factory=list)   # current window
+    making_blocks: List[int] = field(default_factory=list)   # its summaries
 
     @property
     def cur_length(self):
@@ -93,10 +117,28 @@ class BlockedKVCache:
     ragged step as one ``(data, scales)`` pytree."""
 
     def __init__(self, num_layers, num_blocks, block_size, num_kv_heads,
-                 head_dim, dtype=jnp.bfloat16, kv_dtype=None):
+                 head_dim, dtype=jnp.bfloat16, kv_dtype=None, window_size=0,
+                 chunk_size=0):
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
         self.kv_dtype = kv_dtype
+        # window-plus-summary layout (see the module docstring); 0: every
+        # token keeps its K/V for the sequence's life
+        self.window_size = int(window_size or 0)
+        self.chunk_size = int(chunk_size or 0)
+        self.summary_blocks = 0     # blocks of summaries a closed window leaves
+        if self.window_size:
+            w, c, bs = self.window_size, self.chunk_size, self.block_size
+            if c <= 0 or w % c or w % bs or bs % c or (w // c) % bs:
+                raise ValueError(
+                    f"window_size {w} / chunk_size {c} / block_size {bs}: a "
+                    "window is whole chunks and whole blocks, a block whole "
+                    "chunks, and a window's summaries (window_size / "
+                    "chunk_size rows) whole blocks")
+            if kv_dtype is not None:
+                raise NotImplementedError(
+                    "kv_cache_dtype with a window-plus-summary cache")
+            self.summary_blocks = w // c // bs
         shape = (num_layers, 2, num_blocks, block_size, num_kv_heads,
                  head_dim)
         if kv_dtype is None:
@@ -114,7 +156,46 @@ class BlockedKVCache:
         self.allocator._free.discard(0)
 
     def blocks_for(self, num_tokens):
-        return -(-num_tokens // self.block_size)
+        """Blocks a sequence of ``num_tokens`` holds — the ONE function that
+        capacity, deferral, the block-table row's width and the scheduler's
+        claims go through.  Window-plus-summary: the summaries of the closed
+        windows, the summaries of the current window in the making, and the
+        current window's own blocks (a window that is full stays open until
+        the first token after it arrives)."""
+        if not self.window_size or num_tokens <= 0:
+            return -(-num_tokens // self.block_size)
+        closed = (num_tokens - 1) // self.window_size
+        inside = num_tokens - closed * self.window_size
+        return (closed + 1) * self.summary_blocks \
+            + -(-inside // self.block_size)
+
+    def peak_blocks_for(self, num_tokens, start=0):
+        """The most blocks the sequence holds on its way from ``start`` to
+        ``num_tokens`` tokens: what admission has to set aside.  Without a
+        window that is ``blocks_for(num_tokens)``; with one, a sequence holds
+        most when a window is full."""
+        peak = self.blocks_for(num_tokens)
+        if self.window_size:
+            full = num_tokens // self.window_size * self.window_size
+            if full >= max(start, 1):
+                peak = max(peak, self.blocks_for(full))
+        return peak
+
+    def row_width(self, max_context):
+        """Columns of a sequence's block-table row."""
+        if not self.window_size:
+            return -(-int(max_context) // self.block_size)
+        closed = (int(max_context) - 1) // self.window_size
+        return (closed + 1) * self.summary_blocks \
+            + self.window_size // self.block_size
+
+    def run_room(self, seen_tokens):
+        """Tokens that may follow ``seen_tokens`` in ONE step (None: no
+        limit): a step does not straddle a window's end, because the rows on
+        both sides of it would need different block-table rows."""
+        if not self.window_size:
+            return None
+        return self.window_size - seen_tokens % self.window_size
 
 
 class DSStateManager:
@@ -125,8 +206,10 @@ class DSStateManager:
         self.config = config
         self.kv_cache = kv_cache
         self.max_seqs = int(config.max_ragged_sequence_count)
-        self.max_blocks_per_seq = -(-int(config.max_context) //
-                                    kv_cache.block_size)
+        self.max_blocks_per_seq = kv_cache.row_width(config.max_context)
+        #: the longest context a sequence may reach (max_context, in blocks)
+        self.max_tokens = -(-int(config.max_context) // kv_cache.block_size) \
+            * kv_cache.block_size
         self._seqs: Dict[int, DSSequenceDescriptor] = {}
         # slot 0 is reserved for padding tokens (its block-table row stays
         # zero, pointing at the garbage block)
@@ -153,33 +236,87 @@ class DSStateManager:
         self._seqs[uid] = seq
         return seq
 
+    def _check_context(self, seq, total_tokens):
+        if total_tokens > self.max_tokens:
+            raise RuntimeError(
+                f"sequence {seq.uid} exceeds max_context "
+                f"({total_tokens} tokens > {self.max_tokens})")
+
+    def _take(self, seq, column, kind=None):
+        blk = self.kv_cache.allocator.allocate(1)[0]
+        self.block_table[seq.slot, column] = blk
+        seq.blocks.append(blk)
+        if kind is not None:
+            kind.append(blk)
+
     def ensure_capacity(self, seq: DSSequenceDescriptor, total_tokens):
         """Grow the sequence's block list to hold ``total_tokens``."""
-        need = self.kv_cache.blocks_for(total_tokens)
-        if need > self.max_blocks_per_seq:
+        self._check_context(seq, total_tokens)
+        kv = self.kv_cache
+        if not kv.window_size:
+            need = kv.blocks_for(total_tokens)
+            while len(seq.blocks) < need:
+                self._take(seq, len(seq.blocks))
+            return
+        if total_tokens <= 0:
+            return
+        closed = (total_tokens - 1) // kv.window_size
+        if seq.seen_tokens < closed * kv.window_size:
             raise RuntimeError(
-                f"sequence {seq.uid} exceeds max_context "
-                f"({total_tokens} tokens > "
-                f"{self.max_blocks_per_seq * self.kv_cache.block_size})")
-        while len(seq.blocks) < need:
-            blk = self.kv_cache.allocator.allocate(1)[0]
-            self.block_table[seq.slot, len(seq.blocks)] = blk
-            seq.blocks.append(blk)
+                f"sequence {seq.uid}: a step may not straddle a window's end "
+                f"({seq.seen_tokens} tokens seen, {total_tokens} wanted, "
+                f"window {kv.window_size})")
+        if closed * kv.summary_blocks > len(seq.summary_blocks):
+            self._close_window(seq)
+        base = len(seq.summary_blocks)
+        while len(seq.making_blocks) < kv.summary_blocks:
+            self._take(seq, self.max_blocks_per_seq - kv.summary_blocks
+                       + len(seq.making_blocks), seq.making_blocks)
+        inside = total_tokens - closed * kv.window_size
+        while len(seq.window_blocks) < -(-inside // kv.block_size):
+            self._take(seq, base + len(seq.window_blocks), seq.window_blocks)
+
+    def _close_window(self, seq):
+        """The window is full and the next token is about to arrive: its
+        exact K/V go back to the pool, its summaries become readable."""
+        self.kv_cache.allocator.free(seq.window_blocks)
+        seq.summary_blocks += seq.making_blocks
+        seq.window_blocks, seq.making_blocks = [], []
+        seq.blocks = list(seq.summary_blocks)
+        row = self.block_table[seq.slot]
+        row[:] = 0
+        row[:len(seq.blocks)] = seq.blocks
 
     def schedulable_tokens(self, seq: DSSequenceDescriptor, want_total):
-        """How many of the tokens up to ``want_total`` can be scheduled with
-        the blocks this sequence holds plus the allocator's free pool (the
-        reference scheduler's can-schedule check — a sequence the pool
-        cannot grow defers instead of crashing the engine step).  Raises
-        only for the max_context user error."""
-        if self.kv_cache.blocks_for(want_total) > self.max_blocks_per_seq:
-            raise RuntimeError(
-                f"sequence {seq.uid} exceeds max_context "
-                f"({want_total} tokens > "
-                f"{self.max_blocks_per_seq * self.kv_cache.block_size})")
-        affordable = ((len(seq.blocks) + self.free_blocks)
-                      * self.kv_cache.block_size)
-        return max(0, min(want_total, affordable) - seq.seen_tokens)
+        """How many of the tokens up to ``want_total`` can be scheduled in
+        ONE step with the blocks this sequence holds plus the allocator's
+        free pool (the reference scheduler's can-schedule check — a sequence
+        the pool cannot grow defers instead of crashing the engine step),
+        and, with a window, without passing the window's end.  Raises only
+        for the max_context user error."""
+        self._check_context(seq, want_total)
+        kv = self.kv_cache
+        if not kv.window_size:
+            affordable = ((len(seq.blocks) + self.free_blocks)
+                          * kv.block_size)
+            return max(0, min(want_total, affordable) - seq.seen_tokens)
+        seen = seq.seen_tokens
+        want_total = min(want_total, seen + kv.run_room(seen))
+        if want_total <= seen:
+            return 0
+        closed = (want_total - 1) // kv.window_size
+        if closed * kv.summary_blocks > len(seq.summary_blocks):
+            # the close gives the window's blocks back, and the next window
+            # starts with the blocks of its summaries
+            pool = self.free_blocks + len(seq.window_blocks) \
+                - kv.summary_blocks
+            held = 0
+        else:
+            pool = self.free_blocks \
+                - (kv.summary_blocks - len(seq.making_blocks))
+            held = len(seq.window_blocks)
+        affordable = closed * kv.window_size + (held + pool) * kv.block_size
+        return max(0, min(want_total, affordable) - seen)
 
     def flush_sequence(self, uid):
         """Release a sequence (reference ``flush``)."""

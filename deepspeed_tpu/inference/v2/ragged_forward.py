@@ -17,11 +17,15 @@ prefill chunk is causal within itself and sees all earlier chunks, and a
 decode token sees the whole prefix.  Exactly FastGen's ragged semantics.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
+from ...models.evabyte import chunk_summaries
 from ...models.llama import _rope_freqs
 from ...telemetry import names as _names
+from .ragged import window_row_positions
 
 
 def _program(name, **jit_kwargs):
@@ -190,9 +194,15 @@ def _kv_set(kv_data, l, kv_layer):
 def _ragged_attention_block(lp_attn, h, kv_layer, blk, off, block_tables,
                             seq_slots, positions, cos, sin, *, cfg, block_size,
                             rotary=True, rotary_dim=None,
-                            layout=(0, 0), use_kernel=True, kv_dtype=None):
+                            layout=(0, 0), use_kernel=True, kv_dtype=None,
+                            row_positions=None, after_scatter=None):
     """Shared attention sub-block: qkv → rotary → cache scatter → paged
     attention → output projection.  Returns (attn_out [T, D], new kv_layer).
+    ``row_positions`` (default: ``positions``) are the positions inside the
+    block-table row that the paged attention masks by, where they are not
+    the positions the rotary turns by; ``after_scatter(kv_layer)`` may add
+    to the layer's cache between the scatter and the attention (a
+    window-plus-summary cache: ``evabyte_ragged_step``).
     kv_layer: [2, num_blocks, bs, Hkv, Dh] — or, with ``kv_dtype`` set, the
     quantized pair ``(data [2, nb, bs, Hkv, Dh] narrow, scales [2, nb, bs, Hkv]
     f32)``: K/V rows are encoded once on the scatter write and dequantized
@@ -215,6 +225,8 @@ def _ragged_attention_block(lp_attn, h, kv_layer, blk, off, block_tables,
     if kv_dtype is None:
         kv_layer = kv_layer.at[0, blk, off].set(k.astype(kv_layer.dtype))
         kv_layer = kv_layer.at[1, blk, off].set(v.astype(kv_layer.dtype))
+        if after_scatter is not None:
+            kv_layer = after_scatter(kv_layer)
         k_cache, v_cache = kv_layer[0], kv_layer[1]
         kv_scales = None
     else:
@@ -231,7 +243,8 @@ def _ragged_attention_block(lp_attn, h, kv_layer, blk, off, block_tables,
         k_cache, v_cache = data[0], data[1]
         kv_scales = (scales[0], scales[1])
     out = _paged_attention(q, k_cache, v_cache, block_tables, seq_slots,
-                           positions, block_size,
+                           positions if row_positions is None
+                           else row_positions, block_size,
                            window=getattr(cfg, "sliding_window", 0),
                            layout=layout, use_kernel=use_kernel,
                            kv_scales=kv_scales)
@@ -240,6 +253,15 @@ def _ragged_attention_block(lp_attn, h, kv_layer, blk, off, block_tables,
     if "bias" in lp_attn["o_proj"]:
         o = o + lp_attn["o_proj"]["bias"].astype(dtype)
     return o, kv_layer
+
+
+def _swiglu(x, h2, mlp, dtype):
+    """``x + SwiGLU(h2)``."""
+    with jax.named_scope(_names.SCOPE_MLP):
+        gate = h2 @ mlp["gate_proj"]["kernel"].astype(dtype)
+        up = h2 @ mlp["up_proj"]["kernel"].astype(dtype)
+        return x + (jax.nn.silu(gate) * up) @ mlp["down_proj"][
+            "kernel"].astype(dtype)
 
 
 @_ragged_program("llama")
@@ -275,7 +297,6 @@ def llama_ragged_step(params, kv_data, token_ids, positions, seq_slots,
 
     for l in range(cfg.num_hidden_layers):
         lp = params[f"layers_{l}"]
-        mlp = lp["mlp"]
         h = _rmsnorm(x, lp["input_layernorm"]["weight"], eps)
         # scatter this batch's K/V into the paged cache (linear_blocked_kv_
         # rotary analog), then attend against the updated pages
@@ -286,11 +307,7 @@ def llama_ragged_step(params, kv_data, token_ids, positions, seq_slots,
         kv_data = _kv_set(kv_data, l, kv_layer)
         x = x + attn_out
         h2 = _rmsnorm(x, lp["post_attention_layernorm"]["weight"], eps)
-        with jax.named_scope(_names.SCOPE_MLP):
-            gate = h2 @ mlp["gate_proj"]["kernel"].astype(dtype)
-            up = h2 @ mlp["up_proj"]["kernel"].astype(dtype)
-            x = x + (jax.nn.silu(gate) * up) @ mlp["down_proj"][
-                "kernel"].astype(dtype)
+        x = _swiglu(x, h2, lp["mlp"], dtype)
 
     return _lm_head(params, x, last_token_idx, cfg), kv_data
 
@@ -507,11 +524,105 @@ def phi_ragged_step(params, kv_data, token_ids, positions, seq_slots,
     return _head_logits(params, x, last_token_idx), kv_data
 
 
+def _eva_summaries(kv_layer, phi, mu, block_tables, seq_slots, positions,
+                    row_pos, *, chunk, per_window, block_size):
+    """Pool every chunk this step completes and write its summary: the rows
+    whose position ends a chunk, at most ``T // chunk`` + one a sequence.
+    The chunk's K/V are read back from the cache (its first tokens may have
+    arrived in earlier steps); ``k~ = sum_m a_m k_m + mu``, ``v~ = sum_m a_m
+    v_m`` with ``a = softmax_m(k_m . phi)`` in float32.  The summary goes to
+    the sequence's summary block in the making (the last columns of its
+    block-table row), where no query sees it until the window closes."""
+    with jax.named_scope(_names.SCOPE_EVA_SUMMARY):
+        T = positions.shape[0]
+        maxb = block_tables.shape[1]
+        ends = (seq_slots != 0) & (positions % chunk == chunk - 1)
+        n = T // chunk + block_tables.shape[0]
+        rows = jnp.nonzero(ends, size=n, fill_value=0)[0]
+        slot = jnp.where(jnp.arange(n) < jnp.sum(ends), seq_slots[rows], 0)
+        first = row_pos[rows] - (chunk - 1)        # the chunk's first row
+        blk = block_tables[slot, first // block_size][:, None]
+        off = (first % block_size)[:, None] + jnp.arange(chunk)[None, :]
+        # [n, C, H, Dh]: each completed chunk as a sequence of one chunk
+        ks, vs = chunk_summaries(kv_layer[0, blk, off], kv_layer[1, blk, off],
+                                 phi, mu, chunk)
+        ks, vs = ks[:, 0], vs[:, 0]
+        j = (positions[rows] // chunk) % per_window   # its place in the window
+        sblk = block_tables[slot, maxb - per_window // block_size
+                            + j // block_size]
+        kv_layer = kv_layer.at[0, sblk, j % block_size].set(
+            ks.astype(kv_layer.dtype))
+        return kv_layer.at[1, sblk, j % block_size].set(
+            vs.astype(kv_layer.dtype))
+
+
+@_ragged_program("evabyte")
+def evabyte_ragged_step(params, kv_data, token_ids, positions, seq_slots,
+                        block_tables, last_token_idx, *, cfg, block_size,
+                        layout=(0, 0), use_kernel=True, kv_dtype=None):
+    """One ragged engine iteration for EvaByte (``models/evabyte.py`` has the
+    layer's equations).  A sequence's block-table row is ``[summary blocks
+    of its closed windows | blocks of its current window | ... | the summary
+    block in the making]`` (``ragged.py``): the rotary turns by the real
+    position, the cache is addressed and the causal mask taken by the
+    position inside that row, so plain paged attention over the row IS the
+    one softmax over the window's exact keys and the earlier windows'
+    summaries.  The summaries of the chunks this step completes are made and
+    written before the attention runs.  No row of a step lies beyond its
+    sequence's window end (the batch builder's and the burst's bound).  The
+    residual stream is float32 (``fp32_skip_add``); all ``num_pred_heads``
+    heads are computed, head 0's logits (the next byte) are returned."""
+    if kv_dtype is not None:
+        raise NotImplementedError("kv_cache_dtype with EvaByte")
+    dtype = jnp.dtype(cfg.dtype)
+    eps = cfg.rms_norm_eps
+    window, chunk = cfg.window_size, cfg.chunk_size
+    per_window = window // chunk
+    cos, sin = _rope_freqs(cfg.head_dim, cfg.max_position_embeddings,
+                           cfg.rope_theta)
+    cos = jnp.asarray(cos, jnp.float32)
+    sin = jnp.asarray(sin, jnp.float32)
+    unit = 1.0 if cfg.norm_add_unit_offset else 0.0
+    norm = lambda x, p: _rmsnorm(               # the offset g is a column
+        x, unit + p["weight"][:, 0].astype(jnp.float32), eps).astype(dtype)
+
+    with jax.named_scope(_names.SCOPE_EMBED):
+        x = params["embed_tokens"]["embedding"][token_ids].astype(dtype) \
+            .astype(jnp.float32)
+    row_pos = window_row_positions(positions, window, chunk)
+    blk = block_tables[seq_slots, row_pos // block_size]
+    off = row_pos % block_size
+
+    for l in range(cfg.num_hidden_layers):
+        lp = params[f"layers_{l}"]
+        attn = lp["self_attn"]
+        summarise = functools.partial(
+            _eva_summaries, phi=attn["eva_phi"], mu=attn["eva_mu"],
+            block_tables=block_tables, seq_slots=seq_slots,
+            positions=positions, row_pos=row_pos, chunk=chunk,
+            per_window=per_window, block_size=block_size)
+        attn_out, kv_layer = _ragged_attention_block(
+            attn, norm(x, lp["input_layernorm"]), _kv_layer(kv_data, l), blk,
+            off, block_tables, seq_slots, positions, cos, sin, cfg=cfg,
+            block_size=block_size, layout=layout, use_kernel=use_kernel,
+            row_positions=row_pos, after_scatter=summarise)
+        kv_data = _kv_set(kv_data, l, kv_layer)
+        x = x + attn_out.astype(jnp.float32)
+        x = _swiglu(x, norm(x, lp["post_attention_layernorm"]), lp["mlp"],
+                    dtype)
+
+    with jax.named_scope(_names.SCOPE_LM_HEAD):
+        xl = norm(x, params["norm"])[last_token_idx].astype(jnp.float32)
+        heads = xl @ params["lm_head"]["kernel"].astype(jnp.float32)
+    return heads[:, :cfg.vocab_size], kv_data
+
+
 RAGGED_FORWARDS = {"LlamaModel": llama_ragged_step,
                    "MixtralModel": mixtral_ragged_step,
                    "FalconModel": falcon_ragged_step,
                    "OPTModel": opt_ragged_step,
-                   "PhiModel": phi_ragged_step}
+                   "PhiModel": phi_ragged_step,
+                   "EvaByteModel": evabyte_ragged_step}
 
 
 def _device_sample(logits, key, temperature, top_k, top_p):

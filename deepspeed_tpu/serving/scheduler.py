@@ -125,7 +125,7 @@ class ServingScheduler:
         reserve = self.config.kv_admit_reserve_tokens
         if reserve is None:
             reserve = self.engine.kv_cache.block_size   # one decode block
-        return self.engine.kv_cache.blocks_for(
+        return self.engine.kv_cache.peak_blocks_for(
             len(req.resume_tokens) + int(reserve))
 
     def _outstanding_claims(self):
@@ -141,8 +141,9 @@ class ServingScheduler:
         total = 0
         for uid in self._running:
             seq = sm.get_sequence(uid)
-            total += max(0, self.engine.kv_cache.blocks_for(
-                len(seq.tokens) + int(reserve)) - len(seq.blocks))
+            total += max(0, self.engine.kv_cache.peak_blocks_for(
+                len(seq.tokens) + int(reserve), start=seq.seen_tokens)
+                - len(seq.blocks))
         return total
 
     def _admit(self):

@@ -57,7 +57,12 @@ KIND_BURST = "burst"
 SERVE_STEP_COUNTS = ("step", "kind", "running", "queued", "token_budget",
                      "live_tokens", "prefill_tokens", "decode_tokens",
                      "grid_pages", "live_pages", "row_pages", "burst_k",
-                     "preempts")
+                     "preempts",
+                     # what the cache holds, and what a window-plus-summary
+                     # cache (EvaByte) did in the step
+                     "context_tokens", "held_blocks", "block_size",
+                     "summary_pages",
+                     "chunks_closed", "windows_closed")
 
 # ---- jitted programs (``XLA Modules`` events are ``jit_<name>(<id>)``)
 PROGRAM_MICRO = "ds_micro_"               # + the micro-step variant
@@ -77,6 +82,8 @@ SCOPE_MLP = "ds.mlp"                      # serving: the MLP or expert block
 SCOPE_NORM = "ds.norm"                    # serving: rms / layer norms
 SCOPE_KV_CACHE = "ds.kv_cache"            # serving: a layer's slice of the paged
 #                                           cache taken out and written back
+SCOPE_EVA_SUMMARY = "ds.eva_summary"      # serving: pooling the chunks a step
+#                                           completes, and their scatter
 MODULE_ATTENTION = "self_attn"            # flax module name (training)
 MODULE_MLP = "mlp"
 
