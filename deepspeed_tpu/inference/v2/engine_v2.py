@@ -143,16 +143,15 @@ class InferenceEngineV2:
             # shard_params_for_tp via the divisibility fallback)
             self._kv_sharding = NamedSharding(
                 self._tp_mesh,
-                P() if kv_replicated
-                else P(None, None, None, None, "tp", None))
+                P() if kv_replicated else P(None, None, "tp", None))
             # quantized KV × tp (ROADMAP serving follow-on (b)): the
-            # per-(layer, k/v, token, head) f32 scales shard WITH the
+            # per-(token, head) f32 scales shard WITH the
             # cache — their trailing dim IS the kv-head dim the cache
             # shards on, so each rank holds exactly the scales of its own
             # cache shard and the on-read dequant stays rank-local
             self._kv_scales_sharding = NamedSharding(
                 self._tp_mesh,
-                P() if kv_replicated else P(None, None, None, None, "tp"))
+                P() if kv_replicated else P(None, None, "tp"))
         else:
             self._kv_sharding = None
             self._kv_scales_sharding = None
@@ -182,21 +181,17 @@ class InferenceEngineV2:
         #: burst): the counts of ``names.SERVE_STEP_COUNTS`` that the batch
         #: builder knows; the scheduler's ``ds:serve.step`` span carries them
         self.last_step_counts = None
-        # the device-side cache the step functions thread: a plain array
-        # (fp path) or the (data, scales) pytree (quantized path)
-        self._kv = self.kv_cache.data if self._kv_dtype is None \
-            else (self.kv_cache.data, self.kv_cache.scales)
+        # the device-side cache the step functions thread (and donate): one
+        # (k_pages, v_pages[, k_scales, v_scales]) entry a layer
         if self._kv_sharding is not None:
-            if self._kv_dtype is None:
-                self._kv = jax.device_put(self._kv, self._kv_sharding)
-                # drop the replicated original — a full unsharded cache
-                # pinned to device 0 would defeat the point of sharding it
-                self.kv_cache.data = self._kv
-            else:
-                self._kv = jax.device_put(
-                    self._kv,
-                    (self._kv_sharding, self._kv_scales_sharding))
-                self.kv_cache.data, self.kv_cache.scales = self._kv
+            # replaces the replicated original — a full unsharded cache
+            # pinned to device 0 would defeat the point of sharding it
+            layer = (self._kv_sharding, ) * 2 \
+                + (self._kv_scales_sharding, ) * 2
+            self.kv_cache.layers = jax.device_put(
+                self.kv_cache.layers,
+                tuple(layer[:len(entry)] for entry in self.kv_cache.layers))
+        self._kv = self.kv_cache.layers
         logger.info(
             f"InferenceEngineV2: budget={self._budget} blocks={num_blocks}"
             f"×{block_size} max_seqs={self.state_manager.max_seqs}")
@@ -439,7 +434,7 @@ class InferenceEngineV2:
         total = closed[:, cut:].reshape(-1, atom).max(axis=1).sum() \
             if atom else 0
         if not _pa.run_tiled(cfg.num_key_value_heads, cfg.head_dim,
-                             kv.data.dtype):
+                             kv.dtype):
             return int(total + closed[:, :cut].sum())
         tq = _pa.tile_rows(cfg.num_attention_heads, cfg.num_key_value_heads,
                            cut)
@@ -482,7 +477,7 @@ class InferenceEngineV2:
         decode_cap, atom = layout
         cut = decode_cap if atom else pos.shape[1]
         if _pa.run_tiled(cfg.num_key_value_heads, cfg.head_dim,
-                         self.kv_cache.data.dtype):
+                         self.kv_cache.dtype):
             tq = _pa.tile_rows(cfg.num_attention_heads,
                                cfg.num_key_value_heads, cut)
             grid = live = _pa.page_loads(slots[:, :cut], pos[:, :cut], tq,

@@ -3,10 +3,12 @@
 ``BlockedKVCache`` (paged KV storage, ``kv_cache.py``),
 ``DSSequenceDescriptor`` + ``DSStateManager`` (``ragged_manager.py:19``).
 
-TPU shape discipline: the cache is ONE array per model —
-``[L, 2, num_blocks, block_size, Hkv, Dh]`` — and every sequence owns a row
-of a fixed-width block table ``[max_seqs, max_blocks_per_seq]``; the jitted
-ragged forward only ever sees static shapes (the "ragged" part is metadata).
+TPU shape discipline: the cache is one buffer a layer for K and one for V —
+``[num_blocks, block_size, Hkv, Dh]`` each — so that a step scatters into a
+layer's pages in place and hands the buffer itself to the paged kernel; and
+every sequence owns a row of a fixed-width block table ``[max_seqs,
+max_blocks_per_seq]``; the jitted ragged forward only ever sees static shapes
+(the "ragged" part is metadata).
 
 Two kinds of state in one cache (``attention_class == "eva"``, EvaByte): a
 sequence keeps the exact K/V of its CURRENT window of ``window_size`` tokens
@@ -107,14 +109,17 @@ class DSSequenceDescriptor:
 
 
 class BlockedKVCache:
-    """Paged KV storage (reference ``kv_cache.py``): one jnp array
-    ``[L, 2, num_blocks, block_size, Hkv, Dh]`` + the allocator.
+    """Paged KV storage (reference ``kv_cache.py``) + the allocator.
 
-    With ``kv_dtype`` set ("int8"/"fp8" — ``kv_codec.py``), the cache is the
-    quantized-serving layout instead: ``data`` holds the same shape in the
-    narrow storage dtype and ``scales`` holds one f32 per (layer, k/v,
-    block, position, kv-head) row — the pair travels through the jitted
-    ragged step as one ``(data, scales)`` pytree."""
+    ``layers`` is the device-side cache as the step programs thread it: one
+    entry a layer, ``(k_pages, v_pages)``, each ``[num_blocks, block_size,
+    Hkv, Dh]`` and a buffer of its own, so a donated step updates it in place
+    (no layer is ever taken out of, or written back into, a larger array).
+
+    With ``kv_dtype`` set ("int8"/"fp8" — ``kv_codec.py``), the pages hold
+    the narrow storage dtype and a layer's entry is ``(k_pages, v_pages,
+    k_scales, v_scales)``: one f32 per (block, position, kv-head) row,
+    ``[num_blocks, block_size, Hkv]``."""
 
     def __init__(self, num_layers, num_blocks, block_size, num_kv_heads,
                  head_dim, dtype=jnp.bfloat16, kv_dtype=None, window_size=0,
@@ -139,17 +144,20 @@ class BlockedKVCache:
                 raise NotImplementedError(
                     "kv_cache_dtype with a window-plus-summary cache")
             self.summary_blocks = w // c // bs
-        shape = (num_layers, 2, num_blocks, block_size, num_kv_heads,
-                 head_dim)
+        shape = (num_blocks, block_size, num_kv_heads, head_dim)
         if kv_dtype is None:
-            self.data = jnp.zeros(shape, dtype=dtype)
-            self.scales = None
+            self.dtype = jnp.dtype(dtype)
         else:
             from .kv_codec import storage_dtype
-            self.data = jnp.zeros(shape, dtype=storage_dtype(kv_dtype))
-            # scale=1 for never-written positions keeps dequant a no-op on
-            # the zero payload (garbage block included)
-            self.scales = jnp.ones(shape[:5], dtype=jnp.float32)
+            self.dtype = jnp.dtype(storage_dtype(kv_dtype))
+        #: every leaf a buffer of its own (never a view of a shared one).
+        #: scale=1 for never-written positions keeps dequant a no-op on the
+        #: zero payload (garbage block included)
+        self.layers = tuple(
+            tuple(jnp.zeros(shape, self.dtype) for _ in "kv")
+            + tuple(jnp.ones(shape[:3], jnp.float32)
+                    for _ in ("kv" if kv_dtype else ""))
+            for _ in range(int(num_layers)))
         self.allocator = BlockedAllocator(num_blocks)
         # block 0 is the garbage sink: padding tokens in the ragged buffer
         # scatter their K/V there (their slot-0 block-table row is all zeros)

@@ -170,24 +170,21 @@ def _head_logits(params, x, last_token_idx, embed_key="embed_tokens"):
 
 
 @jax.named_scope(_names.SCOPE_KV_CACHE)
-def _kv_layer(kv_data, l):
-    """Layer ``l`` view of the cache pytree: an array slice on the fp path,
-    a ``(data_l, scales_l)`` pair on the quantized path."""
-    if isinstance(kv_data, tuple):
-        data, scales = kv_data
-        return (data[l], scales[l])
-    return kv_data[l]
-
-
-@jax.named_scope(_names.SCOPE_KV_CACHE)
-def _kv_set(kv_data, l, kv_layer):
-    """Write layer ``l`` back into the cache pytree (inverse of
-    :func:`_kv_layer`)."""
-    if isinstance(kv_data, tuple):
-        data, scales = kv_data
-        layer_data, layer_scales = kv_layer
-        return (data.at[l].set(layer_data), scales.at[l].set(layer_scales))
-    return kv_data.at[l].set(kv_layer)
+def _kv_scatter(kv_layer, k, v, blk, off, kv_dtype=None):
+    """Everything a step spends to put its K/V rows ``[T, Hkv, Dh]`` into a
+    layer's pages at ``(blk, off)``: the scatter into the donated buffers
+    and, on the quantized path, the encoding and the scale scatter."""
+    if kv_dtype is None:
+        k_pages, v_pages = kv_layer
+        return (k_pages.at[blk, off].set(k.astype(k_pages.dtype)),
+                v_pages.at[blk, off].set(v.astype(v_pages.dtype)))
+    from .kv_codec import codec
+    encode, _ = codec(kv_dtype)
+    k_pages, v_pages, k_scales, v_scales = kv_layer
+    qk, sk = encode(k)          # [T, Hkv, Dh] narrow, [T, Hkv] f32
+    qv, sv = encode(v)
+    return (k_pages.at[blk, off].set(qk), v_pages.at[blk, off].set(qv),
+            k_scales.at[blk, off].set(sk), v_scales.at[blk, off].set(sv))
 
 
 @jax.named_scope(_names.SCOPE_ATTENTION)
@@ -203,11 +200,15 @@ def _ragged_attention_block(lp_attn, h, kv_layer, blk, off, block_tables,
     the positions the rotary turns by; ``after_scatter(kv_layer)`` may add
     to the layer's cache between the scatter and the attention (a
     window-plus-summary cache: ``evabyte_ragged_step``).
-    kv_layer: [2, num_blocks, bs, Hkv, Dh] — or, with ``kv_dtype`` set, the
-    quantized pair ``(data [2, nb, bs, Hkv, Dh] narrow, scales [2, nb, bs, Hkv]
-    f32)``: K/V rows are encoded once on the scatter write and dequantized
-    on read inside the paged attention (``kv_codec.py``).  ``rotary_dim`` <
-    head_dim → partial rotary (phi family)."""
+    kv_layer: the layer's entry of the cache (``ragged.BlockedKVCache``),
+    ``(k_pages, v_pages)``, each [num_blocks, bs, Hkv, Dh] and a donated
+    buffer of its own: the scatter updates it in place and the paged
+    attention reads that buffer, so no step ever copies a layer's pages.
+    With ``kv_dtype`` set the entry is ``(k_pages, v_pages, k_scales,
+    v_scales)`` (narrow pages, scales [num_blocks, bs, Hkv] f32): K/V rows
+    are encoded once on the scatter write and dequantized on read inside
+    the paged attention (``kv_codec.py``).  ``rotary_dim`` < head_dim →
+    partial rotary (phi family)."""
     dtype = jnp.dtype(cfg.dtype)
     H, Dh = cfg.num_attention_heads, cfg.head_dim
     q = _qkv(h, lp_attn["q_proj"], dtype)
@@ -222,26 +223,11 @@ def _ragged_attention_block(lp_attn, h, kv_layer, blk, off, block_tables,
         else:
             q = _rotary(q, cos, sin, positions)
             k = _rotary(k, cos, sin, positions)
-    if kv_dtype is None:
-        kv_layer = kv_layer.at[0, blk, off].set(k.astype(kv_layer.dtype))
-        kv_layer = kv_layer.at[1, blk, off].set(v.astype(kv_layer.dtype))
-        if after_scatter is not None:
-            kv_layer = after_scatter(kv_layer)
-        k_cache, v_cache = kv_layer[0], kv_layer[1]
-        kv_scales = None
-    else:
-        from .kv_codec import codec
-        encode, _ = codec(kv_dtype)
-        data, scales = kv_layer
-        qk, sk = encode(k)          # [T, Hkv, Dh] narrow, [T, Hkv] f32
-        qv, sv = encode(v)
-        data = data.at[0, blk, off].set(qk)
-        data = data.at[1, blk, off].set(qv)
-        scales = scales.at[0, blk, off].set(sk)
-        scales = scales.at[1, blk, off].set(sv)
-        kv_layer = (data, scales)
-        k_cache, v_cache = data[0], data[1]
-        kv_scales = (scales[0], scales[1])
+    kv_layer = _kv_scatter(kv_layer, k, v, blk, off, kv_dtype)
+    if after_scatter is not None:
+        kv_layer = after_scatter(kv_layer)
+    k_cache, v_cache = kv_layer[:2]
+    kv_scales = kv_layer[2:] or None
     out = _paged_attention(q, k_cache, v_cache, block_tables, seq_slots,
                            positions if row_positions is None
                            else row_positions, block_size,
@@ -272,7 +258,10 @@ def llama_ragged_step(params, kv_data, token_ids, positions, seq_slots,
 
     Args:
       params: LlamaModel param tree (``models/llama.py`` naming).
-      kv_data: [L, 2, num_blocks, bs, Hkv, Dh] paged cache (donated).
+      kv_data: the paged cache (donated): one ``(k_pages, v_pages)`` entry
+        a layer, each [num_blocks, bs, Hkv, Dh] and a buffer of its own
+        (``ragged.BlockedKVCache.layers``; with ``kv_dtype``, the two scale
+        arrays beside them).  Every leaf comes back aliased to its input.
       token_ids/positions/seq_slots: [T] flat batch (padding: slot 0 = the
         reserved garbage block row, position 0).
       block_tables: [max_seqs, maxb] int32.
@@ -295,21 +284,21 @@ def llama_ragged_step(params, kv_data, token_ids, positions, seq_slots,
     blk = block_tables[seq_slots, positions // block_size]   # [T]
     off = positions % block_size
 
+    kv_data = list(kv_data)
     for l in range(cfg.num_hidden_layers):
         lp = params[f"layers_{l}"]
         h = _rmsnorm(x, lp["input_layernorm"]["weight"], eps)
         # scatter this batch's K/V into the paged cache (linear_blocked_kv_
         # rotary analog), then attend against the updated pages
-        attn_out, kv_layer = _ragged_attention_block(
-            lp["self_attn"], h, _kv_layer(kv_data, l), blk, off, block_tables,
+        attn_out, kv_data[l] = _ragged_attention_block(
+            lp["self_attn"], h, kv_data[l], blk, off, block_tables,
             seq_slots, positions, cos, sin, cfg=cfg, block_size=block_size,
             layout=layout, use_kernel=use_kernel, kv_dtype=kv_dtype)
-        kv_data = _kv_set(kv_data, l, kv_layer)
         x = x + attn_out
         h2 = _rmsnorm(x, lp["post_attention_layernorm"]["weight"], eps)
         x = _swiglu(x, h2, lp["mlp"], dtype)
 
-    return _lm_head(params, x, last_token_idx, cfg), kv_data
+    return _lm_head(params, x, last_token_idx, cfg), tuple(kv_data)
 
 
 @jax.named_scope(_names.SCOPE_LM_HEAD)
@@ -345,14 +334,14 @@ def mixtral_ragged_step(params, kv_data, token_ids, positions, seq_slots,
     blk = block_tables[seq_slots, positions // block_size]
     off = positions % block_size
 
+    kv_data = list(kv_data)
     for l in range(cfg.num_hidden_layers):
         lp = params[f"layers_{l}"]
         h = _rmsnorm(x, lp["input_layernorm"]["weight"], eps)
-        attn_out, kv_layer = _ragged_attention_block(
-            lp["self_attn"], h, _kv_layer(kv_data, l), blk, off, block_tables,
+        attn_out, kv_data[l] = _ragged_attention_block(
+            lp["self_attn"], h, kv_data[l], blk, off, block_tables,
             seq_slots, positions, cos, sin, cfg=cfg, block_size=block_size,
             layout=layout, use_kernel=use_kernel, kv_dtype=kv_dtype)
-        kv_data = _kv_set(kv_data, l, kv_layer)
         x = x + attn_out
         h2 = _rmsnorm(x, lp["post_attention_layernorm"]["weight"], eps)
         with jax.named_scope(_names.SCOPE_MLP):
@@ -376,7 +365,7 @@ def mixtral_ragged_step(params, kv_data, token_ids, positions, seq_slots,
                     moe_out.dtype)
         x = x + moe_out
 
-    return _lm_head(params, x, last_token_idx, cfg), kv_data
+    return _lm_head(params, x, last_token_idx, cfg), tuple(kv_data)
 
 
 @jax.named_scope(_names.SCOPE_NORM)
@@ -409,6 +398,7 @@ def falcon_ragged_step(params, kv_data, token_ids, positions, seq_slots,
     off = positions % block_size
     acfg = _attn_cfg_view(cfg)
 
+    kv_data = list(kv_data)
     for l in range(cfg.num_hidden_layers):
         lp = params[f"h_{l}"]
         if cfg.new_decoder_architecture:
@@ -418,11 +408,10 @@ def falcon_ragged_step(params, kv_data, token_ids, positions, seq_slots,
             h_attn = h_mlp = _layernorm(x, lp["input_layernorm"], eps)
         attn_params = {"q_proj": lp["q_proj"], "k_proj": lp["k_proj"],
                        "v_proj": lp["v_proj"], "o_proj": lp["dense"]}
-        attn_out, kv_layer = _ragged_attention_block(
-            attn_params, h_attn, _kv_layer(kv_data, l), blk, off, block_tables,
+        attn_out, kv_data[l] = _ragged_attention_block(
+            attn_params, h_attn, kv_data[l], blk, off, block_tables,
             seq_slots, positions, cos, sin, cfg=acfg, block_size=block_size,
             layout=layout, use_kernel=use_kernel, kv_dtype=kv_dtype)
-        kv_data = _kv_set(kv_data, l, kv_layer)
         if not cfg.parallel_attn:
             x = x + attn_out
             h_mlp = _layernorm(x, lp["post_attention_layernorm"], eps)
@@ -433,7 +422,7 @@ def falcon_ragged_step(params, kv_data, token_ids, positions, seq_slots,
 
     x = _layernorm(x, params["ln_f"], eps)
     return _head_logits(params, x, last_token_idx,
-                        embed_key="word_embeddings"), kv_data
+                        embed_key="word_embeddings"), tuple(kv_data)
 
 
 @_ragged_program("opt")
@@ -456,18 +445,18 @@ def opt_ragged_step(params, kv_data, token_ids, positions, seq_slots,
     off = positions % block_size
     acfg = _attn_cfg_view(cfg)
 
+    kv_data = list(kv_data)
     for l in range(cfg.num_hidden_layers):
         lp = params[f"layers_{l}"]
         h = _layernorm(x, lp["self_attn_layer_norm"], eps) \
             if cfg.do_layer_norm_before else x
         attn_params = {"q_proj": lp["q_proj"], "k_proj": lp["k_proj"],
                        "v_proj": lp["v_proj"], "o_proj": lp["out_proj"]}
-        attn_out, kv_layer = _ragged_attention_block(
-            attn_params, h, _kv_layer(kv_data, l), blk, off, block_tables,
+        attn_out, kv_data[l] = _ragged_attention_block(
+            attn_params, h, kv_data[l], blk, off, block_tables,
             seq_slots, positions, None, None, cfg=acfg, block_size=block_size,
             rotary=False, layout=layout, use_kernel=use_kernel,
             kv_dtype=kv_dtype)
-        kv_data = _kv_set(kv_data, l, kv_layer)
         x = x + attn_out
         if not cfg.do_layer_norm_before:
             x = _layernorm(x, lp["self_attn_layer_norm"], eps)
@@ -481,7 +470,7 @@ def opt_ragged_step(params, kv_data, token_ids, positions, seq_slots,
 
     if cfg.do_layer_norm_before:
         x = _layernorm(x, params["final_layer_norm"], eps)
-    return _head_logits(params, x, last_token_idx), kv_data
+    return _head_logits(params, x, last_token_idx), tuple(kv_data)
 
 
 @_ragged_program("phi")
@@ -504,24 +493,24 @@ def phi_ragged_step(params, kv_data, token_ids, positions, seq_slots,
     off = positions % block_size
     acfg = _attn_cfg_view(cfg)
 
+    kv_data = list(kv_data)
     for l in range(cfg.num_hidden_layers):
         lp = params[f"layers_{l}"]
         h = _layernorm(x, lp["input_layernorm"], eps)
         attn_params = {"q_proj": lp["q_proj"], "k_proj": lp["k_proj"],
                        "v_proj": lp["v_proj"], "o_proj": lp["dense"]}
-        attn_out, kv_layer = _ragged_attention_block(
-            attn_params, h, _kv_layer(kv_data, l), blk, off, block_tables,
+        attn_out, kv_data[l] = _ragged_attention_block(
+            attn_params, h, kv_data[l], blk, off, block_tables,
             seq_slots, positions, cos, sin, cfg=acfg, block_size=block_size,
             rotary_dim=rd,
             layout=layout, use_kernel=use_kernel, kv_dtype=kv_dtype)
-        kv_data = _kv_set(kv_data, l, kv_layer)
         with jax.named_scope(_names.SCOPE_MLP):
             mlp = _lin(jax.nn.gelu(_lin(h, lp["fc1"], dtype)), lp["fc2"],
                        dtype)
         x = x + attn_out + mlp
 
     x = _layernorm(x, params["final_layernorm"], eps)
-    return _head_logits(params, x, last_token_idx), kv_data
+    return _head_logits(params, x, last_token_idx), tuple(kv_data)
 
 
 def _eva_summaries(kv_layer, phi, mu, block_tables, seq_slots, positions,
@@ -544,16 +533,15 @@ def _eva_summaries(kv_layer, phi, mu, block_tables, seq_slots, positions,
         blk = block_tables[slot, first // block_size][:, None]
         off = (first % block_size)[:, None] + jnp.arange(chunk)[None, :]
         # [n, C, H, Dh]: each completed chunk as a sequence of one chunk
-        ks, vs = chunk_summaries(kv_layer[0, blk, off], kv_layer[1, blk, off],
-                                 phi, mu, chunk)
+        k_pages, v_pages = kv_layer
+        ks, vs = chunk_summaries(k_pages[blk, off], v_pages[blk, off], phi,
+                                 mu, chunk)
         ks, vs = ks[:, 0], vs[:, 0]
         j = (positions[rows] // chunk) % per_window   # its place in the window
         sblk = block_tables[slot, maxb - per_window // block_size
                             + j // block_size]
-        kv_layer = kv_layer.at[0, sblk, j % block_size].set(
-            ks.astype(kv_layer.dtype))
-        return kv_layer.at[1, sblk, j % block_size].set(
-            vs.astype(kv_layer.dtype))
+        return (k_pages.at[sblk, j % block_size].set(ks.astype(k_pages.dtype)),
+                v_pages.at[sblk, j % block_size].set(vs.astype(v_pages.dtype)))
 
 
 @_ragged_program("evabyte")
@@ -593,6 +581,7 @@ def evabyte_ragged_step(params, kv_data, token_ids, positions, seq_slots,
     blk = block_tables[seq_slots, row_pos // block_size]
     off = row_pos % block_size
 
+    kv_data = list(kv_data)
     for l in range(cfg.num_hidden_layers):
         lp = params[f"layers_{l}"]
         attn = lp["self_attn"]
@@ -601,12 +590,11 @@ def evabyte_ragged_step(params, kv_data, token_ids, positions, seq_slots,
             block_tables=block_tables, seq_slots=seq_slots,
             positions=positions, row_pos=row_pos, chunk=chunk,
             per_window=per_window, block_size=block_size)
-        attn_out, kv_layer = _ragged_attention_block(
-            attn, norm(x, lp["input_layernorm"]), _kv_layer(kv_data, l), blk,
+        attn_out, kv_data[l] = _ragged_attention_block(
+            attn, norm(x, lp["input_layernorm"]), kv_data[l], blk,
             off, block_tables, seq_slots, positions, cos, sin, cfg=cfg,
             block_size=block_size, layout=layout, use_kernel=use_kernel,
             row_positions=row_pos, after_scatter=summarise)
-        kv_data = _kv_set(kv_data, l, kv_layer)
         x = x + attn_out.astype(jnp.float32)
         x = _swiglu(x, norm(x, lp["post_attention_layernorm"]), lp["mlp"],
                     dtype)
@@ -614,7 +602,7 @@ def evabyte_ragged_step(params, kv_data, token_ids, positions, seq_slots,
     with jax.named_scope(_names.SCOPE_LM_HEAD):
         xl = norm(x, params["norm"])[last_token_idx].astype(jnp.float32)
         heads = xl @ params["lm_head"]["kernel"].astype(jnp.float32)
-    return heads[:, :cfg.vocab_size], kv_data
+    return heads[:, :cfg.vocab_size], tuple(kv_data)
 
 
 RAGGED_FORWARDS = {"LlamaModel": llama_ragged_step,
@@ -682,6 +670,9 @@ def decode_burst(params, kv_data, tok0, pos0, active, block_tables, *,
     iteration) instead of argmax — seed-deterministic, but a DIFFERENT
     stream than the host loop's numpy Generator, which is why the engine
     gates it behind ``decode_burst_sampling``.
+
+    ``kv_data`` (the per-layer K and V buffers, donated) is the scan's
+    carry: every buffer stays in place through the ``while``.
 
     Returns ([k, max_seqs] int32 tokens (one per iteration), new kv).
     """

@@ -80,8 +80,10 @@ SCOPE_LM_HEAD = "ds.lm_head"              # serving: final norm, last-token logi
 SCOPE_ATTENTION = "ds.attn"               # serving: qkv, rotary, cache, paged, o
 SCOPE_MLP = "ds.mlp"                      # serving: the MLP or expert block
 SCOPE_NORM = "ds.norm"                    # serving: rms / layer norms
-SCOPE_KV_CACHE = "ds.kv_cache"            # serving: a layer's slice of the paged
-#                                           cache taken out and written back
+SCOPE_KV_CACHE = "ds.kv_cache"            # serving, inside ds.attn: everything a
+#                                           step spends to put its K/V into the
+#                                           paged cache (the scatter; encoding
+#                                           and scales on the quantized path)
 SCOPE_EVA_SUMMARY = "ds.eva_summary"      # serving: pooling the chunks a step
 #                                           completes, and their scatter
 MODULE_ATTENTION = "self_attn"            # flax module name (training)

@@ -93,6 +93,22 @@ if _cache_dir is not None:
 import pytest  # noqa: E402
 
 
+@pytest.fixture(scope="module")
+def no_disk_cache():
+    """For a module that wraps an engine's step (a recording step, a step
+    around other storage): its burst programs have the HLO of another's under
+    another static ``step_fn``, so the later one would be READ from the
+    suite's disk cache, and a CPU executable deserialized from it mishandles
+    the donated cache (the history is above; seen as a wrong stream in one
+    run of ten).  ``pytestmark = pytest.mark.usefixtures("no_disk_cache")``."""
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
 @pytest.fixture(autouse=True)
 def _reset_global_state():
     """Each test gets a fresh mesh/comm world (analog of per-test process

@@ -210,7 +210,8 @@ def test_v2_tensor_parallel_matches_single():
             # params actually sharded over the tp mesh
             kern = eng.params["layers_0"]["self_attn"]["q_proj"]["kernel"]
             assert len(kern.sharding.device_set) == 2
-            assert len(eng._kv.sharding.device_set) == 2
+            assert all(len(a.sharding.device_set) == 2
+                       for a in jax.tree.leaves(eng._kv))
         outs[tp] = eng.generate(prompts, max_new_tokens=5)
         eng.flush(range(len(prompts)))
     assert outs[1] == outs[2]
@@ -560,9 +561,10 @@ def test_v2_tp_gqa_replicated_kv_matches_single():
                         tensor_parallel=dict(tp_size=tp)))
         if tp > 1:
             # kv cache replicated; q_proj sharded over 4 ranks
-            assert len(eng._kv.sharding.device_set) == 4
             from jax.sharding import PartitionSpec as P
-            assert eng._kv.sharding.spec == P()
+            for a in jax.tree.leaves(eng._kv):
+                assert len(a.sharding.device_set) == 4
+                assert a.sharding.spec == P()
             qk = eng.params["layers_0"]["self_attn"]["q_proj"]["kernel"]
             assert "tp" in str(qk.sharding.spec)
             kk = eng.params["layers_0"]["self_attn"]["k_proj"]["kernel"]

@@ -37,19 +37,8 @@ def _seeded(cfg, seed, **changes):
                                          dtype=jnp.float32)
 
 
-@pytest.fixture(scope="module", autouse=True)
-def _no_disk_cache():
-    """``_record_logits`` makes a burst program whose HLO equals the plain
-    one under another static ``step_fn``: the later one would be READ from
-    the suite's disk cache, and a CPU executable deserialized from it
-    mishandles the donated cache (tests/conftest.py has the history; seen
-    here as a wrong stream in one run of ten)."""
-    from jax.experimental.compilation_cache import compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", True)
-    compilation_cache.reset_cache()
+# ``_record_logits`` wraps the engine's step (tests/conftest.py)
+pytestmark = pytest.mark.usefixtures("no_disk_cache")
 
 
 @pytest.fixture(scope="module")

@@ -82,13 +82,20 @@ def test_kv_bytes_per_token_accounting():
 def test_quantized_cache_layout():
     kv = BlockedKVCache(num_layers=2, num_blocks=8, block_size=4,
                         num_kv_heads=2, head_dim=16, kv_dtype="int8")
-    assert kv.data.dtype == jnp.int8
-    assert kv.data.shape == (2, 2, 8, 4, 2, 16)
-    assert kv.scales.shape == (2, 2, 8, 4, 2)
-    assert kv.scales.dtype == jnp.float32
+    assert kv.dtype == jnp.int8 and len(kv.layers) == 2
+    for k_pages, v_pages, k_scales, v_scales in kv.layers:
+        for pages, scales in ((k_pages, k_scales), (v_pages, v_scales)):
+            assert pages.dtype == jnp.int8
+            assert pages.shape == (8, 4, 2, 16)
+            assert scales.shape == (8, 4, 2)
+            assert scales.dtype == jnp.float32
     fp = BlockedKVCache(num_layers=2, num_blocks=8, block_size=4,
                         num_kv_heads=2, head_dim=16)
-    assert fp.scales is None and fp.kv_dtype is None
+    assert fp.kv_dtype is None and len(fp.layers) == 2
+    assert all(len(layer) == 2 for layer in fp.layers)      # no scales
+    # 2 L buffers of their own: no two leaves share storage
+    assert len({a.unsafe_buffer_pointer()
+                for a in jax.tree.leaves(fp.layers)}) == 4
 
 
 # ----------------------------------------------------------------- engine
@@ -122,14 +129,17 @@ def test_int8_kv_composes_with_tensor_parallel():
             config=dict(dtype="float32", state_manager=dict(sm),
                         kv_cache_dtype="int8",
                         tensor_parallel=dict(tp_size=tp)))
-        data, scales = eng._kv
-        assert data.dtype == jnp.int8
-        if tp > 1:
-            # the cache AND its scales actually live across both ranks,
-            # split on the kv-head dim (scales' trailing dim)
-            assert len(data.sharding.device_set) == 2
-            assert len(scales.sharding.device_set) == 2
-            assert scales.sharding.spec[-1] == "tp", scales.sharding.spec
+        for k_pages, v_pages, k_scales, v_scales in eng._kv:
+            for data, scales in ((k_pages, k_scales), (v_pages, v_scales)):
+                assert data.dtype == jnp.int8
+                if tp > 1:
+                    # the cache AND its scales actually live across both
+                    # ranks, split on the kv-head dim (scales' trailing dim)
+                    assert len(data.sharding.device_set) == 2
+                    assert len(scales.sharding.device_set) == 2
+                    assert data.sharding.spec[2] == "tp", data.sharding.spec
+                    assert scales.sharding.spec[-1] == "tp", \
+                        scales.sharding.spec
         outs[tp] = eng.generate(prompts, max_new_tokens=6)
         eng.flush(range(len(prompts)))
     assert outs[1] == outs[2]
@@ -162,7 +172,9 @@ def test_fp8_kv_serves_and_completes():
     rng = np.random.default_rng(2)
     prompts = [rng.integers(1, 64, size=7).tolist() for _ in range(2)]
     eng = _probe_engine(kv_dtype="fp8")
-    assert eng.kv_cache.data.dtype == jnp.float8_e4m3fn
+    assert eng.kv_cache.dtype == jnp.float8_e4m3fn
+    assert all(a.dtype == jnp.float8_e4m3fn
+               for layer in eng._kv for a in layer[:2])
     out = eng.generate(prompts, max_new_tokens=8)
     assert [len(o) for o in out] == [8, 8]
 
@@ -175,8 +187,7 @@ def test_kv_dtype_unset_is_todays_engine():
     prompts = [rng.integers(1, 64, size=9).tolist() for _ in range(2)]
     eng = _probe_engine()
     assert eng._kv_dtype is None
-    assert not isinstance(eng._kv, tuple)       # plain array, no scales
-    assert eng.kv_cache.scales is None
+    assert all(len(layer) == 2 for layer in eng._kv)    # K and V, no scales
     out = eng.generate(prompts, max_new_tokens=6)
     out2 = _probe_engine().generate(prompts, max_new_tokens=6)
     assert out == out2
