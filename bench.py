@@ -703,8 +703,6 @@ def run_serve_bench(on_tpu: bool) -> dict:
         sm = dict(max_tracked_sequences=8, max_ragged_batch_size=64,
                   max_ragged_sequence_count=8, max_context=128,
                   block_size=16, num_blocks=40)
-    if os.environ.get("DS_SERVE_ATOM") is not None:  # A/B the atom layout
-        sm["prefill_atom_size"] = int(os.environ["DS_SERVE_ATOM"])
     econf = dict(dtype=cfg.dtype, state_manager=sm)
     if os.environ.get("DS_SERVE_BURST") is not None:  # A/B fused decode
         econf["decode_burst"] = int(os.environ["DS_SERVE_BURST"])

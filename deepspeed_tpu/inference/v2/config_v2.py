@@ -22,11 +22,6 @@ class DSStateManagerConfig(DeepSpeedConfigModel):
     # blocked-KV geometry (reference AllocationMode/KVCacheConfig)
     block_size: int = 128
     num_blocks: Optional[int] = None          # None → derived
-    # Atom-tiled prefill (reference atom_builder analog): prefill runs are
-    # laid out atom-aligned past a decode-only region so the Pallas paged
-    # kernel can process `prefill_atom_size` same-sequence query rows per
-    # tile.  0 → single-region per-token layout.
-    prefill_atom_size: int = 16
 
 
 class RaggedInferenceEngineConfig(DeepSpeedConfigModel):

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from ... import telemetry as _telemetry
+from ...ops.pallas.paged_attention import kernel_page_loads
 from ...telemetry import names as _names
 from ...utils.logging import logger
 from .config_v2 import RaggedInferenceEngineConfig
@@ -99,8 +100,8 @@ class InferenceEngineV2:
             # ignored once inlined — dropping it would double peak KV HBM);
             # decode_burst traces the wrapper inside its own program
             self._step_fn = jax.jit(
-                dq_step, static_argnames=("cfg", "block_size", "layout",
-                                          "use_kernel", "kv_dtype"),
+                dq_step, static_argnames=("cfg", "block_size", "use_kernel",
+                                          "kv_dtype"),
                 donate_argnums=(1, ))
         if tp > 1:
             from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -242,52 +243,13 @@ class InferenceEngineV2:
             self.state_manager.flush_sequence(uid)
 
     # -------------------------------------------------------------- schedule
-    def _atom_layout(self):
-        """Static (decode_cap, atom) region split used on prefill-heavy
-        steps: [0, decode_cap) single decode tokens (per-token paged
-        kernel), [decode_cap, T) prefill runs aligned to ``atom`` tiles
-        (atom-tiled kernel — the reference atom_builder analog).  Only two
-        layouts ever compile: this one and the flat (0, 0) legacy."""
-        sm = self._config.state_manager
-        atom = sm.prefill_atom_size
-        if not atom:
-            return (0, 0)
-        decode_cap = min(sm.max_ragged_sequence_count, self._budget // 2)
-        if self._budget - decode_cap < atom:
-            return (0, 0)  # no room for a prefill region
-        # the prefill region must be a whole number of atom tiles — grow
-        # the decode region to absorb the remainder
-        decode_cap = self._budget - (self._budget - decode_cap) // atom * atom
-        return (decode_cap, atom)
-
-    def _pick_layout(self):
-        """Per-step layout choice: atom regions only when prefill dominates
-        (a decode-heavy step keeps the flat layout — zero regression)."""
-        decode_cap, atom = self._atom_layout()
-        if not atom:
-            return (0, 0)
-        n_decode = n_prefill = 0
-        for seq in self.state_manager.tracked_sequences.values():
-            if seq.done:
-                continue
-            # O(1) pending count — pending() slices the full token list
-            p = len(seq.tokens) - seq.seen_tokens
-            if p == 1:
-                n_decode += 1
-            elif p > 1:
-                n_prefill += p
-        if n_prefill >= max(atom, n_decode):
-            return (decode_cap, atom)
-        return (0, 0)
-
     def _build_batch(self):
         """Pack the token budget: decode tokens first (latency), then
         prefill chunks (throughput) — the reference scheduler's policy.
-        With an atom layout, decode tokens fill the decode region and
-        prefill runs are atom-aligned in the prefill region."""
+        The rows of a sequence are contiguous, their positions consecutive;
+        the rows past the last sequence's are dead (slot 0)."""
         T = self._budget
         sm = self.state_manager
-        decode_cap, atom = layout = self._pick_layout()
         toks = np.zeros(T, np.int32)
         pos = np.zeros(T, np.int32)
         slots = np.zeros(T, np.int32)  # slot 0 → garbage block
@@ -297,8 +259,7 @@ class InferenceEngineV2:
         deferred = 0        # sequences the KV pool could not grow this step
         deferred_want = 0   # blocks those sequences needed and couldn't get
 
-        d_cur = 0                      # decode-region cursor
-        p_cur = decode_cap             # prefill-region cursor (atom-aligned)
+        cur = 0                        # the next free buffer row
         order = sorted(sm.tracked_sequences.values(),
                        key=lambda s: len(s.pending()))
         for seq in order:
@@ -307,28 +268,9 @@ class InferenceEngineV2:
             pending = seq.pending()
             if not pending:
                 continue
-            if atom:
-                if len(pending) == 1 and d_cur < decode_cap:
-                    start, room = d_cur, 1
-                else:
-                    start = p_cur
-                    room = T - p_cur
-                    if room <= 0 and d_cur < decode_cap:
-                        # prefill region exhausted but decode rows are free:
-                        # advance this sequence by ONE token through a spare
-                        # decode row.  Exact: the decode path masks keys by
-                        # position, and every earlier token of the sequence
-                        # is already in cache (round-2 advisor finding —
-                        # schedulable work was left on the table)
-                        start, room = d_cur, 1
-                if room <= 0:
-                    continue
-            else:
-                start = d_cur
-                room = T - d_cur
-                if room <= 0:
-                    break
-            take = min(len(pending), room)
+            if cur >= T:
+                break
+            take = min(len(pending), T - cur)
             # KV-pool pressure: schedule only what the free blocks can hold
             # (the reference scheduler's deferral; a dry pool must not crash
             # the step — blocks free as other sequences flush)
@@ -343,24 +285,17 @@ class InferenceEngineV2:
                     - len(seq.blocks))
                 continue
             sm.ensure_capacity(seq, seq.seen_tokens + take)
-            toks[start:start + take] = pending[:take]
-            pos[start:start + take] = np.arange(
+            toks[cur:cur + take] = pending[:take]
+            pos[cur:cur + take] = np.arange(
                 seq.seen_tokens, seq.seen_tokens + take)
-            slots[start:start + take] = seq.slot
+            slots[cur:cur + take] = seq.slot
             if take == len(pending):
-                finishing.append((seq, start + take - 1))
+                finishing.append((seq, cur + take - 1))
             seq.seen_tokens += take
             placed += take
             if len(pending) == 1:
                 placed_decode += 1
-            if atom:
-                if start < decode_cap:   # landed in the decode region
-                    d_cur += 1
-                else:
-                    # advance to the next atom boundary (intra-atom pads)
-                    p_cur = start + (-(-take // atom)) * atom
-            else:
-                d_cur += take
+            cur += take
         if placed == 0:
             if deferred:
                 # nothing schedulable AND nothing in flight to free blocks:
@@ -377,15 +312,14 @@ class InferenceEngineV2:
         last_idx = np.zeros(sm.max_seqs, dtype=np.int32)
         for seq, idx in finishing:
             last_idx[seq.slot] = idx
-        grid_pages, live_pages, row_pages = self._page_counts(pos, slots,
-                                                              layout)
+        grid_pages, live_pages, row_pages = self._page_counts(pos, slots)
         self.last_step_counts = {
             "kind": _names.KIND_RAGGED, "token_budget": T,
             "live_tokens": placed, "decode_tokens": placed_decode,
             "prefill_tokens": placed - placed_decode,
             "grid_pages": grid_pages, "live_pages": live_pages,
             "row_pages": row_pages, "burst_k": 0}
-        return toks, pos, slots, last_idx, finishing, layout
+        return toks, pos, slots, last_idx, finishing
 
     def _table_snapshot(self):
         """The block table as the launched program is to see it.  A COPY:
@@ -395,7 +329,7 @@ class InferenceEngineV2:
         step."""
         return jnp.asarray(self.state_manager.block_table.copy())
 
-    def _count_cache(self, pos, slots, layout=(0, 0)):
+    def _count_cache(self, pos, slots):
         """Add to ``last_step_counts`` what the cache holds once this step
         (or burst: ``[k, rows]``) is scheduled — the tokens of the running
         sequences' contexts (``context_tokens``) and the blocks of
@@ -412,38 +346,36 @@ class InferenceEngineV2:
             context_tokens=sum(s.seen_tokens for s in seqs),
             held_blocks=sum(len(s.blocks) for s in seqs),
             block_size=kv.block_size,
-            summary_pages=self._summary_pages(pos, slots, layout),
+            summary_pages=self._summary_pages(pos, slots),
             chunks_closed=0 if ends is None else int(
                 (ends % kv.chunk_size == 0).sum()),
             windows_closed=0 if ends is None else int(
                 (ends % kv.window_size == 0).sum()))
 
-    def _summary_pages(self, pos, slots, layout=(0, 0)):
+    def _kernel_loads(self, pos, slots, row_pages=None):
+        """``paged_attention.kernel_page_loads`` of one layer's call over
+        the rows at positions ``pos`` (inside the block-table row) in slots
+        ``slots``, for this engine's shapes."""
+        cfg = self.model_config
+        return kernel_page_loads(
+            slots, pos, heads=cfg.num_attention_heads,
+            kv_heads=cfg.num_key_value_heads, head_dim=cfg.head_dim,
+            kv_dtype=self.kv_cache.dtype,
+            block_size=self.kv_cache.block_size,
+            maxb=self.state_manager.block_table.shape[1],
+            window=int(getattr(cfg, "sliding_window", 0) or 0),
+            row_pages=row_pages)
+
+    def _summary_pages(self, pos, slots):
         """Of ``grid_pages``, the loads of summary blocks: every run (every
-        atom, every row on the per-token kernel) loads all the summary pages
-        of the windows its sequence has closed."""
-        from ...ops.pallas import paged_attention as _pa
-        kv, cfg = self.kv_cache, self.model_config
+        row on the per-token kernel) loads all the summary pages of the
+        windows its sequence has closed."""
+        kv = self.kv_cache
         if not kv.window_size:
             return 0
-        pos, slots = np.atleast_2d(pos), np.atleast_2d(slots)
-        closed = np.where(slots != 0,
-                          pos // kv.window_size * kv.summary_blocks, 0)
-        decode_cap, atom = layout
-        cut = decode_cap if atom else pos.shape[1]
-        total = closed[:, cut:].reshape(-1, atom).max(axis=1).sum() \
-            if atom else 0
-        if not _pa.run_tiled(cfg.num_key_value_heads, cfg.head_dim,
-                             kv.dtype):
-            return int(total + closed[:, :cut].sum())
-        tq = _pa.tile_rows(cfg.num_attention_heads, cfg.num_key_value_heads,
-                           cut)
-        rid = _pa.run_plan(np, slots[:, :cut], self._row_positions(
-            pos[:, :cut]), tq, kv.block_size)[1]
-        per_row = np.pad(closed[:, :cut], ((0, 0), (0, -cut % tq))) \
-            .reshape(-1, tq)
-        runs = rid[:, None, :] == np.arange(tq)[None, :, None]
-        return int(total + (runs * per_row[:, None, :]).max(-1).sum())
+        return self._kernel_loads(
+            self._row_positions(pos), slots,
+            row_pages=pos // kv.window_size * kv.summary_blocks)[2]
 
     def _row_positions(self, pos):
         """Positions inside the block-table row (``ragged.py``)."""
@@ -452,43 +384,24 @@ class InferenceEngineV2:
             return pos
         return window_row_positions(pos, kv.window_size, kv.chunk_size)
 
-    def _page_counts(self, pos, slots, layout=(0, 0)):
+    def _page_counts(self, pos, slots):
         """``(grid_pages, live_pages, row_pages)`` of one paged-attention
         call over the rows at positions ``pos`` in slots ``slots`` (0: a
         dead row); ``[k, rows]`` arrays are the ``k`` calls of a burst.
-        ``grid_pages``: the K/V page loads the kernel's loops perform —
-        the run-tiled kernel's items (``paged_attention.run_plan``, the same
-        function the step program takes its loop bounds from), or, for an
-        atom region and for a shape left on the per-token kernel, every
-        grid row times every page of the block table.  ``live_pages``: of
-        those, the loads that hold a key some live row may see (all of the
-        run-tiled kernel's).  ``row_pages``: the (row, page) pairs the live
-        rows' contexts (their sliding windows) span — ``row_pages /
+        ``grid_pages``, ``live_pages``: the K/V page loads the kernel's
+        loops perform and, of those, the loads that hold a key some live
+        row may see (``paged_attention.kernel_page_loads``, beside the
+        kernels it describes).  ``row_pages``: the (row, page) pairs the
+        live rows' contexts (their sliding windows) span — ``row_pages /
         grid_pages`` is how many rows share one page load."""
-        from ...ops.pallas import paged_attention as _pa
         bs = self.kv_cache.block_size
-        maxb = self.state_manager.block_table.shape[1]
-        cfg = self.model_config
-        window = int(getattr(cfg, "sliding_window", 0) or 0)
-        pos, slots = np.atleast_2d(pos), np.atleast_2d(slots)
-        pos = self._row_positions(pos)
+        window = int(getattr(self.model_config, "sliding_window", 0) or 0)
+        pos, slots = self._row_positions(np.atleast_2d(pos)), \
+            np.atleast_2d(slots)
+        grid, live, _ = self._kernel_loads(pos, slots)
         first = np.maximum(pos - window + 1, 0) // bs if window else 0
         pages = np.where(slots != 0, pos // bs + 1 - first, 0)
-        decode_cap, atom = layout
-        cut = decode_cap if atom else pos.shape[1]
-        if _pa.run_tiled(cfg.num_key_value_heads, cfg.head_dim,
-                         self.kv_cache.dtype):
-            tq = _pa.tile_rows(cfg.num_attention_heads,
-                               cfg.num_key_value_heads, cut)
-            grid = live = _pa.page_loads(slots[:, :cut], pos[:, :cut], tq,
-                                         bs, window)
-        else:
-            grid, live = pages[:, :cut].size * maxb, pages[:, :cut].sum()
-        if atom:
-            # one grid row an atom: it streams the pages of its deepest row
-            tiles = pages[:, cut:].reshape(-1, atom).max(axis=1)
-            grid, live = grid + len(tiles) * maxb, live + tiles.sum()
-        return int(grid), int(live), int(pages.sum())
+        return grid, live, int(pages.sum())
 
     @staticmethod
     def _sample_row(row, temperature, top_k, top_p, rng):
@@ -534,7 +447,7 @@ class InferenceEngineV2:
             batch = self._build_batch()
         if batch is None:
             return {}
-        toks, pos, slots, last_idx, finishing, layout = batch
+        toks, pos, slots, last_idx, finishing = batch
         with _telemetry.scope(_names.SERVE_LAUNCH):
             step_args = (self.params, self._kv, jnp.asarray(toks),
                          jnp.asarray(pos), jnp.asarray(slots),
@@ -542,21 +455,17 @@ class InferenceEngineV2:
                          jnp.asarray(last_idx))
             step_kw = dict(cfg=self.model_config,
                            block_size=self.kv_cache.block_size,
-                           layout=layout, use_kernel=self._tp == 1,
+                           use_kernel=self._tp == 1,
                            kv_dtype=self._kv_dtype)
             from ...profiling import cost_model
             if cost_model.capturing():
                 # compiled-cost capture of the serving prefill/decode
-                # program (one analysis compile per distinct layout, only
-                # while capture is armed — docs/observability.md "MFU &
-                # HBM"); layout (0,0) is the flat/decode-heavy program,
-                # (d,a) the atom-tiled prefill one
+                # program (one analysis compile, only while capture is
+                # armed — docs/observability.md "MFU & HBM")
                 cost_model.capture_jit_call(
-                    f"serve/ragged_step[{layout[0]}x{layout[1]}]",
-                    self._step_fn, step_args, step_kw,
-                    meta={"layout": list(layout)})
+                    "serve/ragged_step", self._step_fn, step_args, step_kw)
             logits, self._kv = self._step_fn(*step_args, **step_kw)
-        self._count_cache(pos, slots, layout)
+        self._count_cache(pos, slots)
         out = {}
         if finishing:
             # the fetch is the one place the host waits for the device

@@ -6,10 +6,11 @@ One jitted function processes a *flat token buffer* ``[T]`` — the union of
 prefill chunks and single decode tokens from many sequences — against the
 paged KV cache.  The reference does this with hand-written CUDA (atom builder
 + blocked flash); here the batch metadata (positions, sequence slots, block
-tables) turns the same computation into gathers/scatters XLA schedules, with
-the attention core a candidate for a Pallas paged kernel (the math below is
-already blocked: swap `_paged_attention` for a kernel without touching the
-rest).
+tables) turns the same computation into gathers/scatters XLA schedules, and
+the attention core is ``_paged_attention``: the Pallas paged kernels on a
+TPU, an XLA gather elsewhere.  The buffer has ONE layout: the rows a
+sequence gets in a step are contiguous with consecutive positions
+(``engine_v2._build_batch``), dead rows carry slot 0.
 
 Token semantics: every token's K/V is written to the cache *before* attention
 runs, and each token attends to cache positions ≤ its own — so a multi-token
@@ -40,8 +41,8 @@ def _program(name, **jit_kwargs):
 
 def _ragged_program(arch):
     return _program(_names.PROGRAM_RAGGED_STEP + arch,
-                    static_argnames=("cfg", "block_size", "layout",
-                                     "use_kernel", "kv_dtype"),
+                    static_argnames=("cfg", "block_size", "use_kernel",
+                                     "kv_dtype"),
                     donate_argnums=(1, ))
 
 
@@ -62,24 +63,20 @@ def _rmsnorm(x, w, eps):
 
 
 def _paged_attention(q, k_cache, v_cache, block_tables, seq_slots, positions,
-                     block_size, window=0, layout=(0, 0), use_kernel=True,
-                     kv_scales=None):
+                     block_size, window=0, use_kernel=True, kv_scales=None):
     """q: [T, H, Dh]; caches: [num_blocks, bs, Hkv, Dh]; block_tables:
     [max_seqs, maxb]; seq_slots, positions: [T]; window: sliding-window size
     (0 → full causal).  Returns [T, H, Dh].
 
-    On TPU: the Pallas paged kernels.  The flat layout takes the run-tiled
-    kernel (``ops/pallas/paged_attention.paged_attention``): the rows of one
-    sequence share each page load, and only live pages are visited.
-    ``layout=(decode_cap, atom)`` > (0,0) means the buffer is region-split
-    by the batch builder: the same kernel for the first ``decode_cap`` rows,
-    the atom-tiled kernel (``atom`` same-sequence rows per tile) for the
-    rest.  Off a TPU (and at ``use_kernel=False``): XLA gather of
-    each token's block run with position masking — chosen by
+    On TPU: ``ops/pallas/paged_attention.paged_attention``, which picks the
+    kernel by the shape (run-tiled — the rows of one sequence share each
+    page load, and only live pages are visited — or one grid row a token).
+    Off a TPU (and at ``use_kernel=False``): XLA gather of each token's
+    block run with position masking — chosen by
     ``ops/_use_kernels.use_pallas_kernels``, the same gate as every other
     kernel dispatch site.  (A dead row, slot 0, comes back zero from the
-    run-tiled kernel and as attention over the garbage block from the
-    others; nothing reads it.)
+    kernels and as attention over the garbage block from the gather;
+    nothing reads it.)
 
     ``kv_scales=(k_scales, v_scales)`` ([num_blocks, bs, Hkv] f32 each) is
     the quantized-KV read path: the caches hold int8/fp8 rows and only the
@@ -88,19 +85,9 @@ def _paged_attention(q, k_cache, v_cache, block_tables, seq_slots, positions,
     consume scales, so this path always takes the XLA gather."""
     from ...ops._use_kernels import use_pallas_kernels
     if use_kernel and kv_scales is None and use_pallas_kernels():
-        from ...ops.pallas.paged_attention import (paged_attention,
-                                                   paged_attention_atoms)
-        decode_cap, atom = layout
-        cut = decode_cap if atom and q.shape[0] > decode_cap else None
-        out = paged_attention(q[:cut], k_cache, v_cache, block_tables,
-                              seq_slots[:cut], positions[:cut],
-                              window=window)
-        if cut is None:
-            return out
-        out_p = paged_attention_atoms(
-            q[cut:], k_cache, v_cache, block_tables[seq_slots[cut:]],
-            positions[cut:], atom, window=window)
-        return jnp.concatenate([out, out_p], axis=0)
+        from ...ops.pallas.paged_attention import paged_attention
+        return paged_attention(q, k_cache, v_cache, block_tables, seq_slots,
+                               positions, window=window)
     tables_t = block_tables[seq_slots]
     T, H, Dh = q.shape
     Hkv = k_cache.shape[2]
@@ -191,7 +178,7 @@ def _kv_scatter(kv_layer, k, v, blk, off, kv_dtype=None):
 def _ragged_attention_block(lp_attn, h, kv_layer, blk, off, block_tables,
                             seq_slots, positions, cos, sin, *, cfg, block_size,
                             rotary=True, rotary_dim=None,
-                            layout=(0, 0), use_kernel=True, kv_dtype=None,
+                            use_kernel=True, kv_dtype=None,
                             row_positions=None, after_scatter=None):
     """Shared attention sub-block: qkv → rotary → cache scatter → paged
     attention → output projection.  Returns (attn_out [T, D], new kv_layer).
@@ -232,8 +219,7 @@ def _ragged_attention_block(lp_attn, h, kv_layer, blk, off, block_tables,
                            positions if row_positions is None
                            else row_positions, block_size,
                            window=getattr(cfg, "sliding_window", 0),
-                           layout=layout, use_kernel=use_kernel,
-                           kv_scales=kv_scales)
+                           use_kernel=use_kernel, kv_scales=kv_scales)
     o = out.reshape(out.shape[0], H * Dh)
     o = jnp.einsum("tf,fd->td", o, lp_attn["o_proj"]["kernel"].astype(dtype))
     if "bias" in lp_attn["o_proj"]:
@@ -253,7 +239,7 @@ def _swiglu(x, h2, mlp, dtype):
 @_ragged_program("llama")
 def llama_ragged_step(params, kv_data, token_ids, positions, seq_slots,
                       block_tables, last_token_idx, *, cfg, block_size,
-                      layout=(0, 0), use_kernel=True, kv_dtype=None):
+                      use_kernel=True, kv_dtype=None):
     """One ragged engine iteration for the Llama family.
 
     Args:
@@ -293,7 +279,7 @@ def llama_ragged_step(params, kv_data, token_ids, positions, seq_slots,
         attn_out, kv_data[l] = _ragged_attention_block(
             lp["self_attn"], h, kv_data[l], blk, off, block_tables,
             seq_slots, positions, cos, sin, cfg=cfg, block_size=block_size,
-            layout=layout, use_kernel=use_kernel, kv_dtype=kv_dtype)
+            use_kernel=use_kernel, kv_dtype=kv_dtype)
         x = x + attn_out
         h2 = _rmsnorm(x, lp["post_attention_layernorm"]["weight"], eps)
         x = _swiglu(x, h2, lp["mlp"], dtype)
@@ -315,7 +301,7 @@ def _lm_head(params, x, last_token_idx, cfg):
 @_ragged_program("mixtral")
 def mixtral_ragged_step(params, kv_data, token_ids, positions, seq_slots,
                         block_tables, last_token_idx, *, cfg, block_size,
-                      layout=(0, 0), use_kernel=True, kv_dtype=None):
+                        use_kernel=True, kv_dtype=None):
     """One ragged engine iteration for Mixtral (reference
     ``inference/v2/model_implementations/mixtral/``): Llama attention skeleton
     with the MLP replaced by the exact top-k sparse MoE (``moe_apply`` —
@@ -341,7 +327,7 @@ def mixtral_ragged_step(params, kv_data, token_ids, positions, seq_slots,
         attn_out, kv_data[l] = _ragged_attention_block(
             lp["self_attn"], h, kv_data[l], blk, off, block_tables,
             seq_slots, positions, cos, sin, cfg=cfg, block_size=block_size,
-            layout=layout, use_kernel=use_kernel, kv_dtype=kv_dtype)
+            use_kernel=use_kernel, kv_dtype=kv_dtype)
         x = x + attn_out
         h2 = _rmsnorm(x, lp["post_attention_layernorm"]["weight"], eps)
         with jax.named_scope(_names.SCOPE_MLP):
@@ -380,7 +366,7 @@ def _layernorm(x, p, eps):
 @_ragged_program("falcon")
 def falcon_ragged_step(params, kv_data, token_ids, positions, seq_slots,
                        block_tables, last_token_idx, *, cfg, block_size,
-                      layout=(0, 0), use_kernel=True, kv_dtype=None):
+                       use_kernel=True, kv_dtype=None):
     """One ragged engine iteration for Falcon (reference
     ``inference/v2/model_implementations/falcon/``): parallel-block layout —
     attention and the GELU MLP read the same layernormed input and add into
@@ -411,7 +397,7 @@ def falcon_ragged_step(params, kv_data, token_ids, positions, seq_slots,
         attn_out, kv_data[l] = _ragged_attention_block(
             attn_params, h_attn, kv_data[l], blk, off, block_tables,
             seq_slots, positions, cos, sin, cfg=acfg, block_size=block_size,
-            layout=layout, use_kernel=use_kernel, kv_dtype=kv_dtype)
+            use_kernel=use_kernel, kv_dtype=kv_dtype)
         if not cfg.parallel_attn:
             x = x + attn_out
             h_mlp = _layernorm(x, lp["post_attention_layernorm"], eps)
@@ -428,7 +414,7 @@ def falcon_ragged_step(params, kv_data, token_ids, positions, seq_slots,
 @_ragged_program("opt")
 def opt_ragged_step(params, kv_data, token_ids, positions, seq_slots,
                     block_tables, last_token_idx, *, cfg, block_size,
-                      layout=(0, 0), use_kernel=True, kv_dtype=None):
+                    use_kernel=True, kv_dtype=None):
     """One ragged engine iteration for OPT (reference
     ``inference/v2/model_implementations/opt/``): learned positions (+2
     offset), pre-LN blocks, ReLU MLP, no rotary."""
@@ -455,8 +441,7 @@ def opt_ragged_step(params, kv_data, token_ids, positions, seq_slots,
         attn_out, kv_data[l] = _ragged_attention_block(
             attn_params, h, kv_data[l], blk, off, block_tables,
             seq_slots, positions, None, None, cfg=acfg, block_size=block_size,
-            rotary=False, layout=layout, use_kernel=use_kernel,
-            kv_dtype=kv_dtype)
+            rotary=False, use_kernel=use_kernel, kv_dtype=kv_dtype)
         x = x + attn_out
         if not cfg.do_layer_norm_before:
             x = _layernorm(x, lp["self_attn_layer_norm"], eps)
@@ -476,7 +461,7 @@ def opt_ragged_step(params, kv_data, token_ids, positions, seq_slots,
 @_ragged_program("phi")
 def phi_ragged_step(params, kv_data, token_ids, positions, seq_slots,
                     block_tables, last_token_idx, *, cfg, block_size,
-                      layout=(0, 0), use_kernel=True, kv_dtype=None):
+                    use_kernel=True, kv_dtype=None):
     """One ragged engine iteration for Phi-2 (reference
     ``inference/v2/model_implementations/phi/``): parallel block, partial
     rotary, LayerNorm, biased linears (incl. lm_head)."""
@@ -503,7 +488,7 @@ def phi_ragged_step(params, kv_data, token_ids, positions, seq_slots,
             attn_params, h, kv_data[l], blk, off, block_tables,
             seq_slots, positions, cos, sin, cfg=acfg, block_size=block_size,
             rotary_dim=rd,
-            layout=layout, use_kernel=use_kernel, kv_dtype=kv_dtype)
+            use_kernel=use_kernel, kv_dtype=kv_dtype)
         with jax.named_scope(_names.SCOPE_MLP):
             mlp = _lin(jax.nn.gelu(_lin(h, lp["fc1"], dtype)), lp["fc2"],
                        dtype)
@@ -547,7 +532,7 @@ def _eva_summaries(kv_layer, phi, mu, block_tables, seq_slots, positions,
 @_ragged_program("evabyte")
 def evabyte_ragged_step(params, kv_data, token_ids, positions, seq_slots,
                         block_tables, last_token_idx, *, cfg, block_size,
-                        layout=(0, 0), use_kernel=True, kv_dtype=None):
+                        use_kernel=True, kv_dtype=None):
     """One ragged engine iteration for EvaByte (``models/evabyte.py`` has the
     layer's equations).  A sequence's block-table row is ``[summary blocks
     of its closed windows | blocks of its current window | ... | the summary
@@ -593,7 +578,7 @@ def evabyte_ragged_step(params, kv_data, token_ids, positions, seq_slots,
         attn_out, kv_data[l] = _ragged_attention_block(
             attn, norm(x, lp["input_layernorm"]), kv_data[l], blk,
             off, block_tables, seq_slots, positions, cos, sin, cfg=cfg,
-            block_size=block_size, layout=layout, use_kernel=use_kernel,
+            block_size=block_size, use_kernel=use_kernel,
             row_positions=row_pos, after_scatter=summarise)
         x = x + attn_out.astype(jnp.float32)
         x = _swiglu(x, norm(x, lp["post_attention_layernorm"]), lp["mlp"],
@@ -688,8 +673,7 @@ def decode_burst(params, kv_data, tok0, pos0, active, block_tables, *,
         logits, kv = inner(params, kv, jnp.where(active, toks, 0),
                            jnp.where(active, pos, 0), slots, block_tables,
                            rows, cfg=cfg, block_size=block_size,
-                           layout=(0, 0), use_kernel=use_kernel,
-                           kv_dtype=kv_dtype)
+                           use_kernel=use_kernel, kv_dtype=kv_dtype)
         if sample:
             key, sub = jax.random.split(key)
             nxt = _device_sample(logits, sub, temperature, top_k, top_p)

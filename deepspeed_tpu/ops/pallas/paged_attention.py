@@ -2,8 +2,8 @@
 analog (reference ``inference/v2/kernels/ragged_ops/blocked_flash`` +
 ``linear_blocked_kv_rotary``).
 
-:func:`paged_attention` is the flat layout's kernel.  It tiles the token
-buffer by RUNS: the rows one sequence gets in a step are contiguous and their
+:func:`paged_attention` is the entry.  Its kernel tiles the token buffer by
+RUNS: the rows one sequence gets in a step are contiguous and their
 positions consecutive (``engine_v2._build_batch``), so every row of a run
 attends to a K/V page from ONE load of it.  The grid walks Q tiles of ``TQ``
 buffer rows; inside a tile a loop with a dynamic trip count visits only the
@@ -14,9 +14,9 @@ across the items of a tile, so a tile of 32 decode rows of 32 sequences is as
 exact as one prefill chunk.  GQA is expressed in the index math (no repeated
 KV): the wrapper hands the kernel ``q`` as ``[Hkv, TQ * g, Dh]``.
 
-:func:`paged_attention_atoms` is the older grid of one row (or one aligned
-atom of rows) times every page of the table; the shapes the run-tiled kernel
-does not take (:func:`run_tiled`) keep it with ``atom == 1``.
+:func:`paged_attention_per_token` is the older grid of one row times every
+page of the table; the shapes the run-tiled kernel does not take
+(:func:`run_tiled`) keep it.
 
 The XLA fallback (``inference/v2/ragged_forward._paged_attention``) computes
 the same math by gather; the kernels replace it on TPU where the gather's
@@ -37,7 +37,7 @@ _NEG_INF = float("-inf")
 from ._common import interpret_mode as _interpret
 
 
-# ---------------------------------------------------- run-tiled (flat) path
+# ----------------------------------------------------------- run-tiled path
 def run_tiled(kv_heads, head_dim, kv_dtype):
     """Whether the run-tiled kernel takes this shape — by the shape alone
     (docs/kernels.md lists what is left on the per-token kernel).  A K/V page
@@ -51,19 +51,25 @@ def run_tiled(kv_heads, head_dim, kv_dtype):
         sublanes in (1, 2, 4, 8) or sublanes % 8 == 0)
 
 
-def tile_rows(heads, kv_heads, tokens):
-    """``TQ``: the buffer rows of one Q tile, from the shapes alone.  Every
-    item of a tile pays for all its ``TQ * g`` MXU rows per KV head, and an
-    item's cost is mostly fixed, so small tiles win although they load a
-    long run's pages more often (docs/kernels.md has the v5e readings)."""
+def tile_rows(heads, kv_heads, head_dim, kv_dtype, tokens):
+    """WHICH kernel reads the cache for a call of ``tokens`` rows: the
+    run-tiled one with Q tiles of the returned ``TQ`` buffer rows, or (None)
+    one grid row a token.  :func:`paged_attention` and the count of its
+    loads (:func:`kernel_page_loads`) both ask here; a further reader is
+    added here.  ``TQ`` follows from the shapes alone: every item of a tile
+    pays for all its ``TQ * g`` MXU rows per KV head, and an item's cost is
+    mostly fixed, so small tiles win although they load a long run's pages
+    more often (docs/kernels.md has the v5e readings)."""
+    if not run_tiled(kv_heads, head_dim, kv_dtype):
+        return None
     g = heads // kv_heads
     return min(max(32, 64 // g // 8 * 8), -(-tokens // 8) * 8)
 
 
 def run_plan(xp, seq_slots, positions, tq, block_size, window=0):
     """The loop bounds of the run-tiled kernel, as arrays — with ``xp`` numpy
-    on the host (``InferenceEngineV2._page_counts``) and jax.numpy inside the
-    step program, so that what is counted is what runs.
+    on the host (:func:`kernel_page_loads`) and jax.numpy inside the step
+    program, so that what is counted is what runs.
 
     ``seq_slots``/``positions``: ``[T]`` (or ``[B, T]``: B calls).  A RUN is
     a stretch of live rows (slot != 0) inside one tile with one slot and
@@ -93,11 +99,35 @@ def run_plan(xp, seq_slots, positions, tq, block_size, window=0):
     return pos, rid, run_slot, first_page.astype(xp.int32), n_pages
 
 
-def page_loads(seq_slots, positions, tq, block_size, window=0):
-    """Host-side count of the K/V page loads one :func:`paged_attention`
-    call performs (each brings one K and one V page)."""
-    return int(run_plan(np, np.asarray(seq_slots), np.asarray(positions),
-                        tq, block_size, window)[-1].sum())
+def kernel_page_loads(seq_slots, positions, *, heads, kv_heads, head_dim,
+                      kv_dtype, block_size, maxb, window=0, row_pages=None):
+    """Host-side (numpy) count of the K/V page loads (each brings one K and
+    one V page) of the kernel :func:`paged_attention` picks for these rows
+    (``[T]``, or ``[B, T]``: B calls) against a ``maxb``-page block table:
+    ``(grid, live, shared)``.  ``grid``: the loads the kernel's loops
+    perform — the run-tiled kernel's (run, page) items, or every row times
+    every page of the table.  ``live``: of those, the loads that hold a key
+    some live row may see (all of the run-tiled kernel's).  ``shared``: of
+    ``row_pages`` (a page count a row, equal along a run; None: 0) the sum
+    over what loads together — once a run, or once a row."""
+    slots, pos = (np.atleast_2d(np.asarray(a))
+                  for a in (seq_slots, positions))
+    if row_pages is not None:
+        row_pages = np.where(slots != 0, np.atleast_2d(row_pages), 0)
+    T = slots.shape[-1]
+    tq = tile_rows(heads, kv_heads, head_dim, kv_dtype, T)
+    if tq is None:
+        first = np.maximum(pos - window + 1, 0) // block_size if window else 0
+        live = np.where(slots != 0, pos // block_size + 1 - first, 0).sum()
+        return slots.size * maxb, int(live), \
+            0 if row_pages is None else int(row_pages.sum())
+    _, rid, _, _, n_pages = run_plan(np, slots, pos, tq, block_size, window)
+    grid = int(n_pages.sum())
+    if row_pages is None:
+        return grid, grid, 0
+    per_row = np.pad(row_pages, ((0, 0), (0, -T % tq))).reshape(-1, tq)
+    runs = rid[:, None, :] == np.arange(tq)[None, :, None]
+    return grid, grid, int((runs * per_row[:, None, :]).max(-1).sum())
 
 
 def _head_pages(buf, kv_heads, block_size):
@@ -216,21 +246,22 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_slots, positions,
     any rows; FAST when the rows of a sequence are contiguous with
     consecutive positions, since a run shares each page load
     (:func:`run_plan`).  ``count_loads=True`` also returns the page loads
-    each tile performed (``[n_tiles]``; tests compare :func:`page_loads`).
+    each tile performed (``[n_tiles]``; tests compare
+    :func:`kernel_page_loads`).
 
     A shape :func:`run_tiled` refuses keeps one grid row a token."""
     T, H, Dh = q.shape
     _, bs, Hkv, _ = k_cache.shape
-    if not run_tiled(Hkv, Dh, k_cache.dtype):
+    tq = tile_rows(H, Hkv, Dh, k_cache.dtype, T)
+    if tq is None:
         if count_loads:
             raise ValueError("count_loads needs the run-tiled kernel")
-        out = paged_attention_atoms(q, k_cache, v_cache,
-                                    block_tables[seq_slots], positions, 1,
-                                    window=window)
+        out = paged_attention_per_token(q, k_cache, v_cache,
+                                        block_tables[seq_slots], positions,
+                                        window=window)
         return jnp.where((seq_slots != 0)[:, None, None], out, 0)
     maxb = block_tables.shape[1]
     g = H // Hkv
-    tq = tile_rows(H, Hkv, T)
     M = tq * g
     pos, rid, run_slot, first_page, n_pages = run_plan(
         jnp, seq_slots, positions, tq, bs, int(window))
@@ -284,17 +315,15 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_slots, positions,
     return (out, loads[0][:, 0]) if count_loads else out
 
 
-# ------------------------------------------------------- atom (prefill) path
-def _atom_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
-                 m_ref, l_ref, *, block_size, scale, groups, atom,
-                 window):
-    """Like :func:`_kernel` but one grid row covers ``atom`` consecutive
-    buffer tokens OF THE SAME SEQUENCE (the batch builder guarantees the
-    alignment; intra-atom pad rows produce discarded outputs).  The q tile
-    becomes [Hkv, atom*g, Dh], so each kv-head dot has ``atom*g`` MXU rows
-    instead of ``g`` — the reference's atom_builder idea
-    (``inference/v2/kernels/ragged_ops/atom_builder``) expressed as tiling.
-    """
+# ------------------------------------------------------- per-token path
+def _per_token_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
+                      acc_ref, m_ref, l_ref, *, block_size, scale, groups,
+                      window):
+    """Grid ``(token i, page j of its table row)``: ``q_ref [1, H, Dh]``
+    against the page ``k_ref``/``v_ref [1, bs, Hkv, Dh]`` the index map took
+    from the block table; the online-softmax state lives across ``j``.  A
+    page past the token's position (or wholly before its window) is still
+    brought in by the pipeline, but not computed on."""
     i, j = pl.program_id(0), pl.program_id(1)
     nb = pl.num_programs(1)
 
@@ -305,106 +334,78 @@ def _atom_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
 
     k_start = j * block_size
-    # positions are consecutive within a run; pads carry pos 0, so the last
-    # real row's position is the max → block-liveness bound for the tile
-    pos_tile = jnp.asarray([pos_ref[i * atom + r] for r in range(atom)],
-                           dtype=jnp.int32)            # [atom]
-    max_pos = jnp.max(pos_tile)
-    live = k_start <= max_pos
+    pos = pos_ref[i]
+    live = k_start <= pos
     if window:
-        # blocks entirely older than the oldest row's window are dead;
-        # pad rows carry pos 0, which only loosens the bound (correct)
-        live = jnp.logical_and(
-            live, k_start + block_size - 1 > jnp.min(pos_tile) - window)
+        live = jnp.logical_and(live,
+                               k_start + block_size - 1 > pos - window)
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)               # [atom, H, Dh]
+        q = q_ref[0].astype(jnp.float32)               # [H, Dh]
         k = k_ref[0].astype(jnp.float32)               # [bs, Hkv, Dh]
         v = v_ref[0].astype(jnp.float32)
-        A, H, Dh = q.shape
+        H, Dh = q.shape
         bs, Hkv, _ = k.shape
-        # [A, H, Dh] → [Hkv, A*g, Dh]; row order within a kv head: (a, g)
-        qg = q.reshape(A, Hkv, groups, Dh).transpose(1, 0, 2, 3) \
-              .reshape(Hkv, A * groups, Dh)
-        s = jnp.einsum("kmd,bkd->kmb", qg, k,
+        s = jnp.einsum("kmd,bkd->kmb", q.reshape(Hkv, groups, Dh), k,
                        preferred_element_type=jnp.float32) * scale
         col = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        pos_rows = jnp.broadcast_to(pos_tile[:, None],
-                                    (A, groups)).reshape(1, A * groups, 1)
-        mask = col <= pos_rows
+        mask = col <= pos
         if window:  # sliding window: only the last `window` positions
-            mask = jnp.logical_and(mask, col > pos_rows - window)
+            mask = jnp.logical_and(mask, col > pos - window)
         s = jnp.where(mask, s, _NEG_INF)
 
-        M = Hkv * A * groups
-        s_f = s.reshape(M, bs)
-        m_prev = m_ref[:, :1]                          # [M, 1]
+        s_f = s.reshape(H, bs)
+        m_prev = m_ref[:, :1]                          # [H, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s_f, axis=1, keepdims=True))
         m_safe = jnp.where(m_new == _NEG_INF, 0.0, m_new)
         p = jnp.exp(s_f - m_safe)
         p = jnp.where(s_f == _NEG_INF, 0.0, p)
         alpha = jnp.where(m_prev == _NEG_INF, 0.0, jnp.exp(m_prev - m_safe))
         l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-        pv = jnp.einsum("kmb,bkd->kmd", p.reshape(Hkv, A * groups, bs), v,
+        pv = jnp.einsum("kmb,bkd->kmd", p.reshape(Hkv, groups, bs), v,
                         preferred_element_type=jnp.float32)
-        acc_ref[:] = acc_ref[:] * alpha + pv.reshape(M, Dh)
+        acc_ref[:] = acc_ref[:] * alpha + pv.reshape(H, Dh)
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
     @pl.when(j == nb - 1)
     def _finish():
         l = l_ref[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        out = acc_ref[:] / l_safe                      # [Hkv*A*g, Dh]
-        _, A, H, Dh = o_ref.shape
-        Hkv = H // groups
-        out = out.reshape(Hkv, A, groups, Dh).transpose(1, 0, 2, 3) \
-                 .reshape(A, H, Dh)
-        o_ref[0] = out.astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)) \
+            .astype(o_ref.dtype)
 
 
-def paged_attention_atoms(q, k_cache, v_cache, tables_t, positions,
-                          atom, block_size=None, window=0):
-    """Atom-tiled variant for prefill regions: q rows [T, H, Dh] where every
-    aligned run of ``atom`` rows shares one sequence (pads allowed).  Page
-    streaming uses the FIRST row's block table; per-row position masking
-    gives each token its causal view.  T must be a multiple of ``atom``."""
+def paged_attention_per_token(q, k_cache, v_cache, tables_t, positions,
+                              window=0):
+    """One grid row a token: q ``[T, H, Dh]``, ``tables_t [T, maxb]`` each
+    token's block-table row, ``positions [T]`` → ``[T, H, Dh]``.  Every
+    token streams every page of its row; the kernel for the shapes
+    :func:`run_tiled` refuses."""
     T, H, Dh = q.shape
-    if T % atom:
-        raise ValueError(f"token count {T} not a multiple of atom {atom}")
-    nb_total, bs, Hkv, _ = k_cache.shape
+    _, bs, Hkv, _ = k_cache.shape
     maxb = tables_t.shape[1]
-    groups = H // Hkv
-    scale = Dh**-0.5
-    n_atoms = T // atom
-
+    page = pl.BlockSpec((1, bs, Hkv, Dh),
+                        lambda i, j, tb, ps: (tb[i, j], 0, 0, 0))
+    row = pl.BlockSpec((1, H, Dh), lambda i, j, tb, ps: (i, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(n_atoms, maxb),
-        in_specs=[
-            pl.BlockSpec((1, atom, H, Dh), lambda i, j, tb, ps: (i, 0, 0, 0)),
-            pl.BlockSpec((1, bs, Hkv, Dh),
-                         lambda i, j, tb, ps: (tb[i * atom, j], 0, 0, 0)),
-            pl.BlockSpec((1, bs, Hkv, Dh),
-                         lambda i, j, tb, ps: (tb[i * atom, j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, atom, H, Dh),
-                               lambda i, j, tb, ps: (i, 0, 0, 0)),
+        grid=(T, maxb),
+        in_specs=[row, page, page],
+        out_specs=row,
         scratch_shapes=[
-            pltpu.VMEM((Hkv * atom * groups, Dh), jnp.float32),
-            pltpu.VMEM((Hkv * atom * groups, 128), jnp.float32),
-            pltpu.VMEM((Hkv * atom * groups, 128), jnp.float32),
+            pltpu.VMEM((H, Dh), jnp.float32),
+            pltpu.VMEM((H, 128), jnp.float32),
+            pltpu.VMEM((H, 128), jnp.float32),
         ],
     )
     return pl.pallas_call(
-        functools.partial(_atom_kernel, block_size=bs, scale=scale,
-                          groups=groups, atom=atom, window=int(window)),
+        functools.partial(_per_token_kernel, block_size=bs, scale=Dh**-0.5,
+                          groups=H // Hkv, window=int(window)),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_atoms, atom, H, Dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((T, H, Dh), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
-        name="ds_paged_decode" if atom == 1 else "ds_paged_atom",
-    )(tables_t, positions, q.reshape(n_atoms, atom, H, Dh),
-      k_cache, v_cache).reshape(T, H, Dh)
+        name="ds_paged_decode",
+    )(tables_t, positions, q, k_cache, v_cache)

@@ -18,9 +18,9 @@ layer adds over the raw engine:
   re-enters the admission queue at the FRONT with its full token history,
   so re-admission recomputes the KV prefix and greedy decoding continues
   token-identically;
-* **prefill/decode disaggregation** — the engine's two-layout atom
-  machinery (``engine_v2._atom_layout``) packs the regions; the scheduler
-  books each iteration as a ``ds:serve.step`` span whose counts say what
+* **prefill/decode disaggregation** — the engine packs decode tokens
+  first and prefill chunks after them (``engine_v2._build_batch``); the
+  scheduler books each iteration as a ``ds:serve.step`` span whose counts say what
   it held (``prefill`` / ``decode`` / ``mixed`` in the recorder's phase
   column), and fuses multi-token decode bursts when every in-flight
   sequence is in pure decode;

@@ -92,7 +92,7 @@ MODULE_MLP = "mlp"
 # ---- Pallas kernels: ``pallas_call(name=...)`` prefixes by family
 KERNEL_PREFIX = "ds_"
 KERNEL_FLASH = "ds_flash_"                # fwd, bwd_dq, bwd_dkv (+ _bias_)
-KERNEL_PAGED = "ds_paged_"                # runs (flat), decode (per token), atom
+KERNEL_PAGED = "ds_paged_"                # runs (run-tiled), decode (per token)
 KERNEL_OPTIMIZER = "ds_fused_"            # adam, lion, lamb_phase1/2
 
 #: JAX's own markers in a scope path

@@ -138,6 +138,84 @@ def test_chunked_prefill_budget_smaller_than_prompt():
     assert got == [expected], (got, expected)
 
 
+# ------------------------------------------------------------ batch packing
+def _seen(eng, uids):
+    return {u: eng.state_manager.get_sequence(u).seen_tokens for u in uids}
+
+
+def test_build_batch_more_pending_than_budget_gives_each_token_a_row():
+    """Ten decode tokens and a 12-token prompt against a budget of 16: no
+    scheduled token may land on another's row (a cursor that did not advance
+    would lose one), and the budget is filled to its last row."""
+    model, cfg, params = _model()
+    eng = InferenceEngineV2(model, params, dict(
+        dtype="float32", state_manager=dict(
+            max_tracked_sequences=16, max_ragged_batch_size=16,
+            max_ragged_sequence_count=16, max_context=64, block_size=16,
+            num_blocks=60)))
+    rng = np.random.default_rng(3)
+    uids = list(range(11))
+    eng.put(uids[:10], [[int(t)] for t in rng.integers(1, 96, size=10)])
+    eng.put([10], [rng.integers(1, 96, size=12).tolist()])
+    before = _seen(eng, uids)
+    toks, pos, slots, last_idx, finishing = eng._build_batch()
+    after = _seen(eng, uids)
+    placed = sum(after[u] - before[u] for u in uids)
+    assert int((slots != 0).sum()) == placed == 16
+    assert eng.last_step_counts["live_tokens"] == placed
+    assert eng.last_step_counts["decode_tokens"] == 10
+    # the decode tokens finish (their logits are wanted), the prompt does not
+    assert sorted(seq.uid for seq, _ in finishing) == uids[:10]
+    for seq, idx in finishing:
+        assert slots[idx] == seq.slot and last_idx[seq.slot] == idx
+    eng.flush(uids)
+
+
+def test_build_batch_decodes_first_and_the_prompt_takes_the_rest():
+    """A prompt as long as the budget beside three waiting decode rows: the
+    decode tokens come first, the prompt gets every remaining row (not none,
+    not a row fewer), and what it could not place waits — nothing is
+    skipped."""
+    model, cfg, params = _model()
+    eng = _v2(model, params, budget=16, max_context=64)
+    rng = np.random.default_rng(5)
+    eng.put([0, 1, 2], [[int(t)] for t in rng.integers(1, 96, size=3)])
+    eng.put([3], [rng.integers(1, 96, size=16).tolist()])
+    toks, pos, slots, last_idx, finishing = eng._build_batch()
+    sm = eng.state_manager
+    assert [sm.get_sequence(u).slot for u in (0, 1, 2)] == slots[:3].tolist()
+    assert (slots[3:] == sm.get_sequence(3).slot).all()
+    assert pos[3:].tolist() == list(range(13))
+    assert toks[3:].tolist() == sm.get_sequence(3).tokens[:13]
+    assert _seen(eng, range(4)) == {0: 1, 1: 1, 2: 1, 3: 13}
+    assert len(sm.get_sequence(3).pending()) == 3
+    # the next step holds the prompt's last three tokens only
+    for u in (0, 1, 2):
+        sm.get_sequence(u).done = True
+    toks, pos, slots, _, finishing = eng._build_batch()
+    assert pos[slots != 0].tolist() == [13, 14, 15]
+    assert [seq.uid for seq, _ in finishing] == [3]
+    eng.flush(range(4))
+
+
+def test_a_configuration_that_pins_the_removed_atom_key_still_builds():
+    """``perfbench/configs/*.json`` pass ``"prefill_atom_size": 0`` in
+    ``state_manager`` (a pin around a path that is gone): the key lands
+    where every unknown key lands and selects nothing."""
+    model, cfg, params = _model()
+    sm = dict(max_ragged_batch_size=16, block_size=8, max_context=64,
+              num_blocks=64, max_ragged_sequence_count=8,
+              max_tracked_sequences=8)
+    prompts = [[5, 9, 2, 7, 1, 3, 8, 4, 6], [11, 12]]
+    outs = []
+    for extra in ({}, {"prefill_atom_size": 0}):
+        eng = InferenceEngineV2(model, params, dict(
+            dtype="float32", state_manager={**sm, **extra}))
+        outs.append(eng.generate(prompts, max_new_tokens=4))
+    assert outs[0] == outs[1] and all(len(o) == 4 for o in outs[0])
+    assert "prefill_atom_size" not in DSStateManagerConfig.model_fields
+
+
 def test_put_query_flush_api():
     model, cfg, params = _model()
     eng = _v2(model, params)

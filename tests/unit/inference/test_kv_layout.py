@@ -30,7 +30,7 @@ _spec = importlib.util.spec_from_file_location(
 hlo_check = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(hlo_check)
 
-STATICS = ("cfg", "block_size", "layout", "use_kernel", "kv_dtype")
+STATICS = ("cfg", "block_size", "use_kernel", "kv_dtype")
 ZOO = {
     "LlamaModel": (llama.LlamaModel, lambda: llama.llama_tiny(
         dtype="float32", remat=False, num_key_value_heads=2)),

@@ -1,7 +1,8 @@
-"""The run-tiled paged-attention kernel (``ops/pallas/paged_attention.
-paged_attention``, interpret mode): equal to the XLA gather on every row a
-sequence owns, zero on dead rows, and its page loads are the ones
-``run_plan`` counts: the count ``InferenceEngineV2._page_counts`` reports."""
+"""The paged-attention kernels (``ops/pallas/paged_attention.
+paged_attention``, interpret mode), run-tiled and per-token: equal to the XLA
+gather on every row a sequence owns, zero on dead rows, and the run-tiled
+kernel's page loads are the ones ``kernel_page_loads`` counts: the count
+``InferenceEngineV2._page_counts`` reports."""
 
 import jax
 import jax.numpy as jnp
@@ -12,15 +13,15 @@ from deepspeed_tpu.inference.v2 import InferenceEngineV2
 from deepspeed_tpu.inference.v2 import ragged_forward
 from deepspeed_tpu.inference.v2.ragged_forward import _paged_attention
 from deepspeed_tpu.models import llama
-from deepspeed_tpu.ops.pallas.paged_attention import (page_loads,
+from deepspeed_tpu.ops.pallas.paged_attention import (kernel_page_loads,
                                                       paged_attention,
-                                                      run_tiled, tile_rows)
+                                                      run_tiled)
 
 MAX_SEQS = 8
 
 
 def _case(heads, kv_heads, runs, T, bs=8, maxb=8, window=0,
-          dtype=jnp.float32, seed=0):
+          dtype=jnp.float32, seed=0, head_dim=128):
     """``runs``: (slot, first position, rows, first buffer row) each; every
     other row is dead (slot 0, position 0)."""
     rng = np.random.default_rng(seed)
@@ -34,10 +35,24 @@ def _case(heads, kv_heads, runs, T, bs=8, maxb=8, window=0,
         tables[slot, :used] = perm[slot * maxb:slot * maxb + used]
         slots[at:at + n] = slot
         pos[at:at + n] = np.arange(p0, p0 + n)
-    q = jnp.asarray(rng.standard_normal((T, heads, 128)), dtype)
-    kc, vc = (jnp.asarray(rng.standard_normal((nb, bs, kv_heads, 128)), dtype)
-              for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((T, heads, head_dim)), dtype)
+    kc, vc = (jnp.asarray(rng.standard_normal((nb, bs, kv_heads, head_dim)),
+                          dtype) for _ in range(2))
     return q, kc, vc, jnp.asarray(tables), slots, pos
+
+
+def _assert_is_the_gather(out, q, kc, vc, tables, slots, pos, window):
+    """``out`` equals the XLA gather on every live row and is zero on dead
+    ones."""
+    ref = _paged_attention(q, kc, vc, tables, jnp.asarray(slots),
+                           jnp.asarray(pos), kc.shape[1], window=window,
+                           use_kernel=False)
+    live = slots != 0
+    tol = 2e-5 if kc.dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32)[live],
+                               np.asarray(ref, np.float32)[live],
+                               atol=tol, rtol=tol)
+    assert not np.asarray(out, np.float32)[~live].any()
 
 
 CASES = {
@@ -83,20 +98,57 @@ def test_run_tiled_kernel_matches_the_gather(name):
     out, loads = paged_attention(q, kc, vc, tables, jnp.asarray(slots),
                                  jnp.asarray(pos), window=window,
                                  count_loads=True)
-    ref = _paged_attention(q, kc, vc, tables, jnp.asarray(slots),
-                           jnp.asarray(pos), bs, window=window,
-                           use_kernel=False)
-    live = slots != 0
-    tol = 2e-5 if kc.dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(np.asarray(out, np.float32)[live],
-                               np.asarray(ref, np.float32)[live],
-                               atol=tol, rtol=tol)
-    assert not np.asarray(out, np.float32)[~live].any()
+    _assert_is_the_gather(out, q, kc, vc, tables, slots, pos, window)
     # what the loops loaded is what the host counts, and no more than the
     # pages that hold a key some row of the run may see
-    tq = tile_rows(heads, kv_heads, T)
-    assert int(loads.sum()) == want_loads == page_loads(slots, pos, tq, bs,
-                                                        window)
+    grid, live, _ = kernel_page_loads(
+        slots, pos, heads=heads, kv_heads=kv_heads, head_dim=128,
+        kv_dtype=kc.dtype, block_size=bs, maxb=tables.shape[1], window=window)
+    assert int(loads.sum()) == want_loads == grid == live
+
+
+PER_TOKEN_CASES = {
+    # name: (heads, kv_heads, head_dim, runs, T, kwargs); each names the
+    # fault it would catch
+    # positions 28..35 under a window of 11 see keys 18..35: a page wholly
+    # before the window is skipped, one it cuts is masked inside
+    "window_cuts_a_page": (4, 2, 16, [(1, 28, 8, 0)], 8, {"window": 11}),
+    # a prefill run, dead rows, another sequence's run: a row reads ITS
+    # table row and ITS position, not its neighbour's
+    "rows_of_two_sequences_and_dead_rows_between": (
+        4, 2, 16, [(1, 8, 6, 0), (2, 0, 4, 8)], 12, {}),
+    # head sizes of the zoo that are not whole lanes (OPT, Phi)
+    "head_size_64": (4, 4, 64, [(1, 3, 10, 0), (2, 20, 1, 10)], 12, {}),
+    "head_size_80_window": (
+        4, 2, 80, [(1, 13, 9, 0), (2, 40, 1, 9)], 12, {"window": 11}),
+    # 16-bit MQA (Falcon): every query head reads the one KV head
+    "mqa_bfloat16": (8, 1, 128, [(1, 3, 10, 0), (2, 20, 1, 10)], 12,
+                     {"dtype": jnp.bfloat16}),
+    # decode rows with idle slots between (a burst's rows)
+    "decode_rows_and_idle_slots": (
+        4, 1, 64, [(s, 6 * s, 1, s) for s in (1, 3, 4, 7)], MAX_SEQS, {}),
+}
+
+
+@pytest.mark.parametrize("name", list(PER_TOKEN_CASES))
+def test_per_token_kernel_matches_the_gather(name):
+    heads, kv_heads, head_dim, runs, T, kw = PER_TOKEN_CASES[name]
+    q, kc, vc, tables, slots, pos = _case(heads, kv_heads, runs, T,
+                                          head_dim=head_dim, **kw)
+    bs, window = kc.shape[1], kw.get("window", 0)
+    assert not run_tiled(kv_heads, head_dim, kc.dtype)
+    out = paged_attention(q, kc, vc, tables, jnp.asarray(slots),
+                          jnp.asarray(pos), window=window)
+    _assert_is_the_gather(out, q, kc, vc, tables, slots, pos, window)
+    # one grid row a token: every row streams every page of the table
+    maxb = tables.shape[1]
+    grid, live_pages, _ = kernel_page_loads(
+        slots, pos, heads=heads, kv_heads=kv_heads, head_dim=head_dim,
+        kv_dtype=kc.dtype, block_size=bs, maxb=maxb, window=window)
+    assert grid == T * maxb
+    assert live_pages == sum(
+        p // bs + 1 - (max(p - window + 1, 0) // bs if window else 0)
+        for p in pos[slots != 0])
 
 
 def test_shapes_the_run_tiled_kernel_leaves_to_the_per_token_one():
@@ -108,35 +160,26 @@ def test_shapes_the_run_tiled_kernel_leaves_to_the_per_token_one():
             (32, 80, bf16), (12, 64, bf16), (1, 128, bf16), (40, 128, bf16),
             (12, 128, f32), (8, 128, jnp.float16)):
         assert not run_tiled(kv_heads, head_dim, dtype)
-    # ... and such a shape still answers, one grid row a token
-    q, kc, vc, tables, slots, pos = _case(4, 2, [(1, 3, 10, 0)], 12)
-    q, kc, vc = q[..., :16], kc[..., :16], vc[..., :16]
-    out = paged_attention(q, kc, vc, tables, jnp.asarray(slots),
-                          jnp.asarray(pos))
-    ref = _paged_attention(q, kc, vc, tables, jnp.asarray(slots),
-                           jnp.asarray(pos), 8, use_kernel=False)
-    np.testing.assert_allclose(np.asarray(out)[:10], np.asarray(ref)[:10],
-                               atol=2e-5, rtol=2e-5)
-    assert not np.asarray(out)[10:].any()
+    # such a shape still answers, one grid row a token: PER_TOKEN_CASES
 
 
 # ------------------------------------------------------------- engine level
-def _tiny_mistral(budget=48):
-    """Mistral's shape at toy width: 4 query / 2 KV heads of 128, a sliding
-    window that binds, the flat layout."""
-    cfg = llama.llama_tiny(dtype="float32", remat=False, hidden_size=512,
-                           sliding_window=24)
+def _tiny_mistral(budget=48, head_dim=128):
+    """Mistral's shape at toy width: 4 query / 2 KV heads of ``head_dim``, a
+    sliding window that binds, default engine settings."""
+    cfg = llama.llama_tiny(dtype="float32", remat=False,
+                           hidden_size=4 * head_dim, sliding_window=24)
     model = llama.LlamaModel(cfg)
     params = model.init(jax.random.PRNGKey(0),
                         jnp.zeros((1, 8), jnp.int32))["params"]
     sm = dict(max_tracked_sequences=8, max_ragged_batch_size=budget,
               max_ragged_sequence_count=8, max_context=128, block_size=16,
-              num_blocks=40, prefill_atom_size=0)
+              num_blocks=40)
     return InferenceEngineV2(model, params=params, config=dict(
         dtype="float32", decode_burst=4, state_manager=sm))
 
 
-def test_flat_batch_is_runs_of_consecutive_positions():
+def test_batch_is_runs_of_consecutive_positions():
     """What the kernel is fast on, and the counts rest on: the rows a
     sequence gets in one step are contiguous, their positions consecutive;
     dead rows carry slot 0 and position 0."""
@@ -147,17 +190,18 @@ def test_flat_batch_is_runs_of_consecutive_positions():
                        for n in (30, 1, 7, 29)])
     steps = 0
     while (batch := eng._build_batch()) is not None:
-        toks, pos, slots, _, _, layout = batch
-        assert layout == (0, 0)
+        toks, pos, slots, _, _ = batch
         for s in set(slots[slots != 0].tolist()):
             rows = np.flatnonzero(slots == s)
             assert (np.diff(rows) == 1).all()
             assert (np.diff(pos[rows]) == 1).all()
         assert not pos[slots == 0].any() and not toks[slots == 0].any()
         c = eng.last_step_counts
-        bs, T = eng.kv_cache.block_size, len(slots)
-        assert c["grid_pages"] == c["live_pages"] == page_loads(
-            slots, pos, tile_rows(4, 2, T), bs, window=24)
+        bs = eng.kv_cache.block_size
+        assert c["grid_pages"] == c["live_pages"] == kernel_page_loads(
+            slots, pos, heads=4, kv_heads=2, head_dim=128,
+            kv_dtype=jnp.float32, block_size=bs,
+            maxb=eng.state_manager.block_table.shape[1], window=24)[0]
         assert c["row_pages"] == sum(
             p // bs + 1 - max(p - 24 + 1, 0) // bs for p in pos[slots != 0])
         assert c["row_pages"] >= c["live_pages"] > 0
@@ -166,26 +210,30 @@ def test_flat_batch_is_runs_of_consecutive_positions():
     eng.flush(range(4))
 
 
+@pytest.mark.parametrize("head_dim", [128, 16],
+                         ids=["run_tiled", "per_token"])
 def test_tiny_mistral_streams_the_same_tokens_with_and_without_the_kernel(
-        monkeypatch):
-    """Greedy tokens of the flat layout, ragged steps and decode bursts: the
-    run-tiled kernel (interpret mode) against ``use_kernel=False``.  Each
-    engine gets its own jit of the step, so the suite's cached programs
-    (traced without the kernel gate) are neither used nor replaced."""
+        monkeypatch, head_dim):
+    """Greedy tokens of an engine at its defaults, ragged steps and decode
+    bursts: the kernel ``paged_attention`` picks for the head size
+    (interpret mode) against ``use_kernel=False``.  Each engine gets its own
+    jit of the step, so the suite's cached programs (traced without the
+    kernel gate) are neither used nor replaced."""
+    assert run_tiled(2, head_dim, jnp.float32) == (head_dim == 128)
     monkeypatch.setenv("DS_TPU_FORCE_PALLAS", "1")
     inner = ragged_forward.llama_ragged_step.__wrapped__
     rng = np.random.default_rng(3)
     prompts = [rng.integers(1, 96, size=n).tolist() for n in (41, 9, 2, 23)]
     outs = []
     for use_kernel in (True, False):
-        eng = _tiny_mistral()
+        eng = _tiny_mistral(head_dim=head_dim)
 
         def step(*a, _use=use_kernel, **kw):
             return inner(*a, **{**kw, "use_kernel": _use})
 
         eng._step_fn = jax.jit(
-            step, static_argnames=("cfg", "block_size", "layout",
-                                   "use_kernel", "kv_dtype"),
+            step, static_argnames=("cfg", "block_size", "use_kernel",
+                                   "kv_dtype"),
             donate_argnums=(1, ))
         outs.append(eng.generate(prompts, max_new_tokens=10))
         eng.flush(range(len(prompts)))

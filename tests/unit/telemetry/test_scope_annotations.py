@@ -20,6 +20,7 @@ import deepspeed_tpu
 from deepspeed_tpu import telemetry
 from deepspeed_tpu.inference.v2 import InferenceEngineV2
 from deepspeed_tpu.models import llama
+from deepspeed_tpu.ops.pallas.paged_attention import kernel_page_loads
 from deepspeed_tpu.serving import ServingScheduler
 from deepspeed_tpu.telemetry import names
 from deepspeed_tpu.telemetry.trace import TRACE_FILE
@@ -180,14 +181,11 @@ def test_serving_steps_in_a_real_trace(tiny, tmp_path):
     total = sum(len(sched.query(u).prompt) + len(sched.query(u).produced) - 1
                 for u in uids)
     assert sum(s[3]["live_tokens"] for s in steps) == total
-    # the grid: every budget row (flat layout), or every decode row and
-    # every prefill atom (a prefill-heavy step), times every page of the
-    # table: the tiny model's heads of 16 stay on the per-token kernel
+    # the grid: every budget row times every page of the table: the tiny
+    # model's heads of 16 stay on the per-token kernel
     # (tests/unit/ops/test_paged_runs.py counts the run-tiled kernel's loads)
     ragged = [s[3] for s in steps if s[3]["kind"] == names.KIND_RAGGED]
-    decode_cap, atom = sched.engine._atom_layout()
-    assert {c["grid_pages"] for c in ragged} == {
-        32 * (64 // 8), (decode_cap + (32 - decode_cap) // atom) * (64 // 8)}
+    assert {c["grid_pages"] for c in ragged} == {32 * (64 // 8)}
     assert all(c["row_pages"] >= c["live_pages"] for c in ragged)
     assert {c["token_budget"] for c in ragged} == {32}
     admitted = [e[3]["uid"] for e in t.named(names.SERVE_ADMITTED)]
@@ -214,16 +212,19 @@ def test_preemption_is_an_event_with_its_uid(tiny, tmp_path):
     assert len(t.named(names.SERVE_ADMITTED)) == 8 + sched.preemptions
 
 
-def test_page_counts_follow_the_layout_and_the_window(tiny):
+def test_page_counts_follow_the_kernel_and_the_window(tiny):
     engine = _scheduler(tiny).engine
     pos = np.array([0, 7, 8, 30, 0, 0, 0, 0], np.int32)
     slots = np.array([1, 2, 3, 4, 0, 0, 0, 0], np.int32)
-    # heads of 16: the per-token kernel.  Flat: 8 rows x 8 pages; contexts
-    # span 1, 1, 2 and 4 pages
+    # heads of 16: the per-token kernel.  8 rows x 8 pages; contexts span
+    # 1, 1, 2 and 4 pages
     assert engine._page_counts(pos, slots) == (64, 8, 8)
-    # atoms of 2 behind 2 decode rows: 2 + 3 grid rows; an atom streams the
-    # pages of its deepest row
-    assert engine._page_counts(pos, slots, layout=(2, 2)) == (40, 2 + 4, 8)
+    # the same rows with heads of 128, through the function the engine asks:
+    # the run-tiled kernel loads each decode row's live pages, and nothing
+    # for a dead row
+    assert kernel_page_loads(
+        slots, pos, heads=4, kv_heads=2, head_dim=128, kv_dtype=jnp.float32,
+        block_size=8, maxb=8) == (8, 8, 0)
     # a burst: k rows of positions
     assert engine._page_counts(pos[None, :4] + np.arange(2)[:, None],
                                np.broadcast_to(slots[:4], (2, 4))) == (
@@ -341,7 +342,6 @@ def test_enabled_recorder_gets_the_serving_step_with_its_counts(
     finally:
         telemetry.shutdown()
     steps = [e for e in events if e["cat"] == "serve"]
-    # the prompt may take two chunks (the prefill region of an atom layout)
     phases = [e["name"] for e in steps]
     assert phases[0] == "prefill" and phases[-2:] == ["decode", "decode"]
     assert set(phases) == {"prefill", "decode"}
@@ -382,7 +382,7 @@ def test_every_pallas_call_has_a_ds_name_listed_in_the_docs():
         assert name.startswith(names.KERNEL_PREFIX), name
         assert f"`{name}`" in doc, f"{name} missing from docs/kernels.md"
     assert sum(n.startswith(names.KERNEL_FLASH) for n in kernel_names) == 7
-    assert sum(n.startswith(names.KERNEL_PAGED) for n in kernel_names) == 3
+    assert sum(n.startswith(names.KERNEL_PAGED) for n in kernel_names) == 2
     assert sum(n.startswith(names.KERNEL_OPTIMIZER)
                for n in kernel_names) == 4
 

@@ -121,22 +121,16 @@ def main():
                          sds((4096, 512), jnp.float32)))
 
     from deepspeed_tpu.ops.pallas.paged_attention import (
-        paged_attention, paged_attention_atoms)
+        paged_attention, paged_attention_per_token)
     # serving shapes at Llama-7B width: 32 heads x 128, 128-token pages
-    T, atom, maxb = 64, 16, 5
+    T, maxb = 64, 5
     pq = sds((T, 32, D), bf16)
     kc = sds((41, 128, 32, D), bf16)
     bt = sds((T, maxb), jnp.int32)
     pos = sds((T, ), jnp.int32)
-    results.append(check(
-        "paged_attention_atoms(per token)",
-        lambda q, k, v, t, l: paged_attention_atoms(q, k, v, t, l, 1),
-        pq, kc, kc, bt, pos))
-    results.append(check(
-        "paged_attention_atoms(prefill)",
-        lambda q, k, v, t, l: paged_attention_atoms(q, k, v, t, l, atom),
-        pq, kc, kc, bt, pos))
-    # the flat layout's run-tiled kernel: the serving cell's own shape
+    results.append(check("paged_attention_per_token",
+                         paged_attention_per_token, pq, kc, kc, bt, pos))
+    # the run-tiled kernel: the serving cell's own shape
     # (Mistral-7B, 768-token budget, 27-page table, window 4096), an MHA
     # shape, and head sizes of the zoo that stay on the per-token kernel
     for name, T, heads, kv_heads, head_dim, maxb, window in (

@@ -162,7 +162,7 @@ def run_traffic(scheduler, plan, max_steps=200_000):
         wall_s = time.perf_counter() - t0
     finally:
         # an aborted drive must not leave the process paying an analysis
-        # compile per new serving layout forever
+        # compile per new serving program forever
         cost_model.enable_capture(False)
     reqs = [scheduler.query(u) for u in uids]
     ttfts = [r.ttft for r in reqs if r.ttft is not None]
