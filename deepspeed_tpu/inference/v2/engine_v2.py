@@ -312,13 +312,15 @@ class InferenceEngineV2:
         last_idx = np.zeros(sm.max_seqs, dtype=np.int32)
         for seq, idx in finishing:
             last_idx[seq.slot] = idx
-        grid_pages, live_pages, row_pages = self._page_counts(pos, slots)
+        grid_pages, live_pages, row_pages, short_pages = self._page_counts(
+            pos, slots)
         self.last_step_counts = {
             "kind": _names.KIND_RAGGED, "token_budget": T,
             "live_tokens": placed, "decode_tokens": placed_decode,
             "prefill_tokens": placed - placed_decode,
             "grid_pages": grid_pages, "live_pages": live_pages,
-            "row_pages": row_pages, "burst_k": 0}
+            "row_pages": row_pages, "short_pages": short_pages,
+            "burst_k": 0}
         return toks, pos, slots, last_idx, finishing
 
     def _table_snapshot(self):
@@ -385,23 +387,25 @@ class InferenceEngineV2:
         return window_row_positions(pos, kv.window_size, kv.chunk_size)
 
     def _page_counts(self, pos, slots):
-        """``(grid_pages, live_pages, row_pages)`` of one paged-attention
-        call over the rows at positions ``pos`` in slots ``slots`` (0: a
-        dead row); ``[k, rows]`` arrays are the ``k`` calls of a burst.
-        ``grid_pages``, ``live_pages``: the K/V page loads the kernel's
-        loops perform and, of those, the loads that hold a key some live
-        row may see (``paged_attention.kernel_page_loads``, beside the
-        kernels it describes).  ``row_pages``: the (row, page) pairs the
-        live rows' contexts (their sliding windows) span — ``row_pages /
-        grid_pages`` is how many rows share one page load."""
+        """``(grid_pages, live_pages, row_pages, short_pages)`` of one
+        paged-attention call over the rows at positions ``pos`` in slots
+        ``slots`` (0: a dead row); ``[k, rows]`` arrays are the ``k`` calls
+        of a burst.  ``grid_pages``, ``live_pages``, ``short_pages``: the K/V
+        page loads the kernel's loops perform and, of those, the loads that
+        hold a key some live row may see, and the loads whose item computes
+        one slab of rows and not its tile
+        (``paged_attention.kernel_page_loads``, beside the kernels it
+        describes).  ``row_pages``: the (row, page) pairs the live rows'
+        contexts (their sliding windows) span — ``row_pages / grid_pages``
+        is how many rows share one page load."""
         bs = self.kv_cache.block_size
         window = int(getattr(self.model_config, "sliding_window", 0) or 0)
         pos, slots = self._row_positions(np.atleast_2d(pos)), \
             np.atleast_2d(slots)
-        grid, live, _ = self._kernel_loads(pos, slots)
+        grid, live, _, short = self._kernel_loads(pos, slots)
         first = np.maximum(pos - window + 1, 0) // bs if window else 0
         pages = np.where(slots != 0, pos // bs + 1 - first, 0)
-        return grid, live, int(pages.sum())
+        return grid, live, int(pages.sum()), short
 
     @staticmethod
     def _sample_row(row, temperature, top_k, top_p, rng):
@@ -589,14 +593,15 @@ class InferenceEngineV2:
             slots_k = np.broadcast_to(np.where(act, np.arange(n), 0), (k, n))
             for seq in seqs:        # as the cache stands when the burst ends
                 seq.seen_tokens += k
-            grid_pages, live_pages, row_pages = self._page_counts(pos_k,
-                                                                  slots_k)
+            grid_pages, live_pages, row_pages, short_pages = \
+                self._page_counts(pos_k, slots_k)
             self.last_step_counts = {
                 "kind": _names.KIND_BURST, "token_budget": n * k,
                 "live_tokens": len(seqs) * k,
                 "decode_tokens": len(seqs) * k, "prefill_tokens": 0,
                 "grid_pages": grid_pages, "live_pages": live_pages,
-                "row_pages": row_pages, "burst_k": k}
+                "row_pages": row_pages, "short_pages": short_pages,
+                "burst_k": k}
         from .ragged_forward import decode_burst
         if sample:
             if getattr(self, "_burst_key", None) is None or \

@@ -12,7 +12,11 @@ each page arrives by a double-buffered DMA whose block id is read from the
 scalar-prefetched block table.  The online-softmax state is per row and lives
 across the items of a tile, so a tile of 32 decode rows of 32 sequences is as
 exact as one prefill chunk.  GQA is expressed in the index math (no repeated
-KV): the wrapper hands the kernel ``q`` as ``[Hkv, TQ * g, Dh]``.
+KV): the wrapper hands the kernel ``q`` as ``[Hkv, TQ * g, Dh]``.  The rows an
+item COMPUTES follow the rows of its run: a run that lies inside one slab of
+:func:`slab_rows` rows (a decode token, a burst's row) loads, computes and
+stores that slab alone, every other item the whole tile; and an item's KV
+heads go through the softmax together, their dots back to back.
 
 :func:`paged_attention_per_token` is the older grid of one row times every
 page of the table; the shapes the run-tiled kernel does not take
@@ -24,6 +28,7 @@ HBM blowup ([T, max_ctx, ...]) matters.
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +37,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = float("-inf")
+#: rows of scores the run-tiled kernel takes through the softmax in one
+#: piece: an item's KV heads are stacked up to it, so that their dots run
+#: back to back and not each behind its own softmax (docs/kernels.md has the
+#: v5e readings)
+_STACK_ROWS = 1024
 
 
 from ._common import interpret_mode as _interpret
@@ -56,17 +66,25 @@ def tile_rows(heads, kv_heads, head_dim, kv_dtype, tokens):
     run-tiled one with Q tiles of the returned ``TQ`` buffer rows, or (None)
     one grid row a token.  :func:`paged_attention` and the count of its
     loads (:func:`kernel_page_loads`) both ask here; a further reader is
-    added here.  ``TQ`` follows from the shapes alone: every item of a tile
-    pays for all its ``TQ * g`` MXU rows per KV head, and an item's cost is
-    mostly fixed, so small tiles win although they load a long run's pages
-    more often (docs/kernels.md has the v5e readings)."""
+    added here.  ``TQ`` follows from the shapes alone: an item on the whole
+    tile pays for all its ``TQ * g`` MXU rows per KV head, so small tiles
+    win although they load a long run's pages more often (docs/kernels.md
+    has the v5e readings)."""
     if not run_tiled(kv_heads, head_dim, kv_dtype):
         return None
     g = heads // kv_heads
     return min(max(32, 64 // g // 8 * 8), -(-tokens // 8) * 8)
 
 
-def run_plan(xp, seq_slots, positions, tq, block_size, window=0):
+def slab_rows(g):
+    """The rows ``R`` a SHORT item computes, from the shape alone: the least
+    multiple of 8 (the float32 sublane tile) that holds one token's ``g``
+    query rows wherever they start, with the slab's first row a multiple of
+    8 — 8 where ``g`` divides 8, 16 for ``g`` in 3, 5, 6, 7 and 9-16."""
+    return -(-(8 - math.gcd(g, 8) + g) // 8) * 8
+
+
+def run_plan(xp, seq_slots, positions, tq, block_size, window=0, g=1):
     """The loop bounds of the run-tiled kernel, as arrays — with ``xp`` numpy
     on the host (:func:`kernel_page_loads`) and jax.numpy inside the step
     program, so that what is counted is what runs.
@@ -75,8 +93,14 @@ def run_plan(xp, seq_slots, positions, tq, block_size, window=0):
     a stretch of live rows (slot != 0) inside one tile with one slot and
     consecutive positions.  Returns, per tile: ``pos``, ``rid [n, tq]`` each
     row's position and run (-1: dead row), and per run ``run_slot``,
-    ``first_page``, ``n_pages [n, tq]`` (runs compacted to the front, 0
-    pages past the last run)."""
+    ``first_page``, ``n_pages``, ``slab [n, tq]`` (runs compacted to the
+    front, 0 pages past the last run).
+
+    ``slab`` says which rows a run's items compute, of the tile's ``tq * g``
+    (a token's ``g`` query rows adjacent): the first row of the one slab of
+    :func:`slab_rows` rows that holds all the run's rows (a multiple of 8),
+    or -1: no such slab, its items compute the whole tile.  The kernel
+    branches on it and :func:`kernel_page_loads` counts by it."""
     T = seq_slots.shape[-1]
     pad = -T % tq
     slots, pos = (xp.pad(a.reshape(-1, T).astype(xp.int32),
@@ -96,7 +120,12 @@ def run_plan(xp, seq_slots, positions, tq, block_size, window=0):
                   if window else xp.zeros_like(first_pos))
     n_pages = xp.where(n_rows > 0, (first_pos + n_rows - 1) // block_size
                        + 1 - first_page, 0).astype(xp.int32)
-    return pos, rid, run_slot, first_page.astype(xp.int32), n_pages
+    R = slab_rows(g)
+    row0 = (first * xp.arange(tq, dtype=xp.int32)).sum(-1) * g
+    slab = xp.minimum(row0 // 8 * 8, tq * g - R)
+    slab = xp.where((n_rows > 0) & (row0 + n_rows * g <= slab + R), slab, -1)
+    return pos, rid, run_slot, first_page.astype(xp.int32), n_pages, \
+        slab.astype(xp.int32)
 
 
 def kernel_page_loads(seq_slots, positions, *, heads, kv_heads, head_dim,
@@ -104,12 +133,14 @@ def kernel_page_loads(seq_slots, positions, *, heads, kv_heads, head_dim,
     """Host-side (numpy) count of the K/V page loads (each brings one K and
     one V page) of the kernel :func:`paged_attention` picks for these rows
     (``[T]``, or ``[B, T]``: B calls) against a ``maxb``-page block table:
-    ``(grid, live, shared)``.  ``grid``: the loads the kernel's loops
+    ``(grid, live, shared, short)``.  ``grid``: the loads the kernel's loops
     perform — the run-tiled kernel's (run, page) items, or every row times
     every page of the table.  ``live``: of those, the loads that hold a key
     some live row may see (all of the run-tiled kernel's).  ``shared``: of
     ``row_pages`` (a page count a row, equal along a run; None: 0) the sum
-    over what loads together — once a run, or once a row."""
+    over what loads together — once a run, or once a row.  ``short``: of
+    ``grid``, the loads whose item computes one slab of rows and not the
+    tile (:func:`run_plan`'s ``slab``; 0 on the per-token kernel)."""
     slots, pos = (np.atleast_2d(np.asarray(a))
                   for a in (seq_slots, positions))
     if row_pages is not None:
@@ -120,14 +151,15 @@ def kernel_page_loads(seq_slots, positions, *, heads, kv_heads, head_dim,
         first = np.maximum(pos - window + 1, 0) // block_size if window else 0
         live = np.where(slots != 0, pos // block_size + 1 - first, 0).sum()
         return slots.size * maxb, int(live), \
-            0 if row_pages is None else int(row_pages.sum())
-    _, rid, _, _, n_pages = run_plan(np, slots, pos, tq, block_size, window)
-    grid = int(n_pages.sum())
+            0 if row_pages is None else int(row_pages.sum()), 0
+    _, rid, _, _, n_pages, slab = run_plan(np, slots, pos, tq, block_size,
+                                           window, heads // kv_heads)
+    grid, short = int(n_pages.sum()), int(n_pages[slab >= 0].sum())
     if row_pages is None:
-        return grid, grid, 0
+        return grid, grid, 0, short
     per_row = np.pad(row_pages, ((0, 0), (0, -T % tq))).reshape(-1, tq)
     runs = rid[:, None, :] == np.arange(tq)[None, :, None]
-    return grid, grid, int((runs * per_row[:, None, :]).max(-1).sum())
+    return grid, grid, int((runs * per_row[:, None, :]).max(-1).sum()), short
 
 
 def _head_pages(buf, kv_heads, block_size):
@@ -151,19 +183,24 @@ def _head_pages(buf, kv_heads, block_size):
     return out
 
 
-def _run_kernel(tables_ref, slot_ref, first_ref, npages_ref, total_ref,
-                q_ref, pos_ref, rid_ref, k_hbm, v_hbm, o_ref, *rest,
-                tq, block_size, maxb, scale, window, count_loads):
+def _run_kernel(tables_ref, slot_ref, first_ref, npages_ref, slab_ref,
+                total_ref, q_ref, pos_ref, rid_ref, k_hbm, v_hbm, o_ref,
+                *rest, tq, block_size, maxb, scale, window, count_loads,
+                short=True):
     """One Q tile: ``q_ref [1, Hkv, M, Dh]`` (``M = tq * g`` rows, row
     ``t * g + gi``), ``pos_ref``/``rid_ref [1, M, 1]`` each row's position
-    and run, against the tile's items ``(run k, page p)``."""
+    and run, against the tile's items ``(run k, page p)``.  An item computes
+    the rows ``slab_ref`` gives its run (:func:`run_plan`): one slab, or
+    (-1) the tile; ``short=False`` computes the tile in every item (tests
+    hold the two to the same bits)."""
     if count_loads:
         loads_ref, *rest = rest
-    k_buf, v_buf, sem, acc_ref, m_ref, l_ref = rest
+    k_buf, v_buf, sem, q32_ref, acc_ref, m_ref, l_ref = rest
     i = pl.program_id(0)
     base = i * tq
     total = total_ref[i]
     kv_heads, M = acc_ref.shape[:2]
+    R = slab_rows(M // tq)
 
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
@@ -180,9 +217,54 @@ def _run_kernel(tables_ref, slot_ref, first_ref, npages_ref, total_ref,
     def _first():
         for c in copies(0, 0, 0):
             c.start()
+        # a 16-bit ref cannot be sliced at 8 rows: widen q once a tile
+        for h in range(kv_heads):
+            q32_ref[h] = q_ref[0, h].astype(jnp.float32)
+
+    def attend(k, p, buf, rows):
+        """The item on the rows ``rows`` of the tile (``n`` of its ``M``).  A
+        row's scores, softmax state and accumulation do not depend on which
+        other rows, or heads, are computed beside it: as many heads as fill
+        ``_STACK_ROWS`` rows go through the softmax as one array, between
+        their q.K dots and their P.V dots."""
+        pos, rid = pos_ref[0, rows], rid_ref[0, rows]          # [n, 1]
+        n = pos.shape[0]
+        col = (first_ref[base + k] + p) * block_size + \
+            jax.lax.broadcasted_iota(jnp.int32, (n, block_size), 1)
+        mask = jnp.logical_and(rid == k, col <= pos)
+        if window:  # sliding window: only the last `window` positions
+            mask = jnp.logical_and(mask, col > pos - window)
+        k_pages = _head_pages(k_buf.at[buf], kv_heads, block_size)
+        v_pages = _head_pages(v_buf.at[buf], kv_heads, block_size)
+        together = max(1, _STACK_ROWS // n)
+        for h0 in range(0, kv_heads, together):
+            hs = range(h0, min(h0 + together, kv_heads))
+            c = len(hs)
+            s = jnp.concatenate([jax.lax.dot_general(
+                q32_ref[h, rows], k_pages[h], (((1, ), (1, )), ((), ())),
+                preferred_element_type=jnp.float32) for h in hs]) \
+                * scale                                     # [c * n, bs]
+            live = jnp.tile(mask, (c, 1))
+            s = jnp.where(live, s, _NEG_INF)
+            state = lambda ref: ref[h0:h0 + c, rows].reshape(
+                c * n, ref.shape[2])[:, :1]
+            m_prev = state(m_ref)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            m_safe = jnp.where(m_new == _NEG_INF, 0.0, m_new)
+            e = jnp.where(live, jnp.exp(s - m_safe), 0.0)
+            alpha = jnp.where(m_prev == _NEG_INF, 0.0,
+                              jnp.exp(m_prev - m_safe))
+            l_new = alpha * state(l_ref) + jnp.sum(e, axis=1, keepdims=True)
+            for i, h in enumerate(hs):
+                part = slice(i * n, (i + 1) * n)
+                acc_ref[h, rows] = acc_ref[h, rows] * alpha[part] + jnp.dot(
+                    e[part], v_pages[h], preferred_element_type=jnp.float32)
+            for ref, new in ((m_ref, m_new), (l_ref, l_new)):
+                ref[h0:h0 + c, rows] = jnp.broadcast_to(
+                    new, (c * n, ref.shape[2])).reshape(c, n, ref.shape[2])
 
     def item(it, carry):
-        k, p, loaded = carry
+        k, p, loaded, n_short = carry
         buf = it % 2
         last = p + 1 == npages_ref[base + k]
         k_next = jnp.where(last, k + 1, k)
@@ -196,37 +278,25 @@ def _run_kernel(tables_ref, slot_ref, first_ref, npages_ref, total_ref,
         for c in copies(k, p, buf):
             c.wait()
 
-        pos = pos_ref[0]                                   # [M, 1]
-        col = (first_ref[base + k] + p) * block_size + \
-            jax.lax.broadcasted_iota(jnp.int32, (M, block_size), 1)
-        mask = jnp.logical_and(rid_ref[0] == k, col <= pos)
-        if window:  # sliding window: only the last `window` positions
-            mask = jnp.logical_and(mask, col > pos - window)
-        k_pages = _head_pages(k_buf.at[buf], kv_heads, block_size)
-        v_pages = _head_pages(v_buf.at[buf], kv_heads, block_size)
-        for h in range(kv_heads):
-            q = q_ref[0, h].astype(jnp.float32)            # [M, Dh]
-            s = jax.lax.dot_general(
-                q, k_pages[h], (((1, ), (1, )), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # [M, bs]
-            s = jnp.where(mask, s, _NEG_INF)
-            m_prev = m_ref[h, :, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            m_safe = jnp.where(m_new == _NEG_INF, 0.0, m_new)
-            e = jnp.where(mask, jnp.exp(s - m_safe), 0.0)
-            alpha = jnp.where(m_prev == _NEG_INF, 0.0,
-                              jnp.exp(m_prev - m_safe))
-            l_new = alpha * l_ref[h, :, :1] + jnp.sum(e, axis=1,
-                                                      keepdims=True)
-            acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
-                e, v_pages[h], preferred_element_type=jnp.float32)
-            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
-        return k_next, p_next, loaded + 1
+        slab = slab_ref[base + k] if short else jnp.int32(-1)
 
-    *_, loaded = jax.lax.fori_loop(0, total, item, (jnp.int32(0), ) * 3)
+        @pl.when(slab < 0)
+        def _tile():
+            attend(k, p, buf, slice(None))
+
+        if short:
+            @pl.when(slab >= 0)
+            def _slab():
+                attend(k, p, buf, pl.ds(pl.multiple_of(slab, 8), R))
+
+        return k_next, p_next, loaded + 1, \
+            n_short + (slab >= 0).astype(jnp.int32)
+
+    *_, loaded, n_short = jax.lax.fori_loop(0, total, item,
+                                            (jnp.int32(0), ) * 4)
     if count_loads:
-        loads_ref[0, 0] = loaded
+        loads_ref[0, 0, 0] = loaded
+        loads_ref[0, 0, 1] = n_short
 
     for h in range(kv_heads):
         l = l_ref[h, :, :1]
@@ -246,8 +316,8 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_slots, positions,
     any rows; FAST when the rows of a sequence are contiguous with
     consecutive positions, since a run shares each page load
     (:func:`run_plan`).  ``count_loads=True`` also returns the page loads
-    each tile performed (``[n_tiles]``; tests compare
-    :func:`kernel_page_loads`).
+    each tile performed and, of those, the short items (``[n_tiles, 2]``;
+    tests compare :func:`kernel_page_loads`).
 
     A shape :func:`run_tiled` refuses keeps one grid row a token."""
     T, H, Dh = q.shape
@@ -263,8 +333,8 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_slots, positions,
     maxb = block_tables.shape[1]
     g = H // Hkv
     M = tq * g
-    pos, rid, run_slot, first_page, n_pages = run_plan(
-        jnp, seq_slots, positions, tq, bs, int(window))
+    pos, rid, run_slot, first_page, n_pages, slab = run_plan(
+        jnp, seq_slots, positions, tq, bs, int(window), g)
     n = rid.shape[0]
 
     def rows(a):            # [n, tq] → [n, M, 1]: a token's g rows adjacent
@@ -278,11 +348,11 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_slots, positions,
     out_shape = [jax.ShapeDtypeStruct((n, Hkv, M, Dh), q.dtype)]
     out_specs = [tile(Hkv, M, Dh)]
     if count_loads:
-        out_shape.append(jax.ShapeDtypeStruct((n, 1), jnp.int32))
-        out_specs.append(pl.BlockSpec((1, 1), lambda i, *_: (i, 0),
+        out_shape.append(jax.ShapeDtypeStruct((n, 1, 2), jnp.int32))
+        out_specs.append(pl.BlockSpec((1, 1, 2), lambda i, *_: (i, 0, 0),
                                       memory_space=pltpu.SMEM))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=6,
         grid=(n, ),
         in_specs=[tile(Hkv, M, Dh), tile(M, 1), tile(M, 1),
                   pl.BlockSpec(memory_space=pl.ANY),
@@ -292,6 +362,7 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_slots, positions,
             pltpu.VMEM((2, bs, Hkv, Dh), k_cache.dtype),
             pltpu.VMEM((2, bs, Hkv, Dh), v_cache.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((Hkv, M, Dh), jnp.float32),      # q, widened
             pltpu.VMEM((Hkv, M, Dh), jnp.float32),
             pltpu.VMEM((Hkv, M, 128), jnp.float32),
             pltpu.VMEM((Hkv, M, 128), jnp.float32),
@@ -308,8 +379,8 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_slots, positions,
         interpret=_interpret(),
         name="ds_paged_runs",
     )(block_tables.reshape(-1).astype(jnp.int32), run_slot.reshape(-1),
-      first_page.reshape(-1), n_pages.reshape(-1), n_pages.sum(-1),
-      qt, rows(pos), rows(rid), k_cache, v_cache)
+      first_page.reshape(-1), n_pages.reshape(-1), slab.reshape(-1),
+      n_pages.sum(-1), qt, rows(pos), rows(rid), k_cache, v_cache)
     out = out.reshape(n, Hkv, tq, g, Dh).transpose(0, 2, 1, 3, 4) \
         .reshape(n * tq, H, Dh)[:T]
     return (out, loads[0][:, 0]) if count_loads else out

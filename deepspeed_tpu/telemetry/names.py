@@ -56,8 +56,8 @@ KIND_BURST = "burst"
 #: counts); the batch builder makes them, the scheduler's span carries them
 SERVE_STEP_COUNTS = ("step", "kind", "running", "queued", "token_budget",
                      "live_tokens", "prefill_tokens", "decode_tokens",
-                     "grid_pages", "live_pages", "row_pages", "burst_k",
-                     "preempts",
+                     "grid_pages", "live_pages", "row_pages", "short_pages",
+                     "burst_k", "preempts",
                      # what the cache holds, and what a window-plus-summary
                      # cache (EvaByte) did in the step
                      "context_tokens", "held_blocks", "block_size",

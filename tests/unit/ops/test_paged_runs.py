@@ -2,7 +2,12 @@
 paged_attention``, interpret mode), run-tiled and per-token: equal to the XLA
 gather on every row a sequence owns, zero on dead rows, and the run-tiled
 kernel's page loads are the ones ``kernel_page_loads`` counts: the count
-``InferenceEngineV2._page_counts`` reports."""
+``InferenceEngineV2._page_counts`` reports.  An item of the run-tiled kernel
+computes one slab of rows where its run lies inside one (a decode token), the
+whole tile otherwise: the same bits either way, and the short items the kernel
+ran are the ones the host counts."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -12,21 +17,23 @@ import pytest
 from deepspeed_tpu.inference.v2 import InferenceEngineV2
 from deepspeed_tpu.inference.v2 import ragged_forward
 from deepspeed_tpu.inference.v2.ragged_forward import _paged_attention
+from deepspeed_tpu.inference.v2.ragged import window_row_positions
 from deepspeed_tpu.models import llama
+from deepspeed_tpu.ops.pallas import paged_attention as paged_module
 from deepspeed_tpu.ops.pallas.paged_attention import (kernel_page_loads,
                                                       paged_attention,
-                                                      run_tiled)
+                                                      run_tiled, slab_rows)
 
 MAX_SEQS = 8
 
 
 def _case(heads, kv_heads, runs, T, bs=8, maxb=8, window=0,
-          dtype=jnp.float32, seed=0, head_dim=128):
+          dtype=jnp.float32, seed=0, head_dim=128, max_seqs=MAX_SEQS):
     """``runs``: (slot, first position, rows, first buffer row) each; every
     other row is dead (slot 0, position 0)."""
     rng = np.random.default_rng(seed)
-    nb = 1 + MAX_SEQS * maxb
-    tables = np.zeros((MAX_SEQS, maxb), np.int32)
+    nb = 1 + max_seqs * maxb
+    tables = np.zeros((max_seqs, maxb), np.int32)
     perm = rng.permutation(np.arange(1, nb))
     slots, pos = np.zeros(T, np.int32), np.zeros(T, np.int32)
     for slot, p0, n, at in runs:
@@ -55,43 +62,96 @@ def _assert_is_the_gather(out, q, kc, vc, tables, slots, pos, window):
     assert not np.asarray(out, np.float32)[~live].any()
 
 
+def _eva(position, window=32, chunk=4):
+    """A position inside EvaByte's block-table row ``[summaries | window]``
+    (block 8 = window 32 / chunk 4)."""
+    return int(window_row_positions(np.int32(position), window, chunk))
+
+
 CASES = {
-    # name: (heads, kv_heads, runs, T, kwargs, expected page loads)
+    # name: (heads, kv_heads, runs, T, kwargs, expected page loads, and of
+    # those the loads of SHORT items: the run's g * rows query rows lie in
+    # one slab of `slab_rows(g)` rows that starts at a multiple of 8)
     # tiles of 32 rows; 40 rows at positions 3..42: pages 0-4, then 0-5
     "gqa_32_8_prefill_crosses_a_tile": (
-        32, 8, [(1, 3, 40, 0)], 40, {}, 5 + 6),
-    "gqa_4_1": (4, 1, [(1, 0, 20, 0), (2, 17, 1, 20)], 24, {}, 3 + 3),
-    # tiles of 64 rows: MHA feeds the MXU one row a token
+        32, 8, [(1, 3, 40, 0)], 40, {}, 5 + 6, 0),
+    "gqa_4_1": (4, 1, [(1, 0, 20, 0), (2, 17, 1, 20)], 24, {}, 3 + 3, 3),
+    # tiles of 64 rows: MHA feeds the MXU one row a token.  The run's last
+    # two rows (positions 64, 65: pages 0-8) are a short run of the next tile
     "mha_crosses_a_tile": (
-        4, 4, [(1, 0, 66, 0), (2, 9, 1, 66)], 72, {"maxb": 12}, 8 + 9 + 2),
+        4, 4, [(1, 0, 66, 0), (2, 9, 1, 66)], 72, {"maxb": 12}, 8 + 9 + 2,
+        9 + 2),
     "two_runs_and_dead_rows_in_one_tile": (
-        8, 2, [(1, 5, 9, 2), (2, 30, 6, 14)], 32, {}, 2 + 5),
+        8, 2, [(1, 5, 9, 2), (2, 30, 6, 14)], 32, {}, 2 + 5, 0),
     "decode_rows_of_different_sequences": (
         8, 2, [(s, 7 * s, 1, s - 1) for s in range(1, 8)], 8, {},
+        sum(7 * s // 8 + 1 for s in range(1, 8)),
         sum(7 * s // 8 + 1 for s in range(1, 8))),
     "tokens_not_a_multiple_of_the_tile": (
-        8, 2, [(3, 0, 37, 0), (4, 11, 1, 37)], 43, {}, 4 + 5 + 2),
+        8, 2, [(3, 0, 37, 0), (4, 11, 1, 37)], 43, {}, 4 + 5 + 2, 2),
     # window 11: positions 28..35 see keys 18..35 = pages 2..4 of 0..4
     "window_kills_leading_pages": (
-        8, 2, [(1, 28, 8, 0), (2, 50, 1, 8)], 16, {"window": 11}, 3 + 2),
+        8, 2, [(1, 28, 8, 0), (2, 50, 1, 8)], 16, {"window": 11}, 3 + 2, 2),
     "table_with_unused_trailing_entries": (
-        8, 2, [(1, 0, 5, 0), (2, 9, 1, 5)], 8, {"maxb": 27}, 1 + 2),
+        8, 2, [(1, 0, 5, 0), (2, 9, 1, 5)], 8, {"maxb": 27}, 1 + 2, 2),
     # decode_burst: row i is slot i, idle slots are dead rows in between
     "decode_burst_layout": (
         8, 2, [(s, 5 * s, 1, s) for s in (1, 2, 4, 7)], MAX_SEQS, {},
+        sum(5 * s // 8 + 1 for s in (1, 2, 4, 7)),
         sum(5 * s // 8 + 1 for s in (1, 2, 4, 7))),
     "bfloat16_cache_two_heads_a_word": (
         32, 8, [(1, 3, 40, 0), (2, 20, 1, 40)], 48,
-        {"dtype": jnp.bfloat16}, 5 + 6 + 3),
+        {"dtype": jnp.bfloat16}, 5 + 6 + 3, 3),
     "bfloat16_cache_one_word_a_row": (
         4, 2, [(1, 3, 10, 0), (2, 20, 1, 10)], 16,
-        {"dtype": jnp.bfloat16}, 2 + 3),
+        {"dtype": jnp.bfloat16}, 2 + 3, 3),
+    # ---- the rows an item computes (g = 4: two tokens a slab of 8 rows)
+    # a burst's 64 rows at the serving cell's heads: every item is short
+    "burst_of_64_one_token_runs": (
+        32, 8, [(s, (5 * s) % 23, 1, s) for s in range(1, 64) if s % 9], 64,
+        {"dtype": jnp.bfloat16, "max_seqs": 64, "maxb": 3},
+        sum((5 * s) % 23 // 8 + 1 for s in range(1, 64) if s % 9),
+        sum((5 * s) % 23 // 8 + 1 for s in range(1, 64) if s % 9)),
+    # as _build_batch lays a step: prefill chunks and decode rows, then dead
+    # rows; only the decode rows' items are short
+    "mixed_prefill_chunks_and_decode_rows": (
+        32, 8, [(1, 16, 21, 0), (2, 9, 1, 21), (3, 30, 1, 22), (4, 7, 1, 23),
+                (5, 0, 13, 24), (6, 63, 1, 37)], 48, {},
+        5 + 2 + 4 + 1 + (1 + 2) + 8, 2 + 4 + 1 + 8),
+    # two tokens of one sequence: one slab from an even row, two from an odd
+    "two_token_run_on_an_even_row": (
+        32, 8, [(1, 14, 2, 2), (2, 3, 1, 4)], 8, {}, 2 + 1, 2 + 1),
+    "two_token_run_on_an_odd_row": (
+        32, 8, [(1, 14, 2, 3), (2, 3, 1, 5)], 8, {}, 2 + 1, 1),
+    # MHA (slab of 8 tokens): rows 6..9 straddle, rows 16..23 fill one slab
+    "a_run_that_straddles_a_slab": (
+        4, 4, [(1, 5, 4, 6), (2, 20, 8, 16), (3, 11, 1, 24)], 32, {},
+        2 + 4 + 2, 4 + 2),
+    # g = 7 (Qwen2's 28 / 4): slabs of 16 rows hold a token wherever it
+    # starts, the tile's last token too; two tokens from row 12 do not fit
+    "g_7_slabs_of_16_rows": (
+        28, 4, [(1, 9, 1, 0), (2, 17, 1, 5), (3, 30, 2, 12), (4, 4, 1, 31)],
+        32, {}, 2 + 3 + 4 + 1, 2 + 3 + 1),
+    "g_8_one_token_a_slab": (
+        16, 2, [(1, 6, 3, 0), (2, 12, 1, 3), (3, 40, 1, 7)], 8, {},
+        2 + 2 + 6, 2 + 6),
+    # a window that binds on short items: position 50 sees keys 40..50
+    "binding_window_on_decode_rows": (
+        32, 8, [(1, 50, 1, 0), (2, 23, 1, 1), (3, 8, 1, 2)], 8,
+        {"window": 11, "dtype": jnp.bfloat16}, 2 + 2 + 2, 2 + 2 + 2),
+    # EvaByte: MHA, rows positioned inside [summaries | window]: a prefill
+    # chunk at 70..79 (row positions 22..31: pages 0-3) and decode rows at
+    # 33, 64, 95 (row positions 9, 16, 47)
+    "evabyte_row_positions": (
+        4, 4, [(1, _eva(70), 10, 0), (2, _eva(33), 1, 10),
+               (3, _eva(64), 1, 11), (4, _eva(95), 1, 12)], 16,
+        {"dtype": jnp.bfloat16}, 4 + 2 + 3 + 6, 2 + 3 + 6),
 }
 
 
 @pytest.mark.parametrize("name", list(CASES))
 def test_run_tiled_kernel_matches_the_gather(name):
-    heads, kv_heads, runs, T, kw, want_loads = CASES[name]
+    heads, kv_heads, runs, T, kw, want_loads, want_short = CASES[name]
     q, kc, vc, tables, slots, pos = _case(heads, kv_heads, runs, T, **kw)
     bs, window = kc.shape[1], kw.get("window", 0)
     assert run_tiled(kv_heads, 128, kc.dtype)
@@ -100,11 +160,46 @@ def test_run_tiled_kernel_matches_the_gather(name):
                                  count_loads=True)
     _assert_is_the_gather(out, q, kc, vc, tables, slots, pos, window)
     # what the loops loaded is what the host counts, and no more than the
-    # pages that hold a key some row of the run may see
-    grid, live, _ = kernel_page_loads(
+    # pages that hold a key some row of the run may see; the items that
+    # computed one slab of rows are the ones the host calls short
+    grid, live, _, short = kernel_page_loads(
         slots, pos, heads=heads, kv_heads=kv_heads, head_dim=128,
         kv_dtype=kc.dtype, block_size=bs, maxb=tables.shape[1], window=window)
-    assert int(loads.sum()) == want_loads == grid == live
+    assert int(loads[:, 0].sum()) == want_loads == grid == live
+    assert int(loads[:, 1].sum()) == want_short == short
+
+
+@pytest.mark.parametrize("name", list(CASES))
+def test_short_items_give_the_bits_of_whole_tiles(name, monkeypatch):
+    """The rows an item computes, and the heads that go through the softmax
+    together, do not change a live row's bits: the kernel against itself
+    with every item on its whole tile, one head at a time (the items as
+    they were before either existed)."""
+    heads, kv_heads, runs, T, kw, want_loads, _ = CASES[name]
+    q, kc, vc, tables, slots, pos = _case(heads, kv_heads, runs, T, seed=1,
+                                          **kw)
+    args = (q, kc, vc, tables, jnp.asarray(slots), jnp.asarray(pos))
+    window = kw.get("window", 0)
+    out, loads = paged_attention(*args, window=window, count_loads=True)
+    monkeypatch.setattr(paged_module, "_run_kernel", functools.partial(
+        paged_module._run_kernel, short=False))
+    monkeypatch.setattr(paged_module, "_STACK_ROWS", 1)
+    whole, whole_loads = paged_attention.__wrapped__(
+        *args, window=window, count_loads=True)
+    assert int(whole_loads[:, 0].sum()) == want_loads
+    assert not int(whole_loads[:, 1].sum())
+    as_bits = lambda a: np.asarray(a.astype(jnp.float32)).view(np.uint32)
+    np.testing.assert_array_equal(as_bits(out), as_bits(whole))
+    assert np.asarray(loads)[:, 0].tolist() == \
+        np.asarray(whole_loads)[:, 0].tolist()
+
+
+def test_slab_rows_follow_from_the_group_size():
+    # the float32 sublane tile where a token's rows cannot straddle two;
+    # 16 rows otherwise, never more than the smallest tile (8 tokens)
+    assert [slab_rows(g) for g in (1, 2, 4, 8)] == [8] * 4
+    assert [slab_rows(g) for g in (3, 5, 6, 7, 12, 16)] == [16] * 6
+    assert all(slab_rows(g) <= 8 * g for g in range(1, 72))
 
 
 PER_TOKEN_CASES = {
@@ -142,10 +237,10 @@ def test_per_token_kernel_matches_the_gather(name):
     _assert_is_the_gather(out, q, kc, vc, tables, slots, pos, window)
     # one grid row a token: every row streams every page of the table
     maxb = tables.shape[1]
-    grid, live_pages, _ = kernel_page_loads(
+    grid, live_pages, _, short = kernel_page_loads(
         slots, pos, heads=heads, kv_heads=kv_heads, head_dim=head_dim,
         kv_dtype=kc.dtype, block_size=bs, maxb=maxb, window=window)
-    assert grid == T * maxb
+    assert grid == T * maxb and short == 0
     assert live_pages == sum(
         p // bs + 1 - (max(p - window + 1, 0) // bs if window else 0)
         for p in pos[slots != 0])
@@ -198,10 +293,12 @@ def test_batch_is_runs_of_consecutive_positions():
         assert not pos[slots == 0].any() and not toks[slots == 0].any()
         c = eng.last_step_counts
         bs = eng.kv_cache.block_size
-        assert c["grid_pages"] == c["live_pages"] == kernel_page_loads(
+        loads = kernel_page_loads(
             slots, pos, heads=4, kv_heads=2, head_dim=128,
             kv_dtype=jnp.float32, block_size=bs,
-            maxb=eng.state_manager.block_table.shape[1], window=24)[0]
+            maxb=eng.state_manager.block_table.shape[1], window=24)
+        assert c["grid_pages"] == c["live_pages"] == loads[0]
+        assert c["short_pages"] == loads[3] <= c["grid_pages"]
         assert c["row_pages"] == sum(
             p // bs + 1 - max(p - 24 + 1, 0) // bs for p in pos[slots != 0])
         assert c["row_pages"] >= c["live_pages"] > 0

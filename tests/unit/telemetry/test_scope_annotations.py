@@ -174,6 +174,9 @@ def test_serving_steps_in_a_real_trace(tiny, tmp_path):
         assert c["live_tokens"] == c["prefill_tokens"] + c["decode_tokens"]
         assert 0 < c["live_pages"] <= c["grid_pages"]
         assert c["row_pages"] > 0
+        # ragged steps and bursts both say how many loads were short items
+        # (none here: heads of 16 stay on the per-token kernel)
+        assert c["short_pages"] == 0
         assert (c["burst_k"] >= 2) == (c["kind"] == names.KIND_BURST)
     assert kinds == {names.KIND_RAGGED, names.KIND_BURST}
     # live_tokens is what the engine step consumed: every prompt token and
@@ -218,28 +221,41 @@ def test_page_counts_follow_the_kernel_and_the_window(tiny):
     slots = np.array([1, 2, 3, 4, 0, 0, 0, 0], np.int32)
     # heads of 16: the per-token kernel.  8 rows x 8 pages; contexts span
     # 1, 1, 2 and 4 pages
-    assert engine._page_counts(pos, slots) == (64, 8, 8)
+    assert engine._page_counts(pos, slots) == (64, 8, 8, 0)
     # the same rows with heads of 128, through the function the engine asks:
     # the run-tiled kernel loads each decode row's live pages, and nothing
-    # for a dead row
+    # for a dead row; a decode row's two query rows lie in one slab of 8, so
+    # every load's item is short
     assert kernel_page_loads(
         slots, pos, heads=4, kv_heads=2, head_dim=128, kv_dtype=jnp.float32,
-        block_size=8, maxb=8) == (8, 8, 0)
+        block_size=8, maxb=8) == (8, 8, 0, 8)
     # a burst: k rows of positions
     assert engine._page_counts(pos[None, :4] + np.arange(2)[:, None],
                                np.broadcast_to(slots[:4], (2, 4))) == (
-        64, 1 + 2 + 2 + 4 + 8, 1 + 2 + 2 + 4 + 8)
+        64, 1 + 2 + 2 + 4 + 8, 1 + 2 + 2 + 4 + 8, 0)
     # heads of 128: the run-tiled kernel loads a run's pages once, and only
     # live ones.  Rows 0-2 are one run of slot 1 (positions 6, 7, 8: pages
-    # 0-1), row 3 a decode row at 30 (pages 0-3)
+    # 0-1), row 3 a decode row at 30 (pages 0-3); three tokens' six query
+    # rows lie in one slab and the fourth's two end it: every item is short
     engine.model_config = types.SimpleNamespace(
         num_attention_heads=4, num_key_value_heads=2, head_dim=128)
     pos = np.array([6, 7, 8, 30, 0, 0, 0, 0], np.int32)
     slots = np.array([1, 1, 1, 4, 0, 0, 0, 0], np.int32)
-    assert engine._page_counts(pos, slots) == (2 + 4, 2 + 4, 1 + 1 + 2 + 4)
+    assert engine._page_counts(pos, slots) == (2 + 4, 2 + 4, 1 + 1 + 2 + 4,
+                                               2 + 4)
     # a window of 8 keeps position 30's pages 2-3 and position 8's 0-1
     engine.model_config.sliding_window = 8
-    assert engine._page_counts(pos, slots) == (2 + 2, 2 + 2, 1 + 1 + 2 + 2)
+    assert engine._page_counts(pos, slots) == (2 + 2, 2 + 2, 1 + 1 + 2 + 2,
+                                               2 + 2)
+    # a fourth token of the run (eight query rows, then two): the decode
+    # row's slab is the next one, the run still fills its own
+    pos = np.array([6, 7, 8, 9, 30, 0, 0, 0], np.int32)
+    slots = np.array([1, 1, 1, 1, 4, 0, 0, 0], np.int32)
+    assert engine._page_counts(pos, slots)[3] == 2 + 2
+    # a fifth: ten rows straddle two slabs, its items compute the tile
+    pos = np.array([6, 7, 8, 9, 10, 30, 0, 0], np.int32)
+    slots = np.array([1, 1, 1, 1, 1, 4, 0, 0], np.int32)
+    assert engine._page_counts(pos, slots)[3] == 2
 
 
 # ----------------------------------------------------------------- training

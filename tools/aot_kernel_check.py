@@ -14,10 +14,25 @@ it runs, how fast, or whether the result is right.
 Prints one PASS/FAIL line per kernel; exit 0 only if all pass, 3 if no TPU
 topology can be described here (no libtpu).  ``tests/unit/ops/
 test_aot_kernel_check.py`` runs it in tier-1.
+
+``--ops <kernel>`` (a ``pallas_call`` name, ``ds_paged_runs``) also counts what
+Mosaic made of that kernel: it has the compiler write the kernel after its
+last pass (``--xla_mosaic_dump_to``, a temporary directory) and prints, for
+every check that compiled the kernel, the histogram of ``llo.*`` operations in
+the body of the kernel's first loop and in each branch (``scf.if``) directly
+inside it — for ``ds_paged_runs`` the item loop: the prefetch, the item on the
+whole tile, the item on one slab.  Counts of instructions as written, not of
+cycles: the place a kernel issue starts from.
 """
 
+import argparse
+import collections
+import glob
+import json
 import os
+import re
 import sys
+import tempfile
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -47,7 +62,56 @@ def check(name, fn, *args):
         return name, "FAIL", f"{type(e).__name__}: {str(e)[:300]}"
 
 
+def loop_ops(llo_text):
+    """``[(region, {llo op: count})]`` of the first ``scf.for`` of a kernel's
+    final LLO text: ``"loop"`` (its whole body) and ``"if <n>"`` for each
+    ``scf.if`` directly inside it, in order; [] with no loop.  An attribute's
+    braces open and close on one line, so a region ends on the line after
+    which the count of open braces is what it was before the region's
+    first."""
+    lines = llo_text.splitlines()
+    regions, depth = [], 0      # [name, first line, depth before it, last]
+    for n, line in enumerate(lines):
+        if not regions and "scf.for" in line:
+            regions.append(["loop", n, depth, None])
+        elif regions and depth == regions[0][2] + 1 and "scf.if" in line:
+            regions.append([f"if {len(regions)}", n, depth, None])
+        depth += line.count("{") - line.count("}")
+        for r in regions:
+            if r[3] is None and n > r[1] and depth == r[2]:
+                r[3] = n
+        if regions and regions[0][3] is not None:
+            break
+    return [(name, dict(collections.Counter(
+        re.findall(r"llo\.[a-z_0-9.]+", "\n".join(lines[lo:hi])))
+        .most_common())) for name, lo, _, hi in regions]
+
+
 def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--ops", metavar="KERNEL", help="also print the histogram"
+                    " of llo.* ops in KERNEL's first loop (module docstring)")
+    opts = ap.parse_args()
+    dump = None
+    if opts.ops:        # read by libtpu when it is loaded: before the topology
+        dump = tempfile.TemporaryDirectory(prefix="mosaic_dump_")
+        os.environ["LIBTPU_INIT_ARGS"] = " ".join(filter(None, (
+            os.environ.get("LIBTPU_INIT_ARGS"),
+            f"--xla_mosaic_dump_to={dump.name}")))
+    ops = []
+
+    def checked(name, fn, *args):
+        """:func:`check`, and the ops of the ``--ops`` kernel it compiled."""
+        result = check(name, fn, *args)
+        if dump is not None:
+            for path in sorted(glob.glob(os.path.join(
+                    dump.name, f"*-{opts.ops}-post-finalize-llo.txt"))):
+                with open(path) as f:
+                    ops.append((name, loop_ops(f.read())))
+            for path in glob.glob(os.path.join(dump.name, "*")):
+                os.remove(path)
+        return result
+
     try:
         topo = topologies.get_topology_desc(TOPOLOGY, platform="tpu")
     except Exception as e:
@@ -68,16 +132,16 @@ def main():
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
     q = sds((B, S, H, D), bf16)
     kv = sds((B, S, 2, D), bf16)
-    results.append(check(
+    results.append(checked(
         "flash_attention(MHA causal)",
         lambda q, k, v: flash_attention(q, k, v, causal=True), q, q, q))
-    results.append(check(
+    results.append(checked(
         "flash_attention(GQA window)",
         lambda q, k, v: flash_attention(q, k, v, causal=True, window=256),
         q, kv, kv))
     # the training shape: backward (dq; dk+dv) at D=128, blocks 512/512
     q2 = sds((2, 2048, H, D), bf16)
-    results.append(check(
+    results.append(checked(
         "flash_attention(grad, S=2048 512/512)",
         jax.grad(lambda q, k, v: flash_attention(
             q, k, v, causal=True, block_q=512, block_k=512
@@ -85,7 +149,7 @@ def main():
 
     from deepspeed_tpu.ops.pallas.flash_bias import flash_attention_bias
     bias = sds((B, H, S, S), bf16)
-    results.append(check(
+    results.append(checked(
         "flash_bias(evoformer)",
         lambda q, k, v, b: flash_attention_bias(q, k, v, bias=b),
         q, q, q, bias))
@@ -94,17 +158,17 @@ def main():
                                                      fused_lamb_step,
                                                      fused_lion_step)
     p = sds((1 << 16, ), jnp.float32)
-    results.append(check(
+    results.append(checked(
         "fused_adam_step",
         lambda g, mst, m, v: fused_adam_step(
             g, mst, m, v, lr=1e-3, beta1=0.9, beta2=0.999, eps=1e-8,
             weight_decay=0.0, count=1), p, p, p, p))
-    results.append(check(
+    results.append(checked(
         "fused_lamb_step",
         lambda g, mst, m, v: fused_lamb_step(
             g, mst, m, v, lr=1e-3, beta1=0.9, beta2=0.999, eps=1e-8,
             weight_decay=0.01, count=1), p, p, p, p))
-    results.append(check(
+    results.append(checked(
         "fused_lion_step",
         lambda g, mst, m: fused_lion_step(g, mst, m, lr=1e-4, beta1=0.9,
                                           beta2=0.99, weight_decay=0.0),
@@ -117,7 +181,7 @@ def main():
         qv, scales, meta = quantize_blockwise(x, num_bits=8)
         return dequantize_blockwise(qv, scales, meta)
 
-    results.append(check("quantizer(int8 block)", qdq,
+    results.append(checked("quantizer(int8 block)", qdq,
                          sds((4096, 512), jnp.float32)))
 
     from deepspeed_tpu.ops.pallas.paged_attention import (
@@ -128,19 +192,23 @@ def main():
     kc = sds((41, 128, 32, D), bf16)
     bt = sds((T, maxb), jnp.int32)
     pos = sds((T, ), jnp.int32)
-    results.append(check("paged_attention_per_token",
+    results.append(checked("paged_attention_per_token",
                          paged_attention_per_token, pq, kc, kc, bt, pos))
-    # the run-tiled kernel: the serving cell's own shape
-    # (Mistral-7B, 768-token budget, 27-page table, window 4096), an MHA
-    # shape, and head sizes of the zoo that stay on the per-token kernel
+    # the run-tiled kernel with both branches of an item (the tile, one slab
+    # of rows): the two serving cells' own shapes (Mistral-7B: 768-token
+    # budget, 27-page table, window 4096; EvaByte: 32 / 32 heads, 1 MB pages)
+    # and their bursts' (a row a slot), a shape whose slab is 16 rows, and
+    # head sizes of the zoo that stay on the per-token kernel
     for name, T, heads, kv_heads, head_dim, maxb, window in (
             ("GQA 32/8, the cell", 768, 32, 8, 128, 27, 4096),
-            ("MHA 32/32", 768, 32, 32, 128, 16, 0),
+            ("GQA 32/8, the cell's burst", 65, 32, 8, 128, 27, 4096),
+            ("MHA 32/32, the EvaByte cell", 768, 32, 32, 128, 27, 0),
+            ("MHA 32/32, the EvaByte cell's burst", 17, 32, 32, 128, 27, 0),
             ("GQA 28/4, Qwen2", 256, 28, 4, 128, 16, 0),
             ("MHA 32/32 x 80: per token", 64, 32, 32, 80, 16, 0),
             ("MQA 71/1 x 64: per token", 64, 71, 1, 64, 16, 0)):
         kc = sds((64, 128, kv_heads, head_dim), bf16)
-        results.append(check(
+        results.append(checked(
             f"paged_attention({name})",
             lambda q, k, v, t, s, l, window=window: paged_attention(
                 q, k, v, t, s, l, window=window),
@@ -148,8 +216,17 @@ def main():
             sds((65, maxb), jnp.int32),
             sds((T, ), jnp.int32), sds((T, ), jnp.int32)))
 
+    # the variant the tests count page loads and short items with
+    kc = sds((64, 128, 8, D), bf16)
+    results.append(checked(
+        "paged_attention(GQA 32/8, count_loads)",
+        lambda q, k, v, t, s, l: paged_attention(q, k, v, t, s, l,
+                                                 count_loads=True),
+        sds((256, 32, D), bf16), kc, kc, sds((65, 27), jnp.int32),
+        sds((256, ), jnp.int32), sds((256, ), jnp.int32)))
+
     from deepspeed_tpu.ops.pallas.grouped_matmul import gmm
-    results.append(check(
+    results.append(checked(
         "gmm(moe grouped matmul)", lambda a, b, s: gmm(a, b, s),
         sds((512, 256), bf16), sds((4, 256, 128), bf16),
         sds((4, ), jnp.int32)))
@@ -161,7 +238,7 @@ def main():
     # the layout is static host data (it sizes the kernel's index tables)
     layout = np.asarray(FixedSparsityConfig(num_heads=H,
                                             block=blk).make_layout(S))
-    results.append(check(
+    results.append(checked(
         "block_sparse_flash_attention(fixed)",
         lambda q, k, v: block_sparse_flash_attention(q, k, v, layout, blk),
         q, q, q))
@@ -169,6 +246,10 @@ def main():
     print(f"target: {TOPOLOGY} ({kind}), compile only")
     for name, status, err in results:
         print(f"{status:4s} {name}" + (f"  {err}" if err else ""))
+    for name, regions in ops:
+        for region, counts in regions:
+            print(f"OPS {opts.ops} | {name} | {region} | "
+                  f"{sum(counts.values())} | {json.dumps(counts)}")
     return 0 if all(r[1] == "PASS" for r in results) else 1
 
 
