@@ -286,11 +286,10 @@ def run_cell(workload, seed, seconds, trace, *, root=loader.ROOT,
         # fixed path, so that a cell's second run finds every program
         from deepspeed_tpu.utils.compile_cache import enable_compile_cache
         enable_compile_cache()
-        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-            # the checkout's own cache is never evicted: a size cap from the
-            # environment (JAX_COMPILATION_CACHE_MAX_SIZE) under the cell's
-            # programs makes every run evict what the next one needs
-            jax.config.update("jax_compilation_cache_max_size", -1)
+        # the cache is never evicted, the checkout's own or one that is given:
+        # a size cap from the environment (JAX_COMPILATION_CACHE_MAX_SIZE)
+        # under the cell's programs makes every run evict what the next needs
+        jax.config.update("jax_compilation_cache_max_size", -1)
 
     devices = jax.devices()
     d0 = devices[0]

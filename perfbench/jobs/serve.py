@@ -97,8 +97,6 @@ def build_scheduler(ctx, model, params):
           "block_size": block,
           "num_blocks": int(eng["num_blocks"]),
           "max_ragged_batch_size": int(eng["token_budget"])}
-    if "prefill_atom_size" in eng:
-        sm["prefill_atom_size"] = int(eng["prefill_atom_size"])
     engine_config = {"dtype": "bfloat16", "state_manager": sm,
                      "decode_burst": int(eng["decode_burst"])}
     return build_serving_engine(
@@ -117,10 +115,10 @@ def stream(sched, requests):
 
 
 def warm_programs(ctx, sched, vocab):
-    """Hit every member of the program family: the atom-tiled prefill layout
-    (a prompt longer than the budget), the flat layout (a lone decode step
-    with one token left), a decode burst of each power of two up to the cap,
-    and a mixed prefill + decode step."""
+    """Hit every member of the program family: a ragged step that is all
+    prefill (a prompt longer than the budget), a lone decode step with one
+    token left, a decode burst of each power of two up to the cap, and a
+    mixed prefill + decode step."""
     rng = np.random.default_rng(0)
     eng = ctx.config["program"]["serve"]["engine"]
     cap, budget = int(eng["decode_burst"]), int(eng["token_budget"])
@@ -130,7 +128,7 @@ def warm_programs(ctx, sched, vocab):
     # step, then a burst of each power of two
     bursts = 1 + sum(1 << k for k in range(1, max(cap, 1).bit_length()))
     stream(sched, [(prompt(budget + budget // 6), bursts)])
-    stream(sched, [(prompt(40), 2)])                  # flat layout, k < 2
+    stream(sched, [(prompt(40), 2)])                  # lone decode, k < 2
     stream(sched, [(prompt(20), 8), (prompt(budget - 60), 3),
                    (prompt(5), 4)])
 

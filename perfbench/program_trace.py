@@ -257,12 +257,12 @@ def classify(event_name, meta, program, names):
         return "lm_head"
     if names.SCOPE_EMBED in parts:
         return "embed"
+    if names.SCOPE_KV_CACHE in parts:       # lies inside ds.attn since PR 28
+        return "kv_cache"
     if names.MODULE_ATTENTION in parts or names.SCOPE_ATTENTION in parts:
         return "attention"
     if names.MODULE_MLP in parts or names.SCOPE_MLP in parts:
         return "mlp"
-    if names.SCOPE_KV_CACHE in parts:
-        return "kv_cache"
     if names.SCOPE_NORM in parts or any(
             p == "norm" or p.endswith("layernorm") for p in parts):
         return "norm"
@@ -390,7 +390,8 @@ def reduce_planes(planes, names):
         serve["kinds"][kind] = serve["kinds"].get(kind, 0) + 1
         if kind == names.KIND_RAGGED:
             for key in ("token_budget", "live_tokens", "prefill_tokens",
-                        "decode_tokens", "grid_pages", "live_pages"):
+                        "decode_tokens", "grid_pages", "live_pages",
+                        "short_pages"):
                 sums[key] = sums.get(key, 0) + int(r[3].get(key, 0))
     serve["ragged_sums"] = sums
 

@@ -29,6 +29,17 @@ k-th and (k+1)-th expert exchanged at one layer for one token alone: the
 second of the two answers ``jobs/serve.py`` accepts for a position inside the
 configuration's margin.  ``router_logit_error`` is how that margin is measured
 (README.md).
+
+**One chip's share** (README.md, "One chip's share").  Where the sizes state
+``experts_held`` (and ``first_expert``, default 0), the router keeps its
+width E (the gate's own shape) and its experts per token, the stacks ``w1 /
+w3 / w2`` hold only the experts ``first_expert .. first_expert + experts_held
+- 1``, and the block adds those experts' part of the result and nothing in
+place of the others: the partial result goes on to the next layer.  A token
+whose k-th and (k+1)-th experts are BOTH held elsewhere gives this share the
+same experts either way, so its margin is reported as infinite: no second
+answer is asked for there.  With neither key every expert is held and nothing
+below differs from before.
 """
 
 import os
@@ -70,16 +81,30 @@ def attention_block(x, lp, cfg):
     return r(x + r(out @ a["o_proj"]["kernel"]))
 
 
-def route(router_logits, k, flip_token=-1, renormalise=True):
+def held_experts(cfg):
+    """``(first, count)`` of the experts this share holds, or None where the
+    sizes state no share (every expert is held)."""
+    if cfg.get("experts_held") is None:
+        return None
+    return int(cfg.get("first_expert", 0)), int(cfg["experts_held"])
+
+
+def route(router_logits, k, flip_token=-1, renormalise=True, held=None):
     """``(weights [S, E], margin [S])``: each token's weight on every expert
     (0 where it is not routed there) and its router margin, the k-th largest
-    router logit minus the (k+1)-th (inf where k == E).  The token at index
-    ``flip_token`` takes its (k+1)-th expert in place of its k-th."""
+    router logit minus the (k+1)-th (inf where k == E, and, under a share
+    ``held = (first, count)``, where both of those experts are held
+    elsewhere).  The token at index ``flip_token`` takes its (k+1)-th expert
+    in place of its k-th."""
     s, e = router_logits.shape
     probs = jax.nn.softmax(router_logits, axis=-1)
     top, idx = jax.lax.top_k(router_logits, min(k + 1, e))
     if k < e:
         margin = top[:, k - 1] - top[:, k]
+        if held is not None:
+            here = (idx[:, k - 1:] >= held[0]) & \
+                (idx[:, k - 1:] < held[0] + held[1])
+            margin = jnp.where(jnp.any(here, axis=1), margin, jnp.inf)
         last = jnp.where(jnp.arange(s) == flip_token, idx[:, k],
                          idx[:, k - 1])
         idx = jnp.concatenate([idx[:, :k - 1], last[:, None]], axis=1)
@@ -101,9 +126,13 @@ def moe_block(x, lp, cfg, flip_token=-1, weights=None):
     h = r(base.rms_norm(x, f32(lp["post_attention_layernorm"]["weight"]),
                         cfg["rms_norm_eps"]))
     router_logits = h @ f32(m["gate"]["kernel"])
+    held = held_experts(cfg)
     own, margin = route(router_logits, cfg["num_experts_per_tok"],
-                        flip_token, cfg.get("norm_topk_prob", True))
+                        flip_token, cfg.get("norm_topk_prob", True), held)
     weights = own if weights is None else weights
+    columns = weights.T                      # one row an expert of the router
+    if held is not None:                     # the stacks hold these alone
+        columns = columns[held[0]:held[0] + held[1]]
 
     def expert(acc, e):
         w1, w3, w2, col = e                  # one expert, upcast here
@@ -111,7 +140,7 @@ def moe_block(x, lp, cfg, flip_token=-1, weights=None):
         return acc + r(r(act @ f32(w2)) * col[:, None]), None
 
     out, _ = jax.lax.scan(expert, jnp.zeros_like(h),
-                          (m["w1"], m["w3"], m["w2"], weights.T))
+                          (m["w1"], m["w3"], m["w2"], columns))
     return r(x + r(out)), router_logits, margin, weights
 
 

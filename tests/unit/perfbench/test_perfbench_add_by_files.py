@@ -191,3 +191,61 @@ def test_a_configuration_without_its_measured_worst_fails_by_name(
         pb.run(root, cell, seed=12, trace=0)
     assert missing in str(err.value)
     assert "perfbench/configs/no_block.json" in str(err.value)
+
+
+def test_add_one_chips_share_of_a_deployment_as_a_file(tmp_path):
+    """A configuration that is ONE CHIP'S SHARE of a deployment (its experts
+    and its slice of the vocabulary cut beside depth, a ``share`` block) is
+    one new file and one appended entry: it passes the lint and every part
+    it names resolves.  It is not run: the program has no expert layer that
+    is told which experts it holds yet (the `model_config` PR brings it)."""
+    from perfbench import loader
+    from test_perfbench_manifest import lint_config
+    root = pb.tiny_root(tmp_path, [("t_serve", "tiny_mistral", "tiny_chat",
+                                    "serve")])
+    before = _digest(root)
+    bench = os.path.join(root, "perfbench")
+    config = json.load(open(os.path.join(bench, "configs",
+                                         "tiny_mixtral.json")))
+    # a deployment of 32 experts and 2048 vocabulary rows a layer over 4
+    # chips, depth 8; this file is chip 1's share, cut to depth 4
+    published = dict(config["published"], num_local_experts=32,
+                     vocab_size=2048, num_hidden_layers=8)
+    cut = ["num_hidden_layers", "num_local_experts", "vocab_size"]
+    config.update(
+        published=published, num_local_experts=8, vocab_size=256,
+        num_hidden_layers={"serve": 4}, reduced={k: "test" for k in cut},
+        share={"chips_sharing_a_layer": 4, "this_chip": 1,
+               "how": "32 routed experts and the vocabulary's rows divided "
+                      "evenly; attention and the router whole on every chip"})
+    json.dump(config, open(os.path.join(bench, "configs", "new_share.json"),
+                           "w"))
+    manifest = pb.read_manifest(root)
+    manifest["configs"].append(
+        {"name": "new_share", "source": "test",
+         "file": "perfbench/configs/new_share.json", "reduced": cut,
+         "why": "test"})
+    manifest["workloads"].append(
+        {"name": "new_share_cell", "config": "new_share",
+         "traffic": "tiny_chat", "chips": 1, "why": "test"})
+    pb.write_manifest(root, manifest)
+    after = _digest(root)
+    assert {k: v for k, v in after.items() if k in before} == before
+    assert len(after) == len(before) + 1
+
+    manifest = loader.load_manifest(root)
+    cell = loader.find(manifest["workloads"], "new_share_cell", "workload")
+    entry = loader.find(manifest["configs"], cell["config"], "config")
+    body = loader.load_json(os.path.join(root, entry["file"]))
+    assert lint_config(body, entry["reduced"]) == []
+    # one expert fewer a chip, a sixteenth of the vocabulary, depth 3: faults
+    for change in ({"num_local_experts": 7}, {"vocab_size": 128},
+                   {"num_hidden_layers": {"serve": 3}}):
+        assert lint_config(dict(body, **change), entry["reduced"]), change
+    arch = loader.load_part(root, "models", body["arch"])
+    assert loader.load_part(root, "reference", body["arch"]).moe_block
+    sizes = arch.reference_sizes(body, "serve")
+    # the traffic draws its ids from the slice; depth is the job's
+    assert sizes["vocab_size"] == 256 and sizes["num_hidden_layers"] == 4
+    assert os.path.isfile(loader.part_path(root, "traffic", cell["traffic"],
+                                           "json"))

@@ -42,22 +42,39 @@ def test_top_level_keys_and_sizes(manifest):
 
 #: a key of a configuration that ``reduced`` may never name: a width (hidden,
 #: intermediate, latent, state or projection size, a head size or count, an
-#: expansion factor, the experts a token uses or a router spans, a window, the
-#: vocabulary).  Depth is the only cut.
+#: expansion factor, the experts a token uses, a router spans or every chip
+#: holds alike, a window, the vocabulary).  The cuts are depth and, for a
+#: configuration that states itself ONE CHIP'S SHARE of a deployment (a
+#: ``share`` block), the keys of ``SHARE_KEY``.
 WIDTH_KEY = re.compile(r"(_dim|_rank|hidden_size|intermediate_size"
                        r"|experts_per_tok|vocab_size)$"
                        r"|^(num_local_experts|num_experts|n_routed_experts"
-                       r"|n_shared_experts|d_model|d_ff)$|head|window")
+                       r"|n_shared_experts|num_shared_experts|d_model|d_ff)$"
+                       r"|head|window")
+
+#: of those, a key that counts what ONE CHIP HOLDS of a layer divided over
+#: several: the routed experts (the published count stays the router's width)
+#: and the rows of the vocabulary.  Widths like any other with no ``share``
+#: block; with one, ``reduced`` may name them, under the floors of the
+#: model-configs guide's section 4 (``share_faults``).
+EXPERT_KEYS = ("num_experts", "num_local_experts", "n_routed_experts")
+SHARE_KEY = re.compile(r"^(%s|vocab_size)$" % "|".join(EXPERT_KEYS))
+SHARE_FIELDS = {"chips_sharing_a_layer", "this_chip", "how"}
+MIN_EXPERTS_HELD = 8          # routed experts held in a layer that has them
+MAX_VOCAB_CUT = 8             # the slice is at least an eighth
+MIN_LAYERS_PAST_DENSE = 4     # and at least one whole period of layer kinds
 
 
 WIDTHS = ("hidden_size", "intermediate_size", "moe_intermediate_size",
           "num_attention_heads", "num_key_value_heads", "num_heads",
           "head_dim", "qk_rope_head_dim", "v_head_dim", "kv_lora_rank",
           "q_lora_rank", "num_experts_per_tok", "num_local_experts",
-          "num_experts", "n_routed_experts", "n_shared_experts", "d_model",
-          "d_ff", "sliding_window", "vocab_size")
+          "num_experts", "n_routed_experts", "n_shared_experts",
+          "num_shared_experts", "d_model", "d_ff", "sliding_window",
+          "vocab_size")
 CUTS = ("num_hidden_layers", "max_position_embeddings", "rope_theta",
         "rms_norm_eps", "first_k_dense_replace")
+SHARES = EXPERT_KEYS + ("vocab_size",)
 
 
 @pytest.mark.parametrize("key", WIDTHS + CUTS)
@@ -65,11 +82,86 @@ def test_width_key_names_every_width_and_no_depth(key):
     assert bool(WIDTH_KEY.search(key)) == (key in WIDTHS)
 
 
+@pytest.mark.parametrize("key", WIDTHS + CUTS)
+def test_share_key_names_what_a_chip_holds_and_no_other_width(key):
+    """Every share key is a width (so it is one with no ``share`` block), and
+    no head count, experts per token, shared expert, window or size is one."""
+    assert bool(SHARE_KEY.search(key)) == (key in SHARES)
+    assert key not in SHARES or WIDTH_KEY.search(key)
+
+
+def _whole(n):
+    return isinstance(n, int) and not isinstance(n, bool)
+
+
+def period_of(types):
+    """The least ``p`` with ``types[i] == types[i + p]`` throughout."""
+    return next(p for p in range(1, len(types) + 1)
+                if all(a == b for a, b in zip(types, types[p:])))
+
+
+def share_faults(body, reduced, published):
+    """The faults of a configuration that states a ``share`` block: the block
+    itself, then each share key in ``reduced`` and the depth against the
+    floors of the model-configs guide's section 4."""
+    share, faults = body["share"], []
+    if not isinstance(share, dict) or not set(share) <= SHARE_FIELDS:
+        return [f"`share` has other keys than {sorted(SHARE_FIELDS)}"]
+    n = share.get("chips_sharing_a_layer")
+    if not _whole(n) or n < 2:
+        return ["`share.chips_sharing_a_layer` is not a whole number of at "
+                "least 2"]
+    chip = share.get("this_chip", 0)
+    if not _whole(chip) or not 0 <= chip < n:
+        faults.append(f"`share.this_chip` is not one of 0..{n - 1}")
+    if not isinstance(share.get("how"), str) or not _line(share["how"]):
+        faults.append("`share.how` is not one line of what is divided and "
+                      "what every chip computes alike")
+    cut = [k for k in reduced if SHARE_KEY.match(k)]
+    if not cut:
+        faults.append("a `share` block and no share key in `reduced`")
+    for key in cut:
+        held, whole = body.get(key), published.get(key)
+        if not _whole(held) or not _whole(whole):
+            faults.append(f"share key {key!r} is not a whole number, run "
+                          "and published")
+        elif key == "vocab_size":
+            if held < 1 or whole % held:
+                faults.append(f"{key!r}: {held} rows held does not divide "
+                              f"the published {whole}")
+            elif whole // held > MAX_VOCAB_CUT:
+                faults.append(f"{key!r}: {held} rows held of {whole} is "
+                              f"under 1/{MAX_VOCAB_CUT} of the vocabulary")
+        elif held * n != whole:
+            faults.append(f"{key!r}: {held} held on each of {n} chips is "
+                          f"not the published {whole}")
+        elif held < MIN_EXPERTS_HELD:
+            faults.append(f"{key!r}: {held} experts held, under the floor "
+                          f"of {MIN_EXPERTS_HELD}")
+    dense = published.get("first_k_dense_replace") or 0
+    floors = {"the leading dense layers + 4": dense + MIN_LAYERS_PAST_DENSE}
+    following = (published.get("layer_types") or [])[dense:]
+    if following:
+        floors["the leading dense layers + one period of `layer_types`"] = \
+            dense + period_of(following)
+    depths = body.get("num_hidden_layers")
+    for job, depth in (depths.items() if isinstance(depths, dict)
+                       else [("every job", depths)]):
+        for what, floor in floors.items():
+            if not _whole(depth) or depth < floor:
+                faults.append(f"depth {depth!r} ({job}) is under {what} = "
+                              f"{floor}")
+    return faults
+
+
 def lint_config(body, reduced):
     """The faults of one configuration file against its OWN statement of what
     was published: every key of ``published`` is run at the published value
-    unless ``reduced`` names it, and ``reduced`` names no width.  Returns the
-    faults as strings (none = "published widths, never cut" holds)."""
+    unless ``reduced`` names it, and ``reduced`` names no width: only depth
+    and, where the file states a ``share`` block (one chip's share of a
+    deployment), the experts held and the vocabulary's slice, under the
+    guide's floors.  Returns the faults as strings (none = "published widths,
+    never cut" holds)."""
     faults = []
     if set(body.get("reduced", reduced)) != set(reduced):
         faults.append("the file's `reduced` is not the manifest's")
@@ -77,13 +169,17 @@ def lint_config(body, reduced):
                  if not k.startswith("_")}
     if not published:
         faults.append("no `published` block")
+    shared = "share" in body
+    if shared:
+        faults += share_faults(body, reduced, published)
     for key in reduced:
         if not NAME.match(key) or key not in body:
             faults.append(f"reduced key {key!r} is not a key of the file")
         if key not in published:
             faults.append(f"reduced key {key!r} is not in `published`")
-        if WIDTH_KEY.search(key):
-            faults.append(f"reduced names the width {key!r}")
+        if WIDTH_KEY.search(key) and not (shared and SHARE_KEY.match(key)):
+            faults.append(f"reduced names the width {key!r}" + (
+                " with no `share` block" if SHARE_KEY.match(key) else ""))
     for key, value in published.items():
         if key in reduced:
             if body.get(key) == value:
@@ -188,6 +284,134 @@ def test_published_widths_are_held_to_the_files_own_statement(
         assert any(fault in f for f in faults), faults
 
 
+#: command-a-plus-05-2026 as the model-configs guide's catalog has it
+#: (https://huggingface.co/CohereLabs/command-a-plus-05-2026/blob/main/config.json):
+#: 128 routed experts of width 4096, 8 a token, four shared; three sliding
+#: layers to one full.  No file under perfbench/configs/: the `model_config`
+#: PR that adds the architecture brings it.
+COMMAND_A_PLUS = {
+    "attention_bias": False, "expert_selection_fn": "sigmoid",
+    "first_k_dense_replace": 0, "head_dim": 128, "hidden_act": "silu",
+    "hidden_size": 4096, "intermediate_size": 4096, "layer_norm_eps": 1e-05,
+    "layer_switch": 4,
+    "layer_types": (["sliding_attention"] * 3 + ["full_attention"]) * 8,
+    "logit_scale": 1, "max_position_embeddings": 200000,
+    "model_type": "cohere2_moe", "norm_topk_prob": True,
+    "num_attention_heads": 128, "num_experts": 128, "num_experts_per_tok": 8,
+    "num_hidden_layers": 32, "num_key_value_heads": 8,
+    "num_shared_experts": 4,
+    "order_of_interleaved_layers": "local_attn_first",
+    "position_embedding_type": "rope_gptj",
+    "prefix_dense_intermediate_size": 16384,
+    "prefix_dense_sliding_window_pattern": 1, "rms_norm_eps": None,
+    "rope_parameters": {"rope_theta": 50000, "rope_type": "default"},
+    "rope_theta": 50000, "rotary_pct": 1,
+    "shared_expert_combination_strategy": "average", "sliding_window": 4096,
+    "tf_legacy_loss": False, "tie_word_embeddings": True,
+    "use_embedding_sharing": True, "use_gated_activation": True,
+    "use_parallel_block": True, "use_parallel_embedding": False,
+    "use_qk_norm": False, "vocab_size": 262144}
+
+SHARE_CUT = ["num_hidden_layers", "num_experts", "vocab_size"]
+
+
+def _listed(*widths):
+    """``reduced`` of a share file that lists ``widths`` beside its cuts."""
+    return {k: "" for k in SHARE_CUT + list(widths)}
+
+
+def _share(published, chips, **changes):
+    """``published`` as ``chips`` chips' share: an eighth of the vocabulary,
+    its experts divided evenly, depth 8 to serve, unless ``changes`` say."""
+    body = dict(published, published=dict(published), arch="test",
+                num_hidden_layers={"serve": 8},
+                num_experts=published["num_experts"] // chips,
+                vocab_size=published["vocab_size"] // 8,
+                share={"chips_sharing_a_layer": chips,
+                       "how": "routed experts and vocabulary rows divided "
+                              "evenly; attention, router and shared experts "
+                              "whole on every chip"},
+                reduced=_listed())
+    for key, value in changes.items():
+        if key.startswith("share."):
+            body["share"] = dict(body["share"], **{key[6:]: value})
+        elif key == "share" and value is None:
+            del body["share"]                 # the same file with no block
+        else:
+            body[key] = value
+    return body
+
+
+@pytest.mark.parametrize("body,fault", [
+    pytest.param(_share(OLMOE, 8), None, id="olmoe_share_of_8"),
+    pytest.param(_share(OLMOE, 8, **{"share.this_chip": 7}), None,
+                 id="olmoe_last_of_8"),
+    pytest.param(_share(COMMAND_A_PLUS, 8, num_hidden_layers={"serve": 4}),
+                 None, id="command_a_plus_share_of_8"),
+    pytest.param(_share(COMMAND_A_PLUS, 32, num_hidden_layers={"serve": 4}),
+                 "4 experts held, under the floor of 8",
+                 id="command_a_plus_4_experts"),
+    pytest.param(_share(COMMAND_A_PLUS, 8, num_hidden_layers={"serve": 4},
+                        vocab_size=262144 // 16), "under 1/8",
+                 id="command_a_plus_sixteenth_of_vocabulary"),
+    pytest.param(_share(COMMAND_A_PLUS, 8, num_hidden_layers={"serve": 3}),
+                 "one period of `layer_types` = 4",
+                 id="command_a_plus_depth_3"),
+    pytest.param(_share(OLMOE, 16), "4 experts held, under the floor of 8",
+                 id="experts_under_8"),
+    pytest.param(_share(OLMOE, 8, num_experts=16),
+                 "16 held on each of 8 chips is not the published 64",
+                 id="experts_not_an_nth"),
+    pytest.param(_share(OLMOE, 8, vocab_size=50304 // 16), "under 1/8",
+                 id="vocabulary_under_an_eighth"),
+    pytest.param(_share(OLMOE, 8, vocab_size=6000), "does not divide",
+                 id="vocabulary_not_a_divisor"),
+    pytest.param(_share(dict(OLMOE, first_k_dense_replace=1), 8,
+                        num_hidden_layers={"serve": 4}),
+                 "the leading dense layers + 4 = 5",
+                 id="depth_under_4_past_dense"),
+    pytest.param(_share(OLMOE, 8, num_hidden_layers={"serve": 8, "train": 3}),
+                 "depth 3 (train)", id="one_jobs_depth_under_4"),
+    pytest.param(_share(OLMOE, 8, num_experts=64, vocab_size=50304,
+                        reduced={"num_hidden_layers": ""}),
+                 "no share key in `reduced`", id="share_with_nothing_cut"),
+    pytest.param(_share(OLMOE, 8, num_experts=64, share=None,
+                        reduced={"num_hidden_layers": "", "vocab_size": ""}),
+                 "names the width 'vocab_size' with no `share` block",
+                 id="share_key_without_a_share"),
+    pytest.param(_share(OLMOE, 8, num_attention_heads=8,
+                        reduced=_listed("num_attention_heads")),
+                 "names the width 'num_attention_heads'",
+                 id="heads_listed_beside_a_share"),
+    pytest.param(_share(OLMOE, 8, num_experts_per_tok=2,
+                        reduced=_listed("num_experts_per_tok")),
+                 "names the width 'num_experts_per_tok'",
+                 id="experts_per_token_listed_beside_a_share"),
+    pytest.param(_share(OLMOE, 8, num_experts_per_tok=2),
+                 "'num_experts_per_tok': run 2, published 8",
+                 id="experts_per_token_cut_beside_a_share"),
+    pytest.param(_share(OLMOE, 1, num_experts=8), "at least 2",
+                 id="one_chip_is_no_share"),
+    pytest.param(_share(OLMOE, 8, **{"share.this_chip": 8}), "0..7",
+                 id="this_chip_out_of_range"),
+    pytest.param(_share(OLMOE, 8, **{"share.how": ""}), "`share.how`",
+                 id="share_without_how"),
+    pytest.param(_share(OLMOE, 8,
+                        **{"share.stands_in_for": "the absent chips"}),
+                 "other keys", id="share_with_a_stand_in"),
+])
+def test_one_chips_share_is_held_to_the_guides_floors(body, fault):
+    """A configuration that states a ``share`` block may cut the experts held
+    and the vocabulary's slice beside depth; everything else stays a width,
+    and every floor of the model-configs guide's section 4 is a fault by
+    name.  The manifest's ``reduced`` is the file's own."""
+    faults = lint_config(body, list(body["reduced"]))
+    if fault is None:
+        assert faults == []
+    else:
+        assert any(fault in f for f in faults), faults
+
+
 def test_workloads_resolve_by_name(manifest):
     from perfbench import loader
     cells = manifest["workloads"]
@@ -279,7 +503,7 @@ def test_saturated_serving_cells_are_judged_on_throughput_only(manifest):
 def test_engine_layout_is_the_configurations_not_the_traffics(manifest):
     from perfbench import loader
     knobs = {"block_size", "token_budget", "decode_burst", "num_blocks",
-             "prefill_atom_size", "max_concurrent"}
+             "max_concurrent"}
     traffic_dir = os.path.join(pb.ROOT, "perfbench", "traffic")
     for f in os.listdir(traffic_dir):
         assert not knobs & set(loader.load_json(os.path.join(traffic_dir, f)))
@@ -287,7 +511,7 @@ def test_engine_layout_is_the_configurations_not_the_traffics(manifest):
         serve = loader.load_json(os.path.join(pb.ROOT, c["file"]))[
             "program"].get("serve")
         if serve:
-            assert knobs - {"prefill_atom_size"} <= set(serve["engine"])
+            assert knobs == set(serve["engine"])
 
 
 def test_files_under_paths_are_named_from_name_characters(manifest):
