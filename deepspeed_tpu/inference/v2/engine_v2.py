@@ -31,6 +31,14 @@ from .ragged import (BlockedKVCache, DSStateManager, KVCacheExhausted,
 from .ragged_forward import RAGGED_FORWARDS
 
 
+@jax.jit
+def _tokens_and_counts(logits, counts):
+    """A greedy step's tokens with the device's counts behind them: one
+    array, one transfer."""
+    return jnp.concatenate([jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                            counts])
+
+
 class InferenceEngineV2:
 
     def __init__(self, model, params=None, config=None):
@@ -50,6 +58,12 @@ class InferenceEngineV2:
                 f"no ragged forward registered for {name} "
                 f"(have: {list(RAGGED_FORWARDS)})")
         self._step_fn = RAGGED_FORWARDS[name]
+        # what this model's step counts on the device (its third output),
+        # and the counts of the steps since the last fetch: they stay on the
+        # device until tokens that a request waits for carry them back
+        self._device_counts = getattr(self._step_fn, "step_counts", ())
+        self._no_counts = np.zeros(len(self._device_counts), np.int32)
+        self._counts_owed = self._no_counts
         if params is None:
             raise ValueError("InferenceEngineV2 needs params")
         self.params = jax.tree_util.tree_map(jnp.asarray, params)
@@ -312,15 +326,11 @@ class InferenceEngineV2:
         last_idx = np.zeros(sm.max_seqs, dtype=np.int32)
         for seq, idx in finishing:
             last_idx[seq.slot] = idx
-        grid_pages, live_pages, row_pages, short_pages = self._page_counts(
-            pos, slots)
         self.last_step_counts = {
             "kind": _names.KIND_RAGGED, "token_budget": T,
             "live_tokens": placed, "decode_tokens": placed_decode,
             "prefill_tokens": placed - placed_decode,
-            "grid_pages": grid_pages, "live_pages": live_pages,
-            "row_pages": row_pages, "short_pages": short_pages,
-            "burst_k": 0}
+            **self._page_counts(pos, slots), "burst_k": 0}
         return toks, pos, slots, last_idx, finishing
 
     def _table_snapshot(self):
@@ -354,19 +364,21 @@ class InferenceEngineV2:
             windows_closed=0 if ends is None else int(
                 (ends % kv.window_size == 0).sum()))
 
-    def _kernel_loads(self, pos, slots, row_pages=None):
+    def _kernel_loads(self, pos, slots, row_pages=None, window=None):
         """``paged_attention.kernel_page_loads`` of one layer's call over
         the rows at positions ``pos`` (inside the block-table row) in slots
-        ``slots``, for this engine's shapes."""
+        ``slots``, for this engine's shapes and the layer's ``window``
+        (default: the model's one ``sliding_window``)."""
         cfg = self.model_config
+        if window is None:
+            window = int(getattr(cfg, "sliding_window", 0) or 0)
         return kernel_page_loads(
             slots, pos, heads=cfg.num_attention_heads,
             kv_heads=cfg.num_key_value_heads, head_dim=cfg.head_dim,
             kv_dtype=self.kv_cache.dtype,
             block_size=self.kv_cache.block_size,
             maxb=self.state_manager.block_table.shape[1],
-            window=int(getattr(cfg, "sliding_window", 0) or 0),
-            row_pages=row_pages)
+            window=window, row_pages=row_pages)
 
     def _summary_pages(self, pos, slots):
         """Of ``grid_pages``, the loads of summary blocks: every run (every
@@ -387,25 +399,46 @@ class InferenceEngineV2:
         return window_row_positions(pos, kv.window_size, kv.chunk_size)
 
     def _page_counts(self, pos, slots):
-        """``(grid_pages, live_pages, row_pages, short_pages)`` of one
-        paged-attention call over the rows at positions ``pos`` in slots
-        ``slots`` (0: a dead row); ``[k, rows]`` arrays are the ``k`` calls
-        of a burst.  ``grid_pages``, ``live_pages``, ``short_pages``: the K/V
-        page loads the kernel's loops perform and, of those, the loads that
-        hold a key some live row may see, and the loads whose item computes
-        one slab of rows and not its tile
+        """The page counts of a step's paged-attention calls over the rows at
+        positions ``pos`` in slots ``slots`` (0: a dead row); ``[k, rows]``
+        arrays are the ``k`` calls of a burst.  For a model whose layers read
+        alike, of ONE layer's call: ``grid_pages``, ``live_pages``,
+        ``short_pages``: the K/V page loads the kernel's loops perform and, of
+        those, the loads that hold a key some live row may see, and the loads
+        whose item computes one slab of rows and not its tile
         (``paged_attention.kernel_page_loads``, beside the kernels it
-        describes).  ``row_pages``: the (row, page) pairs the live rows'
+        describes); ``row_pages``: the (row, page) pairs the live rows'
         contexts (their sliding windows) span — ``row_pages / grid_pages``
-        is how many rows share one page load."""
+        is how many rows share one page load.  For a model that states a
+        window a layer (``layer_windows``) the four are summed over ALL its
+        layers' calls, and ``grid_pages_window`` / ``grid_pages_full`` are
+        the loads of its window layers' and of its full layers' calls."""
+        windows = getattr(self.model_config, "layer_windows", None)
+        if windows is None:
+            return self._kind_page_counts(pos, slots, int(getattr(
+                self.model_config, "sliding_window", 0) or 0))
+        total = dict.fromkeys(("grid_pages", "live_pages", "row_pages",
+                               "short_pages", "grid_pages_window",
+                               "grid_pages_full"), 0)
+        for window in sorted(set(windows)):
+            layers = windows.count(window)
+            kind = self._kind_page_counts(pos, slots, window)
+            for name, pages in kind.items():
+                total[name] += layers * pages
+            total["grid_pages_window" if window else "grid_pages_full"] += \
+                layers * kind["grid_pages"]
+        return total
+
+    def _kind_page_counts(self, pos, slots, window):
+        """``_page_counts`` of one call of a layer with this ``window``."""
         bs = self.kv_cache.block_size
-        window = int(getattr(self.model_config, "sliding_window", 0) or 0)
         pos, slots = self._row_positions(np.atleast_2d(pos)), \
             np.atleast_2d(slots)
-        grid, live, _, short = self._kernel_loads(pos, slots)
+        grid, live, _, short = self._kernel_loads(pos, slots, window=window)
         first = np.maximum(pos - window + 1, 0) // bs if window else 0
         pages = np.where(slots != 0, pos // bs + 1 - first, 0)
-        return grid, live, int(pages.sum()), short
+        return {"grid_pages": grid, "live_pages": live,
+                "row_pages": int(pages.sum()), "short_pages": short}
 
     @staticmethod
     def _sample_row(row, temperature, top_k, top_p, rng):
@@ -468,9 +501,11 @@ class InferenceEngineV2:
                 # armed — docs/observability.md "MFU & HBM")
                 cost_model.capture_jit_call(
                     "serve/ragged_step", self._step_fn, step_args, step_kw)
-            logits, self._kv = self._step_fn(*step_args, **step_kw)
+            logits, self._kv, *counted = self._step_fn(*step_args, **step_kw)
         self._count_cache(pos, slots)
         out = {}
+        if counted:                # no wait: an addition queued on the device
+            self._counts_owed = self._counts_owed + counted[0]
         if finishing:
             # the fetch is the one place the host waits for the device
             if do_sample:
@@ -478,6 +513,9 @@ class InferenceEngineV2:
                 slots_f = jnp.asarray([seq.slot for seq, _ in finishing])
                 with _telemetry.scope(_names.SERVE_FETCH):
                     lg = np.asarray(logits[slots_f])
+                    if counted:
+                        self._book_device_counts(np.asarray(
+                            self._counts_owed))
                 for i, (seq, _) in enumerate(finishing):
                     out[seq.uid] = self._sample_row(
                         lg[i], temperature, top_k, top_p, self._rng)
@@ -486,10 +524,25 @@ class InferenceEngineV2:
                 # of [max_seqs, V] logits (the per-step device→host tax on
                 # a decode loop)
                 with _telemetry.scope(_names.SERVE_FETCH):
-                    toks = np.asarray(jnp.argmax(logits, axis=-1))
+                    if counted:     # the counts ride back with the tokens
+                        toks = self._book_device_counts(np.asarray(
+                            _tokens_and_counts(logits, self._counts_owed)))
+                    else:
+                        toks = np.asarray(jnp.argmax(logits, axis=-1))
                 for seq, _ in finishing:
                     out[seq.uid] = int(toks[seq.slot])
         return out
+
+    def _book_device_counts(self, fetched):
+        """Put the device's counts (the step program's ``step_counts``: this
+        step's and those of the steps before it that fetched nothing), the
+        last entries of ``fetched``, among ``last_step_counts``; returns
+        what stands before them (the step's tokens, if any)."""
+        cut = len(fetched) - len(self._device_counts)
+        self.last_step_counts.update(
+            zip(self._device_counts, map(int, fetched[cut:])))
+        self._counts_owed = self._no_counts
+        return fetched[:cut]
 
     # ---------------------------------------------------------- decode burst
     def _decode_burst_step(self, active_uids, produced, max_new_tokens,
@@ -593,15 +646,11 @@ class InferenceEngineV2:
             slots_k = np.broadcast_to(np.where(act, np.arange(n), 0), (k, n))
             for seq in seqs:        # as the cache stands when the burst ends
                 seq.seen_tokens += k
-            grid_pages, live_pages, row_pages, short_pages = \
-                self._page_counts(pos_k, slots_k)
             self.last_step_counts = {
                 "kind": _names.KIND_BURST, "token_budget": n * k,
                 "live_tokens": len(seqs) * k,
                 "decode_tokens": len(seqs) * k, "prefill_tokens": 0,
-                "grid_pages": grid_pages, "live_pages": live_pages,
-                "row_pages": row_pages, "short_pages": short_pages,
-                "burst_k": k}
+                **self._page_counts(pos_k, slots_k), "burst_k": k}
         from .ragged_forward import decode_burst
         if sample:
             if getattr(self, "_burst_key", None) is None or \
@@ -621,6 +670,8 @@ class InferenceEngineV2:
                             key=key, temperature=float(temperature),
                             top_k=int(top_k), top_p=float(top_p),
                             kv_dtype=self._kv_dtype)
+            if self._device_counts:
+                burst_kw["counts0"] = self._counts_owed
             from ...profiling import cost_model
             if cost_model.capturing():
                 # k is static (pow2-quantized above), so the burst variants
@@ -632,6 +683,8 @@ class InferenceEngineV2:
         self._count_cache(pos_k, slots_k)
         with _telemetry.scope(_names.SERVE_FETCH):
             toks_out = np.asarray(toks_out)  # ONE fetch for k×seqs tokens
+            if self._device_counts:          # and the counts behind them
+                toks_out = self._book_device_counts(toks_out).reshape(k, n)
         self.burst_steps = getattr(self, "burst_steps", 0) + 1
         out = {}
         for seq in seqs:

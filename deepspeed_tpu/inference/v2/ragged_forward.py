@@ -39,11 +39,19 @@ def _program(name, **jit_kwargs):
     return wrap
 
 
-def _ragged_program(arch):
-    return _program(_names.PROGRAM_RAGGED_STEP + arch,
-                    static_argnames=("cfg", "block_size", "use_kernel",
-                                     "kv_dtype"),
-                    donate_argnums=(1, ))
+def _ragged_program(arch, step_counts=()):
+    """The ragged step of ``arch``.  ``step_counts``: the names of what the
+    step counts ON THE DEVICE, the int32 vector it returns third (each a sum:
+    two steps' values add); the engine fetches it with the tokens a request
+    waits for and puts it among the ``ds:serve.step`` counts."""
+    def wrap(fn):
+        program = _program(_names.PROGRAM_RAGGED_STEP + arch,
+                           static_argnames=("cfg", "block_size", "use_kernel",
+                                            "kv_dtype"),
+                           donate_argnums=(1, ))(fn)
+        program.step_counts = tuple(step_counts)
+        return program
+    return wrap
 
 
 def _rotary(x, cos, sin, positions):
@@ -179,7 +187,8 @@ def _ragged_attention_block(lp_attn, h, kv_layer, blk, off, block_tables,
                             seq_slots, positions, cos, sin, *, cfg, block_size,
                             rotary=True, rotary_dim=None,
                             use_kernel=True, kv_dtype=None,
-                            row_positions=None, after_scatter=None):
+                            row_positions=None, after_scatter=None,
+                            window=None):
     """Shared attention sub-block: qkv → rotary → cache scatter → paged
     attention → output projection.  Returns (attn_out [T, D], new kv_layer).
     ``row_positions`` (default: ``positions``) are the positions inside the
@@ -195,13 +204,18 @@ def _ragged_attention_block(lp_attn, h, kv_layer, blk, off, block_tables,
     v_scales)`` (narrow pages, scales [num_blocks, bs, Hkv] f32): K/V rows
     are encoded once on the scatter write and dequantized on read inside
     the paged attention (``kv_codec.py``).  ``rotary_dim`` < head_dim →
-    partial rotary (phi family)."""
+    partial rotary (phi family); ``rotary`` may be the layer's own turn ``x
+    [T, heads, Dh] -> x`` (interleaved pairs: ``cohere2_moe_ragged_step``).
+    ``window`` is the LAYER's sliding window (0: none; default: the model's
+    one ``cfg.sliding_window``)."""
     dtype = jnp.dtype(cfg.dtype)
     H, Dh = cfg.num_attention_heads, cfg.head_dim
     q = _qkv(h, lp_attn["q_proj"], dtype)
     k = _qkv(h, lp_attn["k_proj"], dtype)
     v = _qkv(h, lp_attn["v_proj"], dtype)
-    if rotary:
+    if callable(rotary):
+        q, k = rotary(q), rotary(k)
+    elif rotary:
         if rotary_dim and rotary_dim < Dh:
             rot = lambda x: jnp.concatenate(
                 [_rotary(x[..., :rotary_dim], cos, sin, positions),
@@ -218,7 +232,8 @@ def _ragged_attention_block(lp_attn, h, kv_layer, blk, off, block_tables,
     out = _paged_attention(q, k_cache, v_cache, block_tables, seq_slots,
                            positions if row_positions is None
                            else row_positions, block_size,
-                           window=getattr(cfg, "sliding_window", 0),
+                           window=getattr(cfg, "sliding_window", 0)
+                           if window is None else window,
                            use_kernel=use_kernel, kv_scales=kv_scales)
     o = out.reshape(out.shape[0], H * Dh)
     o = jnp.einsum("tf,fd->td", o, lp_attn["o_proj"]["kernel"].astype(dtype))
@@ -310,6 +325,7 @@ def mixtral_ragged_step(params, kv_data, token_ids, positions, seq_slots,
 
     dtype = jnp.dtype(cfg.dtype)
     eps = cfg.rms_norm_eps
+    live = seq_slots != 0          # a dead row of the buffer is not routed
     cos, sin = _rope_freqs(cfg.head_dim, cfg.max_position_embeddings,
                            cfg.rope_theta, cfg.rope_scaling)
     cos = jnp.asarray(cos, jnp.float32)
@@ -338,7 +354,7 @@ def mixtral_ragged_step(params, kv_data, token_ids, positions, seq_slots,
                 h2, router_logits, moe["w1"].astype(dtype),
                 moe["w2"].astype(dtype), moe["w3"].astype(dtype),
                 cfg.num_experts_per_tok,
-                norm_topk=getattr(cfg, "norm_topk_prob", True))
+                norm_topk=getattr(cfg, "norm_topk_prob", True), live=live)
             if "shared_gate_proj" in moe:  # qwen2-moe dense shared expert
                 g = h2 @ moe["shared_gate_proj"]["kernel"].astype(dtype)
                 u = h2 @ moe["shared_up_proj"]["kernel"].astype(dtype)
@@ -590,12 +606,89 @@ def evabyte_ragged_step(params, kv_data, token_ids, positions, seq_slots,
     return heads[:, :cfg.vocab_size], tuple(kv_data)
 
 
+@_ragged_program("cohere2_moe", step_counts=(_names.COUNT_EXPERT_COPIES,
+                                              _names.COUNT_EXPERT_ACTIVE))
+def cohere2_moe_ragged_step(params, kv_data, token_ids, positions, seq_slots,
+                            block_tables, last_token_idx, *, cfg, block_size,
+                            use_kernel=True, kv_dtype=None):
+    """One ragged engine iteration for Cohere2-MoE (``models/cohere2_moe.py``
+    has the layer's equations): ONE LayerNorm feeds the attention and the
+    expert block side by side; a sliding layer turns q and k by rotary on
+    interleaved pairs and reads its window, a full layer has no positions and
+    reads everything (``cfg.layer_windows``: the window is the layer's).  The
+    expert block routes the LIVE rows over the router's full width and
+    computes the held experts' part (``moe/held_experts.py``) beside the
+    averaged shared experts.  Every layer keeps every token in ONE block
+    table: a window layer's pages past its window are held and not read.
+
+    The held experts' grouped matmuls are the Pallas ``ds_grouped_matmul``
+    where the step's kernels are on (``use_kernel`` and the gate of every
+    kernel dispatch site): docs/kernels.md has the v5e readings at this
+    step's shapes that put it ahead of ``lax.ragged_dot`` there.
+
+    Returns ``(logits, new kv_data, counts)``; ``counts`` (int32, the
+    program's ``step_counts``): the (row, expert) copies that landed on a
+    held expert and the held experts with at least one, summed over the
+    layers."""
+    from ...models.cohere2_moe import layer_norm, moe_layer, rotary_pairs
+    from ...ops._use_kernels import use_pallas_kernels
+
+    dtype = jnp.dtype(cfg.dtype)
+    eps = cfg.layer_norm_eps
+    live = seq_slots != 0
+    gmm_kernel = use_kernel and use_pallas_kernels()
+    turn = lambda x: rotary_pairs(x, positions, cfg.rope_theta)
+
+    with jax.named_scope(_names.SCOPE_EMBED):
+        x = params["embed_tokens"]["weight"][token_ids].astype(dtype)
+    blk = block_tables[seq_slots, positions // block_size]
+    off = positions % block_size
+
+    kv_data = list(kv_data)
+    counts = []
+    for l, window in enumerate(cfg.layer_windows):
+        lp = params[f"layers_{l}"]
+        with jax.named_scope(_names.SCOPE_NORM):
+            h = layer_norm(x, lp["input_layernorm"]["weight"], eps)
+        attn_out, kv_data[l] = _ragged_attention_block(
+            lp["self_attn"], h, kv_data[l], blk, off, block_tables,
+            seq_slots, positions, None, None, cfg=cfg, block_size=block_size,
+            rotary=turn if window else False, window=window,
+            use_kernel=use_kernel, kv_dtype=kv_dtype)
+        with jax.named_scope(_names.SCOPE_MLP):
+            moe = lp["moe"]
+            stack = lambda name: moe[name].astype(dtype)
+            with jax.named_scope(_names.SCOPE_MOE_ROUTER):
+                router_logits = (h.astype(jnp.float32)
+                                 @ moe["gate"]["kernel"].astype(jnp.float32))
+            moe_out, landed = moe_layer(
+                h, router_logits, stack("w1"), stack("w2"), stack("w3"),
+                stack("shared_w1"), stack("shared_w2"), stack("shared_w3"),
+                cfg, live=live, kernel=gmm_kernel)
+        counts.append(landed)
+        x = x + attn_out + moe_out
+
+    with jax.named_scope(_names.SCOPE_LM_HEAD):
+        # only each slot's last token reaches the head; the tied embedding is
+        # read in the type it is held in, the products summed in float32
+        xl = layer_norm(x[last_token_idx], params["norm"]["weight"], eps)
+        logits = jnp.einsum("td,vd->tv", xl,
+                            params["embed_tokens"]["weight"].astype(dtype),
+                            preferred_element_type=jnp.float32)
+        if cfg.logit_scale != 1:
+            logits = logits * cfg.logit_scale
+    counts = jnp.stack(counts)                        # [layers, held]
+    return logits, tuple(kv_data), jnp.stack(
+        [jnp.sum(counts), jnp.sum(counts > 0)])
+
+
 RAGGED_FORWARDS = {"LlamaModel": llama_ragged_step,
                    "MixtralModel": mixtral_ragged_step,
                    "FalconModel": falcon_ragged_step,
                    "OPTModel": opt_ragged_step,
                    "PhiModel": phi_ragged_step,
-                   "EvaByteModel": evabyte_ragged_step}
+                   "EvaByteModel": evabyte_ragged_step,
+                   "Cohere2MoeModel": cohere2_moe_ragged_step}
 
 
 def _device_sample(logits, key, temperature, top_k, top_p):
@@ -626,7 +719,7 @@ def _device_sample(logits, key, temperature, top_k, top_p):
 def decode_burst(params, kv_data, tok0, pos0, active, block_tables, *,
                  step_fn, cfg, block_size, k, use_kernel=True,
                  sample=False, key=None, temperature=1.0, top_k=0,
-                 top_p=1.0, kv_dtype=None):
+                 top_p=1.0, kv_dtype=None, counts0=None):
     """``k`` greedy decode iterations in ONE compiled program.
 
     The per-step serving loop pays a host round-trip per generated token
@@ -659,7 +752,11 @@ def decode_burst(params, kv_data, tok0, pos0, active, block_tables, *,
     ``kv_data`` (the per-layer K and V buffers, donated) is the scan's
     carry: every buffer stays in place through the ``while``.
 
-    Returns ([k, max_seqs] int32 tokens (one per iteration), new kv).
+    Returns ([k, max_seqs] int32 tokens (one per iteration), new kv).  For a
+    step that counts on the device (its ``step_counts``) the tokens come back
+    flat with ``counts0`` (what earlier steps counted and no fetch has
+    carried yet) plus the iterations' counts behind them: one array, one
+    transfer.
     """
     n = tok0.shape[0]
     rows = jnp.arange(n, dtype=jnp.int32)
@@ -669,19 +766,26 @@ def decode_burst(params, kv_data, tok0, pos0, active, block_tables, *,
         key = jax.random.PRNGKey(0)
 
     def body(carry, _):
-        kv, toks, pos, key = carry
-        logits, kv = inner(params, kv, jnp.where(active, toks, 0),
-                           jnp.where(active, pos, 0), slots, block_tables,
-                           rows, cfg=cfg, block_size=block_size,
-                           use_kernel=use_kernel, kv_dtype=kv_dtype)
+        kv, toks, pos, key, *counts = carry
+        logits, kv, *counted = inner(
+            params, kv, jnp.where(active, toks, 0),
+            jnp.where(active, pos, 0), slots, block_tables, rows, cfg=cfg,
+            block_size=block_size, use_kernel=use_kernel, kv_dtype=kv_dtype)
+        if counted:
+            counts = [counts[0] + counted[0]]
         if sample:
             key, sub = jax.random.split(key)
             nxt = _device_sample(logits, sub, temperature, top_k, top_p)
         else:
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return (kv, nxt, pos + 1, key), nxt
+        return (kv, nxt, pos + 1, key, *counts), nxt
 
-    (kv_data, _, _, _), toks_out = jax.lax.scan(
+    if counts0 is None:
+        counts0 = jnp.zeros(len(getattr(step_fn, "step_counts", ())))
+    counts0 = (counts0.astype(jnp.int32), ) if counts0.size else ()
+    (kv_data, _, _, _, *counts), toks_out = jax.lax.scan(
         body, (kv_data, tok0.astype(jnp.int32), pos0.astype(jnp.int32),
-               key), None, length=k)
+               key, *counts0), None, length=k)
+    if counts:
+        toks_out = jnp.concatenate([toks_out.reshape(-1), counts[0]])
     return toks_out, kv_data

@@ -11,7 +11,9 @@ Covers the reference's Mixtral support (FastGen impl
   ``P("ep", ...)`` spec and the grouped matmul maps onto the MXU;
 * the expert compute is ``jax.lax.ragged_dot`` over tokens sorted by expert
   (megablocks-style, no token dropping — exact Mixtral semantics), which XLA
-  lowers to the TPU grouped-matmul path;
+  lowers to the TPU grouped-matmul path (``moe/held_experts.py``, the layer
+  Cohere2-MoE shares; docs/kernels.md has the chip's readings against the
+  Pallas ``ds_grouped_matmul``);
 * training adds the standard load-balance aux loss
   (``router_aux_loss_coef``, reference ``sharded_moe.py`` aux-loss algebra).
 
@@ -26,6 +28,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 from jax.sharding import PartitionSpec as P
 
+from ..moe.held_experts import held_experts_apply, route
 from .llama import LlamaAttention, LlamaConfig, RMSNorm
 
 
@@ -50,53 +53,14 @@ def mixtral_tiny(**overrides):
                             **overrides})
 
 
-def moe_expert_ffn(x_sorted, group_sizes, w1, w2, w3):
-    """Grouped SwiGLU over tokens sorted by expert.
-
-    x_sorted: [Tk, D] (token copies ordered so expert e's tokens are
-    contiguous); group_sizes: [E]; w1/w3: [E, D, I]; w2: [E, I, D].
-    Returns [Tk, D].  ``ragged_dot`` is XLA's grouped matmul — each expert's
-    contiguous token block hits the MXU with that expert's weights.
-    """
-    import os
-    if os.environ.get("DS_TPU_MOE_GMM") == "1":
-        # opt-in Pallas grouped GEMM (ops/pallas/grouped_matmul.py) — the
-        # hand-schedulable alternative to XLA's ragged_dot for on-chip A/B
-        try:
-            from ..ops.pallas.grouped_matmul import gmm
-            gs = group_sizes.astype(jnp.int32)
-            gate = gmm(x_sorted, w1, gs)
-            up = gmm(x_sorted, w3, gs)
-            return gmm(nn.silu(gate) * up, w2, gs)
-        except ValueError:
-            pass   # dims not tile-divisible → XLA path below
-    gate = jax.lax.ragged_dot(x_sorted, w1, group_sizes)
-    up = jax.lax.ragged_dot(x_sorted, w3, group_sizes)
-    return jax.lax.ragged_dot(nn.silu(gate) * up, w2, group_sizes)
-
-
-def moe_apply(x, router_logits, w1, w2, w3, k, norm_topk=True):
+def moe_apply(x, router_logits, w1, w2, w3, k, norm_topk=True, live=None):
     """Exact (no-drop) top-k MoE: route, sort token-copies by expert, grouped
-    matmul, weighted scatter-add back.  x: [T, D] → [T, D].
+    matmul, weighted scatter-add back (``moe/held_experts.py``, every expert
+    held).  x: [T, D] → [T, D]; ``live [T]``: the rows that are routed at
+    all (None: every row).
     """
-    T, D = x.shape
-    E = w1.shape[0]
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    topw, topi = jax.lax.top_k(probs, k)              # [T, k]
-    if norm_topk:
-        topw = topw / jnp.sum(topw, axis=-1, keepdims=True)
-
-    flat_expert = topi.reshape(-1)                    # [T*k]
-    order = jnp.argsort(flat_expert)                  # stable
-    token_of = order // k                             # source token per copy
-    group_sizes = jnp.bincount(flat_expert, length=E)
-
-    x_sorted = x[token_of]                            # [T*k, D]
-    y_sorted = moe_expert_ffn(x_sorted, group_sizes, w1, w2, w3)
-    w_sorted = topw.reshape(-1)[order].astype(y_sorted.dtype)
-    out = jnp.zeros((T, D), dtype=y_sorted.dtype)
-    out = out.at[token_of].add(y_sorted * w_sorted[:, None])
-    return out.astype(x.dtype)
+    topi, topw = route(router_logits, k, "softmax", norm_topk)
+    return held_experts_apply(x, topi, topw, w1, w2, w3, live=live)[0]
 
 
 def load_balance_aux_loss(router_logits, k):

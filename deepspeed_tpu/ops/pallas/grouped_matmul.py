@@ -17,11 +17,14 @@ Design (megablocks-style, guided by the group-padding trick):
   kernel);
 * grid (m, n, k) with k innermost accumulating into an f32 VMEM scratch.
 
-XLA's native ``lax.ragged_dot`` serves the same role (and is the default —
-``moe_expert_ffn`` keeps it unless ``DS_TPU_MOE_GMM=1``); this kernel exists
-so the MoE path has a hand-schedulable alternative to A/B on real hardware
-(``tools/kernel_bench`` pattern), exactly how the reference ships a CUTLASS
-grouped GEMM next to cuBLAS.
+XLA's native ``lax.ragged_dot`` serves the same role, and is what the expert
+layer (``moe/held_experts.py`` ``grouped_matmul``) runs unless its caller asks
+for this kernel.  Timed against it on a v5e at a serving step's shapes
+(``tools/moe_gmm_bench.py``; docs/kernels.md has the readings), the kernel
+with tiles of 256 x 1024 x 1024 is ahead in buffers of up to 2560 rows, and
+``cohere2_moe_ragged_step`` asks for it there; it is behind in a buffer of
+16 384, and it has NO gradient (no ``custom_vjp``): a forward that may be
+differentiated keeps ``ragged_dot``.
 """
 
 import functools
@@ -34,28 +37,39 @@ from jax.experimental.pallas import tpu as pltpu
 from ._common import interpret_mode as _interpret
 
 
-def _gmm_kernel(expert_ref, x_ref, w_ref, y_ref, acc_ref, *, nk):
-    @pl.when(pl.program_id(2) == 0)
-    def _zero():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+def _gmm_kernel(expert_ref, live_ref, x_ref, w_ref, y_ref, acc_ref, *, nk):
+    """Grid ``(row tile m, column tile n, k)``.  A row tile past the live
+    ones (``live_ref[0]``: the tiles that hold a row of some group) computes
+    nothing, and its index maps stand still (:func:`gmm`), so it moves
+    nothing either: the call's time follows the rows that are there, not the
+    static bound of the buffer."""
+    m, k = pl.program_id(0), pl.program_id(2)
 
-    acc_ref[...] += jnp.dot(x_ref[...], w_ref[0],
-                            preferred_element_type=jnp.float32)
+    @pl.when(m < live_ref[0])
+    def _live():
+        @pl.when(k == 0)
+        def _zero():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(pl.program_id(2) == nk - 1)
-    def _flush():
-        y_ref[...] = acc_ref[...].astype(y_ref.dtype)
+        acc_ref[...] += jnp.dot(x_ref[...], w_ref[0],
+                                preferred_element_type=jnp.float32)
+
+        @pl.when(k == nk - 1)
+        def _flush():
+            y_ref[...] = acc_ref[...].astype(y_ref.dtype)
 
 
 def _pad_layout(group_sizes, T, E, block_m):
     """Vectorized group-padding layout.
 
-    Returns (dest_idx [T], expert_of_tile [Tp_max//block_m], Tp_max) where
-    row i of the sorted input lands at padded row dest_idx[i], and tile t of
-    the padded buffer belongs to expert expert_of_tile[t].  Tp_max is the
-    STATIC bound T_pad = ceil(T/bm)*bm + E*bm (shapes stay static under
-    jit; tiles past the live data compute into padding rows that the final
-    gather drops)."""
+    Returns (dest_idx [T], expert_of_tile [Tp_max//block_m], live tiles
+    [1], Tp_max) where row i of the sorted input lands at padded row
+    dest_idx[i], and tile t of the padded buffer belongs to expert
+    expert_of_tile[t].  Tp_max is the STATIC bound T_pad = ceil(T/bm)*bm +
+    E*bm (shapes stay static under jit; the kernel skips the tiles past the
+    live ones, and the final gather drops their rows).  Rows of the input
+    past ``sum(group_sizes)`` belong to no group: they land past the live
+    tiles and come back as whatever the buffer held."""
     sizes = group_sizes.astype(jnp.int32)
     starts = jnp.concatenate([jnp.zeros((1, ), jnp.int32),
                               jnp.cumsum(sizes)[:-1]])
@@ -65,14 +79,18 @@ def _pad_layout(group_sizes, T, E, block_m):
     rows = jnp.arange(T, dtype=jnp.int32)
     g_of_row = jnp.searchsorted(jnp.cumsum(sizes), rows, side="right"
                                 ).astype(jnp.int32)
-    dest = pstarts[g_of_row] + (rows - starts[g_of_row])
+    g_of_row = jnp.minimum(g_of_row, E - 1)
+    live_rows = jnp.sum(padded)
+    dest = jnp.where(rows < jnp.sum(sizes),
+                     pstarts[g_of_row] + (rows - starts[g_of_row]),
+                     live_rows + rows - jnp.sum(sizes))
     tp_max = ((T + block_m - 1) // block_m) * block_m + E * block_m
     tiles = jnp.arange(tp_max // block_m, dtype=jnp.int32)
     pends_tiles = jnp.cumsum(padded) // block_m        # [E]
     expert_of_tile = jnp.minimum(
         jnp.searchsorted(pends_tiles, tiles, side="right"),
         E - 1).astype(jnp.int32)
-    return dest, expert_of_tile, tp_max
+    return dest, expert_of_tile, (live_rows // block_m).reshape(1), tp_max
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",
@@ -82,7 +100,9 @@ def gmm(x, w, group_sizes, *, block_m=128, block_n=128, block_k=128,
     """Grouped matmul: ``y[i] = x[i] @ w[g(i)]``.
 
     x: [T, K] with rows SORTED by group (group g's rows contiguous);
-    w: [E, K, N]; group_sizes: [E] summing to T.  Returns [T, N].
+    w: [E, K, N]; group_sizes: [E] summing to at most T.  Returns [T, N];
+    a row past ``sum(group_sizes)`` is in no group and its result is
+    undefined (mask it, do not multiply it by 0).
     """
     T, K = x.shape
     E, Kw, N = w.shape
@@ -92,24 +112,40 @@ def gmm(x, w, group_sizes, *, block_m=128, block_n=128, block_k=128,
     if K % block_k or N % block_n:
         raise ValueError(f"K={K} / N={N} must divide block_k/{block_k} "
                          f"block_n/{block_n}")
-    dest, expert_of_tile, tp = _pad_layout(group_sizes, T, E, block_m)
+    dest, expert_of_tile, live, tp = _pad_layout(group_sizes, T, E, block_m)
     xp = jnp.zeros((tp, K), x.dtype).at[dest].set(x)
 
-    nk = K // block_k
-    grid = (tp // block_m, N // block_n, nk)
+    nk, nn = K // block_k, N // block_n
+    grid = (tp // block_m, nn, nk)
+
+    def at(m, n, k, live):
+        """The step's tile indices; a step past the live row tiles keeps the
+        last live step's, so that nothing is fetched or written for it."""
+        on = m < live[0]
+        last = jnp.maximum(live[0] - 1, 0)
+        return (jnp.where(on, m, last), jnp.where(on, n, nn - 1),
+                jnp.where(on, k, nk - 1))
+
+    def x_map(m, n, k, e, live):
+        m, _, k = at(m, n, k, live)
+        return m, k
+
+    def w_map(m, n, k, e, live):
+        m, n, k = at(m, n, k, live)
+        return e[m], k, n
+
+    def y_map(m, n, k, e, live):
+        m, n, _ = at(m, n, k, live)
+        return m, n
+
     yp = pl.pallas_call(
         functools.partial(_gmm_kernel, nk=nk),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=grid,
-            in_specs=[
-                pl.BlockSpec((block_m, block_k),
-                             lambda m, n, k, e: (m, k)),
-                pl.BlockSpec((1, block_k, block_n),
-                             lambda m, n, k, e: (e[m], k, n)),
-            ],
-            out_specs=pl.BlockSpec((block_m, block_n),
-                                   lambda m, n, k, e: (m, n)),
+            in_specs=[pl.BlockSpec((block_m, block_k), x_map),
+                      pl.BlockSpec((1, block_k, block_n), w_map)],
+            out_specs=pl.BlockSpec((block_m, block_n), y_map),
             scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((tp, N), x.dtype),
@@ -117,5 +153,5 @@ def gmm(x, w, group_sizes, *, block_m=128, block_n=128, block_k=128,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="ds_grouped_matmul",
-    )(expert_of_tile, xp, w)
+    )(expert_of_tile, live, xp, w)
     return yp[dest]

@@ -210,35 +210,6 @@ def bias_attention_timing(B=2, N=8, L=512, H=4, D=32, iters=10):
     return results
 
 
-def gmm_timing(T=4096, D=1024, I=3584, E=8, iters=10, dtype=jnp.bfloat16):
-    """Pallas grouped GEMM vs XLA ragged_dot on the MoE expert-FFN shape
-    (the A/B that decides DS_TPU_MOE_GMM on real hardware)."""
-    import numpy as np
-    from ..ops.pallas.grouped_matmul import gmm
-    r = np.random.default_rng(0)
-    sizes = np.full(E, T // E, np.int32)
-    x = jnp.asarray(r.standard_normal((T, D)), dtype)
-    w = jnp.asarray(r.standard_normal((E, D, I)) * 0.05, dtype)
-    gs = jnp.asarray(sizes)
-
-    def timeit(f):
-        y = f(x, w, gs)
-        jax.block_until_ready(y)
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            y = f(x, w, gs)
-        jax.block_until_ready(y)
-        return (time.perf_counter() - t0) / iters
-
-    t_ragged = timeit(jax.jit(jax.lax.ragged_dot))
-    t_gmm = timeit(jax.jit(lambda x, w, g: gmm(x, w, g)))
-    return {"ragged_dot_ms": round(t_ragged * 1e3, 3),
-            "pallas_gmm_ms": round(t_gmm * 1e3, 3),
-            "speedup": round(t_ragged / t_gmm, 3),
-            "shape": f"T={T} D={D} I={I} E={E} {jnp.dtype(dtype).name}",
-            "backend": jax.default_backend()}
-
-
 def main():
     import argparse
     p = argparse.ArgumentParser()
@@ -248,8 +219,6 @@ def main():
     p.add_argument("--cpu", action="store_true")
     p.add_argument("--bias-attn", action="store_true",
                    help="also run the evoformer bias-kernel A/B")
-    p.add_argument("--gmm", action="store_true",
-                   help="also run the MoE grouped-GEMM A/B")
     args = p.parse_args()
     if args.cpu:
         jax.config.update("jax_platforms", "cpu")
@@ -261,9 +230,6 @@ def main():
         bt = bias_attention_timing()
         print(json.dumps({"metric": "evoformer_bias_attention_timing",
                           **bt}))
-    if args.gmm:
-        gt = gmm_timing()
-        print(json.dumps({"metric": "moe_grouped_gemm_timing", **gt}))
 
 
 if __name__ == "__main__":

@@ -63,6 +63,15 @@ SERVE_STEP_COUNTS = ("step", "kind", "running", "queued", "token_budget",
                      "context_tokens", "held_blocks", "block_size",
                      "summary_pages",
                      "chunks_closed", "windows_closed")
+#: further counts that only some models' steps carry: ``grid_pages_window``
+#: and ``grid_pages_full`` of a model whose layers read differently (a window
+#: on some, none on others), and what a model with an expert layer counted ON
+#: THE DEVICE, fetched with the tokens a request waits for (a step that fetches
+#: nothing leaves its counts to the next that does: they add)
+COUNT_EXPERT_COPIES = "expert_copies"         # (row, expert) pairs on a held
+#                                               expert, summed over layers
+COUNT_EXPERT_ACTIVE = "expert_active"         # held experts with a copy,
+#                                               summed over layers
 
 # ---- jitted programs (``XLA Modules`` events are ``jit_<name>(<id>)``)
 PROGRAM_MICRO = "ds_micro_"               # + the micro-step variant
@@ -79,6 +88,11 @@ SCOPE_LM_HEAD_LOSS = "ds.lm_head_loss"    # training: head and loss, both paths
 SCOPE_LM_HEAD = "ds.lm_head"              # serving: final norm, last-token logits
 SCOPE_ATTENTION = "ds.attn"               # serving: qkv, rotary, cache, paged, o
 SCOPE_MLP = "ds.mlp"                      # serving: the MLP or expert block
+SCOPE_MOE_ROUTER = "ds.moe_router"        # inside ds.mlp: router, top-k
+SCOPE_MOE_EXPERTS = "ds.moe_experts"      # inside ds.mlp: gather, grouped
+#                                           matmuls and weighted scatter-add
+#                                           of the held experts
+SCOPE_MOE_SHARED = "ds.moe_shared"        # inside ds.mlp: the shared experts
 SCOPE_NORM = "ds.norm"                    # serving: rms / layer norms
 SCOPE_KV_CACHE = "ds.kv_cache"            # serving, inside ds.attn: everything a
 #                                           step spends to put its K/V into the

@@ -199,9 +199,10 @@ def test_build_batch_decodes_first_and_the_prompt_takes_the_rest():
 
 
 def test_a_configuration_that_pins_the_removed_atom_key_still_builds():
-    """``perfbench/configs/*.json`` pass ``"prefill_atom_size": 0`` in
-    ``state_manager`` (a pin around a path that is gone): the key lands
-    where every unknown key lands and selects nothing."""
+    """A deployment's file may still pass ``"prefill_atom_size": 0`` in
+    ``state_manager`` (a pin around a path that went with PR 29; the
+    benchmark's own configurations dropped it in PR 32): the key lands where
+    every unknown key lands and selects nothing."""
     model, cfg, params = _model()
     sm = dict(max_ragged_batch_size=16, block_size=8, max_context=64,
               num_blocks=64, max_ragged_sequence_count=8,

@@ -21,7 +21,8 @@ import jax.numpy as jnp
 from deepspeed_tpu.inference.v2 import InferenceEngineV2
 from deepspeed_tpu.inference.v2 import ragged_forward as rf
 from deepspeed_tpu.inference.v2.ragged import BlockedKVCache
-from deepspeed_tpu.models import evabyte, falcon, llama, mixtral, opt, phi
+from deepspeed_tpu.models import (cohere2_moe, evabyte, falcon, llama,
+                                  mixtral, opt, phi)
 
 _spec = importlib.util.spec_from_file_location(
     "serve_hlo_check", os.path.join(
@@ -44,6 +45,8 @@ ZOO = {
         dtype="float32", remat=False)),
     "EvaByteModel": (evabyte.EvaByteModel, lambda: evabyte.evabyte_tiny(
         dtype="float32")),
+    "Cohere2MoeModel": (cohere2_moe.Cohere2MoeModel,
+                        cohere2_moe.cohere2_moe_tiny),
 }
 
 
@@ -157,8 +160,8 @@ def _stacked_storage(engine):
         arrays = kv if isinstance(kv, tuple) else (kv, )
         layers = tuple(tuple(a[l, i] for a in arrays for i in (0, 1))
                        for l in range(arrays[0].shape[0]))
-        logits, layers = inner(params, layers, *args, **kw)
-        return logits, _stack(layers)
+        logits, layers, *counted = inner(params, layers, *args, **kw)
+        return (logits, _stack(layers), *counted)
 
     engine._kv = _stack(engine._kv)
     engine._step_fn = jax.jit(step, static_argnames=STATICS,
@@ -169,9 +172,9 @@ def _record_logits(engine):
     inner, sink = engine._step_fn, []
 
     def step(*args, **kw):
-        logits, kv = inner(*args, **kw)
+        logits, *rest = inner(*args, **kw)   # kv and, of some, their counts
         sink.append(np.asarray(logits))
-        return logits, kv
+        return (logits, *rest)
 
     step.__wrapped__ = inner.__wrapped__
     engine._step_fn = step

@@ -215,13 +215,21 @@ def test_preemption_is_an_event_with_its_uid(tiny, tmp_path):
     assert len(t.named(names.SERVE_ADMITTED)) == 8 + sched.preemptions
 
 
+def _page_counts(engine, pos, slots):
+    """``(grid, live, row, short)`` pages of one layer's call."""
+    counts = engine._page_counts(pos, slots)
+    assert list(counts) == ["grid_pages", "live_pages", "row_pages",
+                            "short_pages"]
+    return tuple(counts.values())
+
+
 def test_page_counts_follow_the_kernel_and_the_window(tiny):
     engine = _scheduler(tiny).engine
     pos = np.array([0, 7, 8, 30, 0, 0, 0, 0], np.int32)
     slots = np.array([1, 2, 3, 4, 0, 0, 0, 0], np.int32)
     # heads of 16: the per-token kernel.  8 rows x 8 pages; contexts span
     # 1, 1, 2 and 4 pages
-    assert engine._page_counts(pos, slots) == (64, 8, 8, 0)
+    assert _page_counts(engine, pos, slots) == (64, 8, 8, 0)
     # the same rows with heads of 128, through the function the engine asks:
     # the run-tiled kernel loads each decode row's live pages, and nothing
     # for a dead row; a decode row's two query rows lie in one slab of 8, so
@@ -230,7 +238,7 @@ def test_page_counts_follow_the_kernel_and_the_window(tiny):
         slots, pos, heads=4, kv_heads=2, head_dim=128, kv_dtype=jnp.float32,
         block_size=8, maxb=8) == (8, 8, 0, 8)
     # a burst: k rows of positions
-    assert engine._page_counts(pos[None, :4] + np.arange(2)[:, None],
+    assert _page_counts(engine, pos[None, :4] + np.arange(2)[:, None],
                                np.broadcast_to(slots[:4], (2, 4))) == (
         64, 1 + 2 + 2 + 4 + 8, 1 + 2 + 2 + 4 + 8, 0)
     # heads of 128: the run-tiled kernel loads a run's pages once, and only
@@ -241,21 +249,21 @@ def test_page_counts_follow_the_kernel_and_the_window(tiny):
         num_attention_heads=4, num_key_value_heads=2, head_dim=128)
     pos = np.array([6, 7, 8, 30, 0, 0, 0, 0], np.int32)
     slots = np.array([1, 1, 1, 4, 0, 0, 0, 0], np.int32)
-    assert engine._page_counts(pos, slots) == (2 + 4, 2 + 4, 1 + 1 + 2 + 4,
+    assert _page_counts(engine, pos, slots) == (2 + 4, 2 + 4, 1 + 1 + 2 + 4,
                                                2 + 4)
     # a window of 8 keeps position 30's pages 2-3 and position 8's 0-1
     engine.model_config.sliding_window = 8
-    assert engine._page_counts(pos, slots) == (2 + 2, 2 + 2, 1 + 1 + 2 + 2,
+    assert _page_counts(engine, pos, slots) == (2 + 2, 2 + 2, 1 + 1 + 2 + 2,
                                                2 + 2)
     # a fourth token of the run (eight query rows, then two): the decode
     # row's slab is the next one, the run still fills its own
     pos = np.array([6, 7, 8, 9, 30, 0, 0, 0], np.int32)
     slots = np.array([1, 1, 1, 1, 4, 0, 0, 0], np.int32)
-    assert engine._page_counts(pos, slots)[3] == 2 + 2
+    assert _page_counts(engine, pos, slots)[3] == 2 + 2
     # a fifth: ten rows straddle two slabs, its items compute the tile
     pos = np.array([6, 7, 8, 9, 10, 30, 0, 0], np.int32)
     slots = np.array([1, 1, 1, 1, 1, 4, 0, 0], np.int32)
-    assert engine._page_counts(pos, slots)[3] == 2
+    assert _page_counts(engine, pos, slots)[3] == 2
 
 
 # ----------------------------------------------------------------- training

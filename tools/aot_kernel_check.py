@@ -205,6 +205,14 @@ def main():
             ("MHA 32/32, the EvaByte cell", 768, 32, 32, 128, 27, 0),
             ("MHA 32/32, the EvaByte cell's burst", 17, 32, 32, 128, 27, 0),
             ("GQA 28/4, Qwen2", 256, 28, 4, 128, 16, 0),
+            # 16 query heads a KV head (tiles of 512 rows, slabs of 16): the
+            # Command A+ cell's window and full layers, and its burst
+            ("GQA 128/8, the Command A+ cell, window", 2048, 128, 8, 128,
+             137, 4096),
+            ("GQA 128/8, the Command A+ cell, full", 2048, 128, 8, 128, 137,
+             0),
+            ("GQA 128/8, the Command A+ cell's burst", 33, 128, 8, 128, 137,
+             0),
             ("MHA 32/32 x 80: per token", 64, 32, 32, 80, 16, 0),
             ("MQA 71/1 x 64: per token", 64, 71, 1, 64, 16, 0)):
         kc = sds((64, 128, kv_heads, head_dim), bf16)
@@ -230,6 +238,14 @@ def main():
         "gmm(moe grouped matmul)", lambda a, b, s: gmm(a, b, s),
         sds((512, 256), bf16), sds((4, 256, 128), bf16),
         sds((4, ), jnp.int32)))
+    # the serving path's expert layer: 16 held experts of 4096 x 4096, the
+    # short buffer of a 2048-row step, tiles of 256 x 1024 x 1024
+    results.append(checked(
+        "gmm(16 experts of 4096 x 4096, tiles 256 x 1024 x 1024)",
+        lambda a, b, s: gmm(a, b, s, block_m=256, block_n=1024,
+                            block_k=1024),
+        sds((2560, 4096), bf16), sds((16, 4096, 4096), bf16),
+        sds((16, ), jnp.int32)))
 
     from deepspeed_tpu.ops.pallas.block_sparse_attention import (
         block_sparse_flash_attention)

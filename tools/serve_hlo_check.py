@@ -40,7 +40,8 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 DEFAULT_CONFIGS = ("perfbench/configs/mistral7b_1chip.json",
-                   "perfbench/configs/evabyte_1chip.json")
+                   "perfbench/configs/evabyte_1chip.json",
+                   "perfbench/configs/command_a_plus_1chip.json")
 
 # name = shape opcode(...: a tuple shape has spaces, no " word(" inside it
 _INSTR = re.compile(r"^\s*(?:ROOT )?(%?[\w.\-]+) = (.+?) ([a-z][\w\-]*)\(")
@@ -151,6 +152,9 @@ def programs(config, sharding=None):
     i32 = lambda *shape: sds(shape, jnp.int32)
     step_fn = rf.RAGGED_FORWARDS[type(model).__name__]
     kw = dict(cfg=cfg, block_size=bs)
+    # a step that counts on the device hands the burst the counts it owes
+    counts = getattr(step_fn, "step_counts", ())
+    burst_kw = dict(counts0=i32(len(counts))) if counts else {}
     n_params = len(jax.tree.leaves(params))
     n_cache = len(jax.tree.leaves(cache))
     page = cache[0][0]
@@ -159,7 +163,8 @@ def programs(config, sharding=None):
                          i32(seqs, maxb), i32(seqs), **kw)
     burst = rf.decode_burst.lower(
         params, cache, i32(seqs), i32(seqs), sds((seqs, ), jnp.bool_),
-        i32(seqs, maxb), step_fn=step_fn, k=int(eng["decode_burst"]), **kw)
+        i32(seqs, maxb), step_fn=step_fn, k=int(eng["decode_burst"]), **kw,
+        **burst_kw)
     return {step_fn.__name__: (step, n_params, n_cache, page_bytes),
             rf.decode_burst.__name__: (burst, n_params, n_cache, page_bytes)}
 
