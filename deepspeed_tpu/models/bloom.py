@@ -21,6 +21,8 @@ import jax.numpy as jnp
 import flax.linen as nn
 from jax.sharding import PartitionSpec as P
 
+from ..runtime.activation_checkpointing import resolve_policy
+
 
 @dataclass(frozen=True)
 class BloomConfig:
@@ -117,7 +119,7 @@ class BloomModel(nn.Module):
                          name="word_embeddings_layernorm")(x)
         block = BloomBlock
         if cfg.remat and not decode:
-            policy = getattr(jax.checkpoint_policies, cfg.remat_policy, None)
+            policy = resolve_policy(cfg.remat_policy)
             block = nn.remat(BloomBlock, policy=policy, static_argnums=(2, ))
         for i in range(cfg.num_hidden_layers):
             x = block(cfg, name=f"h_{i}")(x, decode)

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 from jax.sharding import PartitionSpec as P
 
+from ..runtime.activation_checkpointing import resolve_policy
 from ..telemetry import names as _names
 from .llama import LlamaMLP, _rope_freqs, apply_rotary
 
@@ -233,8 +234,8 @@ class EvaByteModel(nn.Module):
                          name="embed_tokens")(input_ids).astype(jnp.float32)
         block = EvaByteBlock
         if cfg.remat:
-            block = nn.remat(EvaByteBlock, policy=getattr(
-                jax.checkpoint_policies, cfg.remat_policy, None))
+            block = nn.remat(EvaByteBlock, policy=resolve_policy(
+                cfg.remat_policy))
         for i in range(cfg.num_hidden_layers):
             x = block(cfg, name=f"layers_{i}")(x)
         x = OffsetRMSNorm(cfg.rms_norm_eps, dtype, cfg.norm_add_unit_offset,

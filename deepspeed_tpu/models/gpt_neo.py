@@ -19,6 +19,8 @@ import jax.numpy as jnp
 import flax.linen as nn
 from jax.sharding import PartitionSpec as P
 
+from ..runtime.activation_checkpointing import resolve_policy
+
 
 @dataclass(frozen=True)
 class GPTNeoConfig:
@@ -113,7 +115,7 @@ class GPTNeoModel(nn.Module):
 
         block = GPTNeoBlock
         if cfg.remat and not decode:
-            policy = getattr(jax.checkpoint_policies, cfg.remat_policy, None)
+            policy = resolve_policy(cfg.remat_policy)
             block = nn.remat(GPTNeoBlock, policy=policy, static_argnums=(2, ))
         at = cfg.attention_layers
         for i in range(cfg.num_hidden_layers):

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 from jax.sharding import PartitionSpec as P
 
+from ..runtime.activation_checkpointing import resolve_policy
 from .llama import _rope_freqs, apply_rotary
 
 
@@ -144,7 +145,7 @@ class GPTNeoXModel(nn.Module):
                      name="embed_in")(input_ids)
         block = GPTNeoXBlock
         if cfg.remat and not decode:
-            policy = getattr(jax.checkpoint_policies, cfg.remat_policy, None)
+            policy = resolve_policy(cfg.remat_policy)
             block = nn.remat(GPTNeoXBlock, policy=policy,
                              static_argnums=(2, ))
         for i in range(cfg.num_hidden_layers):

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 from jax.sharding import PartitionSpec as P
 
+from ..runtime.activation_checkpointing import resolve_policy
 from .llama import _rope_freqs
 
 
@@ -130,7 +131,7 @@ class GPTJModel(nn.Module):
                      name="wte")(input_ids)
         block = GPTJBlock
         if cfg.remat and not decode:
-            policy = getattr(jax.checkpoint_policies, cfg.remat_policy, None)
+            policy = resolve_policy(cfg.remat_policy)
             block = nn.remat(GPTJBlock, policy=policy, static_argnums=(2, ))
         for i in range(cfg.num_hidden_layers):
             x = block(cfg, name=f"h_{i}")(x, decode)

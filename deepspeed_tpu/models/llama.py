@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 from jax.sharding import PartitionSpec as P
 
+from ..runtime.activation_checkpointing import resolve_policy
 from ..telemetry import names as _names
 
 
@@ -71,7 +72,16 @@ class LlamaConfig:
     # 128) avoid padding, e.g. 6400 for V=32000.
     loss_chunk_vocab: int = 0
     remat: bool = True
-    remat_policy: str = "nothing_saveable"  # or "dots_saveable", "none"
+    # what a recomputed block keeps besides its input: a key of
+    # runtime/activation_checkpointing/checkpointing._POLICIES.  The default
+    # keeps the flash kernel's residuals (its output, log-sum-exp, and q / k /
+    # v as it received them: 8 x hidden + 4 x heads bytes a token a layer in
+    # bf16, against 2 x hidden for the input), so the backward pass runs
+    # neither the forward kernel nor what feeds it again; on the XLA attention
+    # path it keeps nothing.  "nothing_saveable" for a job at its memory
+    # limit; "dots_saveable" keeps every matmul output; "none" is
+    # jax.checkpoint's own default.
+    remat_policy: str = "flash_residuals_saveable"
     use_ulysses: bool = False
     sp_backend: str = "ulysses"  # "ulysses" (a2a reshard) | "ring" (ppermute)
 
@@ -336,7 +346,7 @@ class LlamaModel(nn.Module):
 
         block = LlamaBlock
         if cfg.remat and not decode:
-            policy = getattr(jax.checkpoint_policies, cfg.remat_policy, None)
+            policy = resolve_policy(cfg.remat_policy)
             block = nn.remat(LlamaBlock, policy=policy, static_argnums=(3, ))
         for i in range(cfg.num_hidden_layers):
             x = block(cfg, name=f"layers_{i}")(x, attention_mask, decode)

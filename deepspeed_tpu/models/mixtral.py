@@ -29,6 +29,7 @@ import flax.linen as nn
 from jax.sharding import PartitionSpec as P
 
 from ..moe.held_experts import held_experts_apply, route
+from ..runtime.activation_checkpointing import resolve_policy
 from .llama import LlamaAttention, LlamaConfig, RMSNorm
 
 
@@ -42,6 +43,9 @@ class MixtralConfig(LlamaConfig):
     # routing probs (mixtral renormalizes)
     shared_expert_intermediate_size: int = 0  # 0 → no shared expert
     norm_topk_prob: bool = True
+    # LlamaConfig's default keeps the flash kernel's residuals; an expert
+    # layer's memory has not been measured against that (no cell trains one)
+    remat_policy: str = "nothing_saveable"
 
 
 def mixtral_tiny(**overrides):
@@ -150,7 +154,7 @@ class MixtralModel(nn.Module):
 
         block = MixtralBlock
         if cfg.remat and not decode:
-            policy = getattr(jax.checkpoint_policies, cfg.remat_policy, None)
+            policy = resolve_policy(cfg.remat_policy)
             block = nn.remat(MixtralBlock, policy=policy, static_argnums=(3, ))
         for i in range(cfg.num_hidden_layers):
             x = block(cfg, name=f"layers_{i}")(x, attention_mask, decode)

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 import flax.linen as nn
 from jax.sharding import PartitionSpec as P
 
+from ..runtime.activation_checkpointing import resolve_policy
+
 OPT_POSITION_OFFSET = 2
 
 
@@ -112,7 +114,7 @@ class OPTModel(nn.Module):
 
         block = OPTBlock
         if cfg.remat and not decode:
-            policy = getattr(jax.checkpoint_policies, cfg.remat_policy, None)
+            policy = resolve_policy(cfg.remat_policy)
             block = nn.remat(OPTBlock, policy=policy, static_argnums=(3, ))
         for i in range(cfg.num_hidden_layers):
             x = block(cfg, name=f"layers_{i}")(x, None, decode)

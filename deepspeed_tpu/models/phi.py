@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 from jax.sharding import PartitionSpec as P
 
+from ..runtime.activation_checkpointing import resolve_policy
 from .llama import _rope_freqs, apply_rotary
 
 
@@ -119,7 +120,7 @@ class PhiModel(nn.Module):
         x = embed(input_ids)
         block = PhiBlock
         if cfg.remat and not decode:
-            policy = getattr(jax.checkpoint_policies, cfg.remat_policy, None)
+            policy = resolve_policy(cfg.remat_policy)
             block = nn.remat(PhiBlock, policy=policy, static_argnums=(2, ))
         for i in range(cfg.num_hidden_layers):
             x = block(cfg, name=f"layers_{i}")(x, decode)

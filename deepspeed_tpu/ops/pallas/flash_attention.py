@@ -21,6 +21,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -32,6 +33,19 @@ DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 _NEG_INF = float("-inf")
 _DEAD_ROW_LSE = -1e30  # finite lse sentinel for fully-masked rows
+
+# ``jax.ad_checkpoint.checkpoint_name`` of the custom VJP's residuals, in the
+# kernel's [B, H, S, D] layout (q / k after the caller's rotary): what a
+# ``save_only_these_names`` policy of a rematerialised block may keep.  The
+# names are the identity outside a ``jax.checkpoint``, and exist only where
+# attention ran as this kernel (docs/kernels.md has the bytes each costs).
+RESIDUAL_OUT = "ds_flash_out"
+RESIDUAL_LSE = "ds_flash_lse"
+RESIDUAL_Q = "ds_flash_q"
+RESIDUAL_K = "ds_flash_k"
+RESIDUAL_V = "ds_flash_v"
+RESIDUAL_NAMES = (RESIDUAL_OUT, RESIDUAL_LSE, RESIDUAL_Q, RESIDUAL_K,
+                  RESIDUAL_V)
 
 
 from ._common import interpret_mode as _interpret
@@ -398,8 +412,15 @@ def _flash(q, k, v, slopes, causal, scale, block_q, block_k, sq, sk, window,
 
 def _flash_fwd(q, k, v, slopes, causal, scale, block_q, block_k, sq, sk,
                window, alibi):
+    q = checkpoint_name(q, RESIDUAL_Q)
+    k = checkpoint_name(k, RESIDUAL_K)
+    v = checkpoint_name(v, RESIDUAL_V)
     o, lse = _fwd(q, k, v, slopes, causal, scale, block_q, block_k, sq, sk,
                   window, alibi)
+    # the named ``o`` is BOTH the output and the residual: a policy that
+    # keeps it then keeps the one array the caller's backward reads too
+    o = checkpoint_name(o, RESIDUAL_OUT)
+    lse = checkpoint_name(lse, RESIDUAL_LSE)
     return o, (q, k, v, slopes, o, lse)
 
 

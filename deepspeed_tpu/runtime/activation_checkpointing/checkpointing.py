@@ -33,19 +33,42 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ...ops.pallas.flash_attention import RESIDUAL_NAMES as FLASH_RESIDUALS
 from ...utils.logging import logger
 
-# jax.checkpoint policy registry (reference deepspeed_config_ activation
-# checkpointing knobs → remat policies)
+# THE table of jax.checkpoint policies by name (reference deepspeed_config_
+# activation checkpointing knobs → remat policies): the models'
+# ``remat_policy`` and ``CheckpointPolicy.policy_name`` both resolve here,
+# through :func:`resolve_policy`.
 _POLICIES = {
-    "none": None,
+    "none": None,  # jax.checkpoint's own default: recompute everything
     "nothing_saveable": jax.checkpoint_policies.nothing_saveable,
+    # keep the flash kernel's residuals: its output and log-sum-exp, which
+    # only the kernel can make, and q / k / v as it received them, so that a
+    # recomputed block runs neither the forward kernel nor the projections,
+    # rotary and transposes that feed it a second time.  Where attention did
+    # not run as that kernel the names do not exist and this is
+    # nothing_saveable.
+    "flash_residuals_saveable": jax.checkpoint_policies.save_only_these_names(
+        *FLASH_RESIDUALS),
     "everything_saveable": jax.checkpoint_policies.everything_saveable,
     "dots_saveable": jax.checkpoint_policies.dots_saveable,
     "checkpoint_dots": jax.checkpoint_policies.dots_saveable,
     "dots_with_no_batch_dims_saveable":
         jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+    "checkpoint_dots_with_no_batch_dims":
+        jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
 }
+
+
+def resolve_policy(name):
+    """The ``jax.checkpoint`` policy a configuration string names; an unknown
+    string raises by name (a typo must not become full recomputation)."""
+    try:
+        return _POLICIES[name]
+    except KeyError:
+        raise ValueError(f"unknown remat policy {name!r}: one of "
+                         f"{sorted(_POLICIES)}") from None
 
 
 @dataclass
@@ -74,8 +97,7 @@ class CheckpointPolicy:
             # keep matmul outputs (the contiguous big buffers) — closest
             # XLA-native analog of the reference's contiguous buffer reuse
             return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-        return _POLICIES.get(self.policy_name,
-                             jax.checkpoint_policies.nothing_saveable)
+        return resolve_policy(self.policy_name)
 
 
 _config: Optional[CheckpointPolicy] = None
