@@ -184,12 +184,17 @@ class InferenceEngineV2:
         # a window-plus-summary cache (EvaByte) is the MODEL's statement:
         # its config says how long a token's exact K/V live
         eva = getattr(cfg, "attention_class", None) == "eva"
+        # so is a latent cache: one row a token a layer for all the heads
+        latent = int(getattr(cfg, "kv_latent_dim", 0) or 0)
+        if latent and tp > 1:
+            raise NotImplementedError(
+                "a latent cache has no head axis to shard over tp")
         self.kv_cache = BlockedKVCache(
             cfg.num_hidden_layers, num_blocks, block_size,
-            cfg.num_key_value_heads, cfg.head_dim,
+            cfg.num_key_value_heads, getattr(cfg, "head_dim", 0),
             dtype=jnp.dtype(config.dtype), kv_dtype=self._kv_dtype,
             window_size=cfg.window_size if eva else 0,
-            chunk_size=cfg.chunk_size if eva else 0)
+            chunk_size=cfg.chunk_size if eva else 0, latent_dim=latent)
         self.state_manager = DSStateManager(sm, self.kv_cache)
         self._budget = int(sm.max_ragged_batch_size)
         #: what the newest engine step held (``schedule_step`` or a decode
@@ -207,9 +212,14 @@ class InferenceEngineV2:
                 self.kv_cache.layers,
                 tuple(layer[:len(entry)] for entry in self.kv_cache.layers))
         self._kv = self.kv_cache.layers
+        token_bytes = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(
+            self._kv)) // (num_blocks * block_size)
         logger.info(
             f"InferenceEngineV2: budget={self._budget} blocks={num_blocks}"
-            f"×{block_size} max_seqs={self.state_manager.max_seqs}")
+            f"×{block_size} max_seqs={self.state_manager.max_seqs} "
+            f"cache={token_bytes} B/token over "
+            f"{len(self._kv)} layers"
+            + (f" (latent rows of {latent})" if latent else ""))
 
     # ------------------------------------------------------------- put/query
     def put(self, batch_uids, batch_tokens, do_schedule=False):
@@ -369,16 +379,17 @@ class InferenceEngineV2:
         the rows at positions ``pos`` (inside the block-table row) in slots
         ``slots``, for this engine's shapes and the layer's ``window``
         (default: the model's one ``sliding_window``)."""
-        cfg = self.model_config
+        cfg, kv = self.model_config, self.kv_cache
         if window is None:
             window = int(getattr(cfg, "sliding_window", 0) or 0)
+        latent = bool(kv.latent_dim)      # all the heads on one latent row
         return kernel_page_loads(
             slots, pos, heads=cfg.num_attention_heads,
-            kv_heads=cfg.num_key_value_heads, head_dim=cfg.head_dim,
-            kv_dtype=self.kv_cache.dtype,
-            block_size=self.kv_cache.block_size,
+            kv_heads=1 if latent else cfg.num_key_value_heads,
+            head_dim=kv.latent_row if latent else cfg.head_dim,
+            kv_dtype=kv.dtype, block_size=kv.block_size,
             maxb=self.state_manager.block_table.shape[1],
-            window=window, row_pages=row_pages)
+            window=window, row_pages=row_pages, latent=latent)
 
     def _summary_pages(self, pos, slots):
         """Of ``grid_pages``, the loads of summary blocks: every run (every
@@ -412,11 +423,22 @@ class InferenceEngineV2:
         is how many rows share one page load.  For a model that states a
         window a layer (``layer_windows``) the four are summed over ALL its
         layers' calls, and ``grid_pages_window`` / ``grid_pages_full`` are
-        the loads of its window layers' and of its full layers' calls."""
+        the loads of its window layers' and of its full layers' calls.
+        For a latent cache also ``latent_keys``, the (live row, key) pairs
+        the rows attend, summed over the layers, and how many live rows took
+        the absorbed form and how many the expanded one."""
         windows = getattr(self.model_config, "layer_windows", None)
         if windows is None:
-            return self._kind_page_counts(pos, slots, int(getattr(
+            counts = self._kind_page_counts(pos, slots, int(getattr(
                 self.model_config, "sliding_window", 0) or 0))
+            if self.kv_cache.latent_dim:
+                live = slots != 0
+                counts.update({
+                    _names.COUNT_LATENT_KEYS: int(
+                        (pos + 1)[live].sum()) * len(self._kv),
+                    _names.COUNT_ABSORBED_ROWS: int(live.sum()),
+                    _names.COUNT_EXPANDED_ROWS: 0})
+            return counts
         total = dict.fromkeys(("grid_pages", "live_pages", "row_pages",
                                "short_pages", "grid_pages_window",
                                "grid_pages_full"), 0)

@@ -20,6 +20,12 @@ so the summaries of the current window (its last columns) are beyond every
 query's position until the window closes.  How many blocks ``n`` tokens
 hold is ``BlockedKVCache.blocks_for`` for every architecture; capacity,
 deferral, the row's width and the scheduler's admission claims all ask it.
+
+A LATENT cache (``latent_dim``: a model with multi-head latent attention
+states ``kv_latent_dim``) keeps ONE row a token a layer, the same for every
+head, in one buffer a layer ``[num_blocks, block_size, latent_row]``: no K
+buffer and no V buffer, no head axis.  Allocator, tables, ``blocks_for`` and
+the claims do not know the difference.
 """
 
 from dataclasses import dataclass, field
@@ -119,11 +125,19 @@ class BlockedKVCache:
     With ``kv_dtype`` set ("int8"/"fp8" — ``kv_codec.py``), the pages hold
     the narrow storage dtype and a layer's entry is ``(k_pages, v_pages,
     k_scales, v_scales)``: one f32 per (block, position, kv-head) row,
-    ``[num_blocks, block_size, Hkv]``."""
+    ``[num_blocks, block_size, Hkv]``.
+
+    With ``latent_dim`` set a layer's entry is ``(pages, )``, ONE buffer
+    ``[num_blocks, block_size, latent_row]`` whose row holds the token's
+    ``latent_dim`` values and zeros up to ``latent_row``, the next multiple
+    of 128: a TPU array's minor dimension is tiled by 128 lanes, so the
+    device pads a narrower row to that anyway, and the paged kernel moves
+    whole tiles (docs/kernels.md); ``num_kv_heads``/``head_dim`` are not
+    read."""
 
     def __init__(self, num_layers, num_blocks, block_size, num_kv_heads,
                  head_dim, dtype=jnp.bfloat16, kv_dtype=None, window_size=0,
-                 chunk_size=0):
+                 chunk_size=0, latent_dim=0):
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
         self.kv_dtype = kv_dtype
@@ -144,6 +158,12 @@ class BlockedKVCache:
                 raise NotImplementedError(
                     "kv_cache_dtype with a window-plus-summary cache")
             self.summary_blocks = w // c // bs
+        self.latent_dim = int(latent_dim or 0)
+        self.latent_row = -(-self.latent_dim // 128) * 128
+        if self.latent_dim and (kv_dtype is not None or self.window_size):
+            raise NotImplementedError(
+                "a latent cache with kv_cache_dtype or a window-plus-summary "
+                "layout")
         shape = (num_blocks, block_size, num_kv_heads, head_dim)
         if kv_dtype is None:
             self.dtype = jnp.dtype(dtype)
@@ -153,11 +173,16 @@ class BlockedKVCache:
         #: every leaf a buffer of its own (never a view of a shared one).
         #: scale=1 for never-written positions keeps dequant a no-op on the
         #: zero payload (garbage block included)
-        self.layers = tuple(
-            tuple(jnp.zeros(shape, self.dtype) for _ in "kv")
-            + tuple(jnp.ones(shape[:3], jnp.float32)
-                    for _ in ("kv" if kv_dtype else ""))
-            for _ in range(int(num_layers)))
+        if self.latent_dim:
+            self.layers = tuple(
+                (jnp.zeros(shape[:2] + (self.latent_row, ), self.dtype), )
+                for _ in range(int(num_layers)))
+        else:
+            self.layers = tuple(
+                tuple(jnp.zeros(shape, self.dtype) for _ in "kv")
+                + tuple(jnp.ones(shape[:3], jnp.float32)
+                        for _ in ("kv" if kv_dtype else ""))
+                for _ in range(int(num_layers)))
         self.allocator = BlockedAllocator(num_blocks)
         # block 0 is the garbage sink: padding tokens in the ragged buffer
         # scatter their K/V there (their slot-0 block-table row is all zeros)

@@ -682,13 +682,142 @@ def cohere2_moe_ragged_step(params, kv_data, token_ids, positions, seq_slots,
         [jnp.sum(counts), jnp.sum(counts > 0)])
 
 
+def _latent_attention(q, pages, block_tables, seq_slots, positions,
+                      block_size, *, rank, scale, use_kernel=True):
+    """Absorbed multi-head latent attention over the paged latent cache: q
+    ``[T, H, row]`` (``(q_lat [rank] ; q_r ; zeros)`` a head, as long as the
+    cache's row), pages ``[num_blocks, bs, row]`` -> ``[T, H, rank]``, the
+    softmax-weighted sum of the first ``rank`` values of the rows at
+    positions ``<=`` the query's.  On a TPU the Pallas ``ds_paged_latent``
+    (``ops/pallas/paged_attention.paged_latent_attention``), elsewhere and
+    for a shape it does not take an XLA gather of each row's block run."""
+    from ...ops._use_kernels import use_pallas_kernels
+    from ...ops.pallas.paged_attention import (latent_tiled,
+                                               paged_latent_attention)
+    if use_kernel and use_pallas_kernels() and latent_tiled(
+            q.shape[1], pages.dtype):
+        return paged_latent_attention(q, pages, block_tables, seq_slots,
+                                      positions, rank=rank, scale=scale)
+    tables_t = block_tables[seq_slots]
+    T, ctx = q.shape[0], tables_t.shape[1] * block_size
+    rows = pages[tables_t].reshape(T, ctx, -1).astype(jnp.float32)
+    scores = jnp.einsum("thl,tcl->thc", q.astype(jnp.float32), rows) * scale
+    mask = jnp.arange(ctx)[None, None, :] <= positions[:, None, None]
+    probs = jax.nn.softmax(
+        jnp.where(mask, scores, jnp.finfo(jnp.float32).min), axis=-1)
+    return jnp.einsum("thc,tcr->thr", probs, rows[..., :rank]).astype(q.dtype)
+
+
+@jax.named_scope(_names.SCOPE_ATTENTION)
+def _mla_block(attn, h, kv_layer, blk, off, block_tables, seq_slots,
+               positions, *, cfg, block_size, use_kernel):
+    """Multi-head latent attention of one layer over the ragged buffer, in
+    the ABSORBED form for every row (``models/pangu_ultra_moe.py`` has the
+    equations): the latent row ``(c ; k_r)`` of each token goes into the
+    layer's one cache buffer, every head's query is taken into the latent
+    space (``q_n W_uk^T``), attends the rows themselves, and the latent
+    output comes back through ``W_uv``.  No per-head key or value is made,
+    in the cache or out of it.  Returns (attn_out [T, D], new kv_layer)."""
+    from ...models.pangu_ultra_moe import mla_down
+    dtype = jnp.dtype(cfg.dtype)
+    rank = cfg.kv_lora_rank
+    pages, = kv_layer
+    with jax.named_scope(_names.SCOPE_MLA_DOWN):
+        q_n, q_r, latent = mla_down(h, attn, positions, cfg)
+    spare = pages.shape[-1] - latent.shape[-1]
+    with jax.named_scope(_names.SCOPE_KV_CACHE):
+        pages = pages.at[blk, off].set(
+            jnp.pad(latent, ((0, 0), (0, spare))).astype(pages.dtype))
+    with jax.named_scope(_names.SCOPE_MLA_ABSORB):
+        q_lat = jnp.einsum("thn,chn->thc", q_n,
+                           attn["k_b_proj"]["kernel"].astype(dtype))
+        q = jnp.pad(jnp.concatenate([q_lat, q_r], axis=-1),
+                    ((0, 0), (0, 0), (0, spare)))
+    o_lat = _latent_attention(q, pages, block_tables, seq_slots, positions,
+                              block_size, rank=rank, scale=cfg.softmax_scale,
+                              use_kernel=use_kernel)
+    with jax.named_scope(_names.SCOPE_MLA_ABSORB):
+        o = jnp.einsum("thc,chv->thv", o_lat,
+                       attn["v_b_proj"]["kernel"].astype(dtype))
+    o = o.reshape(o.shape[0], -1) @ attn["o_proj"]["kernel"].astype(dtype)
+    return o, (pages, )
+
+
+@_ragged_program("pangu_ultra_moe", step_counts=(_names.COUNT_EXPERT_COPIES,
+                                                  _names.COUNT_EXPERT_ACTIVE))
+def pangu_ultra_moe_ragged_step(params, kv_data, token_ids, positions,
+                                seq_slots, block_tables, last_token_idx, *,
+                                cfg, block_size, use_kernel=True,
+                                kv_dtype=None):
+    """One ragged engine iteration for openPangu-Ultra-MoE
+    (``models/pangu_ultra_moe.py`` has the layer's equations): sandwich
+    norms (each branch normed going in AND coming out), multi-head latent
+    attention over a LATENT cache (``kv_data``: one ``(pages, )`` entry a
+    layer, ``[num_blocks, bs, row]``, donated and scattered in place), a
+    dense SwiGLU in the first ``first_k_dense_replace`` layers and in the
+    rest the held experts' part of the scaled routed sum
+    (``moe/held_experts.py``) beside the shared expert.  The grouped matmuls
+    are the Pallas ``ds_grouped_matmul`` where the step's kernels are on, as
+    ``cohere2_moe_ragged_step``'s.
+
+    Returns ``(logits, new kv_data, counts)``; ``counts`` as
+    ``cohere2_moe_ragged_step``'s, over the routed layers."""
+    if kv_dtype is not None:
+        raise NotImplementedError("kv_cache_dtype with a latent cache")
+    from ...models.pangu_ultra_moe import moe_layer
+    from ...ops._use_kernels import use_pallas_kernels
+
+    dtype = jnp.dtype(cfg.dtype)
+    eps = cfg.rms_norm_eps
+    live = seq_slots != 0
+    gmm_kernel = use_kernel and use_pallas_kernels()
+
+    with jax.named_scope(_names.SCOPE_EMBED):
+        x = params["embed_tokens"]["embedding"][token_ids].astype(dtype)
+    blk = block_tables[seq_slots, positions // block_size]
+    off = positions % block_size
+
+    kv_data = list(kv_data)
+    counts = []
+    for l in range(cfg.num_hidden_layers):
+        lp = params[f"layers_{l}"]
+        norm = lambda y, name: _rmsnorm(y, lp[name]["weight"], eps)
+        attn_out, kv_data[l] = _mla_block(
+            lp["self_attn"], norm(x, "input_layernorm"), kv_data[l], blk,
+            off, block_tables, seq_slots, positions, cfg=cfg,
+            block_size=block_size, use_kernel=use_kernel)
+        x = x + norm(attn_out, "post_attention_layernorm")
+        h = norm(x, "pre_mlp_layernorm")
+        if cfg.routed(l):
+            with jax.named_scope(_names.SCOPE_MLP):
+                with jax.named_scope(_names.SCOPE_MOE_ROUTER):
+                    router_logits = h.astype(jnp.float32) @ lp["moe"][
+                        "gate"]["kernel"].astype(jnp.float32)
+                m, landed = moe_layer(h, router_logits, lp["moe"], cfg,
+                                      live=live, kernel=gmm_kernel)
+            counts.append(landed)
+        else:               # the branch alone: its norm comes before the add
+            m = _swiglu(0, h, lp["mlp"], dtype)
+        x = x + norm(m, "post_mlp_layernorm")
+
+    with jax.named_scope(_names.SCOPE_LM_HEAD):
+        xl = _rmsnorm(x[last_token_idx], params["norm"]["weight"], eps)
+        logits = jnp.einsum("td,dv->tv", xl,
+                            params["lm_head"]["kernel"].astype(dtype),
+                            preferred_element_type=jnp.float32)
+    counts = jnp.stack(counts)                        # [routed layers, held]
+    return logits, tuple(kv_data), jnp.stack(
+        [jnp.sum(counts), jnp.sum(counts > 0)])
+
+
 RAGGED_FORWARDS = {"LlamaModel": llama_ragged_step,
                    "MixtralModel": mixtral_ragged_step,
                    "FalconModel": falcon_ragged_step,
                    "OPTModel": opt_ragged_step,
                    "PhiModel": phi_ragged_step,
                    "EvaByteModel": evabyte_ragged_step,
-                   "Cohere2MoeModel": cohere2_moe_ragged_step}
+                   "Cohere2MoeModel": cohere2_moe_ragged_step,
+                   "PanguUltraMoeModel": pangu_ultra_moe_ragged_step}
 
 
 def _device_sample(logits, key, temperature, top_k, top_p):

@@ -37,12 +37,13 @@ import jax.numpy as jnp
 _ONE_TIER_ROWS = 1024
 
 
-def route(router_logits, k, score="softmax", norm_topk=True):
+def route(router_logits, k, score="softmax", norm_topk=True, scale=1.0):
     """``(experts [T, k] int32, weights [T, k] float32)`` of router logits
     ``[T, E]``.  ``score``: ``"softmax"`` (over all ``E``, then the ``k``
     largest) or ``"sigmoid"`` (the ``k`` largest logits, each through the
     sigmoid, which is monotone); ``norm_topk``: weights divided by their sum
-    over the ``k``."""
+    over the ``k``; ``scale``: a factor on the weights as they come out
+    (``routed_scaling_factor``)."""
     logits = router_logits.astype(jnp.float32)
     if score == "softmax":
         topw, topi = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
@@ -53,6 +54,8 @@ def route(router_logits, k, score="softmax", norm_topk=True):
         raise ValueError(f"router score {score!r}")
     if norm_topk:
         topw = topw / jnp.sum(topw, axis=-1, keepdims=True)
+    if scale != 1.0:
+        topw = topw * scale
     return topi, topw
 
 

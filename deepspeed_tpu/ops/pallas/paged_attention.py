@@ -22,6 +22,14 @@ heads go through the softmax together, their dots back to back.
 page of the table; the shapes the run-tiled kernel does not take
 (:func:`run_tiled`) keep it.
 
+
+:func:`paged_latent_attention` (``ds_paged_latent``) reads a LATENT cache
+(multi-head latent attention in its absorbed form): one buffer a layer of
+``[num_blocks, bs, L]`` rows ``(c [rank] ; k_r)``, every query head against
+the same row, so ONE page load serves the scores (all ``L`` columns) and the
+values (its first ``rank``).  It walks the same items of the same
+:func:`run_plan`, with all the heads as one KV head's group.
+
 The XLA fallback (``inference/v2/ragged_forward._paged_attention``) computes
 the same math by gather; the kernels replace it on TPU where the gather's
 HBM blowup ([T, max_ctx, ...]) matters.
@@ -42,6 +50,13 @@ _NEG_INF = float("-inf")
 #: back to back and not each behind its own softmax (docs/kernels.md has the
 #: v5e readings)
 _STACK_ROWS = 1024
+#: query rows (tokens x heads) of a tile of the latent kernel: 8 tokens of
+#: 128 heads, which is what its VMEM holds beside the accumulator
+#: (docs/kernels.md)
+_LATENT_TILE_ROWS = 1024
+#: its VMEM: the tile's q and output (each double-buffered), the float32
+#: accumulator and softmax state, two pages
+_LATENT_VMEM_BYTES = 64 * 1024 * 1024
 
 
 from ._common import interpret_mode as _interpret
@@ -61,7 +76,17 @@ def run_tiled(kv_heads, head_dim, kv_dtype):
         sublanes in (1, 2, 4, 8) or sublanes % 8 == 0)
 
 
-def tile_rows(heads, kv_heads, head_dim, kv_dtype, tokens):
+def latent_tiled(heads, kv_dtype):
+    """Whether :func:`paged_latent_attention` takes this shape: a token's
+    ``heads`` query rows are sliced out of the tile in the cache's type, so
+    they have to fill whole sublane tiles of it (8 rows of 32 bits, 16 of
+    16)."""
+    if kv_dtype not in (jnp.float32, jnp.bfloat16):
+        return False
+    return heads % (8 * 4 // jnp.dtype(kv_dtype).itemsize) == 0
+
+
+def tile_rows(heads, kv_heads, head_dim, kv_dtype, tokens, latent=False):
     """WHICH kernel reads the cache for a call of ``tokens`` rows: the
     run-tiled one with Q tiles of the returned ``TQ`` buffer rows, or (None)
     one grid row a token.  :func:`paged_attention` and the count of its
@@ -69,7 +94,16 @@ def tile_rows(heads, kv_heads, head_dim, kv_dtype, tokens):
     added here.  ``TQ`` follows from the shapes alone: an item on the whole
     tile pays for all its ``TQ * g`` MXU rows per KV head, so small tiles
     win although they load a long run's pages more often (docs/kernels.md
-    has the v5e readings)."""
+    has the v5e readings).
+
+    ``latent``: the cache is a latent one (``kv_heads`` 1, ``head_dim`` its
+    row's length) and the reader :func:`paged_latent_attention`, whose tile
+    holds ``_LATENT_TILE_ROWS`` query rows whatever the heads (None: the
+    shape stays on the gather)."""
+    if latent:
+        if not latent_tiled(heads, kv_dtype):
+            return None
+        return max(8, _LATENT_TILE_ROWS // heads // 8 * 8)
     if not run_tiled(kv_heads, head_dim, kv_dtype):
         return None
     g = heads // kv_heads
@@ -129,7 +163,8 @@ def run_plan(xp, seq_slots, positions, tq, block_size, window=0, g=1):
 
 
 def kernel_page_loads(seq_slots, positions, *, heads, kv_heads, head_dim,
-                      kv_dtype, block_size, maxb, window=0, row_pages=None):
+                      kv_dtype, block_size, maxb, window=0, row_pages=None,
+                      latent=False):
     """Host-side (numpy) count of the K/V page loads (each brings one K and
     one V page) of the kernel :func:`paged_attention` picks for these rows
     (``[T]``, or ``[B, T]``: B calls) against a ``maxb``-page block table:
@@ -140,13 +175,16 @@ def kernel_page_loads(seq_slots, positions, *, heads, kv_heads, head_dim,
     ``row_pages`` (a page count a row, equal along a run; None: 0) the sum
     over what loads together — once a run, or once a row.  ``short``: of
     ``grid``, the loads whose item computes one slab of rows and not the
-    tile (:func:`run_plan`'s ``slab``; 0 on the per-token kernel)."""
+    tile (:func:`run_plan`'s ``slab``; 0 on the per-token kernel).
+    ``latent``: the loads of :func:`paged_latent_attention` (each brings ONE
+    page, scores and values both) for ``heads`` query heads on one latent
+    row (``kv_heads`` 1), by :func:`tile_rows`'s branch."""
     slots, pos = (np.atleast_2d(np.asarray(a))
                   for a in (seq_slots, positions))
     if row_pages is not None:
         row_pages = np.where(slots != 0, np.atleast_2d(row_pages), 0)
     T = slots.shape[-1]
-    tq = tile_rows(heads, kv_heads, head_dim, kv_dtype, T)
+    tq = tile_rows(heads, kv_heads, head_dim, kv_dtype, T, latent)
     if tq is None:
         first = np.maximum(pos - window + 1, 0) // block_size if window else 0
         live = np.where(slots != 0, pos // block_size + 1 - first, 0).sum()
@@ -384,6 +422,153 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_slots, positions,
     out = out.reshape(n, Hkv, tq, g, Dh).transpose(0, 2, 1, 3, 4) \
         .reshape(n * tq, H, Dh)[:T]
     return (out, loads[0][:, 0]) if count_loads else out
+
+
+# ------------------------------------------------------------- latent path
+def _latent_kernel(tables_ref, slot_ref, first_ref, npages_ref, slab_ref,
+                   total_ref, q_ref, pos_ref, rid_ref, c_hbm, o_ref,
+                   c_buf, sem, acc_ref, m_ref, l_ref, *, tq, block_size,
+                   maxb, scale, rank):
+    """One Q tile of the latent cache's reader: ``q_ref [1, M, L]`` (``M =
+    tq * heads`` rows, row ``t * heads + h``, each ``(q_lat [rank] ; q_r)``
+    in the cache's type), ``pos_ref``/``rid_ref [1, M, 1]``, against the
+    tile's items ``(run k, page p)`` of :func:`run_plan`.  A page ``[bs, L]``
+    arrives ONCE and is both the keys (all ``L`` columns) and the values
+    (the first ``rank``); the dots take their operands in the cache's type
+    and sum in float32, the softmax state is float32.  An item computes its
+    run's slab of rows, or (-1) the tile, as :func:`_run_kernel`'s."""
+    i = pl.program_id(0)
+    base = i * tq
+    total = total_ref[i]
+    M = acc_ref.shape[0]
+    g = M // tq
+    R = slab_rows(g)
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    def copy(k, p, buf):
+        blk = tables_ref[slot_ref[base + k] * maxb + first_ref[base + k] + p]
+        return pltpu.make_async_copy(c_hbm.at[blk], c_buf.at[buf],
+                                     sem.at[buf])
+
+    @pl.when(total > 0)
+    def _first():
+        copy(0, 0, 0).start()
+
+    def attend(k, p, buf, rows):
+        pos, rid = pos_ref[0, rows], rid_ref[0, rows]          # [n, 1]
+        n = pos.shape[0]
+        col = (first_ref[base + k] + p) * block_size + \
+            jax.lax.broadcasted_iota(jnp.int32, (n, block_size), 1)
+        live = jnp.logical_and(rid == k, col <= pos)
+        page = c_buf[buf]                                      # [bs, L]
+        s = jax.lax.dot_general(
+            q_ref[0, rows], page, (((1, ), (1, )), ((), ())),
+            preferred_element_type=jnp.float32) * scale        # [n, bs]
+        s = jnp.where(live, s, _NEG_INF)
+        m_prev = m_ref[rows, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_safe = jnp.where(m_new == _NEG_INF, 0.0, m_new)
+        e = jnp.where(live, jnp.exp(s - m_safe), 0.0)
+        alpha = jnp.where(m_prev == _NEG_INF, 0.0, jnp.exp(m_prev - m_safe))
+        l_new = alpha * l_ref[rows, :1] + jnp.sum(e, axis=1, keepdims=True)
+        acc_ref[rows] = acc_ref[rows] * alpha + jnp.dot(
+            e.astype(page.dtype), page[:, :rank],
+            preferred_element_type=jnp.float32)
+        m_ref[rows] = jnp.broadcast_to(m_new, (n, m_ref.shape[1]))
+        l_ref[rows] = jnp.broadcast_to(l_new, (n, l_ref.shape[1]))
+
+    def item(it, carry):
+        k, p = carry
+        buf = it % 2
+        last = p + 1 == npages_ref[base + k]
+        k_next = jnp.where(last, k + 1, k)
+        p_next = jnp.where(last, 0, p + 1)
+
+        @pl.when(it + 1 < total)
+        def _prefetch():
+            copy(k_next, p_next, 1 - buf).start()
+
+        copy(k, p, buf).wait()
+        slab = slab_ref[base + k]
+
+        @pl.when(slab < 0)
+        def _tile():
+            attend(k, p, buf, slice(None))
+
+        @pl.when(slab >= 0)
+        def _slab():
+            # a token's g rows start at a multiple of g: where g is whole
+            # sublane tiles the slab IS the token's rows
+            attend(k, p, buf, pl.ds(pl.multiple_of(
+                slab, R if g % 8 == 0 else 8), R))
+
+        return k_next, p_next
+
+    jax.lax.fori_loop(0, total, item, (jnp.int32(0), ) * 2)
+    l = l_ref[:, :1]
+    o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "scale"))
+def paged_latent_attention(q, c_cache, block_tables, seq_slots, positions, *,
+                           rank, scale):
+    """Multi-head latent attention, absorbed, over a paged latent cache.
+
+    q: ``[T, H, L]``, head ``h`` of row ``t`` as ``(q_n W_uk,h^T [rank] ;
+    q_r [L - rank])``; c_cache: ``[num_blocks, bs, L]``, a token's row ``(c
+    [rank] ; k_r)``, the same for every head; block_tables ``[max_seqs,
+    maxb]``, seq_slots, positions ``[T]`` as :func:`paged_attention`'s.
+    Returns ``[T, H, rank]``: ``sum_j softmax_j(q . row_j * scale) c_j`` over
+    the keys ``j <= positions[t]`` of the row's sequence (the caller takes
+    it through ``W_uv``).  A dead row (slot 0) comes back zero.  The shape
+    has to pass :func:`latent_tiled`."""
+    T, H, L = q.shape
+    _, bs, _ = c_cache.shape
+    tq = tile_rows(H, 1, L, c_cache.dtype, T, latent=True)
+    if tq is None:
+        raise ValueError(f"ds_paged_latent does not take {H} heads in "
+                         f"{c_cache.dtype} (latent_tiled)")
+    maxb = block_tables.shape[1]
+    M = tq * H
+    pos, rid, run_slot, first_page, n_pages, slab = run_plan(
+        jnp, seq_slots, positions, tq, bs, 0, H)
+    n = rid.shape[0]
+    rows = lambda a: jnp.repeat(a, H, axis=1)[:, :, None]      # [n, M, 1]
+    qt = jnp.pad(q.astype(c_cache.dtype), ((0, n * tq - T), (0, 0), (0, 0))) \
+        .reshape(n, M, L)
+    tile = lambda *block: pl.BlockSpec(
+        (1, ) + block, lambda i, *_: (i, ) + (0, ) * len(block))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=6,
+        grid=(n, ),
+        in_specs=[tile(M, L), tile(M, 1), tile(M, 1),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=tile(M, rank),
+        scratch_shapes=[
+            pltpu.VMEM((2, bs, L), c_cache.dtype),
+            pltpu.SemaphoreType.DMA((2, )),
+            pltpu.VMEM((M, rank), jnp.float32),
+            pltpu.VMEM((M, 128), jnp.float32),
+            pltpu.VMEM((M, 128), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, tq=tq, block_size=bs, maxb=maxb,
+                          scale=float(scale), rank=int(rank)),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n, M, rank), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", ),
+            vmem_limit_bytes=_LATENT_VMEM_BYTES),
+        interpret=_interpret(),
+        name="ds_paged_latent",
+    )(block_tables.reshape(-1).astype(jnp.int32), run_slot.reshape(-1),
+      first_page.reshape(-1), n_pages.reshape(-1), slab.reshape(-1),
+      n_pages.sum(-1), qt, rows(pos), rows(rid), c_cache)
+    return out.reshape(n * tq, H, rank)[:T]
 
 
 # ------------------------------------------------------- per-token path

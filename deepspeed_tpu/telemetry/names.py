@@ -73,6 +73,13 @@ COUNT_EXPERT_COPIES = "expert_copies"         # (row, expert) pairs on a held
 COUNT_EXPERT_ACTIVE = "expert_active"         # held experts with a copy,
 #                                               summed over layers
 
+#: a model with a LATENT cache (multi-head latent attention): the (live row,
+#: key) pairs its rows attend, summed over the layers, and the live rows
+#: that took the absorbed form (the paged latent kernel) and the expanded one
+COUNT_LATENT_KEYS = "latent_keys"
+COUNT_ABSORBED_ROWS = "absorbed_rows"
+COUNT_EXPANDED_ROWS = "expanded_rows"
+
 # ---- jitted programs (``XLA Modules`` events are ``jit_<name>(<id>)``)
 PROGRAM_MICRO = "ds_micro_"               # + the micro-step variant
 PROGRAM_APPLY = "ds_apply_update"
@@ -98,6 +105,12 @@ SCOPE_KV_CACHE = "ds.kv_cache"            # serving, inside ds.attn: everything 
 #                                           step spends to put its K/V into the
 #                                           paged cache (the scatter; encoding
 #                                           and scales on the quantized path)
+SCOPE_MLA_DOWN = "ds.mla_down"            # serving, inside ds.attn: the two
+#                                           low-rank projections, their norms
+#                                           and the rotary of q_r and k_r
+SCOPE_MLA_ABSORB = "ds.mla_absorb"        # serving, inside ds.attn: q_n into
+#                                           the latent space and the latent
+#                                           output out of it (W_uk, W_uv)
 SCOPE_EVA_SUMMARY = "ds.eva_summary"      # serving: pooling the chunks a step
 #                                           completes, and their scatter
 MODULE_ATTENTION = "self_attn"            # flax module name (training)
@@ -106,7 +119,8 @@ MODULE_MLP = "mlp"
 # ---- Pallas kernels: ``pallas_call(name=...)`` prefixes by family
 KERNEL_PREFIX = "ds_"
 KERNEL_FLASH = "ds_flash_"                # fwd, bwd_dq, bwd_dkv (+ _bias_)
-KERNEL_PAGED = "ds_paged_"                # runs (run-tiled), decode (per token)
+KERNEL_PAGED = "ds_paged_"                # runs (run-tiled), decode (per token),
+#                                           latent (a latent cache's reader)
 KERNEL_OPTIMIZER = "ds_fused_"            # adam, lion, lamb_phase1/2
 
 #: JAX's own markers in a scope path

@@ -22,7 +22,7 @@ from deepspeed_tpu.inference.v2 import InferenceEngineV2
 from deepspeed_tpu.inference.v2 import ragged_forward as rf
 from deepspeed_tpu.inference.v2.ragged import BlockedKVCache
 from deepspeed_tpu.models import (cohere2_moe, evabyte, falcon, llama,
-                                  mixtral, opt, phi)
+                                  mixtral, opt, pangu_ultra_moe, phi)
 
 _spec = importlib.util.spec_from_file_location(
     "serve_hlo_check", os.path.join(
@@ -47,7 +47,12 @@ ZOO = {
         dtype="float32")),
     "Cohere2MoeModel": (cohere2_moe.Cohere2MoeModel,
                         cohere2_moe.cohere2_moe_tiny),
+    "PanguUltraMoeModel": (pangu_ultra_moe.PanguUltraMoeModel,
+                           pangu_ultra_moe.pangu_ultra_moe_tiny),
 }
+#: the models whose cache is ONE latent buffer a layer: it never was a K and
+#: a V in one stacked array, so (c) has nothing to rebuild for them
+LATENT = ("PanguUltraMoeModel", )
 
 
 # a recording step and a storage-rebuilding step wrap the engine's step
@@ -74,9 +79,10 @@ def _programs(name, num_blocks=2048):
     bs, rows, seqs, maxb = 8, 8, 4, 6 if eva else 2
     cache = BlockedKVCache(
         cfg.num_hidden_layers, num_blocks, bs, cfg.num_key_value_heads,
-        cfg.head_dim, dtype=jnp.float32,
+        getattr(cfg, "head_dim", 0), dtype=jnp.float32,
         window_size=cfg.window_size if eva else 0,
-        chunk_size=cfg.chunk_size if eva else 0).layers
+        chunk_size=cfg.chunk_size if eva else 0,
+        latent_dim=getattr(cfg, "kv_latent_dim", 0)).layers
     i32 = lambda *shape: jnp.zeros(shape, jnp.int32)
     step_fn = rf.RAGGED_FORWARDS[name]
     kw = dict(cfg=cfg, block_size=bs)
@@ -92,8 +98,10 @@ def _programs(name, num_blocks=2048):
 
 @pytest.mark.parametrize("name,which", [
     ("LlamaModel", 0), ("EvaByteModel", 0), ("LlamaModel", 1),
-    ("EvaByteModel", 1)], ids=["llama_step", "evabyte_step", "llama_burst",
-                               "evabyte_burst"])
+    ("EvaByteModel", 1), ("PanguUltraMoeModel", 0),
+    ("PanguUltraMoeModel", 1)], ids=[
+        "llama_step", "evabyte_step", "llama_burst", "evabyte_burst",
+        "latent_step", "latent_burst"])
 def test_compiled_program_updates_the_cache_in_place(name, which):
     lowered, n_params, n_cache, page_bytes = _programs(name)
     compiled = lowered[which].compile()
@@ -194,9 +202,15 @@ def _engine(name, kv_dtype):
 @pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["fp", "int8"])
 @pytest.mark.parametrize("name", list(rf.RAGGED_FORWARDS))
 def test_layout_serves_what_the_stacked_array_served(name, kv_dtype):
-    if name == "EvaByteModel" and kv_dtype is not None:
+    if name in ("EvaByteModel", ) + LATENT and kv_dtype is not None:
         with pytest.raises(NotImplementedError, match="kv_cache_dtype"):
             _engine(name, kv_dtype)
+        return
+    if name in LATENT:
+        eng = _engine(name, kv_dtype)
+        assert [len(layer) for layer in eng._kv] == \
+            [1] * eng.model_config.num_hidden_layers
+        assert eng._kv[0][0].shape == (40, 8, 128)
         return
     rng = np.random.default_rng(28)
     vocab = ZOO[name][1]().vocab_size

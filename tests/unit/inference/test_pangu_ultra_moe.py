@@ -1,0 +1,209 @@
+"""openPangu-Ultra-MoE (``models/pangu_ultra_moe.py``) on the serving path, at
+tiny size on the CPU: the ragged step over the LATENT cache (chunked prefill,
+single decode, the burst; the absorbed form) against the dense forward (the
+expanded form); the cache's layout; the absorbed products against the
+expanded ones for one layer; the 32 shares of a routed layer add up; the
+counts a step carries."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.inference.v2 import ragged_forward as rf
+from deepspeed_tpu.inference.v2.ragged import BlockedKVCache
+from deepspeed_tpu.models import pangu_ultra_moe as pm
+from deepspeed_tpu.moe import held_experts as he
+from deepspeed_tpu.serving import build_serving_engine
+
+CFG = pm.pangu_ultra_moe_tiny()     # 1 dense + 4 routed; 16 experts, 8 held
+_made = {}
+
+
+def _model(cfg=CFG):
+    if cfg not in _made:
+        model = pm.PanguUltraMoeModel(cfg)
+        params = model.init(jax.random.PRNGKey(3),
+                            jnp.zeros((1, 8), jnp.int32))["params"]
+        _made[cfg] = model, params
+    return _made[cfg]
+
+
+def _greedy(model, params, prompt, new, length=64):
+    """Greedy tokens of the dense forward: one compiled shape, the sequence
+    padded behind (a causal model's logits do not see what follows)."""
+    forward = jax.jit(lambda ids: model.apply({"params": params}, ids[None])[0])
+    ids = list(prompt)
+    for _ in range(new):
+        padded = jnp.asarray(ids + [0] * (length - len(ids)))
+        ids.append(int(jnp.argmax(forward(padded)[len(ids) - 1])))
+    return ids[len(prompt):]
+
+
+def _scheduler(model, params, burst, budget=16, sessions=2):
+    return build_serving_engine(
+        model, params=params,
+        engine_config={"dtype": "float32", "decode_burst": burst,
+                       "state_manager": {
+                           "max_tracked_sequences": 2 * sessions,
+                           "max_ragged_sequence_count": sessions + 1,
+                           "max_context": 64, "block_size": 8,
+                           "num_blocks": 40,
+                           "max_ragged_batch_size": budget}},
+        serving_config={"max_concurrent": sessions})
+
+
+@pytest.mark.parametrize("burst", [0, 8], ids=["steps", "burst"])
+def test_the_ragged_step_is_the_dense_forward(burst):
+    """Two prompts of 40 and 21 tokens, chunked into budgets of 16 rows, then
+    12 decoded tokens each through the latent cache (pages of 8: a page
+    boundary inside every reply): the streamed tokens are the dense
+    (expanded) forward's greedy tokens, a step at a time and through the
+    burst."""
+    model, params = _model()
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, 256, n).tolist() for n in (40, 21)]
+    want = [_greedy(model, params, p, 12) for p in prompts]
+    sched = _scheduler(model, params, burst)
+    assert sched.serve(prompts, max_new_tokens=12) == want
+    assert (getattr(sched.engine, "burst_steps", 0) > 0) == bool(burst)
+
+
+def test_the_cache_is_one_latent_buffer_a_layer():
+    """One buffer a layer, a row a token for all the heads: 576 values at the
+    published sizes in a row of 640 (the next multiple of 128 lanes), no K
+    and no V buffer, no head axis; the engine builds it from the model's own
+    statement (``kv_latent_dim``)."""
+    cache = BlockedKVCache(7, 12, 128, 128, 0, dtype=jnp.bfloat16,
+                           latent_dim=pm.PanguUltraMoeConfig().kv_latent_dim)
+    assert pm.PanguUltraMoeConfig().kv_latent_dim == 576
+    assert (cache.latent_dim, cache.latent_row) == (576, 640)
+    assert [tuple(x.shape for x in layer) for layer in cache.layers] == \
+        [((12, 128, 640), )] * 7
+    assert cache.layers[0][0].dtype == jnp.bfloat16
+    assert cache.blocks_for(129) == 2 and cache.row_width(1024) == 8
+    with pytest.raises(NotImplementedError):
+        BlockedKVCache(1, 4, 8, 1, 0, latent_dim=40, kv_dtype="int8")
+    model, params = _model()
+    eng = _scheduler(model, params, 0).engine
+    assert CFG.kv_latent_dim == 40
+    assert [tuple(x.shape for x in layer) for layer in eng.kv_cache.layers] \
+        == [((40, 8, 128), )] * 5
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_the_absorbed_form_is_the_expanded_form(seed):
+    """One layer's attention over a sequence of 29 tokens written into a
+    fresh latent cache in one step: ``_mla_block`` (q into the latent space,
+    the cache rows as keys and values, the latent output through W_uv) gives
+    what ``PanguAttention`` (per-head keys and values, one softmax a head)
+    gives, to float32 rounding; and the cache then holds each token's
+    ``(c ; k_r)`` and zeros."""
+    _, params = _model()
+    attn = params["layers_1"]["self_attn"]
+    h = jax.random.normal(jax.random.PRNGKey(seed), (29, CFG.hidden_size))
+    want = pm.PanguAttention(CFG).apply({"params": attn}, h[None])[0]
+    tables = jnp.asarray([[0] * 4, [3, 1, 4, 2]], jnp.int32)
+    pos = jnp.arange(29)
+    pages = jnp.zeros((5, 8, 128), jnp.float32)
+    got, (pages, ) = rf._mla_block(
+        attn, h, (pages, ), tables[1][pos // 8], pos % 8, tables,
+        jnp.ones(29, jnp.int32), pos, cfg=CFG, block_size=8,
+        use_kernel=False)
+    np.testing.assert_allclose(got, want, atol=2e-5 * float(
+        jnp.max(jnp.abs(want))))
+    _, _, latent = pm.mla_down(h, attn, pos, CFG)
+    rows = pages[tables[1]].reshape(32, 128)[:29]
+    np.testing.assert_allclose(rows[:, :40], latent, atol=1e-6)
+    assert not np.asarray(rows[:, 40:]).any()
+
+
+def _layer_inputs(seed, experts, tokens=48):
+    cfg = dataclasses.replace(CFG, n_routed_experts=experts,
+                              experts_held=None, num_experts_per_tok=8
+                              if experts == 256 else 2)
+    _, params = _model(cfg)
+    moe = params["layers_2"]["moe"]
+    h = jax.random.normal(jax.random.PRNGKey(seed), (tokens, cfg.hidden_size))
+    return cfg, moe, h, h @ moe["gate"]["kernel"]
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_the_32_shares_add_up_to_the_uncut_layer(seed):
+    """256 experts, 8 a token, as 32 shares of 8 (the deployment's cut): the
+    shares' routed parts, with the shared expert counted ONCE, are the uncut
+    layer, scaling factor and all; the copies the shares count are every
+    live copy."""
+    cfg, moe, h, router = _layer_inputs(seed, 256)
+    whole, counts = pm.moe_layer(h, router, moe, cfg)
+    shared = pm.swiglu(h, *(moe[f"shared_{n}_proj"]["kernel"]
+                            for n in ("gate", "up", "down")))
+    topi, topw = he.route(router, 8, "sigmoid", True, scale=2.5)
+
+    @jax.jit
+    def share(chip):            # one compiled shape for the 32 of them
+        stacks = [jax.lax.dynamic_slice_in_dim(moe[n], 8 * chip, 8)
+                  for n in ("w1", "w2", "w3")]
+        return he.held_experts_apply(h, topi, topw, *stacks,
+                                     first_expert=8 * chip, experts=256)
+
+    parts, landed = zip(*(share(chip) for chip in range(32)))
+    scale = float(jnp.max(jnp.abs(whole)))
+    for chip in (0, 31):        # the model's own layer, told its share
+        told = dataclasses.replace(cfg, experts_held=8, first_expert=8 * chip)
+        stacks = {n: moe[n][8 * chip:8 * chip + 8] for n in ("w1", "w2", "w3")}
+        out, n = pm.moe_layer(h, router, {**moe, **stacks}, told)
+        np.testing.assert_allclose(out - shared, parts[chip],
+                                   atol=2e-5 * scale)
+        np.testing.assert_array_equal(n, landed[chip])
+    np.testing.assert_allclose(sum(parts) + shared, whole, atol=2e-5 * scale)
+    np.testing.assert_array_equal(np.concatenate(landed), counts)
+    assert int(counts.sum()) == 48 * 8
+    # the scaling factor is on the routed sum alone
+    plain = dataclasses.replace(cfg, routed_scaling_factor=1.0)
+    once, _ = pm.moe_layer(h, router, moe, plain)
+    np.testing.assert_allclose(whole - shared, 2.5 * (once - shared),
+                               atol=2e-5 * scale)
+
+
+def test_the_routers_scale_multiplies_the_normalised_weights():
+    logits = jax.random.normal(jax.random.PRNGKey(0), (6, 16))
+    topi, topw = he.route(logits, 2, "sigmoid", True)
+    topi2, topw2 = he.route(logits, 2, "sigmoid", True, scale=2.5)
+    np.testing.assert_array_equal(topi, topi2)
+    np.testing.assert_allclose(topw.sum(-1), 1.0, atol=1e-6)
+    np.testing.assert_allclose(topw2, 2.5 * topw, atol=1e-6)
+
+
+def test_a_step_carries_the_latent_counts():
+    """13 live rows of a 32-row buffer at positions 0..12: ``latent_keys`` is
+    the (row, key) pairs times the layers, every live row absorbed, the page
+    counts the latent kernel's (one layer's call), the expert counts over the
+    four routed layers only."""
+    model, params = _model()
+    prompt = np.random.default_rng(1).integers(0, 256, 13).tolist()
+    sched = _scheduler(model, params, 0, budget=32, sessions=1)
+    sched.submit(prompt, max_new_tokens=2)
+    sched.step()
+    counts = sched.engine.last_step_counts
+    assert counts["live_tokens"] == 13 and counts["token_budget"] == 32
+    assert counts["latent_keys"] == sum(range(1, 14)) * 5
+    assert (counts["absorbed_rows"], counts["expanded_rows"]) == (13, 0)
+    assert (counts["grid_pages"], counts["row_pages"]) == (2, 8 + 5 * 2)
+    assert 0 < counts["expert_copies"] <= 13 * 2 * 4
+    assert 0 < counts["expert_active"] <= 8 * 4
+
+
+def test_the_dense_layers_lead():
+    cfg = pm.pangu_ultra_moe_tiny(first_k_dense_replace=2)
+    assert [cfg.routed(i) for i in range(5)] == [False, False, True, True,
+                                                 True]
+    _, params = _model(cfg)
+    assert "mlp" in params["layers_1"] and "moe" not in params["layers_1"]
+    assert "moe" in params["layers_2"] and "mlp" not in params["layers_2"]
+    with pytest.raises(ValueError, match="sandwich"):
+        pm.pangu_ultra_moe_tiny(sandwich_norm=False)
+    with pytest.raises(ValueError, match="outside the router"):
+        pm.pangu_ultra_moe_tiny(first_expert=12)

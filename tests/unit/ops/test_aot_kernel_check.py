@@ -35,7 +35,7 @@ def test_every_kernel_compiles_for_v5e(report):
     lines = [ln for ln in r.stdout.splitlines()
              if ln.startswith(("PASS", "FAIL"))]
     assert r.returncode == 0, "\n".join(lines) + r.stderr[-1500:]
-    assert len(lines) >= 19 and all(ln.startswith("PASS") for ln in lines)
+    assert len(lines) >= 21 and all(ln.startswith("PASS") for ln in lines)
     assert "TPU v5 lite" in r.stdout
     # the run-tiled paged kernel, both branches of its item, at the two
     # serving cells' shapes and their bursts': a failure of the kind of
@@ -47,6 +47,8 @@ def test_every_kernel_compiles_for_v5e(report):
                    "paged_attention(MHA 32/32, the EvaByte cell's burst)",
                    "paged_attention(GQA 28/4, Qwen2)",
                    "paged_attention(GQA 32/8, count_loads)",
+                   "paged_latent_attention(MLA 128 x 576, the cell's step)",
+                   "paged_latent_attention(MLA 128 x 576, the cell's burst)",
                    "block_sparse_flash_attention"):
         assert any(kernel in ln for ln in lines), kernel
 

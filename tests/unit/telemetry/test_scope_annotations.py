@@ -398,7 +398,7 @@ def _pallas_call_names():
 
 def test_every_pallas_call_has_a_ds_name_listed_in_the_docs():
     found = _pallas_call_names()
-    assert len({f + str(i) for i, (f, _) in enumerate(found)}) >= 16
+    assert len({f + str(i) for i, (f, _) in enumerate(found)}) >= 17
     kernel_names = [n for _, n in found]
     assert len(set(kernel_names)) == len(kernel_names)
     doc = open(os.path.join(ROOT, "docs", "kernels.md")).read()
@@ -406,9 +406,45 @@ def test_every_pallas_call_has_a_ds_name_listed_in_the_docs():
         assert name.startswith(names.KERNEL_PREFIX), name
         assert f"`{name}`" in doc, f"{name} missing from docs/kernels.md"
     assert sum(n.startswith(names.KERNEL_FLASH) for n in kernel_names) == 7
-    assert sum(n.startswith(names.KERNEL_PAGED) for n in kernel_names) == 2
+    assert sum(n.startswith(names.KERNEL_PAGED) for n in kernel_names) == 3
     assert sum(n.startswith(names.KERNEL_OPTIMIZER)
                for n in kernel_names) == 4
+
+
+def test_the_latent_steps_scopes_reach_its_compiled_program():
+    """``ds.mla_down``, ``ds.kv_cache`` and ``ds.mla_absorb`` inside
+    ``ds.attn``, the three expert scopes inside ``ds.mlp``: the scope paths
+    of the compiled latent-attention step (the device trace carries the
+    same)."""
+    import re
+    from deepspeed_tpu.inference.v2 import ragged_forward as rf
+    from deepspeed_tpu.inference.v2.ragged import BlockedKVCache
+    from deepspeed_tpu.models import pangu_ultra_moe as pm
+    cfg = pm.pangu_ultra_moe_tiny()
+    model = pm.PanguUltraMoeModel(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))["params"]
+    cache = jax.eval_shape(lambda: BlockedKVCache(
+        cfg.num_hidden_layers, 6, 8, 0, 0, dtype=jnp.float32,
+        latent_dim=cfg.kv_latent_dim).layers)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    text = rf.pangu_ultra_moe_ragged_step.lower(
+        params, cache, i32(16), i32(16), i32(16), i32(3, 4), i32(3), cfg=cfg,
+        block_size=8).compile().as_text()
+    paths = set(re.findall(r'op_name="([^"]*)"', text))
+    assert any(p.startswith("jit(" + names.PROGRAM_RAGGED_STEP
+                            + "pangu_ultra_moe)") for p in paths)
+    inside = lambda outer, scope: any(
+        f"/{outer}/{scope}/" in p or p.endswith(f"/{outer}/{scope}")
+        for p in paths)
+    for scope in (names.SCOPE_MLA_DOWN, names.SCOPE_KV_CACHE,
+                  names.SCOPE_MLA_ABSORB):
+        assert inside(names.SCOPE_ATTENTION, scope), scope
+    for scope in (names.SCOPE_MOE_ROUTER, names.SCOPE_MOE_EXPERTS,
+                  names.SCOPE_MOE_SHARED):
+        assert inside(names.SCOPE_MLP, scope), scope
+    assert rf.pangu_ultra_moe_ragged_step.step_counts == (
+        names.COUNT_EXPERT_COPIES, names.COUNT_EXPERT_ACTIVE)
 
 
 def test_names_reach_the_compiled_program_of_the_tiny_model():

@@ -233,6 +233,19 @@ def main():
         sds((256, 32, D), bf16), kc, kc, sds((65, 27), jnp.int32),
         sds((256, ), jnp.int32), sds((256, ), jnp.int32)))
 
+    # the latent cache's reader at openPangu-Ultra-MoE's sizes (128 heads on
+    # rows of 512 + 64 in 640): a prefill step's buffer and a burst's
+    from deepspeed_tpu.ops.pallas.paged_attention import \
+        paged_latent_attention
+    for name, T in (("the cell's step", 1024), ("the cell's burst", 65)):
+        results.append(checked(
+            f"paged_latent_attention(MLA 128 x 576, {name})",
+            lambda q, c, t, s, l: paged_latent_attention(
+                q, c, t, s, l, rank=512, scale=192 ** -0.5),
+            sds((T, 128, 640), bf16), sds((64, 128, 640), bf16),
+            sds((65, 193), jnp.int32), sds((T, ), jnp.int32),
+            sds((T, ), jnp.int32)))
+
     from deepspeed_tpu.ops.pallas.grouped_matmul import gmm
     results.append(checked(
         "gmm(moe grouped matmul)", lambda a, b, s: gmm(a, b, s),

@@ -41,7 +41,8 @@ import jax.numpy as jnp  # noqa: E402
 
 DEFAULT_CONFIGS = ("perfbench/configs/mistral7b_1chip.json",
                    "perfbench/configs/evabyte_1chip.json",
-                   "perfbench/configs/command_a_plus_1chip.json")
+                   "perfbench/configs/command_a_plus_1chip.json",
+                   "perfbench/configs/pangu_ultra_moe_1chip.json")
 
 # name = shape opcode(...: a tuple shape has spaces, no " word(" inside it
 _INSTR = re.compile(r"^\s*(?:ROOT )?(%?[\w.\-]+) = (.+?) ([a-z][\w\-]*)\(")
@@ -144,9 +145,10 @@ def programs(config, sharding=None):
                           arch.param_shapes(model))
     cache = jax.eval_shape(lambda: BlockedKVCache(
         cfg.num_hidden_layers, int(eng["num_blocks"]), bs,
-        cfg.num_key_value_heads, cfg.head_dim, dtype=jnp.bfloat16,
-        window_size=cfg.window_size if eva else 0,
-        chunk_size=cfg.chunk_size if eva else 0).layers)
+        cfg.num_key_value_heads, getattr(cfg, "head_dim", 0),
+        dtype=jnp.bfloat16, window_size=cfg.window_size if eva else 0,
+        chunk_size=cfg.chunk_size if eva else 0,
+        latent_dim=getattr(cfg, "kv_latent_dim", 0)).layers)
     cache = jax.tree.map(lambda s: sds(s.shape, s.dtype), cache)
     maxb = 64                       # the block table's width moves no page
     i32 = lambda *shape: sds(shape, jnp.int32)
