@@ -11,9 +11,17 @@ stream across iterations automatically (chunked prefill).
 Differences from the reference, by TPU design:
   * scheduling quantum = token budget (static shapes for XLA), not CUDA-graph
     atoms;
-  * the engine is synchronous per step (``schedule_step``); serving loops
-    (MII analog) call it in a thread.
+  * ``schedule_step`` is synchronous (launch the step, fetch its tokens);
+    its two halves are ``launch_step`` and ``collect_step``, and a serving
+    loop (``serving/scheduler.py``, the MII analog) launches the NEXT step
+    between them, so that the device holds a queued program when the
+    running one ends.  A row whose id the host has not fetched yet (the
+    token the step in flight chooses) is a count on the host,
+    ``seq.owed``, and takes its id on the device (``_take_chosen``).
 """
+
+import dataclasses
+import functools
 
 import numpy as np
 
@@ -33,10 +41,41 @@ from .ragged_forward import RAGGED_FORWARDS
 
 @jax.jit
 def _tokens_and_counts(logits, counts):
-    """A greedy step's tokens with the device's counts behind them: one
-    array, one transfer."""
-    return jnp.concatenate([jnp.argmax(logits, axis=-1).astype(jnp.int32),
-                            counts])
+    """A greedy step's tokens with the device's counts behind them (one
+    array, one transfer; no counts for a model that makes none), and the
+    tokens alone for the step after it."""
+    toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return jnp.concatenate([toks, counts]), toks
+
+
+@functools.partial(jax.jit, static_argnames=("k", "n"))
+def _burst_last_tokens(toks_out, *, k, n):
+    """The ``[n]`` tokens of a burst's last iteration, of its output as the
+    host fetches it (``[k, n]``, or flat with the device's counts behind)."""
+    return toks_out.reshape(-1)[(k - 1) * n:k * n]
+
+
+@jax.jit
+def _take_chosen(toks, take, chosen):
+    """The ids of a batch whose newest tokens the host has not seen: row
+    ``i`` with ``take[i] > 0`` gets the token the step before chose for slot
+    ``take[i]`` (slot 0 is no sequence's)."""
+    return jnp.where(take > 0, chosen[take], toks)
+
+
+@dataclasses.dataclass
+class LaunchedStep:
+    """A step the device has been given and whose tokens the host has not
+    fetched: what ``launch_step`` / ``launch_burst`` return and
+    ``collect_step`` takes."""
+    seqs: list            # the sequences whose next token(s) it chooses
+    fetch: object         # the device array ``collect_step`` transfers
+    counts: dict          # its ``last_step_counts``
+    burst_k: int = 0      # 0: a ragged step
+    device_counts: object = None   # the device's counts, where not in fetch
+    #: the host's sampling options (then ``fetch`` holds logits rows, and the
+    #: tokens are not known on the device: nothing may run ahead of it)
+    sample: tuple = None
 
 
 class InferenceEngineV2:
@@ -64,6 +103,10 @@ class InferenceEngineV2:
         self._device_counts = getattr(self._step_fn, "step_counts", ())
         self._no_counts = np.zeros(len(self._device_counts), np.int32)
         self._counts_owed = self._no_counts
+        #: the tokens the newest launched step chose, one a slot, on the
+        #: device (None: the host chooses, or the step finished no sequence)
+        self._chosen = None
+        self._uncollected = 0       # launched steps not yet collected
         if params is None:
             raise ValueError("InferenceEngineV2 needs params")
         self.params = jax.tree_util.tree_map(jnp.asarray, params)
@@ -271,10 +314,16 @@ class InferenceEngineV2:
         """Pack the token budget: decode tokens first (latency), then
         prefill chunks (throughput) — the reference scheduler's policy.
         The rows of a sequence are contiguous, their positions consecutive;
-        the rows past the last sequence's are dead (slot 0)."""
+        the rows past the last sequence's are dead (slot 0).
+
+        A sequence whose newest token a step in flight is choosing
+        (``seq.owed``) gets its row all the same: position and slot are
+        counts, and ``take`` names the slot whose chosen token is the row's
+        id (``_take_chosen`` fills it in on the device)."""
         T = self._budget
         sm = self.state_manager
         toks = np.zeros(T, np.int32)
+        take_from = np.zeros(T, np.int32)   # > 0: the id is on the device
         pos = np.zeros(T, np.int32)
         slots = np.zeros(T, np.int32)  # slot 0 → garbage block
         finishing = []  # (seq, buffer index of its last scheduled token)
@@ -285,16 +334,16 @@ class InferenceEngineV2:
 
         cur = 0                        # the next free buffer row
         order = sorted(sm.tracked_sequences.values(),
-                       key=lambda s: len(s.pending()))
+                       key=lambda s: s.n_pending)
         for seq in order:
             if seq.done:
                 continue
-            pending = seq.pending()
-            if not pending:
+            n_pending = seq.n_pending
+            if not n_pending:
                 continue
             if cur >= T:
                 break
-            take = min(len(pending), T - cur)
+            take = min(n_pending, T - cur)
             # KV-pool pressure: schedule only what the free blocks can hold
             # (the reference scheduler's deferral; a dry pool must not crash
             # the step — blocks free as other sequences flush)
@@ -309,15 +358,18 @@ class InferenceEngineV2:
                     - len(seq.blocks))
                 continue
             sm.ensure_capacity(seq, seq.seen_tokens + take)
-            toks[cur:cur + take] = pending[:take]
+            known = seq.pending()[:take]
+            toks[cur:cur + len(known)] = known
+            if take > len(known):       # the last of them is still owed
+                take_from[cur + take - 1] = seq.slot
             pos[cur:cur + take] = np.arange(
                 seq.seen_tokens, seq.seen_tokens + take)
             slots[cur:cur + take] = seq.slot
-            if take == len(pending):
+            if take == n_pending:
                 finishing.append((seq, cur + take - 1))
             seq.seen_tokens += take
             placed += take
-            if len(pending) == 1:
+            if n_pending == 1:
                 placed_decode += 1
             cur += take
         if placed == 0:
@@ -341,7 +393,7 @@ class InferenceEngineV2:
             "live_tokens": placed, "decode_tokens": placed_decode,
             "prefill_tokens": placed - placed_decode,
             **self._page_counts(pos, slots), "burst_k": 0}
-        return toks, pos, slots, last_idx, finishing
+        return toks, pos, slots, last_idx, finishing, take_from
 
     def _table_snapshot(self):
         """The block table as the launched program is to see it.  A COPY:
@@ -491,7 +543,30 @@ class InferenceEngineV2:
         ``rng`` may be a ``np.random.Generator`` or a seed; either way the
         Generator is created once and advances across tokens and steps (a
         seed re-seeded per token would sample identical draws every time).
+
+        ``launch_step`` and ``collect_step`` in one call: the serial
+        spelling.  A loop that has other work for the host calls the two
+        itself, and launches the next step between them
+        (``serving/scheduler.py``).
         """
+        step = self.launch_step(do_sample=do_sample, temperature=temperature,
+                                rng=rng, top_k=top_k, top_p=top_p)
+        return {} if step is None else self.collect_step(step)
+
+    def launch_step(self, do_sample=False, temperature=1.0, rng=None,
+                    top_k=0, top_p=1.0):
+        """The first half of ``schedule_step``: build the batch, give the
+        device its program and, BEHIND it and before anything else, the small
+        array the host will fetch (a greedy step's tokens, with the device's
+        counts; the finishing rows' logits when the host samples).  The
+        device's queue is in order: enqueued here, that array is ready when
+        this step ends, whatever is launched after it.  Returns a
+        :class:`LaunchedStep`, or None when no sequence has a token to run.
+
+        At most ONE step may be launched on top of an uncollected one, and a
+        greedy one at that: its rows may need the tokens the step before it
+        chose, which then are taken on the device (``_take_chosen``)."""
+        self._check_depth()
         if do_sample:
             if isinstance(rng, np.random.Generator):
                 self._rng = rng
@@ -505,10 +580,13 @@ class InferenceEngineV2:
         with _telemetry.scope(_names.SERVE_BUILD_BATCH):
             batch = self._build_batch()
         if batch is None:
-            return {}
-        toks, pos, slots, last_idx, finishing = batch
+            return None
+        toks, pos, slots, last_idx, finishing, take_from = batch
+        seqs = [seq for seq, _ in finishing]
+        step = LaunchedStep(seqs, None, self.last_step_counts)
         with _telemetry.scope(_names.SERVE_LAUNCH):
-            step_args = (self.params, self._kv, jnp.asarray(toks),
+            step_args = (self.params, self._kv,
+                         self._ids_on_device(toks, take_from),
                          jnp.asarray(pos), jnp.asarray(slots),
                          self._table_snapshot(),
                          jnp.asarray(last_idx))
@@ -524,46 +602,105 @@ class InferenceEngineV2:
                 cost_model.capture_jit_call(
                     "serve/ragged_step", self._step_fn, step_args, step_kw)
             logits, self._kv, *counted = self._step_fn(*step_args, **step_kw)
-        self._count_cache(pos, slots)
-        out = {}
-        if counted:                # no wait: an addition queued on the device
-            self._counts_owed = self._counts_owed + counted[0]
-        if finishing:
-            # the fetch is the one place the host waits for the device
-            if do_sample:
-                # fetch ONLY the finishing rows ([F, V]), not every slot
-                slots_f = jnp.asarray([seq.slot for seq, _ in finishing])
-                with _telemetry.scope(_names.SERVE_FETCH):
-                    lg = np.asarray(logits[slots_f])
-                    if counted:
-                        self._book_device_counts(np.asarray(
-                            self._counts_owed))
-                for i, (seq, _) in enumerate(finishing):
-                    out[seq.uid] = self._sample_row(
-                        lg[i], temperature, top_k, top_p, self._rng)
-            else:
+            if counted:            # no wait: an addition queued on the device
+                self._counts_owed = self._counts_owed + counted[0]
+            self._chosen = None
+            if seqs and do_sample:
+                # ONLY the finishing rows ([F, V]), not every slot
+                step.fetch = logits[jnp.asarray([seq.slot for seq in seqs])]
+                step.sample = (temperature, top_k, top_p)
+                if counted:
+                    step.device_counts = self._counts_owed
+            elif seqs:
                 # greedy: argmax on device, fetch one int per slot instead
                 # of [max_seqs, V] logits (the per-step device→host tax on
-                # a decode loop)
-                with _telemetry.scope(_names.SERVE_FETCH):
-                    if counted:     # the counts ride back with the tokens
-                        toks = self._book_device_counts(np.asarray(
-                            _tokens_and_counts(logits, self._counts_owed)))
-                    else:
-                        toks = np.asarray(jnp.argmax(logits, axis=-1))
-                for seq, _ in finishing:
-                    out[seq.uid] = int(toks[seq.slot])
+                # a decode loop); the counts ride back with the tokens
+                step.fetch, self._chosen = _tokens_and_counts(
+                    logits, self._counts_owed)
+            if seqs:
+                self._counts_owed = self._no_counts
+        for seq in seqs:
+            seq.owed += 1
+        self._count_cache(pos, slots)
+        self._uncollected += 1
+        return step
+
+    def collect_step(self, step):
+        """The second half: fetch what a launched step (or burst) chose, the
+        one place the host waits for the device.  Returns ``{uid: token}``
+        (a burst: ``{uid: [tokens]}``, which it also appends to the
+        sequences' histories, as ``burst_decode`` always did); the caller
+        appends a ragged step's token to ``seq.tokens`` if decode goes on.
+        A sequence flushed since the launch gets nothing.  Steps are
+        collected in the order they were launched."""
+        self._uncollected -= 1
+        if step.fetch is None:
+            return {}
+        with _telemetry.scope(_names.SERVE_FETCH):
+            fetched = np.asarray(step.fetch)
+            if step.device_counts is not None:
+                self._book_device_counts(np.asarray(step.device_counts), step)
+            elif step.sample is None:
+                fetched = self._book_device_counts(fetched, step)
+        sm, k = self.state_manager, step.burst_k
+        if k:
+            fetched = fetched.reshape(k, sm.max_seqs)
+        out = {}
+        for i, seq in enumerate(step.seqs):
+            if sm.get_sequence(seq.uid) is not seq:
+                continue            # flushed while the step was in flight
+            seq.owed -= k or 1
+            if k:
+                # k tokens scheduled on device: t0 (the pending one) + the
+                # k-1 fed-back generations; the newest generation is left
+                # pending for the next round
+                out[seq.uid] = [int(t) for t in fetched[:, seq.slot]]
+                seq.tokens.extend(out[seq.uid])
+            elif step.sample is None:
+                out[seq.uid] = int(fetched[seq.slot])
+            else:
+                out[seq.uid] = self._sample_row(fetched[i], *step.sample,
+                                                self._rng)
         return out
 
-    def _book_device_counts(self, fetched):
-        """Put the device's counts (the step program's ``step_counts``: this
-        step's and those of the steps before it that fetched nothing), the
-        last entries of ``fetched``, among ``last_step_counts``; returns
-        what stands before them (the step's tokens, if any)."""
+    @property
+    def launches_programs(self):
+        """Whether a launch only enqueues: the step function is the compiled
+        program the engine registered.  A Python callable put in its place
+        (a hook that reads each step's output as it is made) pairs "the
+        newest call" with "the tokens just returned", which holds in the
+        serial order alone: a scheduler does not run ahead of one."""
+        return isinstance(self._step_fn, jax.stages.Wrapped)
+
+    def _check_depth(self):
+        if self._uncollected > 1:
+            raise RuntimeError(
+                f"{self._uncollected} launched steps are uncollected: "
+                "collect_step the older one before launching another (the "
+                "device holds the tokens of ONE step back)")
+
+    def _ids_on_device(self, toks, take_from):
+        """A batch's token ids as the program takes them: the host's, with
+        the rows ``take_from`` names filled in from what the step in flight
+        chose (one small program queued before the step's)."""
+        ids = jnp.asarray(toks)
+        if take_from.any():
+            ids = _take_chosen(ids, jnp.asarray(take_from), self._chosen)
+        return ids
+
+    def _book_device_counts(self, fetched, step):
+        """Put the device's counts (the step program's ``step_counts``:
+        ``step``'s and those of the steps before it that fetched nothing),
+        the last entries of ``fetched``, among ``last_step_counts``: the
+        NEWEST launched step's, which is ``step``'s own unless another was
+        launched on top of it (they ride with the next fetch, and add).
+        Returns what stands before them (the step's tokens, if any)."""
         cut = len(fetched) - len(self._device_counts)
-        self.last_step_counts.update(
-            zip(self._device_counts, map(int, fetched[cut:])))
-        self._counts_owed = self._no_counts
+        if self.last_step_counts is None:
+            self.last_step_counts = step.counts
+        for name, count in zip(self._device_counts, fetched[cut:]):
+            self.last_step_counts[name] = \
+                self.last_step_counts.get(name, 0) + int(count)
         return fetched[:cut]
 
     # ---------------------------------------------------------- decode burst
@@ -579,7 +716,7 @@ class InferenceEngineV2:
         seqs = []
         for uid in active_uids:
             seq = sm.get_sequence(uid)
-            if len(seq.tokens) - seq.seen_tokens != 1:
+            if seq.n_pending != 1:
                 return None
             seqs.append(seq)
         if not seqs:
@@ -588,8 +725,9 @@ class InferenceEngineV2:
                          for s in seqs))
         if k < 2:
             return None
-        return self._run_burst(seqs, k, sample, temperature, top_k, top_p,
-                               seed)
+        step = self._launch_burst(seqs, k, sample, temperature, top_k, top_p,
+                                  seed)
+        return None if step is None else self.collect_step(step)
 
     def burst_decode(self, uids=None, max_tokens=16, do_sample=False,
                      temperature=1.0, top_k=0, top_p=1.0, rng=None):
@@ -599,7 +737,20 @@ class InferenceEngineV2:
         return ``{uid: [tokens]}``.  Requires every targeted sequence to be
         in pure decode (exactly one pending token) — raises otherwise, so a
         scheduler can fall back to ``schedule_step``.  Sampling uses the
-        device PRNG path (seed-deterministic; pass ``rng`` as a seed)."""
+        device PRNG path (seed-deterministic; pass ``rng`` as a seed).
+        ``launch_burst`` and ``collect_step`` in one call."""
+        step = self.launch_burst(uids, max_tokens, do_sample, temperature,
+                                 top_k, top_p, rng)
+        return {} if step is None else self.collect_step(step)
+
+    def launch_burst(self, uids=None, max_tokens=16, do_sample=False,
+                     temperature=1.0, top_k=0, top_p=1.0, rng=None):
+        """``burst_decode``'s first half, as ``launch_step`` is
+        ``schedule_step``'s: returns a :class:`LaunchedStep`, or None when
+        there is nothing to run or the pool cannot afford a burst.  A
+        sequence's one pending token may be the one a step in flight is
+        choosing."""
+        self._check_depth()
         sm = self.state_manager
         if uids is None:
             uids = [s.uid for s in sm.tracked_sequences.values()
@@ -609,10 +760,10 @@ class InferenceEngineV2:
             seq = sm.get_sequence(uid)
             if seq is None or seq.done:
                 raise ValueError(f"uid {uid!r} is not an active sequence")
-            if len(seq.tokens) - seq.seen_tokens != 1:
+            if seq.n_pending != 1:
                 raise ValueError(
                     f"uid {uid!r} is not in pure decode "
-                    f"({len(seq.pending())} pending tokens) — run "
+                    f"({seq.n_pending} pending tokens) — run "
                     "schedule_step until prefill drains")
             seqs.append(seq)
         k = int(max_tokens)
@@ -620,16 +771,17 @@ class InferenceEngineV2:
         if cap > 1:   # an explicit call may exceed a DISABLED config, not
             k = min(k, cap)   # a configured cap
         if not seqs or k < 2:
-            return {}
+            return None
         if do_sample and isinstance(rng, np.random.Generator):
             raise ValueError("burst_decode sampling needs a seed, not a "
                              "numpy Generator (device PRNG stream)")
-        # None = the KV pool can't afford a burst right now → empty result;
-        # the caller's schedule_step path defers until blocks free
-        return self._run_burst(seqs, k, do_sample, temperature,
-                               top_k, top_p, rng) or {}
+        # None = the KV pool can't afford a burst right now; the caller's
+        # schedule_step path defers until blocks free
+        return self._launch_burst(seqs, k, do_sample, temperature, top_k,
+                                  top_p, rng)
 
-    def _run_burst(self, seqs, k, sample, temperature, top_k, top_p, seed):
+    def _launch_burst(self, seqs, k, sample, temperature, top_k, top_p,
+                      seed):
         sm = self.state_manager
         # KV-pool pressure: a burst pre-allocates k positions per sequence
         # from the SHARED free pool — shrink k until the total new-block
@@ -655,11 +807,15 @@ class InferenceEngineV2:
         n = sm.max_seqs
         with _telemetry.scope(_names.SERVE_BUILD_BATCH):
             tok0 = np.zeros(n, np.int32)
+            take_from = np.zeros(n, np.int32)
             pos0 = np.zeros(n, np.int32)
             act = np.zeros(n, bool)
             for seq in seqs:
                 sm.ensure_capacity(seq, seq.seen_tokens + k)
-                tok0[seq.slot] = seq.tokens[seq.seen_tokens]
+                if seq.pending():
+                    tok0[seq.slot] = seq.tokens[seq.seen_tokens]
+                else:               # a step in flight is choosing it
+                    take_from[seq.slot] = seq.slot
                 pos0[seq.slot] = seq.seen_tokens
                 act[seq.slot] = True
             # k iterations over max_seqs rows each, one token a live row:
@@ -668,6 +824,7 @@ class InferenceEngineV2:
             slots_k = np.broadcast_to(np.where(act, np.arange(n), 0), (k, n))
             for seq in seqs:        # as the cache stands when the burst ends
                 seq.seen_tokens += k
+                seq.owed += k
             self.last_step_counts = {
                 "kind": _names.KIND_BURST, "token_budget": n * k,
                 "live_tokens": len(seqs) * k,
@@ -683,7 +840,8 @@ class InferenceEngineV2:
         else:
             key = None
         with _telemetry.scope(_names.SERVE_LAUNCH):
-            burst_args = (self.params, self._kv, jnp.asarray(tok0),
+            burst_args = (self.params, self._kv,
+                          self._ids_on_device(tok0, take_from),
                           jnp.asarray(pos0), jnp.asarray(act),
                           self._table_snapshot())
             burst_kw = dict(step_fn=self._step_fn, cfg=self.model_config,
@@ -694,6 +852,7 @@ class InferenceEngineV2:
                             kv_dtype=self._kv_dtype)
             if self._device_counts:
                 burst_kw["counts0"] = self._counts_owed
+                self._counts_owed = self._no_counts
             from ...profiling import cost_model
             if cost_model.capturing():
                 # k is static (pow2-quantized above), so the burst variants
@@ -701,22 +860,14 @@ class InferenceEngineV2:
                 cost_model.capture_jit_call(
                     f"serve/decode_burst[k={k}]", decode_burst, burst_args,
                     burst_kw, meta={"k": int(k)})
+            # ONE fetch for k×seqs tokens and the counts behind them; the
+            # last iteration's tokens stay for the step after this one
             toks_out, self._kv = decode_burst(*burst_args, **burst_kw)
+            self._chosen = _burst_last_tokens(toks_out, k=k, n=n)
         self._count_cache(pos_k, slots_k)
-        with _telemetry.scope(_names.SERVE_FETCH):
-            toks_out = np.asarray(toks_out)  # ONE fetch for k×seqs tokens
-            if self._device_counts:          # and the counts behind them
-                toks_out = self._book_device_counts(toks_out).reshape(k, n)
         self.burst_steps = getattr(self, "burst_steps", 0) + 1
-        out = {}
-        for seq in seqs:
-            # k tokens scheduled on device: t0 (the pending one) + the k-1
-            # fed-back generations; invariant len(tokens) == seen + 1 holds
-            # with the newest generation left pending for the next round
-            col = toks_out[:, seq.slot]
-            seq.tokens.extend(int(t) for t in col)
-            out[seq.uid] = [int(t) for t in col]
-        return out
+        self._uncollected += 1
+        return LaunchedStep(seqs, toks_out, self.last_step_counts, burst_k=k)
 
     # ------------------------------------------------------------- generate
     def _mark_done(self, uid, produced, tok, eos_token_id, max_new_tokens):

@@ -98,6 +98,9 @@ class DSSequenceDescriptor:
     slot: int                       # row in the block table
     tokens: List[int] = field(default_factory=list)  # full token history
     seen_tokens: int = 0            # tokens already in the KV cache
+    #: tokens that launched steps choose on the device and the host has not
+    #: fetched: owed to ``tokens``, counted, their values unknown
+    owed: int = 0
     blocks: List[int] = field(default_factory=list)   # every block held
     done: bool = False
     # window-plus-summary caches only: ``blocks`` by kind
@@ -110,8 +113,14 @@ class DSSequenceDescriptor:
         return len(self.tokens)
 
     def pending(self):
-        """Token ids not yet through the model."""
+        """Token ids not yet through the model (the known ones)."""
         return self.tokens[self.seen_tokens:]
+
+    @property
+    def n_pending(self):
+        """How many tokens are not yet through the model: the known ones and
+        the one a step in flight is choosing."""
+        return len(self.tokens) + self.owed - self.seen_tokens
 
 
 class BlockedKVCache:

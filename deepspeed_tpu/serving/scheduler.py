@@ -24,6 +24,13 @@ layer adds over the raw engine:
   it held (``prefill`` / ``decode`` / ``mixed`` in the recorder's phase
   column), and fuses multi-token decode bursts when every in-flight
   sequence is in pure decode;
+* **a turn that runs one step ahead of the device** — ``step`` launches
+  engine step n+1 before it fetches step n's tokens
+  (``launch_step`` / ``collect_step``), so the host's turn (callbacks,
+  admission, the batch builder, the launch) runs beside a step and not
+  between two; the decode rows' ids are taken on the device, an end by EOS
+  is found one step late and its stale row dropped, and where the host
+  draws the tokens the same loop collects before it launches;
 * **streaming** — per-token ``on_token(token, done)`` callbacks as tokens
   are produced, not when the request completes;
 * **observability + health** — per-request TTFT/TBT histograms,
@@ -75,6 +82,11 @@ class ServingScheduler:
         self.completed = 0
         self.tokens_generated = 0
         self.peak_running = 0          # max concurrently admitted sequences
+        self.steps_launched_ahead = 0  # engine steps launched on top of one
+        # (LaunchedStep, its launch time): given to the device, its tokens
+        # not yet fetched; at most one between turns
+        self._in_flight = None
+        self._t_collected = 0.0        # when the newest fetch returned
         # in-flight cap: the engine has max_seqs slots, slot 0 reserved
         self._max_concurrent = min(
             int(config.max_concurrent),
@@ -142,8 +154,8 @@ class ServingScheduler:
         for uid in self._running:
             seq = sm.get_sequence(uid)
             total += max(0, self.engine.kv_cache.peak_blocks_for(
-                len(seq.tokens) + int(reserve), start=seq.seen_tokens)
-                - len(seq.blocks))
+                len(seq.tokens) + seq.owed + int(reserve),
+                start=seq.seen_tokens) - len(seq.blocks))
         return total
 
     def _admit(self):
@@ -202,10 +214,12 @@ class ServingScheduler:
         return True
 
     # ----------------------------------------------------------------- steps
-    def _try_burst(self):
-        """Fused multi-token decode when EVERY in-flight sequence is in
-        pure decode (same eligibility as ``generate``'s burst path).
-        Returns {uid: [tokens]} or None (ineligible / pool too tight)."""
+    def _launch_burst(self):
+        """Launch a fused multi-token decode when EVERY in-flight sequence
+        is in pure decode (same eligibility as ``generate``'s burst path).
+        Eligibility and ``k`` are counts: a sequence's one pending token may
+        be the one the step in flight is choosing.  Returns the engine's
+        :class:`LaunchedStep` or None (ineligible / pool too tight)."""
         cap = int(self.engine._config.decode_burst or 0)
         if cap < 2 or not self._running:
             return None
@@ -215,29 +229,45 @@ class ServingScheduler:
                 and cfg.seed is not None):
             return None   # host-RNG sampling keeps the per-step loop
         sm = self.engine.state_manager
-        k = cap
+        k, uids = cap, []
         for req in self._running.values():
             seq = sm.get_sequence(req.uid)
-            if len(seq.tokens) - seq.seen_tokens != 1:
+            if seq.done:
+                continue    # its last token is in flight: no further row
+            if seq.n_pending != 1:
                 return None
-            k = min(k, req.remaining_tokens)
-        if k < 2:
+            k = min(k, req.remaining_tokens - seq.owed)
+            uids.append(req.uid)
+        if k < 2 or not uids:
             return None
-        out = self.engine.burst_decode(
-            list(self._running), max_tokens=k, do_sample=cfg.do_sample,
+        return self.engine.launch_burst(
+            uids, max_tokens=k, do_sample=cfg.do_sample,
             temperature=cfg.temperature, top_k=cfg.top_k, top_p=cfg.top_p,
             rng=cfg.seed)
-        return out or None
 
     def step(self):
-        """One scheduler iteration: admit → run one engine step (preempting
-        under KV exhaustion) → stream tokens.  Returns {uid: [tokens]}
-        emitted this step (empty when idle).
+        """One scheduler turn: admit → build and LAUNCH the next engine step
+        (preempting under KV exhaustion) → collect the step launched in the
+        turn before → stream its tokens.  The host runs one step ahead of
+        the device, which then always holds a queued program when the
+        running one ends.  Returns {uid: [tokens]} streamed in this turn
+        (empty when idle, and in the first turn after an idle scheduler).
 
-        A working step is one ``ds:serve.step`` span in any profiler capture
+        What runs ahead follows from where tokens are chosen.  Chosen on
+        the device (greedy; a burst's device-PRNG sampling), the next step
+        takes them there (``engine_v2._take_chosen``) and the host fetches
+        them a turn later.  Drawn by the host (``do_sample`` through the
+        ragged step), a step is collected in the turn that launched it, and
+        nothing is in flight while the host draws.  What cannot be known
+        ahead is handled one step late, never guessed: a request that ends
+        by EOS has one stale row in the step in flight, whose token is
+        dropped; one that ends by length gets no row past its last token.
+
+        A working turn is one ``ds:serve.step`` span in any profiler capture
         (``telemetry/names.py``), with the children admit / build_batch /
         launch / fetch / dispatch and, as its counts, what the engine step
-        held (``InferenceEngineV2.last_step_counts``)."""
+        LAUNCHED in it held (``InferenceEngineV2.last_step_counts``) and
+        ``launched_ahead``; its fetch is the wait for the step before."""
         self._step_index += 1
         if self._heartbeat is not None:
             self._heartbeat.beat(self._step_index)
@@ -250,26 +280,36 @@ class ServingScheduler:
                              step=self._step_index) as span:
             with telemetry.scope(names.SERVE_ADMIT):
                 self._admit()
-            emitted = self._run_step(span) if self._running else {}
+            emitted = {}
+            if self._running or self._in_flight is not None:
+                self._run_step(span, emitted)
         self._export_gauges(n_tokens=sum(len(v) for v in emitted.values()))
         return emitted
 
-    def _run_step(self, span):
-        t_launch = self._clock()     # before the engine call — _dispatch
-        preempts = 0                 # amortizes burst wall time over tokens
+    def _run_step(self, span, emitted):
+        cfg = self.config
+        preempts = 0
         while True:
+            ahead = self._in_flight is not None
             try:
-                burst = self._try_burst()
-                if burst is not None:
-                    results = burst
-                else:
-                    cfg = self.config
-                    results = self.engine.schedule_step(
+                step = self._launch_burst()
+                if step is None and ahead and cfg.do_sample:
+                    # the host draws this step's tokens: today's draws in
+                    # today's order, so nothing is in flight while it does
+                    self._collect(emitted)
+                    continue
+                if step is None:
+                    step = self.engine.launch_step(
                         do_sample=cfg.do_sample,
                         temperature=cfg.temperature, top_k=cfg.top_k,
                         top_p=cfg.top_p, rng=cfg.seed)
                 break
             except KVCacheExhausted as e:
+                if ahead:
+                    # what is in flight may end requests and return their
+                    # blocks, and a victim never has an unfetched token
+                    self._collect(emitted)
+                    continue
                 preempts += 1
                 if preempts > int(self.config.max_preemptions_per_step) \
                         or not self._preempt_one():
@@ -279,16 +319,58 @@ class ServingScheduler:
                         "request needs more blocks than the pool holds "
                         "(raise state_manager.num_blocks or lower "
                         "max_context)") from e
-        counts = self.engine.last_step_counts or {}
+        t_launched = self._clock()
+        counts = step.counts if step is not None else {}
+        held = dict(running=len(self._running), queued=len(self._queue))
+        if ahead:
+            self._collect(emitted)      # the step launched the turn before
+        if step is not None:
+            self._in_flight = (step, t_launched)
+            self.steps_launched_ahead += ahead
+            if ahead and telemetry.enabled:
+                telemetry.counter("serving/steps_launched_ahead",
+                                  help="engine steps launched while the one "
+                                  "before was still unfetched").inc()
+            for seq in step.seqs:
+                req = self._running.get(seq.uid)
+                if req is not None and req.remaining_tokens <= seq.owed:
+                    # ends by length with the tokens in flight (a count, not
+                    # a guess): no row past its last token
+                    seq.done = True
+        if step is not None and not (
+                step.sample is None and self.engine.launches_programs
+                and self._has_more_to_launch()):
+            # nothing can run ahead of it (the host draws its tokens), or
+            # nothing is left to: the turn collects its own step
+            self._collect(emitted)
         # the recorder's phase column keeps its prefill|decode|mixed name,
         # now derived from what the step really held
         phase = ("mixed" if counts.get("prefill_tokens")
                  and counts.get("decode_tokens") else
                  "prefill" if counts.get("prefill_tokens") else "decode")
-        span.set(phase=phase, running=len(self._running),
-                 queued=len(self._queue), preempts=preempts, **counts)
+        span.set(phase=phase, preempts=preempts, launched_ahead=int(ahead),
+                 **held, **counts)
+
+    def _has_more_to_launch(self):
+        """Whether a next turn would find rows to run: a running request
+        that does not end with the tokens in flight.  (A queued one waits
+        for what the running ones hold: the sooner they are collected, the
+        sooner it is admitted.)"""
+        sm = self.engine.state_manager
+        return any(not sm.get_sequence(uid).done for uid in self._running)
+
+    def _collect(self, emitted):
+        """Fetch the tokens of the step in flight and stream them; adds to
+        ``emitted``."""
+        (step, t_launch), self._in_flight = self._in_flight, None
+        results = self.engine.collect_step(step)
+        # the device took the step up when it was launched, or when the one
+        # before it ended: what _dispatch amortizes a burst's tokens over
+        t_start, self._t_collected = max(t_launch, self._t_collected), \
+            self._clock()
         with telemetry.scope(names.SERVE_DISPATCH):
-            return self._dispatch(results, t_launch)
+            for uid, toks in self._dispatch(results, t_start).items():
+                emitted.setdefault(uid, []).extend(toks)
 
     def _dispatch(self, results, t_launch=None):
         """Book engine output into request records: streaming callbacks,
@@ -387,8 +469,9 @@ class ServingScheduler:
     # ----------------------------------------------------------- convenience
     @property
     def idle(self):
-        """No queued and no running work."""
-        return not self._queue and not self._running
+        """No queued and no running work, and no step in flight."""
+        return not self._queue and not self._running \
+            and self._in_flight is None
 
     def drain(self, max_steps=100_000):
         """Step until every submitted request completes."""

@@ -31,12 +31,21 @@ TRAIN_ACCUMULATE = "train.accumulate"     # fold grads into the accumulator
 TRAIN_APPLY = "train.apply"               # call of the optimizer program
 TRAIN_REPORT = "train.report"             # metrics, scheduler, hooks
 
-# ---- serving host spans
-SERVE_STEP = "serve.step"                 # one ServingScheduler.step
+# ---- serving host spans.  A scheduler turn launches the next engine step
+# and THEN fetches the one launched the turn before (serving/scheduler.py):
+# a ``serve.step`` carries the counts of the step it LAUNCHED, and the
+# ``serve.fetch`` inside it is the wait for the step before that one
+SERVE_STEP = "serve.step"                 # one ServingScheduler.step (a turn)
 SERVE_ADMIT = "serve.admit"               # admission gate
 SERVE_BUILD_BATCH = "serve.build_batch"   # pack the token budget (numpy)
-SERVE_LAUNCH = "serve.launch"             # host arrays -> device, jit call
-SERVE_FETCH = "serve.fetch"               # np.asarray of the tokens: the wait
+SERVE_LAUNCH = "serve.launch"             # host arrays -> device, the jit
+#                                           call, and the step's token array
+#                                           enqueued behind it
+SERVE_FETCH = "serve.fetch"               # np.asarray of a launched step's
+#                                           tokens: the one wait for the
+#                                           device (in a turn that runs ahead:
+#                                           for the step BEFORE the one it
+#                                           launched)
 SERVE_DISPATCH = "serve.dispatch"         # callbacks, lifecycle, flush
 # one short span per request event (count: uid)
 SERVE_ADMITTED = "serve.admitted"
@@ -58,6 +67,11 @@ SERVE_STEP_COUNTS = ("step", "kind", "running", "queued", "token_budget",
                      "live_tokens", "prefill_tokens", "decode_tokens",
                      "grid_pages", "live_pages", "row_pages", "short_pages",
                      "burst_k", "preempts",
+                     # 1: launched while the step before was unfetched (the
+                     # device had it queued when that one ended); 0: the
+                     # first step after an idle scheduler or an exhaustion,
+                     # and every step whose tokens the host draws
+                     "launched_ahead",
                      # what the cache holds, and what a window-plus-summary
                      # cache (EvaByte) did in the step
                      "context_tokens", "held_blocks", "block_size",

@@ -68,11 +68,12 @@ def test_the_ragged_step_is_the_dense_forward(burst):
 def test_a_step_counts_the_live_copies_on_the_device():
     """``expert_copies`` of each step is the count of (live row, expert)
     pairs whose expert is held, over all layers, as the model's own router
-    decides them; ``expert_active`` follows."""
+    decides them; ``expert_active`` follows.  (One new token: nothing is left
+    to launch after the step, so the turn that launched it fetches it.)"""
     model, params = _model()
     prompt = np.random.default_rng(1).integers(0, 256, 13).tolist()
     sched = _scheduler(model, params, 0, budget=32, sessions=1)
-    sched.submit(prompt, max_new_tokens=2)
+    sched.submit(prompt, max_new_tokens=1)
     sched.step()
     counts = sched.engine.last_step_counts
     assert counts["live_tokens"] == 13 and counts["token_budget"] == 32
@@ -88,32 +89,34 @@ def test_a_step_that_fetches_nothing_waits_for_nothing(burst, monkeypatch):
     token of theirs, so the host fetches NOTHING after them (it goes on to
     build the next batch while the device runs) and their counts stay on the
     device until the next tokens carry them back, added to that step's: a
-    ragged step's or a burst's."""
+    ragged step's or a burst's.  The scheduler runs one step ahead: the
+    tokens of the prompt's last chunk are fetched in the turn AFTER the one
+    that launched it, and the counts they carry stand on the span of the step
+    launched in that turn."""
     model, params = _model()
     prompt = np.random.default_rng(2).integers(0, 256, 40).tolist()
     sched = _scheduler(model, params, burst, budget=16, sessions=1)
-    sched.submit(prompt, max_new_tokens=9)
+    sched.submit(prompt, max_new_tokens=12)   # more than 1 + a burst of 8
     fetches = []
     real = np.asarray
     monkeypatch.setattr(
         np, "asarray", lambda a, *args, **kw: (
             fetches.append(1) if isinstance(a, jax.Array) else None,
             real(a, *args, **kw))[1])
-    booked = []
-    for chunk in range(3):                  # 16 + 16 + 8 rows
+    for turn in range(4):          # 16 + 16 + 8 rows, then a decode launch
         before = len(fetches)
         sched.step()
         counts = sched.engine.last_step_counts
-        booked.append(counts.get("expert_copies"))
         assert (len(fetches) - before, "expert_copies" in counts) == \
-            ((0, False) if chunk < 2 else (1, True)), chunk
+            ((0, False) if turn < 3 else (1, True)), turn
     monkeypatch.undo()
     per_layer = _routing_counts(model, params, prompt)
-    assert booked[2] == sum(c.sum() for c in per_layer)
-    sched.step()                            # a decode step or a burst
+    assert counts["expert_copies"] == sum(c.sum() for c in per_layer)
+    assert counts["burst_k"] == burst       # a decode step or a burst
+    live = counts["live_tokens"]
+    sched.step()                            # fetches THAT step's tokens
     counts = sched.engine.last_step_counts
-    assert counts["burst_k"] == burst
-    assert 0 < counts["expert_copies"] <= counts["live_tokens"] * 2 * 4
+    assert 0 < counts["expert_copies"] <= live * 2 * 4
 
 
 def _routing_counts(model, params, prompt):

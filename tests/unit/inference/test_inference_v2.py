@@ -158,7 +158,7 @@ def test_build_batch_more_pending_than_budget_gives_each_token_a_row():
     eng.put(uids[:10], [[int(t)] for t in rng.integers(1, 96, size=10)])
     eng.put([10], [rng.integers(1, 96, size=12).tolist()])
     before = _seen(eng, uids)
-    toks, pos, slots, last_idx, finishing = eng._build_batch()
+    toks, pos, slots, last_idx, finishing, _ = eng._build_batch()
     after = _seen(eng, uids)
     placed = sum(after[u] - before[u] for u in uids)
     assert int((slots != 0).sum()) == placed == 16
@@ -181,7 +181,7 @@ def test_build_batch_decodes_first_and_the_prompt_takes_the_rest():
     rng = np.random.default_rng(5)
     eng.put([0, 1, 2], [[int(t)] for t in rng.integers(1, 96, size=3)])
     eng.put([3], [rng.integers(1, 96, size=16).tolist()])
-    toks, pos, slots, last_idx, finishing = eng._build_batch()
+    toks, pos, slots, last_idx, finishing, _ = eng._build_batch()
     sm = eng.state_manager
     assert [sm.get_sequence(u).slot for u in (0, 1, 2)] == slots[:3].tolist()
     assert (slots[3:] == sm.get_sequence(3).slot).all()
@@ -192,7 +192,7 @@ def test_build_batch_decodes_first_and_the_prompt_takes_the_rest():
     # the next step holds the prompt's last three tokens only
     for u in (0, 1, 2):
         sm.get_sequence(u).done = True
-    toks, pos, slots, _, finishing = eng._build_batch()
+    toks, pos, slots, _, finishing, _ = eng._build_batch()
     assert pos[slots != 0].tolist() == [13, 14, 15]
     assert [seq.uid for seq, _ in finishing] == [3]
     eng.flush(range(4))

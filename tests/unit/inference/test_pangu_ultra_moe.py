@@ -185,7 +185,7 @@ def test_a_step_carries_the_latent_counts():
     model, params = _model()
     prompt = np.random.default_rng(1).integers(0, 256, 13).tolist()
     sched = _scheduler(model, params, 0, budget=32, sessions=1)
-    sched.submit(prompt, max_new_tokens=2)
+    sched.submit(prompt, max_new_tokens=1)     # so the turn fetches its step
     sched.step()
     counts = sched.engine.last_step_counts
     assert counts["live_tokens"] == 13 and counts["token_budget"] == 32

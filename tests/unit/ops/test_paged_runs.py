@@ -285,7 +285,7 @@ def test_batch_is_runs_of_consecutive_positions():
                        for n in (30, 1, 7, 29)])
     steps = 0
     while (batch := eng._build_batch()) is not None:
-        toks, pos, slots, _, _ = batch
+        toks, pos, slots, *_ = batch
         for s in set(slots[slots != 0].tolist()):
             rows = np.flatnonzero(slots == s)
             assert (np.diff(rows) == 1).all()

@@ -199,6 +199,76 @@ def test_serving_steps_in_a_real_trace(tiny, tmp_path):
     assert not hasattr(sched, "_phase")
 
 
+class _Spy:
+    """Records its calls, and is still a compiled program to whoever asks
+    (``jax.stages.Wrapped``: ``__call__``, ``lower``, ``trace``)."""
+
+    def __init__(self, fn, log, name):
+        self.fn, self.log, self.name = fn, log, name
+
+    def __call__(self, *args, **kw):
+        self.log.append(self.name)
+        return self.fn(*args, **kw)
+
+    def lower(self, *args, **kw):
+        return self.fn.lower(*args, **kw)
+
+    def trace(self, *args, **kw):
+        return self.fn.trace(*args, **kw)
+
+
+def test_a_turn_launches_the_next_step_before_it_fetches_the_last(
+        tiny, tmp_path, monkeypatch):
+    """The span contract of the run-ahead turn (ISSUE 36): one
+    ``ds:serve.step`` a LAUNCHED step, carrying that step's counts and
+    ``launched_ahead``; its one ``ds:serve.fetch`` is the wait for the step
+    before (none in the first turn; the last turn, with nothing left to
+    launch, fetches its own step too); ``live_tokens`` sums to the rows
+    computed.  And the order of the dispatches, the only guard on a CPU that
+    a fetch waits for its own step alone: a step's token array is enqueued
+    WITH the step, before the next step's programs, and fetched after
+    them."""
+    from deepspeed_tpu.inference.v2 import engine_v2
+    sched = _scheduler(tiny, decode_burst=0)
+    engine, log = sched.engine, []
+    engine._step_fn = _Spy(engine._step_fn, log, "step")
+    for name in ("_tokens_and_counts", "_take_chosen"):
+        monkeypatch.setattr(engine_v2, name,
+                            _Spy(getattr(engine_v2, name), log, name))
+    assert engine.launches_programs
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(1, 96, size=n).tolist() for n in (5, 9, 7)]
+    uids = [sched.submit(p, max_new_tokens=5) for p in prompts]
+    real = np.asarray
+    with Traced(tmp_path) as t:
+        monkeypatch.setattr(
+            np, "asarray", lambda a, *args, **kw: (
+                log.append("fetch") if isinstance(a, jax.Array) else None,
+                real(a, *args, **kw))[1])
+        turns = sched.drain()
+        monkeypatch.undo()
+    launch = ["_take_chosen", "step", "_tokens_and_counts"]
+    # five steps: the prompts, then four of three decode rows whose ids the
+    # device takes from the step before
+    assert turns == 5
+    assert log == launch[1:] + (launch + ["fetch"]) * 4 + ["fetch"]
+    steps = t.named(names.SERVE_STEP)
+    assert [s[3]["launched_ahead"] for s in steps] == [0, 1, 1, 1, 1]
+    assert sched.steps_launched_ahead == 4
+    fetches = t.named(names.SERVE_FETCH)
+    assert [sum(t.inside(f, s) for f in fetches) for s in steps] == \
+        [0, 1, 1, 1, 2]
+    for step in steps:
+        assert set(names.SERVE_STEP_COUNTS) <= set(step[3])
+        launched, = [e for e in t.named(names.SERVE_LAUNCH)
+                     if t.inside(e, step)]
+        assert all(launched[2] <= f[1] for f in fetches
+                   if t.inside(f, step))
+    assert [s[3]["live_tokens"] for s in steps] == [21, 3, 3, 3, 3]
+    assert [s[3]["decode_tokens"] for s in steps] == [0, 3, 3, 3, 3]
+    assert [len(sched.query(u).produced) for u in uids] == [5, 5, 5]
+
+
 def test_preemption_is_an_event_with_its_uid(tiny, tmp_path):
     sched = _scheduler(tiny, num_blocks=15, decode_burst=0)
     rng = np.random.default_rng(0)
@@ -363,9 +433,11 @@ def test_enabled_recorder_gets_the_serving_step_with_its_counts(
         sched.submit(list(range(1, 20)), max_new_tokens=3)
         sched.drain()
         events = rec.chrome_trace()["traceEvents"]
+        ahead = telemetry.counter("serving/steps_launched_ahead").value
     finally:
         telemetry.shutdown()
     steps = [e for e in events if e["cat"] == "serve"]
+    assert ahead == sum(e["args"]["launched_ahead"] for e in steps) == 2
     phases = [e["name"] for e in steps]
     assert phases[0] == "prefill" and phases[-2:] == ["decode", "decode"]
     assert set(phases) == {"prefill", "decode"}
