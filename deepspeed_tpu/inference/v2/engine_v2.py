@@ -72,7 +72,14 @@ class LaunchedStep:
     fetch: object         # the device array ``collect_step`` transfers
     counts: dict          # its ``last_step_counts``
     burst_k: int = 0      # 0: a ragged step
+    #: the engine's count of its launches when this one was made (from 1,
+    #: ragged steps and bursts in one sequence): the ``launch`` of every
+    #: span of the step's life (``telemetry/names.py``)
+    index: int = 0
     device_counts: object = None   # the device's counts, where not in fetch
+    #: how many launches' device counts the fetch brings: this step's and
+    #: those of the steps before it that fetched nothing (0: none to bring)
+    covers: int = 0
     #: the host's sampling options (then ``fetch`` holds logits rows, and the
     #: tokens are not known on the device: nothing may run ahead of it)
     sample: tuple = None
@@ -103,6 +110,9 @@ class InferenceEngineV2:
         self._device_counts = getattr(self._step_fn, "step_counts", ())
         self._no_counts = np.zeros(len(self._device_counts), np.int32)
         self._counts_owed = self._no_counts
+        self._launches_owed = 0    # launches whose counts _counts_owed holds
+        #: engine steps launched so far, ragged steps and bursts alike
+        self.launches = 0
         #: the tokens the newest launched step chose, one a slot, on the
         #: device (None: the host chooses, or the step finished no sequence)
         self._chosen = None
@@ -452,7 +462,7 @@ class InferenceEngineV2:
             return 0
         return self._kernel_loads(
             self._row_positions(pos), slots,
-            row_pages=pos // kv.window_size * kv.summary_blocks)[2]
+            row_pages=pos // kv.window_size * kv.summary_blocks)[1]
 
     def _row_positions(self, pos):
         """Positions inside the block-table row (``ragged.py``)."""
@@ -465,15 +475,14 @@ class InferenceEngineV2:
         """The page counts of a step's paged-attention calls over the rows at
         positions ``pos`` in slots ``slots`` (0: a dead row); ``[k, rows]``
         arrays are the ``k`` calls of a burst.  For a model whose layers read
-        alike, of ONE layer's call: ``grid_pages``, ``live_pages``,
-        ``short_pages``: the K/V page loads the kernel's loops perform and, of
-        those, the loads that hold a key some live row may see, and the loads
-        whose item computes one slab of rows and not its tile
+        alike, of ONE layer's call: ``grid_pages``, ``short_pages``: the K/V
+        page loads the kernel's loops perform and, of those, the loads whose
+        item computes one slab of rows and not its tile
         (``paged_attention.kernel_page_loads``, beside the kernels it
         describes); ``row_pages``: the (row, page) pairs the live rows'
         contexts (their sliding windows) span — ``row_pages / grid_pages``
         is how many rows share one page load.  For a model that states a
-        window a layer (``layer_windows``) the four are summed over ALL its
+        window a layer (``layer_windows``) the three are summed over ALL its
         layers' calls, and ``grid_pages_window`` / ``grid_pages_full`` are
         the loads of its window layers' and of its full layers' calls.
         For a latent cache also ``latent_keys``, the (live row, key) pairs
@@ -491,9 +500,8 @@ class InferenceEngineV2:
                     _names.COUNT_ABSORBED_ROWS: int(live.sum()),
                     _names.COUNT_EXPANDED_ROWS: 0})
             return counts
-        total = dict.fromkeys(("grid_pages", "live_pages", "row_pages",
-                               "short_pages", "grid_pages_window",
-                               "grid_pages_full"), 0)
+        total = dict.fromkeys(("grid_pages", "row_pages", "short_pages",
+                               "grid_pages_window", "grid_pages_full"), 0)
         for window in sorted(set(windows)):
             layers = windows.count(window)
             kind = self._kind_page_counts(pos, slots, window)
@@ -508,11 +516,11 @@ class InferenceEngineV2:
         bs = self.kv_cache.block_size
         pos, slots = self._row_positions(np.atleast_2d(pos)), \
             np.atleast_2d(slots)
-        grid, live, _, short = self._kernel_loads(pos, slots, window=window)
+        grid, _, short = self._kernel_loads(pos, slots, window=window)
         first = np.maximum(pos - window + 1, 0) // bs if window else 0
         pages = np.where(slots != 0, pos // bs + 1 - first, 0)
-        return {"grid_pages": grid, "live_pages": live,
-                "row_pages": int(pages.sum()), "short_pages": short}
+        return {"grid_pages": grid, "row_pages": int(pages.sum()),
+                "short_pages": short}
 
     @staticmethod
     def _sample_row(row, temperature, top_k, top_p, rng):
@@ -583,8 +591,11 @@ class InferenceEngineV2:
             return None
         toks, pos, slots, last_idx, finishing, take_from = batch
         seqs = [seq for seq, _ in finishing]
-        step = LaunchedStep(seqs, None, self.last_step_counts)
-        with _telemetry.scope(_names.SERVE_LAUNCH):
+        self.launches += 1
+        step = LaunchedStep(seqs, None, self.last_step_counts,
+                            index=self.launches)
+        with _telemetry.scope(_names.SERVE_LAUNCH, launch=step.index,
+                              kind=_names.KIND_RAGGED, burst_k=0):
             step_args = (self.params, self._kv,
                          self._ids_on_device(toks, take_from),
                          jnp.asarray(pos), jnp.asarray(slots),
@@ -604,6 +615,7 @@ class InferenceEngineV2:
             logits, self._kv, *counted = self._step_fn(*step_args, **step_kw)
             if counted:            # no wait: an addition queued on the device
                 self._counts_owed = self._counts_owed + counted[0]
+                self._launches_owed += 1
             self._chosen = None
             if seqs and do_sample:
                 # ONLY the finishing rows ([F, V]), not every slot
@@ -618,6 +630,7 @@ class InferenceEngineV2:
                 step.fetch, self._chosen = _tokens_and_counts(
                     logits, self._counts_owed)
             if seqs:
+                step.covers, self._launches_owed = self._launches_owed, 0
                 self._counts_owed = self._no_counts
         for seq in seqs:
             seq.owed += 1
@@ -636,12 +649,13 @@ class InferenceEngineV2:
         self._uncollected -= 1
         if step.fetch is None:
             return {}
-        with _telemetry.scope(_names.SERVE_FETCH):
+        with _telemetry.scope(_names.SERVE_FETCH, launch=step.index) as span:
             fetched = np.asarray(step.fetch)
             if step.device_counts is not None:
-                self._book_device_counts(np.asarray(step.device_counts), step)
+                self._book_device_counts(np.asarray(step.device_counts), step,
+                                         span)
             elif step.sample is None:
-                fetched = self._book_device_counts(fetched, step)
+                fetched = self._book_device_counts(fetched, step, span)
         sm, k = self.state_manager, step.burst_k
         if k:
             fetched = fetched.reshape(k, sm.max_seqs)
@@ -688,19 +702,26 @@ class InferenceEngineV2:
             ids = _take_chosen(ids, jnp.asarray(take_from), self._chosen)
         return ids
 
-    def _book_device_counts(self, fetched, step):
+    def _book_device_counts(self, fetched, step, span):
         """Put the device's counts (the step program's ``step_counts``:
-        ``step``'s and those of the steps before it that fetched nothing),
-        the last entries of ``fetched``, among ``last_step_counts``: the
-        NEWEST launched step's, which is ``step``'s own unless another was
-        launched on top of it (they ride with the next fetch, and add).
+        ``step``'s and those of the ``step.covers - 1`` steps before it that
+        fetched nothing), the last entries of ``fetched``, where they are
+        read: on ``span``, the fetch of the step that counted them, with
+        ``launches_covered``; and among ``last_step_counts``: the NEWEST
+        launched step's, which is ``step``'s own unless another was launched
+        on top of it (what the turn's ``ds:serve.step`` carries: they add).
         Returns what stands before them (the step's tokens, if any)."""
         cut = len(fetched) - len(self._device_counts)
         if self.last_step_counts is None:
             self.last_step_counts = step.counts
-        for name, count in zip(self._device_counts, fetched[cut:]):
-            self.last_step_counts[name] = \
-                self.last_step_counts.get(name, 0) + int(count)
+        if self._device_counts:
+            counts = {name: int(count) for name, count in
+                      zip(self._device_counts, fetched[cut:])}
+            for name, count in counts.items():
+                self.last_step_counts[name] = \
+                    self.last_step_counts.get(name, 0) + count
+            span.set(**{_names.COUNT_LAUNCHES_COVERED: step.covers},
+                     **counts)
         return fetched[:cut]
 
     # ---------------------------------------------------------- decode burst
@@ -839,7 +860,9 @@ class InferenceEngineV2:
             self._burst_key, key = jax.random.split(self._burst_key)
         else:
             key = None
-        with _telemetry.scope(_names.SERVE_LAUNCH):
+        self.launches += 1
+        with _telemetry.scope(_names.SERVE_LAUNCH, launch=self.launches,
+                              kind=_names.KIND_BURST, burst_k=k):
             burst_args = (self.params, self._kv,
                           self._ids_on_device(tok0, take_from),
                           jnp.asarray(pos0), jnp.asarray(act),
@@ -850,9 +873,11 @@ class InferenceEngineV2:
                             key=key, temperature=float(temperature),
                             top_k=int(top_k), top_p=float(top_p),
                             kv_dtype=self._kv_dtype)
+            covers = 0
             if self._device_counts:
                 burst_kw["counts0"] = self._counts_owed
                 self._counts_owed = self._no_counts
+                covers, self._launches_owed = self._launches_owed + 1, 0
             from ...profiling import cost_model
             if cost_model.capturing():
                 # k is static (pow2-quantized above), so the burst variants
@@ -867,7 +892,8 @@ class InferenceEngineV2:
         self._count_cache(pos_k, slots_k)
         self.burst_steps = getattr(self, "burst_steps", 0) + 1
         self._uncollected += 1
-        return LaunchedStep(seqs, toks_out, self.last_step_counts, burst_k=k)
+        return LaunchedStep(seqs, toks_out, self.last_step_counts, burst_k=k,
+                            index=self.launches, covers=covers)
 
     # ------------------------------------------------------------- generate
     def _mark_done(self, uid, produced, tok, eos_token_id, max_new_tokens):

@@ -168,17 +168,16 @@ def kernel_page_loads(seq_slots, positions, *, heads, kv_heads, head_dim,
     """Host-side (numpy) count of the K/V page loads (each brings one K and
     one V page) of the kernel :func:`paged_attention` picks for these rows
     (``[T]``, or ``[B, T]``: B calls) against a ``maxb``-page block table:
-    ``(grid, live, shared, short)``.  ``grid``: the loads the kernel's loops
-    perform — the run-tiled kernel's (run, page) items, or every row times
-    every page of the table.  ``live``: of those, the loads that hold a key
-    some live row may see (all of the run-tiled kernel's).  ``shared``: of
-    ``row_pages`` (a page count a row, equal along a run; None: 0) the sum
-    over what loads together — once a run, or once a row.  ``short``: of
-    ``grid``, the loads whose item computes one slab of rows and not the
-    tile (:func:`run_plan`'s ``slab``; 0 on the per-token kernel).
-    ``latent``: the loads of :func:`paged_latent_attention` (each brings ONE
-    page, scores and values both) for ``heads`` query heads on one latent
-    row (``kv_heads`` 1), by :func:`tile_rows`'s branch."""
+    ``(grid, shared, short)``.  ``grid``: the loads the kernel's loops
+    perform — the run-tiled kernel's (run, page) items, each of which holds
+    a key some live row may see, or every row times every page of the
+    table.  ``shared``: of ``row_pages`` (a page count a row, equal along a
+    run; None: 0) the sum over what loads together — once a run, or once a
+    row.  ``short``: of ``grid``, the loads whose item computes one slab of
+    rows and not the tile (:func:`run_plan`'s ``slab``; 0 on the per-token
+    kernel).  ``latent``: the loads of :func:`paged_latent_attention` (each
+    brings ONE page, scores and values both) for ``heads`` query heads on
+    one latent row (``kv_heads`` 1), by :func:`tile_rows`'s branch."""
     slots, pos = (np.atleast_2d(np.asarray(a))
                   for a in (seq_slots, positions))
     if row_pages is not None:
@@ -186,18 +185,16 @@ def kernel_page_loads(seq_slots, positions, *, heads, kv_heads, head_dim,
     T = slots.shape[-1]
     tq = tile_rows(heads, kv_heads, head_dim, kv_dtype, T, latent)
     if tq is None:
-        first = np.maximum(pos - window + 1, 0) // block_size if window else 0
-        live = np.where(slots != 0, pos // block_size + 1 - first, 0).sum()
-        return slots.size * maxb, int(live), \
+        return slots.size * maxb, \
             0 if row_pages is None else int(row_pages.sum()), 0
     _, rid, _, _, n_pages, slab = run_plan(np, slots, pos, tq, block_size,
                                            window, heads // kv_heads)
     grid, short = int(n_pages.sum()), int(n_pages[slab >= 0].sum())
     if row_pages is None:
-        return grid, grid, 0, short
+        return grid, 0, short
     per_row = np.pad(row_pages, ((0, 0), (0, -T % tq))).reshape(-1, tq)
     runs = rid[:, None, :] == np.arange(tq)[None, :, None]
-    return grid, grid, int((runs * per_row[:, None, :]).max(-1).sum()), short
+    return grid, int((runs * per_row[:, None, :]).max(-1).sum()), short
 
 
 def _head_pages(buf, kv_heads, block_size):

@@ -267,7 +267,11 @@ class ServingScheduler:
         (``telemetry/names.py``), with the children admit / build_batch /
         launch / fetch / dispatch and, as its counts, what the engine step
         LAUNCHED in it held (``InferenceEngineV2.last_step_counts``) and
-        ``launched_ahead``; its fetch is the wait for the step before."""
+        ``launched_ahead``; its fetch is the wait for the step before.  A
+        launched step's id (``LaunchedStep.index``) is the ``launch`` of its
+        launch, fetch and dispatch spans, whichever turns they fall in; the
+        turn carries it as ``launch`` and the id of the step it collected as
+        ``fetched``."""
         self._step_index += 1
         if self._heartbeat is not None:
             self._heartbeat.beat(self._step_index)
@@ -289,6 +293,7 @@ class ServingScheduler:
     def _run_step(self, span, emitted):
         cfg = self.config
         preempts = 0
+        ids = {}        # the turn's launch / fetched (names.SERVE_STEP_IDS)
         while True:
             ahead = self._in_flight is not None
             try:
@@ -296,7 +301,7 @@ class ServingScheduler:
                 if step is None and ahead and cfg.do_sample:
                     # the host draws this step's tokens: today's draws in
                     # today's order, so nothing is in flight while it does
-                    self._collect(emitted)
+                    self._collect(emitted, ids)
                     continue
                 if step is None:
                     step = self.engine.launch_step(
@@ -308,7 +313,7 @@ class ServingScheduler:
                 if ahead:
                     # what is in flight may end requests and return their
                     # blocks, and a victim never has an unfetched token
-                    self._collect(emitted)
+                    self._collect(emitted, ids)
                     continue
                 preempts += 1
                 if preempts > int(self.config.max_preemptions_per_step) \
@@ -323,8 +328,9 @@ class ServingScheduler:
         counts = step.counts if step is not None else {}
         held = dict(running=len(self._running), queued=len(self._queue))
         if ahead:
-            self._collect(emitted)      # the step launched the turn before
+            self._collect(emitted, ids)     # the step launched the turn before
         if step is not None:
+            ids[names.COUNT_LAUNCH] = step.index
             self._in_flight = (step, t_launched)
             self.steps_launched_ahead += ahead
             if ahead and telemetry.enabled:
@@ -342,14 +348,14 @@ class ServingScheduler:
                 and self._has_more_to_launch()):
             # nothing can run ahead of it (the host draws its tokens), or
             # nothing is left to: the turn collects its own step
-            self._collect(emitted)
+            self._collect(emitted, ids)
         # the recorder's phase column keeps its prefill|decode|mixed name,
         # now derived from what the step really held
         phase = ("mixed" if counts.get("prefill_tokens")
                  and counts.get("decode_tokens") else
                  "prefill" if counts.get("prefill_tokens") else "decode")
         span.set(phase=phase, preempts=preempts, launched_ahead=int(ahead),
-                 **held, **counts)
+                 **held, **counts, **ids)
 
     def _has_more_to_launch(self):
         """Whether a next turn would find rows to run: a running request
@@ -359,29 +365,32 @@ class ServingScheduler:
         sm = self.engine.state_manager
         return any(not sm.get_sequence(uid).done for uid in self._running)
 
-    def _collect(self, emitted):
+    def _collect(self, emitted, ids):
         """Fetch the tokens of the step in flight and stream them; adds to
-        ``emitted``."""
+        ``emitted`` and names the step in ``ids`` as the turn's
+        ``fetched``."""
         (step, t_launch), self._in_flight = self._in_flight, None
+        ids[names.COUNT_FETCHED] = step.index
         results = self.engine.collect_step(step)
         # the device took the step up when it was launched, or when the one
         # before it ended: what _dispatch amortizes a burst's tokens over
         t_start, self._t_collected = max(t_launch, self._t_collected), \
             self._clock()
-        with telemetry.scope(names.SERVE_DISPATCH):
-            for uid, toks in self._dispatch(results, t_start).items():
+        with telemetry.scope(names.SERVE_DISPATCH, launch=step.index):
+            for uid, toks in self._dispatch(results, t_start,
+                                            step.index).items():
                 emitted.setdefault(uid, []).extend(toks)
 
-    def _dispatch(self, results, t_launch=None):
+    def _dispatch(self, results, t_launch, launch):
         """Book engine output into request records: streaming callbacks,
         lifecycle transitions, completion + immediate flush (blocks return
         to the pool the moment a request finishes).  Burst results arrive
         k-at-a-time from one engine call; their timestamps interpolate over
         [t_launch, now] so the TBT accounting reflects per-token cost, not
-        k−1 fabricated zero gaps plus one burst-sized one."""
+        k−1 fabricated zero gaps plus one burst-sized one.  ``launch``: the
+        id of the engine step whose output this is, on the
+        ``ds:serve.finished`` of a request it ends."""
         now = self._clock()
-        if t_launch is None:
-            t_launch = now
         sm = self.engine.state_manager
         emitted = {}
         for uid, toks in results.items():
@@ -416,7 +425,7 @@ class ServingScheduler:
                     del self._running[uid]
                     self.completed += 1
                     telemetry.mark(names.SERVE_FINISHED, uid=uid,
-                                   tokens=len(req.produced))
+                                   tokens=len(req.produced), launch=launch)
                     if telemetry.enabled:
                         telemetry.counter("serving/requests_completed",
                                           help="requests finished (EOS or "

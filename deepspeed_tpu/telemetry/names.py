@@ -32,9 +32,14 @@ TRAIN_APPLY = "train.apply"               # call of the optimizer program
 TRAIN_REPORT = "train.report"             # metrics, scheduler, hooks
 
 # ---- serving host spans.  A scheduler turn launches the next engine step
-# and THEN fetches the one launched the turn before (serving/scheduler.py):
-# a ``serve.step`` carries the counts of the step it LAUNCHED, and the
-# ``serve.fetch`` inside it is the wait for the step before that one
+# and THEN fetches the one launched the turn before (serving/scheduler.py).
+# An engine step's life crosses two turns, so every span of it carries the
+# step's id, ``launch`` (the engine's count of its launches, ragged steps and
+# bursts in one sequence, from 1): its ``serve.launch``, the ``serve.fetch``
+# that waits for it, the ``serve.dispatch`` that streams its tokens, the
+# ``serve.finished`` of a request it ends.  The turn, ``serve.step``, carries
+# the counts of the step it LAUNCHED, that step's id as ``launch`` and the
+# id of the step it collected as ``fetched``
 SERVE_STEP = "serve.step"                 # one ServingScheduler.step (a turn)
 SERVE_ADMIT = "serve.admit"               # admission gate
 SERVE_BUILD_BATCH = "serve.build_batch"   # pack the token budget (numpy)
@@ -45,11 +50,12 @@ SERVE_FETCH = "serve.fetch"               # np.asarray of a launched step's
 #                                           tokens: the one wait for the
 #                                           device (in a turn that runs ahead:
 #                                           for the step BEFORE the one it
-#                                           launched)
+#                                           launched; its ``launch`` says
+#                                           which)
 SERVE_DISPATCH = "serve.dispatch"         # callbacks, lifecycle, flush
 # one short span per request event (count: uid)
 SERVE_ADMITTED = "serve.admitted"
-SERVE_FINISHED = "serve.finished"         # + tokens
+SERVE_FINISHED = "serve.finished"         # + tokens, launch
 SERVE_PREEMPTED = "serve.preempted"
 
 TRAIN_SPANS = (TRAIN_SHARD_BATCH, TRAIN_MICRO, TRAIN_BACKWARD,
@@ -57,15 +63,15 @@ TRAIN_SPANS = (TRAIN_SHARD_BATCH, TRAIN_MICRO, TRAIN_BACKWARD,
 SERVE_STEP_CHILDREN = (SERVE_ADMIT, SERVE_BUILD_BATCH, SERVE_LAUNCH,
                        SERVE_FETCH, SERVE_DISPATCH)
 
-#: ``kind`` of a ``ds:serve.step``: one ragged engine step, or a fused
-#: multi-token decode burst
+#: ``kind`` of a ``ds:serve.step`` and of a ``ds:serve.launch``: one ragged
+#: engine step, or a fused multi-token decode burst
 KIND_RAGGED = "ragged"
 KIND_BURST = "burst"
 #: the counts of a ``ds:serve.step`` (docs/observability.md says what each
 #: counts); the batch builder makes them, the scheduler's span carries them
 SERVE_STEP_COUNTS = ("step", "kind", "running", "queued", "token_budget",
                      "live_tokens", "prefill_tokens", "decode_tokens",
-                     "grid_pages", "live_pages", "row_pages", "short_pages",
+                     "grid_pages", "row_pages", "short_pages",
                      "burst_k", "preempts",
                      # 1: launched while the step before was unfetched (the
                      # device had it queued when that one ended); 0: the
@@ -77,11 +83,26 @@ SERVE_STEP_COUNTS = ("step", "kind", "running", "queued", "token_budget",
                      "context_tokens", "held_blocks", "block_size",
                      "summary_pages",
                      "chunks_closed", "windows_closed")
+#: a launched engine step's id (``InferenceEngineV2.launches`` when it was
+#: launched) on every span of its life; on the turn's ``serve.step`` the
+#: step it launched (absent: it launched none)
+COUNT_LAUNCH = "launch"
+#: on a ``serve.step``: the id of the step the turn collected (the newer
+#: one where it collected two; absent: none)
+COUNT_FETCHED = "fetched"
+#: on a ``serve.fetch`` that brings counts made on the device: how many
+#: launches' counts it brings (its own step's and those of the steps before
+#: it that fetched nothing: they add)
+COUNT_LAUNCHES_COVERED = "launches_covered"
+#: the ids a ``serve.step`` may carry beside ``SERVE_STEP_COUNTS``
+SERVE_STEP_IDS = (COUNT_LAUNCH, COUNT_FETCHED)
 #: further counts that only some models' steps carry: ``grid_pages_window``
 #: and ``grid_pages_full`` of a model whose layers read differently (a window
 #: on some, none on others), and what a model with an expert layer counted ON
 #: THE DEVICE, fetched with the tokens a request waits for (a step that fetches
-#: nothing leaves its counts to the next that does: they add)
+#: nothing leaves its counts to the next that does: they add).  They are the
+#: ``serve.fetch``'s own stats, the fetch of the step that counted them; the
+#: turn in which they arrive also adds them to its ``serve.step``
 COUNT_EXPERT_COPIES = "expert_copies"         # (row, expert) pairs on a held
 #                                               expert, summed over layers
 COUNT_EXPERT_ACTIVE = "expert_active"         # held experts with a copy,

@@ -159,13 +159,13 @@ def test_run_tiled_kernel_matches_the_gather(name):
                                  jnp.asarray(pos), window=window,
                                  count_loads=True)
     _assert_is_the_gather(out, q, kc, vc, tables, slots, pos, window)
-    # what the loops loaded is what the host counts, and no more than the
-    # pages that hold a key some row of the run may see; the items that
-    # computed one slab of rows are the ones the host calls short
-    grid, live, _, short = kernel_page_loads(
+    # what the loops loaded is what the host counts (every one a page that
+    # holds a key some row of the run may see); the items that computed one
+    # slab of rows are the ones the host calls short
+    grid, _, short = kernel_page_loads(
         slots, pos, heads=heads, kv_heads=kv_heads, head_dim=128,
         kv_dtype=kc.dtype, block_size=bs, maxb=tables.shape[1], window=window)
-    assert int(loads[:, 0].sum()) == want_loads == grid == live
+    assert int(loads[:, 0].sum()) == want_loads == grid
     assert int(loads[:, 1].sum()) == want_short == short
 
 
@@ -237,13 +237,16 @@ def test_per_token_kernel_matches_the_gather(name):
     _assert_is_the_gather(out, q, kc, vc, tables, slots, pos, window)
     # one grid row a token: every row streams every page of the table
     maxb = tables.shape[1]
-    grid, live_pages, _, short = kernel_page_loads(
+    row_pages = np.where(
+        slots != 0, pos // bs + 1 - (np.maximum(pos - window + 1, 0) // bs
+                                     if window else 0), 0)
+    grid, shared, short = kernel_page_loads(
         slots, pos, heads=heads, kv_heads=kv_heads, head_dim=head_dim,
-        kv_dtype=kc.dtype, block_size=bs, maxb=maxb, window=window)
+        kv_dtype=kc.dtype, block_size=bs, maxb=maxb, window=window,
+        row_pages=row_pages)
     assert grid == T * maxb and short == 0
-    assert live_pages == sum(
-        p // bs + 1 - (max(p - window + 1, 0) // bs if window else 0)
-        for p in pos[slots != 0])
+    # nothing loads together on this kernel: once a row
+    assert shared == row_pages.sum() > 0
 
 
 def test_shapes_the_run_tiled_kernel_leaves_to_the_per_token_one():
@@ -297,11 +300,11 @@ def test_batch_is_runs_of_consecutive_positions():
             slots, pos, heads=4, kv_heads=2, head_dim=128,
             kv_dtype=jnp.float32, block_size=bs,
             maxb=eng.state_manager.block_table.shape[1], window=24)
-        assert c["grid_pages"] == c["live_pages"] == loads[0]
-        assert c["short_pages"] == loads[3] <= c["grid_pages"]
+        assert c["grid_pages"] == loads[0] and "live_pages" not in c
+        assert c["short_pages"] == loads[2] <= c["grid_pages"]
         assert c["row_pages"] == sum(
             p // bs + 1 - max(p - 24 + 1, 0) // bs for p in pos[slots != 0])
-        assert c["row_pages"] >= c["live_pages"] > 0
+        assert c["row_pages"] >= c["grid_pages"] > 0
         steps += 1
     assert steps == 2       # 48 rows of 67 prompt tokens, then the rest
     eng.flush(range(4))

@@ -172,7 +172,7 @@ def test_serving_steps_in_a_real_trace(tiny, tmp_path):
         kinds.add(c["kind"])
         assert 0 < c["live_tokens"] <= c["token_budget"]
         assert c["live_tokens"] == c["prefill_tokens"] + c["decode_tokens"]
-        assert 0 < c["live_pages"] <= c["grid_pages"]
+        assert c["grid_pages"] > 0 and "live_pages" not in c
         assert c["row_pages"] > 0
         # ragged steps and bursts both say how many loads were short items
         # (none here: heads of 16 stay on the per-token kernel)
@@ -189,7 +189,7 @@ def test_serving_steps_in_a_real_trace(tiny, tmp_path):
     # (tests/unit/ops/test_paged_runs.py counts the run-tiled kernel's loads)
     ragged = [s[3] for s in steps if s[3]["kind"] == names.KIND_RAGGED]
     assert {c["grid_pages"] for c in ragged} == {32 * (64 // 8)}
-    assert all(c["row_pages"] >= c["live_pages"] for c in ragged)
+    assert all(c["row_pages"] > 0 for c in ragged)
     assert {c["token_budget"] for c in ragged} == {32}
     admitted = [e[3]["uid"] for e in t.named(names.SERVE_ADMITTED)]
     assert sorted(admitted) == sorted(uids)
@@ -269,6 +269,247 @@ def test_a_turn_launches_the_next_step_before_it_fetches_the_last(
     assert [len(sched.query(u).produced) for u in uids] == [5, 5, 5]
 
 
+# --------------------------------------------------- a step's life, one id
+# What the turns' ``ds:serve.step`` spans of the drain below carried at the
+# parent commit (ISSUE 37: "to the value what they were"), in the order of
+# ``_OLD_COUNTS``; ``live_pages`` went with its last reader.
+_OLD_COUNTS = ("step", "kind", "running", "queued", "token_budget",
+               "live_tokens", "prefill_tokens", "decode_tokens",
+               "grid_pages", "row_pages", "short_pages", "burst_k",
+               "preempts", "launched_ahead", "context_tokens", "held_blocks",
+               "block_size", "summary_pages", "chunks_closed",
+               "windows_closed")
+_OLD_VALUES = {
+    0: [(1, "ragged", 4, 0, 32, 32, 32, 0, 256, 43, 0, 0, 0, 0, 32, 7, 8, 0,
+         0, 0),
+        (2, "ragged", 4, 0, 32, 32, 29, 3, 256, 77, 0, 0, 0, 1, 64, 10, 8,
+         0, 0, 0),
+        (3, "ragged", 4, 0, 32, 13, 10, 3, 256, 54, 0, 0, 0, 1, 77, 11, 8,
+         0, 0, 0),
+        (4, "ragged", 4, 0, 32, 4, 0, 4, 256, 12, 0, 0, 0, 1, 81, 12, 8, 0,
+         0, 0),
+        (5, "ragged", 4, 0, 32, 4, 0, 4, 256, 13, 0, 0, 0, 1, 85, 13, 8, 0,
+         0, 0),
+        (6, "ragged", 4, 0, 32, 4, 0, 4, 256, 13, 0, 0, 0, 1, 89, 13, 8, 0,
+         0, 0),
+        (7, "ragged", 4, 0, 32, 1, 0, 1, 256, 6, 0, 0, 0, 1, 44, 6, 8, 0, 0,
+         0),
+        (8, "ragged", 1, 0, 32, 1, 0, 1, 256, 6, 0, 0, 0, 1, 45, 6, 8, 0, 0,
+         0)],
+    4: [(1, "ragged", 4, 0, 32, 32, 32, 0, 256, 43, 0, 0, 0, 0, 32, 7, 8, 0,
+         0, 0),
+        (2, "ragged", 4, 0, 32, 32, 29, 3, 256, 77, 0, 0, 0, 1, 64, 10, 8,
+         0, 0, 0),
+        (3, "ragged", 4, 0, 32, 13, 10, 3, 256, 54, 0, 0, 0, 1, 77, 11, 8,
+         0, 0, 0),
+        (4, "burst", 4, 0, 24, 8, 0, 8, 192, 25, 0, 2, 0, 1, 85, 13, 8, 0, 0,
+         0),
+        (5, "ragged", 4, 0, 32, 4, 0, 4, 256, 13, 0, 0, 0, 1, 89, 13, 8, 0,
+         0, 0),
+        (6, "burst", 4, 0, 24, 2, 0, 2, 192, 12, 0, 2, 0, 1, 45, 6, 8, 0, 0,
+         0)]}
+
+
+@pytest.fixture(scope="module")
+def drained(tiny, tmp_path_factory):
+    """``{decode_burst: (the capture, the scheduler, the streams)}`` of one
+    drained scheduler a setting (four prompts, six new tokens each)."""
+    made = {}
+    for burst in _OLD_VALUES:
+        sched = _scheduler(tiny, decode_burst=burst)
+        rng = np.random.default_rng(0)
+        streams = []
+        for n in (40, 9, 17, 5):
+            streams.append([])
+            sched.submit(rng.integers(1, 96, size=n).tolist(),
+                         max_new_tokens=6,
+                         on_token=lambda t, d, out=streams[-1]: out.append(t))
+        with Traced(tmp_path_factory.mktemp(f"burst{burst}")) as t:
+            sched.drain()
+        made[burst] = t, sched, streams
+    return made
+
+
+def _in_turns(t, name):
+    """The ``name`` spans of a capture, a list for every ``ds:serve.step``."""
+    return [[e for e in t.named(name) if t.inside(e, step)]
+            for step in t.named(names.SERVE_STEP)]
+
+
+@pytest.mark.parametrize("burst", list(_OLD_VALUES))
+def test_a_fetch_names_the_step_launched_the_turn_before(drained, burst):
+    t, sched, _ = drained[burst]
+    launches, fetches = (_in_turns(t, n) for n in (names.SERVE_LAUNCH,
+                                                   names.SERVE_FETCH))
+    assert all(len(turn) == 1 for turn in launches)
+    launched = [turn[0][3][names.COUNT_LAUNCH] for turn in launches]
+    fetched = [[f[3][names.COUNT_LAUNCH] for f in turn] for turn in fetches]
+    # none in the first turn; the last turn, with nothing left to launch,
+    # fetches the step before and its own
+    assert fetched == [[]] + [[n] for n in launched[:-2]] + [launched[-2:]]
+    # within a turn the launch ends before the fetch of the step before it
+    # begins: that step ran while the host built and launched this one
+    for (launch, ), turn in zip(launches, fetches):
+        assert all(launch[2] <= f[1] for f in turn)
+
+
+@pytest.mark.parametrize("burst", list(_OLD_VALUES))
+def test_launch_ids_rise_by_one_over_ragged_steps_and_bursts(drained, burst):
+    t, sched, _ = drained[burst]
+    launches = t.named(names.SERVE_LAUNCH)
+    assert [e[3][names.COUNT_LAUNCH] for e in launches] == \
+        list(range(1, len(launches) + 1))
+    assert sched.engine.launches == len(launches)
+    for e in launches:
+        assert set(e[3]) == {names.COUNT_LAUNCH, "kind", "burst_k"}
+    kinds = [(e[3]["kind"], e[3]["burst_k"]) for e in launches]
+    assert kinds == [(row[1], row[11]) for row in _OLD_VALUES[burst]]
+    assert ((names.KIND_BURST, 2) in kinds) == bool(burst)
+
+
+@pytest.mark.parametrize("burst", list(_OLD_VALUES))
+def test_a_turn_keeps_its_old_counts_and_names_its_two_steps(drained, burst):
+    t, _, _ = drained[burst]
+    steps = t.named(names.SERVE_STEP)
+    assert [tuple(s[3][k] for k in _OLD_COUNTS) for s in steps] == \
+        _OLD_VALUES[burst]
+    assert set(_OLD_COUNTS) == set(names.SERVE_STEP_COUNTS)
+    launches, fetches = (_in_turns(t, n) for n in (names.SERVE_LAUNCH,
+                                                   names.SERVE_FETCH))
+    for step, (launch, ), turn in zip(steps, launches, fetches):
+        ids = {k: v for k, v in step[3].items() if k not in _OLD_COUNTS}
+        want = {names.COUNT_LAUNCH: launch[3][names.COUNT_LAUNCH]}
+        if turn:        # the newer one where the turn collected two
+            want[names.COUNT_FETCHED] = turn[-1][3][names.COUNT_LAUNCH]
+        assert ids == want and set(ids) <= set(names.SERVE_STEP_IDS)
+
+
+@pytest.mark.parametrize("burst", list(_OLD_VALUES))
+def test_dispatch_and_finished_carry_the_step_whose_tokens_they_book(
+        drained, burst):
+    t, sched, streams = drained[burst]
+    fetches, dispatches = t.named(names.SERVE_FETCH), \
+        t.named(names.SERVE_DISPATCH)
+    assert [d[3][names.COUNT_LAUNCH] for d in dispatches] == \
+        [f[3][names.COUNT_LAUNCH] for f in fetches]
+    for d in dispatches:
+        assert set(d[3]) == {names.COUNT_LAUNCH}
+    assert sum(map(len, streams)) == 4 * 6
+    # a request's last token comes with the step that ends it: the mark lies
+    # inside that step's dispatch
+    finished = t.named(names.SERVE_FINISHED)
+    assert len(finished) == 4
+    for mark in finished:
+        home, = [d for d in dispatches if t.inside(mark, d)]
+        assert mark[3][names.COUNT_LAUNCH] == home[3][names.COUNT_LAUNCH]
+        assert mark[3]["tokens"] == 6
+
+
+def _routed(burst, serial=False):
+    """A tiny routed model (16 experts, 8 held: ``expert_copies`` and
+    ``expert_active`` are counted on the device) behind a scheduler."""
+    from deepspeed_tpu.models import cohere2_moe
+    if not hasattr(_routed, "made"):
+        model = cohere2_moe.Cohere2MoeModel(cohere2_moe.cohere2_moe_tiny())
+        _routed.made = model, model.init(
+            jax.random.PRNGKey(3), jnp.zeros((1, 8), jnp.int32))["params"]
+    model, params = _routed.made
+    sm = dict(max_tracked_sequences=12, max_ragged_batch_size=24,
+              max_ragged_sequence_count=6, max_context=128, block_size=8,
+              num_blocks=96)
+    engine = InferenceEngineV2(model, params=params, config=dict(
+        dtype="float32", decode_burst=burst, state_manager=sm))
+    if serial:          # the same loop, every step collected where launched
+        engine.__class__ = type("Serial", (InferenceEngineV2, ),
+                                {"launches_programs": False})
+    sched = ServingScheduler(engine)
+    rng = np.random.default_rng(1)
+    # two prompts of more than two budgets: the first two steps are middle
+    # chunks alone, which finish no row and fetch nothing
+    for n, new in ((70, 10), (60, 12)):
+        sched.submit(rng.integers(1, 96, size=n).tolist(), max_new_tokens=new)
+    return sched
+
+
+def _device_counts_by_launch(t):
+    """``{launch: (launches_covered, expert_copies, expert_active)}`` of the
+    capture's ``ds:serve.fetch`` spans."""
+    keys = (names.COUNT_LAUNCHES_COVERED, names.COUNT_EXPERT_COPIES,
+            names.COUNT_EXPERT_ACTIVE)
+    return {f[3][names.COUNT_LAUNCH]: tuple(f[3][k] for k in keys)
+            for f in t.named(names.SERVE_FETCH)}
+
+
+@pytest.mark.parametrize("burst", [0, 4])
+def test_device_counts_stand_on_the_fetch_of_the_step_that_counted_them(
+        tmp_path, burst):
+    """Run ahead, a fetch arrives a turn after its step's launch; the serial
+    spelling of the same loop fetches every step in the turn that launched
+    it.  Launch for launch the two fetch the same counts: they are the
+    step's own, whichever turn brings them."""
+    with Traced(tmp_path / "ahead") as ahead:
+        sched = _routed(burst)
+        sched.drain()
+    with Traced(tmp_path / "serial") as serial:
+        _routed(burst, serial=True).drain()
+    got, want = (_device_counts_by_launch(t) for t in (ahead, serial))
+    assert got == want and len(got) > 5
+    assert all(copies > 0 and active > 0 for _, copies, active in
+               got.values())
+    # a step that fetched nothing left its counts to the next fetch: every
+    # launch is covered once
+    assert sum(covers for covers, _, _ in got.values()) == \
+        sched.engine.launches > len(got)
+    assert [covers for covers, _, _ in got.values()][:2] == [3, 1]
+    # the turn in which they arrive still carries them (the readers that
+    # are there read ``ds:serve.step``): to the value, the sum of its fetches
+    for step, fetches in zip(ahead.named(names.SERVE_STEP),
+                             _in_turns(ahead, names.SERVE_FETCH)):
+        for key in (names.COUNT_EXPERT_COPIES, names.COUNT_EXPERT_ACTIVE):
+            assert step[3].get(key, 0) == sum(f[3][key] for f in fetches)
+    # the serial spelling collects in the turn that launched, fetch or none
+    assert all(s[3][names.COUNT_FETCHED] == s[3][names.COUNT_LAUNCH]
+               for s in serial.named(names.SERVE_STEP))
+
+
+@pytest.mark.parametrize("burst", [0, 4])
+def test_a_turn_that_launches_nothing_loses_no_count(tmp_path, monkeypatch,
+                                                     burst):
+    """After a ``KVCacheExhausted`` a turn collects what is in flight and
+    may find nothing left to build: its ``ds:serve.step`` has no step's
+    counts to add the fetched ones to (PERF.md's open question (e) before
+    ISSUE 37).  They stand on the turn's ``ds:serve.fetch``."""
+    from deepspeed_tpu.inference.v2.ragged import KVCacheExhausted
+    with Traced(tmp_path / "plain") as plain:
+        _routed(burst).drain()
+    sched = _routed(burst)
+    engine, real = sched.engine, sched.engine.launch_step
+    calls = []      # of launch_step once three steps are launched
+
+    def launch_step(**kw):
+        if engine.launches == 3:
+            calls.append(engine._uncollected)
+            if len(calls) == 1:     # on top of step 3: the pool ran dry,
+                raise KVCacheExhausted(1, 0)
+            if len(calls) == 2:     # and with step 3 collected, nothing
+                return None         # is left to build in this turn
+        return real(**kw)
+
+    monkeypatch.setattr(engine, "launch_step", launch_step)
+    with Traced(tmp_path / "fault") as t:
+        sched.drain()
+    assert calls == [1, 0, 0]
+    empty, = [s for s in t.named(names.SERVE_STEP)
+              if names.COUNT_LAUNCH not in s[3]]
+    assert empty[3][names.COUNT_FETCHED] == 3 and "kind" not in empty[3]
+    assert names.COUNT_EXPERT_COPIES not in empty[3]
+    fetch, = [f for f in t.named(names.SERVE_FETCH) if t.inside(f, empty)]
+    assert fetch[3][names.COUNT_LAUNCH] == 3
+    assert fetch[3][names.COUNT_EXPERT_COPIES] > 0
+    # the same steps ran: launch for launch the plain drain's counts
+    assert _device_counts_by_launch(t) == _device_counts_by_launch(plain)
+
+
 def test_preemption_is_an_event_with_its_uid(tiny, tmp_path):
     sched = _scheduler(tiny, num_blocks=15, decode_burst=0)
     rng = np.random.default_rng(0)
@@ -286,10 +527,9 @@ def test_preemption_is_an_event_with_its_uid(tiny, tmp_path):
 
 
 def _page_counts(engine, pos, slots):
-    """``(grid, live, row, short)`` pages of one layer's call."""
+    """``(grid, row, short)`` pages of one layer's call."""
     counts = engine._page_counts(pos, slots)
-    assert list(counts) == ["grid_pages", "live_pages", "row_pages",
-                            "short_pages"]
+    assert list(counts) == ["grid_pages", "row_pages", "short_pages"]
     return tuple(counts.values())
 
 
@@ -299,18 +539,18 @@ def test_page_counts_follow_the_kernel_and_the_window(tiny):
     slots = np.array([1, 2, 3, 4, 0, 0, 0, 0], np.int32)
     # heads of 16: the per-token kernel.  8 rows x 8 pages; contexts span
     # 1, 1, 2 and 4 pages
-    assert _page_counts(engine, pos, slots) == (64, 8, 8, 0)
+    assert _page_counts(engine, pos, slots) == (64, 8, 0)
     # the same rows with heads of 128, through the function the engine asks:
     # the run-tiled kernel loads each decode row's live pages, and nothing
     # for a dead row; a decode row's two query rows lie in one slab of 8, so
     # every load's item is short
     assert kernel_page_loads(
         slots, pos, heads=4, kv_heads=2, head_dim=128, kv_dtype=jnp.float32,
-        block_size=8, maxb=8) == (8, 8, 0, 8)
+        block_size=8, maxb=8) == (8, 0, 8)
     # a burst: k rows of positions
     assert _page_counts(engine, pos[None, :4] + np.arange(2)[:, None],
                                np.broadcast_to(slots[:4], (2, 4))) == (
-        64, 1 + 2 + 2 + 4 + 8, 1 + 2 + 2 + 4 + 8, 0)
+        64, 1 + 2 + 2 + 4 + 8, 0)
     # heads of 128: the run-tiled kernel loads a run's pages once, and only
     # live ones.  Rows 0-2 are one run of slot 1 (positions 6, 7, 8: pages
     # 0-1), row 3 a decode row at 30 (pages 0-3); three tokens' six query
@@ -319,21 +559,19 @@ def test_page_counts_follow_the_kernel_and_the_window(tiny):
         num_attention_heads=4, num_key_value_heads=2, head_dim=128)
     pos = np.array([6, 7, 8, 30, 0, 0, 0, 0], np.int32)
     slots = np.array([1, 1, 1, 4, 0, 0, 0, 0], np.int32)
-    assert _page_counts(engine, pos, slots) == (2 + 4, 2 + 4, 1 + 1 + 2 + 4,
-                                               2 + 4)
+    assert _page_counts(engine, pos, slots) == (2 + 4, 1 + 1 + 2 + 4, 2 + 4)
     # a window of 8 keeps position 30's pages 2-3 and position 8's 0-1
     engine.model_config.sliding_window = 8
-    assert _page_counts(engine, pos, slots) == (2 + 2, 2 + 2, 1 + 1 + 2 + 2,
-                                               2 + 2)
+    assert _page_counts(engine, pos, slots) == (2 + 2, 1 + 1 + 2 + 2, 2 + 2)
     # a fourth token of the run (eight query rows, then two): the decode
     # row's slab is the next one, the run still fills its own
     pos = np.array([6, 7, 8, 9, 30, 0, 0, 0], np.int32)
     slots = np.array([1, 1, 1, 1, 4, 0, 0, 0], np.int32)
-    assert _page_counts(engine, pos, slots)[3] == 2 + 2
+    assert _page_counts(engine, pos, slots)[2] == 2 + 2
     # a fifth: ten rows straddle two slabs, its items compute the tile
     pos = np.array([6, 7, 8, 9, 10, 30, 0, 0], np.int32)
     slots = np.array([1, 1, 1, 1, 1, 4, 0, 0], np.int32)
-    assert _page_counts(engine, pos, slots)[3] == 2
+    assert _page_counts(engine, pos, slots)[2] == 2
 
 
 # ----------------------------------------------------------------- training
@@ -443,6 +681,10 @@ def test_enabled_recorder_gets_the_serving_step_with_its_counts(
     assert set(phases) == {"prefill", "decode"}
     assert sum(e["args"]["prefill_tokens"] for e in steps) == 19
     assert set(names.SERVE_STEP_COUNTS) <= set(steps[0]["args"])
+    # the ids reach the recorder through the same ``span.set``
+    assert [e["args"][names.COUNT_LAUNCH] for e in steps] == [1, 2, 3]
+    assert [e["args"].get(names.COUNT_FETCHED) for e in steps] == \
+        [None, 1, 3]
     children = {e["name"] for e in events} - {e["name"] for e in steps}
     assert set(names.SERVE_STEP_CHILDREN) <= children
 
