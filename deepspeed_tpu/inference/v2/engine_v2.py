@@ -475,14 +475,15 @@ class InferenceEngineV2:
         """The page counts of a step's paged-attention calls over the rows at
         positions ``pos`` in slots ``slots`` (0: a dead row); ``[k, rows]``
         arrays are the ``k`` calls of a burst.  For a model whose layers read
-        alike, of ONE layer's call: ``grid_pages``, ``short_pages``: the K/V
-        page loads the kernel's loops perform and, of those, the loads whose
-        item computes one slab of rows and not its tile
-        (``paged_attention.kernel_page_loads``, beside the kernels it
-        describes); ``row_pages``: the (row, page) pairs the live rows'
+        alike, of ONE layer's call: ``grid_pages``, ``short_pages``,
+        ``block_pages``: the K/V page loads the kernel's loops perform and,
+        of those, the loads whose item computes one slab of rows and not its
+        tile, and the loads of items that take a block of pages through one
+        softmax update (``paged_attention.kernel_page_loads``, beside the
+        kernels it describes); ``row_pages``: the (row, page) pairs the live rows'
         contexts (their sliding windows) span — ``row_pages / grid_pages``
         is how many rows share one page load.  For a model that states a
-        window a layer (``layer_windows``) the three are summed over ALL its
+        window a layer (``layer_windows``) the four are summed over ALL its
         layers' calls, and ``grid_pages_window`` / ``grid_pages_full`` are
         the loads of its window layers' and of its full layers' calls.
         For a latent cache also ``latent_keys``, the (live row, key) pairs
@@ -501,7 +502,8 @@ class InferenceEngineV2:
                     _names.COUNT_EXPANDED_ROWS: 0})
             return counts
         total = dict.fromkeys(("grid_pages", "row_pages", "short_pages",
-                               "grid_pages_window", "grid_pages_full"), 0)
+                               "block_pages", "grid_pages_window",
+                               "grid_pages_full"), 0)
         for window in sorted(set(windows)):
             layers = windows.count(window)
             kind = self._kind_page_counts(pos, slots, window)
@@ -516,11 +518,11 @@ class InferenceEngineV2:
         bs = self.kv_cache.block_size
         pos, slots = self._row_positions(np.atleast_2d(pos)), \
             np.atleast_2d(slots)
-        grid, _, short = self._kernel_loads(pos, slots, window=window)
+        grid, _, short, block = self._kernel_loads(pos, slots, window=window)
         first = np.maximum(pos - window + 1, 0) // bs if window else 0
         pages = np.where(slots != 0, pos // bs + 1 - first, 0)
         return {"grid_pages": grid, "row_pages": int(pages.sum()),
-                "short_pages": short}
+                "short_pages": short, "block_pages": block}
 
     @staticmethod
     def _sample_row(row, temperature, top_k, top_p, rng):

@@ -16,7 +16,9 @@ KV): the wrapper hands the kernel ``q`` as ``[Hkv, TQ * g, Dh]``.  The rows an
 item COMPUTES follow the rows of its run: a run that lies inside one slab of
 :func:`slab_rows` rows (a decode token, a burst's row) loads, computes and
 stores that slab alone, every other item the whole tile; and an item's KV
-heads go through the softmax together, their dots back to back.
+heads go through the softmax together, their dots back to back.  An item
+on the whole tile takes a BLOCK of :func:`item_pages` consecutive pages of
+its run through one softmax update, as far as the run has whole blocks.
 
 :func:`paged_attention_per_token` is the older grid of one row times every
 page of the table; the shapes the run-tiled kernel does not take
@@ -50,6 +52,21 @@ _NEG_INF = float("-inf")
 #: back to back and not each behind its own softmax (docs/kernels.md has the
 #: v5e readings)
 _STACK_ROWS = 1024
+#: a block of pages (:func:`item_pages`): as many keys as the flash kernels'
+#: key block, at most the 8 pages that were measured, and what the blocks'
+#: four K/V buffers (K and V, double-buffered) may hold together
+_BLOCK_KEYS = 512
+_BLOCK_MAX_PAGES = 8
+_BLOCK_BUFFER_BYTES = 8 * 1024 * 1024
+#: the run-tiled kernel's VMEM.  What it holds itself (the tile's q and out,
+#: the accumulator and softmax state, the blocks' K/V buffers) may take three
+#: quarters of the compiler's default of 16 MB, the rest being the compiler's
+#: own (a block's score arrays ``[_STACK_ROWS, P * bs]``, 2 MB each); a
+#: kernel that holds more (16 MB at 16 query heads a KV head) asks for 64 MB.
+#: Only then: a call that asks costs its program 24 us beside the kernel
+#: (docs/kernels.md)
+_RUNS_VMEM_HELD_BYTES = 12 * 1024 * 1024
+_RUNS_VMEM_BYTES = 64 * 1024 * 1024
 #: query rows (tokens x heads) of a tile of the latent kernel: 8 tokens of
 #: 128 heads, which is what its VMEM holds beside the accumulator
 #: (docs/kernels.md)
@@ -118,7 +135,24 @@ def slab_rows(g):
     return -(-(8 - math.gcd(g, 8) + g) // 8) * 8
 
 
-def run_plan(xp, seq_slots, positions, tq, block_size, window=0, g=1):
+def item_pages(kv_heads, head_dim, kv_dtype, block_size):
+    """The pages ``P`` that one item of a LONG run (one whose rows take the
+    whole tile) brings and takes through ONE online-softmax update, from the
+    shapes alone.  The softmax's state (``m``, ``alpha``, ``l``, the
+    accumulator's rescaling) is owed once an update whatever the keys, and
+    with one page an update it is more than half of an item's vector work;
+    a block's price is VMEM: four buffers of ``P`` pages and score arrays
+    ``P`` times as wide.  So: the flash kernels' 512 keys (4 pages of 128),
+    fewer where the buffers would pass 8 MB (2 pages of 1 MB: EvaByte's 32
+    KV heads).  1: every item is one page.  docs/kernels.md has the v5e
+    sweep (``P`` 1 / 2 / 4 / 8 at the three serving cells' shapes)."""
+    page = block_size * kv_heads * head_dim * jnp.dtype(kv_dtype).itemsize
+    return int(max(1, min(_BLOCK_KEYS // block_size, _BLOCK_MAX_PAGES,
+                          _BLOCK_BUFFER_BYTES // (4 * page))))
+
+
+def run_plan(xp, seq_slots, positions, tq, block_size, window=0, g=1,
+             block=1):
     """The loop bounds of the run-tiled kernel, as arrays — with ``xp`` numpy
     on the host (:func:`kernel_page_loads`) and jax.numpy inside the step
     program, so that what is counted is what runs.
@@ -127,14 +161,21 @@ def run_plan(xp, seq_slots, positions, tq, block_size, window=0, g=1):
     a stretch of live rows (slot != 0) inside one tile with one slot and
     consecutive positions.  Returns, per tile: ``pos``, ``rid [n, tq]`` each
     row's position and run (-1: dead row), and per run ``run_slot``,
-    ``first_page``, ``n_pages``, ``slab [n, tq]`` (runs compacted to the
-    front, 0 pages past the last run).
+    ``first_page``, ``n_pages``, ``slab``, ``n_blocks [n, tq]`` (runs
+    compacted to the front, 0 pages past the last run).
 
     ``slab`` says which rows a run's items compute, of the tile's ``tq * g``
     (a token's ``g`` query rows adjacent): the first row of the one slab of
     :func:`slab_rows` rows that holds all the run's rows (a multiple of 8),
     or -1: no such slab, its items compute the whole tile.  The kernel
-    branches on it and :func:`kernel_page_loads` counts by it."""
+    branches on it and :func:`kernel_page_loads` counts by it.
+
+    ``n_blocks``: a run whose items compute the whole tile goes through its
+    pages ``block`` (:func:`item_pages`) at a time, one item a BLOCK, as far
+    as whole blocks go (``n_pages // block`` of them), and the rest of its
+    pages one item each; 0 for a run with a slab, and for every run where
+    ``block`` is 1.  A tile's items are ``n_pages - n_blocks * (block - 1)``
+    summed over its runs; every other count is of PAGES."""
     T = seq_slots.shape[-1]
     pad = -T % tq
     slots, pos = (xp.pad(a.reshape(-1, T).astype(xp.int32),
@@ -158,8 +199,10 @@ def run_plan(xp, seq_slots, positions, tq, block_size, window=0, g=1):
     row0 = (first * xp.arange(tq, dtype=xp.int32)).sum(-1) * g
     slab = xp.minimum(row0 // 8 * 8, tq * g - R)
     slab = xp.where((n_rows > 0) & (row0 + n_rows * g <= slab + R), slab, -1)
+    n_blocks = xp.where(slab < 0, n_pages // block, 0) if block > 1 \
+        else xp.zeros_like(n_pages)
     return pos, rid, run_slot, first_page.astype(xp.int32), n_pages, \
-        slab.astype(xp.int32)
+        slab.astype(xp.int32), n_blocks.astype(xp.int32)
 
 
 def kernel_page_loads(seq_slots, positions, *, heads, kv_heads, head_dim,
@@ -168,14 +211,17 @@ def kernel_page_loads(seq_slots, positions, *, heads, kv_heads, head_dim,
     """Host-side (numpy) count of the K/V page loads (each brings one K and
     one V page) of the kernel :func:`paged_attention` picks for these rows
     (``[T]``, or ``[B, T]``: B calls) against a ``maxb``-page block table:
-    ``(grid, shared, short)``.  ``grid``: the loads the kernel's loops
+    ``(grid, shared, short, block)``.  ``grid``: the loads the kernel's loops
     perform — the run-tiled kernel's (run, page) items, each of which holds
     a key some live row may see, or every row times every page of the
     table.  ``shared``: of ``row_pages`` (a page count a row, equal along a
     run; None: 0) the sum over what loads together — once a run, or once a
     row.  ``short``: of ``grid``, the loads whose item computes one slab of
     rows and not the tile (:func:`run_plan`'s ``slab``; 0 on the per-token
-    kernel).  ``latent``: the loads of :func:`paged_latent_attention` (each
+    kernel).  ``block``: of ``grid``, the loads of items that take a block
+    of :func:`item_pages` pages through one softmax update
+    (:func:`run_plan`'s ``n_blocks``; 0 where an item is one page: the
+    per-token kernel, the latent one).  All four count PAGES.  ``latent``: the loads of :func:`paged_latent_attention` (each
     brings ONE page, scores and values both) for ``heads`` query heads on
     one latent row (``kv_heads`` 1), by :func:`tile_rows`'s branch."""
     slots, pos = (np.atleast_2d(np.asarray(a))
@@ -186,51 +232,56 @@ def kernel_page_loads(seq_slots, positions, *, heads, kv_heads, head_dim,
     tq = tile_rows(heads, kv_heads, head_dim, kv_dtype, T, latent)
     if tq is None:
         return slots.size * maxb, \
-            0 if row_pages is None else int(row_pages.sum()), 0
-    _, rid, _, _, n_pages, slab = run_plan(np, slots, pos, tq, block_size,
-                                           window, heads // kv_heads)
+            0 if row_pages is None else int(row_pages.sum()), 0, 0
+    P = 1 if latent else item_pages(kv_heads, head_dim, kv_dtype, block_size)
+    _, rid, _, _, n_pages, slab, n_blocks = run_plan(
+        np, slots, pos, tq, block_size, window, heads // kv_heads, P)
     grid, short = int(n_pages.sum()), int(n_pages[slab >= 0].sum())
+    block = int(n_blocks.sum()) * P
     if row_pages is None:
-        return grid, 0, short
+        return grid, 0, short, block
     per_row = np.pad(row_pages, ((0, 0), (0, -T % tq))).reshape(-1, tq)
     runs = rid[:, None, :] == np.arange(tq)[None, :, None]
-    return grid, int((runs * per_row[:, None, :]).max(-1).sum()), short
+    return grid, int((runs * per_row[:, None, :]).max(-1).sum()), short, block
 
 
-def _head_pages(buf, kv_heads, block_size):
-    """The float32 ``[bs, Dh]`` page of every KV head, from the VMEM page
-    ``buf [bs, Hkv, Dh]`` — one sublane-strided load a head; a bfloat16 page
+def _head_pages(buf, kv_heads, keys):
+    """The float32 ``[keys, Dh]`` rows of every KV head, from the first
+    ``keys`` rows (one page, or a block of pages) of the VMEM buffer ``buf
+    [rows, Hkv, Dh]`` — one sublane-strided load a head; a bfloat16 page
     is read as uint32 words that hold two heads each, and a bfloat16 IS the
     high half of its float32."""
-    rows = buf.reshape(block_size * kv_heads, buf.shape[-1])
+    rows = buf.reshape(buf.shape[0] * kv_heads, buf.shape[-1])
     if buf.dtype == jnp.float32:
         if kv_heads == 1:
-            return [rows[...]]
-        return [rows[pl.ds(h, block_size, stride=kv_heads), :]
+            return [rows[:keys]]
+        return [rows[pl.ds(h, keys, stride=kv_heads), :]
                 for h in range(kv_heads)]
     words = rows.bitcast(jnp.uint32)
     out = []
     for j in range(kv_heads // 2):
-        w = (words[...] if kv_heads == 2 else
-             words[pl.ds(j, block_size, stride=kv_heads // 2), :])
+        w = (words[:keys] if kv_heads == 2 else
+             words[pl.ds(j, keys, stride=kv_heads // 2), :])
         out.append(pltpu.bitcast(w << 16, jnp.float32))
         out.append(pltpu.bitcast(w & jnp.uint32(0xFFFF0000), jnp.float32))
     return out
 
 
 def _run_kernel(tables_ref, slot_ref, first_ref, npages_ref, slab_ref,
-                total_ref, q_ref, pos_ref, rid_ref, k_hbm, v_hbm, o_ref,
-                *rest, tq, block_size, maxb, scale, window, count_loads,
-                short=True):
+                nblocks_ref, total_ref, q_ref, pos_ref, rid_ref, k_hbm,
+                v_hbm, o_ref, *rest, tq, block_size, maxb, scale, window,
+                count_loads, block, short=True):
     """One Q tile: ``q_ref [1, Hkv, M, Dh]`` (``M = tq * g`` rows, row
     ``t * g + gi``), ``pos_ref``/``rid_ref [1, M, 1]`` each row's position
-    and run, against the tile's items ``(run k, page p)``.  An item computes
-    the rows ``slab_ref`` gives its run (:func:`run_plan`): one slab, or
-    (-1) the tile; ``short=False`` computes the tile in every item (tests
-    hold the two to the same bits)."""
+    and run, against the tile's items.  An item is one page ``p`` of a run
+    ``k``, or (``nblocks_ref``: :func:`run_plan`'s ``n_blocks``) a BLOCK of
+    ``block`` consecutive pages of it, which go through one softmax update
+    together.  An item computes the rows ``slab_ref`` gives its run: one
+    slab, or (-1) the tile; ``short=False`` computes the tile in every item
+    (tests hold the two to the same bits)."""
     if count_loads:
         loads_ref, *rest = rest
-    k_buf, v_buf, sem, q32_ref, acc_ref, m_ref, l_ref = rest
+    k_buf, v_buf, q32_ref, acc_ref, m_ref, l_ref, sem = rest
     i = pl.program_id(0)
     base = i * tq
     total = total_ref[i]
@@ -241,36 +292,54 @@ def _run_kernel(tables_ref, slot_ref, first_ref, npages_ref, slab_ref,
     m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
 
-    def copies(k, p, buf):
-        blk = tables_ref[slot_ref[base + k] * maxb + first_ref[base + k] + p]
-        return (pltpu.make_async_copy(k_hbm.at[blk], k_buf.at[buf],
-                                      sem.at[0, buf]),
-                pltpu.make_async_copy(v_hbm.at[blk], v_buf.at[buf],
-                                      sem.at[1, buf]))
+    def blocked(k, p):
+        """Whether the item at page ``p`` of run ``k`` is a block."""
+        return p < nblocks_ref[base + k] * block
+
+    def each_copy(k, p, buf, act):
+        """``act`` (start, or wait) on the DMAs of the item at page ``p`` of
+        run ``k``: its page, or its block's pages, side by side in ``buf``."""
+        def pages(js):
+            row = slot_ref[base + k] * maxb + first_ref[base + k] + p
+            for j in js:
+                blk = tables_ref[row + j]
+                at = pl.ds(j * block_size, block_size)
+                act(pltpu.make_async_copy(k_hbm.at[blk], k_buf.at[buf, at],
+                                          sem.at[0, buf]))
+                act(pltpu.make_async_copy(v_hbm.at[blk], v_buf.at[buf, at],
+                                          sem.at[1, buf]))
+
+        pages((0, ))
+        if block > 1:
+            pl.when(blocked(k, p))(lambda: pages(range(1, block)))
+
+    start, wait = (lambda c: c.start()), (lambda c: c.wait())
 
     @pl.when(total > 0)
     def _first():
-        for c in copies(0, 0, 0):
-            c.start()
+        each_copy(0, 0, 0, start)
         # a 16-bit ref cannot be sliced at 8 rows: widen q once a tile
         for h in range(kv_heads):
             q32_ref[h] = q_ref[0, h].astype(jnp.float32)
 
-    def attend(k, p, buf, rows):
-        """The item on the rows ``rows`` of the tile (``n`` of its ``M``).  A
-        row's scores, softmax state and accumulation do not depend on which
-        other rows, or heads, are computed beside it: as many heads as fill
-        ``_STACK_ROWS`` rows go through the softmax as one array, between
-        their q.K dots and their P.V dots."""
+    def attend(k, p, buf, rows, pages=1):
+        """The item on the rows ``rows`` of the tile (``n`` of its ``M``)
+        against ``pages`` consecutive pages from ``p``: ONE online-softmax
+        update over their ``pages * bs`` keys.  A row's scores, softmax
+        state and accumulation do not depend on which other rows, or heads,
+        are computed beside it: as many heads as fill ``_STACK_ROWS`` rows
+        go through the softmax as one array, between their q.K dots and
+        their P.V dots."""
         pos, rid = pos_ref[0, rows], rid_ref[0, rows]          # [n, 1]
         n = pos.shape[0]
+        keys = pages * block_size
         col = (first_ref[base + k] + p) * block_size + \
-            jax.lax.broadcasted_iota(jnp.int32, (n, block_size), 1)
+            jax.lax.broadcasted_iota(jnp.int32, (n, keys), 1)
         mask = jnp.logical_and(rid == k, col <= pos)
         if window:  # sliding window: only the last `window` positions
             mask = jnp.logical_and(mask, col > pos - window)
-        k_pages = _head_pages(k_buf.at[buf], kv_heads, block_size)
-        v_pages = _head_pages(v_buf.at[buf], kv_heads, block_size)
+        k_pages = _head_pages(k_buf.at[buf], kv_heads, keys)
+        v_pages = _head_pages(v_buf.at[buf], kv_heads, keys)
         together = max(1, _STACK_ROWS // n)
         for h0 in range(0, kv_heads, together):
             hs = range(h0, min(h0 + together, kv_heads))
@@ -278,7 +347,7 @@ def _run_kernel(tables_ref, slot_ref, first_ref, npages_ref, slab_ref,
             s = jnp.concatenate([jax.lax.dot_general(
                 q32_ref[h, rows], k_pages[h], (((1, ), (1, )), ((), ())),
                 preferred_element_type=jnp.float32) for h in hs]) \
-                * scale                                     # [c * n, bs]
+                * scale                                     # [c * n, keys]
             live = jnp.tile(mask, (c, 1))
             s = jnp.where(live, s, _NEG_INF)
             state = lambda ref: ref[h0:h0 + c, rows].reshape(
@@ -299,39 +368,45 @@ def _run_kernel(tables_ref, slot_ref, first_ref, npages_ref, slab_ref,
                     new, (c * n, ref.shape[2])).reshape(c, n, ref.shape[2])
 
     def item(it, carry):
-        k, p, loaded, n_short = carry
+        k, p, loaded, n_short, n_block = carry
         buf = it % 2
-        last = p + 1 == npages_ref[base + k]
+        slab = slab_ref[base + k] if short else jnp.int32(-1)
+        whole = blocked(k, p) if block > 1 else False
+        took = jnp.where(whole, block, 1)
+        last = p + took == npages_ref[base + k]
         k_next = jnp.where(last, k + 1, k)
-        p_next = jnp.where(last, 0, p + 1)
+        p_next = jnp.where(last, 0, p + took)
 
         @pl.when(it + 1 < total)
         def _prefetch():
-            for c in copies(k_next, p_next, 1 - buf):
-                c.start()
+            each_copy(k_next, p_next, 1 - buf, start)
 
-        for c in copies(k, p, buf):
-            c.wait()
+        each_copy(k, p, buf, wait)
 
-        slab = slab_ref[base + k] if short else jnp.int32(-1)
-
-        @pl.when(slab < 0)
+        @pl.when(jnp.logical_and(slab < 0, jnp.logical_not(whole)))
         def _tile():
             attend(k, p, buf, slice(None))
+
+        if block > 1:
+            @pl.when(whole)
+            def _block():
+                attend(k, p, buf, slice(None), block)
 
         if short:
             @pl.when(slab >= 0)
             def _slab():
                 attend(k, p, buf, pl.ds(pl.multiple_of(slab, 8), R))
 
-        return k_next, p_next, loaded + 1, \
-            n_short + (slab >= 0).astype(jnp.int32)
+        return k_next, p_next, loaded + took, \
+            n_short + (slab >= 0).astype(jnp.int32), \
+            n_block + jnp.where(whole, block, 0)
 
-    *_, loaded, n_short = jax.lax.fori_loop(0, total, item,
-                                            (jnp.int32(0), ) * 4)
+    *_, loaded, n_short, n_block = jax.lax.fori_loop(
+        0, total, item, (jnp.int32(0), ) * 5)
     if count_loads:
         loads_ref[0, 0, 0] = loaded
         loads_ref[0, 0, 1] = n_short
+        loads_ref[0, 0, 2] = n_block
 
     for h in range(kv_heads):
         l = l_ref[h, :, :1]
@@ -351,8 +426,9 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_slots, positions,
     any rows; FAST when the rows of a sequence are contiguous with
     consecutive positions, since a run shares each page load
     (:func:`run_plan`).  ``count_loads=True`` also returns the page loads
-    each tile performed and, of those, the short items (``[n_tiles, 2]``;
-    tests compare :func:`kernel_page_loads`).
+    each tile performed and, of those, the short items' and the block
+    items' (``[n_tiles, 3]``, all in pages; tests compare
+    :func:`kernel_page_loads`).
 
     A shape :func:`run_tiled` refuses keeps one grid row a token."""
     T, H, Dh = q.shape
@@ -368,8 +444,9 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_slots, positions,
     maxb = block_tables.shape[1]
     g = H // Hkv
     M = tq * g
-    pos, rid, run_slot, first_page, n_pages, slab = run_plan(
-        jnp, seq_slots, positions, tq, bs, int(window), g)
+    P = item_pages(Hkv, Dh, k_cache.dtype, bs)
+    pos, rid, run_slot, first_page, n_pages, slab, n_blocks = run_plan(
+        jnp, seq_slots, positions, tq, bs, int(window), g, P)
     n = rid.shape[0]
 
     def rows(a):            # [n, tq] → [n, M, 1]: a token's g rows adjacent
@@ -383,39 +460,45 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_slots, positions,
     out_shape = [jax.ShapeDtypeStruct((n, Hkv, M, Dh), q.dtype)]
     out_specs = [tile(Hkv, M, Dh)]
     if count_loads:
-        out_shape.append(jax.ShapeDtypeStruct((n, 1, 2), jnp.int32))
-        out_specs.append(pl.BlockSpec((1, 1, 2), lambda i, *_: (i, 0, 0),
+        out_shape.append(jax.ShapeDtypeStruct((n, 1, 3), jnp.int32))
+        out_specs.append(pl.BlockSpec((1, 1, 3), lambda i, *_: (i, 0, 0),
                                       memory_space=pltpu.SMEM))
+    buffers = [
+        pltpu.VMEM((2, P * bs, Hkv, Dh), k_cache.dtype),
+        pltpu.VMEM((2, P * bs, Hkv, Dh), v_cache.dtype),
+        pltpu.VMEM((Hkv, M, Dh), jnp.float32),      # q, widened
+        pltpu.VMEM((Hkv, M, Dh), jnp.float32),
+        pltpu.VMEM((Hkv, M, 128), jnp.float32),
+        pltpu.VMEM((Hkv, M, 128), jnp.float32),
+    ]
+    # the kernel's own VMEM: its buffers, and q and out tiles twice each
+    held = sum(math.prod(b.shape) * jnp.dtype(b.dtype).itemsize
+               for b in buffers) + 4 * Hkv * M * Dh * q.dtype.itemsize
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
+        num_scalar_prefetch=7,
         grid=(n, ),
         in_specs=[tile(Hkv, M, Dh), tile(M, 1), tile(M, 1),
                   pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((2, bs, Hkv, Dh), k_cache.dtype),
-            pltpu.VMEM((2, bs, Hkv, Dh), v_cache.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.VMEM((Hkv, M, Dh), jnp.float32),      # q, widened
-            pltpu.VMEM((Hkv, M, Dh), jnp.float32),
-            pltpu.VMEM((Hkv, M, 128), jnp.float32),
-            pltpu.VMEM((Hkv, M, 128), jnp.float32),
-        ],
+        scratch_shapes=buffers + [pltpu.SemaphoreType.DMA((2, 2))],
     )
     out, *loads = pl.pallas_call(
         functools.partial(_run_kernel, tq=tq, block_size=bs, maxb=maxb,
                           scale=Dh**-0.5, window=int(window),
-                          count_loads=count_loads),
+                          count_loads=count_loads, block=P),
         grid_spec=grid_spec,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", )),
+            dimension_semantics=("parallel", ),
+            vmem_limit_bytes=None if held <= _RUNS_VMEM_HELD_BYTES
+            else _RUNS_VMEM_BYTES),
         interpret=_interpret(),
         name="ds_paged_runs",
     )(block_tables.reshape(-1).astype(jnp.int32), run_slot.reshape(-1),
       first_page.reshape(-1), n_pages.reshape(-1), slab.reshape(-1),
-      n_pages.sum(-1), qt, rows(pos), rows(rid), k_cache, v_cache)
+      n_blocks.reshape(-1), (n_pages - n_blocks * (P - 1)).sum(-1), qt,
+      rows(pos), rows(rid), k_cache, v_cache)
     out = out.reshape(n, Hkv, tq, g, Dh).transpose(0, 2, 1, 3, 4) \
         .reshape(n * tq, H, Dh)[:T]
     return (out, loads[0][:, 0]) if count_loads else out
@@ -530,7 +613,7 @@ def paged_latent_attention(q, c_cache, block_tables, seq_slots, positions, *,
                          f"{c_cache.dtype} (latent_tiled)")
     maxb = block_tables.shape[1]
     M = tq * H
-    pos, rid, run_slot, first_page, n_pages, slab = run_plan(
+    pos, rid, run_slot, first_page, n_pages, slab, _ = run_plan(
         jnp, seq_slots, positions, tq, bs, 0, H)
     n = rid.shape[0]
     rows = lambda a: jnp.repeat(a, H, axis=1)[:, :, None]      # [n, M, 1]

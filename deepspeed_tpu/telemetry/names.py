@@ -72,6 +72,9 @@ KIND_BURST = "burst"
 SERVE_STEP_COUNTS = ("step", "kind", "running", "queued", "token_budget",
                      "live_tokens", "prefill_tokens", "decode_tokens",
                      "grid_pages", "row_pages", "short_pages",
+                     # of grid_pages, the pages that a multi-page item of
+                     # the paged kernel loaded (a block of a long run)
+                     "block_pages",
                      "burst_k", "preempts",
                      # 1: launched while the step before was unfetched (the
                      # device had it queued when that one ended); 0: the

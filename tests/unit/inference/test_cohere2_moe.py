@@ -248,7 +248,7 @@ def test_page_counts_are_a_layer_kinds():
     assert counts["row_pages"] == 3 * one(16)["row_pages"] + one(0)["row_pages"]
 
 
-def test_a_one_kind_model_keeps_its_three_counts():
+def test_a_one_kind_model_keeps_its_counts_of_one_call():
     from deepspeed_tpu.models.llama import LlamaModel, llama_tiny
     cfg = llama_tiny(sliding_window=16)
     model = LlamaModel(cfg)
@@ -257,5 +257,6 @@ def test_a_one_kind_model_keeps_its_three_counts():
     eng = _scheduler(model, params, 0, sessions=1).engine
     pos, slots = np.arange(40, 48), np.full(8, 1)
     counts = eng._page_counts(pos, slots)
-    assert set(counts) == {"grid_pages", "row_pages", "short_pages"}
+    assert set(counts) == {"grid_pages", "row_pages", "short_pages",
+                           "block_pages"}
     assert counts == eng._kind_page_counts(pos, slots, 16)

@@ -2,7 +2,8 @@
 ``TPU v5 lite`` (``tools/aot_kernel_check.py`` — libtpu describes the
 topology with no chip attached).  Compile only: nothing here says a kernel
 runs, is right, or is fast.  With ``--ops ds_paged_runs`` the tool also counts
-the instructions Mosaic made of that kernel's item loop."""
+the instructions Mosaic made of that kernel's item loop, branch by branch: the
+one-page item on the tile, the block item (a page of it beside), the slab."""
 
 import json
 import os
@@ -37,15 +38,18 @@ def test_every_kernel_compiles_for_v5e(report):
     assert r.returncode == 0, "\n".join(lines) + r.stderr[-1500:]
     assert len(lines) >= 21 and all(ln.startswith("PASS") for ln in lines)
     assert "TPU v5 lite" in r.stdout
-    # the run-tiled paged kernel, both branches of its item, at the two
-    # serving cells' shapes and their bursts': a failure of the kind of
-    # PERF.md's fault 1 is met here, before a cell meets it
+    # the run-tiled paged kernel, every branch of its item (the block of
+    # pages too), at the three serving cells' shapes and their bursts': a
+    # failure of the kind of PERF.md's fault 1 is met here, before a cell is
     for kernel in ("flash_attention(grad", "paged_attention_per_token",
                    "paged_attention(GQA 32/8, the cell)",
                    "paged_attention(GQA 32/8, the cell's burst)",
                    "paged_attention(MHA 32/32, the EvaByte cell)",
                    "paged_attention(MHA 32/32, the EvaByte cell's burst)",
                    "paged_attention(GQA 28/4, Qwen2)",
+                   "paged_attention(GQA 128/8, the Command A+ cell, window)",
+                   "paged_attention(GQA 128/8, the Command A+ cell, full)",
+                   "paged_attention(GQA 128/8, the Command A+ cell's burst)",
                    "paged_attention(GQA 32/8, count_loads)",
                    "paged_latent_attention(MLA 128 x 576, the cell's step)",
                    "paged_latent_attention(MLA 128 x 576, the cell's burst)",
@@ -53,24 +57,70 @@ def test_every_kernel_compiles_for_v5e(report):
         assert any(kernel in ln for ln in lines), kernel
 
 
-def test_a_short_item_holds_a_fraction_of_the_tiles_matmuls(report):
-    """``OPS <kernel> | <check> | <region> | <ops> | {op: count}``: the item
-    loop of ``ds_paged_runs`` holds the prefetch and the item's two
-    branches.  The branch on one slab of rows streams 8 rows a dot where
-    the branch on the tile streams all of them; both latch the same pages."""
-    ops = {}
+REGIONS = ["loop", "prefetch", "wait for a block's other pages", "tile item",
+           "block item", "slab item"]
+
+
+@pytest.fixture(scope="module")
+def ops(report):
+    """``OPS <kernel> | <check> | <region> | <ops> | {op: count}`` of the
+    item loop of ``ds_paged_runs``: ``{check: {region: (pages, counts)}}``,
+    the block item under ``"block item"`` with the pages its name states."""
+    out = {}
     for ln in report.stdout.splitlines():
         if ln.startswith("OPS ds_paged_runs | "):
-            _, check, region, _, counts = ln.split(" | ", 4)
-            ops.setdefault(check, {})[region] = json.loads(counts)
-    for check, rows in (("paged_attention(GQA 32/8, the cell)", 128),
-                        ("paged_attention(MHA 32/32, the EvaByte cell)", 64)):
-        regions = ops[check]
-        assert list(regions) == ["loop", "if 1", "if 2", "if 3"]
-        assert "llo.enqueue_dma" in regions["if 1"]
-        tile, slab = regions["if 2"], regions["if 3"]
-        assert tile["llo.vmatmul"] * 8 == slab["llo.vmatmul"] * rows
-        assert slab["llo.vmatmul"] * 5 < tile["llo.vmatmul"]
-        assert slab["llo.vlatch"] == tile["llo.vlatch"]
-        assert slab["llo.vexp.f32"] * 5 < tile["llo.vexp.f32"]
-        assert sum(slab.values()) * 2 < sum(tile.values())
+            _, check, region, total, counts = ln.split(" | ", 4)
+            counts, pages = json.loads(counts), 1
+            assert int(total) == sum(counts.values())
+            if region.startswith("block item of "):
+                pages = int(region.split()[3])
+                assert region == f"block item of {pages} pages: " \
+                    f"{int(total) // pages} a page"
+                region = "block item"
+            out.setdefault(check, {})[region] = (pages, counts)
+    return out
+
+
+CELLS = (("paged_attention(GQA 32/8, the cell)", 128, 4),
+         ("paged_attention(MHA 32/32, the EvaByte cell)", 64, 2),
+         ("paged_attention(GQA 128/8, the Command A+ cell, full)", 512, 4))
+
+
+@pytest.mark.parametrize("check, rows, pages", CELLS)
+def test_a_short_item_holds_a_fraction_of_the_tiles_matmuls(ops, check, rows,
+                                                            pages):
+    """The item loop holds the prefetch, the wait for the rest of a block
+    and the item's three branches.  The branch on one slab of rows streams
+    ``slab_rows(g)`` rows a dot where the branch on the tile streams all of
+    them; both latch the same pages."""
+    regions = ops[check]
+    assert list(regions) == REGIONS
+    assert "llo.enqueue_dma" in regions["prefetch"][1]
+    assert "llo.dma_done" in regions["wait for a block's other pages"][1]
+    tile, slab = regions["tile item"][1], regions["slab item"][1]
+    R = 16 if rows == 512 else 8
+    assert tile["llo.vmatmul"] * R == slab["llo.vmatmul"] * rows
+    assert slab["llo.vmatmul"] * 5 < tile["llo.vmatmul"]
+    assert slab["llo.vlatch"] == tile["llo.vlatch"]
+    assert slab["llo.vexp.f32"] * 5 < tile["llo.vexp.f32"]
+    assert sum(slab.values()) * 2 < sum(tile.values())
+
+
+@pytest.mark.parametrize("check, rows, pages", CELLS)
+def test_a_block_item_pays_the_softmax_state_once_for_all_its_pages(
+        ops, check, rows, pages):
+    """A block item against ``pages`` one-page tile items: the same dots
+    (latches and matmuls a page), the ``exp`` of ``alpha`` and the lane
+    broadcasts of the state once and not once a page, one cross-lane
+    reduction a row and not one a page."""
+    regions = ops[check]
+    (n, block), (_, tile) = regions["block item"], regions["tile item"]
+    assert n == pages
+    for op in ("llo.vlatch", "llo.vmatmul"):
+        assert block[op] == pages * tile[op], op
+    # a one-page item's vexp are half the scores', half alpha's
+    assert block["llo.vexp.f32"] * 2 == (pages + 1) * tile["llo.vexp.f32"]
+    assert block["llo.vperm"] <= tile["llo.vperm"] * 1.05
+    for op in ("llo.vmax.xlane.f32", "llo.vadd.xlane.f32"):
+        assert block[op] == tile[op], op
+    assert sum(block.values()) < 0.8 * pages * sum(tile.values())

@@ -88,12 +88,13 @@ def test_the_kernel_is_the_gather_and_its_loads_are_counted(name):
     # what is counted is what runs: the items of run_plan at the kernel's tile
     tq = tile_rows(heads, 1, ROW, pages.dtype, T, latent=True)
     assert tq == max(8, 1024 // heads // 8 * 8)
-    *_, n_pages, slab = run_plan(np, slots, pos, tq, BS, 0, heads)
-    grid, _, short = kernel_page_loads(
+    *_, n_pages, slab, _ = run_plan(np, slots, pos, tq, BS, 0, heads)
+    # (the latent kernel's items are one page each: no block of pages)
+    grid, _, short, block = kernel_page_loads(
         slots, pos, heads=heads, kv_heads=1, head_dim=ROW,
         kv_dtype=pages.dtype, block_size=BS, maxb=tables.shape[1],
         latent=True)
-    assert grid == int(n_pages.sum()) == want_loads
+    assert grid == int(n_pages.sum()) == want_loads and block == 0
     assert short == int(n_pages[slab >= 0].sum()) == want_short
 
 
@@ -106,7 +107,7 @@ def test_a_burst_is_k_calls():
               block_size=BS, maxb=8, latent=True)
     each = [kernel_page_loads(slots[i], pos[i], **kw) for i in range(3)]
     assert kernel_page_loads(slots, pos, **kw) == tuple(
-        sum(e[i] for e in each) for i in range(3))
+        sum(e[i] for e in each) for i in range(4))
     # positions 7 -> 8, 15 -> 16 and 31 -> 32 cross a page: a load more
     assert [e[0] for e in each] == [2 + 1 + 4, 2 + 2 + 4, 3 + 2 + 5]
 
@@ -127,7 +128,7 @@ def test_which_shapes_the_kernel_takes():
         q, pages, tables, slots, pos = _case(12, [(1, 0, 4, 0)], 8)
         paged_latent_attention(q, pages, tables, jnp.asarray(slots),
                                jnp.asarray(pos), rank=RANK, scale=SCALE)
-    grid, _, short = kernel_page_loads(
+    grid, _, short, _ = kernel_page_loads(
         np.array([1, 1, 0]), np.array([8, 9, 0]), heads=12, kv_heads=1,
         head_dim=ROW, kv_dtype=jnp.float32, block_size=BS, maxb=5,
         latent=True)

@@ -5,7 +5,9 @@ kernel's page loads are the ones ``kernel_page_loads`` counts: the count
 ``InferenceEngineV2._page_counts`` reports.  An item of the run-tiled kernel
 computes one slab of rows where its run lies inside one (a decode token), the
 whole tile otherwise: the same bits either way, and the short items the kernel
-ran are the ones the host counts."""
+ran are the ones the host counts.  An item of a LONG run takes a block of
+``item_pages`` pages through one softmax update: the one-page items' result to
+the rounding of a reordered float32 sum, and every count still one of pages."""
 
 import functools
 
@@ -20,7 +22,8 @@ from deepspeed_tpu.inference.v2.ragged_forward import _paged_attention
 from deepspeed_tpu.inference.v2.ragged import window_row_positions
 from deepspeed_tpu.models import llama
 from deepspeed_tpu.ops.pallas import paged_attention as paged_module
-from deepspeed_tpu.ops.pallas.paged_attention import (kernel_page_loads,
+from deepspeed_tpu.ops.pallas.paged_attention import (item_pages,
+                                                      kernel_page_loads,
                                                       paged_attention,
                                                       run_tiled, slab_rows)
 
@@ -162,7 +165,7 @@ def test_run_tiled_kernel_matches_the_gather(name):
     # what the loops loaded is what the host counts (every one a page that
     # holds a key some row of the run may see); the items that computed one
     # slab of rows are the ones the host calls short
-    grid, _, short = kernel_page_loads(
+    grid, _, short, _ = kernel_page_loads(
         slots, pos, heads=heads, kv_heads=kv_heads, head_dim=128,
         kv_dtype=kc.dtype, block_size=bs, maxb=tables.shape[1], window=window)
     assert int(loads[:, 0].sum()) == want_loads == grid
@@ -192,6 +195,145 @@ def test_short_items_give_the_bits_of_whole_tiles(name, monkeypatch):
     np.testing.assert_array_equal(as_bits(out), as_bits(whole))
     assert np.asarray(loads)[:, 0].tolist() == \
         np.asarray(whole_loads)[:, 0].tolist()
+
+
+# ------------------------------------------------ blocks of pages (PR 38)
+#: pages of 8 tokens are small: every shape of this file takes blocks of 8
+P = 8
+
+BLOCK_CASES = {
+    # name: (heads, kv_heads, runs, T, kwargs, page loads, of those the short
+    # items' and the BLOCK items': whole blocks of P pages of a run whose
+    # items compute the tile, counted in pages)
+    # 16 rows at positions 48..63: pages 0-7
+    "a_run_of_exactly_one_block": (
+        8, 2, [(1, 48, 16, 0)], 16, {}, 8, 0, 8),
+    # at 56..71: pages 0-8, the ninth through the one-page item
+    "one_page_more_than_a_block": (
+        8, 2, [(1, 56, 16, 0)], 16, {"maxb": 12}, 9, 0, 8),
+    # at 40..55: pages 0-6, no block at all
+    "one_page_short_of_a_block": (
+        8, 2, [(1, 40, 16, 0)], 16, {}, 7, 0, 0),
+    "three_blocks_and_no_rest": (
+        8, 2, [(1, 176, 16, 0)], 16, {"maxb": 24}, 24, 0, 24),
+    # window 100: position 150 sees keys 51..150, so the run's first page is
+    # page 6 and its one block pages 6-13 (the later rows' windows start
+    # inside it), then pages 14-20 one by one
+    "a_window_whose_first_page_falls_inside_a_block": (
+        8, 2, [(1, 150, 16, 0)], 16, {"maxb": 24, "window": 100}, 15, 0, 8),
+    # EvaByte: a chunk at 234..243 in its eighth window: row positions
+    # 66..75 behind seven windows' summaries, pages 0-9 of the table row
+    "evabyte_summaries_and_window_in_one_block": (
+        4, 4, [(1, _eva(234), 10, 0), (2, _eva(33), 1, 10)], 16,
+        {"maxb": 12, "dtype": jnp.bfloat16}, 10 + 2, 2, 8),
+    # behind a decode row (4 short loads), from buffer row 9: 60..79
+    "a_run_that_starts_mid_tile": (
+        8, 2, [(2, 30, 1, 0), (1, 60, 20, 9)], 32, {"maxb": 12},
+        4 + 10, 4, 8),
+    # three tiles of 32 rows, the middle one dead: 64..87 (pages 0-10) and
+    # 100..129 (pages 0-16: two blocks)
+    "a_dead_tile_between_two_runs": (
+        8, 2, [(1, 64, 24, 0), (2, 100, 30, 64)], 96, {"maxb": 24},
+        11 + 17, 0, 8 + 16),
+    # 70..83 (pages 0-10) and 127..142 (pages 0-17) side by side
+    "two_runs_in_one_tile": (
+        8, 2, [(1, 70, 14, 0), (2, 127, 16, 14)], 32, {"maxb": 24},
+        11 + 18, 0, 8 + 16),
+    # the serving cells' heads, two to a word: 60..91 in the first tile
+    # (pages 0-11), 92..99 in the second (0-12), a decode row at 20
+    "bfloat16_prefill_crosses_a_tile": (
+        32, 8, [(1, 60, 40, 0), (2, 20, 1, 40)], 48,
+        {"maxb": 16, "dtype": jnp.bfloat16}, 12 + 13 + 3, 3, 8 + 8),
+}
+
+
+def _block_case(name, **more):
+    heads, kv_heads, runs, T, kw, *want = BLOCK_CASES[name]
+    q, kc, vc, tables, slots, pos = _case(heads, kv_heads, runs, T,
+                                          **{**kw, **more})
+    assert item_pages(kv_heads, 128, kc.dtype, kc.shape[1]) == P
+    return (q, kc, vc, tables, jnp.asarray(slots), jnp.asarray(pos)), \
+        kw.get("window", 0), want, dict(
+            heads=heads, kv_heads=kv_heads, head_dim=128, kv_dtype=kc.dtype,
+            block_size=kc.shape[1], maxb=tables.shape[1])
+
+
+@pytest.mark.parametrize("name", list(BLOCK_CASES))
+def test_block_items_match_the_gather_and_the_hosts_counts(name):
+    args, window, want, shapes = _block_case(name)
+    out, loads = paged_attention(*args, window=window, count_loads=True)
+    slots, pos = (np.asarray(a) for a in args[4:])
+    _assert_is_the_gather(out, *args[:4], slots, pos, window)
+    # what the loops loaded, in PAGES whatever the items: all of it, the
+    # short items' part and the block items' part are the host's three
+    grid, _, short, block = kernel_page_loads(slots, pos, window=window,
+                                              **shapes)
+    assert np.asarray(loads).sum(0).tolist() == want == [grid, short, block]
+    assert block % P == 0 and block + short <= grid
+
+
+@pytest.mark.parametrize("name", list(BLOCK_CASES))
+def test_block_items_are_one_page_items_with_their_sums_reordered(
+        name, monkeypatch):
+    """Against the same kernel with the blocks off (every item one page, the
+    kernel as it was): the same pages tile by tile, and a live row's result
+    to the rounding of a float32 sum taken in another order; where no run
+    holds a block, the same bits."""
+    args, window, want, shapes = _block_case(name, seed=2)
+    out, loads = paged_attention(*args, window=window, count_loads=True)
+    monkeypatch.setattr(paged_module, "item_pages", lambda *a: 1)
+    paged, paged_loads = paged_attention.__wrapped__(
+        *args, window=window, count_loads=True)
+    assert np.asarray(paged_loads).sum(0).tolist() == want[:2] + [0]
+    assert kernel_page_loads(*(np.asarray(a) for a in args[4:]),
+                             window=window, **shapes)[3] == 0
+    assert np.asarray(loads)[:, :2].tolist() == \
+        np.asarray(paged_loads)[:, :2].tolist()
+    out, paged = (np.asarray(a, np.float32) for a in (out, paged))
+    if not want[2]:
+        np.testing.assert_array_equal(out.view(np.uint32),
+                                      paged.view(np.uint32))
+    # float32 results a few ulps apart; a bfloat16 output one rounding
+    tol = 2e-6 if args[1].dtype == jnp.float32 else 8e-3
+    np.testing.assert_allclose(out, paged, atol=tol, rtol=tol)
+
+
+def test_the_pages_of_a_block_follow_from_the_shapes():
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    # 512 keys: four pages of 128 at 8 KV heads (Mistral, Command A+) ...
+    assert item_pages(8, 128, bf16, 128) == 4
+    # ... two where four buffers of four 1 MB pages would pass 8 MB (EvaByte)
+    assert item_pages(32, 128, bf16, 128) == 2
+    assert item_pages(32, 128, f32, 128) == 1
+    # pages of 256 keys: two; of 512 and more: one (every item one page);
+    # small pages: no more than the eight that were measured
+    assert [item_pages(8, 128, bf16, bs) for bs in (256, 512, 1024)] == \
+        [2, 1, 1]
+    assert [item_pages(8, 128, bf16, bs) for bs in (8, 16, 64)] == [8, 8, 8]
+
+
+@pytest.mark.parametrize("heads, kv_heads, T, limit", [
+    (32, 8, 768, None),             # Mistral's step: 7 MB of its own
+    (32, 32, 768, 64 << 20),        # EvaByte's: 14 MB
+    (32, 32, 16, None),             # its burst: a tile of 16 rows
+    (128, 8, 2048, 64 << 20),       # Command A+'s: 16 MB
+])
+def test_the_kernel_asks_for_vmem_only_where_its_buffers_need_it(
+        heads, kv_heads, T, limit, monkeypatch):
+    """A call that asks for a VMEM limit costs its program 24 us beside the
+    kernel (docs/kernels.md): the cells' shapes, pages of 128 keys."""
+    asked = []
+    params = paged_module.pltpu.CompilerParams
+    monkeypatch.setattr(
+        paged_module.pltpu, "CompilerParams",
+        lambda **kw: asked.append(kw["vmem_limit_bytes"]) or params(**kw))
+    sds = jax.ShapeDtypeStruct
+    cache = sds((4, 128, kv_heads, 128), jnp.bfloat16)
+    jax.eval_shape(paged_attention.__wrapped__,
+                   sds((T, heads, 128), jnp.bfloat16), cache, cache,
+                   sds((3, 4), jnp.int32), sds((T, ), jnp.int32),
+                   sds((T, ), jnp.int32))
+    assert asked == [limit]
 
 
 def test_slab_rows_follow_from_the_group_size():
@@ -240,7 +382,7 @@ def test_per_token_kernel_matches_the_gather(name):
     row_pages = np.where(
         slots != 0, pos // bs + 1 - (np.maximum(pos - window + 1, 0) // bs
                                      if window else 0), 0)
-    grid, shared, short = kernel_page_loads(
+    grid, shared, short, _ = kernel_page_loads(
         slots, pos, heads=heads, kv_heads=kv_heads, head_dim=head_dim,
         kv_dtype=kc.dtype, block_size=bs, maxb=maxb, window=window,
         row_pages=row_pages)
@@ -302,6 +444,7 @@ def test_batch_is_runs_of_consecutive_positions():
             maxb=eng.state_manager.block_table.shape[1], window=24)
         assert c["grid_pages"] == loads[0] and "live_pages" not in c
         assert c["short_pages"] == loads[2] <= c["grid_pages"]
+        assert c["block_pages"] == loads[3] == 0    # no run of 8 pages
         assert c["row_pages"] == sum(
             p // bs + 1 - max(p - 24 + 1, 0) // bs for p in pos[slots != 0])
         assert c["row_pages"] >= c["grid_pages"] > 0

@@ -373,11 +373,15 @@ def test_a_turn_keeps_its_old_counts_and_names_its_two_steps(drained, burst):
     steps = t.named(names.SERVE_STEP)
     assert [tuple(s[3][k] for k in _OLD_COUNTS) for s in steps] == \
         _OLD_VALUES[burst]
-    assert set(_OLD_COUNTS) == set(names.SERVE_STEP_COUNTS)
+    # what has joined them since: the pages of the paged kernel's
+    # multi-page items (PR 38; these prompts have no run of a block's pages)
+    assert set(names.SERVE_STEP_COUNTS) - set(_OLD_COUNTS) == {"block_pages"}
+    assert not any(s[3]["block_pages"] for s in steps)
     launches, fetches = (_in_turns(t, n) for n in (names.SERVE_LAUNCH,
                                                    names.SERVE_FETCH))
     for step, (launch, ), turn in zip(steps, launches, fetches):
-        ids = {k: v for k, v in step[3].items() if k not in _OLD_COUNTS}
+        ids = {k: v for k, v in step[3].items()
+               if k not in names.SERVE_STEP_COUNTS}
         want = {names.COUNT_LAUNCH: launch[3][names.COUNT_LAUNCH]}
         if turn:        # the newer one where the turn collected two
             want[names.COUNT_FETCHED] = turn[-1][3][names.COUNT_LAUNCH]
@@ -527,9 +531,12 @@ def test_preemption_is_an_event_with_its_uid(tiny, tmp_path):
 
 
 def _page_counts(engine, pos, slots):
-    """``(grid, row, short)`` pages of one layer's call."""
+    """``(grid, row, short)`` pages of one layer's call; none of these
+    runs is long enough for a block of pages."""
     counts = engine._page_counts(pos, slots)
-    assert list(counts) == ["grid_pages", "row_pages", "short_pages"]
+    assert list(counts) == ["grid_pages", "row_pages", "short_pages",
+                            "block_pages"]
+    assert counts.pop("block_pages") == 0
     return tuple(counts.values())
 
 
@@ -546,7 +553,7 @@ def test_page_counts_follow_the_kernel_and_the_window(tiny):
     # every load's item is short
     assert kernel_page_loads(
         slots, pos, heads=4, kv_heads=2, head_dim=128, kv_dtype=jnp.float32,
-        block_size=8, maxb=8) == (8, 0, 8)
+        block_size=8, maxb=8) == (8, 0, 8, 0)
     # a burst: k rows of positions
     assert _page_counts(engine, pos[None, :4] + np.arange(2)[:, None],
                                np.broadcast_to(slots[:4], (2, 4))) == (

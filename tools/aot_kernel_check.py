@@ -20,9 +20,12 @@ Mosaic made of that kernel: it has the compiler write the kernel after its
 last pass (``--xla_mosaic_dump_to``, a temporary directory) and prints, for
 every check that compiled the kernel, the histogram of ``llo.*`` operations in
 the body of the kernel's first loop and in each branch (``scf.if``) directly
-inside it — for ``ds_paged_runs`` the item loop: the prefetch, the item on the
-whole tile, the item on one slab.  Counts of instructions as written, not of
-cycles: the place a kernel issue starts from.
+inside it — for ``ds_paged_runs`` the item loop, whose branches it names: the
+prefetch, the wait for the rest of a block's pages, the one-page item on the
+whole tile, the BLOCK item (``item_pages`` pages through one softmax update:
+also printed a page, beside the one-page item's), the item on one slab.
+Counts of instructions as written, not of cycles: the place a kernel issue
+starts from.
 """
 
 import argparse
@@ -87,6 +90,26 @@ def loop_ops(llo_text):
         .most_common())) for name, lo, _, hi in regions]
 
 
+#: ``ds_paged_runs``' item loop and the ``scf.if`` regions in it, in order,
+#: where its items take blocks of pages (every shape checked here)
+PAGED_RUNS_REGIONS = ("loop", "prefetch", "wait for a block's other pages",
+                      "tile item", "block item", "slab item")
+
+
+def paged_runs_lines(check, regions, pages):
+    """The ``OPS`` lines of one check that compiled ``ds_paged_runs`` with
+    blocks of ``pages`` pages: each region under its name, the block item's
+    total also divided by its pages."""
+    named = len(regions) == len(PAGED_RUNS_REGIONS)
+    for n, (region, counts) in enumerate(regions):
+        name = PAGED_RUNS_REGIONS[n] if named else region
+        total = sum(counts.values())
+        if name == "block item":
+            name = f"block item of {pages} pages: {total // pages} a page"
+        yield f"OPS ds_paged_runs | {check} | {name} | {total} | " \
+            f"{json.dumps(counts)}"
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--ops", metavar="KERNEL", help="also print the histogram"
@@ -100,14 +123,15 @@ def main():
             f"--xla_mosaic_dump_to={dump.name}")))
     ops = []
 
-    def checked(name, fn, *args):
-        """:func:`check`, and the ops of the ``--ops`` kernel it compiled."""
+    def checked(name, fn, *args, pages=1):
+        """:func:`check`, and the ops of the ``--ops`` kernel it compiled
+        (``pages``: the pages of a block item, if it has one)."""
         result = check(name, fn, *args)
         if dump is not None:
             for path in sorted(glob.glob(os.path.join(
                     dump.name, f"*-{opts.ops}-post-finalize-llo.txt"))):
                 with open(path) as f:
-                    ops.append((name, loop_ops(f.read())))
+                    ops.append((name, loop_ops(f.read()), pages))
             for path in glob.glob(os.path.join(dump.name, "*")):
                 os.remove(path)
         return result
@@ -185,7 +209,7 @@ def main():
                          sds((4096, 512), jnp.float32)))
 
     from deepspeed_tpu.ops.pallas.paged_attention import (
-        paged_attention, paged_attention_per_token)
+        item_pages, paged_attention, paged_attention_per_token)
     # serving shapes at Llama-7B width: 32 heads x 128, 128-token pages
     T, maxb = 64, 5
     pq = sds((T, 32, D), bf16)
@@ -194,8 +218,8 @@ def main():
     pos = sds((T, ), jnp.int32)
     results.append(checked("paged_attention_per_token",
                          paged_attention_per_token, pq, kc, kc, bt, pos))
-    # the run-tiled kernel with both branches of an item (the tile, one slab
-    # of rows): the two serving cells' own shapes (Mistral-7B: 768-token
+    # the run-tiled kernel with every branch of an item (the tile, a block of
+    # pages on the tile, one slab of rows): the serving cells' own shapes (Mistral-7B: 768-token
     # budget, 27-page table, window 4096; EvaByte: 32 / 32 heads, 1 MB pages)
     # and their bursts' (a row a slot), a shape whose slab is 16 rows, and
     # head sizes of the zoo that stay on the per-token kernel
@@ -222,7 +246,8 @@ def main():
                 q, k, v, t, s, l, window=window),
             sds((T, heads, head_dim), bf16), kc, kc,
             sds((65, maxb), jnp.int32),
-            sds((T, ), jnp.int32), sds((T, ), jnp.int32)))
+            sds((T, ), jnp.int32), sds((T, ), jnp.int32),
+            pages=item_pages(kv_heads, head_dim, bf16, 128)))
 
     # the variant the tests count page loads and short items with
     kc = sds((64, 128, 8, D), bf16)
@@ -231,7 +256,8 @@ def main():
         lambda q, k, v, t, s, l: paged_attention(q, k, v, t, s, l,
                                                  count_loads=True),
         sds((256, 32, D), bf16), kc, kc, sds((65, 27), jnp.int32),
-        sds((256, ), jnp.int32), sds((256, ), jnp.int32)))
+        sds((256, ), jnp.int32), sds((256, ), jnp.int32),
+        pages=item_pages(8, D, bf16, 128)))
 
     # the latent cache's reader at openPangu-Ultra-MoE's sizes (128 heads on
     # rows of 512 + 64 in 640): a prefill step's buffer and a burst's
@@ -275,7 +301,10 @@ def main():
     print(f"target: {TOPOLOGY} ({kind}), compile only")
     for name, status, err in results:
         print(f"{status:4s} {name}" + (f"  {err}" if err else ""))
-    for name, regions in ops:
+    for name, regions, pages in ops:
+        if opts.ops == "ds_paged_runs":
+            print("\n".join(paged_runs_lines(name, regions, pages)))
+            continue
         for region, counts in regions:
             print(f"OPS {opts.ops} | {name} | {region} | "
                   f"{sum(counts.values())} | {json.dumps(counts)}")
