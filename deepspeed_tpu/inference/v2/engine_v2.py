@@ -242,12 +242,21 @@ class InferenceEngineV2:
         if latent and tp > 1:
             raise NotImplementedError(
                 "a latent cache has no head axis to shard over tp")
+        # and so are entries of a second kind: a state-space layer keeps a
+        # fixed row a sequence slot, not pages (ragged.py)
+        recurrent = getattr(cfg, "recurrent_state", None)
+        if recurrent and (tp > 1 or self._kv_dtype is not None):
+            raise NotImplementedError(
+                "a cache with recurrent state rows: kv_cache_dtype and "
+                "tensor parallelism are not implemented for it (a state row "
+                "has no head axis to shard, and no 8-bit form)")
         self.kv_cache = BlockedKVCache(
             cfg.num_hidden_layers, num_blocks, block_size,
             cfg.num_key_value_heads, getattr(cfg, "head_dim", 0),
             dtype=jnp.dtype(config.dtype), kv_dtype=self._kv_dtype,
             window_size=cfg.window_size if eva else 0,
-            chunk_size=cfg.chunk_size if eva else 0, latent_dim=latent)
+            chunk_size=cfg.chunk_size if eva else 0, latent_dim=latent,
+            recurrent=recurrent, max_seqs=sm.max_ragged_sequence_count)
         self.state_manager = DSStateManager(sm, self.kv_cache)
         self._budget = int(sm.max_ragged_batch_size)
         #: what the newest engine step held (``schedule_step`` or a decode
@@ -265,14 +274,17 @@ class InferenceEngineV2:
                 self.kv_cache.layers,
                 tuple(layer[:len(entry)] for entry in self.kv_cache.layers))
         self._kv = self.kv_cache.layers
-        token_bytes = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(
-            self._kv)) // (num_blocks * block_size)
+        token_bytes, seq_bytes = self.kv_cache.bytes_by_kind()
+        self._state_row_bytes = seq_bytes     # a constant of the engine
+        n_pages = self.kv_cache.page_layers
         logger.info(
             f"InferenceEngineV2: budget={self._budget} blocks={num_blocks}"
             f"×{block_size} max_seqs={self.state_manager.max_seqs} "
             f"cache={token_bytes} B/token over "
-            f"{len(self._kv)} layers"
-            + (f" (latent rows of {latent})" if latent else ""))
+            f"{n_pages} layers of pages"
+            + (f" (latent rows of {latent})" if latent else "")
+            + (f" + {seq_bytes} B/sequence over {len(self._kv) - n_pages} "
+               "layers of recurrent state" if seq_bytes else ""))
 
     # ------------------------------------------------------------- put/query
     def put(self, batch_uids, batch_tokens, do_schedule=False):
@@ -497,9 +509,11 @@ class InferenceEngineV2:
                 live = slots != 0
                 counts.update({
                     _names.COUNT_LATENT_KEYS: int(
-                        (pos + 1)[live].sum()) * len(self._kv),
+                        (pos + 1)[live].sum()) * self.kv_cache.page_layers,
                     _names.COUNT_ABSORBED_ROWS: int(live.sum()),
                     _names.COUNT_EXPANDED_ROWS: 0})
+            if "state" in self.kv_cache.kinds:
+                counts.update(self._state_counts(pos, slots))
             return counts
         total = dict.fromkeys(("grid_pages", "row_pages", "short_pages",
                                "block_pages", "grid_pages_window",
@@ -512,6 +526,24 @@ class InferenceEngineV2:
             total["grid_pages_window" if window else "grid_pages_full"] += \
                 layers * kind["grid_pages"]
         return total
+
+    def _state_counts(self, pos, slots):
+        """What a step's recurrent layers do, summed over them: the state
+        rows they read (a run that starts past position 0 starts from its
+        slot's row) and write (every run leaves its final state), and the
+        tokens their scans walk; ``state_row_bytes``: one sequence's row over
+        all of them.  A RUN is the contiguous rows of one sequence; in a
+        burst (``[k, rows]``) every live row of every iteration is one."""
+        pos, slots = np.atleast_2d(pos), np.atleast_2d(slots)
+        live = slots != 0
+        start = live.copy()
+        start[:, 1:] &= slots[:, 1:] != slots[:, :-1]
+        layers = self.kv_cache.kinds.count("state")
+        return {_names.COUNT_STATE_ROWS_READ:
+                int((start & (pos > 0)).sum()) * layers,
+                _names.COUNT_STATE_ROWS_WRITTEN: int(start.sum()) * layers,
+                _names.COUNT_SCAN_TOKENS: int(live.sum()) * layers,
+                _names.COUNT_STATE_ROW_BYTES: self._state_row_bytes}
 
     def _kind_page_counts(self, pos, slots, window):
         """``_page_counts`` of one call of a layer with this ``window``."""

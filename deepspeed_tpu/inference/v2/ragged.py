@@ -26,6 +26,19 @@ states ``kv_latent_dim``) keeps ONE row a token a layer, the same for every
 head, in one buffer a layer ``[num_blocks, block_size, latent_row]``: no K
 buffer and no V buffer, no head axis.  Allocator, tables, ``blocks_for`` and
 the claims do not know the difference.
+
+Entries of a SECOND KIND (``recurrent``: a model with state-space layers states
+``recurrent_state``, ``models/jamba.py``): a ``"state"`` layer keeps no pages
+but ``(conv_state [K - 1, slots, C], ssm_state [slots, S, C])``, a row a
+SEQUENCE SLOT (``DSSequenceDescriptor.slot``; slot 0 is the garbage row that
+padding writes to) and not a row a token, in the cache's type, threaded and
+donated exactly as pages are.  Its bytes are fixed by ``max_seqs``; the
+allocator, ``blocks_for`` and the claims count the pages of the ``"pages"``
+layers, which every such layer holds alike.  Nothing is cleared on the host: a
+run that starts at position 0 starts from zeros inside the step program, so a
+freed slot's next owner and a preempted request's recomputation read nothing
+of what the row held.  (The axes are the ones a TPU tiles without padding or
+relayout: the channels fill the lanes, ``S`` or the slots the sublanes.)
 """
 
 from dataclasses import dataclass, field
@@ -146,7 +159,7 @@ class BlockedKVCache:
 
     def __init__(self, num_layers, num_blocks, block_size, num_kv_heads,
                  head_dim, dtype=jnp.bfloat16, kv_dtype=None, window_size=0,
-                 chunk_size=0, latent_dim=0):
+                 chunk_size=0, latent_dim=0, recurrent=None, max_seqs=0):
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
         self.kv_dtype = kv_dtype
@@ -173,12 +186,36 @@ class BlockedKVCache:
             raise NotImplementedError(
                 "a latent cache with kv_cache_dtype or a window-plus-summary "
                 "layout")
-        shape = (num_blocks, block_size, num_kv_heads, head_dim)
+        #: the kind of every layer's entry: "pages" (rows a token) or
+        #: "state" (a row a sequence slot: the module docstring)
+        self.kinds = tuple(recurrent["kinds"]) if recurrent \
+            else ("pages", ) * int(num_layers)
+        if recurrent and (kv_dtype is not None or self.window_size
+                          or self.latent_dim):
+            raise NotImplementedError(
+                "recurrent state beside kv_cache_dtype, a window-plus-summary "
+                "layout or a latent cache")
+        if len(self.kinds) != int(num_layers) or (
+                recurrent and int(max_seqs) < 2):
+            raise ValueError("recurrent: a kind a layer, and max_seqs slots")
         if kv_dtype is None:
             self.dtype = jnp.dtype(dtype)
         else:
             from .kv_codec import storage_dtype
             self.dtype = jnp.dtype(storage_dtype(kv_dtype))
+        #: tokens a row of a page: 2 for a bfloat16 multi-query cache, whose
+        #: page is held ``[block_size / 2, 2, Dh]``; the paged kernels' module
+        #: states the format and why (``page_row_tokens``)
+        from ...ops.pallas.paged_attention import page_row_tokens
+        self.token_pairs = 1 if (kv_dtype is not None or self.latent_dim) \
+            else page_row_tokens(num_kv_heads, head_dim, self.dtype)
+        if self.token_pairs == 2 and (self.window_size
+                                      or self.block_size % 2):
+            raise NotImplementedError(
+                "a bfloat16 multi-query cache holds two tokens a row: an "
+                "even block_size, and no window-plus-summary layout")
+        shape = (num_blocks, self.block_size // self.token_pairs,
+                 num_kv_heads * self.token_pairs, head_dim)
         #: every leaf a buffer of its own (never a view of a shared one).
         #: scale=1 for never-written positions keeps dequant a no-op on the
         #: zero payload (garbage block included)
@@ -187,15 +224,37 @@ class BlockedKVCache:
                 (jnp.zeros(shape[:2] + (self.latent_row, ), self.dtype), )
                 for _ in range(int(num_layers)))
         else:
-            self.layers = tuple(
-                tuple(jnp.zeros(shape, self.dtype) for _ in "kv")
+            pages = lambda: tuple(jnp.zeros(shape, self.dtype) for _ in "kv") \
                 + tuple(jnp.ones(shape[:3], jnp.float32)
                         for _ in ("kv" if kv_dtype else ""))
-                for _ in range(int(num_layers)))
+            if recurrent:
+                (taps, chans), ssm = recurrent["conv"], recurrent["ssm"]
+            state = lambda: (
+                jnp.zeros((taps, int(max_seqs), chans), self.dtype),
+                jnp.zeros((int(max_seqs), ) + tuple(ssm), self.dtype))
+            self.layers = tuple(pages() if kind == "pages" else state()
+                                for kind in self.kinds)
         self.allocator = BlockedAllocator(num_blocks)
         # block 0 is the garbage sink: padding tokens in the ragged buffer
         # scatter their K/V there (their slot-0 block-table row is all zeros)
         self.allocator._free.discard(0)
+
+    @property
+    def page_layers(self):
+        """How many layers keep pages (every one, but in a cache with
+        recurrent state): what a per-layer page count is multiplied by."""
+        return self.kinds.count("pages")
+
+    def bytes_by_kind(self):
+        """``(bytes a token over the "pages" layers, bytes a sequence over
+        the "state" layers)`` as the device holds them."""
+        size = lambda kind: sum(
+            leaf.nbytes for entry, k in zip(self.layers, self.kinds)
+            if k == kind for leaf in entry)
+        slots = [e[1].shape[0] for e, k in zip(self.layers, self.kinds)
+                 if k == "state"]
+        return (size("pages") // (self.num_blocks * self.block_size),
+                size("state") // slots[0] if slots else 0)
 
     def blocks_for(self, num_tokens):
         """Blocks a sequence of ``num_tokens`` holds — the ONE function that

@@ -39,17 +39,21 @@ def _program(name, **jit_kwargs):
     return wrap
 
 
-def _ragged_program(arch, step_counts=()):
+def _ragged_program(arch, step_counts=(), slot_rows=False):
     """The ragged step of ``arch``.  ``step_counts``: the names of what the
     step counts ON THE DEVICE, the int32 vector it returns third (each a sum:
     two steps' values add); the engine fetches it with the tokens a request
-    waits for and puts it among the ``ds:serve.step`` counts."""
+    waits for and puts it among the ``ds:serve.step`` counts.  ``slot_rows``:
+    the step takes the static ``slot_rows=True`` from a caller whose buffer
+    has ONE row a slot, row ``i`` slot ``i`` (``decode_burst``)."""
     def wrap(fn):
         program = _program(_names.PROGRAM_RAGGED_STEP + arch,
                            static_argnames=("cfg", "block_size", "use_kernel",
-                                            "kv_dtype"),
+                                            "kv_dtype") + (
+                               ("slot_rows", ) if slot_rows else ()),
                            donate_argnums=(1, ))(fn)
         program.step_counts = tuple(step_counts)
+        program.slot_rows = bool(slot_rows)
         return program
     return wrap
 
@@ -95,10 +99,13 @@ def _paged_attention(q, k_cache, v_cache, block_tables, seq_slots, positions,
     if use_kernel and kv_scales is None and use_pallas_kernels():
         from ...ops.pallas.paged_attention import paged_attention
         return paged_attention(q, k_cache, v_cache, block_tables, seq_slots,
-                               positions, window=window)
+                               positions, window=window,
+                               block_size=block_size)
     tables_t = block_tables[seq_slots]
     T, H, Dh = q.shape
-    Hkv = k_cache.shape[2]
+    from ...ops.pallas.paged_attention import page_kv_heads
+    # whatever tokens a row of a page holds: the same bytes, row-major
+    Hkv = page_kv_heads(k_cache.shape, block_size)
     maxb = tables_t.shape[1]
     ctx = maxb * block_size
     k_ctx = k_cache[tables_t].reshape(T, ctx, Hkv, Dh)
@@ -170,7 +177,12 @@ def _kv_scatter(kv_layer, k, v, blk, off, kv_dtype=None):
     layer's pages at ``(blk, off)``: the scatter into the donated buffers
     and, on the quantized path, the encoding and the scale scatter."""
     if kv_dtype is None:
+        from ...ops.pallas.paged_attention import page_row_tokens
         k_pages, v_pages = kv_layer
+        if page_row_tokens(k.shape[1], k.shape[2], k_pages.dtype) == 2:
+            at = (blk, off // 2, off % 2)       # two tokens a row
+            return (k_pages.at[at].set(k[:, 0].astype(k_pages.dtype)),
+                    v_pages.at[at].set(v[:, 0].astype(v_pages.dtype)))
         return (k_pages.at[blk, off].set(k.astype(k_pages.dtype)),
                 v_pages.at[blk, off].set(v.astype(v_pages.dtype)))
     from .kv_codec import codec
@@ -809,6 +821,235 @@ def pangu_ultra_moe_ragged_step(params, kv_data, token_ids, positions,
     return logits, tuple(kv_data), jnp.stack(
         [jnp.sum(counts), jnp.sum(counts > 0)])
 
+# ---------------------------------------------------------------- Jamba
+def _run_plan(seq_slots, positions, n_slots):
+    """What the recurrent layers of a step read of its buffer, made once a
+    step.  A RUN is the contiguous rows of one sequence.  By row: ``idx``,
+    the row's place in its run; ``flags``, ``ops/pallas/selective_scan``'s
+    (a run's first row takes its slot's state, or zeros where the run starts
+    at position 0; its last row leaves the state); ``n_live``.  By slot:
+    whether the step holds a run of it (``has_run``), the run's length and
+    last row, and whether it starts at position 0 (``fresh``)."""
+    from ...ops.pallas.selective_scan import LOAD, STORE, ZERO
+    T = seq_slots.shape[0]
+    t = jnp.arange(T, dtype=jnp.int32)
+    live = seq_slots != 0
+    edge = jnp.full((1, ), -1, seq_slots.dtype)
+    start = live & (seq_slots != jnp.concatenate([edge, seq_slots[:-1]]))
+    end = live & (seq_slots != jnp.concatenate([seq_slots[1:], edge]))
+    idx = t - jax.lax.cummax(jnp.where(start, t, 0))
+    fresh = positions == idx
+    flags = jnp.where(start, jnp.where(fresh, ZERO, LOAD), 0) \
+        | jnp.where(end, STORE, 0)
+    zeros = jnp.zeros(n_slots, jnp.int32)
+    last_row = zeros.at[seq_slots].max(jnp.where(live, t, 0))
+    return dict(
+        slots=seq_slots, live=live, idx=idx, flags=flags.astype(jnp.int32),
+        n_live=jnp.max(jnp.where(live, t + 1, 0))[None],
+        has_run=zeros.at[seq_slots].max(live.astype(jnp.int32)) > 0,
+        run_len=zeros.at[seq_slots].add(live.astype(jnp.int32)),
+        last_row=last_row, fresh=fresh[last_row])
+
+
+def _slot_plan(seq_slots, positions):
+    """:func:`_run_plan` of a buffer with ONE row a slot (row ``i`` slot
+    ``i``, a burst's): every live row is a run of one token."""
+    return dict(live=seq_slots != 0, fresh=positions == 0)
+
+
+def _conv_runs(x, conv_state, conv, plan):
+    """The causal depthwise convolution over the runs of a ragged buffer ``x
+    [T, C]``: a row's taps come from its run's earlier rows and, before the
+    run's first row, from its slot's ``conv_state [K - 1, slots, C]`` (plane
+    ``K - 2`` the newest; zeros for a run that starts at position 0).
+    Returns ``(conv + bias [T, C] float32, new conv_state)``: every run
+    leaves its last ``K - 1`` inputs, in the state's type."""
+    w = conv["weight"].astype(jnp.float32)                 # [K, C]
+    K, S = w.shape[0], conv_state.shape[1]
+    x32 = x.astype(jnp.float32)
+    acc = w[K - 1] * x32 + conv["bias"].astype(jnp.float32)[:, 0]
+    idx, slots = plan["idx"], plan["slots"]
+    for j in range(1, K):                    # the run's own earlier rows
+        back = jnp.pad(x32, ((j, 0), (0, 0)))[:-j]
+        acc = acc + jnp.where((idx >= j)[:, None], back, 0) * w[K - 1 - j]
+    # what a run's first K - 1 rows take from the slot's state: made a SLOT
+    # (a few rows each), gathered a row
+    old = jnp.where(plan["fresh"][None, :, None], 0, conv_state)
+    st = old.astype(jnp.float32)
+    corr = jnp.stack([sum(w[K - 1 - j] * st[K - 1 - (j - i)]
+                          for j in range(i + 1, K)) for i in range(K - 1)])
+    corr = jnp.concatenate([corr.reshape((K - 1) * S, -1),
+                            jnp.zeros((1, x.shape[1]), jnp.float32)])
+    acc = acc + corr[jnp.where(plan["live"] & (idx < K - 1),
+                               idx * S + slots, (K - 1) * S)]
+    # the state a run leaves: its last K - 1 inputs, the old planes shifted
+    # where the run is shorter
+    planes = []
+    for k in range(K - 1):
+        back = K - 2 - k                     # rows before the run's last
+        new = x[jnp.maximum(plan["last_row"] - back, 0)] \
+            .astype(conv_state.dtype)
+        for length in range(1, back + 1):
+            new = jnp.where((plan["run_len"] == length)[:, None],
+                            old[k + length], new)
+        planes.append(jnp.where(plan["has_run"][:, None], new,
+                                conv_state[k]))
+    return acc, jnp.stack(planes)
+
+
+def _conv_slots(x, conv_state, conv, plan):
+    """:func:`_conv_runs` for a buffer of ONE row a slot (``x [slots, C]``,
+    row ``i`` slot ``i``): elementwise over the state's planes."""
+    w = conv["weight"].astype(jnp.float32)
+    K = w.shape[0]
+    old = jnp.where(plan["fresh"][None, :, None], 0, conv_state)
+    acc = w[K - 1] * x.astype(jnp.float32) \
+        + conv["bias"].astype(jnp.float32)[:, 0] \
+        + sum(w[k] * old[k].astype(jnp.float32) for k in range(K - 1))
+    new = jnp.concatenate([old[1:], x[None].astype(conv_state.dtype)])
+    return acc, jnp.where(plan["live"][None, :, None], new, conv_state)
+
+
+def _scan_runs(dt, u, B, Cm, A, ssm_state, plan, use_kernel):
+    """The recurrence over the runs of a ragged buffer (``ssm_state [slots,
+    S, C]``, a row a slot, donated): the Pallas ``ds_selective_scan`` on a
+    TPU, a ``lax.scan`` over the rows elsewhere; ``h`` is float32 inside a
+    run and takes the state's type where the run leaves it.  Returns ``(y [T,
+    C] float32, new ssm_state)``."""
+    from ...ops._use_kernels import use_pallas_kernels
+    from ...ops.pallas.selective_scan import (LOAD, STORE, ZERO,
+                                              selective_scan, state_tile)
+    if use_kernel and use_pallas_kernels() and state_tile(ssm_state):
+        return selective_scan(dt, dt * u.astype(jnp.float32), B, Cm, A,
+                              ssm_state, plan["slots"], plan["flags"],
+                              plan["n_live"])
+
+    def token(carry, row):
+        state, h = carry
+        dt_t, u_t, B_t, C_t, slot, flag = row
+        h = jnp.where((flag & LOAD) != 0, state[slot].astype(jnp.float32), h)
+        h = jnp.where((flag & ZERO) != 0, 0, h)
+        h = jnp.exp(dt_t[None, :] * A) * h \
+            + (dt_t * u_t)[None, :] * B_t[:, None]
+        state = state.at[slot].set(jnp.where(
+            (flag & STORE) != 0, h.astype(state.dtype), state[slot]))
+        return (state, h), jnp.sum(h * C_t[:, None], axis=0)
+
+    (ssm_state, _), y = jax.lax.scan(
+        token, (ssm_state, jnp.zeros(A.shape, jnp.float32)),
+        (dt, u.astype(jnp.float32), B, Cm, plan["slots"], plan["flags"]))
+    return y, ssm_state
+
+
+def _scan_slots(dt, u, B, Cm, A, ssm_state, plan):
+    """:func:`_scan_runs` for a buffer of ONE row a slot: one elementwise
+    update of every slot's ``h``, the state buffer read once and written once
+    in place."""
+    h = jnp.where(plan["fresh"][:, None, None], 0,
+                  ssm_state.astype(jnp.float32))
+    h = jnp.exp(dt[:, None, :] * A) * h \
+        + (dt * u.astype(jnp.float32))[:, None, :] * B[:, :, None]
+    y = jnp.sum(h * Cm[:, :, None], axis=1)
+    return y, jnp.where(plan["live"][:, None, None],
+                        h.astype(ssm_state.dtype), ssm_state)
+
+
+@jax.named_scope(_names.SCOPE_SSM)
+def _ssm_block(mp, h, state, plan, *, cfg, use_kernel, slot_rows):
+    """The Mamba-1 mixer of one layer over the step's buffer
+    (``models/jamba.py`` has the equations).  ``state``: the layer's entry of
+    the cache, ``(conv_state [K - 1, slots, C], ssm_state [slots, S, C])``,
+    donated buffers of their own; a run starts from ITS slot's rows (zeros at
+    position 0), never crosses into its neighbour's, and leaves its final
+    state in the slot.  Returns (out [T, D], new state)."""
+    from ...models.jamba import (conv_out, in_proj, mamba_A, ssm_gate_out,
+                                 ssm_inputs)
+    conv_state, ssm_state = state
+    with jax.named_scope(_names.SCOPE_SSM_PROJ):
+        x, z = in_proj(h, mp, cfg)
+    with jax.named_scope(_names.SCOPE_SSM_CONV):
+        acc, conv_state = (_conv_slots if slot_rows else _conv_runs)(
+            x, conv_state, mp["conv1d"], plan)
+        u = conv_out(acc, cfg)
+    with jax.named_scope(_names.SCOPE_SSM_PROJ):
+        dt, B, Cm = ssm_inputs(u, mp, cfg)
+        A = mamba_A(mp, cfg)
+    with jax.named_scope(_names.SCOPE_SSM_SCAN):
+        if slot_rows:
+            y, ssm_state = _scan_slots(dt, u, B, Cm, A, ssm_state, plan)
+        else:
+            y, ssm_state = _scan_runs(dt, u, B, Cm, A, ssm_state, plan,
+                                      use_kernel)
+    with jax.named_scope(_names.SCOPE_SSM_PROJ):
+        out = ssm_gate_out(y, u, z, mp, cfg)
+    return out, (conv_state, ssm_state)
+
+
+@_ragged_program("jamba", slot_rows=True)
+def jamba_ragged_step(params, kv_data, token_ids, positions, seq_slots,
+                      block_tables, last_token_idx, *, cfg, block_size,
+                      use_kernel=True, kv_dtype=None, slot_rows=False):
+    """One ragged engine iteration for Jamba (``models/jamba.py`` has the
+    layer's equations): Mamba-1 layers beside a few multi-query attention
+    layers WITHOUT positions, a dense SwiGLU in every layer, a tied table.
+
+    ``kv_data`` (donated) holds entries of TWO kinds (``ragged.py``): an
+    attention layer's ``(k_pages, v_pages)``, scattered in place and read by
+    the paged kernel as every other model's, and a Mamba layer's
+    ``(conv_state, ssm_state)``, a row a sequence SLOT: the buffer holds
+    several sequences' runs side by side (a prefill chunk of one, single
+    decode rows of others), and every run starts from its own slot's state
+    (zeros at position 0, so nothing is cleared on the host) and leaves its
+    final state there.  ``slot_rows``: the buffer has ONE row a slot, row
+    ``i`` slot ``i`` (a burst's): convolution and recurrence are then one
+    elementwise update over the state buffers, in place."""
+    if kv_dtype is not None:
+        raise NotImplementedError("kv_cache_dtype with recurrent state")
+    from ...models.jamba import attention_leaves, gated_mlp, rms_norm
+    dtype = jnp.dtype(cfg.dtype)
+    eps = cfg.rms_norm_eps
+
+    with jax.named_scope(_names.SCOPE_EMBED):
+        # the residual stream is held in the activations' type
+        # (models/jamba.py: where the numbers are rounded); a matrix product
+        # reads its input in the serving type
+        x = params["embed_tokens"]["weight"][token_ids].astype(cfg.act_dtype)
+    blk = block_tables[seq_slots, positions // block_size]
+    off = positions % block_size
+    plan = _slot_plan(seq_slots, positions) if slot_rows else \
+        _run_plan(seq_slots, positions, block_tables.shape[0])
+
+    kv_data = list(kv_data)
+    for l in range(cfg.num_hidden_layers):
+        lp = params[f"layers_{l}"]
+        with jax.named_scope(_names.SCOPE_NORM):
+            h = rms_norm(x, lp["input_layernorm"]["weight"], eps) \
+                .astype(dtype)
+        if cfg.is_attention(l):
+            mixed, kv_data[l] = _ragged_attention_block(
+                attention_leaves(lp["self_attn"], cfg), h, kv_data[l], blk,
+                off, block_tables, seq_slots, positions, None, None, cfg=cfg,
+                block_size=block_size, rotary=False, use_kernel=use_kernel)
+        else:
+            mixed, kv_data[l] = _ssm_block(
+                lp["mamba"], h, kv_data[l], plan, cfg=cfg,
+                use_kernel=use_kernel, slot_rows=slot_rows)
+        x = x + mixed.astype(x.dtype)
+        with jax.named_scope(_names.SCOPE_NORM):
+            h2 = rms_norm(x, lp["pre_ff_layernorm"]["weight"], eps)
+        with jax.named_scope(_names.SCOPE_MLP):
+            x = x + gated_mlp(h2, lp["mlp"], cfg)
+
+    with jax.named_scope(_names.SCOPE_LM_HEAD):
+        # only each slot's last token reaches the head; the tied table is
+        # read in the type it is held in, the products summed in float32
+        xl = rms_norm(x[last_token_idx], params["final_layernorm"]["weight"],
+                      eps).astype(dtype)
+        logits = jnp.einsum("td,vd->tv", xl,
+                            params["embed_tokens"]["weight"].astype(dtype),
+                            preferred_element_type=jnp.float32)
+    return logits, tuple(kv_data)
+
 
 RAGGED_FORWARDS = {"LlamaModel": llama_ragged_step,
                    "MixtralModel": mixtral_ragged_step,
@@ -817,7 +1058,8 @@ RAGGED_FORWARDS = {"LlamaModel": llama_ragged_step,
                    "PhiModel": phi_ragged_step,
                    "EvaByteModel": evabyte_ragged_step,
                    "Cohere2MoeModel": cohere2_moe_ragged_step,
-                   "PanguUltraMoeModel": pangu_ultra_moe_ragged_step}
+                   "PanguUltraMoeModel": pangu_ultra_moe_ragged_step,
+                   "JambaModel": jamba_ragged_step}
 
 
 def _device_sample(logits, key, temperature, top_k, top_p):
@@ -894,12 +1136,18 @@ def decode_burst(params, kv_data, tok0, pos0, active, block_tables, *,
     if key is None:
         key = jax.random.PRNGKey(0)
 
+    # a step with per-slot state takes the layout's statement (row i is
+    # slot i): its update is then elementwise over the state buffers
+    layout = {"slot_rows": True} if getattr(step_fn, "slot_rows", False) \
+        else {}
+
     def body(carry, _):
         kv, toks, pos, key, *counts = carry
         logits, kv, *counted = inner(
             params, kv, jnp.where(active, toks, 0),
             jnp.where(active, pos, 0), slots, block_tables, rows, cfg=cfg,
-            block_size=block_size, use_kernel=use_kernel, kv_dtype=kv_dtype)
+            block_size=block_size, use_kernel=use_kernel, kv_dtype=kv_dtype,
+            **layout)
         if counted:
             counts = [counts[0] + counted[0]]
         if sample:

@@ -80,14 +80,40 @@ from ._common import interpret_mode as _interpret
 
 
 # ----------------------------------------------------------- run-tiled path
+def page_row_tokens(kv_heads, head_dim, kv_dtype):
+    """TOKENS a row of a K/V page, the one statement of the page's format: 2
+    for ONE KV head (multi-query) of a whole-lane head size in bfloat16, whose
+    page is held ``[bs / 2, 2, Dh]`` (the bytes of ``[bs, 1, Dh]``
+    row-major): the device tiles a 16-bit array's second-minor axis by 2 at
+    least, so a head axis of 1 would be padded to twice the memory and Mosaic
+    could not copy such a page.  1 everywhere else: a row a token, ``[bs,
+    Hkv, Dh]``.  The cache lays its pages out by it
+    (``ragged.BlockedKVCache``), the scatter writes by it
+    (``ragged_forward._kv_scatter``), and :func:`run_tiled` takes a bfloat16
+    multi-query shape because a page of it is held so."""
+    return 2 if (kv_heads == 1 and head_dim % 128 == 0
+                 and kv_dtype == jnp.bfloat16) else 1
+
+
+def page_kv_heads(cache_shape, block_size):
+    """The KV heads of a cache ``[blocks, rows, heads a row, Dh]`` whose page
+    holds ``block_size`` tokens in ``rows`` rows (:func:`page_row_tokens` a
+    row)."""
+    return cache_shape[2] * cache_shape[1] // block_size
+
+
 def run_tiled(kv_heads, head_dim, kv_dtype):
     """Whether the run-tiled kernel takes this shape — by the shape alone
     (docs/kernels.md lists what is left on the per-token kernel).  A K/V page
     ``[bs, Hkv, Dh]`` is read per KV head by a sublane-strided load of its
     ``[bs * Hkv, Dh]`` view; a 16-bit cache packs two heads a sublane, so the
-    heads have to pair up and the packed view has to tile."""
+    heads have to pair up and the packed view has to tile.  ONE KV head
+    (multi-query) is the page itself, read whole and widened: in bfloat16 a
+    page of token pairs (:func:`page_row_tokens`)."""
     if kv_dtype not in (jnp.float32, jnp.bfloat16):
         return False
+    if kv_heads == 1:
+        return head_dim % 128 == 0
     sublanes, odd = divmod(kv_heads, 4 // jnp.dtype(kv_dtype).itemsize)
     return not odd and head_dim % 128 == 0 and (
         sublanes in (1, 2, 4, 8) or sublanes % 8 == 0)
@@ -251,12 +277,14 @@ def _head_pages(buf, kv_heads, keys):
     [rows, Hkv, Dh]`` — one sublane-strided load a head; a bfloat16 page
     is read as uint32 words that hold two heads each, and a bfloat16 IS the
     high half of its float32."""
-    rows = buf.reshape(buf.shape[0] * kv_heads, buf.shape[-1])
+    rows = buf.reshape(buf.shape[0] * buf.shape[1], buf.shape[-1])
     if buf.dtype == jnp.float32:
         if kv_heads == 1:
             return [rows[:keys]]
         return [rows[pl.ds(h, keys, stride=kv_heads), :]
                 for h in range(kv_heads)]
+    if kv_heads == 1:           # multi-query: the page is the head's rows
+        return [rows[:keys].astype(jnp.float32)]
     words = rows.bitcast(jnp.uint32)
     out = []
     for j in range(kv_heads // 2):
@@ -299,11 +327,13 @@ def _run_kernel(tables_ref, slot_ref, first_ref, npages_ref, slab_ref,
     def each_copy(k, p, buf, act):
         """``act`` (start, or wait) on the DMAs of the item at page ``p`` of
         run ``k``: its page, or its block's pages, side by side in ``buf``."""
+        page_rows = k_hbm.shape[1]     # block_size, or half (token pairs)
+
         def pages(js):
             row = slot_ref[base + k] * maxb + first_ref[base + k] + p
             for j in js:
                 blk = tables_ref[row + j]
-                at = pl.ds(j * block_size, block_size)
+                at = pl.ds(j * page_rows, page_rows)
                 act(pltpu.make_async_copy(k_hbm.at[blk], k_buf.at[buf, at],
                                           sem.at[0, buf]))
                 act(pltpu.make_async_copy(v_hbm.at[blk], v_buf.at[buf, at],
@@ -414,9 +444,10 @@ def _run_kernel(tables_ref, slot_ref, first_ref, npages_ref, slab_ref,
             .astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "count_loads"))
+@functools.partial(jax.jit, static_argnames=("window", "count_loads",
+                                             "block_size"))
 def paged_attention(q, k_cache, v_cache, block_tables, seq_slots, positions,
-                    window=0, count_loads=False):
+                    window=0, count_loads=False, block_size=None):
     """q: [T, H, Dh]; caches: [num_blocks, bs, Hkv, Dh]; block_tables:
     [max_seqs, maxb] int32; seq_slots, positions: [T] int32 → [T, H, Dh].
 
@@ -430,9 +461,19 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_slots, positions,
     items' (``[n_tiles, 3]``, all in pages; tests compare
     :func:`kernel_page_loads`).
 
+    ``block_size`` (default: the cache's second axis): the tokens of a page,
+    which a cache whose rows hold :func:`page_row_tokens` 2 has to state
+    (``[num_blocks, bs / 2, 2, Dh]``).
+
     A shape :func:`run_tiled` refuses keeps one grid row a token."""
     T, H, Dh = q.shape
-    _, bs, Hkv, _ = k_cache.shape
+    _, page_rows, page_heads, _ = k_cache.shape
+    bs = int(block_size or page_rows)
+    Hkv = page_kv_heads(k_cache.shape, bs)
+    if bs != page_rows * page_row_tokens(Hkv, Dh, k_cache.dtype):
+        raise ValueError(
+            f"pages of {page_rows} rows x {page_heads} for {bs} tokens of "
+            f"{Hkv} KV heads: page_row_tokens states the format")
     tq = tile_rows(H, Hkv, Dh, k_cache.dtype, T)
     if tq is None:
         if count_loads:
@@ -464,8 +505,8 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_slots, positions,
         out_specs.append(pl.BlockSpec((1, 1, 3), lambda i, *_: (i, 0, 0),
                                       memory_space=pltpu.SMEM))
     buffers = [
-        pltpu.VMEM((2, P * bs, Hkv, Dh), k_cache.dtype),
-        pltpu.VMEM((2, P * bs, Hkv, Dh), v_cache.dtype),
+        pltpu.VMEM((2, P * page_rows, page_heads, Dh), k_cache.dtype),
+        pltpu.VMEM((2, P * page_rows, page_heads, Dh), v_cache.dtype),
         pltpu.VMEM((Hkv, M, Dh), jnp.float32),      # q, widened
         pltpu.VMEM((Hkv, M, Dh), jnp.float32),
         pltpu.VMEM((Hkv, M, 128), jnp.float32),

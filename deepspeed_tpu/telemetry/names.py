@@ -118,6 +118,17 @@ COUNT_LATENT_KEYS = "latent_keys"
 COUNT_ABSORBED_ROWS = "absorbed_rows"
 COUNT_EXPANDED_ROWS = "expanded_rows"
 
+#: a model with RECURRENT layers (state-space: a fixed row a sequence slot in
+#: the cache): summed over those layers, the state rows a step's runs read
+#: (a run that starts at position 0 reads none) and write, the tokens their
+#: scans walk, and the bytes of one sequence's row over all of them.  Made by
+#: the batch builder, so they are the launched step's own counts on its
+#: turn's ``serve.step`` (as ``latent_keys``; nothing is counted on the device)
+COUNT_STATE_ROWS_READ = "state_rows_read"
+COUNT_STATE_ROWS_WRITTEN = "state_rows_written"
+COUNT_SCAN_TOKENS = "scan_tokens"
+COUNT_STATE_ROW_BYTES = "state_row_bytes"
+
 # ---- jitted programs (``XLA Modules`` events are ``jit_<name>(<id>)``)
 PROGRAM_MICRO = "ds_micro_"               # + the micro-step variant
 PROGRAM_APPLY = "ds_apply_update"
@@ -149,6 +160,17 @@ SCOPE_MLA_DOWN = "ds.mla_down"            # serving, inside ds.attn: the two
 SCOPE_MLA_ABSORB = "ds.mla_absorb"        # serving, inside ds.attn: q_n into
 #                                           the latent space and the latent
 #                                           output out of it (W_uk, W_uv)
+SCOPE_SSM = "ds.ssm"                      # serving: a Mamba mixer, the twin
+#                                           of ds.attn; inside it:
+SCOPE_SSM_PROJ = "ds.ssm_proj"            # in_proj, x_proj, the inner norms,
+#                                           dt_proj + softplus, gate, out_proj
+SCOPE_SSM_CONV = "ds.ssm_conv"            # the causal convolution over a
+#                                           run's rows and its slot's last
+#                                           rows, and their write-back
+SCOPE_SSM_SCAN = "ds.ssm_scan"            # the recurrence of either kind of
+#                                           step: the kernel ds_selective_scan
+#                                           or a burst's update of every
+#                                           slot, the state's read and write
 SCOPE_EVA_SUMMARY = "ds.eva_summary"      # serving: pooling the chunks a step
 #                                           completes, and their scatter
 MODULE_ATTENTION = "self_attn"            # flax module name (training)
@@ -159,6 +181,7 @@ KERNEL_PREFIX = "ds_"
 KERNEL_FLASH = "ds_flash_"                # fwd, bwd_dq, bwd_dkv (+ _bias_)
 KERNEL_PAGED = "ds_paged_"                # runs (run-tiled), decode (per token),
 #                                           latent (a latent cache's reader)
+KERNEL_SCAN = "ds_selective_scan"         # a Mamba-1 recurrence over runs
 KERNEL_OPTIMIZER = "ds_fused_"            # adam, lion, lamb_phase1/2
 
 #: JAX's own markers in a scope path

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from deepspeed_tpu.inference.v2 import InferenceEngineV2
 from deepspeed_tpu.inference.v2 import ragged_forward as rf
 from deepspeed_tpu.inference.v2.ragged import BlockedKVCache
-from deepspeed_tpu.models import (cohere2_moe, evabyte, falcon, llama,
+from deepspeed_tpu.models import (cohere2_moe, evabyte, falcon, jamba, llama,
                                   mixtral, opt, pangu_ultra_moe, phi)
 
 _spec = importlib.util.spec_from_file_location(
@@ -49,10 +49,14 @@ ZOO = {
                         cohere2_moe.cohere2_moe_tiny),
     "PanguUltraMoeModel": (pangu_ultra_moe.PanguUltraMoeModel,
                            pangu_ultra_moe.pangu_ultra_moe_tiny),
+    "JambaModel": (jamba.JambaModel, jamba.jamba_tiny),
 }
 #: the models whose cache is ONE latent buffer a layer: it never was a K and
 #: a V in one stacked array, so (c) has nothing to rebuild for them
 LATENT = ("PanguUltraMoeModel", )
+#: the models whose cache holds entries of two kinds (pages, and state rows
+#: a sequence slot): no stacked array ever held them either
+RECURRENT = ("JambaModel", )
 
 
 # a recording step and a storage-rebuilding step wrap the engine's step
@@ -117,6 +121,30 @@ def test_compiled_program_updates_the_cache_in_place(name, which):
         moved = [v for v in hlo_check.page_sized_values(text, page_bytes)
                  if v[1] not in ("scatter", "fusion")]
         assert not moved, moved[:4]
+
+
+def test_comparable_hlo_drops_what_names_the_source_and_nothing_else():
+    """``--dump``: two checkouts whose programs are the same give the same
+    text, whatever their paths and line numbers; another shape does not."""
+    def hlo(path, line, body, shape="bf16[8,128]"):
+        return "\n".join([
+            "HloModule jit_step, input_output_alias={ {0}: (1, {}, may-alias) }",
+            "", "FileNames", f'1 "{path}/ragged_forward.py"', "",
+            "FileLocations", f"1 {{file_name_id=1 line={line}}}", "",
+            "ENTRY %main (p0: s32[4], p1: bf16[8,128]) -> bf16[8,128] {",
+            f"  %c = {shape} custom-call(%p1), custom_call_target="
+            f'"tpu_custom_call", backend_config={{"custom_call_config": '
+            f'{{"body": "{body}", "needs_hlo_passes": true}}}}, '
+            f'metadata={{op_name="jit(step)/x" stack_frame_id={line}}}',
+            f"  ROOT %r = {shape} add(%c, %c), metadata={{op_name=\"y\"}}",
+            "}"])
+
+    a = hlo_check.comparable(hlo("/root/parent", 301, "TUxJUg=="))
+    assert a == hlo_check.comparable(hlo("/root/repo", 317, "TUxJUnh4"))
+    assert "parent" not in a and "301" not in a and "TUxJ" not in a
+    assert "needs_hlo_passes" in a and "ROOT %r = bf16[8,128] add" in a
+    assert a != hlo_check.comparable(
+        hlo("/root/repo", 317, "TUxJUnh4", shape="bf16[8,256]"))
 
 
 def test_hlo_reader_finds_a_copy_of_a_layer_and_knows_a_scatter():
@@ -202,7 +230,8 @@ def _engine(name, kv_dtype):
 @pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["fp", "int8"])
 @pytest.mark.parametrize("name", list(rf.RAGGED_FORWARDS))
 def test_layout_serves_what_the_stacked_array_served(name, kv_dtype):
-    if name in ("EvaByteModel", ) + LATENT and kv_dtype is not None:
+    if name in ("EvaByteModel", ) + LATENT + RECURRENT \
+            and kv_dtype is not None:
         with pytest.raises(NotImplementedError, match="kv_cache_dtype"):
             _engine(name, kv_dtype)
         return
@@ -211,6 +240,15 @@ def test_layout_serves_what_the_stacked_array_served(name, kv_dtype):
         assert [len(layer) for layer in eng._kv] == \
             [1] * eng.model_config.num_hidden_layers
         assert eng._kv[0][0].shape == (40, 8, 128)
+        return
+    if name in RECURRENT:
+        eng = _engine(name, kv_dtype)
+        kinds = eng.model_config.layer_kinds
+        assert eng.kv_cache.kinds == kinds and kinds.count("pages") == 1
+        for entry, kind in zip(eng._kv, kinds):
+            shapes = [leaf.shape for leaf in entry]
+            assert shapes == ([(40, 8, 1, 16)] * 2 if kind == "pages" else
+                              [(3, 5, 128), (5, 16, 128)]), (kind, shapes)
         return
     rng = np.random.default_rng(28)
     vocab = ZOO[name][1]().vocab_size

@@ -36,7 +36,7 @@ def test_every_kernel_compiles_for_v5e(report):
     lines = [ln for ln in r.stdout.splitlines()
              if ln.startswith(("PASS", "FAIL"))]
     assert r.returncode == 0, "\n".join(lines) + r.stderr[-1500:]
-    assert len(lines) >= 21 and all(ln.startswith("PASS") for ln in lines)
+    assert len(lines) >= 25 and all(ln.startswith("PASS") for ln in lines)
     assert "TPU v5 lite" in r.stdout
     # the run-tiled paged kernel, every branch of its item (the block of
     # pages too), at the three serving cells' shapes and their bursts': a
@@ -51,6 +51,12 @@ def test_every_kernel_compiles_for_v5e(report):
                    "paged_attention(GQA 128/8, the Command A+ cell, full)",
                    "paged_attention(GQA 128/8, the Command A+ cell's burst)",
                    "paged_attention(GQA 32/8, count_loads)",
+                   # 20 query heads on one KV head, a page of token pairs
+                   "paged_attention(MQA 20/1, the Jamba cell)",
+                   "paged_attention(MQA 20/1, the Jamba cell's burst)",
+                   "selective_scan(16 x 5120, 257 slots, the Jamba cell's "
+                   "step)",
+                   "selective_scan(16 x 5120, 257 slots, a short step)",
                    "paged_latent_attention(MLA 128 x 576, the cell's step)",
                    "paged_latent_attention(MLA 128 x 576, the cell's burst)",
                    "block_sparse_flash_attention"):

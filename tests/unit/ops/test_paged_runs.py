@@ -48,15 +48,25 @@ def _case(heads, kv_heads, runs, T, bs=8, maxb=8, window=0,
     q = jnp.asarray(rng.standard_normal((T, heads, head_dim)), dtype)
     kc, vc = (jnp.asarray(rng.standard_normal((nb, bs, kv_heads, head_dim)),
                           dtype) for _ in range(2))
+    if kv_heads == 1 and head_dim == 128 and dtype == jnp.bfloat16:
+        # a multi-query page of a 16-bit cache holds two tokens a row: the
+        # same bytes (paged_attention.page_row_tokens)
+        kc, vc = (c.reshape(nb, bs // 2, 2, head_dim) for c in (kc, vc))
     return q, kc, vc, jnp.asarray(tables), slots, pos
 
 
-def _assert_is_the_gather(out, q, kc, vc, tables, slots, pos, window):
+def _bs(kc, kv_heads):
+    """The tokens of a page (its rows, but in a cache of token pairs)."""
+    return kc.shape[1] * kc.shape[2] // kv_heads
+
+
+def _assert_is_the_gather(out, q, kc, vc, tables, slots, pos, window,
+                          bs=None):
     """``out`` equals the XLA gather on every live row and is zero on dead
     ones."""
     ref = _paged_attention(q, kc, vc, tables, jnp.asarray(slots),
-                           jnp.asarray(pos), kc.shape[1], window=window,
-                           use_kernel=False)
+                           jnp.asarray(pos), bs or kc.shape[1],
+                           window=window, use_kernel=False)
     live = slots != 0
     tol = 2e-5 if kc.dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32)[live],
@@ -108,6 +118,15 @@ CASES = {
     "bfloat16_cache_one_word_a_row": (
         4, 2, [(1, 3, 10, 0), (2, 20, 1, 10)], 16,
         {"dtype": jnp.bfloat16}, 2 + 3, 3),
+    # 16-bit MQA (Falcon, Jamba): ONE KV head, a page of two tokens a row
+    "bfloat16_mqa_two_tokens_a_row": (
+        8, 1, [(1, 3, 10, 0), (2, 20, 1, 10)], 16,
+        {"dtype": jnp.bfloat16}, 2 + 3, 3),
+    # Jamba's 20 query heads on the one KV head (slabs of 24 rows): a
+    # prefill chunk that crosses a tile of 32 tokens, and decode rows
+    "bfloat16_mqa_20_heads_a_kv_head": (
+        20, 1, [(1, 3, 40, 0), (2, 20, 1, 40), (3, 9, 1, 41)], 48,
+        {"dtype": jnp.bfloat16}, 5 + 6 + 3 + 2, 3 + 2),
     # ---- the rows an item computes (g = 4: two tokens a slab of 8 rows)
     # a burst's 64 rows at the serving cell's heads: every item is short
     "burst_of_64_one_token_runs": (
@@ -156,12 +175,12 @@ CASES = {
 def test_run_tiled_kernel_matches_the_gather(name):
     heads, kv_heads, runs, T, kw, want_loads, want_short = CASES[name]
     q, kc, vc, tables, slots, pos = _case(heads, kv_heads, runs, T, **kw)
-    bs, window = kc.shape[1], kw.get("window", 0)
+    bs, window = _bs(kc, kv_heads), kw.get("window", 0)
     assert run_tiled(kv_heads, 128, kc.dtype)
     out, loads = paged_attention(q, kc, vc, tables, jnp.asarray(slots),
                                  jnp.asarray(pos), window=window,
-                                 count_loads=True)
-    _assert_is_the_gather(out, q, kc, vc, tables, slots, pos, window)
+                                 count_loads=True, block_size=bs)
+    _assert_is_the_gather(out, q, kc, vc, tables, slots, pos, window, bs)
     # what the loops loaded is what the host counts (every one a page that
     # holds a key some row of the run may see); the items that computed one
     # slab of rows are the ones the host calls short
@@ -183,12 +202,14 @@ def test_short_items_give_the_bits_of_whole_tiles(name, monkeypatch):
                                           **kw)
     args = (q, kc, vc, tables, jnp.asarray(slots), jnp.asarray(pos))
     window = kw.get("window", 0)
-    out, loads = paged_attention(*args, window=window, count_loads=True)
+    bs = _bs(kc, kv_heads)
+    out, loads = paged_attention(*args, window=window, count_loads=True,
+                                 block_size=bs)
     monkeypatch.setattr(paged_module, "_run_kernel", functools.partial(
         paged_module._run_kernel, short=False))
     monkeypatch.setattr(paged_module, "_STACK_ROWS", 1)
     whole, whole_loads = paged_attention.__wrapped__(
-        *args, window=window, count_loads=True)
+        *args, window=window, count_loads=True, block_size=bs)
     assert int(whole_loads[:, 0].sum()) == want_loads
     assert not int(whole_loads[:, 1].sum())
     as_bits = lambda a: np.asarray(a.astype(jnp.float32)).view(np.uint32)
@@ -358,8 +379,9 @@ PER_TOKEN_CASES = {
     "head_size_64": (4, 4, 64, [(1, 3, 10, 0), (2, 20, 1, 10)], 12, {}),
     "head_size_80_window": (
         4, 2, 80, [(1, 13, 9, 0), (2, 40, 1, 9)], 12, {"window": 11}),
-    # 16-bit MQA (Falcon): every query head reads the one KV head
-    "mqa_bfloat16": (8, 1, 128, [(1, 3, 10, 0), (2, 20, 1, 10)], 12,
+    # 16-bit MQA at a head size that is not whole lanes: every query head
+    # reads the one KV head (at 128 the run-tiled kernel reads it: CASES)
+    "mqa_bfloat16_head_64": (8, 1, 64, [(1, 3, 10, 0), (2, 20, 1, 10)], 12,
                      {"dtype": jnp.bfloat16}),
     # decode rows with idle slots between (a burst's rows)
     "decode_rows_and_idle_slots": (
@@ -395,9 +417,11 @@ def test_shapes_the_run_tiled_kernel_leaves_to_the_per_token_one():
     bf16, f32 = jnp.bfloat16, jnp.float32
     assert run_tiled(8, 128, bf16) and run_tiled(32, 128, bf16)
     assert run_tiled(4, 128, bf16) and run_tiled(1, 128, f32)
+    # one KV head in 16 bits: its page holds two tokens a row
+    assert run_tiled(1, 128, bf16) and not run_tiled(3, 128, bf16)
     # head sizes that are not whole lanes; heads that do not pair up or tile
     for kv_heads, head_dim, dtype in (
-            (32, 80, bf16), (12, 64, bf16), (1, 128, bf16), (40, 128, bf16),
+            (32, 80, bf16), (12, 64, bf16), (1, 64, bf16), (40, 128, bf16),
             (12, 128, f32), (8, 128, jnp.float16)):
         assert not run_tiled(kv_heads, head_dim, dtype)
     # such a shape still answers, one grid row a token: PER_TOKEN_CASES

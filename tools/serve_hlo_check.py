@@ -10,12 +10,23 @@ configuration's serving layout, and reads the OPTIMISED HLO:
 * no instruction outside a fusion's body — a ``copy``, a slice, an update, a
   fusion — gives a value of the size of a layer's K pages or larger, except
   the scatter that writes a step's K/V rows into the donated buffer (its
-  output IS that buffer); the same inside the ``while`` body of the burst.
+  output IS that buffer); the same inside the ``while`` body of the burst;
+* for a cache with recurrent state rows (``ragged.py``: a row a sequence slot,
+  e.g. ``perfbench/configs/jamba2_3b_1chip.json``), no ``copy`` gives a value
+  of a state buffer's shape, in the step or in the burst's ``while`` body: the
+  rows are read and written where they lie.
 
-``python tools/serve_hlo_check.py [--aot] [configuration files]``: on the
-attached device, or with ``--aot`` for a described ``TPU v5 lite`` with no chip
-(compile only; JAX itself stays on the CPU).  One JSON line a program, exit 1
-if a program copies.  It says what the compiler planned, not how long it takes.
+``python tools/serve_hlo_check.py [--aot] [--dump DIR] [configuration
+files]``: on the attached device, or with ``--aot`` for a described ``TPU v5
+lite`` with no chip (compile only; JAX itself stays on the CPU).  One JSON line
+a program, exit 1 if a program copies.  It says what the compiler planned, not
+how long it takes.
+
+``--dump DIR`` also writes each program's optimised HLO there, with what
+names the source taken out (:func:`comparable`): run it in two checkouts and
+``diff -r`` the directories to see whether a change moved another
+configuration's programs at all (PR 41: the four older serving configurations'
+eight programs came out identical).
 """
 
 import argparse
@@ -42,7 +53,8 @@ import jax.numpy as jnp  # noqa: E402
 DEFAULT_CONFIGS = ("perfbench/configs/mistral7b_1chip.json",
                    "perfbench/configs/evabyte_1chip.json",
                    "perfbench/configs/command_a_plus_1chip.json",
-                   "perfbench/configs/pangu_ultra_moe_1chip.json")
+                   "perfbench/configs/pangu_ultra_moe_1chip.json",
+                   "perfbench/configs/jamba2_3b_1chip.json")
 
 # name = shape opcode(...: a tuple shape has spaces, no " word(" inside it
 _INSTR = re.compile(r"^\s*(?:ROOT )?(%?[\w.\-]+) = (.+?) ([a-z][\w\-]*)\(")
@@ -98,6 +110,28 @@ def in_place_scatter(hlo_text, name):
     return bool(body and re.search(r"ROOT \S+ = \S+ scatter\(", body.group(0)))
 
 
+def comparable(hlo_text):
+    """The optimised HLO without what only names its SOURCE: the tables of
+    files and lines before the first computation, each instruction's
+    ``metadata`` and stack frame, and the kernels' serialized Mosaic bodies
+    (bytecode that carries line numbers; ``jax.make_jaxpr`` of the kernel's
+    wrapper compares those).  Two checkouts whose programs are the same give
+    the same text."""
+    out, table = [], False
+    for line in hlo_text.split("\n"):
+        if line.strip() in ("FileNames", "FunctionNames", "FileLocations",
+                            "StackFrames"):
+            table = True
+        elif table:
+            table = bool(line.strip())
+        else:
+            out.append(line)
+    text = re.sub(r", metadata=\{[^}]*\}", "", "\n".join(out))
+    text = re.sub(r"stack_frame_id=\d+", "", text).replace("\\", "")
+    return re.sub(r"custom_call_config[^}]*body[^,}]*",
+                  "custom_call_config<body>", text)
+
+
 def aliased_parameters(hlo_text):
     """Parameter numbers in the module's ``input_output_alias`` (the
     ``HloModule`` line's ``{output index}: (parameter, {index}, kind)``)."""
@@ -105,10 +139,19 @@ def aliased_parameters(hlo_text):
                                        hlo_text.split("\n", 1)[0])}
 
 
-def check(compiled, n_params, n_cache, page_bytes):
+def state_copies(hlo_text, state_shapes):
+    """The ``copy`` instructions (outside fusions' bodies) whose result has
+    the shape of a recurrent state buffer (``bf16[3,257,5120]``)."""
+    return [f"{comp}: copy {shape}" for comp, op, shape, _ in
+            page_sized_values(hlo_text, 1)
+            if op == "copy" and shape.split("{")[0] in state_shapes]
+
+
+def check(compiled, n_params, n_cache, page_bytes, state_shapes=()):
     """One program's verdict.  ``n_params`` flat parameters come before the
     ``n_cache`` cache buffers in the entry computation's signature."""
     text = compiled.as_text()
+    copied = state_copies(text, state_shapes)
     aliased = aliased_parameters(text)
     cache = set(range(n_params, n_params + n_cache))
     moved = [v for v in page_sized_values(text, page_bytes)
@@ -121,7 +164,8 @@ def check(compiled, n_params, n_cache, page_bytes):
             "moved": [f"{c}: {op} {shape}" for c, op, shape, _ in moved[:8]],
             "temp_bytes": getattr(ma, "temp_size_in_bytes", None),
             "page_bytes": page_bytes,
-            "ok": cache <= aliased and not moved}
+            "state_buffers_copied": len(copied), "copied": copied[:8],
+            "ok": cache <= aliased and not moved and not copied}
 
 
 def programs(config, sharding=None):
@@ -137,6 +181,8 @@ def programs(config, sharding=None):
     bs, budget = int(eng["block_size"]), int(eng["token_budget"])
     seqs = int(eng["max_concurrent"]) + 1
     eva = getattr(cfg, "attention_class", None) == "eva"
+    recurrent = getattr(cfg, "recurrent_state", None)
+    kinds = recurrent["kinds"] if recurrent else ("pages", )
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
@@ -148,7 +194,8 @@ def programs(config, sharding=None):
         cfg.num_key_value_heads, getattr(cfg, "head_dim", 0),
         dtype=jnp.bfloat16, window_size=cfg.window_size if eva else 0,
         chunk_size=cfg.chunk_size if eva else 0,
-        latent_dim=getattr(cfg, "kv_latent_dim", 0)).layers)
+        latent_dim=getattr(cfg, "kv_latent_dim", 0),
+        recurrent=recurrent, max_seqs=seqs).layers)
     cache = jax.tree.map(lambda s: sds(s.shape, s.dtype), cache)
     maxb = 64                       # the block table's width moves no page
     i32 = lambda *shape: sds(shape, jnp.int32)
@@ -159,16 +206,21 @@ def programs(config, sharding=None):
     burst_kw = dict(counts0=i32(len(counts))) if counts else {}
     n_params = len(jax.tree.leaves(params))
     n_cache = len(jax.tree.leaves(cache))
-    page = cache[0][0]
+    page = cache[kinds.index("pages")][0]
     page_bytes = page.dtype.itemsize * math.prod(page.shape)
+    state_shapes = {        # a state row is held in the serving type
+        f"bf16[{','.join(map(str, leaf.shape))}]"
+        for entry, kind in zip(cache, kinds) if kind == "state"
+        for leaf in entry}
     step = step_fn.lower(params, cache, i32(budget), i32(budget), i32(budget),
                          i32(seqs, maxb), i32(seqs), **kw)
     burst = rf.decode_burst.lower(
         params, cache, i32(seqs), i32(seqs), sds((seqs, ), jnp.bool_),
         i32(seqs, maxb), step_fn=step_fn, k=int(eng["decode_burst"]), **kw,
         **burst_kw)
-    return {step_fn.__name__: (step, n_params, n_cache, page_bytes),
-            rf.decode_burst.__name__: (burst, n_params, n_cache, page_bytes)}
+    rest = (n_params, n_cache, page_bytes, state_shapes)
+    return {step_fn.__name__: (step, ) + rest,
+            rf.decode_burst.__name__: (burst, ) + rest}
 
 
 def main():
@@ -176,6 +228,8 @@ def main():
     ap.add_argument("configs", nargs="*", default=DEFAULT_CONFIGS)
     ap.add_argument("--aot", action="store_true",
                     help="compile for a described TPU v5 lite, no chip")
+    ap.add_argument("--dump", metavar="DIR",
+                    help="write each program's comparable HLO there")
     args = ap.parse_args()
     sharding, kind = None, jax.devices()[0].device_kind
     if args.aot:
@@ -191,6 +245,11 @@ def main():
         for name, (lowered, *rest) in programs(config, sharding).items():
             compiled = lowered.compile()
             row = check(compiled, *rest)
+            if args.dump:
+                os.makedirs(args.dump, exist_ok=True)
+                with open(os.path.join(args.dump, os.path.basename(path)
+                                       + "." + name + ".hlo"), "w") as f:
+                    f.write(comparable(compiled.as_text()))
             ok &= row["ok"]
             print(json.dumps({"config": os.path.basename(path),
                               "program": name, "device": kind, **row}),
