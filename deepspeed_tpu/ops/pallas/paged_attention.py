@@ -30,7 +30,8 @@ page of the table; the shapes the run-tiled kernel does not take
 ``[num_blocks, bs, L]`` rows ``(c [rank] ; k_r)``, every query head against
 the same row, so ONE page load serves the scores (all ``L`` columns) and the
 values (its first ``rank``).  It walks the same items of the same
-:func:`run_plan`, with all the heads as one KV head's group.
+:func:`run_plan`, blocks of :func:`item_pages` pages among them, with all the
+heads as one KV head's group.
 
 The XLA fallback (``inference/v2/ragged_forward._paged_attention``) computes
 the same math by gather; the kernels replace it on TPU where the gather's
@@ -72,7 +73,7 @@ _RUNS_VMEM_BYTES = 64 * 1024 * 1024
 #: (docs/kernels.md)
 _LATENT_TILE_ROWS = 1024
 #: its VMEM: the tile's q and output (each double-buffered), the float32
-#: accumulator and softmax state, two pages
+#: accumulator and softmax state, two blocks of pages, a block's score arrays
 _LATENT_VMEM_BYTES = 64 * 1024 * 1024
 
 
@@ -170,8 +171,9 @@ def item_pages(kv_heads, head_dim, kv_dtype, block_size):
     a block's price is VMEM: four buffers of ``P`` pages and score arrays
     ``P`` times as wide.  So: the flash kernels' 512 keys (4 pages of 128),
     fewer where the buffers would pass 8 MB (2 pages of 1 MB: EvaByte's 32
-    KV heads).  1: every item is one page.  docs/kernels.md has the v5e
-    sweep (``P`` 1 / 2 / 4 / 8 at the three serving cells' shapes)."""
+    KV heads).  1: every item is one page.  The latent kernel asks with its
+    one row as the one KV head (4 pages of 128 x 640).  docs/kernels.md has
+    the v5e sweeps (``P`` 1 / 2 / 4 / 8 at the serving cells' shapes)."""
     page = block_size * kv_heads * head_dim * jnp.dtype(kv_dtype).itemsize
     return int(max(1, min(_BLOCK_KEYS // block_size, _BLOCK_MAX_PAGES,
                           _BLOCK_BUFFER_BYTES // (4 * page))))
@@ -247,9 +249,10 @@ def kernel_page_loads(seq_slots, positions, *, heads, kv_heads, head_dim,
     kernel).  ``block``: of ``grid``, the loads of items that take a block
     of :func:`item_pages` pages through one softmax update
     (:func:`run_plan`'s ``n_blocks``; 0 where an item is one page: the
-    per-token kernel, the latent one).  All four count PAGES.  ``latent``: the loads of :func:`paged_latent_attention` (each
-    brings ONE page, scores and values both) for ``heads`` query heads on
-    one latent row (``kv_heads`` 1), by :func:`tile_rows`'s branch."""
+    per-token kernel).  All four count PAGES.  ``latent``: the loads of
+    :func:`paged_latent_attention` (each brings ONE page, scores and values
+    both) for ``heads`` query heads on one latent row (``kv_heads`` 1,
+    ``head_dim`` the row's length), by :func:`tile_rows`'s branch."""
     slots, pos = (np.atleast_2d(np.asarray(a))
                   for a in (seq_slots, positions))
     if row_pages is not None:
@@ -259,7 +262,7 @@ def kernel_page_loads(seq_slots, positions, *, heads, kv_heads, head_dim,
     if tq is None:
         return slots.size * maxb, \
             0 if row_pages is None else int(row_pages.sum()), 0, 0
-    P = 1 if latent else item_pages(kv_heads, head_dim, kv_dtype, block_size)
+    P = item_pages(kv_heads, head_dim, kv_dtype, block_size)
     _, rid, _, _, n_pages, slab, n_blocks = run_plan(
         np, slots, pos, tq, block_size, window, heads // kv_heads, P)
     grid, short = int(n_pages.sum()), int(n_pages[slab >= 0].sum())
@@ -547,17 +550,22 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_slots, positions,
 
 # ------------------------------------------------------------- latent path
 def _latent_kernel(tables_ref, slot_ref, first_ref, npages_ref, slab_ref,
-                   total_ref, q_ref, pos_ref, rid_ref, c_hbm, o_ref,
-                   c_buf, sem, acc_ref, m_ref, l_ref, *, tq, block_size,
-                   maxb, scale, rank):
+                   nblocks_ref, long_ref, total_ref, q_ref, pos_ref, rid_ref,
+                   c_hbm, o_ref, c_buf, sem, acc_ref, m_ref, l_ref, *, tq,
+                   block_size, maxb, scale, rank, block):
     """One Q tile of the latent cache's reader: ``q_ref [1, M, L]`` (``M =
     tq * heads`` rows, row ``t * heads + h``, each ``(q_lat [rank] ; q_r)``
     in the cache's type), ``pos_ref``/``rid_ref [1, M, 1]``, against the
-    tile's items ``(run k, page p)`` of :func:`run_plan`.  A page ``[bs, L]``
-    arrives ONCE and is both the keys (all ``L`` columns) and the values
-    (the first ``rank``); the dots take their operands in the cache's type
-    and sum in float32, the softmax state is float32.  An item computes its
-    run's slab of rows, or (-1) the tile, as :func:`_run_kernel`'s."""
+    tile's items of :func:`run_plan`: one page ``p`` of a run ``k``, or
+    (``nblocks_ref``) a BLOCK of ``block`` consecutive pages of it, which go
+    through one softmax update together, as :func:`_run_kernel`'s.  A page
+    ``[bs, L]`` arrives ONCE and is both the keys (all ``L`` columns) and the
+    values (the first ``rank``); the dots take their operands in the cache's
+    type and sum in float32, the softmax state is float32.  An item computes
+    its run's slab of rows, or (-1) the tile.  A tile none of whose runs
+    takes the whole tile (``long_ref``: a burst's every tile) walks its
+    items in a loop of its own: beside the block item in one loop body a
+    slab item costs 7 % more (docs/kernels.md)."""
     i = pl.program_id(0)
     base = i * tq
     total = total_ref[i]
@@ -569,25 +577,48 @@ def _latent_kernel(tables_ref, slot_ref, first_ref, npages_ref, slab_ref,
     m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
 
-    def copy(k, p, buf):
-        blk = tables_ref[slot_ref[base + k] * maxb + first_ref[base + k] + p]
-        return pltpu.make_async_copy(c_hbm.at[blk], c_buf.at[buf],
-                                     sem.at[buf])
+    def blocked(k, p):
+        """Whether the item at page ``p`` of run ``k`` is a block."""
+        return p < nblocks_ref[base + k] * block
+
+    def copies(k, p, buf, js, act):
+        """``act`` (start, or wait) on the DMAs of the pages ``p + j`` of run
+        ``k``, one under the other in ``c_buf[buf]``."""
+        row = slot_ref[base + k] * maxb + first_ref[base + k] + p
+        for j in js:
+            act(pltpu.make_async_copy(
+                c_hbm.at[tables_ref[row + j]],
+                c_buf.at[buf, pl.ds(j * block_size, block_size)],
+                sem.at[buf]))
+
+    def each_copy(k, p, buf, act):
+        """``act`` on the DMAs of the item at page ``p`` of run ``k``: its
+        page, or its block's pages."""
+        copies(k, p, buf, (0, ), act)
+        if block > 1:
+            pl.when(blocked(k, p))(
+                lambda: copies(k, p, buf, range(1, block), act))
+
+    start, wait = (lambda c: c.start()), (lambda c: c.wait())
 
     @pl.when(total > 0)
     def _first():
-        copy(0, 0, 0).start()
+        each_copy(0, 0, 0, start)
 
-    def attend(k, p, buf, rows):
+    def attend(k, p, buf, rows, pages=1):
+        """The item on the rows ``rows`` of the tile against ``pages``
+        consecutive pages from ``p``: ONE online-softmax update over their
+        ``pages * bs`` keys."""
         pos, rid = pos_ref[0, rows], rid_ref[0, rows]          # [n, 1]
         n = pos.shape[0]
+        keys = pages * block_size
         col = (first_ref[base + k] + p) * block_size + \
-            jax.lax.broadcasted_iota(jnp.int32, (n, block_size), 1)
+            jax.lax.broadcasted_iota(jnp.int32, (n, keys), 1)
         live = jnp.logical_and(rid == k, col <= pos)
-        page = c_buf[buf]                                      # [bs, L]
+        page = c_buf[buf, :keys]                               # [keys, L]
         s = jax.lax.dot_general(
             q_ref[0, rows], page, (((1, ), (1, )), ((), ())),
-            preferred_element_type=jnp.float32) * scale        # [n, bs]
+            preferred_element_type=jnp.float32) * scale        # [n, keys]
         s = jnp.where(live, s, _NEG_INF)
         m_prev = m_ref[rows, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -601,34 +632,70 @@ def _latent_kernel(tables_ref, slot_ref, first_ref, npages_ref, slab_ref,
         m_ref[rows] = jnp.broadcast_to(m_new, (n, m_ref.shape[1]))
         l_ref[rows] = jnp.broadcast_to(l_new, (n, l_ref.shape[1]))
 
+    def attend_slab(k, p, buf):
+        # a token's g rows start at a multiple of g: where g is whole
+        # sublane tiles the slab IS the token's rows
+        attend(k, p, buf, pl.ds(pl.multiple_of(
+            slab_ref[base + k], R if g % 8 == 0 else 8), R))
+
+    def item_after(k, p, took):
+        """The run and page of the item after the one that took ``took``
+        pages from page ``p`` of run ``k``."""
+        last = p + took == npages_ref[base + k]
+        return jnp.where(last, k + 1, k), jnp.where(last, 0, p + took)
+
     def item(it, carry):
         k, p = carry
         buf = it % 2
-        last = p + 1 == npages_ref[base + k]
-        k_next = jnp.where(last, k + 1, k)
-        p_next = jnp.where(last, 0, p + 1)
+        slab = slab_ref[base + k]
+        whole = blocked(k, p) if block > 1 else False
+        k_next, p_next = item_after(k, p, jnp.where(whole, block, 1))
 
         @pl.when(it + 1 < total)
         def _prefetch():
-            copy(k_next, p_next, 1 - buf).start()
+            each_copy(k_next, p_next, 1 - buf, start)
 
-        copy(k, p, buf).wait()
-        slab = slab_ref[base + k]
+        each_copy(k, p, buf, wait)
 
-        @pl.when(slab < 0)
+        @pl.when(jnp.logical_and(slab < 0, jnp.logical_not(whole)))
         def _tile():
             attend(k, p, buf, slice(None))
 
+        if block > 1:
+            @pl.when(whole)
+            def _block():
+                attend(k, p, buf, slice(None), block)
+
         @pl.when(slab >= 0)
         def _slab():
-            # a token's g rows start at a multiple of g: where g is whole
-            # sublane tiles the slab IS the token's rows
-            attend(k, p, buf, pl.ds(pl.multiple_of(
-                slab, R if g % 8 == 0 else 8), R))
+            attend_slab(k, p, buf)
 
         return k_next, p_next
 
-    jax.lax.fori_loop(0, total, item, (jnp.int32(0), ) * 2)
+    def slab_item(it, carry):
+        """:func:`item` where every run of the tile lies in one slab."""
+        k, p = carry
+        buf = it % 2
+        k_next, p_next = item_after(k, p, 1)
+
+        @pl.when(it + 1 < total)
+        def _prefetch():
+            copies(k_next, p_next, 1 - buf, (0, ), start)
+
+        copies(k, p, buf, (0, ), wait)
+        attend_slab(k, p, buf)
+        return k_next, p_next
+
+    long_tile = long_ref[i] > 0
+
+    @pl.when(long_tile)
+    def _items():
+        jax.lax.fori_loop(0, total, item, (jnp.int32(0), ) * 2)
+
+    @pl.when(jnp.logical_not(long_tile))
+    def _slab_items():
+        jax.lax.fori_loop(0, total, slab_item, (jnp.int32(0), ) * 2)
+
     l = l_ref[:, :1]
     o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
@@ -654,8 +721,9 @@ def paged_latent_attention(q, c_cache, block_tables, seq_slots, positions, *,
                          f"{c_cache.dtype} (latent_tiled)")
     maxb = block_tables.shape[1]
     M = tq * H
-    pos, rid, run_slot, first_page, n_pages, slab, _ = run_plan(
-        jnp, seq_slots, positions, tq, bs, 0, H)
+    P = item_pages(1, L, c_cache.dtype, bs)
+    pos, rid, run_slot, first_page, n_pages, slab, n_blocks = run_plan(
+        jnp, seq_slots, positions, tq, bs, 0, H, P)
     n = rid.shape[0]
     rows = lambda a: jnp.repeat(a, H, axis=1)[:, :, None]      # [n, M, 1]
     qt = jnp.pad(q.astype(c_cache.dtype), ((0, n * tq - T), (0, 0), (0, 0))) \
@@ -663,13 +731,13 @@ def paged_latent_attention(q, c_cache, block_tables, seq_slots, positions, *,
     tile = lambda *block: pl.BlockSpec(
         (1, ) + block, lambda i, *_: (i, ) + (0, ) * len(block))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
+        num_scalar_prefetch=8,
         grid=(n, ),
         in_specs=[tile(M, L), tile(M, 1), tile(M, 1),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=tile(M, rank),
         scratch_shapes=[
-            pltpu.VMEM((2, bs, L), c_cache.dtype),
+            pltpu.VMEM((2, P * bs, L), c_cache.dtype),
             pltpu.SemaphoreType.DMA((2, )),
             pltpu.VMEM((M, rank), jnp.float32),
             pltpu.VMEM((M, 128), jnp.float32),
@@ -678,7 +746,7 @@ def paged_latent_attention(q, c_cache, block_tables, seq_slots, positions, *,
     )
     out = pl.pallas_call(
         functools.partial(_latent_kernel, tq=tq, block_size=bs, maxb=maxb,
-                          scale=float(scale), rank=int(rank)),
+                          scale=float(scale), rank=int(rank), block=P),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, M, rank), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -688,7 +756,10 @@ def paged_latent_attention(q, c_cache, block_tables, seq_slots, positions, *,
         name="ds_paged_latent",
     )(block_tables.reshape(-1).astype(jnp.int32), run_slot.reshape(-1),
       first_page.reshape(-1), n_pages.reshape(-1), slab.reshape(-1),
-      n_pages.sum(-1), qt, rows(pos), rows(rid), c_cache)
+      n_blocks.reshape(-1),
+      ((slab < 0) & (n_pages > 0)).sum(-1).astype(jnp.int32),
+      (n_pages - n_blocks * (P - 1)).sum(-1), qt,
+      rows(pos), rows(rid), c_cache)
     return out.reshape(n * tq, H, rank)[:T]
 
 

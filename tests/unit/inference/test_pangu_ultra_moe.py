@@ -3,7 +3,8 @@ tiny size on the CPU: the ragged step over the LATENT cache (chunked prefill,
 single decode, the burst; the absorbed form) against the dense forward (the
 expanded form); the cache's layout; the absorbed products against the
 expanded ones for one layer; the 32 shares of a routed layer add up; the
-counts a step carries."""
+counts a step carries, the latent kernel's block items among them, and a
+prompt long enough to take blocks through that kernel."""
 
 import dataclasses
 
@@ -16,6 +17,8 @@ from deepspeed_tpu.inference.v2 import ragged_forward as rf
 from deepspeed_tpu.inference.v2.ragged import BlockedKVCache
 from deepspeed_tpu.models import pangu_ultra_moe as pm
 from deepspeed_tpu.moe import held_experts as he
+from deepspeed_tpu.ops.pallas.paged_attention import (item_pages,
+                                                      kernel_page_loads)
 from deepspeed_tpu.serving import build_serving_engine
 
 CFG = pm.pangu_ultra_moe_tiny()     # 1 dense + 4 routed; 16 experts, 8 held
@@ -42,14 +45,14 @@ def _greedy(model, params, prompt, new, length=64):
     return ids[len(prompt):]
 
 
-def _scheduler(model, params, burst, budget=16, sessions=2):
+def _scheduler(model, params, burst, budget=16, sessions=2, context=64):
     return build_serving_engine(
         model, params=params,
         engine_config={"dtype": "float32", "decode_burst": burst,
                        "state_manager": {
                            "max_tracked_sequences": 2 * sessions,
                            "max_ragged_sequence_count": sessions + 1,
-                           "max_context": 64, "block_size": 8,
+                           "max_context": context, "block_size": 8,
                            "num_blocks": 40,
                            "max_ragged_batch_size": budget}},
         serving_config={"max_concurrent": sessions})
@@ -194,6 +197,83 @@ def test_a_step_carries_the_latent_counts():
     assert (counts["grid_pages"], counts["row_pages"]) == (2, 8 + 5 * 2)
     assert 0 < counts["expert_copies"] <= 13 * 2 * 4
     assert 0 < counts["expert_active"] <= 8 * 4
+
+
+def test_a_latent_caches_page_counts_carry_the_kernels_block_pages():
+    """``_page_counts`` of a latent cache is ``kernel_page_loads(latent=
+    True)``'s: a prefill run of more than ``item_pages`` pages has whole
+    blocks among its loads, a burst's ``[k, rows]`` calls (every item a slab)
+    have none."""
+    model, params = _model()
+    eng = _scheduler(model, params, 8, budget=32, context=128).engine
+    row, P = eng.kv_cache.latent_row, 8
+    assert item_pages(1, row, eng.kv_cache.dtype, 8) == P
+    shapes = dict(heads=CFG.num_attention_heads, kv_heads=1, head_dim=row,
+                  kv_dtype=eng.kv_cache.dtype, block_size=8,
+                  maxb=eng.state_manager.block_table.shape[1], latent=True)
+    # rows 2..25 a chunk at positions 70..93 (pages 0-11: a block and four
+    # pages), decode rows beside it, the rest dead
+    slots, pos = np.zeros(32, np.int32), np.zeros(32, np.int32)
+    slots[0], pos[0] = 2, 17
+    slots[2:26], pos[2:26] = 1, np.arange(70, 94)
+    slots[27], pos[27] = 3, 66
+    counts = eng._page_counts(pos, slots)
+    grid, _, short, block = kernel_page_loads(slots, pos, **shapes)
+    assert (counts["grid_pages"], counts["short_pages"],
+            counts["block_pages"]) == (grid, short, block) == (
+                3 + 12 + 9, 3 + 9, P)
+    assert counts["latent_keys"] == (18 + sum(range(71, 95)) + 67) * 5
+    # a burst of three iterations over the two decoding slots
+    slots = np.tile(np.array([0, 0, 2, 3], np.int32), (3, 1))
+    pos = np.where(slots != 0, np.array([0, 0, 18, 67])[None]
+                   + np.arange(3)[:, None], 0)
+    counts = eng._page_counts(pos, slots)
+    assert counts["block_pages"] == 0 == kernel_page_loads(
+        slots, pos, **shapes)[3]
+    assert counts["grid_pages"] == counts["short_pages"] == 3 * (3 + 9)
+
+
+@pytest.mark.parametrize("burst", [0, 8], ids=["steps", "burst"])
+def test_a_prompt_long_enough_to_take_blocks_streams_generates_tokens(
+        monkeypatch, burst):
+    """A prompt of 90 tokens in chunks of 16 rows (pages of 8: its later
+    chunks' runs hold a block of 8 pages and a rest) beside a short one,
+    through ``ds_paged_latent`` itself (interpret mode): the scheduler's
+    streams are ``generate()``'s tokens on the gather, and steps carried
+    ``block_pages``.  Each engine gets its own jit of the step, so the
+    suite's cached programs (traced without the kernel gate) are neither
+    used nor replaced."""
+    monkeypatch.setenv("DS_TPU_FORCE_PALLAS", "1")
+    inner = rf.pangu_ultra_moe_ragged_step.__wrapped__
+    model, params = _model()
+    rng = np.random.default_rng(4)
+    prompts = [rng.integers(0, 256, n).tolist() for n in (90, 13)]
+
+    def engine(use_kernel):
+        sched = _scheduler(model, params, burst, context=128)
+
+        def step(*a, **kw):
+            return inner(*a, **{**kw, "use_kernel": use_kernel})
+
+        sched.engine._step_fn = jax.jit(
+            step, static_argnames=("cfg", "block_size", "use_kernel",
+                                   "kv_dtype"), donate_argnums=(1, ))
+        return sched
+
+    want = engine(False).engine.generate(prompts, max_new_tokens=10)
+    sched = engine(True)
+    blocks, build = [], sched.engine._build_batch
+
+    def counted(*a, **kw):
+        out = build(*a, **kw)
+        blocks.append(sched.engine.last_step_counts["block_pages"])
+        return out
+
+    monkeypatch.setattr(sched.engine, "_build_batch", counted)
+    assert sched.serve(prompts, max_new_tokens=10) == want
+    assert all(len(w) == 10 for w in want)
+    # the three chunks whose run ends past page 7 hold one block each
+    assert set(blocks) == {0, 8} and blocks.count(8) == 3
 
 
 def test_the_dense_layers_lead():

@@ -1,9 +1,10 @@
 """The sandbox's Mosaic gate: every Pallas kernel compiles ahead of time for
 ``TPU v5 lite`` (``tools/aot_kernel_check.py`` — libtpu describes the
 topology with no chip attached).  Compile only: nothing here says a kernel
-runs, is right, or is fast.  With ``--ops ds_paged_runs`` the tool also counts
-the instructions Mosaic made of that kernel's item loop, branch by branch: the
-one-page item on the tile, the block item (a page of it beside), the slab."""
+runs, is right, or is fast.  With ``--ops ds_paged_runs,ds_paged_latent`` the
+tool also counts the instructions Mosaic made of those kernels' item loops,
+branch by branch: the one-page item on the tile, the block item (a page of it
+beside), the slab."""
 
 import json
 import os
@@ -24,7 +25,7 @@ def report():
     kernel modules are imported."""
     r = subprocess.run(
         [sys.executable, os.path.join(ROOT, "tools", "aot_kernel_check.py"),
-         "--ops", "ds_paged_runs"],
+         "--ops", "ds_paged_runs,ds_paged_latent"],
         capture_output=True, text=True, timeout=600)
     if r.returncode == NO_TOPOLOGY_RC:
         pytest.skip("get_topology_desc unavailable: " + r.stdout.strip()[-200:])
@@ -70,11 +71,12 @@ REGIONS = ["loop", "prefetch", "wait for a block's other pages", "tile item",
 @pytest.fixture(scope="module")
 def ops(report):
     """``OPS <kernel> | <check> | <region> | <ops> | {op: count}`` of the
-    item loop of ``ds_paged_runs``: ``{check: {region: (pages, counts)}}``,
-    the block item under ``"block item"`` with the pages its name states."""
+    item loops of ``ds_paged_runs`` and ``ds_paged_latent`` (a check compiles
+    one of them): ``{check: {region: (pages, counts)}}``, the block item under
+    ``"block item"`` with the pages its name states."""
     out = {}
     for ln in report.stdout.splitlines():
-        if ln.startswith("OPS ds_paged_runs | "):
+        if ln.startswith(("OPS ds_paged_runs | ", "OPS ds_paged_latent | ")):
             _, check, region, total, counts = ln.split(" | ", 4)
             counts, pages = json.loads(counts), 1
             assert int(total) == sum(counts.values())
@@ -130,3 +132,31 @@ def test_a_block_item_pays_the_softmax_state_once_for_all_its_pages(
     for op in ("llo.vmax.xlane.f32", "llo.vadd.xlane.f32"):
         assert block[op] == tile[op], op
     assert sum(block.values()) < 0.8 * pages * sum(tile.values())
+
+
+LATENT = ("paged_latent_attention(MLA 128 x 576, the cell's step)",
+          "paged_latent_attention(MLA 128 x 576, the cell's burst)")
+
+
+@pytest.mark.parametrize("check", LATENT)
+def test_the_latent_kernels_block_item_rescales_its_accumulator_once(ops,
+                                                                     check):
+    """``ds_paged_latent``'s item loop holds the same branches.  Its block
+    item of 4 pages against 4 one-page tile items: the same dots, the lane
+    broadcasts of the state (``m``, ``alpha``, ``l`` over the accumulator's
+    columns) and the accumulator's loads and stores once and not once a page;
+    a slab item (a decode token's 128 heads) an eighth of the tile's dots."""
+    regions = ops[check]
+    assert list(regions) == REGIONS
+    assert "llo.enqueue_dma" in regions["prefetch"][1]
+    assert "llo.dma_done" in regions["wait for a block's other pages"][1]
+    (n, block), (_, tile) = regions["block item"], regions["tile item"]
+    slab = regions["slab item"][1]
+    assert n == 4
+    assert block["llo.vmatmul"] == n * tile["llo.vmatmul"]
+    assert tile["llo.vmatmul"] == 8 * slab["llo.vmatmul"]
+    assert block["llo.vperm"] <= tile["llo.vperm"] * 1.05
+    assert block["llo.vector_store"] < 2 * tile["llo.vector_store"]
+    for op in ("llo.vmax.xlane.f32", "llo.vadd.xlane.f32"):
+        assert block[op] == tile[op], op
+    assert sum(block.values()) < 0.6 * n * sum(tile.values())

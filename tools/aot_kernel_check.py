@@ -15,15 +15,16 @@ Prints one PASS/FAIL line per kernel; exit 0 only if all pass, 3 if no TPU
 topology can be described here (no libtpu).  ``tests/unit/ops/
 test_aot_kernel_check.py`` runs it in tier-1.
 
-``--ops <kernel>`` (a ``pallas_call`` name, ``ds_paged_runs``) also counts what
-Mosaic made of that kernel: it has the compiler write the kernel after its
-last pass (``--xla_mosaic_dump_to``, a temporary directory) and prints, for
-every check that compiled the kernel, the histogram of ``llo.*`` operations in
-the body of the kernel's first loop and in each branch (``scf.if``) directly
-inside it — for ``ds_paged_runs`` the item loop, whose branches it names: the
-prefetch, the wait for the rest of a block's pages, the one-page item on the
-whole tile, the BLOCK item (``item_pages`` pages through one softmax update:
-also printed a page, beside the one-page item's), the item on one slab.
+``--ops <kernel>`` (a ``pallas_call`` name, ``ds_paged_runs``; several with
+commas between) also counts what Mosaic made of that kernel: it has the
+compiler write the kernel after its last pass (``--xla_mosaic_dump_to``, a
+temporary directory) and prints, for every check that compiled the kernel, the
+histogram of ``llo.*`` operations in the body of the kernel's first loop and
+in each branch (``scf.if``) directly inside it — for ``ds_paged_runs`` and
+``ds_paged_latent`` the item loop, whose branches it names: the prefetch, the
+wait for the rest of a block's pages, the one-page item on the whole tile, the
+BLOCK item (``item_pages`` pages through one softmax update: also printed a
+page, beside the one-page item's), the item on one slab.
 Counts of instructions as written, not of cycles: the place a kernel issue
 starts from.
 """
@@ -90,30 +91,32 @@ def loop_ops(llo_text):
         .most_common())) for name, lo, _, hi in regions]
 
 
-#: ``ds_paged_runs``' item loop and the ``scf.if`` regions in it, in order,
-#: where its items take blocks of pages (every shape checked here)
-PAGED_RUNS_REGIONS = ("loop", "prefetch", "wait for a block's other pages",
-                      "tile item", "block item", "slab item")
+#: the item loop of ``ds_paged_runs`` and of ``ds_paged_latent`` and the
+#: ``scf.if`` regions in it, in order, where its items take blocks of pages
+#: (every shape checked here)
+PAGED_REGIONS = ("loop", "prefetch", "wait for a block's other pages",
+                 "tile item", "block item", "slab item")
 
 
-def paged_runs_lines(check, regions, pages):
-    """The ``OPS`` lines of one check that compiled ``ds_paged_runs`` with
-    blocks of ``pages`` pages: each region under its name, the block item's
-    total also divided by its pages."""
-    named = len(regions) == len(PAGED_RUNS_REGIONS)
+def paged_lines(kernel, check, regions, pages):
+    """The ``OPS`` lines of one check that compiled ``kernel`` (one of the
+    two above) with blocks of ``pages`` pages: each region under its name,
+    the block item's total also divided by its pages."""
+    named = len(regions) == len(PAGED_REGIONS)
     for n, (region, counts) in enumerate(regions):
-        name = PAGED_RUNS_REGIONS[n] if named else region
+        name = PAGED_REGIONS[n] if named else region
         total = sum(counts.values())
         if name == "block item":
             name = f"block item of {pages} pages: {total // pages} a page"
-        yield f"OPS ds_paged_runs | {check} | {name} | {total} | " \
+        yield f"OPS {kernel} | {check} | {name} | {total} | " \
             f"{json.dumps(counts)}"
 
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--ops", metavar="KERNEL", help="also print the histogram"
-                    " of llo.* ops in KERNEL's first loop (module docstring)")
+                    " of llo.* ops in KERNEL's first loop (module docstring);"
+                    " several kernels with commas between")
     ap.add_argument("--only", metavar="TEXT", help="compile only the checks "
                     "whose name holds TEXT (the others read SKIP)")
     opts = ap.parse_args()
@@ -132,10 +135,11 @@ def main():
             return name, "SKIP", ""
         result = check(name, fn, *args)
         if dump is not None:
-            for path in sorted(glob.glob(os.path.join(
-                    dump.name, f"*-{opts.ops}-post-finalize-llo.txt"))):
-                with open(path) as f:
-                    ops.append((name, loop_ops(f.read()), pages))
+            for kernel in opts.ops.split(","):
+                for path in sorted(glob.glob(os.path.join(
+                        dump.name, f"*-{kernel}-post-finalize-llo.txt"))):
+                    with open(path) as f:
+                        ops.append((kernel, name, loop_ops(f.read()), pages))
             for path in glob.glob(os.path.join(dump.name, "*")):
                 os.remove(path)
         return result
@@ -280,7 +284,7 @@ def main():
                 q, c, t, s, l, rank=512, scale=192 ** -0.5),
             sds((T, 128, 640), bf16), sds((64, 128, 640), bf16),
             sds((65, 193), jnp.int32), sds((T, ), jnp.int32),
-            sds((T, ), jnp.int32)))
+            sds((T, ), jnp.int32), pages=item_pages(1, 640, bf16, 128)))
 
     # the Mamba-1 recurrence at the Jamba cell's shapes: a 2048-row step over
     # 257 slots' state of 16 x 5120 (bfloat16, aliased in and out)
@@ -323,12 +327,12 @@ def main():
     print(f"target: {TOPOLOGY} ({kind}), compile only")
     for name, status, err in results:
         print(f"{status:4s} {name}" + (f"  {err}" if err else ""))
-    for name, regions, pages in ops:
-        if opts.ops == "ds_paged_runs":
-            print("\n".join(paged_runs_lines(name, regions, pages)))
+    for kernel, name, regions, pages in ops:
+        if kernel in ("ds_paged_runs", "ds_paged_latent"):
+            print("\n".join(paged_lines(kernel, name, regions, pages)))
             continue
         for region, counts in regions:
-            print(f"OPS {opts.ops} | {name} | {region} | "
+            print(f"OPS {kernel} | {name} | {region} | "
                   f"{sum(counts.values())} | {json.dumps(counts)}")
     return 0 if all(r[1] != "FAIL" for r in results) else 1
 

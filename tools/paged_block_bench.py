@@ -41,9 +41,9 @@ SHAPES = {
 }
 
 
-def kernel_ms(fn, args, reps):
-    """Milliseconds a call of the device's ``ds_paged_runs*`` events over
-    ``reps`` traced calls, and the last output."""
+def kernel_ms(fn, args, reps, kernel="ds_paged_runs"):
+    """Milliseconds a call of the device's ``<kernel>*`` events over ``reps``
+    traced calls, and the last output."""
     out = jax.block_until_ready(fn(*args))
     with tempfile.TemporaryDirectory(prefix="paged_block_") as d:
         jax.profiler.start_trace(d)
@@ -57,9 +57,9 @@ def kernel_ms(fn, args, reps):
           if plane.name.startswith("/device:TPU:0")
           for line in plane.lines if line.name == "XLA Ops"
           for e in line.events
-          if e.name.lstrip("%").startswith("ds_paged_runs")]
+          if e.name.lstrip("%").startswith(kernel)]
     if len(ns) != reps:
-        sys.exit(f"{len(ns)} ds_paged_runs events in a trace of {reps} calls")
+        sys.exit(f"{len(ns)} {kernel} events in a trace of {reps} calls")
     return sum(ns) / reps / 1e6, out
 
 
