@@ -43,8 +43,9 @@ class MixtralConfig(LlamaConfig):
     # routing probs (mixtral renormalizes)
     shared_expert_intermediate_size: int = 0  # 0 → no shared expert
     norm_topk_prob: bool = True
-    # LlamaConfig's default keeps the flash kernel's residuals; an expert
-    # layer's memory has not been measured against that (no cell trains one)
+    # LlamaConfig's default keeps the flash kernel's residuals; THIS block's
+    # memory has not been measured against that (the cell that trains a
+    # routed block, smallthinker_21b_train_8k, runs models/smallthinker.py)
     remat_policy: str = "nothing_saveable"
 
 

@@ -3,8 +3,9 @@
 A deployment of a many-expert model divides each layer's experts over several
 chips; every chip routes its tokens over the router's FULL width and computes
 the part of the result that its own experts give.  This module is that part,
-for inference (serving and the dense forward of ``models/``): no capacity, no
-dropped copy, whatever the routing.
+for serving, for the dense forward of ``models/`` and, under ``jax.grad``, for
+training (``models/smallthinker.py``): no capacity, no dropped copy, whatever
+the routing.
 
 * :func:`route` — router logits ``[T, E]`` -> each token's ``k`` experts and
   weights: softmax over all ``E`` then top-k (Mixtral), or top-k of the
@@ -13,9 +14,10 @@ dropped copy, whatever the routing.
 * :func:`held_experts_apply` — the (token, expert) copies whose expert lies
   in ``first_expert .. first_expert + H - 1`` (the stacks' own length) and
   whose row is live are gathered sorted by expert, taken through the grouped
-  SwiGLU (:func:`grouped_swiglu`: ``lax.ragged_dot``, which has a gradient;
-  with ``kernel=True``, the choice of the serving step that timed it, the
-  Pallas ``ds_grouped_matmul``, which has none) and added back weighted.
+  gated feed-forward (:func:`grouped_swiglu`: ``lax.ragged_dot``, which has a
+  gradient; with ``kernel=True``, the choice of the serving step that timed
+  it, the Pallas ``ds_grouped_matmul``, which has none) and added back
+  weighted.
   Copies that land elsewhere, and dead rows, reach no expert and nothing
   stands in for them.  With every expert held and every row live it is
   Mixtral's layer, operation for operation.
@@ -73,8 +75,13 @@ def grouped_matmul(x_sorted, w, group_sizes, kernel=False):
     (docs/kernels.md: ahead of ``ragged_dot`` on a v5e in a buffer of up to
     2560 rows over 16 experts of 4096 x 4096, behind it in one of 16 384),
     and it has no gradient: a forward that may be differentiated leaves it
-    off.  A row past ``sum(group_sizes)`` is in no group and its result is
-    undefined."""
+    off.  Training keeps ``ragged_dot`` and its transposes: at a training
+    step's shapes (15 360 rows over 16 experts of 2560 x 768) they take half
+    the time of the kernel under a ``custom_vjp`` with a transposed grouped
+    product for the weights' gradient (``tools/moe_gmm_train_bench.py``,
+    docs/kernels.md).  A row past ``sum(group_sizes)`` is in no group and its
+    result is undefined, with either path and in ``ragged_dot``'s transposes
+    too (on a TPU such rows come back as what the buffer held)."""
     if not kernel:
         return jax.lax.ragged_dot(x_sorted, w, group_sizes)
     from ..ops.pallas.grouped_matmul import gmm
@@ -83,8 +90,11 @@ def grouped_matmul(x_sorted, w, group_sizes, kernel=False):
                block_n=_tile(N), block_k=_tile(K))
 
 
-def grouped_swiglu(x_sorted, group_sizes, w1, w2, w3, kernel=False):
-    """SwiGLU of each group's expert over rows sorted by group.
+def grouped_swiglu(x_sorted, group_sizes, w1, w2, w3, kernel=False,
+                   act=jax.nn.silu):
+    """The gated feed-forward of each group's expert over rows sorted by
+    group: ``w2 (act(w1 x) * w3 x)``, SwiGLU with the default ``act``, ReGLU
+    with ``jax.nn.relu``.
 
     x_sorted: [C, D] (group g's rows contiguous, rows past ``sum(group_sizes)``
     in no group: mask what comes back for them); group_sizes: [E]; w1/w3:
@@ -92,7 +102,7 @@ def grouped_swiglu(x_sorted, group_sizes, w1, w2, w3, kernel=False):
     [C, D]."""
     gate = grouped_matmul(x_sorted, w1, group_sizes, kernel)
     up = grouped_matmul(x_sorted, w3, group_sizes, kernel)
-    return grouped_matmul(jax.nn.silu(gate) * up, w2, group_sizes, kernel)
+    return grouped_matmul(act(gate) * up, w2, group_sizes, kernel)
 
 
 def tier_rows(tokens, k, held, experts):
@@ -106,7 +116,8 @@ def tier_rows(tokens, k, held, experts):
 
 
 def held_experts_apply(x, topi, topw, w1, w2, w3, *, first_expert=0,
-                       experts=None, live=None, kernel=False):
+                       experts=None, live=None, kernel=False,
+                       act=jax.nn.silu):
     """The held experts' part of a top-k expert layer, exact.
 
     x: [T, D]; topi/topw: [T, k] each token's experts (ids over the router's
@@ -116,9 +127,10 @@ def held_experts_apply(x, topi, topw, w1, w2, w3, *, first_expert=0,
     matmul (:func:`grouped_matmul`) in the buffer of ``tier_rows``, or in the
     one buffer where there is no second; the worst case's buffer behind the
     ``lax.cond`` keeps ``ragged_dot``, which the chip's readings put ahead
-    there.  Returns ``(out [T, D] in x's type, counts [H] int32)``: the
-    weighted sum over each row's experts that are held, and the copies that
-    landed on each held expert."""
+    there; ``act``: the gate's activation (:func:`grouped_swiglu`).  Returns
+    ``(out [T, D] in x's type, counts [H] int32)``: the weighted sum over
+    each row's experts that are held, and the copies that landed on each held
+    expert."""
     T, D = x.shape
     H, k = w1.shape[0], topi.shape[1]
     local = topi.astype(jnp.int32) - first_expert
@@ -137,11 +149,16 @@ def held_experts_apply(x, topi, topw, w1, w2, w3, *, first_expert=0,
         def run(_):
             copy = order[:rows]
             token_of = copy // k
-            y = grouped_swiglu(x[token_of], counts, w1, w2, w3, kernel)
+            # a row past the copies that landed is in no group: what the
+            # grouped products return for it is undefined, forward and (the
+            # rows' gradient) backward, so it is cut off on both sides, the
+            # result before its weight multiplies it (the weight's gradient
+            # is the result)
+            in_group = (jnp.arange(rows) < landed)[:, None]
+            y = grouped_swiglu(jnp.where(in_group, x[token_of], 0), counts,
+                               w1, w2, w3, kernel, act)
             w = weights[copy].astype(y.dtype)
-            # a row past the copies that landed is in no group
-            y = jnp.where((jnp.arange(rows) < landed)[:, None],
-                          y * w[:, None], 0)
+            y = jnp.where(in_group, y, 0) * w[:, None]
             return jnp.zeros((T, D), y.dtype).at[token_of].add(y)
         return run
 

@@ -24,7 +24,11 @@ for this kernel.  Timed against it on a v5e at a serving step's shapes
 with tiles of 256 x 1024 x 1024 is ahead in buffers of up to 2560 rows, and
 ``cohere2_moe_ragged_step`` asks for it there; it is behind in a buffer of
 16 384, and it has NO gradient (no ``custom_vjp``): a forward that may be
-differentiated keeps ``ragged_dot``.
+differentiated keeps ``ragged_dot``.  One was tried for the training step
+(``tools/moe_gmm_train_bench.py``: this kernel for the forward and the rows'
+gradient, a transposed grouped product for the weights'); ``ragged_dot`` with
+its transposes took half the time at that step's shapes, so the kernel stays
+forward-only.
 """
 
 import functools

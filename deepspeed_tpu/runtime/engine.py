@@ -21,6 +21,7 @@ ZeRO stages are *sharding policies* (``zero/partition.py``), not optimizer
 subclasses; the optimizer is an optax-style transform from ``deepspeed_tpu.ops``.
 """
 
+import collections
 import os
 import re
 import tempfile
@@ -277,6 +278,12 @@ class DeepSpeedEngine:
         # (reference _configure_distributed_model engine.py:1145: dtype cast +
         # device move; here: build apply_fn + cast/shard params)
         self.module = model
+        # counts a model makes on the device in a training micro-step
+        # (models/smallthinker.py): their names, and the arrays of the
+        # micro-steps not yet booked on a span (_ready_device_counts)
+        self._device_count_names = tuple(
+            getattr(model, "device_counts", None) or ())
+        self._pending_counts = collections.deque()
         if _is_flax_module(model):
             def apply_fn(params, *inputs, rngs=None, **kw):
                 variables = {"params": params}
@@ -1252,7 +1259,8 @@ class DeepSpeedEngine:
             from .domino.transformer import split_microstreams
             apply_fn = split_microstreams(apply_fn, dc.n_streams)
         from .utils import make_scaled_loss_fn
-        loss_fn = make_scaled_loss_fn(apply_fn, gas)
+        loss_fn = make_scaled_loss_fn(apply_fn, gas,
+                                      bool(self._device_count_names))
 
         from .zero.overlap import overlap_opts
         ov = overlap_opts(co)
@@ -1645,8 +1653,14 @@ class DeepSpeedEngine:
         # freed the buffers it needs (the host then waits HERE for the
         # previous step).  The recorder's "forward" phase is this span.
         with _telemetry.scope(_names.TRAIN_MICRO,
-                              phase=_telemetry.SPAN_FORWARD, **ids):
+                              phase=_telemetry.SPAN_FORWARD, **ids,
+                              **self._ready_device_counts()):
             loss, grads = micro(self.params, self.scale_state.scale, inputs)
+        if isinstance(loss, tuple):
+            # what the model counted on the device: booked on the span of
+            # the first later call that finds the array ready
+            loss, counts = loss
+            self._pending_counts.append(counts)
         from ..utils.fault_injection import fault_point
         if fault_point("engine.poison", step=self.micro_steps):
             # injected data poisoning: NaN loss + grads, exactly what a bad
@@ -1660,6 +1674,23 @@ class DeepSpeedEngine:
         self.timers(FORWARD_GLOBAL_TIMER).stop()
         self._maybe_profile_flops(inputs)
         return loss
+
+    def _ready_device_counts(self):
+        """The counts of the earlier micro-steps whose arrays the device has
+        finished, summed under the model's ``device_counts`` names, with how
+        many micro-steps they cover; ``{}`` where none is ready.  Never a
+        wait for the device: an array that is not ready stays for a later
+        call, and one that is costs the copy of a few integers."""
+        pending, total, n = self._pending_counts, None, 0
+        while pending and pending[0].is_ready():
+            c = np.asarray(pending.popleft())
+            total = c if total is None else total + c
+            n += 1
+        if not n:
+            return {}
+        return {**{k: int(v) for k, v in zip(self._device_count_names,
+                                             total)},
+                _names.COUNT_MICROS_COVERED: n}
 
     def _eval_forward(self, inputs, kwargs):
         """Compiled eval/validation forward, shape-keyed like the train

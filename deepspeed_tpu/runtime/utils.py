@@ -150,15 +150,18 @@ def ensure_directory_exists(filename):
     os.makedirs(os.path.dirname(os.path.abspath(filename)), exist_ok=True)
 
 
-def make_scaled_loss_fn(apply_fn, gas):
+def make_scaled_loss_fn(apply_fn, gas, device_counts=False):
     """The one loss-scaling convention shared by every micro-step variant
     (GSPMD, qgZ manual-SPMD, 1-bit local-grad): scale for fp16, divide by GAS
-    (reference engine.backward :2023), return (scaled, raw) for has_aux."""
+    (reference engine.backward :2023), return (scaled, raw) for has_aux.
+    ``device_counts``: the model returns ``(loss, counts)`` and the counts
+    leave beside the loss, as ``(scaled, (raw, counts))``."""
 
     def loss_fn(params, scale, inputs):
         out = apply_fn(params, *inputs)
         loss = out[0] if isinstance(out, (tuple, list)) else out
-        return loss.astype(jnp.float32) * scale / gas, loss
+        aux = (loss, out[1]) if device_counts else loss
+        return loss.astype(jnp.float32) * scale / gas, aux
 
     return loss_fn
 

@@ -110,6 +110,14 @@ COUNT_EXPERT_COPIES = "expert_copies"         # (row, expert) pairs on a held
 #                                               expert, summed over layers
 COUNT_EXPERT_ACTIVE = "expert_active"         # held experts with a copy,
 #                                               summed over layers
+#: TRAINING counts the same two on the device inside the micro-step, and the
+#: fullest held expert's copies, summed over layers (what the grouped products
+#: of a layer wait on).  They leave the program beside the loss and are the
+#: stats of a LATER step's ``train.micro`` span, the first whose call finds
+#: the array ready (the loop never waits for the device), with the
+#: number of micro-steps whose counts it brings (they add)
+COUNT_EXPERT_ROWS_MAX = "expert_rows_max"
+COUNT_MICROS_COVERED = "micro_steps_covered"
 
 #: a model with a LATENT cache (multi-head latent attention): the (live row,
 #: key) pairs its rows attend, summed over the layers, and the live rows
