@@ -4,7 +4,9 @@ topology with no chip attached).  Compile only: nothing here says a kernel
 runs, is right, or is fast.  With ``--ops ds_paged_runs,ds_paged_latent`` the
 tool also counts the instructions Mosaic made of those kernels' item loops,
 branch by branch: the one-page item on the tile, the block item (a page of it
-beside), the slab."""
+beside), the slab; with ``ds_flash_fwd,ds_flash_bwd_dq,ds_flash_bwd_dkv`` those
+of a grid step of the flash kernels at the training cells' shapes: an edge
+block, an interior block, a row's first and last step."""
 
 import json
 import os
@@ -25,7 +27,8 @@ def report():
     kernel modules are imported."""
     r = subprocess.run(
         [sys.executable, os.path.join(ROOT, "tools", "aot_kernel_check.py"),
-         "--ops", "ds_paged_runs,ds_paged_latent"],
+         "--ops", "ds_paged_runs,ds_paged_latent,"
+         "ds_flash_fwd,ds_flash_bwd_dq,ds_flash_bwd_dkv"],
         capture_output=True, text=True, timeout=600)
     if r.returncode == NO_TOPOLOGY_RC:
         pytest.skip("get_topology_desc unavailable: " + r.stdout.strip()[-200:])
@@ -37,12 +40,16 @@ def test_every_kernel_compiles_for_v5e(report):
     lines = [ln for ln in r.stdout.splitlines()
              if ln.startswith(("PASS", "FAIL"))]
     assert r.returncode == 0, "\n".join(lines) + r.stderr[-1500:]
-    assert len(lines) >= 25 and all(ln.startswith("PASS") for ln in lines)
+    assert len(lines) >= 32 and all(ln.startswith("PASS") for ln in lines)
     assert "TPU v5 lite" in r.stdout
     # the run-tiled paged kernel, every branch of its item (the block of
     # pages too), at the three serving cells' shapes and their bursts': a
     # failure of the kind of PERF.md's fault 1 is met here, before a cell is
-    for kernel in ("flash_attention(grad", "paged_attention_per_token",
+    for kernel in ("flash_attention(grad, S=2048",
+                   "flash_attention(ALiBi, grad)",
+                   *FLASH_CELLS, *(c.replace("(", "(grad, ", 1)
+                                   for c in FLASH_CELLS),
+                   "paged_attention_per_token",
                    "paged_attention(GQA 32/8, the cell)",
                    "paged_attention(GQA 32/8, the cell's burst)",
                    "paged_attention(MHA 32/32, the EvaByte cell)",
@@ -63,6 +70,15 @@ def test_every_kernel_compiles_for_v5e(report):
                    "block_sparse_flash_attention"):
         assert any(kernel in ln for ln in lines), kernel
 
+
+#: the flash kernels at the shapes the three training cells run (bfloat16, a
+#: sequence a chip, blocks of 512): forward, and under ``jax.grad``
+FLASH_CELLS = (
+    "flash_attention(S 8192, 28 heads, window 4096: the SmallThinker cell)",
+    "flash_attention(S 8192, 28 heads, full: the SmallThinker cell)",
+    "flash_attention(S 4096, 32 heads, window 4096: the Mistral cells)")
+FLASH_REGIONS = ["every step", "first step of a row", "edge block",
+                 "interior block", "last step of a row"]
 
 REGIONS = ["loop", "prefetch", "wait for a block's other pages", "tile item",
            "block item", "slab item"]
@@ -160,3 +176,59 @@ def test_the_latent_kernels_block_item_rescales_its_accumulator_once(ops,
     for op in ("llo.vmax.xlane.f32", "llo.vadd.xlane.f32"):
         assert block[op] == tile[op], op
     assert sum(block.values()) < 0.6 * n * sum(tile.values())
+
+
+@pytest.fixture(scope="module")
+def flash_ops(report):
+    """``{(kernel, check): {region: counts}}`` of the ``OPS ds_flash_*``
+    lines: a grid step's ``pl.when`` regions under their names."""
+    out = {}
+    for ln in report.stdout.splitlines():
+        if ln.startswith("OPS ds_flash_"):
+            kernel, check, region, total, counts = ln[4:].split(" | ", 4)
+            counts = json.loads(counts)
+            assert int(total) == sum(counts.values())
+            out.setdefault((kernel, check), {})[region] = counts
+    return out
+
+
+def _count(counts, prefix):
+    return sum(n for op, n in counts.items() if op.startswith(prefix))
+
+
+@pytest.mark.parametrize("kernel", ["ds_flash_fwd", "ds_flash_bwd_dq",
+                                    "ds_flash_bwd_dkv"])
+@pytest.mark.parametrize("check", FLASH_CELLS)
+def test_a_flash_step_does_only_what_its_block_needs(flash_ops, check,
+                                                     kernel):
+    """At the cells' shapes (blocks of 1024) a grid step of each kernel is a
+    live block (a dead one is no step): outside its regions scalar work
+    alone; on an INTERIOR block no compare, no select and no mask ``and``; on
+    an EDGE block the mask over all 1024 vregs of the tile; the same dots on
+    both (1024 ``vmatmul`` a 1024 x 1024 x 128 product of float32 operands);
+    no transpose and no identity in a block of the backward; ``lse`` /
+    ``delta`` packed and unpacked by the XLU once a row, with no identity
+    product."""
+    regions = flash_ops[(kernel, check.replace("(", "(grad, ", 1))]
+    assert list(regions) == FLASH_REGIONS
+    if kernel == "ds_flash_fwd":        # the forward alone: the same step
+        assert flash_ops[(kernel, check)] == regions
+    every, first, edge, interior, last = regions.values()
+    assert not any(op.startswith("llo.v") for op in every) and \
+        sum(every.values()) < 400
+    for op in ("llo.vcmp", "llo.vselect", "llo.vmand"):
+        assert _count(interior, op) == 0, op
+    assert edge["llo.vmand"] >= 1024 and edge["llo.vselect"] >= 1024
+    dots = {"ds_flash_fwd": 2, "ds_flash_bwd_dq": 3, "ds_flash_bwd_dkv": 4}
+    for block in (edge, interior):
+        assert block["llo.vmatmul"] == 1024 * dots[kernel]
+        assert block["llo.vexp.f32"] <= 1024 + 128
+        if kernel != "ds_flash_fwd":
+            assert "llo.vxpose" not in block
+    for region in regions.values():     # no identity is built anywhere
+        assert "llo.vcmp.eq.s32" not in region
+    assert sum(interior.values()) < 0.92 * sum(edge.values())
+    if kernel == "ds_flash_fwd":        # lse to its packed row
+        assert last["llo.vxpose"] == 128 and "llo.vmatmul" not in last
+    if kernel == "ds_flash_bwd_dq":     # lse / delta to columns once a row
+        assert first["llo.vxpose"] == 256 and "llo.vmatmul" not in first

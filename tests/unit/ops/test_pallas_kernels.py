@@ -3,6 +3,8 @@ pattern: each native kernel is tested against a framework implementation).
 
 Kernels run in interpret mode on CPU (``_interpret()`` auto-detects)."""
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,6 +17,10 @@ from deepspeed_tpu.ops.pallas.optimizers import (fused_adam_step,
                                                  fused_lion_step)
 from deepspeed_tpu.ops.pallas.quantizer import (dequantize_blockwise,
                                                 quantize_blockwise)
+
+
+# (the package's attribute ``flash_attention`` is the function)
+fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
 
 
 def _rand(key, shape, dtype=jnp.float32):
@@ -151,6 +157,178 @@ def test_flash_attention_dead_rows_no_nan():
                                atol=5e-5, rtol=5e-5)
     for a, b in zip(g[1:], gr[1:]):
         assert bool(jnp.all(jnp.isfinite(a)))
+
+
+# bfloat16 inputs, as the training cells send them (float32 inputs: the
+# 2e-5 / 5e-5 limits above).  The reference is float32 XLA attention on the
+# SAME values; what differs is the rounding of the results to bfloat16 (and,
+# on the chip alone, of each product's operands inside the MXU).
+BF16_CASES = {
+    # name: (sq, sk, Hq, Hkv, D, block, window, alibi)
+    "no window": (256, 256, 2, 2, 64, 64, 0, False),
+    # the cells' blocks: the window's edge and the diagonal cross blocks of
+    # 512, one block a row is interior, the oldest are dead
+    "window binds, crosses blocks": (1536, 1536, 1, 1, 64, 512, 640, False),
+    "GQA 4:1 through the index maps": (192, 192, 4, 1, 32, 64, 0, False),
+    "GQA 7:1, window": (192, 192, 7, 1, 32, 64, 100, False),
+    "S not a multiple of the block": (200, 200, 2, 2, 64, 64, 0, False),
+    "sk > sq": (64, 192, 2, 2, 64, 64, 0, False),
+    "sk > sq, window": (64, 192, 2, 1, 64, 32, 72, False),
+    "ALiBi": (192, 192, 4, 4, 32, 64, 0, True),
+}
+
+
+@pytest.mark.parametrize("case", list(BF16_CASES))
+def test_flash_attention_bfloat16_against_float32_reference(case):
+    sq, sk, Hq, Hkv, D, block, window, alibi = BF16_CASES[case]
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    q = _rand(ks[0], (1, sq, Hq, D), jnp.bfloat16)
+    k = _rand(ks[1], (1, sk, Hkv, D), jnp.bfloat16)
+    v = _rand(ks[2], (1, sk, Hkv, D), jnp.bfloat16)
+    cot = _rand(ks[3], (1, sq, Hq, D))
+    slopes = None
+    if alibi:
+        from deepspeed_tpu.models.bloom import alibi_slopes
+        slopes = alibi_slopes(Hq)
+
+    def flash(q, k, v):
+        o = flash_attention(q, k, v, causal=True, window=window,
+                            alibi_slopes=slopes, block_q=block, block_k=block)
+        assert o.dtype == jnp.bfloat16
+        return jnp.sum(o.astype(jnp.float32) * cot), o
+
+    def ref(q, k, v):
+        rep = lambda x: jnp.repeat(x, Hq // Hkv, axis=2)
+        o = _xla_attention(q, rep(k), rep(v), causal=True, window=window,
+                           alibi_slopes=slopes)
+        return jnp.sum(o * cot), o
+
+    f32 = lambda x: np.asarray(x, np.float32)
+    (_, o), g = jax.value_and_grad(flash, argnums=(0, 1, 2), has_aux=True)(
+        q, k, v)
+    (_, o_ref), g_ref = jax.value_and_grad(
+        ref, argnums=(0, 1, 2), has_aux=True)(*(x.astype(jnp.float32)
+                                                for x in (q, k, v)))
+    np.testing.assert_allclose(f32(o), o_ref, atol=2e-2, rtol=2e-2)
+    for a, b, name in zip(g, g_ref, ("dq", "dk", "dv")):
+        assert a.dtype == jnp.bfloat16
+        # a gradient entry sums up to S rounded terms: against its scale
+        np.testing.assert_allclose(f32(a), b, rtol=2e-2,
+                                   atol=2e-2 * float(np.abs(b).max()),
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_flash_interior_body_equals_edge_body_to_the_bit(dtype, monkeypatch):
+    """On an interior block the mask is all true: the body without it gives
+    the bits of the body with it (every live block masked is the kernel
+    before the split), value and gradients.  Held to the bit in bfloat16;
+    in float32 to the last bits, because the CPU's XLA orders the row sums
+    of the two interpreted programs differently (its choice, not the
+    bodies': they differ by selects that pick their first operand)."""
+    sq = sk = 160
+    where = (True, sq, sk, 32, 32, 70)
+    steps, live, edge = fa.block_counts(sq, sk, *where[3:5], True, 70)
+    assert 0 < edge < live < steps       # all three kinds of step are run
+    ks = jax.random.split(jax.random.PRNGKey(13), 3)
+    q, k, v = (_rand(kk, (1, sq, 2, 32), jnp.dtype(dtype)) for kk in ks)
+
+    def run():
+        def loss(q, k, v):
+            o = flash_attention(q, k, v, causal=True, window=70, block_q=32,
+                                block_k=32)
+            return jnp.sum(o.astype(jnp.float32) ** 2), o
+        (_, o), g = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                       has_aux=True)(q, k, v)
+        return [np.asarray(x, np.float32) for x in (o, *g)]
+
+    split = run()
+    monkeypatch.setattr(fa, "_block_needs_mask",
+                        lambda q_start, k_start, *a: k_start >= 0)
+    for a, b in zip(split, run()):
+        if dtype == "bfloat16":
+            np.testing.assert_array_equal(a, b)
+        else:
+            np.testing.assert_allclose(a, b, rtol=2e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("S, window, block, want", [
+    # smallthinker_21b_train_8k, 3 layers of 4; its full layer;
+    # mistral7b_train_4k (no dead window block): at blocks of 512, then at
+    # the default's
+    (8192, 4096, 512, (256, 108, 24)),
+    (8192, 0, 512, (256, 136, 16)),
+    (4096, 4096, 512, (64, 36, 8)),
+    (8192, 4096, None, (64, 30, 12)),
+    (8192, 0, None, (64, 36, 8)),
+    (4096, 4096, None, (16, 10, 4)),
+])
+def test_block_counts_at_the_cells_shapes(S, window, block, want):
+    assert (fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K) == (1024, 1024)
+    block = block or fa.DEFAULT_BLOCK_Q
+    assert fa.block_counts(S, S, block, block, True, window) == want
+
+
+GRIDS = [
+    # sq, sk, block_q, block_k, causal, window
+    (8192, 8192, 512, 512, True, 4096),
+    (96, 200, 16, 32, True, 40),      # sk > sq, a padded key tail
+    (128, 70, 32, 16, True, 33),      # sk < sq: the first rows are dead
+    (70, 70, 16, 64, True, 7),
+    (100, 100, 32, 32, True, 0),
+    (64, 96, 16, 32, False, 0),
+]
+
+
+@pytest.mark.parametrize("sq, sk, bq, bk, causal, window", GRIDS)
+def test_a_mask_is_built_only_where_it_is_not_all_true(sq, sk, bq, bk,
+                                                       causal, window):
+    """``_block_needs_mask`` against the mask itself on every live block, and
+    a dead block's mask is all false."""
+    where = (causal, sq, sk, bq, bk, window)
+    for i in range(-(-sq // bq)):
+        for j in range(-(-sk // bk)):
+            mask = np.asarray(fa._score_mask(i * bq, j * bk, *where))
+            if fa._block_live(i * bq, j * bk, *where):
+                assert fa._block_needs_mask(i * bq, j * bk, *where) == (
+                    not mask.all()), (i, j)
+            else:
+                assert not mask.any(), (i, j)
+            np.testing.assert_array_equal(      # the transposed form's
+                fa._score_mask(i * bq, j * bk, *where, key_axis=0), mask.T)
+
+
+@pytest.mark.parametrize("by_k", [False, True])
+@pytest.mark.parametrize("sq, sk, bq, bk, causal, window", GRIDS)
+def test_the_grid_walks_the_live_blocks_alone(sq, sk, bq, bk, causal, window,
+                                              by_k):
+    """The steps of a head's grid (the tables the index maps read): every
+    live block once, row after row (K blocks of a q block; ``by_k``, the q
+    blocks of a K block), and nothing dead, so a dead block costs neither a
+    fetch nor a turn; a row with no live block keeps one step, whose mask is
+    all false, so that its outputs are written."""
+    where = (causal, sq, sk, bq, bk, window)
+    nq, nk = -(-sq // bq), -(-sk // bk)
+    live = np.array([[bool(fa._block_live(i * bq, j * bk, *where))
+                      for j in range(nk)] for i in range(nq)])
+    iq, ik = fa._live_steps(sq, sk, bq, bk, causal, window, by_k=by_k)
+    assert iq.dtype == ik.dtype == np.int32
+    outer, inner = (ik, iq) if by_k else (iq, ik)
+    rows = live.T if by_k else live
+    steps = list(zip(outer.tolist(), inner.tolist()))
+    assert steps == sorted(set(steps))          # once each, in row order
+    assert sorted(set(outer.tolist())) == list(range(len(rows)))
+    for r, row in enumerate(rows):
+        mine = [c for o, c in steps if o == r]
+        if row.any():
+            assert mine == [c for c in range(len(row)) if row[c]]
+        else:
+            c, = mine
+            i, j = (c, r) if by_k else (r, c)
+            assert not np.asarray(fa._score_mask(i * bq, j * bk,
+                                                 *where)).any()
+            assert fa._block_needs_mask(i * bq, j * bk, *where)
+    assert fa.block_counts(sq, sk, bq, bk, causal, window)[1] == live.sum()
 
 
 # ------------------------------------------------------------- optimizers

@@ -71,6 +71,31 @@ def test_flash_window_gqa_and_grads():
                                    atol=1e-4, rtol=1e-4, err_msg=name)
 
 
+@pytest.mark.parametrize("window", [20, 33, 70, 96])
+def test_flash_window_grads_over_edge_interior_and_dead_blocks(window):
+    """Blocks of 32 over 160 tokens: the diagonal and the window's lower edge
+    cross some blocks (masked), some lie wholly inside the window (no mask
+    is built), the oldest are dead (skipped, nothing fetched); with 20 no
+    block is interior.  Float32 at the float32 limits."""
+    q, k, v = _qkv(S=160, H=4, Hkv=2)
+
+    def loss_flash(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, window=window,
+                                       block_q=32, block_k=32) ** 2)
+
+    def loss_ref(q, k, v):
+        kr = jnp.repeat(k, 2, axis=2)
+        vr = jnp.repeat(v, 2, axis=2)
+        return jnp.sum(naive_window(q, kr, vr, window).astype(q.dtype) ** 2)
+
+    vf, gf = jax.value_and_grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    vr, gr = jax.value_and_grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(vf, vr, rtol=1e-5)
+    for a, b, name in zip(gf, gr, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-4, rtol=1e-4, err_msg=name)
+
+
 def test_window_requires_causal():
     q, k, v = _qkv(S=16)
     with pytest.raises(ValueError, match="causal"):

@@ -69,15 +69,18 @@ def check(name, fn, *args):
 def loop_ops(llo_text):
     """``[(region, {llo op: count})]`` of the first ``scf.for`` of a kernel's
     final LLO text: ``"loop"`` (its whole body) and ``"if <n>"`` for each
-    ``scf.if`` directly inside it, in order; [] with no loop.  An attribute's
-    braces open and close on one line, so a region ends on the line after
-    which the count of open braces is what it was before the region's
-    first."""
+    ``scf.if`` directly inside it, in order.  A kernel with no loop (the flash
+    kernels: a grid step is the whole program) gives its ``@main`` instead,
+    under ``"step"``.  An attribute's braces open and close on one line, so a
+    region ends on the line after which the count of open braces is what it
+    was before the region's first."""
     lines = llo_text.splitlines()
+    outer, name = ("scf.for", "loop") if "scf.for" in llo_text else (
+        "func.func @main", "step")
     regions, depth = [], 0      # [name, first line, depth before it, last]
     for n, line in enumerate(lines):
-        if not regions and "scf.for" in line:
-            regions.append(["loop", n, depth, None])
+        if not regions and outer in line:
+            regions.append([name, n, depth, None])
         elif regions and depth == regions[0][2] + 1 and "scf.if" in line:
             regions.append([f"if {len(regions)}", n, depth, None])
         depth += line.count("{") - line.count("}")
@@ -110,6 +113,29 @@ def paged_lines(kernel, check, regions, pages):
             name = f"block item of {pages} pages: {total // pages} a page"
         yield f"OPS {kernel} | {check} | {name} | {total} | " \
             f"{json.dumps(counts)}"
+
+
+#: a grid step of ``ds_flash_fwd`` / ``_bwd_dq`` / ``_bwd_dkv`` (every step
+#: is a live block: a dead one is no step): what it runs outside the
+#: ``pl.when`` regions, then those in order
+FLASH_REGIONS = ("every step", "first step of a row", "edge block",
+                 "interior block", "last step of a row")
+
+
+def flash_lines(kernel, check, regions):
+    """The ``OPS`` lines of one check that compiled a flash kernel: the
+    step's ``scf.if`` regions under their names, before them what is left of
+    the step outside them."""
+    (_, step), branches = regions[0], regions[1:]
+    outside = collections.Counter(step)
+    for _, counts in branches:
+        outside.subtract(counts)
+    regions = [("", dict(+outside))] + branches
+    named = len(regions) == len(FLASH_REGIONS)
+    for n, (region, counts) in enumerate(regions):
+        name = FLASH_REGIONS[n] if named else region or "outside"
+        yield f"OPS {kernel} | {check} | {name} | " \
+            f"{sum(counts.values())} | {json.dumps(counts)}"
 
 
 def main():
@@ -178,6 +204,33 @@ def main():
         jax.grad(lambda q, k, v: flash_attention(
             q, k, v, causal=True, block_q=512, block_k=512
         ).astype(jnp.float32).sum(), argnums=(0, 1, 2)), q2, q2, q2))
+
+    # Bloom's ALiBi (slopes a head in SMEM), forward and gradients
+    slopes = np.linspace(0.05, 0.4, H).astype(np.float32)
+    results.append(checked(
+        "flash_attention(ALiBi, grad)",
+        jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, alibi_slopes=slopes
+        ).astype(jnp.float32).sum(), argnums=(0, 1, 2)), q2, q2, q2))
+    # the training cells' shapes (a sequence a chip, bfloat16, blocks of the
+    # default): SmallThinker's window and full layers (S 8192, 28 heads) and
+    # Mistral-7B's (S 4096, 32 heads, a window that does not bind), forward
+    # and all three gradients
+    for name, S2, heads, window in (
+            ("S 8192, 28 heads, window 4096: the SmallThinker cell", 8192, 28,
+             4096),
+            ("S 8192, 28 heads, full: the SmallThinker cell", 8192, 28, 0),
+            ("S 4096, 32 heads, window 4096: the Mistral cells", 4096, 32,
+             4096)):
+        qc = sds((1, S2, heads, D), bf16)
+        attend = lambda q, k, v, window=window: flash_attention(
+            q, k, v, causal=True, window=window)
+        results.append(checked(f"flash_attention({name})", attend,
+                               qc, qc, qc))
+        results.append(checked(
+            f"flash_attention(grad, {name})",
+            jax.grad(lambda q, k, v, attend=attend: attend(q, k, v).astype(
+                jnp.float32).sum(), argnums=(0, 1, 2)), qc, qc, qc))
 
     from deepspeed_tpu.ops.pallas.flash_bias import flash_attention_bias
     bias = sds((B, H, S, S), bf16)
@@ -330,6 +383,9 @@ def main():
     for kernel, name, regions, pages in ops:
         if kernel in ("ds_paged_runs", "ds_paged_latent"):
             print("\n".join(paged_lines(kernel, name, regions, pages)))
+            continue
+        if kernel.startswith("ds_flash_") and regions:
+            print("\n".join(flash_lines(kernel, name, regions)))
             continue
         for region, counts in regions:
             print(f"OPS {kernel} | {name} | {region} | "
