@@ -112,8 +112,8 @@ def run_bench(on_tpu: bool) -> dict:
                     # fp32 for logsumexp regardless)
                     head_dtype=os.environ.get("BENCH_HEAD_DTYPE",
                                               "bfloat16"),
-                    # fused chunked head+loss (no [B,S,V] logits); 6400
-                    # divides V=32000 and is a lane multiple
+                    # fused head+loss in chunks of rows (no [B,S,V]
+                    # logits); 6400 of V=32000: a fifth of the rows a chunk
                     loss_chunk_vocab=int(os.environ.get("BENCH_LOSS_CHUNK",
                                                         "0")))
             else:

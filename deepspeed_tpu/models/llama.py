@@ -64,12 +64,14 @@ class LlamaConfig:
     # the logsumexp either way).
     head_dtype: str = "float32"
     # > 0 → fused chunked head+loss: the lm-head matmul and cross entropy
-    # run per vocab-chunk under an online logsumexp (sequence/cross_entropy
-    # .fused_linear_cross_entropy) so the [B, S, V] logits are never
-    # materialized in either pass.  Frees ~V·S·B·(2+4) bytes of live HBM
-    # (bf16 logits + fp32 softmax), which is what forces remat at larger
-    # batch.  Value = chunk width; MXU-friendly divisors of V (multiples of
-    # 128) avoid padding, e.g. 6400 for V=32000.
+    # run a chunk of ROWS at a time (sequence/cross_entropy
+    # .fused_linear_cross_entropy: the chunk's softmax is whole, so its
+    # gradient products run in the same pass and nothing is computed twice)
+    # and the [B, S, V] logits are never materialized in either pass.  Frees
+    # ~V·S·B·(2+4) bytes of live HBM (bf16 logits + fp32 softmax), which is
+    # what forces remat at larger batch.  Value = the bound on the logits
+    # alive at once, as the width of a [B·S, value] array: a chunk holds
+    # ceil(B·S · value / V) rows, e.g. 6400 for V=32000 is a fifth of the rows.
     loss_chunk_vocab: int = 0
     remat: bool = True
     # what a recomputed block keeps besides its input: a key of
@@ -200,17 +202,18 @@ def _lm_loss(logits, labels, attention_mask=None):
 
 def _lm_loss_chunked(x, w, labels, attention_mask, chunk, head_dtype):
     """Shifted CE via the fused chunked head+loss (no [B, S, V] logits).
-    ``x``: [B, S, D] final hidden states, ``w``: [D, V] head kernel."""
+    ``x``: [B, S, D] final hidden states, ``w``: [D, V] head kernel.  The
+    mask and its denominator go in as the rows' weights."""
     from ..sequence.cross_entropy import fused_linear_cross_entropy
     b, s, d = x.shape
     n = b * (s - 1)
-    loss = fused_linear_cross_entropy(
-        x[:, :-1].reshape(n, d), w, labels[:, 1:].reshape(n),
-        chunk, logit_dtype=head_dtype)
+    weights = None
     if attention_mask is not None:
         m = attention_mask[:, 1:].astype(jnp.float32).reshape(n)
-        return jnp.sum(loss * m) / jnp.maximum(jnp.sum(m), 1.0)
-    return jnp.mean(loss)
+        weights = m / jnp.maximum(jnp.sum(m), 1.0)
+    return fused_linear_cross_entropy(
+        x[:, :-1].reshape(n, d), w, labels[:, 1:].reshape(n),
+        chunk, logit_dtype=head_dtype, row_weights=weights)
 
 
 class RMSNorm(nn.Module):

@@ -6,6 +6,8 @@ tokens; the mean over the full sequence is a psum.  Usable inside shard_map
 (axis-name form) or on global arrays (GSPMD handles the reduction).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -18,57 +20,138 @@ def softmax_cross_entropy_with_logits(logits, labels):
     return logz - gold
 
 
-def fused_linear_cross_entropy(x, w, labels, chunk_size, logit_dtype=None):
-    """CE of ``x @ w`` against ``labels`` WITHOUT materializing the [N, V]
-    logits — the TPU answer to the reference's chunked logits loss
-    (``deepspeed/sequence/fpdt_layer.py:1137`` FPDT_LogitsLoss chunks the
-    sequence; here the vocab dim is chunked, which also removes the [N, V]
-    fp32 softmax intermediate from the backward pass).
+def _chunk_rows(n, v, chunk_size):
+    """Rows a chunk: as many as hold the logits of an ``[n, chunk_size]``
+    array (``chunk_size`` bounds the logits alive at once), a multiple of 8;
+    all ``n`` when that is every row (``chunk_size`` 0 or >= ``v``)."""
+    if not chunk_size or chunk_size >= v:
+        return n
+    rows = -(-n * chunk_size // v)
+    rows = -(-rows // 8) * 8
+    return n if rows >= n else rows
 
-    ``x``: [N, D] hidden states (head dtype), ``w``: [D, V] head kernel,
-    ``labels``: [N] int32.  Returns [N] fp32 per-token loss.
 
-    A ``lax.scan`` runs an online logsumexp over vocab chunks; the body is
-    ``jax.checkpoint``-ed so backward recomputes each chunk's logits —
-    peak live logits are [N, chunk_size] instead of [N, V] in BOTH passes.
-    The extra head-matmul recompute is ~2·N·D·V flops; the saving is the
-    [N, V] fp32 round-trips to HBM, which at V≳32k dominate and otherwise
-    force gradient checkpointing (lower MFU) at batch sizes that would
-    fit without them.
+def _row_chunks(rows, *arrays):
+    """Each ``[N, ...]`` array as ``[chunks, rows, ...]``, padded with zeros
+    (a padded row carries weight 0).  Chunk ``c`` holds the rows ``c``,
+    ``c + chunks``, ...: of rows sharded over devices (a batch over "dp")
+    every chunk then takes an equal part from each, where a chunk of
+    neighbours would lie on one device and be computed by all."""
+    pad = -arrays[0].shape[0] % rows
+    return tuple(
+        jnp.moveaxis(jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)
+                             ).reshape((rows, -1) + a.shape[1:]), 1, 0)
+        for a in arrays)
+
+
+def _rows_back(a, n):
+    """``_row_chunks``'s inverse: ``[chunks, rows, ...]`` as ``[n, ...]``."""
+    return jnp.moveaxis(a, 0, 1).reshape((-1,) + a.shape[2:])[:n]
+
+
+def _chunk_stats(xc, w, labels):
+    """One chunk of rows: float32 logits ``[rows, V]``, the whole row's
+    log-sum-exp, the mask of each row's label (a compare against an iota:
+    no gather forward, no scatter backward) and the per-row loss."""
+    logits = (xc @ w).astype(jnp.float32)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    hit = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1) \
+        == labels[:, None]
+    gold = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
+    return logits, lse, hit, lse - gold
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _head_loss(chunk_size, ld, x, w, labels, row_weights):
+    """``x`` and ``w`` in the logits' dtype ``ld``, ``row_weights`` float32.
+    The primal alone (an evaluation step): one product a chunk."""
+    rows = _chunk_rows(x.shape[0], w.shape[1], chunk_size)
+
+    def body(total, chunk):
+        xc, lab, rw = chunk
+        loss = _chunk_stats(xc, w, lab)[-1]
+        return total + jnp.sum(loss * rw), None
+
+    total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32),
+                            _row_chunks(rows, x, labels, row_weights))
+    return total
+
+
+def _head_loss_fwd(chunk_size, ld, x, w, labels, row_weights):
+    """Loss and, at unit cotangent, both gradients in one pass over the row
+    chunks: three products a chunk, nothing for the backward to compute."""
+    n, v = x.shape[0], w.shape[1]
+    rows = _chunk_rows(n, v, chunk_size)
+    # the weights meet the logits' dtype divided by their largest, and the
+    # float32 products are multiplied back: 1 / N of a long sequence times a
+    # small probability is below float16's range, and the loss scale that
+    # would lift it arrives only with the cotangent
+    top = jnp.maximum(jnp.max(jnp.abs(row_weights)),
+                      jnp.finfo(jnp.float32).tiny)
+
+    def body(dw, chunk):
+        xc, lab, rw = chunk
+        logits, lse, hit, loss = _chunk_stats(xc, w, lab)
+        dlogits = ((jnp.exp(logits - lse[:, None]) - hit) * rw[:, None]
+                   ).astype(ld)
+        dx = top * jax.lax.dot_general(
+            dlogits, w, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dw = dw + top * jax.lax.dot_general(
+            xc, dlogits, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return dw, (loss, dx)
+
+    dw, (loss, dx) = jax.lax.scan(
+        body, jnp.zeros(w.shape, jnp.float32),
+        _row_chunks(rows, x, labels, row_weights / top))
+    loss, dx = _rows_back(loss, n), _rows_back(dx, n)
+    return jnp.sum(loss * row_weights), (dx, dw, loss)
+
+
+def _head_loss_bwd(chunk_size, ld, res, g):
+    # float32 residuals at unit cotangent: a loss scale multiplies float32
+    # numbers before they are rounded to the logits' dtype
+    dx, dw, loss = res
+    return (g * dx).astype(ld), (g * dw).astype(ld), None, g * loss
+
+
+_head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
+
+
+def fused_linear_cross_entropy(x, w, labels, chunk_size, logit_dtype=None,
+                               row_weights=None):
+    """``sum_n row_weights[n] * CE_n`` of ``x @ w`` against ``labels``
+    WITHOUT materializing the [N, V] logits: the TPU answer to the
+    reference's chunked logits loss (``deepspeed/sequence/fpdt_layer.py:1137``
+    FPDT_LogitsLoss), which chunks the sequence as this does.
+
+    ``x``: [N, D] hidden states, ``w``: [D, V] head kernel, ``labels``: [N]
+    int32, ``row_weights``: [N] (``None`` = the mean, ``1 / N``: a mask
+    divided by its sum weighs rows as a masked mean does).  Returns the
+    float32 scalar.  The rows' weights come in and the scalar goes out
+    because ``dW``, summed over rows, cannot be rescaled row by row later.
+
+    ``chunk_size`` bounds the logits alive at once to those of an
+    ``[N, chunk_size]`` array: a ``lax.scan`` runs over chunks of
+    ``ceil(N * chunk_size / V)`` ROWS (a multiple of 8; ``N`` padded with rows
+    of weight 0), each a whole ``[rows, V]`` slab of logits in ``logit_dtype``
+    (default ``x.dtype``) widened to float32.  A chunk of rows can finish its
+    softmax, so under differentiation the same pass forms ``dlogits =
+    (softmax - onehot) * row_weights`` and runs both gradient products while
+    the chunk's logits are there: three products a chunk (logits, ``dx``,
+    ``dW`` into a float32 carry), none of them twice, and no [N, V] array in
+    either pass.  The backward rule only scales the float32 ``dx`` / ``dW``
+    by the cotangent (an fp16 run's loss scale arrives there) and casts.
+    The cotangent of ``row_weights`` is the per-row loss.  Called without
+    differentiation it runs the loss alone, one product a chunk.
     """
-    n, d = x.shape
-    v = w.shape[1]
-    chunk_size = int(min(chunk_size, v))
-    n_chunks = -(-v // chunk_size)
-    if v % chunk_size:
-        # pad once so every scan step slices a full chunk; padded columns
-        # are masked to -inf below and contribute exp(-inf)=0
-        w = jnp.pad(w, ((0, 0), (0, n_chunks * chunk_size - v)))
+    n = x.shape[0]
     ld = jnp.dtype(logit_dtype) if logit_dtype is not None else x.dtype
-    xc = x.astype(ld)
-
-    def body(carry, c):
-        m, s, gold = carry
-        base = c * chunk_size
-        wc = jax.lax.dynamic_slice_in_dim(w, base, chunk_size, axis=1)
-        logits = (xc @ wc.astype(ld)).astype(jnp.float32)  # [N, chunk]
-        col = base + jnp.arange(chunk_size)
-        logits = jnp.where(col[None, :] < v, logits, -jnp.inf)
-        m_new = jnp.maximum(m, jnp.max(logits, axis=-1))
-        s = s * jnp.exp(m - m_new) + jnp.sum(
-            jnp.exp(logits - m_new[:, None]), axis=-1)
-        in_chunk = (labels >= base) & (labels < base + chunk_size)
-        idx = jnp.clip(labels - base, 0, chunk_size - 1)
-        g = jnp.take_along_axis(logits, idx[:, None], axis=1)[:, 0]
-        gold = jnp.where(in_chunk, g, gold)
-        return (m_new, s, gold), None
-
-    init = (jnp.full((n,), -jnp.inf, jnp.float32),
-            jnp.zeros((n,), jnp.float32),
-            jnp.zeros((n,), jnp.float32))
-    (m, s, gold), _ = jax.lax.scan(jax.checkpoint(body), init,
-                                   jnp.arange(n_chunks))
-    return m + jnp.log(s) - gold
+    if row_weights is None:
+        row_weights = jnp.full((n,), 1.0 / n, jnp.float32)
+    return _head_loss(int(chunk_size), ld, x.astype(ld), w.astype(ld), labels,
+                      row_weights.astype(jnp.float32))
 
 
 def vocab_sequence_parallel_cross_entropy(logits, labels, sp_axis=None,
