@@ -247,11 +247,10 @@ def phase_train(cfg, compiles, *, micro_batch, seq_len, steady_steps=5,
 
 # ------------------------------------------------------------------ phase B
 def probe_params(model, seed=0, alpha=48.0, beta=8.0, shift=17):
-    """Seeded bf16 weights with DECISIVE greedy margins, after
-    ``tools/serve_bench.probe_model``: scaled identity embeddings put the
-    last token's coordinate far above what the (random-init, fully
-    exercised) attention/MLP blocks add, and a permutation lm_head maps it
-    to a shifted next token.  Random-init logits at this width are nearly
+    """Seeded bf16 weights with DECISIVE greedy margins: scaled identity
+    embeddings put the last token's coordinate far above what the
+    (random-init, fully exercised) attention/MLP blocks add, and a
+    permutation lm_head maps it to a shifted next token.  Random-init logits at this width are nearly
     flat, so argmax would flip with batch composition; with these weights a
     token mismatch means the path is broken, not that a coin landed
     otherwise.  Only token ids below hidden_size take part."""

@@ -217,9 +217,9 @@ class Autotuner:
                                               wire_dtype=w,
                                               quantization_group_size=gs))
                     if "flat_manual" in (c.zero_mode_candidates or []):
-                        # the zero-mode dimension (ds_bench --zero-mode's
-                        # search twin): race the legacy full-manual qgZ
-                        # micro against the GSPMD-first islands default
+                        # the zero-mode dimension: race the legacy
+                        # full-manual qgZ micro against the GSPMD-first
+                        # islands default
                         bases.append(dict(proto, quantized_gradients=True,
                                           wire_dtype=w,
                                           zero_mode="flat_manual"))
@@ -246,9 +246,8 @@ class Autotuner:
                                      "max_inflight": infl}
                     blocks.append(nb)
         if stage >= 3:
-            # forward param-gather prefetch only exists at stage 3; give the
-            # gather-direction priors (and sweep bests) candidates to land
-            # on — one set over the flat base, one over the qwZ ladder base
+            # forward param-gather prefetch only exists at stage 3: one set
+            # of candidates over the flat base, one over the qwZ ladder base
             pf_bases = [None] + ([bases[-1]] if ladder_ag else [])
             for b in pf_bases:
                 for mb in c.bucket_mb_candidates:
@@ -353,19 +352,6 @@ class Autotuner:
         if not exps:
             raise AutotuningError("comm tuning space is empty — check "
                                   "zero_stages / candidate lists")
-        if self.cfg.priors_file:
-            from .priors import load_priors_file, seed_exps_with_priors
-            priors = load_priors_file(self.cfg.priors_file)
-            # the baseline candidates (absent-block default + the user's
-            # own block) stay pinned at the FRONT: they are what the
-            # acceptance compares against, and a priors ordering that
-            # pushed them past the trial budget would break the
-            # "autotuned ≤ default" invariant (and the smoke gate)
-            pinned = [e for e in exps if e.get("pinned")]
-            rest = [e for e in exps if not e.get("pinned")]
-            exps = pinned + seed_exps_with_priors(rest, priors)
-            logger.info(f"autotuning: search seeded from priors file "
-                        f"{self.cfg.priors_file}")
         return exps
 
     # ---------------------------------------------- memory-feasibility filter
@@ -551,8 +537,6 @@ class Autotuner:
         exps = self.memory_feasibility_filter(exps)
         tuner_cls = TUNERS.get(c.tuner_type, GridSearchTuner)
         kw = {}
-        if tuner_cls is ModelBasedTuner:
-            kw["priors"] = self._measured_priors(metric)
         if tie is not None:
             kw["tie_breaker"] = tie
             kw["tie_rtol"] = c.tie_rtol
@@ -569,20 +553,6 @@ class Autotuner:
                 g.set(float(best["result"][metric]))
         self._write_results(best, metric)
         return best
-
-    def _measured_priors(self, metric):
-        if not (self.cfg.priors_path and
-                os.path.isdir(self.cfg.priors_path)):
-            return None
-        if metric != "throughput":
-            # bench records are tokens/s (a throughput); seeding a
-            # latency/step-time search with them would silently run cold
-            logger.warning(
-                f"measured priors only exist for metric='throughput' "
-                f"(configured: {metric!r}); tuning starts cold")
-            return None
-        from .priors import load_measured_priors
-        return load_measured_priors(self.cfg.priors_path)
 
     # ---------------------------------------------------------------- emit
     def _trial_rows(self, metric):

@@ -51,10 +51,6 @@ class AutotuningConfig(DeepSpeedConfigModel):
     # None + tune_mesh=True → derived from the device count.
     tune_mesh: bool = False
     mesh_candidates: Optional[List[Dict]] = None
-    # TPU addition: seed ModelBasedTuner with measured on-chip records from
-    # this directory (bench.py JSON records).  Opt-in ("" = off):
-    # stale artifacts in a launch cwd must not silently bias a search.
-    priors_path: str = ""
 
     # ------------------------------------------------ comm-surface loop
     # tune_comm: walk the comm_optimizations/ZeRO surface instead of the
@@ -63,10 +59,6 @@ class AutotuningConfig(DeepSpeedConfigModel):
     # / overlap bucketing, scored by measured step time with
     # exposed_comm_frac as the tie-breaker (docs/autotuning.md).
     tune_comm: bool = False
-    # fold_sweeps --priors artifact; "" = cold start.  Candidates matching
-    # the measured-best (direction, bucket_mb, wire) aggregates are
-    # proposed first.
-    priors_file: str = ""
     # mesh axis the comm trials/probes sweep
     comm_axis: str = "dp"
     # micro-probe surface: log2 payload bytes per size bucket, quantized
@@ -86,7 +78,7 @@ class AutotuningConfig(DeepSpeedConfigModel):
     # (qgZ/qwZ) wire bases; empty (default) keeps the block default —
     # the space is unchanged unless the user opts into the sweep
     group_size_candidates: List[int] = []
-    # the zero-mode search dimension (ds_bench --zero-mode's twin): when
+    # the zero-mode search dimension: when
     # "flat_manual" is listed, every quantized-gradient wire base gets a
     # legacy full-manual-micro sibling so the measured trial decides which
     # micro architecture carries qgZ on THIS model/mesh (docs/zero.md)

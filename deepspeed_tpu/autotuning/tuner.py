@@ -162,47 +162,24 @@ def featurize_config(cfg):
 
 class ModelBasedTuner(BaseTuner):
     """Reference ``model_based_tuner.py:19``: fit a cost model on measured
-    points, propose the predicted-best next.
-
-    ``priors``: measured ground-truth points
-    (``[{"ds_config": ..., "<metric>": value}]``, see
-    ``autotuning/priors.load_measured_priors``) — with ≥3 priors the FIRST
-    proposal is already the predicted-best config instead of a cold guess.
-    Priors steer the proposal order only until enough LIVE trials exist
-    (their units are the bench's tokens/s for a fixed model; live trials
-    measure the user's model in samples/s — mixing both in one fit would
-    let the priors' magnitude drown the live signal)."""
+    points, propose the predicted-best next."""
 
     _MIN_FIT = 3
 
     def __init__(self, exps, runner, metric="throughput", mode="max",
-                 tie_breaker=None, tie_rtol=0.02, tuning_space=None,
-                 priors=None):
+                 tie_breaker=None, tie_rtol=0.02, tuning_space=None):
         super().__init__(exps, runner, metric, mode=mode,
                          tie_breaker=tie_breaker, tie_rtol=tie_rtol)
-        self._X, self._y = [], []            # live measurements only
-        self._pX, self._py = [], []          # measured priors
-        for p in priors or []:
-            val = p.get(metric)
-            if val is None or "ds_config" not in p:
-                continue
-            self._pX.append(self._featurize(p))
-            self._py.append(float(val))
+        self._X, self._y = [], []            # live measurements
 
     def _featurize(self, exp):
         return featurize_config(exp["ds_config"])
 
     def _predict(self, exp):
-        # live measurements take over as soon as there are enough to fit;
-        # until then, measured priors (if any) order the proposals
-        if len(self._y) >= self._MIN_FIT:
-            A, y = self._X, self._y
-        elif len(self._py) >= self._MIN_FIT:
-            A, y = self._pX, self._py
-        else:
+        if len(self._y) < self._MIN_FIT:
             return 0.0
-        A = np.array(A)
-        y = np.array(y)
+        A = np.array(self._X)
+        y = np.array(self._y)
         # ridge regression on a degree-2 feature expansion
         def expand(M):
             return np.concatenate([M, M**2, np.ones((len(M), 1))], axis=1)

@@ -12,7 +12,7 @@ so the comm trajectory of ``comm_optimizations`` configs is measurable.
     ds_bench                       # sweep all ops over the dp axis
     ds_bench --op quant_all_gather --axis dp --maxsize 28
     ds_bench --mesh dp=4,tp=2      # explicit mesh factorization
-    ds_bench --json out.json       # machine-readable rows (BENCH_*.json food)
+    ds_bench --json out.json       # machine-readable rows
 
 Prints one table row per (op, size): logical bytes, wire bytes (what the
 bottleneck link actually carries — post-quantization payload + scales),
@@ -21,7 +21,6 @@ latency, algbw, busbw.  Bandwidths are computed from WIRE bytes.
 
 import argparse
 import json
-import os
 import time
 
 import numpy as np
@@ -62,11 +61,6 @@ def _timed_stats(f, args, iters, warmup, repeat=1):
     iqr = float(np.percentile(samples, 75) - np.percentile(samples, 25)) \
         if len(samples) > 1 else 0.0
     return med, iqr
-
-
-def _timed(f, args, iters, warmup, repeat=1):
-    """Median per-call latency (see :func:`_timed_stats`)."""
-    return _timed_stats(f, args, iters, warmup, repeat=repeat)[0]
 
 
 class UnsplittableAxis(ValueError):
@@ -187,12 +181,11 @@ def _bench_one(op, axis, nbytes, mesh, iters, warmup, intra=0, repeat=1,
 # ------------------------------------------------------------- row schema
 def bench_row(**fields):
     """THE uniform ``ds_bench --json`` row: every producer (the op sweep,
-    the overlap sweep, :func:`probe_op`, the autotuner's trial archive)
-    builds rows through this one constructor, so a field added to the
-    schema lands everywhere at once instead of drifting across hand-built
-    dict literals.  Unset schema fields are explicit ``None``; extra
-    producer-specific keys (overlap accounting, trial names) pass
-    through."""
+    :func:`probe_op`, the autotuner's trial archive) builds rows through
+    this one constructor, so a field added to the schema lands everywhere
+    at once instead of drifting across hand-built dict literals.  Unset
+    schema fields are explicit ``None``; extra producer-specific keys
+    (trial names) pass through."""
     row = {"op": None, "bytes": None, "wire_bytes": None,
            "latency_us": None, "iqr_us": None, "repeat": None,
            "wire_dtype": None, "algbw_gbps": None, "busbw_gbps": None,
@@ -230,717 +223,14 @@ def probe_op(op, nbytes, axis="dp", mesh=None, iters=5, warmup=2, repeat=3,
         algbw_gbps=algbw, busbw_gbps=busbw)
 
 
-# ------------------------------------------------------------ overlap sweep
-# Bucketed comm/compute-overlap candidates (bucket size × wire dtype), in
-# BOTH directions: how much of the gradient-reduction time can hide under
-# backward compute ("reduce"), and how much of the stage-3 param all-gather
-# can hide under forward compute ("gather")?  Feeds the overlap scheduler's
-# bucket_mb / prefetch.bucket_mb choices (see docs/overlap.md) the way the
-# op sweep feeds wire_dtype.
-
-OVERLAP_BUCKET_MBS = (1.0, 4.0, 16.0)
-OVERLAP_WIRES = ("fp32", "int8")
-OVERLAP_LAYERS = 8
-OVERLAP_DIRECTIONS = ("reduce", "gather")
-
-
-def _overlap_candidate(mesh, axis, bucket_mb, wire, total_bytes, layers,
-                       iters, warmup, recorder=None):
-    """Measure one (bucket_mb, wire_dtype) candidate.
-
-    Synthetic backward: a chain of matmul segments (the remaining backward
-    compute) + per-layer gradient leaves reduced over ``axis``.  Three
-    compiled programs — compute-only, comm-only (per bucket, so the trace
-    carries real per-bucket costs), and the bucketed overlapped step where
-    bucket *k*'s reduce is fenced to segment *k* of the compute chain via
-    ``optimization_barrier`` (grads "materialize" as backward progresses).
-    Overlap efficiency = hidden / total comm time, where
-    ``hidden = comm − exposed`` and ``exposed = step − compute``.
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-    from ..comm.collectives import quantized as Q
-    from ..runtime.zero.overlap import partition_buckets
-
-    n = mesh.shape[axis]
-    elems = total_bytes // 4 // layers
-    elems = max(n * GROUP_SIZE, elems // (n * GROUP_SIZE) * (n * GROUP_SIZE))
-    grads = [jnp.linspace(-1.0, 1.0, elems, dtype=jnp.float32)
-             for _ in range(layers)]
-    H = 256
-    x = jnp.ones((8, H), jnp.float32)
-    w = jnp.eye(H, dtype=jnp.float32) * 0.999
-
-    buckets = partition_buckets(
-        [(f"layer_{i}", g) for i, g in enumerate(grads)],
-        int(bucket_mb * (1 << 20)))
-
-    def reduce_leaf(g):
-        if wire == "fp32":
-            return jax.lax.psum_scatter(g, axis, scatter_dimension=0,
-                                        tiled=True)
-        return Q.all_to_all_quant_reduce(g, (axis, ), 0, n,
-                                         wire_format=wire,
-                                         group_size=GROUP_SIZE)
-
-    def sm(fn, out_specs):
-        return jax.jit(jax.shard_map(
-            fn, mesh=mesh, in_specs=(P(), P(), P()), out_specs=out_specs,
-            check_vma=False))
-
-    def compute_only(x, w, grads):
-        cur = x
-        for _ in range(len(buckets)):
-            cur = cur @ w
-        return cur
-
-    def overlapped(x, w, grads):
-        cur = x
-        outs = [None] * len(grads)
-        for b in buckets:
-            cur = cur @ w
-            tied = jax.lax.optimization_barrier(
-                tuple(grads[i] for i in b.indices) + (cur, ))
-            cur = tied[-1]
-            for j, i in enumerate(b.indices):
-                outs[i] = reduce_leaf(tied[j])
-        return cur, tuple(outs)
-
-    def monolithic(x, w, grads):
-        cur = x
-        for _ in range(len(buckets)):
-            cur = cur @ w
-        tied = jax.lax.optimization_barrier(tuple(grads) + (cur, ))
-        return tied[-1], tuple(reduce_leaf(g) for g in tied[:-1])
-
-    out_grads = P(axis)  # both hops scatter the reduced shard over axis
-    args = (x, w, tuple(grads))
-    t_compute = _timed(sm(compute_only, P()), args, iters, warmup)
-    fn_step, step_analysis = _aot_with_analysis(
-        sm(overlapped, (P(), tuple(out_grads for _ in grads))), args)
-    t_step = _timed(fn_step, args, iters, warmup)
-    t_mono = _timed(sm(monolithic, (P(), tuple(out_grads for _ in grads))),
-                    args, iters, warmup)
-    # comm-only, per bucket — the trace carries real per-bucket costs
-    t_comm = 0.0
-    for b in buckets:
-        idx = b.indices
-
-        def bucket_fn(x, w, grads, _idx=idx):
-            return tuple(reduce_leaf(grads[i]) for i in _idx)
-
-        fn = sm(bucket_fn, tuple(out_grads for _ in idx))
-        if recorder is not None:
-            with recorder.bucket_span(b.index, nbytes=b.nbytes):
-                t_b = _timed(fn, args, iters, warmup)
-        else:
-            t_b = _timed(fn, args, iters, warmup)
-        t_comm += t_b
-
-    if wire == "fp32":
-        wire_bytes = elems * 4 * layers
-    else:
-        wire_bytes = Q.quantized_wire_bytes(elems, wire, GROUP_SIZE) * layers
-    return _candidate_row("reduce", bucket_mb, wire, len(buckets), elems,
-                          layers, wire_bytes, t_compute, t_comm, t_step,
-                          t_mono,
-                          cost_fields=_step_cost_fields(step_analysis,
-                                                        t_step))
-
-
-def _aot_with_analysis(fn, args):
-    """Compile a candidate's stepped program ONCE (ahead-of-time) and
-    return ``(executable, analysis)`` — the SAME executable is then timed,
-    so the cost fields describe exactly what ran and the sweep pays no
-    second analysis compile (jit's lazy path + a separate ``analyze_fn``
-    would compile every candidate twice).  Falls back to the lazy-jit
-    callable with empty analysis where AOT is unavailable."""
-    from ..profiling import cost_model
-    try:
-        compiled = fn.lower(*args).compile()
-        return compiled, cost_model.analyze_compiled(compiled)
-    except Exception:
-        return fn, {"flops": None, "peak_hbm_bytes": None}
-
-
-def _step_cost_fields(analysis, t_step):
-    """Row fields from a stepped program's analysis: mfu = XLA's per-chip
-    flop count over the measured step time ÷ peak, plus the static
-    peak-HBM estimate (None-safe on backends without the cost model)."""
-    from ..profiling import cost_model
-    flops = analysis.get("flops")
-    return {
-        "mfu": cost_model.mfu(flops / t_step
-                              if flops and t_step > 0 else None),
-        "peak_hbm_bytes": analysis.get("peak_hbm_bytes"),
-    }
-
-
-def _candidate_row(direction, bucket_mb, wire, n_buckets, elems, layers,
-                   wire_bytes, t_compute, t_comm, t_step, t_mono,
-                   cost_fields=None):
-    """Shared overlap-candidate accounting: exposed = step − compute,
-    hidden = comm − exposed, efficiency = hidden / comm — identical for
-    the reduce (backward) and gather (forward prefetch) directions."""
-    exposed = max(0.0, t_step - t_compute)
-    hidden = min(t_comm, max(0.0, t_comm - exposed))
-    row = dict(cost_fields or {})
-    row.update({
-        "op": "overlap",
-        "direction": direction,
-        "bucket_mb": float(bucket_mb),
-        "wire_dtype": wire,
-        "buckets": n_buckets,
-        "bytes": int(elems * 4 * layers),
-        "wire_bytes": int(wire_bytes),
-        "layers": int(layers),
-        "compute_ms": t_compute * 1e3,
-        "comm_ms": t_comm * 1e3,
-        "step_ms": t_step * 1e3,
-        "monolithic_ms": t_mono * 1e3,
-        "hidden_ms": hidden * 1e3,
-        "exposed_ms": exposed * 1e3,
-        "exposed_comm_frac": (exposed / t_step if t_step > 0 else 0.0),
-        "overlap_efficiency": (hidden / t_comm if t_comm > 0 else 1.0),
-    })
-    return row
-
-
-def _gather_candidate(mesh, axis, bucket_mb, wire, total_bytes, layers,
-                      iters, warmup, recorder=None):
-    """Measure one forward-direction (bucket_mb, wire_dtype) prefetch
-    candidate.
-
-    Synthetic stage-3 forward: per-layer ZeRO-sharded param leaves + a
-    matmul chain (the layer compute).  Three compiled programs — compute-
-    only, gather-only (per bucket, so the trace carries real per-bucket
-    costs), and the prefetched step where segment *k* of the chain is
-    fenced to bucket *k*'s gathered params via ``optimization_barrier``
-    (the layers that need bucket *k* run once its params arrive, while
-    bucket *k+1*'s gather — independent of the chain — may run
-    underneath).  ``wire`` = "fp32" is the plain all-gather; anything else
-    is the qwZ quantized all-gather at that wire dtype.
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-    from ..comm.collectives import quantized as Q
-    from ..runtime.zero.overlap import partition_prefetch_buckets
-
-    n = mesh.shape[axis]
-    elems = total_bytes // 4 // layers
-    elems = max(n * GROUP_SIZE, elems // (n * GROUP_SIZE) * (n * GROUP_SIZE))
-    params = [jnp.linspace(-1.0, 1.0, elems, dtype=jnp.float32)
-              for _ in range(layers)]
-    H = 256
-    x = jnp.ones((8, H), jnp.float32)
-    w = jnp.eye(H, dtype=jnp.float32) * 0.999
-
-    buckets = partition_prefetch_buckets(
-        [(f"layer_{i}", p) for i, p in enumerate(params)],
-        int(bucket_mb * (1 << 20)))
-
-    def gather_leaf(p):
-        if wire == "fp32":
-            return jax.lax.all_gather(p, axis, axis=0, tiled=True)
-        return Q.quantized_all_gather(p, (axis, ), 0, wire, GROUP_SIZE)
-
-    def sm(fn, out_specs):
-        return jax.jit(jax.shard_map(
-            fn, mesh=mesh, in_specs=(P(), P(), P(axis)),
-            out_specs=out_specs, check_vma=False))
-
-    def compute_only(x, w, params):
-        cur = x
-        for _ in range(len(buckets)):
-            cur = cur @ w
-        return cur
-
-    def prefetched(x, w, params):
-        cur = x
-        full = [None] * len(params)
-        for b in buckets:
-            gathered = tuple(gather_leaf(params[i]) for i in b.indices)
-            tied = jax.lax.optimization_barrier(gathered + (cur, ))
-            cur = tied[-1] @ w
-            for j, i in enumerate(b.indices):
-                full[i] = tied[j]
-        return cur, tuple(full)
-
-    def monolithic(x, w, params):
-        full = tuple(gather_leaf(p) for p in params)
-        tied = jax.lax.optimization_barrier(full + (x, ))
-        cur = tied[-1]
-        for _ in range(len(buckets)):
-            cur = cur @ w
-        return cur, tied[:-1]
-
-    out_full = tuple(P() for _ in params)  # gathered: replicated over axis
-    args = (x, w, tuple(params))
-    t_compute = _timed(sm(compute_only, P()), args, iters, warmup)
-    fn_step, step_analysis = _aot_with_analysis(
-        sm(prefetched, (P(), out_full)), args)
-    t_step = _timed(fn_step, args, iters, warmup)
-    t_mono = _timed(sm(monolithic, (P(), out_full)), args, iters, warmup)
-    t_comm = 0.0
-    for b in buckets:
-        idx = b.indices
-
-        def bucket_fn(x, w, params, _idx=idx):
-            return tuple(gather_leaf(params[i]) for i in _idx)
-
-        fn = sm(bucket_fn, tuple(P() for _ in idx))
-        if recorder is not None:
-            with recorder.bucket_span(b.index, kind="param_gather",
-                                      nbytes=b.nbytes):
-                t_b = _timed(fn, args, iters, warmup)
-        else:
-            t_b = _timed(fn, args, iters, warmup)
-        t_comm += t_b
-
-    if wire == "fp32":
-        wire_bytes = elems * 4 * layers
-    else:
-        wire_bytes = Q.quantized_wire_bytes(elems, wire, GROUP_SIZE) * layers
-    return _candidate_row("gather", bucket_mb, wire, len(buckets), elems,
-                          layers, wire_bytes, t_compute, t_comm, t_step,
-                          t_mono,
-                          cost_fields=_step_cost_fields(step_analysis,
-                                                        t_step))
-
-
-def run_overlap_sweep(axis="dp", mesh=None, bucket_mbs=OVERLAP_BUCKET_MBS,
-                      wires=OVERLAP_WIRES, total_mb=8.0,
-                      layers=OVERLAP_LAYERS, iters=10, warmup=2,
-                      print_fn=print, recorder=None,
-                      directions=OVERLAP_DIRECTIONS):
-    """bucket_mb × wire_dtype sweep of the bucketed overlap schedulers, one
-    pass per ``direction``: "reduce" (backward grad reduce-scatter) and
-    "gather" (forward stage-3 param all-gather prefetch).  Returns
-    candidate dicts (the ``--json`` rows / comm_summary ``overlap``
-    section), each tagged with its ``direction``."""
-    from ..utils import groups
-    if mesh is None:
-        mesh = groups.get_mesh_state().mesh
-    unknown = [d for d in directions if d not in OVERLAP_DIRECTIONS]
-    if unknown:
-        # a --overlap-directions typo must not burn a sweep under a
-        # mislabeled tag that every report then silently drops
-        raise ValueError(
-            f"unknown overlap sweep direction(s) {unknown!r} — valid: "
-            f"{', '.join(OVERLAP_DIRECTIONS)}")
-    out = []
-    for direction in directions:
-        measure = (_overlap_candidate if direction == "reduce"
-                   else _gather_candidate)
-        # the hidden/exposed comm-event rows use the base op the direction
-        # actually sweeps, in the op[variant] vocabulary of training traces
-        base_op = "reduce_scatter" if direction == "reduce" else "all_gather"
-        var_prefix = "overlap" if direction == "reduce" else "prefetch"
-        print_fn(f"# overlap sweep: direction={direction} "
-                 f"mesh={dict(mesh.shape)} axis={axis} "
-                 f"total={total_mb}MiB layers={layers}")
-        print_fn(f"{'bucket_mb':>10}{'wire':>8}{'buckets':>9}"
-                 f"{'compute_ms':>12}"
-                 f"{'comm_ms':>10}{'step_ms':>10}{'mono_ms':>10}"
-                 f"{'exposed_frac':>14}{'overlap_eff':>13}")
-        cands = []
-        for wire in wires:
-            for mb in bucket_mbs:
-                c = measure(mesh, axis, mb, wire,
-                            int(total_mb * (1 << 20)), layers,
-                            iters, warmup, recorder=recorder)
-                cands.append(c)
-                if recorder is not None:
-                    # exposed/hidden split rides the comm-event spine
-                    variant = f"{var_prefix}_{wire}_b{mb:g}"
-                    recorder.comm_event(base_op, variant, c["bytes"],
-                                        c["wire_bytes"],
-                                        c["exposed_ms"] / 1e3,
-                                        world_size=mesh.shape[axis])
-                    recorder.comm_event(base_op, variant, 0,
-                                        0, c["hidden_ms"] / 1e3,
-                                        world_size=mesh.shape[axis],
-                                        exposed=False)
-                print_fn(f"{mb:>10g}{wire:>8}{c['buckets']:>9}"
-                         f"{c['compute_ms']:>12.3f}{c['comm_ms']:>10.3f}"
-                         f"{c['step_ms']:>10.3f}{c['monolithic_ms']:>10.3f}"
-                         f"{c['exposed_comm_frac']:>14.3f}"
-                         f"{c['overlap_efficiency']:>13.3f}")
-        best = max(cands, key=lambda c: c["overlap_efficiency"])
-        print_fn(f"# best {direction}: bucket_mb={best['bucket_mb']:g} "
-                 f"wire={best['wire_dtype']} "
-                 f"overlap_efficiency={best['overlap_efficiency']:.3f}")
-        out.extend(cands)
-    return out
-
-
-# ---------------------------------------------------------------- moe sweep
-# Expert-dispatch candidates (E × capacity_factor × wire dtype): how much
-# does the quantized/hierarchical a2a exchange save over the GSPMD
-# constraint reshard for the hardest collective in the stack?  Feeds
-# ``moe.wire_dtype`` the way the op sweep feeds ``wire_dtype`` (docs/moe.md).
-
-MOE_EXPERTS = (8, 16)
-MOE_CAPACITY_FACTORS = (1.0, 2.0)
-MOE_WIRES = ("fp32", "int8")
-MOE_TOKENS = 4096
-MOE_HIDDEN = 256
-
-
-def _moe_candidate(mesh, experts, capacity_factor, wire, tokens, hidden,
-                   iters, warmup, repeat):
-    """Measure one (E, capacity_factor, wire) expert-dispatch candidate:
-    the full dispatch → (trivial) expert → combine round trip, GSPMD
-    constraint path for wire None vs the manual exchange at ``wire``."""
-    import jax
-    import jax.numpy as jnp
-    from ..moe import engine as moe_engine
-    from ..moe.engine import MoeOptions, expert_dispatch_wire_bytes
-    from ..moe.sharded_moe import top1gating
-
-    ep = mesh.shape.get("ep", 1)
-    E = experts - experts % ep if experts % ep else experts
-    if E < ep:
-        # experts < ep rounds to 0 and the gate's capacity math divides by
-        # E — skip with guidance instead of a cryptic ZeroDivisionError
-        raise UnsplittableAxis(
-            f"experts={experts} cannot shard over ep={ep} (need >= ep, "
-            "divisible) — raise --moe-experts or shrink the ep axis")
-    rngk = jax.random.PRNGKey(0)
-    x = jax.random.normal(rngk, (tokens, hidden), jnp.float32)
-    logits = jax.random.normal(jax.random.fold_in(rngk, 1), (tokens, E),
-                               jnp.float32)
-    l_aux, combine, dispatch, counts = top1gating(
-        logits, capacity_factor=capacity_factor)
-    C = combine.shape[-1]
-    kept = float(jnp.sum(dispatch.astype(jnp.float32)))
-    drop_fraction = 1.0 - kept / tokens
-    mean_c = max(1e-9, kept / E)
-    imbalance = float(jnp.max(counts.astype(jnp.float32))) / mean_c
-    expert_fn = lambda d: d * 1.0009765625  # trivial: comm-dominant
-
-    # snapshot the FULL dispatcher state (options + comm view): a live
-    # engine may have installed a wire ladder this sweep must hand back
-    prev = moe_engine.snapshot()
-    opts = None if wire is None else MoeOptions(
-        enabled=True, quantized_dispatch=True, wire_dtype=wire,
-        quantization_group_size=GROUP_SIZE)
-    moe_engine.configure(opts)
-    payload = E * C * hidden
-    try:
-        if opts is not None:
-            # report what the timed exchange ACTUALLY moves: the same
-            # resolution the dispatcher uses (ladder rung + hierarchy —
-            # the 2-hop variant crosses the bottleneck link with 1/n_inner
-            # of the data)
-            _, _, _, wire_bytes = moe_engine.resolve_exchange(
-                mesh, opts, "ep", payload)
-        else:
-            wire_bytes = expert_dispatch_wire_bytes(payload, "fp32",
-                                                    GROUP_SIZE)
-        fn = jax.jit(lambda t, cm, dm: moe_engine.dispatch_combine(
-            t, cm, dm, expert_fn, mesh=mesh))
-        lat, iqr = _timed_stats(fn, (x, combine, dispatch), iters, warmup,
-                                repeat=repeat)
-    finally:
-        moe_engine.restore(prev)
-    return bench_row(
-        op="moe_dispatch", direction="moe",
-        wire_dtype=(wire if wire is not None else "gspmd"),
-        bytes=int(payload * 4), wire_bytes=int(wire_bytes),
-        latency_us=lat * 1e6, iqr_us=iqr * 1e6, repeat=int(repeat),
-        experts=int(E), capacity_factor=float(capacity_factor),
-        capacity=int(C), tokens=int(tokens),
-        drop_fraction=float(drop_fraction),
-        load_imbalance=float(imbalance),
-        aux_loss=float(l_aux))
-
-
-def run_moe_sweep(mesh=None, experts=MOE_EXPERTS,
-                  capacity_factors=MOE_CAPACITY_FACTORS, wires=MOE_WIRES,
-                  tokens=MOE_TOKENS, hidden=MOE_HIDDEN, iters=10, warmup=2,
-                  repeat=3, print_fn=print, recorder=None):
-    """E × capacity_factor × wire sweep of the expert-dispatch exchange.
-    Every candidate also runs the GSPMD constraint baseline once per (E,
-    cf) so the manual variants have an in-row comparison.  Returns uniform
-    ``bench_row`` dicts tagged ``direction: "moe"``."""
-    from ..utils import groups
-    if mesh is None:
-        mesh = groups.get_mesh_state().mesh
-    if mesh.shape.get("ep", 1) < 2:
-        raise SystemExit(
-            f"moe sweep needs an expert-parallel mesh (ep >= 2), got "
-            f"{dict(mesh.shape)} — pass e.g. --mesh dp=2,ep=4")
-    print_fn(f"# moe dispatch sweep: mesh={dict(mesh.shape)} "
-             f"tokens={tokens} hidden={hidden}")
-    print_fn(f"{'experts':>8}{'cf':>6}{'wire':>8}{'capacity':>10}"
-             f"{'drop_frac':>11}{'imbalance':>11}{'wire_bytes':>12}"
-             f"{'latency_us':>12}{'iqr_us':>9}")
-    rows = []
-    ep = mesh.shape.get("ep", 1)
-    for E in experts:
-        if E - E % ep < ep:
-            # experts < ep rounds to an empty expert stack — skip the whole
-            # E loudly instead of dying in the gate's capacity division
-            print_fn(f"# E={E}: skipped (cannot shard over ep={ep}; "
-                     "raise --moe-experts or shrink the ep axis)")
-            continue
-        if E % ep:
-            # no silent caps: the rounded-down count is what actually runs
-            # (and what the emitted rows carry as `experts`)
-            print_fn(f"# E={E}: rounded down to {E - E % ep} "
-                     f"(must divide ep={ep})")
-        for cf in capacity_factors:
-            for wire in (None, ) + tuple(wires):
-                span = (recorder.span(
-                    f"moe_dispatch/{E}x{cf:g}/{wire or 'gspmd'}",
-                    cat="bench") if recorder is not None else None)
-                if span is not None:
-                    with span:
-                        c = _moe_candidate(mesh, E, cf, wire, tokens,
-                                           hidden, iters, warmup, repeat)
-                else:
-                    c = _moe_candidate(mesh, E, cf, wire, tokens, hidden,
-                                       iters, warmup, repeat)
-                rows.append(c)
-                if recorder is not None and wire is not None:
-                    recorder.comm_event(
-                        "all_to_all", f"moe_q_{wire}", c["bytes"],
-                        c["wire_bytes"], c["latency_us"] / 1e6,
-                        world_size=mesh.shape.get("ep", 1))
-                print_fn(f"{c['experts']:>8}{c['capacity_factor']:>6g}"
-                         f"{c['wire_dtype']:>8}{c['capacity']:>10}"
-                         f"{c['drop_fraction']:>11.3f}"
-                         f"{c['load_imbalance']:>11.2f}"
-                         f"{c['wire_bytes']:>12}"
-                         f"{c['latency_us']:>12.1f}{c['iqr_us']:>9.1f}")
-    best = best_moe_candidate(rows)
-    if best is not None:
-        r, speedup = best
-        print_fn(f"# best manual dispatch: wire={r['wire_dtype']} "
-                 f"E={r['experts']} cf={r['capacity_factor']:g} "
-                 f"({speedup:.2f}x vs gspmd)")
-    return rows
-
-
-def best_moe_candidate(rows):
-    """(row, speedup) of the manual-dispatch wire with the best PER-CELL
-    speedup over its own (E, capacity_factor) gspmd baseline, or None when
-    no manual wire beats its baseline — raw cross-cell latency would let
-    the smallest-payload cell decide (same rule as
-    ``fold_sweeps.aggregate_moe``'s suggestion)."""
-    baselines = {(r.get("experts"), r.get("capacity_factor")):
-                 r.get("latency_us")
-                 for r in rows if r.get("wire_dtype") == "gspmd"}
-    best, best_speedup = None, 1.0
-    for r in rows:
-        if r.get("wire_dtype") in ("gspmd", None):
-            continue
-        base = baselines.get((r.get("experts"), r.get("capacity_factor")))
-        lat = r.get("latency_us")
-        if not base or not lat:
-            continue
-        speedup = base / lat
-        if speedup > best_speedup:
-            best, best_speedup = r, speedup
-    return None if best is None else (best, best_speedup)
-
-
-# ------------------------------------------------------------ zero-mode lane
-# The three micro-step architectures that can carry a ZeRO training step
-# (ISSUE 15, docs/zero.md "GSPMD-first ZeRO"), measured against each other
-# on a REAL engine micro-step (not a synthetic proxy):
-#   flat_manual — the legacy full-manual shard_map qgZ micro
-#                 (comm_optimizations.zero_mode: "flat_manual");
-#   gspmd       — the pure GSPMD micro, no quantization (the flat-wire
-#                 upper bound XLA schedules end to end);
-#   gspmd_q     — the GSPMD-first micro with quantized islands (the
-#                 default qgZ path).
-# bench LANES, not config values — runtime/zero/gspmd.ZERO_MODES
-# (the comm_optimizations.zero_mode validator) accepts only
-# "gspmd"/"flat_manual"; "gspmd_q" names the quantized-islands lane
-ZERO_MODE_LANES = ("flat_manual", "gspmd", "gspmd_q")
-ZERO_MODE_WIRES = ("int8", )
-ZERO_MODE_HIDDEN = 256
-ZERO_MODE_LAYERS = 4
-
-
-def _zero_mode_config(mode, stage, wire):
-    cfg = {
-        "train_micro_batch_size_per_gpu": 8,
-        "optimizer": {"type": "sgd", "params": {"lr": 0.1}},
-        "zero_optimization": {"stage": stage,
-                              "stage3_param_persistence_threshold": 0},
-        "mesh": {"dp": -1},
-    }
-    if mode != "gspmd":
-        cfg["comm_optimizations"] = {
-            "enabled": True, "quantized_gradients": True,
-            "wire_dtype": wire, "quantization_group_size": GROUP_SIZE,
-            **({"zero_mode": "flat_manual"} if mode == "flat_manual"
-               else {}),
-        }
-    return cfg
-
-
-def _zero_mode_candidate(mode, stage, wire, hidden, nlayers, iters, warmup,
-                         repeat):
-    """Time one zero-mode lane: build a real engine with that micro-step
-    architecture, AOT-compile its ACTUAL micro (the same executable
-    training runs) and report the median step latency + compiled-cost
-    fields.  One uniform ``bench_row`` with ``direction: "zero_mode"``."""
-    import jax
-    import deepspeed_tpu
-    from ..comm.collectives import quantized as Q
-    from ..utils import groups
-
-    groups.reset_mesh()
-    deepspeed_tpu.comm.destroy_process_group()
-    rng = np.random.RandomState(0)
-    params = {}
-    for i in range(nlayers):
-        params[f"layer_{i}"] = {
-            "w": (rng.standard_normal((hidden, hidden)) * 0.05
-                  ).astype("float32"),
-            "b": np.zeros((hidden, ), "float32"),
-        }
-
-    def apply_fn(p, x, y):
-        import jax.numpy as jnp
-        h = x
-        for i in range(nlayers):
-            lp = p[f"layer_{i}"]
-            h = jnp.tanh(h @ lp["w"] + lp["b"])
-        return jnp.mean((h - y) ** 2)
-
-    engine, _, _, _ = deepspeed_tpu.initialize(
-        model=apply_fn, model_parameters=params,
-        config=_zero_mode_config(mode, stage, wire))
-    try:
-        xs = rng.standard_normal(
-            (8 * engine.dp_world_size, hidden)).astype("float32")
-        ys = np.tanh(xs * 0.5).astype("float32")
-        inputs = engine.shard_batch(xs, ys)
-        micro = engine._micro_step_fn()
-        args = (engine.params, engine.scale_state.scale, inputs)
-        fn, analysis = _aot_with_analysis(jax.jit(micro), args)
-        lat, iqr = _timed_stats(fn, args, iters, warmup, repeat=repeat)
-        variant = engine._micro_variant()
-        grad_elems = sum(int(np.prod(x.shape))
-                         for x in jax.tree_util.tree_leaves(params))
-        if mode == "gspmd":
-            wire_bytes = grad_elems * 4
-        else:
-            wire_bytes = Q.quantized_wire_bytes(grad_elems, wire,
-                                                GROUP_SIZE)
-        return bench_row(
-            op="zero_micro_step", direction="zero_mode",
-            zero_mode=mode, micro_variant=variant, stage=int(stage),
-            wire_dtype=(wire if mode != "gspmd" else "fp32"),
-            bytes=int(grad_elems * 4), wire_bytes=int(wire_bytes),
-            latency_us=lat * 1e6, iqr_us=iqr * 1e6, repeat=int(repeat),
-            # the lane ALWAYS runs on its own pure-dp mesh over all
-            # devices (the three micros differ only in the dp exchange) —
-            # recorded per row because the payload-level "mesh" describes
-            # the surrounding op sweeps, not these engines
-            mesh={"dp": int(engine.dp_world_size)},
-            **_step_cost_fields(analysis, lat))
-    finally:
-        groups.reset_mesh()
-        deepspeed_tpu.comm.destroy_process_group()
-
-
-def run_zero_mode_sweep(mesh=None, stages=(2, ), wires=ZERO_MODE_WIRES,
-                        hidden=ZERO_MODE_HIDDEN, layers=ZERO_MODE_LAYERS,
-                        iters=5, warmup=2, repeat=3, print_fn=print,
-                        recorder=None):
-    """The three-way flat-manual / GSPMD / GSPMD+quantized-islands lane
-    (``ds_bench --zero-mode``): one real engine micro-step per
-    architecture, per stage × wire.  Returns uniform ``bench_row`` dicts
-    tagged ``direction: "zero_mode"`` — ``fold_sweeps.
-    aggregate_zero_mode`` folds archives and the autotuner searches the
-    same knob (``comm_optimizations.zero_mode``)."""
-    import contextlib
-
-    import jax
-    from ..utils import groups
-    if len(jax.devices()) < 2:
-        raise SystemExit("zero-mode lane needs >= 2 devices (the three "
-                         "micros differ only in how the dp exchange runs)")
-    # the lane rebuilds engines (and thus meshes) per candidate; remember
-    # the bench mesh so the other sweeps in this invocation still see it
-    orig = (dict(mesh.shape) if mesh is not None
-            else dict(groups.get_mesh_state().mesh.shape))
-    print_fn(f"# zero-mode lane: devices={len(jax.devices())} "
-             f"hidden={hidden} layers={layers} "
-             f"(flat_manual / gspmd / gspmd_q)")
-    print_fn(f"{'stage':>6}{'mode':>13}{'wire':>7}{'variant':>18}"
-             f"{'latency_us':>12}{'iqr_us':>9}{'wire_bytes':>12}")
-    rows = []
-    try:
-        for stage in stages:
-            for wire in wires:
-                for mode in ZERO_MODE_LANES:
-                    span = (recorder.span(
-                        f"zero_mode/{stage}/{wire}/{mode}", cat="bench")
-                        if recorder is not None
-                        else contextlib.nullcontext())
-                    with span:
-                        c = _zero_mode_candidate(mode, stage, wire, hidden,
-                                                 layers, iters, warmup,
-                                                 repeat)
-                    rows.append(c)
-                    print_fn(f"{c['stage']:>6}{c['zero_mode']:>13}"
-                             f"{c['wire_dtype']:>7}"
-                             f"{c['micro_variant']:>18}"
-                             f"{c['latency_us']:>12.1f}"
-                             f"{c['iqr_us']:>9.1f}"
-                             f"{c['wire_bytes']:>12}")
-                fm = next(r for r in rows[-len(ZERO_MODE_LANES):]
-                          if r["zero_mode"] == "flat_manual")
-                for r in rows[-len(ZERO_MODE_LANES):]:
-                    if r["zero_mode"] != "flat_manual" and r["latency_us"]:
-                        print_fn(
-                            f"# z{stage}/{wire} {r['zero_mode']}: "
-                            f"{fm['latency_us'] / r['latency_us']:.2f}x "
-                            f"vs flat_manual")
-    finally:
-        # restore the bench mesh for whatever sweeps follow
-        groups.reset_mesh()
-        import deepspeed_tpu
-        deepspeed_tpu.comm.destroy_process_group()
-        groups.initialize_mesh(**{k: int(v) for k, v in orig.items()})
-    return rows
-
-
-# engine-variant op → (facade op, comms-logging variant tag) so traced
-# sweeps use the same ``op[variant]`` vocabulary as training traces
-_TRACE_VARIANTS = {
-    "hier_all_reduce": ("all_reduce", "hier"),
-    "quant_all_gather": ("all_gather", f"q_{WIRE_FORMAT}"),
-    "quant_reduce_scatter": ("reduce_scatter", f"q_{WIRE_FORMAT}"),
-    "hier_quant_reduce_scatter": ("reduce_scatter", f"hier_q_{WIRE_FORMAT}"),
-}
-
-
 def run(ops=ALL_OPS, axis="dp", minsize=16, maxsize=26, mesh_spec=None,
         iters=20, warmup=3, print_fn=print, intra=0, json_path=None,
-        trace_dir=None, overlap=False, overlap_total_mb=8.0,
-        overlap_bucket_mbs=OVERLAP_BUCKET_MBS, overlap_wires=OVERLAP_WIRES,
-        overlap_directions=OVERLAP_DIRECTIONS, repeat=3, moe=False,
-        moe_experts=MOE_EXPERTS, moe_capacity_factors=MOE_CAPACITY_FACTORS,
-        moe_wires=MOE_WIRES, moe_tokens=MOE_TOKENS, zero_mode=False,
-        zero_mode_stages=(2, ), zero_mode_wires=ZERO_MODE_WIRES):
+        repeat=3):
     """Sweep collectives over powers-of-two message sizes.  Returns rows of
     (op, bytes, wire_bytes, latency_s, algbw_gbps, busbw_gbps, iqr_s) —
     latency is the MEDIAN over ``repeat`` timed blocks, iqr their
     interquartile range (see ``_timed_stats``); with ``json_path``, also
-    writes them as machine-readable JSON; with ``trace_dir``, archives
-    telemetry artifacts (chrome trace + per-variant comm attribution)
-    alongside the sweep output so a BENCH_*.json row can be traced back to
-    what actually ran."""
+    writes them as machine-readable JSON."""
     from ..utils import groups
     if mesh_spec:
         kw = {}
@@ -950,17 +240,10 @@ def run(ops=ALL_OPS, axis="dp", minsize=16, maxsize=26, mesh_spec=None,
         groups.reset_mesh()
         groups.initialize_mesh(**kw)
     mesh = groups.get_mesh_state().mesh
-    # the op/overlap sweeps run collectives over `axis`; a moe-only
-    # invocation keys on the ep axis instead (run_moe_sweep guards it)
-    needs_axis = bool(ops) or overlap
-    if needs_axis and mesh.shape.get(axis, 1) < 2:
+    if mesh.shape.get(axis, 1) < 2:
         raise SystemExit(
             f"axis {axis!r} has size {mesh.shape.get(axis, 1)} on mesh "
             f"{dict(mesh.shape)} — nothing to benchmark (pass --mesh)")
-    recorder = None
-    if trace_dir:
-        from ..telemetry import TraceRecorder
-        recorder = TraceRecorder(trace_dir, rank=0)
     rows = []
     print_fn(f"# mesh={dict(mesh.shape)} axis={axis} dtype=fp32 "
              f"wire={WIRE_FORMAT} repeat={repeat}")
@@ -969,50 +252,18 @@ def run(ops=ALL_OPS, axis="dp", minsize=16, maxsize=26, mesh_spec=None,
     for op in ops:
         for p in range(minsize, maxsize + 1, 2):
             try:
-                if recorder is not None:
-                    with recorder.span(f"{op}/{1 << p}", cat="bench"):
-                        size, wire, lat, algbw, busbw, iqr = _bench_one(
-                            op, axis, 1 << p, mesh, iters, warmup,
-                            intra=intra, repeat=repeat)
-                else:
-                    size, wire, lat, algbw, busbw, iqr = _bench_one(
-                        op, axis, 1 << p, mesh, iters, warmup, intra=intra,
-                        repeat=repeat)
+                size, wire, lat, algbw, busbw, iqr = _bench_one(
+                    op, axis, 1 << p, mesh, iters, warmup, intra=intra,
+                    repeat=repeat)
             except UnsplittableAxis as e:
                 # hier_* on an unsplittable axis: note and keep sweeping the
                 # other ops (any other error still fails the bench loudly)
                 print_fn(f"# {op}: skipped ({e})")
                 break
             rows.append((op, size, wire, lat, algbw, busbw, iqr))
-            if recorder is not None:
-                base, variant = _TRACE_VARIANTS.get(op, (op, None))
-                recorder.comm_event(base, variant, size, wire, lat,
-                                    world_size=mesh.shape[axis])
             print_fn(f"{op:<28}{size:>12}{wire:>12}{lat * 1e6:>14.1f}"
                      f"{iqr * 1e6:>10.1f}{algbw:>12.2f}{busbw:>12.2f}")
-    overlap_rows = []
-    if overlap:
-        overlap_rows = run_overlap_sweep(
-            axis=axis, mesh=mesh, bucket_mbs=overlap_bucket_mbs,
-            wires=overlap_wires, total_mb=overlap_total_mb,
-            iters=max(2, iters // 2), warmup=warmup, print_fn=print_fn,
-            recorder=recorder, directions=overlap_directions)
-    moe_rows = []
-    if moe:
-        moe_rows = run_moe_sweep(
-            mesh=mesh, experts=moe_experts,
-            capacity_factors=moe_capacity_factors, wires=moe_wires,
-            tokens=moe_tokens, iters=max(2, iters // 2), warmup=warmup,
-            repeat=repeat, print_fn=print_fn, recorder=recorder)
-    zero_mode_rows = []
-    if zero_mode:
-        zero_mode_rows = run_zero_mode_sweep(
-            mesh=mesh, stages=zero_mode_stages, wires=zero_mode_wires,
-            iters=max(2, iters // 4), warmup=warmup, repeat=repeat,
-            print_fn=print_fn, recorder=recorder)
     if json_path:
-        # uniform row schema (bench_row): overlap/stat fields present on
-        # every row so BENCH_* aggregation (fold_sweeps) never key-errors
         json_rows = [bench_row(op=op, bytes=int(size),
                                wire_bytes=int(wire), latency_us=lat * 1e6,
                                iqr_us=iqr * 1e6, repeat=repeat,
@@ -1020,13 +271,6 @@ def run(ops=ALL_OPS, axis="dp", minsize=16, maxsize=26, mesh_spec=None,
                                            else "fp32"),
                                algbw_gbps=algbw, busbw_gbps=busbw)
                      for op, size, wire, lat, algbw, busbw, iqr in rows]
-        for c in overlap_rows:
-            # overlap candidates time single blocks, not `repeat` medians —
-            # stamping the op sweep's repeat here would let downstream
-            # aggregation weigh them as multi-block medians they are not
-            json_rows.append(bench_row(**c, latency_us=c["step_ms"] * 1e3))
-        json_rows.extend(moe_rows)  # already uniform bench_row dicts
-        json_rows.extend(zero_mode_rows)  # uniform, direction:"zero_mode"
         payload = {
             "mesh": {k: int(v) for k, v in dict(mesh.shape).items()},
             "axis": axis,
@@ -1038,22 +282,6 @@ def run(ops=ALL_OPS, axis="dp", minsize=16, maxsize=26, mesh_spec=None,
         with open(json_path, "w") as fh:
             json.dump(payload, fh, indent=2)
         print_fn(f"# wrote {len(json_rows)} rows to {json_path}")
-    if recorder is not None:
-        summary_path = os.path.join(recorder.trace_dir, "comm_summary.json")
-        summary = {"mesh": {k: int(v)
-                            for k, v in dict(mesh.shape).items()},
-                   "axis": axis, "ops": recorder.comm_summary()}
-        if overlap_rows:
-            summary["overlap"] = overlap_rows
-        if moe_rows:
-            summary["moe"] = moe_rows
-        if zero_mode_rows:
-            summary["zero_mode"] = zero_mode_rows
-        with open(summary_path, "w") as fh:
-            json.dump(summary, fh, indent=2)
-        recorder.close()
-        print_fn(f"# archived trace + comm attribution under "
-                 f"{recorder.trace_dir}")
     return rows
 
 
@@ -1082,83 +310,11 @@ def cli_main(argv=None):
                     "auto-detect, falling back to an even split)")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write machine-readable rows to PATH")
-    ap.add_argument("--trace", default=None, metavar="DIR",
-                    help="archive telemetry artifacts (chrome trace + "
-                    "per-variant comm attribution) under DIR alongside "
-                    "the --json rows")
-    ap.add_argument("--overlap", action="store_true",
-                    help="also sweep the bucketed overlap schedulers "
-                    "(bucket_mb × wire dtype, reduce AND gather "
-                    "directions; docs/overlap.md)")
-    ap.add_argument("--overlap-directions", default=None,
-                    metavar="D[,D]",
-                    help="comma-separated overlap sweep directions "
-                    "(default reduce,gather)")
-    ap.add_argument("--overlap-total-mb", type=float, default=8.0,
-                    help="total gradient payload for the overlap sweep")
-    ap.add_argument("--overlap-buckets", default=None, metavar="MB,MB,…",
-                    help="comma-separated bucket_mb candidates "
-                    "(default 1,4,16)")
-    ap.add_argument("--overlap-wires", default=None, metavar="W,W",
-                    help="comma-separated wire dtypes for the overlap "
-                    "sweep (default fp32,int8)")
-    ap.add_argument("--moe", action="store_true",
-                    help="also sweep the expert-dispatch exchange "
-                    "(experts × capacity_factor × wire dtype on the ep "
-                    "axis; needs an ep>=2 mesh — docs/moe.md)")
-    ap.add_argument("--moe-experts", default=None, metavar="E,E",
-                    help="comma-separated expert counts (default 8,16)")
-    ap.add_argument("--moe-capacity-factors", default=None, metavar="F,F",
-                    help="comma-separated capacity factors (default 1,2)")
-    ap.add_argument("--moe-wires", default=None, metavar="W,W",
-                    help="comma-separated dispatch wire dtypes "
-                    "(default fp32,int8; the GSPMD baseline always runs)")
-    ap.add_argument("--moe-tokens", type=int, default=MOE_TOKENS,
-                    help="tokens per dispatch for the moe sweep")
-    ap.add_argument("--zero-mode", action="store_true",
-                    help="also run the three-way ZeRO micro-step lane "
-                    "(flat-manual / GSPMD / GSPMD+quantized-islands on a "
-                    "real engine micro — docs/zero.md)")
-    ap.add_argument("--zero-mode-stages", default=None, metavar="S,S",
-                    help="comma-separated ZeRO stages for the zero-mode "
-                    "lane (default 2)")
-    ap.add_argument("--zero-mode-wires", default=None, metavar="W,W",
-                    help="comma-separated qgZ wire dtypes for the "
-                    "zero-mode lane (default int8)")
     args = ap.parse_args(argv)
-    # --overlap/--moe/--zero-mode alone sweep just their lane; add --op to
-    # also run the collective op sweep in the same invocation
-    default_ops = () if (args.overlap or args.moe or args.zero_mode) \
-        else ALL_OPS
-    run(ops=(args.op, ) if args.op else default_ops, axis=args.axis,
+    run(ops=(args.op, ) if args.op else ALL_OPS, axis=args.axis,
         minsize=args.minsize, maxsize=args.maxsize, mesh_spec=args.mesh,
         iters=args.iters, warmup=args.warmup, repeat=args.repeat,
-        intra=args.intra,
-        json_path=args.json, trace_dir=args.trace, overlap=args.overlap,
-        overlap_total_mb=args.overlap_total_mb,
-        overlap_bucket_mbs=(tuple(float(x) for x in
-                                  args.overlap_buckets.split(","))
-                            if args.overlap_buckets else OVERLAP_BUCKET_MBS),
-        overlap_wires=(tuple(args.overlap_wires.split(","))
-                       if args.overlap_wires else OVERLAP_WIRES),
-        overlap_directions=(tuple(args.overlap_directions.split(","))
-                            if args.overlap_directions
-                            else OVERLAP_DIRECTIONS),
-        moe=args.moe,
-        moe_experts=(tuple(int(x) for x in args.moe_experts.split(","))
-                     if args.moe_experts else MOE_EXPERTS),
-        moe_capacity_factors=(
-            tuple(float(x) for x in args.moe_capacity_factors.split(","))
-            if args.moe_capacity_factors else MOE_CAPACITY_FACTORS),
-        moe_wires=(tuple(args.moe_wires.split(","))
-                   if args.moe_wires else MOE_WIRES),
-        moe_tokens=args.moe_tokens,
-        zero_mode=args.zero_mode,
-        zero_mode_stages=(tuple(int(x) for x in
-                                args.zero_mode_stages.split(","))
-                          if args.zero_mode_stages else (2, )),
-        zero_mode_wires=(tuple(args.zero_mode_wires.split(","))
-                         if args.zero_mode_wires else ZERO_MODE_WIRES))
+        intra=args.intra, json_path=args.json)
 
 
 if __name__ == "__main__":

@@ -74,8 +74,7 @@ class CommOptimizations:
     min_message_size: int = 0
     # micro-step architecture for the qgZ training path: "gspmd" (default,
     # the GSPMD-first micro with quantized islands — docs/zero.md) or
-    # "flat_manual" (force the legacy full-manual shard_map micro; the
-    # ds_bench --zero-mode baseline lane)
+    # "flat_manual" (force the legacy full-manual shard_map micro)
     zero_mode: str = "gspmd"
     # bucketed backward-pass gradient-reduction scheduler
     overlap: Overlap = field(default_factory=Overlap)

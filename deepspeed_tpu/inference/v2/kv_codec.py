@@ -11,8 +11,8 @@ the whole cache.
 Scale granularity is per (layer, k/v, token, head): one scale over a
 token's ``[Dh]`` head row — 4·Hkv bytes/token/layer of overhead (well
 under 2% for Dh ≥ 64), fine enough that int8 greedy decode stays
-token-identical to the fp cache (the serve_bench ``--smoke`` gate pins
-this over ≥64 decode steps).
+token-identical to the fp cache (``tests/unit/serving/test_kv_quant.py``
+pins this over ≥64 decode steps).
 
 TPU note: the quantized path reads through the XLA gather fallback of
 ``ragged_forward._paged_attention`` — the Pallas paged kernel streams fp
@@ -60,8 +60,7 @@ def codec(fmt):
 
 def kv_bytes_per_token(num_layers, num_kv_heads, head_dim, fmt=None,
                        fp_dtype=jnp.bfloat16):
-    """Cache bytes one token occupies (both K and V, all layers) — the
-    ``kv_bytes_per_token`` field of serve_bench's ``--json`` rows.
+    """Cache bytes one token occupies (both K and V, all layers).
     ``fmt=None`` is the full-precision cache in ``fp_dtype``."""
     elems = 2 * num_layers * num_kv_heads * head_dim
     if fmt is None:

@@ -386,8 +386,8 @@ def _token_axes(mesh):
 
 def resolve_exchange(mesh, opts, ep_axis, payload_elems):
     """(wire, group_size, hierarchy-or-None, wire_bytes) for one dispatch
-    exchange of ``payload_elems`` fp32 elements — the public view of what
-    the dispatcher will put on the wire (ds_bench reports through it)."""
+    exchange of ``payload_elems`` fp32 elements — what the dispatcher
+    will put on the wire."""
     gs = int(getattr(opts, "quantization_group_size", Q.DEFAULT_GROUP_SIZE))
     wire = dispatch_wire(payload_elems * 4, opts)
     h = None

@@ -20,8 +20,8 @@ Design (megablocks-style, guided by the group-padding trick):
 XLA's native ``lax.ragged_dot`` serves the same role, and is what the expert
 layer (``moe/held_experts.py`` ``grouped_matmul``) runs unless its caller asks
 for this kernel.  Timed against it on a v5e at a serving step's shapes
-(``tools/moe_gmm_bench.py``; docs/kernels.md has the readings), the kernel
-with tiles of 256 x 1024 x 1024 is ahead in buffers of up to 2560 rows, and
+(docs/kernels.md has the readings), the kernel with tiles of
+256 x 1024 x 1024 is ahead in buffers of up to 2560 rows, and
 ``cohere2_moe_ragged_step`` asks for it there; it is behind in a buffer of
 16 384, and it has NO gradient (no ``custom_vjp``): a forward that may be
 differentiated keeps ``ragged_dot``.  One was tried for the training step

@@ -53,7 +53,7 @@ PEAK_FLOPS_BY_KIND = (
     ("tpu v2", 46e12),
     # nominal host-CPU figure so the tier-1 telemetry tests (which run on
     # the CPU mesh) get a finite MFU.  Not a device number: chip_smoke.py
-    # and bench.py refuse to run on a CPU before they could reach it.
+    # and perfbench/run.py refuse to run on a CPU before they could reach it.
     ("cpu", 1e11),
 )
 
@@ -314,8 +314,7 @@ class CostModelRegistry:
         return [p.describe() for p in self._programs.values()]
 
     def total_flops_executed(self):
-        """Σ flops × calls over programs with a known flop count (the
-        serve_bench MFU numerator)."""
+        """Σ flops × calls over programs with a known flop count."""
         total = 0.0
         any_known = False
         for p in self._programs.values():
@@ -381,7 +380,7 @@ def check_oom_margin(name, peak_hbm_bytes):
 
 # -------------------------------------------------------------- capture API
 #: force-capture switch for tools that want the registry populated without
-#: enabling the full telemetry spine (serve_bench, chip_smoke);
+#: enabling the full telemetry spine (chip_smoke);
 #: telemetry.enabled also arms capture at the opt-in call sites (serving) —
 #: the training engine captures unconditionally.
 _force_capture = False

@@ -165,7 +165,7 @@ class CommOptimizationsConfig(DeepSpeedConfigModel):
     #     islands cannot express yet (tp>1, hpZ/MiCS, MoE, dp×ep) keep the
     #     manual micro automatically;
     #   "flat_manual" — force the legacy full-manual shard_map micro
-    #     (the ds_bench --zero-mode baseline lane).
+    #     (the baseline the islands micro is held bitwise-equal to).
     zero_mode: str = "gspmd"
     # bucketed backward-pass gradient-reduction scheduler (own enable gate)
     overlap: OverlapConfig = OverlapConfig()

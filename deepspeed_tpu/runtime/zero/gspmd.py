@@ -36,8 +36,8 @@ Compositions whose correctness depends on the full-manual region keep it:
 reshaped meshes, MoE's manual-context dispatch, sp/pp rejection, dp×ep
 hierarchies) and the engine routes those to ``build_manual_dp_micro``
 unchanged.  ``comm_optimizations.zero_mode: "flat_manual"`` forces the
-legacy micro everywhere — the ``ds_bench --zero-mode`` lane measures the
-two against each other (flat-manual / GSPMD / GSPMD+quantized-islands).
+legacy micro everywhere — ``tests/unit/runtime/test_zero_gspmd.py`` holds
+the islands micro loss-bitwise-equal to it.
 """
 
 import numpy as np

@@ -39,7 +39,7 @@ islands, XLA scheduling everything around them.  The full-manual
 remains for the compositions the islands cannot express yet (tp>1 via
 PARTIAL-manual shard_map, hpZ/MiCS reshaped meshes, MoE's manual-context
 dispatch, dp×ep hierarchies) and for ``comm_optimizations.zero_mode:
-"flat_manual"`` (the ``ds_bench --zero-mode`` baseline lane); sp/pp are
+"flat_manual"`` (the baseline the islands micro is tested against); sp/pp are
 rejected loudly (their collectives interleave with the reduction being
 replaced).
 

@@ -5,7 +5,7 @@ Continuous in-flight batching over the FastGen-style ragged engine
 DONE/EVICTED), token-budget admission with KV-pressure backpressure, LIFO
 preemption-and-requeue on KV exhaustion, streaming per-token callbacks,
 and the quantized paged-KV mode (``kv_cache_dtype: int8|fp8``).  See
-docs/serving.md; ``tools/serve_bench.py`` is the traffic driver.
+docs/serving.md; ``perfbench/jobs/serve.py`` drives it with traffic.
 """
 
 from .config import ServingConfig                          # noqa: F401

@@ -117,7 +117,6 @@ class TraceRecorder:
         self._moe_s = {}             # layer → accumulated routing stats
         self._hbm = None             # memory_stats snapshot for the step
         self._step_comm = CommAttribution()
-        self._run_comm = CommAttribution()
         self.steps_recorded = 0
         os.makedirs(self.trace_dir, exist_ok=True)
         atexit.register(self.close)
@@ -359,7 +358,7 @@ class TraceRecorder:
     def comm_event(self, op, variant, msg_bytes, wire_bytes, latency_s,
                    world_size=1, exposed=True):
         """One eager collective: chrome event on the comm track + join into
-        the per-step (and whole-run) attribution.  ``exposed=False`` books
+        the per-step attribution.  ``exposed=False`` books
         the latency as hidden (overlapped-under-compute) comm time — it
         feeds ``overlap_efficiency`` instead of the exposed fraction."""
         if self._closed:
@@ -372,8 +371,6 @@ class TraceRecorder:
                          "wire_bytes": int(wire_bytes if wire_bytes
                                            is not None else msg_bytes),
                          "exposed": bool(exposed)})
-        self._run_comm.record(op, variant, msg_bytes, wire_bytes, latency_s,
-                              world_size, exposed=exposed)
         if self._step is not None:
             self._step_comm.record(op, variant, msg_bytes, wire_bytes,
                                    latency_s, world_size, exposed=exposed)
@@ -386,11 +383,6 @@ class TraceRecorder:
         except (TypeError, ValueError):
             payload = repr(payload)
         self._meta[str(name)] = payload
-
-    def comm_summary(self):
-        """Whole-run per-``op[variant]`` attribution (``ds_bench --trace``
-        and the smoke tool read this)."""
-        return self._run_comm.summary()
 
     # ---------------------------------------------------------------- output
     def chrome_trace(self):

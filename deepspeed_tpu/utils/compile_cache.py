@@ -9,7 +9,7 @@ name, a pid, a timestamp) never hits.  Policy:
   (:data:`CHECKOUT_CACHE_DIR`).
 
 Library code never calls this — only ``__main__`` entry points do
-(``chip_smoke.py``, ``bench.py``, the examples, ``__graft_entry__.py``).
+(``chip_smoke.py``, ``perfbench/``, the examples, ``__graft_entry__.py``).
 """
 
 import os

@@ -1,11 +1,9 @@
 """tools/autotune_smoke.py — the ISSUE-12 tier-1 gate, driven in-process
-(bench-gate convention: loaded via importlib, no subprocess)."""
+(loaded via importlib, no subprocess)."""
 
 import importlib.util
 import json
 import os
-
-import pytest
 
 TOOLS = os.path.join(os.path.dirname(__file__), "..", "..", "..", "tools")
 
@@ -18,29 +16,14 @@ def _load_smoke():
     return mod
 
 
-@pytest.mark.parametrize("with_priors", (False, True))
-def test_autotune_smoke_gate(tmp_path, with_priors):
+def test_autotune_smoke_gate(tmp_path):
     """End-to-end acceptance: probe → budgeted search → autotuned config's
     measured step time ≤ the hand-written default's, chosen config passes
     the comm_smoke loss-parity gate, and the emit-stage artifacts land
     with the round-tripped block."""
     smoke = _load_smoke()
-    priors_file = ""
-    if with_priors:
-        # a priors file seeds the search without changing the verdict
-        priors_file = str(tmp_path / "priors.json")
-        with open(priors_file, "w") as f:
-            json.dump({"schema": "ds_tpu_autotune_priors/1",
-                       "generated_from": [],
-                       "overlap": [{"direction": "reduce",
-                                    "bucket_mb": 0.0005,
-                                    "wire_dtype": "int8",
-                                    "overlap_efficiency": 0.9,
-                                    "exposed_comm_frac": 0.05,
-                                    "runs": 2}]}, f)
     results = tmp_path / "results"
-    r = smoke.run_autotune_smoke(trials=8, results_dir=str(results),
-                                 priors_file=priors_file)
+    r = smoke.run_autotune_smoke(trials=8, results_dir=str(results))
     assert r["pass"], r
     assert r["beats_default"] and r["best_step_ms"] <= r["default_step_ms"]
     assert r["parity_delta"] <= r["tolerance"] and r["converged"]

@@ -137,58 +137,3 @@ def test_mesh_tuning_space_and_trial(tmp_path):
     assert all(r["result"] is not None for r in tuner.results), tuner.results
     groups.reset_mesh()
     dist.destroy_process_group()
-
-
-def test_model_based_tuner_measured_priors(tmp_path):
-    """r5 (VERDICT #9): on-chip sweep records seed the cost model, so the
-    tuner's FIRST proposed candidate is the best measured config — no cold
-    trials re-measuring what the sweep already paid for."""
-    from deepspeed_tpu.autotuning.priors import (load_measured_priors,
-                                                 record_to_prior)
-
-    # fake .bench_runs: device-mode records peaked at B=4, plus records
-    # the trust filter must drop
-    runs = tmp_path / "runs"
-    (runs / "sweeps").mkdir(parents=True)
-    def rec(b, v, note=""):
-        return {"metric": "llama_train_tokens_per_sec_per_chip",
-                "value": v,
-                "unit": f"tokens/s (B={b} S=2048 params=536M step=100ms "
-                        f"MFU=0.5 backend=tpu{note})",
-                "vs_baseline": 1.0}
-    for name, r in [("b1", rec(1, 9000.0)), ("b2", rec(2, 20000.0)),
-                    ("sweeps/b4", rec(4, 31000.0)),
-                    ("sweeps/b8", rec(8, 24000.0)),
-                    ("sweeps/bad_cpu", rec(16, 99999.0,
-                                           " [cpu-fallback: x]")),
-                    ("sweeps/bad_partial", rec(16, 88888.0, " partial"))]:
-        (runs / f"{name}.json").write_text(json.dumps(r))
-    priors = load_measured_priors(str(runs))
-    assert len(priors) == 4  # untrusted records filtered
-    assert {p["ds_config"]["train_micro_batch_size_per_gpu"]
-            for p in priors} == {1, 2, 4, 8}
-
-    # candidate space: same stage/gas, mbs axis — first proposal must be
-    # the measured-best mbs=4
-    exps = [{"name": f"mbs{b}",
-             "ds_config": {"zero_optimization": {"stage": 0},
-                           "train_micro_batch_size_per_gpu": b,
-                           "gradient_accumulation_steps": 1}}
-            for b in (1, 2, 4, 8)]
-    seen = []
-
-    def run(exp):
-        seen.append(exp["name"])
-        b = exp["ds_config"]["train_micro_batch_size_per_gpu"]
-        return {"throughput": {1: 9000.0, 2: 20000.0, 4: 31000.0,
-                               8: 24000.0}[b]}
-
-    tuner = ModelBasedTuner(exps, run, priors=priors)
-    best = tuner.tune(n_trials=1)      # ONE trial allowed
-    assert seen[0] == "mbs4", seen     # first candidate = measured best
-    assert best["name"] == "mbs4"
-
-    # non-record files and cold tuner keep working
-    assert record_to_prior({"metric": "other", "value": 1}) is None
-    cold = ModelBasedTuner(_exps(), _runner_best_at(2))
-    assert cold.tune(n_trials=100)["name"] == "e2"

@@ -1,9 +1,8 @@
 """Closed-loop comm autotuner (ISSUE-12): tuner strategies on synthetic
-cost surfaces, probe machinery + wire-ladder derivation, priors-file flow,
-and the emitted-config round-trip self-check."""
+cost surfaces, probe machinery + wire-ladder derivation, and the
+emitted-config round-trip self-check."""
 
 import json
-import os
 
 import numpy as np
 import pytest
@@ -13,19 +12,6 @@ from deepspeed_tpu.autotuning import (Autotuner, AutotuningError,
                                       RandomTuner, derive_wire_ladder,
                                       featurize_config, probe_topology,
                                       run_probes)
-from deepspeed_tpu.autotuning.priors import (PRIORS_SCHEMA, load_priors_file,
-                                             seed_exps_with_priors)
-
-TOOLS = os.path.join(os.path.dirname(__file__), "..", "..", "..", "tools")
-
-
-def _load_tool(name):
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(TOOLS, f"{name}.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 # --------------------------------------------------- synthetic cost surface
@@ -217,49 +203,6 @@ def test_derive_wire_ladder_merges_runs():
     assert ladder == [[1 << 12, "fp32"], [None, "int8"]]
 
 
-# ------------------------------------------------------------- priors file
-def test_priors_file_round_trip_and_seeding(tmp_path):
-    fold = _load_tool("fold_sweeps")
-    # the duplicated schema tag must never drift from the loader's
-    assert fold.PRIORS_SCHEMA == PRIORS_SCHEMA
-    sweep = {"rows": [
-        {"op": "overlap", "direction": "reduce", "bucket_mb": 4.0,
-         "wire_dtype": "int8", "overlap_efficiency": 0.9,
-         "exposed_comm_frac": 0.05},
-        {"op": "overlap", "direction": "reduce", "bucket_mb": 1.0,
-         "wire_dtype": "fp32", "overlap_efficiency": 0.3,
-         "exposed_comm_frac": 0.4}]}
-    p = tmp_path / "sweep.json"
-    p.write_text(json.dumps(sweep))
-    out = tmp_path / "priors.json"
-    payload = fold.export_priors([str(p)], str(out))
-    assert payload["overlap"][0]["bucket_mb"] == 4.0  # best first
-
-    priors = load_priors_file(str(out))
-    assert priors["schema"] == PRIORS_SCHEMA
-    # candidates matching the measured best (int8, bucket 4.0) run first
-    exps = [
-        {"name": "default", "ds_config": {}},
-        {"name": "match", "ds_config": {"comm_optimizations": {
-            "enabled": True, "quantized_gradients": True,
-            "wire_dtype": "int8",
-            "overlap": {"enabled": True, "bucket_mb": 4.0}}}},
-        {"name": "mismatch", "ds_config": {"comm_optimizations": {
-            "enabled": True, "quantized_gradients": True,
-            "wire_dtype": "fp8",
-            "overlap": {"enabled": True, "bucket_mb": 16.0}}}},
-    ]
-    ordered = seed_exps_with_priors(exps, priors)
-    assert ordered[0]["name"] == "match"
-
-
-def test_priors_file_rejects_foreign_json(tmp_path):
-    p = tmp_path / "random.json"
-    p.write_text(json.dumps({"rows": []}))
-    with pytest.raises(ValueError, match="not an autotuner priors file"):
-        load_priors_file(str(p))
-
-
 # --------------------------------------------------------- emit round-trip
 def _tuner_for_emit(tmp_path):
     return Autotuner(lambda p, x: x, {"autotuning": {
@@ -317,19 +260,21 @@ def test_emit_block_detects_silent_value_drift(tmp_path):
 
 
 # ------------------------------------------------------------ config guard
-def test_autotuning_config_rejects_unknown_keys():
-    from deepspeed_tpu.autotuning import AutotuningConfig
-    with pytest.raises(Exception, match="bucket_mb_candiates"):
-        AutotuningConfig(enabled=True, bucket_mb_candiates=[1.0])  # typo
+@pytest.mark.parametrize("key, value", [
+    ("bucket_mb_candiates", [1.0]),        # typo
     # stale reference-only fields are gone, not silently accepted
-    with pytest.raises(Exception, match="arg_mappings"):
-        AutotuningConfig(arg_mappings={"a": "b"})
-    with pytest.raises(Exception, match="metric"):
-        AutotuningConfig(metric="tokens")
-    with pytest.raises(Exception, match="tuner_type"):
-        AutotuningConfig(tuner_type="bayes")
-    with pytest.raises(Exception, match="probe_wires"):
-        AutotuningConfig(probe_wires=["int7"])
+    ("arg_mappings", {"a": "b"}),
+    ("metric", "tokens"),
+    ("tuner_type", "bayes"),
+    ("probe_wires", ["int7"]),
+    # the tuner starts cold: the two keys that seeded it are gone
+    ("priors_file", "x"),
+    ("priors_path", "x"),
+])
+def test_autotuning_config_rejects_unknown_keys(key, value):
+    from deepspeed_tpu.autotuning import AutotuningConfig
+    with pytest.raises(Exception, match=key):
+        AutotuningConfig(enabled=True, **{key: value})
 
 
 def test_runtime_config_validates_autotuning_block():
@@ -436,20 +381,10 @@ def test_wire_ladder_steers_zero_training_path():
                zip(ladder_fp32, flat)) <= 1e-6  # fp32 rung = unquantized
 
 
-def test_comm_space_pins_user_block_and_gather_candidates(tmp_path):
+def test_comm_space_pins_user_block_and_gather_candidates():
     """The user's own hand-written comm block is a pinned candidate (the
-    ≤-baseline covers what the user already had, and priors reordering
-    can't push it past the trial budget), and stage-3 spaces carry
-    prefetch candidates for the gather-direction priors to land on."""
-    fold = _load_tool("fold_sweeps")
-    priors_path = tmp_path / "p.json"
-    sweep = tmp_path / "s.json"
-    sweep.write_text(json.dumps({"rows": [
-        {"op": "overlap", "direction": "gather", "bucket_mb": 4.0,
-         "wire_dtype": "int8", "overlap_efficiency": 0.9,
-         "exposed_comm_frac": 0.1}]}))
-    fold.export_priors([str(sweep)], str(priors_path))
-
+    ≤-baseline covers what the user already had), and stage-3 spaces
+    carry prefetch candidates in the order of ``bucket_mb_candidates``."""
     at = Autotuner(lambda p, x: x, {
         "zero_optimization": {"stage": 3},
         "comm_optimizations": {"enabled": True, "wire_dtype": "fp8",
@@ -457,21 +392,19 @@ def test_comm_space_pins_user_block_and_gather_candidates(tmp_path):
         "autotuning": {"enabled": True, "tune_comm": True,
                        "zero_stages": [3],
                        "bucket_mb_candidates": [4.0, 16.0],
-                       "probe_wires": ["int8"],
-                       "priors_file": str(priors_path)}})
+                       "probe_wires": ["int8"]}})
     # skip the measured probe stage: candidate construction is under test
     at.probe_rows = []
     at.topology = {}
     exps = at.build_comm_space()
     names = [e["name"] for e in exps]
-    # pinned order survives priors seeding: default first, user block next
+    # pinned order: default first, user block next
     assert names[0] == "z3_default" and names[1] == "z3_user"
     assert exps[1]["ds_config"]["comm_optimizations"]["wire_dtype"] == "fp8"
     # stage-3 space carries prefetch candidates...
     pf = [e for e in exps if "_pf" in e["name"]]
     assert pf, names
-    # ...and the gather prior (bucket 4.0) ranks its match before the
-    # non-matching prefetch candidate
+    # ...in the order the config lists their bucket sizes
     pf_names = [n for n in names if "_pf" in n]
     assert pf_names[0].startswith("z3_pf4"), pf_names
 

@@ -1,5 +1,7 @@
 """ds_bench collective sweep (reference bin/ds_bench surface)."""
 
+import re
+
 import numpy as np
 import pytest
 
@@ -71,128 +73,6 @@ def test_probe_op_single_row_schema():
     assert flat["wire_dtype"] == "fp32"
     assert q["wire_dtype"] == "fp8"
     assert q["wire_bytes"] < flat["wire_bytes"]  # fp8 payload + scales
-
-
-def test_overlap_sweep_rows_and_schema(tmp_path):
-    """The overlap sweep emits one candidate per (direction, bucket_mb,
-    wire) — reduce AND gather directions — with the overlap-efficiency
-    accounting, archives them under --trace, and every --json row (op
-    sweep included) carries the uniform overlap fields."""
-    import json
-    out = tmp_path / "bench.json"
-    trace = tmp_path / "trace"
-    run(ops=("all_reduce", ), axis="dp", minsize=12, maxsize=12, iters=1,
-        warmup=1, print_fn=lambda *a: None, json_path=str(out),
-        trace_dir=str(trace), overlap=True, overlap_total_mb=0.5,
-        overlap_bucket_mbs=(0.05, 0.25), overlap_wires=("fp32", "int8"))
-    payload = json.loads(out.read_text())
-    over = [r for r in payload["rows"] if r["op"] == "overlap"]
-    flat = [r for r in payload["rows"] if r["op"] != "overlap"]
-    assert len(over) == 8 and len(flat) == 1
-    assert {r["direction"] for r in over} == {"reduce", "gather"}
-    for row in payload["rows"]:  # uniform schema, flat rows carry None
-        assert {"overlap_efficiency", "bucket_mb", "direction",
-                "exposed_comm_frac"} <= set(row)
-    assert flat[0]["overlap_efficiency"] is None
-    assert flat[0]["direction"] is None
-    for c in over:
-        assert 0.0 <= c["overlap_efficiency"] <= 1.0
-        assert 0.0 <= c["exposed_comm_frac"] <= 1.0
-        assert c["buckets"] >= 1 and c["comm_ms"] > 0 and c["step_ms"] > 0
-        # PR 14: compiled-cost fields on every candidate (CPU backend
-        # implements cost/memory analysis, so both are populated here)
-        assert c["mfu"] is not None and c["mfu"] > 0
-        assert c["peak_hbm_bytes"] and c["peak_hbm_bytes"] > 0
-    # smaller bound → more buckets, in both directions
-    eff = {(c["direction"], c["bucket_mb"], c["wire_dtype"]): c["buckets"]
-           for c in over}
-    assert eff[("reduce", 0.05, "fp32")] >= eff[("reduce", 0.25, "fp32")]
-    assert eff[("gather", 0.05, "fp32")] >= eff[("gather", 0.25, "fp32")]
-    # --trace archived the candidates for trace_report --json
-    summary = json.loads((trace / "comm_summary.json").read_text())
-    assert len(summary["overlap"]) == 8
-    # int8 candidates move fewer wire bytes than fp32 at equal payload,
-    # per direction
-    for direction in ("reduce", "gather"):
-        by_wire = {}
-        for c in over:
-            if c["direction"] == direction:
-                by_wire.setdefault(c["wire_dtype"], c["wire_bytes"])
-        assert by_wire["int8"] < by_wire["fp32"], direction
-
-
-def test_overlap_sweep_rejects_unknown_direction():
-    """A --overlap-directions typo fails loudly instead of burning a
-    sweep under a mislabeled tag every report would drop."""
-    from deepspeed_tpu.benchmarks.comm_bench import run_overlap_sweep
-    with pytest.raises(ValueError, match="gahter"):
-        run_overlap_sweep(axis="dp", directions=("reduce", "gahter"),
-                          print_fn=lambda *a: None)
-
-
-def test_fold_sweeps_aggregates_overlap(tmp_path):
-    import importlib.util
-    import json
-    import os
-    spec = importlib.util.spec_from_file_location(
-        "fold_sweeps", os.path.join(os.path.dirname(__file__), "..", "..",
-                                    "..", "tools", "fold_sweeps.py"))
-    fold = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(fold)
-    rows = [{"op": "overlap", "bucket_mb": 4.0, "wire_dtype": "int8",
-             "overlap_efficiency": 0.8, "exposed_comm_frac": 0.1},
-            {"op": "overlap", "bucket_mb": 4.0, "wire_dtype": "int8",
-             "overlap_efficiency": 0.6, "exposed_comm_frac": 0.3},
-            {"op": "overlap", "bucket_mb": 1.0, "wire_dtype": "fp32",
-             "overlap_efficiency": 0.2, "exposed_comm_frac": 0.5},
-            {"op": "all_reduce", "bucket_mb": None,
-             "overlap_efficiency": None, "exposed_comm_frac": None}]
-    p1 = tmp_path / "a.json"
-    p1.write_text(json.dumps({"rows": rows[:2]}))
-    p2 = tmp_path / "b.json"
-    p2.write_text(json.dumps({"rows": rows[2:]}))
-    agg = fold.aggregate_overlap([str(p1), str(p2)])
-    assert agg[0]["bucket_mb"] == 4.0 and agg[0]["runs"] == 2
-    assert abs(agg[0]["overlap_efficiency"] - 0.7) < 1e-9
-    assert agg[1]["bucket_mb"] == 1.0  # sorted best-first
-    # rows predating the direction field aggregate as direction="reduce"
-    assert all(r["direction"] == "reduce" for r in agg)
-    # bench-format and malformed files are ignored, not fatal
-    (tmp_path / "c.json").write_text("{not json")
-    assert fold.aggregate_overlap([str(tmp_path / "c.json")]) == []
-
-
-def test_fold_sweeps_aggregates_both_directions(tmp_path):
-    """One sweep archive feeds the autotuner both bucket sizes: gather
-    rows aggregate separately from reduce rows under the same
-    (bucket_mb, wire) cell."""
-    import importlib.util
-    import json
-    import os
-    spec = importlib.util.spec_from_file_location(
-        "fold_sweeps", os.path.join(os.path.dirname(__file__), "..", "..",
-                                    "..", "tools", "fold_sweeps.py"))
-    fold = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(fold)
-    rows = [{"op": "overlap", "direction": "reduce", "bucket_mb": 4.0,
-             "wire_dtype": "int8", "overlap_efficiency": 0.8,
-             "exposed_comm_frac": 0.1},
-            {"op": "overlap", "direction": "gather", "bucket_mb": 4.0,
-             "wire_dtype": "int8", "overlap_efficiency": 0.4,
-             "exposed_comm_frac": 0.5},
-            {"op": "overlap", "direction": "gather", "bucket_mb": 1.0,
-             "wire_dtype": "int8", "overlap_efficiency": 0.6,
-             "exposed_comm_frac": 0.2}]
-    p = tmp_path / "a.json"
-    p.write_text(json.dumps({"rows": rows}))
-    agg = fold.aggregate_overlap([str(p)])
-    assert len(agg) == 3
-    gather = [r for r in agg if r["direction"] == "gather"]
-    reduce_ = [r for r in agg if r["direction"] == "reduce"]
-    assert len(gather) == 2 and len(reduce_) == 1
-    # best-first within the gather direction
-    assert gather[0]["bucket_mb"] == 1.0
-    assert gather[0]["overlap_efficiency"] == 0.6
 
 
 def test_hier_ops_skipped_on_unsplittable_axis():
@@ -268,148 +148,24 @@ def test_group_rank_introspection():
     dist.destroy_process_group()
 
 
-def test_moe_sweep_rows_and_schema(tmp_path):
-    """ds_bench --moe: uniform bench_row schema (E × capacity_factor ×
-    wire), GSPMD baseline per cell, quantized rows moving fewer wire
-    bytes, and archived into the --json payload + comm_summary."""
+def test_cli_is_the_op_sweep_and_nothing_else(tmp_path, capsys):
+    """``ds_bench`` takes the ten flags of the collective sweep (what a
+    cell decides has no flag here), and its ``--json`` rows are
+    ``bench_row``s."""
     import json
-    from deepspeed_tpu.utils import groups
-    groups.reset_mesh()
-    groups.initialize_mesh(ep=4)
-    out = tmp_path / "moe.json"
-    trace = tmp_path / "trace"
-    run(ops=(), mesh_spec=None, iters=1, warmup=0, repeat=1,
-        print_fn=lambda *a: None, json_path=str(out), trace_dir=str(trace),
-        moe=True, moe_experts=(8, ), moe_capacity_factors=(1.0, ),
-        moe_wires=("fp32", "int8"), moe_tokens=256)
+    from deepspeed_tpu.benchmarks.comm_bench import cli_main, bench_row
+    with pytest.raises(SystemExit):
+        cli_main(["--help"])
+    flags = set(re.findall(r"--[a-z][a-z-]*", capsys.readouterr().out))
+    assert flags == {"--help", "--op", "--axis", "--mesh", "--minsize",
+                     "--maxsize", "--iters", "--warmup", "--repeat",
+                     "--intra", "--json"}
+    out = tmp_path / "out.json"
+    cli_main(["--op", "all_reduce", "--minsize", "12", "--maxsize", "12",
+              "--iters", "1", "--warmup", "1", "--json", str(out)])
+    capsys.readouterr()
     payload = json.loads(out.read_text())
-    rows = [r for r in payload["rows"] if r.get("direction") == "moe"]
-    assert len(rows) == 3  # gspmd baseline + fp32 + int8
-    for row in rows:
-        assert set(row) >= {"op", "bytes", "wire_bytes", "latency_us",
-                            "iqr_us", "repeat", "wire_dtype", "direction",
-                            "experts", "capacity_factor", "capacity",
-                            "drop_fraction", "load_imbalance"}
-        assert row["op"] == "moe_dispatch"
-        assert 0.0 <= row["drop_fraction"] <= 1.0
-        assert row["load_imbalance"] >= 1.0 - 1e-6
-    by_wire = {r["wire_dtype"]: r for r in rows}
-    assert by_wire["int8"]["wire_bytes"] < by_wire["fp32"]["wire_bytes"]
-    assert by_wire["gspmd"]["wire_bytes"] == by_wire["fp32"]["wire_bytes"]
-    summary = json.loads((trace / "comm_summary.json").read_text())
-    assert len(summary["moe"]) == 3
-    groups.reset_mesh()
-
-
-def test_moe_sweep_needs_ep_mesh():
-    from deepspeed_tpu.benchmarks.comm_bench import run_moe_sweep
-    from deepspeed_tpu.utils import groups
-    groups.reset_mesh()
-    groups.initialize_mesh()  # ep=1
-    with pytest.raises(SystemExit, match="ep"):
-        run_moe_sweep(print_fn=lambda *a: None)
-    groups.reset_mesh()
-
-
-def test_fold_sweeps_aggregates_moe(tmp_path):
-    import importlib.util
-    import json
-    import os
-    spec = importlib.util.spec_from_file_location(
-        "fold_sweeps", os.path.join(os.path.dirname(__file__), "..", "..",
-                                    "..", "tools", "fold_sweeps.py"))
-    fold = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(fold)
-    rows = [{"op": "moe_dispatch", "direction": "moe", "experts": 8,
-             "capacity_factor": 1.0, "wire_dtype": "int8",
-             "latency_us": 100.0, "drop_fraction": 0.1,
-             "load_imbalance": 1.5, "wire_bytes": 1000},
-            {"op": "moe_dispatch", "direction": "moe", "experts": 8,
-             "capacity_factor": 1.0, "wire_dtype": "int8",
-             "latency_us": 300.0, "drop_fraction": 0.3,
-             "load_imbalance": 2.5, "wire_bytes": 1000},
-            {"op": "moe_dispatch", "direction": "moe", "experts": 8,
-             "capacity_factor": 1.0, "wire_dtype": "gspmd",
-             "latency_us": 50.0, "drop_fraction": 0.1,
-             "load_imbalance": 1.5, "wire_bytes": 4000},
-            # non-moe rows must be skipped, not crash the fold
-            {"op": "overlap", "direction": "reduce", "bucket_mb": 4.0,
-             "overlap_efficiency": 0.5, "exposed_comm_frac": 0.1}]
-    p = tmp_path / "a.json"
-    p.write_text(json.dumps({"rows": rows}))
-    agg = fold.aggregate_moe([str(p)])
-    assert len(agg) == 2
-    cell = next(r for r in agg if r["wire_dtype"] == "int8")
-    assert cell["runs"] == 2
-    assert abs(cell["latency_us"] - 200.0) < 1e-9
-    assert abs(cell["drop_fraction"] - 0.2) < 1e-9
-    # fastest-first within (E, cf)
-    assert agg[0]["wire_dtype"] == "gspmd"
-
-
-def test_zero_mode_sweep_rows_and_schema(tmp_path):
-    """ds_bench --zero-mode (ISSUE-15 acceptance): the three-way
-    flat-manual / GSPMD / GSPMD+quantized-islands lane emits uniform
-    bench_rows tagged direction:"zero_mode" on a REAL engine micro-step,
-    archives them into --json and comm_summary, and on this 8-virtual-
-    device mesh the GSPMD path's step time is <= flat-manual."""
-    import json
-    from deepspeed_tpu.utils import groups
-    groups.reset_mesh()
-    out = tmp_path / "zm.json"
-    trace = tmp_path / "trace"
-    run(ops=(), mesh_spec=None, iters=2, warmup=1, repeat=1,
-        print_fn=lambda *a: None, json_path=str(out), trace_dir=str(trace),
-        zero_mode=True, zero_mode_stages=(2, ), zero_mode_wires=("int8", ))
-    payload = json.loads(out.read_text())
-    rows = [r for r in payload["rows"] if r.get("direction") == "zero_mode"]
-    assert len(rows) == 3  # flat_manual + gspmd + gspmd_q
-    for row in rows:
-        assert set(row) >= {"op", "bytes", "wire_bytes", "latency_us",
-                            "iqr_us", "repeat", "wire_dtype", "direction",
-                            "zero_mode", "micro_variant", "stage"}
-        assert row["op"] == "zero_micro_step" and row["stage"] == 2
-        assert row["latency_us"] > 0
-    by_mode = {r["zero_mode"]: r for r in rows}
-    assert by_mode["flat_manual"]["micro_variant"] == "qgZ_manual"
-    assert by_mode["gspmd_q"]["micro_variant"] == "qgZ_islands"
-    assert by_mode["gspmd"]["wire_dtype"] == "fp32"
-    # quantized lanes move fewer wire bytes than the flat GSPMD lane
-    assert by_mode["gspmd_q"]["wire_bytes"] < by_mode["gspmd"]["wire_bytes"]
-    # the acceptance bar: XLA-scheduled >= hand-rolled on >=8 devices
-    assert by_mode["gspmd"]["latency_us"] <= \
-        by_mode["flat_manual"]["latency_us"], by_mode
-    summary = json.loads((trace / "comm_summary.json").read_text())
-    assert len(summary["zero_mode"]) == 3
-    # the lane restores the bench mesh for whatever sweeps follow
-    assert dict(groups.get_mesh_state().mesh.shape)["dp"] == 8
-    groups.reset_mesh()
-
-
-def test_fold_sweeps_aggregates_zero_mode(tmp_path):
-    import importlib.util
-    import json
-    import os
-    spec = importlib.util.spec_from_file_location(
-        "fold_sweeps", os.path.join(os.path.dirname(__file__), "..", "..",
-                                    "..", "tools", "fold_sweeps.py"))
-    fold = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(fold)
-    zm = {"op": "zero_micro_step", "direction": "zero_mode", "stage": 2,
-          "wire_dtype": "int8", "wire_bytes": 500, "mfu": None,
-          "peak_hbm_bytes": None}
-    rows = [dict(zm, zero_mode="gspmd_q", latency_us=100.0),
-            dict(zm, zero_mode="gspmd_q", latency_us=300.0),
-            dict(zm, zero_mode="flat_manual", latency_us=400.0),
-            # non-zero-mode rows must be skipped, not crash the fold
-            {"op": "overlap", "direction": "reduce", "bucket_mb": 4.0,
-             "overlap_efficiency": 0.5, "exposed_comm_frac": 0.1}]
-    p = tmp_path / "a.json"
-    p.write_text(json.dumps({"rows": rows}))
-    agg = fold.aggregate_zero_mode([str(p)])
-    assert len(agg) == 2
-    cell = next(r for r in agg if r["zero_mode"] == "gspmd_q")
-    assert cell["runs"] == 2
-    assert abs(cell["latency_us"] - 200.0) < 1e-9
-    # fastest-first within (stage, wire)
-    assert agg[0]["zero_mode"] == "gspmd_q"
+    assert set(payload) == {"mesh", "axis", "dtype", "wire_format",
+                            "quantization_group_size", "rows"}
+    (row, ) = payload["rows"]
+    assert list(row) == list(bench_row()) and row["op"] == "all_reduce"

@@ -1,7 +1,7 @@
 """ISSUE-5 acceptance gate: with ``comm_optimizations`` enabled, a ZeRO-2
 smoke train reaches loss parity (≤1e-2) with the flat path while the
 gradient wire payload shrinks.  Drives ``tools/comm_smoke.py`` in-process
-(same importlib convention as ``test_bench_gate.py`` → ``bench.py``)."""
+(loaded via importlib)."""
 
 import importlib.util
 import os

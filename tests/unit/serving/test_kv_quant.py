@@ -2,9 +2,6 @@
 layout, the int8-vs-fp greedy parity gate (≥64 decode steps on the
 decisive-logits probe model), and unset-dtype bit-identity."""
 
-import importlib.util
-import os
-
 import numpy as np
 import pytest
 
@@ -17,12 +14,8 @@ from deepspeed_tpu.inference.v2 import InferenceEngineV2
 from deepspeed_tpu.inference.v2.kv_codec import (kv_bytes_per_token,
                                                  resolve_kv_dtype)
 from deepspeed_tpu.inference.v2.ragged import BlockedKVCache
-
-_spec = importlib.util.spec_from_file_location(
-    "serve_bench", os.path.join(os.path.dirname(__file__), "..", "..", "..",
-                                "tools", "serve_bench.py"))
-serve_bench = importlib.util.module_from_spec(_spec)
-_spec.loader.exec_module(serve_bench)
+from deepspeed_tpu.serving import ServingScheduler
+from tests.unit.serving.probe_engine import probe_engine, probe_model
 
 
 # -------------------------------------------------------------------- codec
@@ -100,7 +93,7 @@ def test_quantized_cache_layout():
 
 # ----------------------------------------------------------------- engine
 def test_engine_rejects_unknown_kv_dtype():
-    model, params, _ = serve_bench.probe_model()
+    model, params = probe_model()
     with pytest.raises(ValueError, match="kv_cache_dtype"):
         InferenceEngineV2(model, params=params,
                           config=dict(dtype="float32",
@@ -145,21 +138,19 @@ def test_int8_kv_composes_with_tensor_parallel():
     assert outs[1] == outs[2]
 
 
-def _probe_engine(kv_dtype=None, **kw):
-    eng, _ = serve_bench._tiny_engine(kv_dtype=kv_dtype, num_blocks=96,
-                                      probe=True, **kw)
-    return eng
-
-
-def test_int8_kv_parity_gate_64_steps():
+@pytest.mark.parametrize("through", ("generate", "scheduler"))
+def test_int8_kv_parity_gate_64_steps(through):
     """THE acceptance gate: int8 paged-KV greedy decode token-identical to
     the fp cache over ≥64 decode steps (chunked prefill + decode bursts
-    included)."""
+    included), from the engine's own loop and through the scheduler."""
     rng = np.random.default_rng(0)
     prompts = [rng.integers(1, 64, size=n).tolist() for n in (15, 6, 9)]
-    out_fp = _probe_engine().generate(prompts, max_new_tokens=64)
-    eng_q = _probe_engine(kv_dtype="int8")
-    out_q = eng_q.generate(prompts, max_new_tokens=64)
+    out_fp = probe_engine().generate(prompts, max_new_tokens=64)
+    eng_q = probe_engine(kv_dtype="int8")
+    if through == "generate":
+        out_q = eng_q.generate(prompts, max_new_tokens=64)
+    else:
+        out_q = ServingScheduler(eng_q).serve(prompts, max_new_tokens=64)
     assert min(len(o) for o in out_fp) >= 64
     assert out_q == out_fp
     assert getattr(eng_q, "burst_steps", 0) >= 1   # bursts ran quantized
@@ -171,7 +162,7 @@ def test_fp8_kv_serves_and_completes():
     storage dtype — while int8 carries the token-identity gate."""
     rng = np.random.default_rng(2)
     prompts = [rng.integers(1, 64, size=7).tolist() for _ in range(2)]
-    eng = _probe_engine(kv_dtype="fp8")
+    eng = probe_engine(kv_dtype="fp8")
     assert eng.kv_cache.dtype == jnp.float8_e4m3fn
     assert all(a.dtype == jnp.float8_e4m3fn
                for layer in eng._kv for a in layer[:2])
@@ -185,11 +176,11 @@ def test_kv_dtype_unset_is_todays_engine():
     step-function statics path, same tokens."""
     rng = np.random.default_rng(4)
     prompts = [rng.integers(1, 64, size=9).tolist() for _ in range(2)]
-    eng = _probe_engine()
+    eng = probe_engine()
     assert eng._kv_dtype is None
     assert all(len(layer) == 2 for layer in eng._kv)    # K and V, no scales
     out = eng.generate(prompts, max_new_tokens=6)
-    out2 = _probe_engine().generate(prompts, max_new_tokens=6)
+    out2 = probe_engine().generate(prompts, max_new_tokens=6)
     assert out == out2
 
 
@@ -198,10 +189,7 @@ def test_quantized_kv_composes_with_weight_quant():
     together — the two quantization planes are independent."""
     rng = np.random.default_rng(5)
     prompts = [rng.integers(1, 64, size=8).tolist()]
-    eng, _ = serve_bench._tiny_engine(kv_dtype="int8", num_blocks=96,
-                                      probe=True)
-    # weight-quant rides quantization_mode; rebuild with both set
-    model, params, _ = serve_bench.probe_model()
+    model, params = probe_model()
     both = InferenceEngineV2(
         model, params=params,
         config=dict(dtype="float32", kv_cache_dtype="int8",

@@ -17,12 +17,12 @@ End-to-end on the virtual 8-device CPU mesh (~1 min):
    must track the flat baseline to the same 1e-2 final-loss tolerance
    (tools/comm_smoke machinery — zero loss-parity regression).
 
-Run:  python tools/autotune_smoke.py [--trials N] [--priors PRIORS.json]
+Run:  python tools/autotune_smoke.py [--trials N]
 Exit: 0 on PASS, 1 on any deviation.
 
 ``tests/unit/autotuning/test_autotune_smoke.py`` drives
-:func:`run_autotune_smoke` in-process (bench-gate convention: loaded via
-importlib, no subprocess).
+:func:`run_autotune_smoke` in-process (loaded via importlib, no
+subprocess).
 """
 
 import json
@@ -33,7 +33,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOLERANCE = 1e-2
 
 
-def _smoke_autotuning_config(trials, results_dir, priors_file=""):
+def _smoke_autotuning_config(trials, results_dir):
     """Budgeted search knobs for the gate: tiny probe surface, one ZeRO
     stage, sub-KiB overlap bucket bound (the tiny model must form >1
     bucket for the overlap candidates to mean anything), tie_rtol 0 so
@@ -57,13 +57,12 @@ def _smoke_autotuning_config(trials, results_dir, priors_file=""):
         "hierarchical_candidates": [True],
         "tie_rtol": 0.0,
         "results_dir": results_dir,
-        "priors_file": priors_file,
         "start_profile_step": 2,
         "end_profile_step": 6,
     }
 
 
-def run_autotune_smoke(trials=8, results_dir=None, priors_file=""):
+def run_autotune_smoke(trials=8, results_dir=None):
     """Run the gate in-process; returns a dict with the measurements and a
     ``pass`` verdict — the CLI and the unit test both key off it."""
     from deepspeed_tpu.autotuning.autotuner import (
@@ -80,8 +79,7 @@ def run_autotune_smoke(trials=8, results_dir=None, priors_file=""):
         "train_micro_batch_size_per_gpu": 4,
         "optimizer": {"type": "sgd", "params": {"lr": 0.1}},
         "zero_optimization": {"stage": 2},
-        "autotuning": _smoke_autotuning_config(trials, results_dir,
-                                               priors_file),
+        "autotuning": _smoke_autotuning_config(trials, results_dir),
     }
     tuner = Autotuner(model, base, model_parameters=params,
                       batch_fn=batch_fn)
@@ -149,13 +147,10 @@ def main(argv=None):
     sys.path.insert(0, REPO)
     argv = list(sys.argv[1:] if argv is None else argv)
     trials = 8
-    priors = ""
     if "--trials" in argv:
         trials = int(argv[argv.index("--trials") + 1])
-    if "--priors" in argv:
-        priors = argv[argv.index("--priors") + 1]
 
-    r = run_autotune_smoke(trials=trials, priors_file=priors)
+    r = run_autotune_smoke(trials=trials)
     print(f"topology: {r['topology']}")
     print(f"wire ladders: {r['wire_ladders']}")
     if r["best_step_ms"] is None or r["default_step_ms"] is None:

@@ -19,7 +19,7 @@ Run:  python tools/comm_smoke.py
 Exit: 0 on PASS, 1 on any deviation.
 
 ``tests/unit/comm/test_comm_smoke.py`` drives :func:`run_smoke` in-process
-(bench-gate convention: loaded via importlib, no subprocess).
+(loaded via importlib, no subprocess).
 """
 
 import os

@@ -28,7 +28,7 @@ Run:  python tools/moe_smoke.py
 Exit: 0 on PASS, 1 on any deviation.
 
 ``tests/unit/moe/test_moe_smoke.py`` drives the ``run_*`` functions
-in-process (bench-gate convention: importlib, no subprocess).
+in-process (loaded via importlib, no subprocess).
 """
 
 import os

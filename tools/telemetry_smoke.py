@@ -21,7 +21,7 @@ Run:  JAX_PLATFORMS=cpu python tools/telemetry_smoke.py
 Exit: 0 on PASS, 1 on any deviation.
 
 ``tests/unit/telemetry/test_telemetry_smoke.py`` drives :func:`run_smoke`
-in-process (bench-gate convention: loaded via importlib, no subprocess).
+in-process (loaded via importlib, no subprocess).
 """
 
 import json

@@ -38,7 +38,6 @@ def load_steps(path):
     if os.path.isdir(path):
         path = os.path.join(path, "steps.jsonl")
     if not os.path.exists(path):
-        # e.g. a ds_bench --trace dir: collectives only, no train steps
         print(f"# no step record stream at {path}", file=sys.stderr)
         return []
     steps, bad = [], 0
@@ -351,71 +350,6 @@ def render_report(steps, summary, last=None, print_fn=print):
                 line += (f"{um:>11.3f}" if um is not None else f"{'-':>11}")
                 line += (f"{ux:>10.3f}" if ux is not None else f"{'-':>10}")
             print_fn(line)
-    moe_sweep = summary.get("moe_sweep") or []
-    if moe_sweep:
-        print_fn("")
-        print_fn("== moe dispatch sweep (E × capacity_factor × wire) ==")
-        print_fn(f"{'experts':>8}{'cf':>6}{'wire':>8}{'drop_frac':>11}"
-                 f"{'imbalance':>11}{'wire_bytes':>12}{'latency_us':>12}")
-        for c in moe_sweep:
-            print_fn(f"{c.get('experts', 0):>8}"
-                     f"{c.get('capacity_factor', 0):>6g}"
-                     f"{c.get('wire_dtype', '-'):>8}"
-                     f"{c.get('drop_fraction', 0.0):>11.3f}"
-                     f"{c.get('load_imbalance', 0.0):>11.2f}"
-                     f"{c.get('wire_bytes', 0):>12}"
-                     f"{c.get('latency_us', 0.0):>12.1f}")
-        # best = the wire with the best PER-CELL speedup over its own
-        # (E, cf) gspmd baseline — raw cross-cell latency would let the
-        # smallest-payload cell decide (same rule as fold_sweeps)
-        baselines = {(c.get("experts"), c.get("capacity_factor")):
-                     c.get("latency_us")
-                     for c in moe_sweep if c.get("wire_dtype") == "gspmd"}
-        best, best_speedup = None, 1.0
-        for c in moe_sweep:
-            if c.get("wire_dtype") in ("gspmd", None):
-                continue
-            base = baselines.get((c.get("experts"),
-                                  c.get("capacity_factor")))
-            lat = c.get("latency_us")
-            if base and lat and base / lat > best_speedup:
-                best, best_speedup = c, base / lat
-        if best is not None:
-            print_fn(f"best manual dispatch: wire={best.get('wire_dtype')} "
-                     f"E={best.get('experts')} "
-                     f"cf={best.get('capacity_factor') or 0:g} "
-                     f"({best_speedup:.2f}x vs gspmd)")
-    sweep = summary.get("overlap_sweep") or []
-    # one table per sweep direction; rows predating the gather direction
-    # have no "direction" field and count as reduce
-    reduce_rows = [c for c in sweep
-                   if (c.get("direction") or "reduce") == "reduce"]
-    gather_rows = [c for c in sweep if c.get("direction") == "gather"]
-    for title, rows_d, suggest in (
-            ("overlap sweep (bucketed grad-reduce candidates)",
-             reduce_rows, "best candidate"),
-            ("gather-prefetch sweep (forward param-gather candidates)",
-             gather_rows, "best prefetch candidate")):
-        if not rows_d:
-            continue
-        print_fn("")
-        print_fn(f"== {title} ==")
-        print_fn(f"{'bucket_mb':>10}{'wire':>8}{'buckets':>9}"
-                 f"{'step_ms':>10}{'comm_ms':>10}{'hidden_ms':>11}"
-                 f"{'exposed_frac':>14}{'overlap_eff':>13}")
-        for c in rows_d:
-            print_fn(f"{c.get('bucket_mb', 0):>10g}"
-                     f"{c.get('wire_dtype', '-'):>8}"
-                     f"{c.get('buckets', 0):>9}"
-                     f"{c.get('step_ms', 0.0):>10.2f}"
-                     f"{c.get('comm_ms', 0.0):>10.2f}"
-                     f"{c.get('hidden_ms', 0.0):>11.2f}"
-                     f"{c.get('exposed_comm_frac', 0.0):>14.3f}"
-                     f"{c.get('overlap_efficiency', 0.0):>13.3f}")
-        best = max(rows_d, key=lambda c: c.get("overlap_efficiency", 0.0))
-        print_fn(f"{suggest}: bucket_mb={best.get('bucket_mb')} "
-                 f"wire={best.get('wire_dtype')} "
-                 f"overlap_efficiency={best.get('overlap_efficiency', 0):.3f}")
     programs = summary.get("compiled_programs") or []
     if programs:
         print_fn("")
@@ -458,29 +392,10 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     steps = load_steps(args.path)
-    summary = summarize(steps)
-    comm_path = (os.path.join(args.path, "comm_summary.json")
-                 if os.path.isdir(args.path) else
-                 os.path.join(os.path.dirname(args.path),
-                              "comm_summary.json"))
-    archived = {}
-    if os.path.exists(comm_path):
-        with open(comm_path) as f:
-            archived = json.load(f)
-    if archived.get("overlap"):
-        # ds_bench overlap sweep: per-bucket-size overlap-efficiency rows
-        # (the autotuner's bucket-size feed)
-        summary["overlap_sweep"] = archived["overlap"]
-    if archived.get("moe"):
-        # ds_bench --moe sweep: expert-dispatch candidates
-        summary["moe_sweep"] = archived["moe"]
     if not steps:
-        # steps-less trace (ds_bench --trace): report from the archived
-        # comm attribution alone instead of bailing
-        if not archived:
-            print("no step records found", file=sys.stderr)
-            return 1
-        summary["comm_ops"] = archived.get("ops", {})
+        print("no step records found", file=sys.stderr)
+        return 1
+    summary = summarize(steps)
 
     trace_path = (os.path.join(args.path, "trace.json")
                   if os.path.isdir(args.path) else
