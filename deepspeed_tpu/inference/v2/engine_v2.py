@@ -250,8 +250,10 @@ class InferenceEngineV2:
                 "a cache with recurrent state rows: kv_cache_dtype and "
                 "tensor parallelism are not implemented for it (a state row "
                 "has no head axis to shard, and no 8-bit form)")
+        # and so is the COUNT of the cache's entries (a layer with two
+        # attentions states two)
         self.kv_cache = BlockedKVCache(
-            cfg.num_hidden_layers, num_blocks, block_size,
+            BlockedKVCache.entries_of(cfg), num_blocks, block_size,
             cfg.num_key_value_heads, getattr(cfg, "head_dim", 0),
             dtype=jnp.dtype(config.dtype), kv_dtype=self._kv_dtype,
             window_size=cfg.window_size if eva else 0,
@@ -281,10 +283,10 @@ class InferenceEngineV2:
             f"InferenceEngineV2: budget={self._budget} blocks={num_blocks}"
             f"×{block_size} max_seqs={self.state_manager.max_seqs} "
             f"cache={token_bytes} B/token over "
-            f"{n_pages} layers of pages"
+            f"{n_pages} entries of pages"
             + (f" (latent rows of {latent})" if latent else "")
             + (f" + {seq_bytes} B/sequence over {len(self._kv) - n_pages} "
-               "layers of recurrent state" if seq_bytes else ""))
+               "entries of recurrent state" if seq_bytes else ""))
 
     # ------------------------------------------------------------- put/query
     def put(self, batch_uids, batch_tokens, do_schedule=False):
@@ -499,8 +501,10 @@ class InferenceEngineV2:
         layers' calls, and ``grid_pages_window`` / ``grid_pages_full`` are
         the loads of its window layers' and of its full layers' calls.
         For a latent cache also ``latent_keys``, the (live row, key) pairs
-        the rows attend, summed over the layers, and how many live rows took
-        the absorbed form and how many the expanded one."""
+        the rows attend, summed over the cache's ENTRIES (every attention's
+        call: two a layer where the model states two), and how many live
+        rows took the absorbed form and how many the expanded one (rows of
+        ONE call, as ``grid_pages``: every call reads alike)."""
         windows = getattr(self.model_config, "layer_windows", None)
         if windows is None:
             counts = self._kind_page_counts(pos, slots, int(getattr(

@@ -27,6 +27,13 @@ head, in one buffer a layer ``[num_blocks, block_size, latent_row]``: no K
 buffer and no V buffer, no head axis.  Allocator, tables, ``blocks_for`` and
 the claims do not know the difference.
 
+HOW MANY entries the cache holds is the model's statement too
+(``kv_cache_entries``; a model that does not say has one a layer): a layer
+with two attentions keeps two, each its own buffer, addressed by the same
+block table (a block is a run of tokens in EVERY entry, so the allocator, the
+tables and the claims count blocks as before and a token's bytes are the sum
+over the entries, ``bytes_by_kind``).  ``num_layers`` below is that count.
+
 Entries of a SECOND KIND (``recurrent``: a model with state-space layers states
 ``recurrent_state``, ``models/jamba.py``): a ``"state"`` layer keeps no pages
 but ``(conv_state [K - 1, slots, C], ssm_state [slots, S, C])``, a row a
@@ -157,6 +164,14 @@ class BlockedKVCache:
     whole tiles (docs/kernels.md); ``num_kv_heads``/``head_dim`` are not
     read."""
 
+    @staticmethod
+    def entries_of(model_config):
+        """How many entries a model's cache holds (``num_layers`` below):
+        what it states as ``kv_cache_entries``, one a layer where it does not
+        say."""
+        return int(getattr(model_config, "kv_cache_entries", 0)
+                   or model_config.num_hidden_layers)
+
     def __init__(self, num_layers, num_blocks, block_size, num_kv_heads,
                  head_dim, dtype=jnp.bfloat16, kv_dtype=None, window_size=0,
                  chunk_size=0, latent_dim=0, recurrent=None, max_seqs=0):
@@ -241,8 +256,9 @@ class BlockedKVCache:
 
     @property
     def page_layers(self):
-        """How many layers keep pages (every one, but in a cache with
-        recurrent state): what a per-layer page count is multiplied by."""
+        """How many entries keep pages (every one, but in a cache with
+        recurrent state; two a layer where a layer has two attentions): what
+        one call's page count is multiplied by."""
         return self.kinds.count("pages")
 
     def bytes_by_kind(self):

@@ -254,9 +254,9 @@ def _ragged_attention_block(lp_attn, h, kv_layer, blk, off, block_tables,
     return o, kv_layer
 
 
-def _swiglu(x, h2, mlp, dtype):
-    """``x + SwiGLU(h2)``."""
-    with jax.named_scope(_names.SCOPE_MLP):
+def _swiglu(x, h2, mlp, dtype, scope=_names.SCOPE_MLP):
+    """``x + SwiGLU(h2)``, under ``scope``."""
+    with jax.named_scope(scope):
         gate = h2 @ mlp["gate_proj"]["kernel"].astype(dtype)
         up = h2 @ mlp["up_proj"]["kernel"].astype(dtype)
         return x + (jax.nn.silu(gate) * up) @ mlp["down_proj"][
@@ -722,20 +722,23 @@ def _latent_attention(q, pages, block_tables, seq_slots, positions,
 
 @jax.named_scope(_names.SCOPE_ATTENTION)
 def _mla_block(attn, h, kv_layer, blk, off, block_tables, seq_slots,
-               positions, *, cfg, block_size, use_kernel):
-    """Multi-head latent attention of one layer over the ragged buffer, in
-    the ABSORBED form for every row (``models/pangu_ultra_moe.py`` has the
+               positions, *, cfg, block_size, use_kernel, q_scale=1.0,
+               kv_scale=1.0):
+    """Multi-head latent attention of one cache entry over the ragged buffer,
+    in the ABSORBED form for every row (``models/pangu_ultra_moe.py`` has the
     equations): the latent row ``(c ; k_r)`` of each token goes into the
-    layer's one cache buffer, every head's query is taken into the latent
+    entry's one cache buffer, every head's query is taken into the latent
     space (``q_n W_uk^T``), attends the rows themselves, and the latent
     output comes back through ``W_uv``.  No per-head key or value is made,
-    in the cache or out of it.  Returns (attn_out [T, D], new kv_layer)."""
+    in the cache or out of it.  ``q_scale`` / ``kv_scale``: ``mla_down``'s.
+    Returns (attn_out [T, D], new kv_layer)."""
     from ...models.pangu_ultra_moe import mla_down
     dtype = jnp.dtype(cfg.dtype)
     rank = cfg.kv_lora_rank
     pages, = kv_layer
     with jax.named_scope(_names.SCOPE_MLA_DOWN):
-        q_n, q_r, latent = mla_down(h, attn, positions, cfg)
+        q_n, q_r, latent = mla_down(h, attn, positions, cfg, q_scale,
+                                    kv_scale)
     spare = pages.shape[-1] - latent.shape[-1]
     with jax.named_scope(_names.SCOPE_KV_CACHE):
         pages = pages.at[blk, off].set(
@@ -820,6 +823,77 @@ def pangu_ultra_moe_ragged_step(params, kv_data, token_ids, positions,
     counts = jnp.stack(counts)                        # [routed layers, held]
     return logits, tuple(kv_data), jnp.stack(
         [jnp.sum(counts), jnp.sum(counts > 0)])
+
+@_ragged_program("longcat_flash", step_counts=(
+    _names.COUNT_EXPERT_COPIES, _names.COUNT_EXPERT_ACTIVE,
+    _names.COUNT_ZERO_EXPERT_COPIES))
+def longcat_flash_ragged_step(params, kv_data, token_ids, positions,
+                              seq_slots, block_tables, last_token_idx, *,
+                              cfg, block_size, use_kernel=True,
+                              kv_dtype=None):
+    """One ragged engine iteration for LongCat-Flash
+    (``models/longcat_flash.py`` has the layer's equations): TWO latent
+    attentions a layer, each with its own weights and its own cache entry
+    (``kv_data[2 l]`` and ``kv_data[2 l + 1]``, ``_mla_block`` with the
+    model's two scale factors), a dense SwiGLU after each, and the expert
+    branch on a SHORTCUT: it reads the first attention's normed output and is
+    added after the second feed-forward, so nothing between the two waits for
+    it.  The branch is the held experts' part of the routed sum
+    (``moe/held_experts.py``, the router's width counting the identity
+    experts) plus the identity experts' weighted copy of its input.
+
+    Returns ``(logits, new kv_data, counts)``; ``counts`` as
+    ``cohere2_moe_ragged_step``'s and, third, the (live row, layer, chosen
+    identity expert) triples."""
+    if kv_dtype is not None:
+        raise NotImplementedError("kv_cache_dtype with a latent cache")
+    from ...models.longcat_flash import mla_weights, moe_branch
+    from ...ops._use_kernels import use_pallas_kernels
+
+    dtype = jnp.dtype(cfg.dtype)
+    eps = cfg.rms_norm_eps
+    live = seq_slots != 0
+    gmm_kernel = use_kernel and use_pallas_kernels()
+
+    with jax.named_scope(_names.SCOPE_EMBED):
+        x = params["embed_tokens"]["embedding"][token_ids].astype(dtype)
+    blk = block_tables[seq_slots, positions // block_size]
+    off = positions % block_size
+
+    kv_data = list(kv_data)
+    counts, zero_copies = [], []
+    for l in range(cfg.num_layers):
+        lp = params[f"layers_{l}"]
+        norm = lambda y, name: _rmsnorm(y, lp[name]["weight"], eps)
+        shortcut = None
+        for i in (0, 1):
+            entry = 2 * l + i
+            attn_out, kv_data[entry] = _mla_block(
+                mla_weights(lp[f"self_attn_{i}"], cfg),
+                norm(x, f"input_layernorm_{i}"), kv_data[entry], blk, off,
+                block_tables, seq_slots, positions, cfg=cfg,
+                block_size=block_size, use_kernel=use_kernel,
+                q_scale=cfg.q_scale, kv_scale=cfg.kv_scale)
+            x = x + attn_out
+            h = norm(x, f"post_attention_layernorm_{i}")
+            if i == 0:
+                with jax.named_scope(_names.SCOPE_MLP):
+                    shortcut, landed, zero = moe_branch(
+                        h, lp["moe"], cfg, live=live, kernel=gmm_kernel)
+                counts.append(landed)
+                zero_copies.append(zero)
+            x = _swiglu(x, h, lp[f"mlp_{i}"], dtype, _names.SCOPE_DENSE_FFN)
+        x = x + shortcut
+
+    with jax.named_scope(_names.SCOPE_LM_HEAD):
+        xl = _rmsnorm(x[last_token_idx], params["norm"]["weight"], eps)
+        logits = jnp.einsum("td,dv->tv", xl,
+                            params["lm_head"]["kernel"].astype(dtype),
+                            preferred_element_type=jnp.float32)
+    counts = jnp.stack(counts)                        # [layers, held]
+    return logits, tuple(kv_data), jnp.stack(
+        [jnp.sum(counts), jnp.sum(counts > 0), sum(zero_copies)])
+
 
 # ---------------------------------------------------------------- Jamba
 def _run_plan(seq_slots, positions, n_slots):
@@ -1059,6 +1133,7 @@ RAGGED_FORWARDS = {"LlamaModel": llama_ragged_step,
                    "EvaByteModel": evabyte_ragged_step,
                    "Cohere2MoeModel": cohere2_moe_ragged_step,
                    "PanguUltraMoeModel": pangu_ultra_moe_ragged_step,
+                   "LongcatFlashModel": longcat_flash_ragged_step,
                    "JambaModel": jamba_ragged_step}
 
 

@@ -138,12 +138,13 @@ def pangu_ultra_moe_tiny(**overrides):
         **overrides})
 
 
-def rms_norm(x, weight, eps):
-    """RMSNorm, float32 inside, back in ``x``'s type."""
+def rms_norm(x, weight, eps, scale=1.0):
+    """RMSNorm, float32 inside, back in ``x``'s type; ``scale``: a factor on
+    what comes out, applied before the rounding."""
     x32 = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-    return (x32 * jax.lax.rsqrt(var + eps)
-            * weight.astype(jnp.float32)).astype(x.dtype)
+    y = x32 * jax.lax.rsqrt(var + eps) * weight.astype(jnp.float32)
+    return (y if scale == 1.0 else y * scale).astype(x.dtype)
 
 
 def rope_half(x, positions, theta):
@@ -161,21 +162,52 @@ def rope_half(x, positions, theta):
                            axis=-1).astype(x.dtype)
 
 
-def mla_down(h, attn, positions, cfg):
+def mla_down(h, attn, positions, cfg, q_scale=1.0, kv_scale=1.0):
     """The low-rank half of MLA for rows ``h [..., T, D]`` at ``positions``:
     ``(q_n [..., T, H, dn], q_r [..., T, H, dr], latent [..., T, r + dr])``
-    with both norms applied, ``q_r`` and the latent row's ``k_r`` turned."""
+    with both norms applied, ``q_r`` and the latent row's ``k_r`` turned.
+    ``q_scale``: a factor on every head's query, both parts (it is linear in
+    ``c_q``, and is applied there, inside the norm's float32);
+    ``kv_scale``: one on the normed ``c`` (``k_r`` is not scaled)."""
     dtype, eps = h.dtype, cfg.rms_norm_eps
     r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
     c_q = rms_norm(h @ attn["q_a_proj"]["kernel"].astype(dtype),
-                   attn["q_a_layernorm"]["weight"], eps)
+                   attn["q_a_layernorm"]["weight"], eps, q_scale)
     q = jnp.einsum("...tq,qhe->...the", c_q,
                    attn["q_b_proj"]["kernel"].astype(dtype))
     ckv = h @ attn["kv_a_proj"]["kernel"].astype(dtype)
-    c = rms_norm(ckv[..., :r], attn["kv_a_layernorm"]["weight"], eps)
+    c = rms_norm(ckv[..., :r], attn["kv_a_layernorm"]["weight"], eps,
+                 kv_scale)
     k_r = rope_half(ckv[..., r:], positions, cfg.rope_theta)
     q_r = rope_half(q[..., dn:], positions, cfg.rope_theta)
     return q[..., :dn], q_r, jnp.concatenate([c, k_r], axis=-1)
+
+
+def mla_expanded(h, attn, cfg, q_scale=1.0, kv_scale=1.0):
+    """MLA of ``h [B, S, D]`` in the EXPANDED form over the leaves ``attn``:
+    per-head keys and values made from the latent rows, one causal softmax a
+    head in float32, the output through ``o_proj``.  ``q_scale`` /
+    ``kv_scale``: :func:`mla_down`'s."""
+    dtype = h.dtype
+    B, S, _ = h.shape
+    r = cfg.kv_lora_rank
+    pos = jnp.arange(S)
+    q_n, q_r, latent = mla_down(h, attn, pos[None], cfg, q_scale, kv_scale)
+    c, k_r = latent[..., :r], latent[..., r:]
+    k_n = jnp.einsum("btc,chn->bthn", c,
+                     attn["k_b_proj"]["kernel"].astype(dtype))
+    v = jnp.einsum("btc,chv->bthv", c,
+                   attn["v_b_proj"]["kernel"].astype(dtype))
+    f32 = lambda x: x.astype(jnp.float32)
+    scores = (jnp.einsum("bshn,bthn->bhst", f32(q_n), f32(k_n))
+              + jnp.einsum("bshr,btr->bhst", f32(q_r), f32(k_r))) \
+        * cfg.softmax_scale
+    mask = pos[:, None] >= pos[None, :]
+    probs = jax.nn.softmax(
+        jnp.where(mask, scores, jnp.finfo(jnp.float32).min), axis=-1)
+    out = jnp.einsum("bhst,bthv->bshv", probs, f32(v))
+    return out.reshape(B, S, -1).astype(dtype) \
+        @ attn["o_proj"]["kernel"].astype(dtype)
 
 
 def swiglu(h, gate, up, down):
@@ -236,8 +268,7 @@ class PanguAttention(nn.Module):
     @nn.compact
     def __call__(self, h):
         cfg = self.config
-        dtype = jnp.dtype(cfg.dtype)
-        B, S, D = h.shape
+        D = h.shape[-1]
         H, r = cfg.num_attention_heads, cfg.kv_lora_rank
         dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                       cfg.v_head_dim)
@@ -249,23 +280,7 @@ class PanguAttention(nn.Module):
                      ("v_b_proj", (r, H, dv)), ("o_proj", (H * dv, D))),
             weights=(("q_a_layernorm", (cfg.q_lora_rank, )),
                      ("kv_a_layernorm", (r, ))))
-        pos = jnp.arange(S)
-        q_n, q_r, latent = mla_down(h, attn, pos[None], cfg)
-        c, k_r = latent[..., :r], latent[..., r:]
-        k_n = jnp.einsum("btc,chn->bthn", c,
-                         attn["k_b_proj"]["kernel"].astype(dtype))
-        v = jnp.einsum("btc,chv->bthv", c,
-                       attn["v_b_proj"]["kernel"].astype(dtype))
-        f32 = lambda x: x.astype(jnp.float32)
-        scores = (jnp.einsum("bshn,bthn->bhst", f32(q_n), f32(k_n))
-                  + jnp.einsum("bshr,btr->bhst", f32(q_r), f32(k_r))) \
-            * cfg.softmax_scale
-        mask = pos[:, None] >= pos[None, :]
-        probs = jax.nn.softmax(
-            jnp.where(mask, scores, jnp.finfo(jnp.float32).min), axis=-1)
-        out = jnp.einsum("bhst,bthv->bshv", probs, f32(v))
-        return out.reshape(B, S, H * dv).astype(dtype) \
-            @ attn["o_proj"]["kernel"].astype(dtype)
+        return mla_expanded(h, attn, cfg)
 
 
 class PanguMLP(nn.Module):

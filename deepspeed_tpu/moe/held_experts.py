@@ -10,7 +10,8 @@ the routing.
 * :func:`route` — router logits ``[T, E]`` -> each token's ``k`` experts and
   weights: softmax over all ``E`` then top-k (Mixtral), or top-k of the
   logits with sigmoid scores (``expert_selection_fn: sigmoid``), either
-  normalised over the ``k`` chosen or not.
+  normalised over the ``k`` chosen or not; with a ``bias`` the choice is by
+  ``score + bias`` and the weights are the scores.
 * :func:`held_experts_apply` — the (token, expert) copies whose expert lies
   in ``first_expert .. first_expert + H - 1`` (the stacks' own length) and
   whose row is live are gathered sorted by expert, taken through the grouped
@@ -39,21 +40,29 @@ import jax.numpy as jnp
 _ONE_TIER_ROWS = 1024
 
 
-def route(router_logits, k, score="softmax", norm_topk=True, scale=1.0):
+def route(router_logits, k, score="softmax", norm_topk=True, scale=1.0,
+          bias=None):
     """``(experts [T, k] int32, weights [T, k] float32)`` of router logits
     ``[T, E]``.  ``score``: ``"softmax"`` (over all ``E``, then the ``k``
     largest) or ``"sigmoid"`` (the ``k`` largest logits, each through the
     sigmoid, which is monotone); ``norm_topk``: weights divided by their sum
     over the ``k``; ``scale``: a factor on the weights as they come out
-    (``routed_scaling_factor``)."""
+    (``routed_scaling_factor``); ``bias [E]`` (``e_score_correction_bias``):
+    the ``k`` are chosen by ``score + bias`` and weighed by the score
+    WITHOUT it."""
+    if score not in ("softmax", "sigmoid"):
+        raise ValueError(f"router score {score!r}")
     logits = router_logits.astype(jnp.float32)
-    if score == "softmax":
+    if bias is not None:
+        scores = jax.nn.softmax(logits, axis=-1) if score == "softmax" \
+            else jax.nn.sigmoid(logits)
+        _, topi = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+        topw = jnp.take_along_axis(scores, topi, axis=-1)
+    elif score == "softmax":
         topw, topi = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
-    elif score == "sigmoid":
+    else:
         top, topi = jax.lax.top_k(logits, k)
         topw = jax.nn.sigmoid(top)
-    else:
-        raise ValueError(f"router score {score!r}")
     if norm_topk:
         topw = topw / jnp.sum(topw, axis=-1, keepdims=True)
     if scale != 1.0:

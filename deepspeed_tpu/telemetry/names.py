@@ -110,6 +110,10 @@ COUNT_EXPERT_COPIES = "expert_copies"         # (row, expert) pairs on a held
 #                                               expert, summed over layers
 COUNT_EXPERT_ACTIVE = "expert_active"         # held experts with a copy,
 #                                               summed over layers
+#: a router some of whose experts have NO WEIGHTS (identity experts: the
+#: weighted copy of the layer's input, computed where the token lives): the
+#: (live row, routed layer, chosen id past the real experts) triples
+COUNT_ZERO_EXPERT_COPIES = "zero_expert_copies"
 #: TRAINING counts the same two on the device inside the micro-step, and the
 #: fullest held expert's copies, summed over layers (what the grouped products
 #: of a layer wait on).  They leave the program beside the loss and are the
@@ -157,6 +161,12 @@ SCOPE_MOE_EXPERTS = "ds.moe_experts"      # inside ds.mlp: gather, grouped
 #                                           matmuls and weighted scatter-add
 #                                           of the held experts
 SCOPE_MOE_SHARED = "ds.moe_shared"        # inside ds.mlp: the shared experts
+SCOPE_MOE_ZERO = "ds.moe_zero"            # inside ds.mlp: the identity
+#                                           experts' weighted copy of the
+#                                           branch's input
+SCOPE_DENSE_FFN = "ds.dense_ffn"          # serving: the dense feed-forwards
+#                                           of a layer whose expert branch
+#                                           (ds.mlp) runs BESIDE them
 SCOPE_NORM = "ds.norm"                    # serving: rms / layer norms
 SCOPE_KV_CACHE = "ds.kv_cache"            # serving, inside ds.attn: everything a
 #                                           step spends to put its K/V into the

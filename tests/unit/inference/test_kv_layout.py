@@ -22,7 +22,8 @@ from deepspeed_tpu.inference.v2 import InferenceEngineV2
 from deepspeed_tpu.inference.v2 import ragged_forward as rf
 from deepspeed_tpu.inference.v2.ragged import BlockedKVCache
 from deepspeed_tpu.models import (cohere2_moe, evabyte, falcon, jamba, llama,
-                                  mixtral, opt, pangu_ultra_moe, phi)
+                                  longcat_flash, mixtral, opt,
+                                  pangu_ultra_moe, phi)
 
 _spec = importlib.util.spec_from_file_location(
     "serve_hlo_check", os.path.join(
@@ -50,10 +51,12 @@ ZOO = {
     "PanguUltraMoeModel": (pangu_ultra_moe.PanguUltraMoeModel,
                            pangu_ultra_moe.pangu_ultra_moe_tiny),
     "JambaModel": (jamba.JambaModel, jamba.jamba_tiny),
+    "LongcatFlashModel": (longcat_flash.LongcatFlashModel,
+                          longcat_flash.longcat_flash_tiny),
 }
 #: the models whose cache is ONE latent buffer a layer: it never was a K and
 #: a V in one stacked array, so (c) has nothing to rebuild for them
-LATENT = ("PanguUltraMoeModel", )
+LATENT = ("PanguUltraMoeModel", "LongcatFlashModel")
 #: the models whose cache holds entries of two kinds (pages, and state rows
 #: a sequence slot): no stacked array ever held them either
 RECURRENT = ("JambaModel", )
@@ -82,7 +85,8 @@ def _programs(name, num_blocks=2048):
     eva = name == "EvaByteModel"
     bs, rows, seqs, maxb = 8, 8, 4, 6 if eva else 2
     cache = BlockedKVCache(
-        cfg.num_hidden_layers, num_blocks, bs, cfg.num_key_value_heads,
+        BlockedKVCache.entries_of(cfg), num_blocks, bs,
+        cfg.num_key_value_heads,
         getattr(cfg, "head_dim", 0), dtype=jnp.float32,
         window_size=cfg.window_size if eva else 0,
         chunk_size=cfg.chunk_size if eva else 0,
@@ -103,9 +107,11 @@ def _programs(name, num_blocks=2048):
 @pytest.mark.parametrize("name,which", [
     ("LlamaModel", 0), ("EvaByteModel", 0), ("LlamaModel", 1),
     ("EvaByteModel", 1), ("PanguUltraMoeModel", 0),
-    ("PanguUltraMoeModel", 1)], ids=[
+    ("PanguUltraMoeModel", 1), ("LongcatFlashModel", 0),
+    ("LongcatFlashModel", 1)], ids=[
         "llama_step", "evabyte_step", "llama_burst", "evabyte_burst",
-        "latent_step", "latent_burst"])
+        "latent_step", "latent_burst", "two_entries_a_layer_step",
+        "two_entries_a_layer_burst"])
 def test_compiled_program_updates_the_cache_in_place(name, which):
     lowered, n_params, n_cache, page_bytes = _programs(name)
     compiled = lowered[which].compile()
@@ -237,8 +243,10 @@ def test_layout_serves_what_the_stacked_array_served(name, kv_dtype):
         return
     if name in LATENT:
         eng = _engine(name, kv_dtype)
+        cfg = eng.model_config      # one buffer an ENTRY (two a layer
+        # where the model states kv_cache_entries)
         assert [len(layer) for layer in eng._kv] == \
-            [1] * eng.model_config.num_hidden_layers
+            [1] * BlockedKVCache.entries_of(cfg)
         assert eng._kv[0][0].shape == (40, 8, 128)
         return
     if name in RECURRENT:

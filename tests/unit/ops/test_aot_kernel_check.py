@@ -67,6 +67,10 @@ def test_every_kernel_compiles_for_v5e(report):
                    "selective_scan(16 x 5120, 257 slots, a short step)",
                    "paged_latent_attention(MLA 128 x 576, the cell's step)",
                    "paged_latent_attention(MLA 128 x 576, the cell's burst)",
+                   "paged_latent_attention(MLA 64 x 576, the LongCat cell's "
+                   "step)",
+                   "paged_latent_attention(MLA 64 x 576, the LongCat cell's "
+                   "burst)",
                    "block_sparse_flash_attention"):
         assert any(kernel in ln for ln in lines), kernel
 

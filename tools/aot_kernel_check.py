@@ -338,6 +338,17 @@ def main():
             sds((T, 128, 640), bf16), sds((64, 128, 640), bf16),
             sds((65, 193), jnp.int32), sds((T, ), jnp.int32),
             sds((T, ), jnp.int32), pages=item_pages(1, 640, bf16, 128)))
+    # the same reader at LongCat-Flash's 64 heads (a tile of 16 tokens, a
+    # decode token's slab of 64 rows): a step of 2048 rows and a burst's
+    for name, T in (("the LongCat cell's step", 2048),
+                    ("the LongCat cell's burst", 33)):
+        results.append(checked(
+            f"paged_latent_attention(MLA 64 x 576, {name})",
+            lambda q, c, t, s, l: paged_latent_attention(
+                q, c, t, s, l, rank=512, scale=192 ** -0.5),
+            sds((T, 64, 640), bf16), sds((64, 128, 640), bf16),
+            sds((33, 137), jnp.int32), sds((T, ), jnp.int32),
+            sds((T, ), jnp.int32), pages=item_pages(1, 640, bf16, 128)))
 
     # the Mamba-1 recurrence at the Jamba cell's shapes: a 2048-row step over
     # 257 slots' state of 16 x 5120 (bfloat16, aliased in and out)

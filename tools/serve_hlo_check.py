@@ -190,7 +190,7 @@ def programs(config, sharding=None):
     params = jax.tree.map(lambda s: sds(s.shape, jnp.bfloat16),
                           arch.param_shapes(model))
     cache = jax.eval_shape(lambda: BlockedKVCache(
-        cfg.num_hidden_layers, int(eng["num_blocks"]), bs,
+        BlockedKVCache.entries_of(cfg), int(eng["num_blocks"]), bs,
         cfg.num_key_value_heads, getattr(cfg, "head_dim", 0),
         dtype=jnp.bfloat16, window_size=cfg.window_size if eva else 0,
         chunk_size=cfg.chunk_size if eva else 0,
