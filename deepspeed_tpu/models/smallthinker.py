@@ -25,7 +25,7 @@ chosen experts that are held, nothing stands in for the rest, and that
 partial ``y`` goes on to the next layer (``moe/held_experts.py``).
 
 **Counted on the device.**  With labels the model returns ``(loss, counts)``:
-``counts [3] int32`` in the order of :data:`DEVICE_COUNTS`, summed over the
+``counts [4] int32`` in the order of :data:`DEVICE_COUNTS`, summed over the
 layers.  The engine reads ``SmallThinkerModel.device_counts`` and books them
 on a later step's ``ds:train.micro`` span (docs/observability.md).
 """
@@ -39,7 +39,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 from jax.sharding import PartitionSpec as P
 
-from ..moe.held_experts import held_experts_apply, route
+from ..moe.held_experts import held_experts_apply, in_blocks, route
 from ..runtime.activation_checkpointing import resolve_policy
 from ..telemetry import names as _names
 from .llama import (RMSNorm, _lm_loss, _lm_loss_chunked, _rope_freqs,
@@ -48,7 +48,8 @@ from .llama import (RMSNorm, _lm_loss, _lm_loss_chunked, _rope_freqs,
 #: what the model counts on the device in a training micro-step, in the order
 #: of the vector it returns beside the loss
 DEVICE_COUNTS = (_names.COUNT_EXPERT_COPIES, _names.COUNT_EXPERT_ACTIVE,
-                 _names.COUNT_EXPERT_ROWS_MAX)
+                 _names.COUNT_EXPERT_ROWS_MAX,
+                 _names.COUNT_EXPERT_PADDED_CALLS)
 
 
 @dataclass(frozen=True)
@@ -218,7 +219,7 @@ class SmallThinkerBlock(nn.Module):
 
 class SmallThinkerModel(nn.Module):
     """Causal LM.  ``__call__(input_ids)`` -> logits; with ``labels`` ->
-    ``(loss, counts [3] int32)``, the mean next-token cross-entropy and
+    ``(loss, counts [4] int32)``, the mean next-token cross-entropy and
     :data:`DEVICE_COUNTS` summed over the layers."""
     config: SmallThinkerConfig
     #: the engine's protocol for counts made on the device in a micro-step
@@ -261,8 +262,13 @@ class SmallThinkerModel(nn.Module):
                     attention_mask, cfg.loss_chunk_vocab, hd)
             else:
                 loss = _lm_loss(head(x.astype(hd)), labels, attention_mask)
-        return loss, jnp.stack([jnp.sum(landed), jnp.sum(landed > 0),
-                                jnp.sum(jnp.max(landed, axis=1))])
+        tokens = input_ids.size
+        return loss, jnp.stack([
+            jnp.sum(landed), jnp.sum(landed > 0),
+            jnp.sum(jnp.max(landed, axis=1)),
+            jnp.sum(in_blocks(landed, tokens,
+                              cfg.moe_num_active_primary_experts,
+                              cfg.moe_num_primary_experts))])
 
 
 def tp_rules(config: SmallThinkerConfig):

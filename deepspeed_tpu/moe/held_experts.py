@@ -14,11 +14,18 @@ the routing.
   ``score + bias`` and the weights are the scores.
 * :func:`held_experts_apply` — the (token, expert) copies whose expert lies
   in ``first_expert .. first_expert + H - 1`` (the stacks' own length) and
-  whose row is live are gathered sorted by expert, taken through the grouped
-  gated feed-forward (:func:`grouped_swiglu`: ``lax.ragged_dot``, which has a
-  gradient; with ``kernel=True``, the choice of the serving step that timed
-  it, the Pallas ``ds_grouped_matmul``, which has none) and added back
-  weighted.
+  whose row is live are gathered sorted by expert, taken through the gated
+  feed-forward of their experts and added back weighted.  The forward that
+  may be differentiated (TRAINING, and the dense forward of ``models/``) lays
+  them in per-expert padded blocks ``[H, block_rows, D]`` and runs three
+  batched dense products, whose transposes are batched dense products too
+  (:func:`padded_swiglu`), and moves its rows into the blocks and back by
+  gathers alone, backward too (:func:`to_blocks`, :func:`from_blocks`: on a
+  TPU a scatter-add of these rows costs thirty gathers of them); with
+  ``kernel=True``, the choice of the serving
+  step that timed it, they stay one sorted buffer under the Pallas
+  ``ds_grouped_matmul``, which has no gradient.  ``lax.ragged_dot``
+  (:func:`grouped_swiglu`) is the worst case's form on both.
   Copies that land elsewhere, and dead rows, reach no expert and nothing
   stands in for them.  With every expert held and every row live it is
   Mixtral's layer, operation for operation.
@@ -26,10 +33,13 @@ the routing.
 **Cost follows the live copies.**  Of a step's ``T * k`` copies the share
 ``H / E`` lands here on average and all of them may.  The gathered buffer's
 length is a static shape, so there are two: ``tier_rows`` (the mean with a
-quarter of room) and ``T * k``, chosen on the device by the count of copies
-that landed (``lax.cond``): the step pays for the worst case only when it
-happens.
+quarter of room; differentiable: ``block_rows`` of them an expert) and
+``T * k``, chosen on the device by the count of copies that landed (by the
+fullest expert's count) in a ``lax.cond``: the step pays for the worst case
+only when it happens.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -84,11 +94,13 @@ def grouped_matmul(x_sorted, w, group_sizes, kernel=False):
     (docs/kernels.md: ahead of ``ragged_dot`` on a v5e in a buffer of up to
     2560 rows over 16 experts of 4096 x 4096, behind it in one of 16 384),
     and it has no gradient: a forward that may be differentiated leaves it
-    off.  Training keeps ``ragged_dot`` and its transposes: at a training
-    step's shapes (15 360 rows over 16 experts of 2560 x 768) they take half
-    the time of the kernel under a ``custom_vjp`` with a transposed grouped
-    product for the weights' gradient (``tools/moe_gmm_train_bench.py``,
-    docs/kernels.md).  A row past ``sum(group_sizes)`` is in no group and its
+    off.  Training runs neither where the routing is near even, but
+    :func:`padded_swiglu`'s batched dense products: at a training step's
+    shapes (16 experts of 2560 x 768, 12 288 copies) ``ragged_dot`` and its
+    transposes take half the time of the kernel under a ``custom_vjp``, and
+    the padded blocks less than either (``tools/moe_gmm_train_bench.py``,
+    docs/kernels.md, "Under a gradient"); ``ragged_dot`` stays the form of
+    the worst case.  A row past ``sum(group_sizes)`` is in no group and its
     result is undefined, with either path and in ``ragged_dot``'s transposes
     too (on a TPU such rows come back as what the buffer held)."""
     if not kernel:
@@ -114,6 +126,18 @@ def grouped_swiglu(x_sorted, group_sizes, w1, w2, w3, kernel=False,
     return grouped_matmul(act(gate) * up, w2, group_sizes, kernel)
 
 
+def padded_swiglu(x_blocks, w1, w2, w3, act=jax.nn.silu):
+    """:func:`grouped_swiglu` over PER-EXPERT PADDED BLOCKS: three batched
+    dense products over the expert axis, under a gradient six more of the
+    same kind.  x_blocks: [E, R, D], expert e's rows in block e and rows of
+    zeros after them (zeros in, zeros out: ``act(0) * 0 = 0``, and a row of
+    zeros gives no weight a gradient); w1/w3: [E, D, I]; w2: [E, I, D].
+    Returns [E, R, D]."""
+    gate = jnp.einsum("erd,edi->eri", x_blocks, w1)
+    up = jnp.einsum("erd,edi->eri", x_blocks, w3)
+    return jnp.einsum("eri,eid->erd", act(gate) * up, w2)
+
+
 def tier_rows(tokens, k, held, experts):
     """The length of the gathered buffer that holds a step's copies when the
     routing is near even: the mean ``tokens * k * held / experts`` and a
@@ -122,6 +146,105 @@ def tier_rows(tokens, k, held, experts):
     full = tokens * k
     rows = -(-(full * held * 5 // (experts * 4)) // 128) * 128
     return rows if full > _ONE_TIER_ROWS and rows < full else None
+
+
+def block_rows(tokens, k, held, experts):
+    """The rows of ONE held expert's padded block in the forward that may be
+    differentiated: its share of :func:`tier_rows` in whole tiles of 128
+    rows; None where there is no tier.  A step whose fullest expert holds
+    more takes the worst case's buffer."""
+    tier = tier_rows(tokens, k, held, experts)
+    return tier and -(-tier // (held * 128)) * 128
+
+
+def in_blocks(counts, tokens, k, experts):
+    """Whether :func:`held_experts_apply` without ``kernel`` runs a call in
+    padded blocks, from what the call returned: counts [..., H] the copies
+    on each held expert -> bool [...], made on the device (what a model
+    counts as ``expert_padded_calls``)."""
+    rows = block_rows(tokens, k, counts.shape[-1], experts)
+    if rows is None:
+        return jnp.zeros(counts.shape[:-1], bool)
+    return jnp.max(counts, axis=-1) <= rows
+
+
+def _tokens_sum(rows, slot, w):
+    """``out[t] = sum_i w[t, i] rows[slot[t, i]]`` in float32: ``k`` gathers
+    of ``T`` rows (``w`` is 0 for a copy with no slot)."""
+    return sum(rows[slot[:, i]].astype(jnp.float32) * w[:, i, None]
+               for i in range(slot.shape[1]))
+
+
+@jax.custom_vjp
+def to_blocks(x, moves):
+    """Tokens ``[T, D]`` -> the slots of the padded blocks ``[S, D]``: slot s
+    reads the token of its copy, a slot with none zeros.  ``moves`` =
+    ``(copy [S], valid [S], slot [T, k], held [T, k])``: a slot's copy
+    ``t * k + i`` and whether it has one; a copy's slot and whether it has
+    one.  Its transpose sums each token's slots (:func:`_tokens_sum`), where
+    JAX's own would scatter-add ``S`` rows (4.4 ms against 0.4 at the
+    training cell's shapes: docs/kernels.md)."""
+    copy, valid, slot, _ = moves
+    return jnp.where(valid[:, None], x[copy // slot.shape[1]], 0)
+
+
+def _to_blocks_bwd(moves, g):
+    _, _, slot, held = moves
+    return _tokens_sum(g, slot, held.astype(jnp.float32)).astype(g.dtype), None
+
+
+to_blocks.defvjp(lambda x, moves: (to_blocks(x, moves), moves),
+                 _to_blocks_bwd)
+
+
+@jax.custom_vjp
+def from_blocks(y, w, moves):
+    """The slots' results ``[S, D]`` -> tokens ``[T, D]`` in y's type: each
+    token's held copies weighed by ``w [T, k]`` (float32) and summed in
+    float32.  ``moves``: :func:`to_blocks`'s.  Its transposes are gathers
+    too: a slot's gradient is its token's, weighed; a weight's is its slot's
+    result times its token's gradient."""
+    _, _, slot, held = moves
+    return _tokens_sum(y, slot, jnp.where(held, w, 0)).astype(y.dtype)
+
+
+def _from_blocks_bwd(res, g):
+    y, w, moves = res
+    copy, _, slot, held = moves
+    g_slots = to_blocks(g, moves)
+    dy = g_slots * w.reshape(-1)[copy].astype(g.dtype)[:, None]
+    dw_slots = jnp.sum(y.astype(jnp.float32) * g_slots.astype(jnp.float32),
+                       axis=-1)
+    return dy, jnp.where(held, dw_slots[slot], 0).astype(w.dtype), None
+
+
+from_blocks.defvjp(lambda y, w, moves: (from_blocks(y, w, moves),
+                                        (y, w, moves)), _from_blocks_bwd)
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "act"))
+def _blocks_layer(x, topw, w1, w2, w3, here, key, order, counts, *, rows,
+                  act):
+    """:func:`held_experts_apply`'s layer over ``H`` padded blocks of ``rows``
+    slots: slot ``(e, j)`` is expert e's j-th sorted copy, a slot past its
+    copies a row of zeros.  Rows move by GATHERS alone, forward and backward
+    (:func:`to_blocks`, :func:`from_blocks`).  here [T, k]: the copies on a
+    held expert; key [T k]: their expert (H: none); order: ``argsort(key)``;
+    counts [H].  Jitted for the trace's sake: a model's layers share their
+    shapes, so all but the first reuse its jaxpr (and its derivatives)."""
+    (T, D), k, H = x.shape, topw.shape[1], w1.shape[0]
+    j = jnp.arange(rows)
+    valid = j < counts[:, None]                                 # [H, rows]
+    first = jnp.cumsum(counts) - counts
+    copy = order[jnp.where(valid, first[:, None] + j, 0)]
+    # a held copy's slot: its expert's block, and its place among the
+    # sorted copies counted from that expert's first
+    slot = key * rows + jnp.argsort(order) - first[jnp.minimum(key, H - 1)]
+    moves = (copy.reshape(-1), valid.reshape(-1),
+             jnp.where(here, slot.reshape(T, k), 0), here)
+    y = padded_swiglu(to_blocks(x, moves).reshape(H, rows, D), w1, w2, w3,
+                      act)
+    return from_blocks(y.reshape(-1, D), topw.astype(jnp.float32), moves)
 
 
 def held_experts_apply(x, topi, topw, w1, w2, w3, *, first_expert=0,
@@ -134,9 +257,12 @@ def held_experts_apply(x, topi, topw, w1, w2, w3, *, first_expert=0,
     [H, D, I], w2: [H, I, D] the experts ``first_expert .. first_expert + H -
     1``; live: [T] bool (None: every row); ``kernel``: the Pallas grouped
     matmul (:func:`grouped_matmul`) in the buffer of ``tier_rows``, or in the
-    one buffer where there is no second; the worst case's buffer behind the
-    ``lax.cond`` keeps ``ragged_dot``, which the chip's readings put ahead
-    there; ``act``: the gate's activation (:func:`grouped_swiglu`).  Returns
+    one buffer where there is no second; without it (the forward that may be
+    differentiated) the tier is ``H`` padded blocks of ``block_rows`` under
+    :func:`padded_swiglu`, taken when the fullest expert's copies fit one;
+    the worst case's buffer behind the ``lax.cond`` keeps ``ragged_dot``,
+    which the chip's readings put ahead there; ``act``: the gate's
+    activation (:func:`grouped_swiglu`).  Returns
     ``(out [T, D] in x's type, counts [H] int32)``: the weighted sum over
     each row's experts that are held, and the copies that landed on each held
     expert."""
@@ -171,10 +297,18 @@ def held_experts_apply(x, topi, topw, w1, w2, w3, *, first_expert=0,
             return jnp.zeros((T, D), y.dtype).at[token_of].add(y)
         return run
 
-    tier = tier_rows(T, k, H, experts or H)
+    experts = experts or H
+    tier = tier_rows(T, k, H, experts)
     if tier is None:
         out = part(T * k, kernel)(None)
-    else:
-        out = jax.lax.cond(landed <= tier, part(tier, kernel),
+    elif kernel:
+        out = jax.lax.cond(landed <= tier, part(tier, True),
                            part(T * k, False), None)
+    else:
+        rows = block_rows(T, k, H, experts)
+        out = jax.lax.cond(
+            in_blocks(counts, T, k, experts),
+            lambda _: _blocks_layer(x, topw, w1, w2, w3, here, key, order,
+                                    counts, rows=rows, act=act),
+            part(T * k, False), None)
     return out.astype(x.dtype), counts

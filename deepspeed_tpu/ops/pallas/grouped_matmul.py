@@ -24,11 +24,14 @@ for this kernel.  Timed against it on a v5e at a serving step's shapes
 256 x 1024 x 1024 is ahead in buffers of up to 2560 rows, and
 ``cohere2_moe_ragged_step`` asks for it there; it is behind in a buffer of
 16 384, and it has NO gradient (no ``custom_vjp``): a forward that may be
-differentiated keeps ``ragged_dot``.  One was tried for the training step
+differentiated runs neither this kernel nor, where its routing is near even,
+``ragged_dot``, but batched dense products over per-expert padded blocks
+(``held_experts.padded_swiglu``), and keeps ``ragged_dot`` for the worst
+case.  A ``custom_vjp`` was tried for the training step
 (``tools/moe_gmm_train_bench.py``: this kernel for the forward and the rows'
 gradient, a transposed grouped product for the weights'); ``ragged_dot`` with
-its transposes took half the time at that step's shapes, so the kernel stays
-forward-only.
+its transposes took half the time at that step's shapes and the padded blocks
+a fifth, so the kernel stays forward-only.
 """
 
 import functools

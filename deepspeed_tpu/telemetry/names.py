@@ -114,13 +114,17 @@ COUNT_EXPERT_ACTIVE = "expert_active"         # held experts with a copy,
 #: weighted copy of the layer's input, computed where the token lives): the
 #: (live row, routed layer, chosen id past the real experts) triples
 COUNT_ZERO_EXPERT_COPIES = "zero_expert_copies"
-#: TRAINING counts the same two on the device inside the micro-step, and the
+#: TRAINING counts the same two on the device inside the micro-step, the
 #: fullest held expert's copies, summed over layers (what the grouped products
-#: of a layer wait on).  They leave the program beside the loss and are the
+#: of a layer wait on), and the layer-calls whose copies ran in per-expert
+#: padded blocks (``moe/held_experts.in_blocks``: the fullest expert fitted
+#: one; the others took the worst case's buffer).  They leave the program
+#: beside the loss and are the
 #: stats of a LATER step's ``train.micro`` span, the first whose call finds
 #: the array ready (the loop never waits for the device), with the
 #: number of micro-steps whose counts it brings (they add)
 COUNT_EXPERT_ROWS_MAX = "expert_rows_max"
+COUNT_EXPERT_PADDED_CALLS = "expert_padded_calls"
 COUNT_MICROS_COVERED = "micro_steps_covered"
 
 #: a model with a LATENT cache (multi-head latent attention): the (live row,

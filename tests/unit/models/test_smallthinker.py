@@ -211,7 +211,7 @@ def test_device_counts_reach_a_later_micro_span_and_add_up(monkeypatch):
     model = st.SmallThinkerModel(cfg)
     assert model.device_counts == (
         names.COUNT_EXPERT_COPIES, names.COUNT_EXPERT_ACTIVE,
-        names.COUNT_EXPERT_ROWS_MAX)
+        names.COUNT_EXPERT_ROWS_MAX, names.COUNT_EXPERT_PADDED_CALLS)
     engine, _, _, _ = deepspeed_tpu.initialize(
         model=model, tp_rules=st.tp_rules(cfg), config={
             "train_micro_batch_size_per_gpu": 1,
@@ -220,9 +220,12 @@ def test_device_counts_reach_a_later_micro_span_and_add_up(monkeypatch):
             "bf16": {"enabled": True},
             "zero_optimization": {"stage": 3},
             "mesh": {"dp": jax.device_count()}})
+    # 2 x 160 copies a row: more than one tier's worth (1024), so a step
+    # whose fullest expert fits its block runs in padded blocks
     rows = jax.device_count()
-    ids = np.random.default_rng(0).integers(0, 256, (rows, 64)).astype(
+    ids = np.random.default_rng(0).integers(0, 256, (rows, 160)).astype(
         np.int32)
+    assert he.block_rows(ids.size, 2, cfg.held, 8) == 512
     engine.initialize_parameters(jax.random.PRNGKey(0), ids, ids)
     want = []
     for _ in range(4):
@@ -247,8 +250,13 @@ def test_device_counts_reach_a_later_micro_span_and_add_up(monkeypatch):
         want[3][0]
     assert engine._ready_device_counts() == {}
     # the fullest experts hold at least the layers' means, at most every copy
-    copies, _, fullest = want[0]
+    copies, _, fullest, padded = want[0]
     assert copies / cfg.held <= fullest <= copies
+    # near-even routing: every layer's copies fitted their blocks, and the
+    # spans say so of the three micro-steps they cover
+    assert padded == cfg.num_hidden_layers
+    assert sum(kw[names.COUNT_EXPERT_PADDED_CALLS] for kw in booked) == \
+        3 * cfg.num_hidden_layers
 
 
 def test_a_model_without_counts_books_none(monkeypatch):

@@ -1,20 +1,36 @@
 #!/usr/bin/env python
 """Time the grouped products of a held-expert layer under a GRADIENT, on the
 attached chip, at a training step's shapes: XLA's ``lax.ragged_dot`` and its
-transposes against the Pallas ``ds_grouped_matmul`` under a ``custom_vjp``
-(the rows' gradient = a grouped product with the transposed stack, the
-weights' gradient = a TRANSPOSED grouped product over the same row groups,
-the kernel ``ds_grouped_matmul_t`` of this file).
+transposes, the Pallas ``ds_grouped_matmul`` under a ``custom_vjp`` (the
+rows' gradient = a grouped product with the transposed stack, the weights'
+gradient = a TRANSPOSED grouped product over the same row groups, the kernel
+``ds_grouped_matmul_t`` of this file), and the batched dense products of
+``moe/held_experts.padded_swiglu`` over per-expert padded blocks of
+``--block-rows`` rows: ``padded_blocks`` is the products alone on blocks laid
+beforehand, ``padded_from_sorted`` also gathers the blocks from the sorted
+buffer and gathers the result back into it (more glue than the layer has:
+there the blocks are gathered from the tokens, as the sorted buffer is).
 
-    python tools/moe_gmm_train_bench.py [--out FILE]
+    python tools/moe_gmm_train_bench.py [--paths ragged_dot,padded] [--out FILE]
+    python tools/moe_gmm_train_bench.py --layer [--layer-file a.py,b.py]
 
 The gated feed-forward of ``E`` experts of ``D x I`` over a buffer of ``C``
-rows sorted by expert of which the first ``N`` are live, forward alone and
-forward with the gradient of every input.  One JSON line a case:
-``{"path", "rows", "live", "fwd_ms", "fwd_bwd_ms", "tflops", ...}`` (``tflops``:
-nine products of ``D x I`` a live row over the forward-and-backward time).
+rows sorted by expert of which the first ``N`` are live (``E x block-rows``
+live rows: every expert as many), forward alone and forward with the gradient
+of every input.  One JSON line a case and path: ``{"path", "rows", "live",
+"fwd_ms", "fwd_bwd_ms", "tflops", ...}`` (``tflops``: nine products of ``D x
+I`` a live row over the forward-and-backward time).  A padded path runs where
+the fullest expert fits a block and the buffer is no longer than the blocks.
 docs/kernels.md has the v5e readings and which path ``moe/held_experts.py``
 kept; the kernel below lives here because the readings left it off the path.
+
+``--layer`` times the WHOLE layer instead, ``held_experts_apply`` over
+``--tokens`` tokens of which each chooses ``--topk`` of ``--router`` experts
+(a seeded router: near even), the first ``--experts`` held: the sort, the
+copies' way into the products and back, and the products, forward and with
+the five gradients; ``--layer-file`` times other versions of
+``moe/held_experts.py`` beside the tree's in the same process (``git show
+<commit>:<path>`` into the git-ignored ``.chip_checkout/``).
 """
 
 import argparse
@@ -135,11 +151,40 @@ def gmm_with_gradient(blocks):
     return dot
 
 
+def sorted_to_blocks(a, sizes, cap):
+    """Sorted rows ``[C, D]`` -> blocks ``[E, cap, D]``: slot ``(e, j)`` is
+    group e's j-th row, zeros past its rows (``held_experts_apply``'s
+    gather)."""
+    j = jnp.arange(cap)
+    valid = j < sizes[:, None]
+    first = jnp.cumsum(sizes) - sizes
+    return jnp.where(valid[..., None],
+                     a[jnp.where(valid, first[:, None] + j, 0)], 0)
+
+
+def blocks_to_sorted(blocks, sizes, rows):
+    """Blocks ``[E, cap, D]`` -> sorted rows ``[rows, D]`` (a row in no group
+    reads some block's row: the caller's mask cuts it off)."""
+    ends = jnp.cumsum(sizes)
+    i = jnp.arange(rows)
+    g = jnp.minimum(jnp.searchsorted(ends, i, side="right"),
+                    sizes.shape[0] - 1)
+    return blocks[g, jnp.minimum(i - (ends - sizes)[g], blocks.shape[1] - 1)]
+
+
 def reglu(dot):
     def ffn(x, sizes, w1, w2, w3):
         return dot(jax.nn.relu(dot(x, w1, sizes)) * dot(x, w3, sizes), w2,
                    sizes)
     return ffn
+
+
+def write(lines, out):
+    if out:
+        os.makedirs(os.path.dirname(os.path.abspath(out)), exist_ok=True)
+        with open(out, "w") as f:
+            f.writelines(json.dumps(line) + "\n" for line in lines)
+    return 0
 
 
 def timed(fn, args, reps):
@@ -152,16 +197,73 @@ def timed(fn, args, reps):
     return (time.perf_counter() - t0) / reps, out
 
 
+def layer_lines(opts):
+    """One line a version of ``moe/held_experts.py``: the whole layer under a
+    gradient at a training step's shapes."""
+    import importlib.util
+    from deepspeed_tpu.moe import held_experts
+    versions = {"tree": held_experts}
+    for path in filter(None, opts.layer_file.split(",")):
+        spec = importlib.util.spec_from_file_location(
+            "held_experts_%d" % len(versions), path)
+        versions[path] = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(versions[path])
+    T, k, R = opts.tokens, opts.topk, opts.router
+    H, D, I = opts.experts, opts.hidden, opts.width
+    key = jax.random.PRNGKey(1)
+    draw = lambda i, *shape: jax.random.normal(
+        jax.random.fold_in(key, i), shape, jnp.float32)
+    stack = lambda i, *shape: (draw(i, *shape) / np.sqrt(shape[1])).astype(
+        jnp.bfloat16)
+    x, cot = draw(0, T, D).astype(jnp.bfloat16), draw(4, T, D)
+    topi, topw = held_experts.route(draw(5, T, R), k)
+    args = (x, topw, stack(1, H, D, I), stack(2, H, I, D), stack(3, H, D, I))
+    lines, want = [], None
+    for name, module in versions.items():
+        layer = lambda x, topw, w1, w2, w3, m=module: m.held_experts_apply(
+            x, topi, topw, w1, w2, w3, experts=R, act=jax.nn.relu)
+        fwd = jax.jit(layer)
+        both = jax.jit(jax.grad(lambda *a: jnp.sum(
+            layer(*a)[0].astype(jnp.float32) * cot), argnums=(0, 1, 2, 3, 4)))
+        s_fwd, (_, counts) = timed(fwd, args, opts.reps)
+        s_both, grads = timed(both, args, opts.reps)
+        got = [np.asarray(g.astype(jnp.float32)) for g in grads]
+        want = got if want is None else want
+        lines.append({
+            "path": "layer:" + name, "tokens": T,
+            "copies": int(jnp.sum(counts)), "fullest": int(jnp.max(counts)),
+            "fwd_ms": 1e3 * s_fwd, "fwd_bwd_ms": 1e3 * s_both,
+            "tflops": int(jnp.sum(counts)) * 18 * D * I / s_both / 1e12,
+            "finite": bool(all(np.isfinite(g).all() for g in got)),
+            "grad_max_diff_to_first": [
+                float(np.max(np.abs(a - b))) for a, b in zip(got, want)],
+            "grad_max": [float(np.max(np.abs(b))) for b in want]})
+        print(json.dumps(lines[-1]), flush=True)
+    return lines
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--experts", type=int, default=16)
     ap.add_argument("--hidden", type=int, default=2560)
     ap.add_argument("--width", type=int, default=768)
     ap.add_argument("--reps", type=int, default=10)
-    ap.add_argument("--cases", default="15360:12288,15360:15360,49152:12288")
+    ap.add_argument("--cases", default="15360:12288,16384:12288,"
+                    "16384:16384,49152:12288")
+    ap.add_argument("--block-rows", type=int, default=1024)
+    ap.add_argument("--paths", default="",
+                    help="only the paths whose name holds one of these")
+    ap.add_argument("--layer", action="store_true")
+    ap.add_argument("--layer-file", default="")
+    ap.add_argument("--tokens", type=int, default=8192)
+    ap.add_argument("--topk", type=int, default=6)
+    ap.add_argument("--router", type=int, default=64)
     ap.add_argument("--out")
     opts = ap.parse_args()
-    E, D, I = opts.experts, opts.hidden, opts.width
+    if opts.layer:
+        return write(layer_lines(opts), opts.out)
+    from deepspeed_tpu.moe.held_experts import padded_swiglu
+    E, D, I, cap = opts.experts, opts.hidden, opts.width, opts.block_rows
     key = jax.random.PRNGKey(0)
     draw = lambda i, *shape: jax.random.normal(
         jax.random.fold_in(key, i), shape, jnp.bfloat16) / np.sqrt(shape[1])
@@ -170,6 +272,10 @@ def main():
     for blocks in ((256, 256, 512), (256, 768, 512), (512, 256, 512),
                    (512, 768, 512), (512, 768, 2560)):
         paths["gmm_vjp_%dx%dx%d" % blocks] = gmm_with_gradient(blocks)
+    paths["padded_blocks"] = paths["padded_from_sorted"] = None
+    wanted = [p for p in opts.paths.split(",") if p]
+    paths = {name: dot for name, dot in paths.items()
+             if not wanted or any(p in name for p in wanted)}
     lines = []
     for case in opts.cases.split(","):
         rows, live = (int(v) for v in case.split(":"))
@@ -178,22 +284,42 @@ def main():
         cot = jax.random.normal(jax.random.fold_in(key, rows + 1), (rows, D),
                                 jnp.bfloat16)
         rng = np.random.default_rng(rows + live)
-        # near-even groups, as a router over random weights gives them
-        sizes = rng.multinomial(live, np.ones(E) / E)
+        # near-even groups, as a router over random weights gives them;
+        # blocks that are full: every group a block's rows
+        sizes = np.full(E, cap) if live == E * cap else \
+            rng.multinomial(live, np.ones(E) / E)
+        fits = sizes.max() <= cap and rows <= E * cap
         sizes = jnp.asarray(sizes, jnp.int32)
         mask = (jnp.arange(rows) < live)[:, None]
         want = None
         for name, dot in paths.items():
-            ffn = reglu(dot)
-            fwd = jax.jit(lambda x, w1, w2, w3, f=ffn: jnp.where(
-                mask, f(x, sizes, w1, w2, w3), 0))
-            loss = lambda x, w1, w2, w3, f=fwd: jnp.sum(
+            first, cot_as = x, cot
+            if dot is not None:
+                ffn = reglu(dot)
+            elif not fits:
+                continue
+            elif name == "padded_from_sorted":
+                ffn = lambda x, sizes, w1, w2, w3: blocks_to_sorted(padded_swiglu(
+                    sorted_to_blocks(x, sizes, cap), w1, w2, w3, jax.nn.relu),
+                    sizes, rows)
+            else:
+                first = sorted_to_blocks(jnp.where(mask, x, 0), sizes, cap)
+                cot_as = sorted_to_blocks(jnp.where(mask, cot, 0), sizes, cap)
+                ffn = lambda xb, sizes, w1, w2, w3: padded_swiglu(
+                    xb, w1, w2, w3, jax.nn.relu)
+            keep = mask if first is x else True
+            fwd = jax.jit(lambda x, w1, w2, w3, f=ffn, keep=keep: jnp.where(
+                keep, f(x, sizes, w1, w2, w3), 0))
+            loss = lambda x, w1, w2, w3, f=fwd, cot=cot_as: jnp.sum(
                 f(x, w1, w2, w3).astype(jnp.float32)
                 * cot.astype(jnp.float32))
             both = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3)))
             try:
-                s_fwd, _ = timed(fwd, (x, w1, w2, w3), opts.reps)
-                s_both, grads = timed(both, (x, w1, w2, w3), opts.reps)
+                s_fwd, _ = timed(fwd, (first, w1, w2, w3), opts.reps)
+                s_both, grads = timed(both, (first, w1, w2, w3), opts.reps)
+                if first is not x:      # the rows' gradient, sorted again
+                    grads = (jnp.where(mask, blocks_to_sorted(
+                        grads[0], sizes, rows), 0), ) + grads[1:]
             except Exception as e:      # a tiling Mosaic refuses is a result
                 lines.append({"path": name, "rows": rows, "live": live,
                               "error": f"{type(e).__name__}: {str(e)[:300]}"})
@@ -212,11 +338,7 @@ def main():
                     float(np.max(np.abs(a - b))) for a, b in zip(got, want)],
                 "grad_max": [float(np.max(np.abs(b))) for b in want]})
             print(json.dumps(lines[-1]), flush=True)
-    if opts.out:
-        os.makedirs(os.path.dirname(os.path.abspath(opts.out)), exist_ok=True)
-        with open(opts.out, "w") as f:
-            f.writelines(json.dumps(line) + "\n" for line in lines)
-    return 0
+    return write(lines, opts.out)
 
 
 if __name__ == "__main__":
