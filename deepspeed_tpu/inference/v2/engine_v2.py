@@ -29,7 +29,8 @@ import jax
 import jax.numpy as jnp
 
 from ... import telemetry as _telemetry
-from ...ops.pallas.paged_attention import kernel_page_loads
+from ...ops.pallas.paged_attention import (chunk_page_loads,
+                                           kernel_page_loads, latent_min_rows)
 from ...telemetry import names as _names
 from ...utils.logging import logger
 from .config_v2 import RaggedInferenceEngineConfig
@@ -500,22 +501,13 @@ class InferenceEngineV2:
         window a layer (``layer_windows``) the four are summed over ALL its
         layers' calls, and ``grid_pages_window`` / ``grid_pages_full`` are
         the loads of its window layers' and of its full layers' calls.
-        For a latent cache also ``latent_keys``, the (live row, key) pairs
-        the rows attend, summed over the cache's ENTRIES (every attention's
-        call: two a layer where the model states two), and how many live
-        rows took the absorbed form and how many the expanded one (rows of
-        ONE call, as ``grid_pages``: every call reads alike)."""
+        A latent cache's are :meth:`_latent_page_counts`."""
         windows = getattr(self.model_config, "layer_windows", None)
         if windows is None:
+            if self.kv_cache.latent_dim:
+                return self._latent_page_counts(pos, slots)
             counts = self._kind_page_counts(pos, slots, int(getattr(
                 self.model_config, "sliding_window", 0) or 0))
-            if self.kv_cache.latent_dim:
-                live = slots != 0
-                counts.update({
-                    _names.COUNT_LATENT_KEYS: int(
-                        (pos + 1)[live].sum()) * self.kv_cache.page_layers,
-                    _names.COUNT_ABSORBED_ROWS: int(live.sum()),
-                    _names.COUNT_EXPANDED_ROWS: 0})
             if "state" in self.kv_cache.kinds:
                 counts.update(self._state_counts(pos, slots))
             return counts
@@ -530,6 +522,36 @@ class InferenceEngineV2:
             total["grid_pages_window" if window else "grid_pages_full"] += \
                 layers * kind["grid_pages"]
         return total
+
+    def _latent_page_counts(self, pos, slots):
+        """``_page_counts`` of a latent cache, whose rows read it in one of
+        two forms (``paged_attention.latent_row_forms``: the choice the step
+        program makes from the same rows).  ``absorbed_rows`` /
+        ``expanded_rows``: the live rows of ONE call that took each (every
+        call reads alike).  The page counts and ``latent_keys`` are the
+        ABSORBED kernel's alone: its loads of one call, and the (row, key)
+        pairs its rows attend summed over the cache's ENTRIES (every
+        attention's call: two a layer where the model states two).  The
+        expanded kernel's are ``expanded_keys``, its rows' pairs over the
+        entries, and ``expanded_pages``, the latent pages one call of it
+        brings in."""
+        cfg, kv = self.model_config, self.kv_cache
+        # a burst's [k, rows] has one row a slot: its program holds the
+        # absorbed kernel alone (``slot_rows``)
+        min_rows = None if np.ndim(slots) == 2 else latent_min_rows(
+            cfg, kv.latent_row, kv.dtype, slots.shape[-1])
+        pos, slots = np.atleast_2d(pos), np.atleast_2d(slots)
+        expanded, keys, pages = chunk_page_loads(
+            slots, pos, heads=cfg.num_attention_heads,
+            block_size=kv.block_size, min_rows=min_rows)
+        absorbed = (slots != 0) & ~expanded
+        return {**self._kind_page_counts(pos, np.where(expanded, 0, slots), 0),
+                _names.COUNT_LATENT_KEYS: int(
+                    (pos + 1)[absorbed].sum()) * kv.page_layers,
+                _names.COUNT_ABSORBED_ROWS: int(absorbed.sum()),
+                _names.COUNT_EXPANDED_ROWS: int(expanded.sum()),
+                _names.COUNT_EXPANDED_KEYS: keys * kv.page_layers,
+                _names.COUNT_EXPANDED_PAGES: pages}
 
     def _state_counts(self, pos, slots):
         """What a step's recurrent layers do, summed over them: the state

@@ -694,6 +694,11 @@ def cohere2_moe_ragged_step(params, kv_data, token_ids, positions, seq_slots,
         [jnp.sum(counts), jnp.sum(counts > 0)])
 
 
+#: rows of a stretch of the buffer that ``_mla_block`` takes through the
+#: absorbed form together, or skips where none of them takes it
+_ABSORBED_STRETCH_ROWS = 256
+
+
 def _latent_attention(q, pages, block_tables, seq_slots, positions,
                       block_size, *, rank, scale, use_kernel=True):
     """Absorbed multi-head latent attention over the paged latent cache: q
@@ -723,16 +728,27 @@ def _latent_attention(q, pages, block_tables, seq_slots, positions,
 @jax.named_scope(_names.SCOPE_ATTENTION)
 def _mla_block(attn, h, kv_layer, blk, off, block_tables, seq_slots,
                positions, *, cfg, block_size, use_kernel, q_scale=1.0,
-               kv_scale=1.0):
-    """Multi-head latent attention of one cache entry over the ragged buffer,
-    in the ABSORBED form for every row (``models/pangu_ultra_moe.py`` has the
-    equations): the latent row ``(c ; k_r)`` of each token goes into the
-    entry's one cache buffer, every head's query is taken into the latent
-    space (``q_n W_uk^T``), attends the rows themselves, and the latent
-    output comes back through ``W_uv``.  No per-head key or value is made,
-    in the cache or out of it.  ``q_scale`` / ``kv_scale``: ``mla_down``'s.
-    Returns (attn_out [T, D], new kv_layer)."""
+               kv_scale=1.0, slot_rows=False):
+    """Multi-head latent attention of one cache entry over the ragged buffer
+    (``models/pangu_ultra_moe.py`` has the equations): the latent row ``(c ;
+    k_r)`` of each token goes into the entry's one cache buffer, and a row
+    reads the cache in one of TWO forms, picked by the length of its run
+    (``paged_attention.latent_row_forms``, the batch builder's choice too).
+    ABSORBED (a decode row, a short run, every row of a burst): the head's
+    query is taken into the latent space (``q_n W_uk^T``), attends the rows
+    themselves (``ds_paged_latent``), and the latent output comes back
+    through ``W_uv``.  EXPANDED (a prefill chunk's rows): the per-head keys
+    and values are made from the latent pages inside ``ds_paged_mla_chunk``,
+    once a run, and the query and the output stay as they are.  Rows of the
+    other form reach each kernel dead (slot 0) and come back zero.  No
+    per-head key or value is kept, in the cache or in HBM.  ``q_scale`` /
+    ``kv_scale``: ``mla_down``'s.  ``slot_rows``: the buffer has ONE row a
+    slot (a burst's): every run is one row, and the program holds the
+    absorbed kernel alone.  Returns (attn_out [T, D], new kv_layer)."""
     from ...models.pangu_ultra_moe import mla_down
+    from ...ops._use_kernels import use_pallas_kernels
+    from ...ops.pallas.paged_attention import (
+        latent_min_rows, latent_row_forms, paged_mla_chunk_attention)
     dtype = jnp.dtype(cfg.dtype)
     rank = cfg.kv_lora_rank
     pages, = kv_layer
@@ -743,27 +759,60 @@ def _mla_block(attn, h, kv_layer, blk, off, block_tables, seq_slots,
     with jax.named_scope(_names.SCOPE_KV_CACHE):
         pages = pages.at[blk, off].set(
             jnp.pad(latent, ((0, 0), (0, spare))).astype(pages.dtype))
-    with jax.named_scope(_names.SCOPE_MLA_ABSORB):
-        q_lat = jnp.einsum("thn,chn->thc", q_n,
-                           attn["k_b_proj"]["kernel"].astype(dtype))
-        q = jnp.pad(jnp.concatenate([q_lat, q_r], axis=-1),
+    w_uk = attn["k_b_proj"]["kernel"].astype(dtype)
+    w_uv = attn["v_b_proj"]["kernel"].astype(dtype)
+    T = h.shape[0]
+    # None: no row of a buffer this short, or of this shape, is expanded
+    min_rows = latent_min_rows(cfg, pages.shape[-1], pages.dtype, T) \
+        if use_kernel and use_pallas_kernels() and not slot_rows else None
+
+    def absorbed(q_n, q_r, slots, positions):
+        """The absorbed form of the rows given (the buffer's, or a stretch
+        of them): ``[n, H, v_head_dim]``, zero where ``slots`` is 0."""
+        with jax.named_scope(_names.SCOPE_MLA_ABSORB):
+            q_lat = jnp.einsum("thn,chn->thc", q_n, w_uk)
+            q = jnp.pad(jnp.concatenate([q_lat, q_r], axis=-1),
+                        ((0, 0), (0, 0), (0, spare)))
+        o_lat = _latent_attention(q, pages, block_tables, slots, positions,
+                                  block_size, rank=rank,
+                                  scale=cfg.softmax_scale,
+                                  use_kernel=use_kernel)
+        with jax.named_scope(_names.SCOPE_MLA_ABSORB):
+            return jnp.einsum("thc,chv->thv", o_lat, w_uv)
+
+    if min_rows is None:
+        o = absorbed(q_n, q_r, seq_slots, positions)
+    else:
+        # the absorbed form's three products are owed by ITS rows alone: a
+        # stretch of the buffer that holds none of them (a chunk's rows, dead
+        # rows) skips them
+        slots = jnp.where(latent_row_forms(jnp, seq_slots, positions,
+                                           min_rows), 0, seq_slots)
+        n = T // _ABSORBED_STRETCH_ROWS if T % _ABSORBED_STRETCH_ROWS == 0 \
+            else 1
+        stretches = lambda a: a.reshape((n, T // n) + a.shape[1:])
+        shape = (T // n, q_n.shape[1], w_uv.shape[-1])
+        o = jax.lax.map(
+            lambda rows: jax.lax.cond(
+                jnp.any(rows[2] != 0), lambda: absorbed(*rows),
+                lambda: jnp.zeros(shape, dtype)),
+            tuple(map(stretches, (q_n, q_r, slots, positions)))) \
+            .reshape((T, ) + shape[1:])
+        q = jnp.pad(jnp.concatenate([q_n, q_r], axis=-1),
                     ((0, 0), (0, 0), (0, spare)))
-    o_lat = _latent_attention(q, pages, block_tables, seq_slots, positions,
-                              block_size, rank=rank, scale=cfg.softmax_scale,
-                              use_kernel=use_kernel)
-    with jax.named_scope(_names.SCOPE_MLA_ABSORB):
-        o = jnp.einsum("thc,chv->thv", o_lat,
-                       attn["v_b_proj"]["kernel"].astype(dtype))
+        o = o + paged_mla_chunk_attention(
+            q, pages, w_uk, w_uv, block_tables, seq_slots, positions,
+            rank=rank, scale=cfg.softmax_scale, min_rows=min_rows)
     o = o.reshape(o.shape[0], -1) @ attn["o_proj"]["kernel"].astype(dtype)
     return o, (pages, )
 
 
-@_ragged_program("pangu_ultra_moe", step_counts=(_names.COUNT_EXPERT_COPIES,
-                                                  _names.COUNT_EXPERT_ACTIVE))
+@_ragged_program("pangu_ultra_moe", step_counts=(
+    _names.COUNT_EXPERT_COPIES, _names.COUNT_EXPERT_ACTIVE), slot_rows=True)
 def pangu_ultra_moe_ragged_step(params, kv_data, token_ids, positions,
                                 seq_slots, block_tables, last_token_idx, *,
                                 cfg, block_size, use_kernel=True,
-                                kv_dtype=None):
+                                kv_dtype=None, slot_rows=False):
     """One ragged engine iteration for openPangu-Ultra-MoE
     (``models/pangu_ultra_moe.py`` has the layer's equations): sandwich
     norms (each branch normed going in AND coming out), multi-head latent
@@ -773,7 +822,7 @@ def pangu_ultra_moe_ragged_step(params, kv_data, token_ids, positions,
     rest the held experts' part of the scaled routed sum
     (``moe/held_experts.py``) beside the shared expert.  The grouped matmuls
     are the Pallas ``ds_grouped_matmul`` where the step's kernels are on, as
-    ``cohere2_moe_ragged_step``'s.
+    ``cohere2_moe_ragged_step``'s.  ``slot_rows``: ``_mla_block``'s.
 
     Returns ``(logits, new kv_data, counts)``; ``counts`` as
     ``cohere2_moe_ragged_step``'s, over the routed layers."""
@@ -800,7 +849,7 @@ def pangu_ultra_moe_ragged_step(params, kv_data, token_ids, positions,
         attn_out, kv_data[l] = _mla_block(
             lp["self_attn"], norm(x, "input_layernorm"), kv_data[l], blk,
             off, block_tables, seq_slots, positions, cfg=cfg,
-            block_size=block_size, use_kernel=use_kernel)
+            block_size=block_size, use_kernel=use_kernel, slot_rows=slot_rows)
         x = x + norm(attn_out, "post_attention_layernorm")
         h = norm(x, "pre_mlp_layernorm")
         if cfg.routed(l):
@@ -826,11 +875,11 @@ def pangu_ultra_moe_ragged_step(params, kv_data, token_ids, positions,
 
 @_ragged_program("longcat_flash", step_counts=(
     _names.COUNT_EXPERT_COPIES, _names.COUNT_EXPERT_ACTIVE,
-    _names.COUNT_ZERO_EXPERT_COPIES))
+    _names.COUNT_ZERO_EXPERT_COPIES), slot_rows=True)
 def longcat_flash_ragged_step(params, kv_data, token_ids, positions,
                               seq_slots, block_tables, last_token_idx, *,
                               cfg, block_size, use_kernel=True,
-                              kv_dtype=None):
+                              kv_dtype=None, slot_rows=False):
     """One ragged engine iteration for LongCat-Flash
     (``models/longcat_flash.py`` has the layer's equations): TWO latent
     attentions a layer, each with its own weights and its own cache entry
@@ -841,6 +890,7 @@ def longcat_flash_ragged_step(params, kv_data, token_ids, positions,
     it.  The branch is the held experts' part of the routed sum
     (``moe/held_experts.py``, the router's width counting the identity
     experts) plus the identity experts' weighted copy of its input.
+    ``slot_rows``: ``_mla_block``'s.
 
     Returns ``(logits, new kv_data, counts)``; ``counts`` as
     ``cohere2_moe_ragged_step``'s and, third, the (live row, layer, chosen
@@ -873,7 +923,8 @@ def longcat_flash_ragged_step(params, kv_data, token_ids, positions,
                 norm(x, f"input_layernorm_{i}"), kv_data[entry], blk, off,
                 block_tables, seq_slots, positions, cfg=cfg,
                 block_size=block_size, use_kernel=use_kernel,
-                q_scale=cfg.q_scale, kv_scale=cfg.kv_scale)
+                q_scale=cfg.q_scale, kv_scale=cfg.kv_scale,
+                slot_rows=slot_rows)
             x = x + attn_out
             h = norm(x, f"post_attention_layernorm_{i}")
             if i == 0:

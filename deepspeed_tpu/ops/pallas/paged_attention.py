@@ -75,6 +75,17 @@ _LATENT_TILE_ROWS = 1024
 #: its VMEM: the tile's q and output (each double-buffered), the float32
 #: accumulator and softmax state, two blocks of pages, a block's score arrays
 _LATENT_VMEM_BYTES = 64 * 1024 * 1024
+#: the EXPANDED form's reader (:func:`paged_mla_chunk_attention`): a tile of
+#: at most 2048 buffer rows, whose runs' keys are made once a head; 1024 of
+#: its rows against the 1024 keys of a block of pages through one softmax
+#: update (the flash kernels' blocks: docs/kernels.md has the v5e sweep);
+#: its VMEM (the tile's q and output twice each, a float32 accumulator and
+#: softmax state, two blocks of pages, the made keys and values, a
+#: stretch's score arrays ``[1024, 1024]``, 4 MB each)
+_CHUNK_TILE_ROWS = 2048
+_CHUNK_SUB_ROWS = 1024
+_CHUNK_BLOCK_KEYS = 1024
+_CHUNK_VMEM_BYTES = 96 * 1024 * 1024
 
 
 from ._common import interpret_mode as _interpret
@@ -761,6 +772,336 @@ def paged_latent_attention(q, c_cache, block_tables, seq_slots, positions, *,
       (n_pages - n_blocks * (P - 1)).sum(-1), qt,
       rows(pos), rows(rid), c_cache)
     return out.reshape(n * tq, H, rank)[:T]
+
+
+# ------------------------------------------- latent path, expanded form
+def expanded_min_rows(rank, nope, rope, value):
+    """The rows a run has to hold for the EXPANDED form of multi-head latent
+    attention to cost fewer operations than the absorbed one, from the
+    configuration's widths alone.  A (row, key) pair costs a head ``2 (2 rank
+    + rope)`` operations absorbed (scores over the latent row, values its
+    first ``rank``) and ``2 (nope + rope + value)`` expanded, where a context
+    token's key and value are first made from its latent row: ``2 rank (nope
+    + value)`` a head, ONCE a run (:func:`paged_mla_chunk_attention` keeps a
+    run in one tile).  The least whole ``n`` past the break-even ``rank (nope
+    + value) / (2 rank - nope - value)``; None where the absorbed pair is the
+    cheaper one and no run is long enough."""
+    saved = 2 * rank - nope - value        # (2 rank + rope) - (nope + rope + value)
+    if saved <= 0:
+        return None
+    return rank * (nope + value) // saved + 1
+
+
+def chunk_tile_rows(tokens, min_rows):
+    """``(TQ, SQ, R)`` of :func:`paged_mla_chunk_attention` for a call of
+    ``tokens`` rows: the rows of a tile (a run is cut at a tile's end, and
+    its keys are made once a tile), the rows that go through one softmax
+    update together, and the most runs of ``min_rows`` rows a tile holds."""
+    sq = min(_CHUNK_SUB_ROWS, -(-tokens // 8) * 8)
+    tq = min(_CHUNK_TILE_ROWS // sq, -(-tokens // sq)) * sq
+    return tq, sq, max(1, tq // min_rows)
+
+
+def chunk_tiled(rank, nope, value, row, kv_dtype):
+    """Whether :func:`paged_mla_chunk_attention` takes this shape: every
+    slice of a page, of the made keys and of the query is whole lane tiles.
+    (Interpreted, off the chip, any shape goes: the tests' widths.)"""
+    if kv_dtype not in (jnp.float32, jnp.bfloat16):
+        return False
+    return _interpret() or not any(
+        n % 128 for n in (rank, nope, value, row - rank))
+
+
+def latent_min_rows(cfg, row, kv_dtype, tokens):
+    """``min_rows`` of :func:`latent_row_forms` for a call of ``tokens`` rows
+    of a model ``cfg`` (its published widths) on a latent cache of ``row``
+    columns: :func:`expanded_min_rows`, or None where every row takes the
+    absorbed form: a shape one of the two readers does not take, or a
+    buffer too short to hold one long run (a burst's: its program holds no
+    call of the second reader)."""
+    rank, nope, value = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                         cfg.v_head_dim)
+    min_rows = expanded_min_rows(rank, nope, cfg.qk_rope_head_dim, value)
+    if min_rows is None or tokens < min_rows or not (
+            latent_tiled(cfg.num_attention_heads, kv_dtype)
+            and chunk_tiled(rank, nope, value, row, kv_dtype)):
+        return None
+    return min_rows
+
+
+def latent_row_forms(xp, seq_slots, positions, min_rows):
+    """WHICH form each row of a step over a latent cache takes: ``[..., T]``
+    bool, True the expanded one (:func:`paged_mla_chunk_attention`), False
+    the absorbed one (:func:`paged_latent_attention`) or a dead row.  With
+    ``xp`` numpy in the batch builder and jax.numpy inside the step program,
+    from the same ``seq_slots`` / ``positions``: the two make the same
+    choice.  A RUN is a stretch of live rows with one slot and consecutive
+    positions inside one tile of :func:`chunk_tile_rows`; it takes the
+    expanded form when it holds ``min_rows`` rows
+    (:func:`latent_min_rows`; None: no row does) or more.  ``[k, T]`` rows
+    are ``k`` calls."""
+    if min_rows is None:
+        return xp.zeros(seq_slots.shape, bool)
+    return _chunk_runs(xp, seq_slots, positions, min_rows)[0]
+
+
+def _running(xp, a, reverse=False):
+    """The running maximum of ``a [n, tq]`` along a tile's rows (``reverse``:
+    the running minimum, from the tile's end)."""
+    if xp is np:
+        return np.minimum.accumulate(a[:, ::-1], 1)[:, ::-1] if reverse \
+            else np.maximum.accumulate(a, 1)
+    return jax.lax.cummin(a, 1, reverse=True) if reverse \
+        else jax.lax.cummax(a, 1)
+
+
+def _chunk_runs(xp, seq_slots, positions, min_rows):
+    """:func:`latent_row_forms` (in ``seq_slots``' shape) and, of the runs
+    that take the expanded form, by tile of :func:`chunk_tile_rows` (``[n,
+    R]``, a tile's runs compacted to the front): the ``slot``, first buffer
+    row ``row0``, rows ``n_rows`` (0: no such run) and first position
+    ``pos0``."""
+    T = seq_slots.shape[-1]
+    tq, _, R = chunk_tile_rows(T, min_rows)
+    slots, pos = (xp.pad(a.reshape(-1, T).astype(xp.int32),
+                         ((0, 0), (0, -T % tq))).reshape(-1, tq)
+                  for a in (seq_slots, positions))
+    live = slots != 0
+    edge = xp.full((slots.shape[0], 1), -1, xp.int32)
+    joins = (slots == xp.concatenate([edge, slots[:, :-1]], 1)) \
+        & (pos == xp.concatenate([edge, pos[:, :-1]], 1) + 1)
+    start = live & ~joins
+    # a run's last row: the next one starts a run, is dead, or is the tile's
+    end = live & xp.concatenate([~(live & joins)[:, 1:], edge == -1], 1)
+    at = xp.arange(tq, dtype=xp.int32)[None]
+    first = _running(xp, xp.where(start, at, 0))
+    last = _running(xp, xp.where(end, at, tq - 1), reverse=True)
+    expanded = live & (last - first + 1 >= min_rows)
+    head = start & expanded
+    nth = xp.cumsum(head, axis=1) - 1
+    pick = head[:, None, :] & (
+        nth[:, None, :] == xp.arange(R, dtype=xp.int32)[None, :, None])
+    of = lambda a: (pick * a[:, None, :]).sum(-1).astype(xp.int32)
+    return expanded.reshape(seq_slots.shape[:-1] + (-1, ))[..., :T], \
+        of(slots), of(xp.broadcast_to(at, slots.shape)), \
+        of(last - first + 1), of(pos)
+
+
+def _chunk_blocks(xp, n_rows, pos0, block_size):
+    """``(P, blocks)``: the pages of a block of the chunk kernel, and the
+    blocks each run of :func:`_chunk_runs` walks (its context's, from key
+    0)."""
+    P = _CHUNK_BLOCK_KEYS // block_size or 1
+    return P, xp.where(n_rows > 0,
+                       (pos0 + n_rows - 1) // (P * block_size) + 1, 0)
+
+
+def chunk_page_loads(seq_slots, positions, *, heads, block_size, min_rows):
+    """Host-side (numpy) count of what :func:`paged_mla_chunk_attention`
+    does for these rows (``[T]``, or ``[B, T]``: B calls): ``(expanded,
+    keys, pages)``, the rows that take it (:func:`latent_row_forms`), the
+    (row, key) pairs they attend, and the latent pages its loops bring in:
+    a run's blocks of ``_CHUNK_BLOCK_KEYS`` keys, once a head."""
+    slots, pos = (np.atleast_2d(np.asarray(a))
+                  for a in (seq_slots, positions))
+    if min_rows is None:
+        return np.zeros(slots.shape, bool), 0, 0
+    expanded, _, _, n_rows, pos0 = _chunk_runs(np, slots, pos, min_rows)
+    P, blocks = _chunk_blocks(np, n_rows, pos0, block_size)
+    return expanded, int((pos + 1)[expanded].sum()), \
+        int(blocks.sum()) * P * heads
+
+
+def _mla_chunk_kernel(tables_ref, total_ref, slot_ref, row0_ref, nrows_ref,
+                      pos0_ref, q_ref, wuk_ref, wuv_ref, c_hbm, o_ref, c_buf,
+                      sem, k_ref, v_ref, acc_ref, m_ref, l_ref, *, sq,
+                      block_size, pages, maxb, scale, rank, max_runs):
+    """One tile of ``tq`` buffer rows and one head: ``q_ref [tq, W]`` (a row
+    ``(q_n [nope] ; q_r ; zeros)``, as long as ``nope`` plus a page row's
+    columns past ``rank``), ``wuk_ref [rank, nope]``, ``wuv_ref [rank,
+    value]``, against the tile's runs (:func:`_chunk_runs`).  An item is one
+    BLOCK of ``pages`` pages of a run: its latent rows arrive by DMA, the
+    head's keys ``(c W_uk ; k_r ; zeros)`` and values ``c W_uv`` are made
+    from them into VMEM in the cache's type, and every stretch of ``sq``
+    rows that holds rows of the run which see the block takes one
+    online-softmax update: with a mask only where an edge (the run's first
+    or last row, the diagonal) crosses the stretch's square."""
+    i = pl.program_id(0)
+    base = i * max_runs
+    tq = acc_ref.shape[0]
+    nope = wuk_ref.shape[1]
+    keys = pages * block_size
+    total = total_ref[i]
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    def copies(k, j, buf, act):
+        """``act`` (start, or wait) on the DMAs of block ``j`` of run ``k``:
+        its pages one under the other in ``c_buf[buf]``.  A page past the
+        table's end is its last page again (no row sees it)."""
+        row = slot_ref[base + k] * maxb
+        for p in range(pages):
+            page = jnp.minimum(j * pages + p, maxb - 1)
+            act(pltpu.make_async_copy(
+                c_hbm.at[tables_ref[row + page]],
+                c_buf.at[buf, pl.ds(p * block_size, block_size)],
+                sem.at[buf]))
+
+    start, wait = (lambda c: c.start()), (lambda c: c.wait())
+
+    @pl.when(total > 0)
+    def _first():
+        copies(0, 0, 0, start)
+
+    def attend(k, j, r0, masked):
+        """The rows ``r0 .. r0 + sq`` against the made block; ``masked``: an
+        edge crosses the square (not every row is the run's, or not every
+        row sees every key)."""
+        rows = pl.ds(r0, sq)
+        s = jax.lax.dot_general(
+            q_ref[rows], k_ref[...], (((1, ), (1, )), ((), ())),
+            preferred_element_type=jnp.float32) * scale       # [sq, keys]
+        m_prev = m_ref[rows, :1]
+        if masked:
+            row = r0 - row0_ref[base + k] + jax.lax.broadcasted_iota(
+                jnp.int32, (sq, keys), 0)
+            col = j * keys + jax.lax.broadcasted_iota(
+                jnp.int32, (sq, keys), 1)
+            live = (row >= 0) & (row < nrows_ref[base + k]) \
+                & (col <= pos0_ref[base + k] + row)
+            s = jnp.where(live, s, _NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            # a row of another run, or of none, keeps -inf: exp(-inf - 0)
+            m_safe = jnp.where(m_new == _NEG_INF, 0.0, m_new)
+            alpha = jnp.where(m_prev == _NEG_INF, 0.0,
+                              jnp.exp(m_prev - m_safe))
+        else:                   # every score counts: m_new is finite
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            m_safe = m_new
+            alpha = jnp.exp(m_prev - m_new)
+        e = jnp.exp(s - m_safe)
+        l_new = alpha * l_ref[rows, :1] + jnp.sum(e, axis=1, keepdims=True)
+        acc_ref[rows] = acc_ref[rows] * alpha + jnp.dot(
+            e.astype(v_ref.dtype), v_ref[...],
+            preferred_element_type=jnp.float32)
+        m_ref[rows] = jnp.broadcast_to(m_new, (sq, m_ref.shape[1]))
+        l_ref[rows] = jnp.broadcast_to(l_new, (sq, l_ref.shape[1]))
+
+    def item(it, carry):
+        k, j = carry
+        buf = it % 2
+        row0, n_rows = row0_ref[base + k], nrows_ref[base + k]
+        pos0 = pos0_ref[base + k]
+        last = j == (pos0 + n_rows - 1) // keys
+        k_next, j_next = jnp.where(last, k + 1, k), jnp.where(last, 0, j + 1)
+
+        @pl.when(it + 1 < total)
+        def _prefetch():
+            copies(k_next, j_next, 1 - buf, start)
+
+        copies(k, j, buf, wait)
+        block = c_buf[buf]                                  # [keys, row]
+        made = lambda w: jnp.dot(
+            block[:, :rank], w[...],
+            preferred_element_type=jnp.float32).astype(k_ref.dtype)
+        k_ref[:, :nope] = made(wuk_ref)
+        k_ref[:, nope:] = block[:, rank:]
+        v_ref[...] = made(wuv_ref)
+
+        for r0 in range(0, tq, sq):
+            # the run's rows of this stretch (one past the last); whether
+            # the last of them sees the block; whether the stretch lies
+            # inside the run and its first row sees all of the block
+            lo = jnp.maximum(row0, r0)
+            hi = jnp.minimum(row0 + n_rows, r0 + sq)
+            sees = (lo < hi) & (j * keys <= pos0 + hi - 1 - row0)
+            inner = (row0 <= r0) & (row0 + n_rows >= r0 + sq) \
+                & ((j + 1) * keys - 1 <= pos0 + r0 - row0)
+            pl.when(sees & inner)(
+                functools.partial(attend, k, j, r0, False))
+            pl.when(sees & jnp.logical_not(inner))(
+                functools.partial(attend, k, j, r0, True))
+        return k_next, j_next
+
+    jax.lax.fori_loop(0, total, item, (jnp.int32(0), ) * 2)
+
+    l = l_ref[:, :1]
+    o_ref[...] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)) \
+        .astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "scale", "min_rows"))
+def paged_mla_chunk_attention(q, c_cache, w_uk, w_uv, block_tables, seq_slots,
+                              positions, *, rank, scale, min_rows):
+    """Multi-head latent attention in the EXPANDED form over the paged latent
+    cache, for the rows of LONG runs (``ds_paged_mla_chunk``).
+
+    q: ``[T, H, W]``, head ``h`` of row ``t`` as ``(q_n [nope] ; q_r ;
+    zeros)`` with ``W = nope + (L - rank)``; c_cache ``[num_blocks, bs, L]``,
+    a token's row ``(c [rank] ; k_r ; zeros)``; w_uk ``[rank, H, nope]``,
+    w_uv ``[rank, H, value]``; block_tables, seq_slots, positions as
+    :func:`paged_latent_attention`'s.  Returns ``[T, H, value]``: for every
+    row of a run of ``min_rows`` rows or more (:func:`latent_row_forms`)
+    ``sum_j softmax_j(q . (c_j W_uk,h ; k_r,j) * scale) c_j W_uv,h`` over
+    the keys ``j <= positions[t]`` of its sequence, the keys and values made
+    from the latent pages in VMEM, a block of ``_CHUNK_BLOCK_KEYS`` keys at
+    a time, in the cache's type with float32 sums; every other row (a
+    shorter run's, a dead one) comes back zero.  The grid is (tile, head):
+    a head's step walks the blocks of the tile's runs, so a run's keys are
+    made once a head.  The shape has to pass :func:`chunk_tiled`."""
+    T, H, W = q.shape
+    _, bs, L = c_cache.shape
+    nope, value = w_uk.shape[2], w_uv.shape[2]
+    if W != nope + L - rank or not chunk_tiled(rank, nope, value, L,
+                                               c_cache.dtype):
+        raise ValueError(
+            f"ds_paged_mla_chunk does not take queries of {W} on keys of "
+            f"{nope} + {L} - {rank} in {c_cache.dtype} (chunk_tiled)")
+    dtype = c_cache.dtype
+    tq, sq, R = chunk_tile_rows(T, min_rows)
+    maxb = block_tables.shape[1]
+    _, run_slot, row0, n_rows, pos0 = _chunk_runs(
+        jnp, seq_slots, positions, min_rows)
+    n = row0.shape[0]
+    P, blocks = _chunk_blocks(jnp, n_rows, pos0, bs)
+    qt = jnp.pad(q.astype(dtype).reshape(T, H * W), ((0, n * tq - T), (0, 0)))
+    cols = lambda width: pl.BlockSpec((tq, width), lambda i, h, *_: (i, h))
+    weight = lambda width: pl.BlockSpec((rank, width),
+                                        lambda i, h, *_: (0, h))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=6,
+        grid=(n, H),
+        in_specs=[cols(W), weight(nope), weight(value),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=cols(value),
+        scratch_shapes=[
+            pltpu.VMEM((2, P * bs, L), dtype),
+            pltpu.SemaphoreType.DMA((2, )),
+            pltpu.VMEM((P * bs, W), dtype),             # the made keys
+            pltpu.VMEM((P * bs, value), dtype),         # the made values
+            pltpu.VMEM((tq, value), jnp.float32),
+            pltpu.VMEM((tq, 128), jnp.float32),
+            pltpu.VMEM((tq, 128), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_mla_chunk_kernel, sq=sq, block_size=bs, pages=P,
+                          maxb=maxb, scale=float(scale), rank=int(rank),
+                          max_runs=R),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n * tq, H * value), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_CHUNK_VMEM_BYTES),
+        interpret=_interpret(),
+        name="ds_paged_mla_chunk",
+    )(block_tables.reshape(-1).astype(jnp.int32), blocks.sum(-1),
+      run_slot.reshape(-1), row0.reshape(-1), n_rows.reshape(-1),
+      pos0.reshape(-1), qt, w_uk.astype(dtype).reshape(rank, H * nope),
+      w_uv.astype(dtype).reshape(rank, H * value), c_cache)
+    return out[:T].reshape(T, H, value)
 
 
 # ------------------------------------------------------- per-token path

@@ -127,12 +127,18 @@ COUNT_EXPERT_ROWS_MAX = "expert_rows_max"
 COUNT_EXPERT_PADDED_CALLS = "expert_padded_calls"
 COUNT_MICROS_COVERED = "micro_steps_covered"
 
-#: a model with a LATENT cache (multi-head latent attention): the (live row,
-#: key) pairs its rows attend, summed over the layers, and the live rows
-#: that took the absorbed form (the paged latent kernel) and the expanded one
+#: a model with a LATENT cache (multi-head latent attention), whose rows
+#: read it in one of two forms by the length of their run: the live rows of
+#: one call that took the absorbed form (``ds_paged_latent``) and the
+#: expanded one (``ds_paged_mla_chunk``); the (row, key) pairs each form's
+#: rows attend, summed over the cache's entries (``latent_keys``: the
+#: absorbed rows'); the latent pages one call of the expanded kernel brings
+#: in (the absorbed kernel's are ``grid_pages``)
 COUNT_LATENT_KEYS = "latent_keys"
 COUNT_ABSORBED_ROWS = "absorbed_rows"
 COUNT_EXPANDED_ROWS = "expanded_rows"
+COUNT_EXPANDED_KEYS = "expanded_keys"
+COUNT_EXPANDED_PAGES = "expanded_pages"
 
 #: a model with RECURRENT layers (state-space: a fixed row a sequence slot in
 #: the cache): summed over those layers, the state rows a step's runs read

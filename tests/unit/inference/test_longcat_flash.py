@@ -334,6 +334,48 @@ def test_the_latent_kernel_runs_under_the_step(monkeypatch):
     assert engine(True).serve(prompts, max_new_tokens=6) == want
 
 
+def test_both_kernels_run_under_a_step_with_a_long_chunk(monkeypatch):
+    """A budget of 48 rows (the tiny widths' rule: a run of 33 rows is
+    expanded): a prompt of 60 tokens goes as 35 and 25 rows beside a short
+    prompt's 13, through ``ds_paged_mla_chunk`` and ``ds_paged_latent``
+    (interpret mode), both attentions of every layer with the model's two
+    scale factors: the streams are the gather's, and the first step counts
+    35 expanded rows with their pairs over all 8 cache entries."""
+    monkeypatch.setenv("DS_TPU_FORCE_PALLAS", "1")
+    inner = rf.longcat_flash_ragged_step.__wrapped__
+    model, params = _model()
+    rng = np.random.default_rng(4)
+    prompts = [rng.integers(0, 256, n).tolist() for n in (60, 13)]
+
+    def engine(use_kernel):
+        sched = _scheduler(model, params, 0, budget=48, context=128)
+
+        def step(*a, **kw):
+            return inner(*a, **{**kw, "use_kernel": use_kernel})
+
+        sched.engine._step_fn = jax.jit(
+            step, static_argnames=("cfg", "block_size", "use_kernel",
+                                   "kv_dtype"), donate_argnums=(1, ))
+        return sched
+
+    want = engine(False).engine.generate(prompts, max_new_tokens=6)
+    sched = engine(True)
+    firsts, build = [], sched.engine._build_batch
+
+    def counted(*a, **kw):
+        out = build(*a, **kw)
+        firsts.append(dict(sched.engine.last_step_counts))
+        return out
+
+    monkeypatch.setattr(sched.engine, "_build_batch", counted)
+    assert sched.serve(prompts, max_new_tokens=6) == want
+    assert (firsts[0]["absorbed_rows"], firsts[0]["expanded_rows"]) == (13,
+                                                                        35)
+    assert firsts[0]["expanded_keys"] == sum(range(1, 36)) * 8
+    assert firsts[0]["latent_keys"] == sum(range(1, 14)) * 8
+    assert firsts[1]["expanded_rows"] == 0      # the last 25 rows: absorbed
+
+
 def test_the_config_states_what_it_implements():
     cfg = lf.LongcatFlashConfig()
     assert (cfg.router_width, cfg.q_scale, round(cfg.kv_scale ** 2),

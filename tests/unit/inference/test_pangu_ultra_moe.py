@@ -7,6 +7,7 @@ counts a step carries, the latent kernel's block items among them, and a
 prompt long enough to take blocks through that kernel."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -274,6 +275,101 @@ def test_a_prompt_long_enough_to_take_blocks_streams_generates_tokens(
     assert all(len(w) == 10 for w in want)
     # the three chunks whose run ends past page 7 hold one block each
     assert set(blocks) == {0, 8} and blocks.count(8) == 3
+
+
+def _forced(sched, inner, use_kernel):
+    """``sched`` with its own jit of the step ``inner`` (the suite's cached
+    programs were traced without the kernel gate)."""
+    def step(*a, **kw):
+        return inner(*a, **{**kw, "use_kernel": use_kernel})
+
+    sched.engine._step_fn = jax.jit(
+        step, static_argnames=("cfg", "block_size", "use_kernel", "kv_dtype",
+                               "slot_rows"), donate_argnums=(1, ))
+    return sched
+
+
+@pytest.mark.parametrize("burst,stretch", [(0, 256), (8, 256), (0, 16)],
+                         ids=["steps", "burst", "stretches_of_16_rows"])
+def test_a_long_chunks_rows_take_the_expanded_kernel(monkeypatch, burst,
+                                                     stretch):
+    """A budget of 48 rows at the tiny widths (even at 32 rows: a run of 33
+    is expanded): a prompt of 100 tokens goes in chunks of 47-48 rows
+    through ``ds_paged_mla_chunk`` (interpret mode) beside a short prompt's
+    rows and the decode rows on ``ds_paged_latent``; the streams are
+    ``generate()``'s tokens on the gather, and every step's counts add up to
+    its live rows and (row, key) pairs.  The absorbed form's products run a
+    stretch of the buffer at a time and are skipped where a stretch holds
+    none of its rows (``_ABSORBED_STRETCH_ROWS``: the buffer is one stretch,
+    or three)."""
+    monkeypatch.setenv("DS_TPU_FORCE_PALLAS", "1")
+    monkeypatch.setattr(rf, "_ABSORBED_STRETCH_ROWS", stretch)
+    inner = rf.pangu_ultra_moe_ragged_step.__wrapped__
+    model, params = _model()
+    rng = np.random.default_rng(4)
+    prompts = [rng.integers(0, 256, n).tolist() for n in (100, 13)]
+    engine = lambda use_kernel: _forced(
+        _scheduler(model, params, burst, budget=48, context=128), inner,
+        use_kernel)
+    want = engine(False).engine.generate(prompts, max_new_tokens=10)
+    sched = engine(True)
+    seen, build = [], sched.engine._build_batch
+
+    def counted(*a, **kw):
+        out = build(*a, **kw)
+        if out is not None:
+            _, pos, slots, *_ = out
+            seen.append((dict(sched.engine.last_step_counts),
+                         int((pos + 1)[slots != 0].sum())))
+        return out
+
+    monkeypatch.setattr(sched.engine, "_build_batch", counted)
+    assert sched.serve(prompts, max_new_tokens=10) == want
+    assert all(len(w) == 10 for w in want)
+    for counts, pairs in seen:
+        assert counts["absorbed_rows"] + counts["expanded_rows"] \
+            == counts["live_tokens"]
+        assert counts["latent_keys"] + counts["expanded_keys"] == pairs * 5
+        assert (counts["expanded_pages"] > 0) == (counts["expanded_rows"] > 0)
+    # the first step: the short prompt's 13 rows, 35 of the long one's
+    assert (seen[0][0]["absorbed_rows"], seen[0][0]["expanded_rows"]) == (
+        13, 35)
+    assert seen[0][0]["grid_pages"] == 2            # positions 0..12
+    assert seen[1][0]["expanded_rows"] >= 47
+    # the last chunk (100 - 35 - 48 = 17 rows) and every decode row: absorbed
+    assert sum(c["expanded_rows"] > 0 for c, _ in seen) == 2
+
+
+def test_a_burst_holds_no_call_of_the_expanded_kernel(monkeypatch):
+    """The ragged step of a buffer that can hold a long run calls both
+    kernels; the burst (one row a slot, ``slot_rows``) and a step of a
+    buffer too short for one long run call ``ds_paged_latent`` alone,
+    whatever the number of slots."""
+    monkeypatch.setenv("DS_TPU_FORCE_PALLAS", "1")
+    model, params = _model()
+    eng = _scheduler(model, params, 8, budget=48, sessions=40,
+                     context=64).engine
+    n = eng.state_manager.max_seqs
+    assert n > 33                       # the tiny widths' rule
+    i32 = lambda *s: jnp.zeros(s, jnp.int32)
+    tables = i32(n, eng.state_manager.block_table.shape[1])
+    inner = rf.pangu_ultra_moe_ragged_step.__wrapped__
+    # (an interpreted kernel is inlined where it is lowered: read the jaxpr)
+    step = lambda T: str(jax.make_jaxpr(functools.partial(
+        inner, cfg=CFG, block_size=8))(
+        params, eng._kv, i32(T), i32(T), i32(T), tables, i32(n)))
+    assert "ds_paged_mla_chunk" in step(48) and "ds_paged_latent" in step(48)
+    assert "ds_paged_mla_chunk" not in step(32)
+    burst = str(jax.make_jaxpr(functools.partial(
+        rf.decode_burst.__wrapped__, step_fn=rf.pangu_ultra_moe_ragged_step,
+        cfg=CFG, block_size=8, k=4))(
+        params, eng._kv, i32(n), i32(n), jnp.ones(n, bool), tables))
+    assert "ds_paged_latent" in burst and "ds_paged_mla_chunk" not in burst
+    # and the batch builder counts a burst's [k, rows] so
+    slots = np.tile(np.arange(n, dtype=np.int32), (3, 1))
+    counts = eng._page_counts(np.where(slots != 0, 20 + slots, 0), slots)
+    assert (counts["absorbed_rows"], counts["expanded_rows"]) == (
+        3 * (n - 1), 0)
 
 
 def test_the_dense_layers_lead():

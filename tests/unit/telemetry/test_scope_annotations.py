@@ -727,7 +727,7 @@ def test_every_pallas_call_has_a_ds_name_listed_in_the_docs():
         assert name.startswith(names.KERNEL_PREFIX), name
         assert f"`{name}`" in doc, f"{name} missing from docs/kernels.md"
     assert sum(n.startswith(names.KERNEL_FLASH) for n in kernel_names) == 7
-    assert sum(n.startswith(names.KERNEL_PAGED) for n in kernel_names) == 3
+    assert sum(n.startswith(names.KERNEL_PAGED) for n in kernel_names) == 4
     assert sum(n.startswith(names.KERNEL_OPTIMIZER)
                for n in kernel_names) == 4
 

@@ -350,6 +350,23 @@ def main():
             sds((33, 137), jnp.int32), sds((T, ), jnp.int32),
             sds((T, ), jnp.int32), pages=item_pages(1, 640, bf16, 128)))
 
+    # the EXPANDED form's reader of the same pages, at the two cells' steps:
+    # a tile of 1024 rows of 128 heads, one of 2048 rows of 64
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        expanded_min_rows, paged_mla_chunk_attention)
+    for name, T, heads, seqs, maxb in (
+            ("the Pangu cell's step", 1024, 128, 65, 193),
+            ("the LongCat cell's step", 2048, 64, 33, 137)):
+        results.append(checked(
+            f"paged_mla_chunk_attention(MLA {heads} x 576, {name})",
+            lambda q, c, k, v, t, s, l: paged_mla_chunk_attention(
+                q, c, k, v, t, s, l, rank=512, scale=192 ** -0.5,
+                min_rows=expanded_min_rows(512, 128, 64, 128)),
+            sds((T, heads, 256), bf16), sds((64, 128, 640), bf16),
+            sds((512, heads, 128), bf16), sds((512, heads, 128), bf16),
+            sds((seqs, maxb), jnp.int32), sds((T, ), jnp.int32),
+            sds((T, ), jnp.int32)))
+
     # the Mamba-1 recurrence at the Jamba cell's shapes: a 2048-row step over
     # 257 slots' state of 16 x 5120 (bfloat16, aliased in and out)
     from deepspeed_tpu.ops.pallas.selective_scan import selective_scan

@@ -2,22 +2,29 @@
 """Multi-head latent attention over the paged latent cache, both forms, on the
 chip: the ABSORBED form (``ds_paged_latent``: every head's query taken into
 the latent space, the cache rows themselves the keys and the values) against
-the EXPANDED form (per-head keys and values made from the cache rows in a
-step's scratch, ``expanded_run_attention`` below) for one prefill chunk of one
-sequence; the absorbed kernel's chunk with its long runs' tile items taking a
-BLOCK of ``P`` pages through one softmax update, ``P`` in 1 / 2 / 4 / 8 (set
-from here: the program's ``P`` is ``paged_attention.item_pages``' from the
-shapes, and has no option); and the kernel at a decode burst's shape.
+the EXPANDED form, as the kernel that serves a prefill chunk's rows since PR 51
+(``ds_paged_mla_chunk``: a block's per-head keys and values made from the
+latent pages in VMEM; section ``chunk``) and as PR 35 wrote it in XLA (per-head
+keys and values in a step's scratch, ``expanded_run_attention`` below; section
+``forms``); the absorbed kernel's chunk with its long runs' tile items taking
+a BLOCK of ``P`` pages through one softmax update, ``P`` in 1 / 2 / 4 / 8
+(section ``pages``; set from here: the program's ``P`` is
+``paged_attention.item_pages``' from the shapes, and has no option); and the
+kernel at a decode burst's shape (section ``burst``).
 
-    python tools/mla_forms_bench.py            # chip only, ~3 min
+    python tools/mla_forms_bench.py                       # chip only, ~5 min
+    python tools/mla_forms_bench.py --sections chunk      # ~2 min
 
 Prints one JSON line a shape: milliseconds a call (one layer), the largest
-difference between the two forms' outputs, and what the absorbed kernel's
-time is of its roofline; in the sweep the kernel's own time from a device
-trace of ``--reps`` calls, microseconds a PAGE and a tile item's roofline
-over that.  docs/kernels.md and PERF.md hold the readings that decided which
-rows take which form in ``pangu_ultra_moe_ragged_step`` and what
-``item_pages`` gives the latent kernel.
+difference between the two forms' outputs, and what a kernel's time is of its
+roofline; in the sweeps the kernel's own time from a device trace of
+``--reps`` calls, microseconds a PAGE and a tile item's roofline over that.
+Section ``chunk`` puts one run that fills the buffer (``--chunk-shapes``:
+heads x rows, the two cells' steps) through both PATHS and sweeps the new
+kernel's rows of a softmax update and keys of a block (``--chunk-variants``,
+set from here: the program has no such option).  docs/kernels.md and PERF.md
+hold the readings that decided which rows take which form in ``_mla_block``
+and what ``item_pages`` gives the latent kernel.
 """
 
 import argparse
@@ -35,7 +42,8 @@ import numpy as np  # noqa: E402
 
 from deepspeed_tpu.ops.pallas import paged_attention as paged  # noqa: E402
 from deepspeed_tpu.ops.pallas.paged_attention import (  # noqa: E402
-    kernel_page_loads, paged_latent_attention)
+    chunk_page_loads, expanded_min_rows, kernel_page_loads,
+    paged_latent_attention, paged_mla_chunk_attention)
 from paged_block_bench import kernel_ms  # noqa: E402
 
 H, RANK, DN, DR, DV, ROW, BS = 128, 512, 128, 64, 128, 640, 128
@@ -109,12 +117,15 @@ def roofline_ms(slots, pos, maxb):
     return max(nbytes / PEAK_BYTES, flops / PEAK_FLOPS) * 1e3, grid, keys
 
 
-def chunk(rng, ctx, T, maxb, nb):
-    """One sequence's ``T`` rows that end a context of ``ctx`` tokens."""
+def chunk(rng, ctx, T, maxb, nb, lead=0):
+    """One sequence's rows that end a context of ``ctx`` tokens: all ``T``
+    of a buffer, or all but its first ``lead`` (dead rows, where a step has
+    its decode rows: the chunk then fills no stretch of the kernel's)."""
     tables = np.zeros((65, maxb), np.int32)
     tables[1] = rng.permutation(np.arange(1, nb))[:maxb]
-    return tables, np.ones(T, np.int32), np.arange(ctx - T, ctx,
-                                                   dtype=np.int32)
+    live = np.arange(T) >= lead
+    return tables, live.astype(np.int32), np.where(
+        live, np.arange(ctx - T, ctx), 0).astype(np.int32)
 
 
 def compiled_with(P, args, slots, pos, maxb):
@@ -132,6 +143,92 @@ def compiled_with(P, args, slots, pos, maxb):
     return fn, block
 
 
+def chunk_section(opts, dev, pages, rng, maxb, nb):
+    """``ds_paged_mla_chunk`` (the expanded form inside one kernel) on one
+    run that fills the step's buffer, beside the absorbed PATH on the same
+    rows (``q`` into the latent space, ``ds_paged_latent``, the output
+    through ``W_uv``): each kernel's own time from a device trace, each
+    path's call, and the new kernel's share of ITS roofline (a pair's 2 x
+    (nope + rope + value) operations a head, and 2 x rank x (nope + value) a
+    head for every context token a tile makes keys for)."""
+    bf16 = jnp.bfloat16
+    min_rows = expanded_min_rows(RANK, DN, DR, DV)
+    for shape in opts.chunk_shapes.split(","):
+        heads, T = (int(x) for x in shape.split("x"))
+        ks = jax.random.split(jax.random.PRNGKey(heads), 4)
+        w_uk = jax.random.normal(ks[0], (RANK, heads, DN), bf16) * RANK ** -0.5
+        w_uv = jax.random.normal(ks[1], (RANK, heads, DV), bf16) * RANK ** -0.5
+        q_n = jax.random.normal(ks[2], (T, heads, DN), bf16)
+        q_r = jax.random.normal(ks[3], (T, heads, DR), bf16)
+
+        def absorbed_path(q_n, q_r, pg, t, s, p):
+            q_lat = jnp.einsum("thn,chn->thc", q_n, w_uk)
+            q = jnp.pad(jnp.concatenate([q_lat, q_r], -1),
+                        ((0, 0), (0, 0), (0, ROW - RANK - DR)))
+            o_lat = paged_latent_attention(q, pg, t, s, p, rank=RANK,
+                                           scale=SCALE)
+            return jnp.einsum("thc,chv->thv", o_lat, w_uv)
+
+        def expanded_path(q_n, q_r, pg, t, s, p):
+            q = jnp.pad(jnp.concatenate([q_n, q_r], -1),
+                        ((0, 0), (0, 0), (0, ROW - RANK - DR)))
+            return paged_mla_chunk_attention.__wrapped__(
+                q, pg, w_uk, w_uv, t, s, p, rank=RANK, scale=SCALE,
+                min_rows=min_rows)
+
+        for ctx in (int(x) for x in opts.chunk_contexts.split(",")):
+            if ctx < T:
+                continue
+            tables, slots, pos = chunk(rng, ctx, T, maxb, nb,
+                                       opts.chunk_lead)
+            args = (q_n, q_r, pages, jnp.asarray(tables), jnp.asarray(slots),
+                    jnp.asarray(pos))
+            fn_a = jax.jit(absorbed_path).lower(*args).compile()
+            ms_a, out_a = timed(fn_a, *args)
+            own_a, _ = kernel_ms(fn_a, args, opts.reps, "ds_paged_latent")
+            out_a = np.asarray(out_a, np.float32)
+            for variant in opts.chunk_variants.split(","):
+                sub, keys = (int(x) for x in variant.split(":"))
+                saved = paged._CHUNK_SUB_ROWS, paged._CHUNK_BLOCK_KEYS
+                paged._CHUNK_SUB_ROWS, paged._CHUNK_BLOCK_KEYS = sub, keys
+                try:
+                    t0 = time.perf_counter()
+                    # (a jit of its own: the trace is cached by function)
+                    fn_e = jax.jit(lambda *a: expanded_path(*a)).lower(
+                        *args).compile()
+                    compile_s = time.perf_counter() - t0
+                    forms, pairs, loads = chunk_page_loads(
+                        slots, pos, heads=heads, block_size=BS,
+                        min_rows=min_rows)
+                finally:
+                    paged._CHUNK_SUB_ROWS, paged._CHUNK_BLOCK_KEYS = saved
+                ms_e, _ = timed(fn_e, *args)
+                own_e, out_e = kernel_ms(fn_e, args, opts.reps,
+                                         "ds_paged_mla_chunk")
+                out_e = np.asarray(out_e, np.float32)
+                tiles = -(-T // paged.chunk_tile_rows(T, min_rows)[0])
+                flops = pairs * heads * 2 * (DN + DR + DV) \
+                    + tiles * ctx * heads * 2 * RANK * (DN + DV)
+                floor = max(flops / PEAK_FLOPS,
+                            loads * BS * ROW * 2 / PEAK_BYTES) * 1e3
+                print(json.dumps({
+                    "shape": f"chunk of {T - opts.chunk_lead} rows, {heads} "
+                    f"heads, context {ctx}", "device": dev.device_kind,
+                    "rows_update:keys_block": variant,
+                    "absorbed_path_ms": round(ms_a, 3),
+                    "ds_paged_latent_ms": round(own_a, 3),
+                    "expanded_path_ms": round(ms_e, 3),
+                    "ds_paged_mla_chunk_ms": round(own_e, 3),
+                    "compile_s": round(compile_s, 2),
+                    "expanded_rows": int(forms.sum()), "expanded_keys": pairs,
+                    "expanded_pages": loads,
+                    "us_a_page": round(1e3 * own_e / loads, 4),
+                    "chunk_floor_ms": round(floor, 3),
+                    "chunk_roofline_share": round(100 * floor / own_e, 2),
+                    "max_abs_diff": float(np.abs(out_a - out_e).max()),
+                    "out_abs_max": float(np.abs(out_a).max())}), flush=True)
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--pages", default="1,2,4,8")
@@ -139,6 +236,17 @@ def main():
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--forms", type=int, default=1,
                     help="0: leave the absorbed / expanded comparison out")
+    ap.add_argument("--sections", default="forms,pages,burst,chunk",
+                    help="which parts run (chunk: ds_paged_mla_chunk)")
+    ap.add_argument("--chunk-shapes", default="128x1024,64x2048",
+                    help="heads x rows of the chunk kernel's calls")
+    ap.add_argument("--chunk-contexts", default="2048,4096,8192,16384")
+    ap.add_argument("--chunk-lead", type=int, default=0,
+                    help="dead rows before the chunk (a step's decode rows)")
+    ap.add_argument("--chunk-variants", default="1024:1024",
+                    help="rows of a softmax update : keys of a block, several "
+                    "with commas between (set from here; the program has no "
+                    "such option)")
     opts = ap.parse_args()
     ints = lambda s: [int(x) for x in s.split(",")]
     dev = jax.devices()[0]
@@ -176,7 +284,11 @@ def main():
     q_lat = jnp.pad(jnp.concatenate(
         [jnp.einsum("thn,chn->thc", q_n, w_uk), q_r], -1),
         ((0, 0), (0, 0), (0, ROW - RANK - DR)))
-    for ctx in ints(opts.contexts) if opts.forms else ():
+    sections = set(opts.sections.split(","))
+    if "chunk" in sections:
+        chunk_section(opts, dev, pages, rng, maxb, nb)
+    for ctx in ints(opts.contexts) if opts.forms and "forms" in sections \
+            else ():
         tables, slots, pos = chunk(rng, ctx, T, maxb, nb)
         p0 = ctx - T
         args = (jnp.asarray(tables), jnp.asarray(slots), jnp.asarray(pos))
@@ -198,7 +310,7 @@ def main():
     # ---- the same chunk, a long run's item on P pages (every item of a
     # one-sequence chunk is a tile item): the kernel's own time
     rule = paged.item_pages(1, ROW, bf16, BS)
-    for ctx in ints(opts.contexts):
+    for ctx in ints(opts.contexts) if "pages" in sections else ():
         tables, slots, pos = chunk(rng, ctx, T, maxb, nb)
         args = (q_lat, pages, jnp.asarray(tables), jnp.asarray(slots),
                 jnp.asarray(pos))
@@ -221,7 +333,7 @@ def main():
                 "out_abs_max": float(np.abs(base).max())}), flush=True)
 
     # ---- a decode burst's iteration: 64 sequences, one row each
-    for ctx in (2048, 6500, 12000):
+    for ctx in (2048, 6500, 12000) if "burst" in sections else ():
         tables = np.zeros((65, maxb), np.int32)
         for s in range(1, 65):
             tables[s] = rng.integers(1, nb, maxb)
