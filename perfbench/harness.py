@@ -184,6 +184,16 @@ def fold_seed(seed):
     return jax.random.fold_in(key, (seed >> 31) & 0x7FFFFFFF)
 
 
+def weights_seed(ctx):
+    """The seed the weights are made from: the configuration's
+    ``weights_seed`` where its file states one, else ``--seed``.  A routed
+    model's weights decide how many rows its held experts get, and so how much
+    work a step is: a configuration whose cells' work moved with the seed
+    states one (README.md, "What a seed decides"), and ``--seed`` then makes
+    the inputs alone."""
+    return int(ctx.config.get("weights_seed", ctx.seed))
+
+
 # ----------------------------------------------------------------- devices
 def device_record(devices):
     peak = 0

@@ -247,7 +247,7 @@ class Session:
 def run(ctx):
     import jax
     from perfbench import weights
-    from perfbench.harness import fold_seed, percentile
+    from perfbench.harness import fold_seed, percentile, weights_seed
     from perfbench.traffic_gen import RequestStream
     from deepspeed_tpu.serving import AdmissionQueueFull
 
@@ -259,7 +259,7 @@ def run(ctx):
 
     t0 = time.perf_counter()
     params = weights.seeded_weights(ctx.arch.param_shapes(model),
-                                    fold_seed(ctx.seed))
+                                    fold_seed(weights_seed(ctx)))
     jax.block_until_ready(params)
     t_weights = time.perf_counter() - t0
     n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
@@ -324,6 +324,7 @@ def run(ctx):
              warm_s=round(t_warm, 2), reference_check_s=round(t_check, 2),
              ramp_s=round(t_ramp, 2), tolerances=tols,
              tolerances_from=ctx.config_file,
+             weights_seed=weights_seed(ctx),
              compiles=ctx.compiles.summary())
 
     # ---- the timed window
@@ -338,6 +339,7 @@ def run(ctx):
     trace_at = min(2.0, ctx.seconds / 4) if ctx.trace else None
     traced = None
     raised = 0
+    n_steps = 0          # scheduler steps begun inside the window
     last_step = None     # (start, end, tokens) of the newest scheduler step
     t_window = clock()
     setup_s = t_window - ctx.t_process_start
@@ -356,6 +358,7 @@ def run(ctx):
             t_s, n_s = clock(), streamed[0]
             with spans.span("sched_step"):
                 emitted = sched.step()
+            n_steps += 1
             last_step = (t_s, clock(), streamed[0] - n_s)
             if emitted:
                 step_ms.append(1e3 * (last_step[1] - t_s))
@@ -406,6 +409,7 @@ def run(ctx):
     e2e = {"serve_tokens_per_s": rate, "setup_s": setup_s}
     ctx.info("window", window_s=window_s, completed=len(finished),
              requests_per_s=len(finished) / window_s, tokens=tokens,
+             sched_steps=n_steps,
              tokens_of_completed=sum(len(r.times) for r in finished),
              serve_tokens_per_s=rate, submitted_in_window=len(mine),
              still_waiting_for_first_token=waiting,

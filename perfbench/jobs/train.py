@@ -134,7 +134,7 @@ def reference_check(ctx, engine_losses, w0, batch, sizes, adam, tols):
 def run(ctx):
     import jax
     from perfbench import flops
-    from perfbench.harness import fold_seed
+    from perfbench.harness import fold_seed, weights_seed
 
     traffic, config = ctx.traffic, ctx.config
     n = len(ctx.devices)
@@ -145,7 +145,7 @@ def run(ctx):
     sizes = ctx.arch.reference_sizes(config, "train")
     depth = sizes["num_hidden_layers"]
     vocab = sizes["vocab_size"]
-    key = fold_seed(ctx.seed)
+    key = fold_seed(weights_seed(ctx))
     rng = np.random.default_rng([ctx.seed, 1])
     check_batch = rng.integers(0, vocab, size=(rows, seq)).astype(np.int32)
     opt = traffic["optimizer"]["params"]
@@ -199,6 +199,7 @@ def run(ctx):
              reference_s=round(t_reference, 2),
              rebuild_and_warm_s=round(t_rebuild, 2),
              tolerances=tols, tolerances_from=ctx.config_file,
+             weights_seed=weights_seed(ctx),
              compiles=ctx.compiles.summary())
 
     # ---- 4. the timed window

@@ -375,7 +375,7 @@ def test_the_cell_is_the_issues_traffic_on_one_chip():
     cell = loader.find(manifest["workloads"], CELL, "workload")
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "command_a_plus_1chip", "rag_closed32", 1)
-    assert manifest["workloads"][-1] == cell and "saturation" in cell["why"]
+    assert "saturation" in cell["why"]
     t = loader.load_json(loader.part_path(pb.ROOT, "traffic",
                                           cell["traffic"], "json"))
     assert (t["job"], t["loop"], t["sessions"]) == ("serve", "closed", 32)
@@ -393,10 +393,12 @@ def test_the_cell_is_the_issues_traffic_on_one_chip():
         m["name"] for m in manifest["end_to_end"] + manifest["per_layer"]
         if name in m.get("workloads", [name])}
     mine, chat = of(CELL), of("mistral7b_serve_chat")
+    # its own five, and the latent cells' row share, which reads 0 here
+    # (PR 51 listed it for a pin a ``benchmark`` PR alone may edit)
     assert mine - chat == {
         "serve_moe_experts_ms_per_step", "serve_moe_shared_ms_per_step",
         "serve_moe_experts_roofline_share", "serve_expert_copies_per_row",
-        "serve_window_page_share"}
+        "serve_window_page_share", "serve_expanded_row_share"}
     # one accepted metric's list is held to two cells by its own test
     assert chat - mine == {"serve_short_run_page_share"}
 

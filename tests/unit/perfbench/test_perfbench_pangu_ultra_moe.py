@@ -415,6 +415,7 @@ def test_the_cell_is_the_issues_traffic_on_one_chip():
         if name in m.get("workloads", [name])}
     mine, rag = of(CELL), of("command_a_plus_serve_rag")
     assert mine - rag == {"serve_latent_kernel_roofline_share",
+                          "serve_mla_chunk_kernel_roofline_share",
                           "serve_mla_absorb_ms_per_step",
                           "serve_mla_down_ms_per_step"}
     # their readers take intermediate_size for an expert's width, divide by

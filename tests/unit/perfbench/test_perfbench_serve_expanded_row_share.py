@@ -113,4 +113,3 @@ def test_the_manifest_lists_it_for_the_latent_cells():
         "source": "program_counter", "layer": "kernels",
         "moves": "serve_tokens_per_s", "workloads": [
             "command_a_plus_serve_rag", "pangu_ultra_moe_serve_reason", CELL]}
-    assert manifest["per_layer"][-1] == entry
