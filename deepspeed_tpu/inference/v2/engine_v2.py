@@ -252,14 +252,20 @@ class InferenceEngineV2:
                 "tensor parallelism are not implemented for it (a state row "
                 "has no head axis to shard, and no 8-bit form)")
         # and so is the COUNT of the cache's entries (a layer with two
-        # attentions states two)
+        # attentions states two; a stack run T times a token T a layer, the
+        # T of one layer in ONE buffer: ``kv_entries_a_buffer``)
+        looped = int(getattr(cfg, "kv_entries_a_buffer", 1) or 1)
+        if looped > 1 and tp > 1:
+            raise NotImplementedError(
+                "cache entries that share a buffer under tensor parallelism")
         self.kv_cache = BlockedKVCache(
             BlockedKVCache.entries_of(cfg), num_blocks, block_size,
             cfg.num_key_value_heads, getattr(cfg, "head_dim", 0),
             dtype=jnp.dtype(config.dtype), kv_dtype=self._kv_dtype,
             window_size=cfg.window_size if eva else 0,
             chunk_size=cfg.chunk_size if eva else 0, latent_dim=latent,
-            recurrent=recurrent, max_seqs=sm.max_ragged_sequence_count)
+            recurrent=recurrent, max_seqs=sm.max_ragged_sequence_count,
+            entries_a_buffer=looped)
         self.state_manager = DSStateManager(sm, self.kv_cache)
         self._budget = int(sm.max_ragged_batch_size)
         #: what the newest engine step held (``schedule_step`` or a decode
@@ -279,6 +285,8 @@ class InferenceEngineV2:
         self._kv = self.kv_cache.layers
         token_bytes, seq_bytes = self.kv_cache.bytes_by_kind()
         self._state_row_bytes = seq_bytes     # a constant of the engine
+        #: what a looped model's steps carry as ``cache_token_bytes``
+        self._cache_token_bytes = token_bytes if looped > 1 else None
         n_pages = self.kv_cache.page_layers
         logger.info(
             f"InferenceEngineV2: budget={self._budget} blocks={num_blocks}"
@@ -286,7 +294,11 @@ class InferenceEngineV2:
             f"cache={token_bytes} B/token over "
             f"{n_pages} entries of pages"
             + (f" (latent rows of {latent})" if latent else "")
-            + (f" + {seq_bytes} B/sequence over {len(self._kv) - n_pages} "
+            + (f" ({looped} passes' entries in each of {len(self._kv)} "
+               f"buffers; a block of {block_size} tokens is "
+               f"{token_bytes * block_size} B)" if looped > 1 else "")
+            + (f" + {seq_bytes} B/sequence over "
+               f"{self.kv_cache.kinds.count('state')} "
                "entries of recurrent state" if seq_bytes else ""))
 
     # ------------------------------------------------------------- put/query
@@ -501,7 +513,9 @@ class InferenceEngineV2:
         window a layer (``layer_windows``) the four are summed over ALL its
         layers' calls, and ``grid_pages_window`` / ``grid_pages_full`` are
         the loads of its window layers' and of its full layers' calls.
-        A latent cache's are :meth:`_latent_page_counts`."""
+        A latent cache's are :meth:`_latent_page_counts`.  A looped model's
+        step (several cache entries a buffer) also carries
+        ``cache_token_bytes``: one token's bytes over ALL the entries."""
         windows = getattr(self.model_config, "layer_windows", None)
         if windows is None:
             if self.kv_cache.latent_dim:
@@ -510,6 +524,9 @@ class InferenceEngineV2:
                 self.model_config, "sliding_window", 0) or 0))
             if "state" in self.kv_cache.kinds:
                 counts.update(self._state_counts(pos, slots))
+            if self._cache_token_bytes is not None:
+                counts[_names.COUNT_CACHE_TOKEN_BYTES] = \
+                    self._cache_token_bytes
             return counts
         total = dict.fromkeys(("grid_pages", "row_pages", "short_pages",
                                "block_pages", "grid_pages_window",

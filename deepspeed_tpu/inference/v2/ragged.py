@@ -34,6 +34,15 @@ block table (a block is a run of tokens in EVERY entry, so the allocator, the
 tables and the claims count blocks as before and a token's bytes are the sum
 over the entries, ``bytes_by_kind``).  ``num_layers`` below is that count.
 
+SEVERAL entries may live in ONE buffer (``entries_a_buffer``: a looped model,
+``models/ouro.py``, states ``kv_entries_a_buffer``, the passes its stack runs a
+token): entry ``t * L + l`` (pass ``t`` of layer ``l``, ``L`` buffers) is the
+pages ``[t * num_blocks, (t + 1) * num_blocks)`` of buffer ``l``, so a step
+that rolls its loop over the passes reaches pass ``t``'s entry by adding ``t
+* num_blocks`` to the block table and threads ``L`` buffers, not ``T x L``.
+A block is still a run of tokens in every entry: a token claims a row in each
+of the ``T x L``, and allocator, tables and claims count blocks as before.
+
 Entries of a SECOND KIND (``recurrent``: a model with state-space layers states
 ``recurrent_state``, ``models/jamba.py``): a ``"state"`` layer keeps no pages
 but ``(conv_state [K - 1, slots, C], ssm_state [slots, S, C])``, a row a
@@ -174,9 +183,21 @@ class BlockedKVCache:
 
     def __init__(self, num_layers, num_blocks, block_size, num_kv_heads,
                  head_dim, dtype=jnp.bfloat16, kv_dtype=None, window_size=0,
-                 chunk_size=0, latent_dim=0, recurrent=None, max_seqs=0):
+                 chunk_size=0, latent_dim=0, recurrent=None, max_seqs=0,
+                 entries_a_buffer=1):
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
+        #: entries that share one buffer, pass-major (the module docstring)
+        self.entries_a_buffer = int(entries_a_buffer or 1)
+        if int(num_layers) % self.entries_a_buffer or (
+                self.entries_a_buffer > 1 and (
+                    kv_dtype is not None or window_size or latent_dim
+                    or recurrent)):
+            raise NotImplementedError(
+                "entries_a_buffer divides the entries, and is not implemented "
+                "beside kv_cache_dtype, a window-plus-summary layout, a "
+                "latent cache or recurrent state")
+        buffers = int(num_layers) // self.entries_a_buffer
         self.kv_dtype = kv_dtype
         # window-plus-summary layout (see the module docstring); 0: every
         # token keeps its K/V for the sequence's life
@@ -204,13 +225,13 @@ class BlockedKVCache:
         #: the kind of every layer's entry: "pages" (rows a token) or
         #: "state" (a row a sequence slot: the module docstring)
         self.kinds = tuple(recurrent["kinds"]) if recurrent \
-            else ("pages", ) * int(num_layers)
+            else ("pages", ) * buffers
         if recurrent and (kv_dtype is not None or self.window_size
                           or self.latent_dim):
             raise NotImplementedError(
                 "recurrent state beside kv_cache_dtype, a window-plus-summary "
                 "layout or a latent cache")
-        if len(self.kinds) != int(num_layers) or (
+        if len(self.kinds) != buffers or (
                 recurrent and int(max_seqs) < 2):
             raise ValueError("recurrent: a kind a layer, and max_seqs slots")
         if kv_dtype is None:
@@ -229,7 +250,8 @@ class BlockedKVCache:
             raise NotImplementedError(
                 "a bfloat16 multi-query cache holds two tokens a row: an "
                 "even block_size, and no window-plus-summary layout")
-        shape = (num_blocks, self.block_size // self.token_pairs,
+        shape = (self.entries_a_buffer * self.num_blocks,
+                 self.block_size // self.token_pairs,
                  num_kv_heads * self.token_pairs, head_dim)
         #: every leaf a buffer of its own (never a view of a shared one).
         #: scale=1 for never-written positions keeps dequant a no-op on the
@@ -259,7 +281,16 @@ class BlockedKVCache:
         """How many entries keep pages (every one, but in a cache with
         recurrent state; two a layer where a layer has two attentions): what
         one call's page count is multiplied by."""
-        return self.kinds.count("pages")
+        return self.kinds.count("pages") * self.entries_a_buffer
+
+    def entry_pages(self, layers, entry):
+        """Entry ``entry``'s own pages ``[num_blocks, ...]`` of every leaf,
+        out of ``layers`` (this cache's buffers as a step returned them):
+        where several entries share a buffer, its slice of that buffer."""
+        buffers = len(layers)
+        first = entry // buffers * self.num_blocks
+        return tuple(leaf[first:first + self.num_blocks]
+                     for leaf in layers[entry % buffers])
 
     def bytes_by_kind(self):
         """``(bytes a token over the "pages" layers, bytes a sequence over

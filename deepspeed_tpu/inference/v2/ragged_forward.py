@@ -946,6 +946,100 @@ def longcat_flash_ragged_step(params, kv_data, token_ids, positions,
         [jnp.sum(counts), jnp.sum(counts > 0), sum(zero_copies)])
 
 
+# ----------------------------------------------------------------- Ouro
+@_ragged_program("ouro", step_counts=(_names.COUNT_LOOP_ROW_PASSES,
+                                      _names.COUNT_GATE_EXIT_PASSES_Q8))
+def ouro_ragged_step(params, kv_data, token_ids, positions, seq_slots,
+                     block_tables, last_token_idx, *, cfg, block_size,
+                     use_kernel=True, kv_dtype=None):
+    """One ragged engine iteration for a LOOPED model (``models/ouro.py`` has
+    the equations): the stack of ``L`` sandwich-norm layers run ``T =
+    total_ut_steps`` times over the rows, the SAME ``params[f"layers_{l}"]``
+    in every pass, the final norm between two passes (the last pass's is
+    ``_lm_head``'s), the exit gate on every pass's output but the last.
+
+    The loop over the passes is ROLLED (``lax.fori_loop``; the program holds
+    ``L`` layer bodies, not ``T x L``: a quarter of the compile time at ``T``
+    4): ``kv_data`` is one ``(k_pages, v_pages)`` a LAYER, each ``[T x
+    num_blocks, bs, Hkv, Dh]`` and the loop's carry, and pass ``t`` of layer
+    ``l`` reads and writes cache entry ``t * L + l``: the pages ``[t *
+    num_blocks, (t + 1) * num_blocks)`` of buffer ``l``, reached by adding
+    ``t * num_blocks`` to the block table (``ragged.BlockedKVCache``,
+    ``entries_a_buffer``).  A pass never reads another pass's keys.
+
+    Returns ``(logits, new kv_data, counts)``: ``counts`` (int32 ``[2]``, the
+    program's ``step_counts``) are the live rows x the passes they ran, and
+    the sum over the live rows of the pass the exit distribution expects a
+    row to leave after (``models/ouro.exit_distribution``), in 1/256ths: the
+    distribution is computed and counted, not acted on."""
+    if kv_dtype is not None:
+        raise NotImplementedError("kv_cache_dtype with a looped model's cache")
+    from ...models.ouro import exit_gate
+
+    dtype = jnp.dtype(cfg.dtype)
+    eps, Dh = cfg.rms_norm_eps, cfg.head_dim
+    T, L = cfg.total_ut_steps, cfg.num_hidden_layers
+    live = seq_slots != 0
+    # the rotary's angles from the rows' positions (the same in every pass):
+    # no table of max_position_embeddings rows in the program
+    inv = 1.0 / (cfg.rope_theta ** (jnp.arange(0, Dh, 2, dtype=jnp.float32)
+                                    / Dh))
+    ang = positions.astype(jnp.float32)[:, None] * inv[None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    turn = lambda y: _rotary(y, cos, sin, jnp.arange(y.shape[0]))
+
+    with jax.named_scope(_names.SCOPE_EMBED):
+        x = params["embed_tokens"]["embedding"][token_ids].astype(dtype)
+    blk = block_tables[seq_slots, positions // block_size]
+    off = positions % block_size
+    num_blocks = kv_data[0][0].shape[0] // T
+
+    def between(x, left, expect):
+        """After a pass but the last: its output normed, the gate on it, the
+        share of a row still in the loop (``left``), and the pass a row is
+        expected to leave after so far: the sum over ``t`` of ``t p(t)`` is
+        one plus the sum of ``left`` after each pass but the last."""
+        with jax.named_scope(_names.SCOPE_UT_PASS), \
+                jax.named_scope(_names.SCOPE_UT_NORM):
+            h = _rmsnorm(x, params["norm"]["weight"], eps)
+        with jax.named_scope(_names.SCOPE_EXIT_GATE):
+            left = left * (1.0 - exit_gate(h, params["early_exit_gate"]))
+        return h, left, expect + left
+
+    def one_pass(t, carry):
+        x, kv, left, expect = carry
+        x, left, expect = jax.lax.cond(
+            t > 0, between, lambda *kept: kept, x, left, expect)
+        shift = t * num_blocks                  # pass t's pages of a buffer
+        kv = list(kv)
+        with jax.named_scope(_names.SCOPE_UT_PASS):
+            for l in range(L):
+                lp = params[f"layers_{l}"]
+                # a post-sublayer gain is held [D / 32, 32] (models/ouro.py)
+                norm = lambda y, name: _rmsnorm(
+                    y, lp[name]["weight"].reshape(-1), eps)
+                attn_out, kv[l] = _ragged_attention_block(
+                    lp["self_attn"], norm(x, "input_layernorm"), kv[l],
+                    blk + shift, off, block_tables + shift, seq_slots,
+                    positions, None, None, cfg=cfg, block_size=block_size,
+                    rotary=turn, use_kernel=use_kernel)
+                x = x + norm(attn_out, "input_layernorm_2")
+                m = _swiglu(0, norm(x, "post_attention_layernorm"),
+                            lp["mlp"], dtype)
+                x = x + norm(m, "post_attention_layernorm_2")
+        return x, tuple(kv), left, expect
+
+    ones = jnp.ones(x.shape[:1], jnp.float32)
+    x, kv_data, _, expect = jax.lax.fori_loop(
+        0, T, one_pass, (x, tuple(kv_data), ones, ones))
+    with jax.named_scope(_names.SCOPE_EXIT_GATE):
+        counts = jnp.stack([
+            T * jnp.sum(live),
+            jnp.round(256.0 * jnp.sum(jnp.where(live, expect, 0.0)))
+            .astype(jnp.int32)])
+    return _lm_head(params, x, last_token_idx, cfg), kv_data, counts
+
+
 # ---------------------------------------------------------------- Jamba
 def _run_plan(seq_slots, positions, n_slots):
     """What the recurrent layers of a step read of its buffer, made once a
@@ -1185,7 +1279,8 @@ RAGGED_FORWARDS = {"LlamaModel": llama_ragged_step,
                    "Cohere2MoeModel": cohere2_moe_ragged_step,
                    "PanguUltraMoeModel": pangu_ultra_moe_ragged_step,
                    "LongcatFlashModel": longcat_flash_ragged_step,
-                   "JambaModel": jamba_ragged_step}
+                   "JambaModel": jamba_ragged_step,
+                   "OuroModel": ouro_ragged_step}
 
 
 def _device_sample(logits, key, temperature, top_k, top_p):

@@ -151,6 +151,17 @@ COUNT_STATE_ROWS_WRITTEN = "state_rows_written"
 COUNT_SCAN_TOKENS = "scan_tokens"
 COUNT_STATE_ROW_BYTES = "state_row_bytes"
 
+#: a LOOPED model (``models/ouro.py``: one stack of layers run several times
+#: a token): counted ON THE DEVICE, the live rows x the passes of the stack
+#: they ran (every row all of them while no row leaves the loop early), and
+#: the pass the exit gate's distribution expects a live row to leave after,
+#: summed over the live rows, in 1/256ths; and, from the batch builder, the
+#: bytes the cache keeps of ONE token over all its entries (the engine's own
+#: count of its buffers, as ``state_row_bytes`` is)
+COUNT_LOOP_ROW_PASSES = "loop_row_passes"
+COUNT_GATE_EXIT_PASSES_Q8 = "gate_exit_passes_q8"
+COUNT_CACHE_TOKEN_BYTES = "cache_token_bytes"
+
 # ---- jitted programs (``XLA Modules`` events are ``jit_<name>(<id>)``)
 PROGRAM_MICRO = "ds_micro_"               # + the micro-step variant
 PROGRAM_APPLY = "ds_apply_update"
@@ -199,6 +210,14 @@ SCOPE_SSM_SCAN = "ds.ssm_scan"            # the recurrence of either kind of
 #                                           step: the kernel ds_selective_scan
 #                                           or a burst's update of every
 #                                           slot, the state's read and write
+SCOPE_UT_PASS = "ds.ut_pass"              # serving, a looped model: ONE pass
+#                                           of the stack (all its layers);
+#                                           inside it:
+SCOPE_UT_NORM = "ds.ut_norm"              # the final norm BETWEEN two passes
+#                                           (the last pass's is ds.lm_head's)
+SCOPE_EXIT_GATE = "ds.exit_gate"          # serving, a looped model: the exit
+#                                           gate on a pass's output and the
+#                                           exit distribution's bookkeeping
 SCOPE_EVA_SUMMARY = "ds.eva_summary"      # serving: pooling the chunks a step
 #                                           completes, and their scatter
 MODULE_ATTENTION = "self_attn"            # flax module name (training)

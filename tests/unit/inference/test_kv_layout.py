@@ -22,7 +22,7 @@ from deepspeed_tpu.inference.v2 import InferenceEngineV2
 from deepspeed_tpu.inference.v2 import ragged_forward as rf
 from deepspeed_tpu.inference.v2.ragged import BlockedKVCache
 from deepspeed_tpu.models import (cohere2_moe, evabyte, falcon, jamba, llama,
-                                  longcat_flash, mixtral, opt,
+                                  longcat_flash, mixtral, opt, ouro,
                                   pangu_ultra_moe, phi)
 
 _spec = importlib.util.spec_from_file_location(
@@ -53,6 +53,7 @@ ZOO = {
     "JambaModel": (jamba.JambaModel, jamba.jamba_tiny),
     "LongcatFlashModel": (longcat_flash.LongcatFlashModel,
                           longcat_flash.longcat_flash_tiny),
+    "OuroModel": (ouro.OuroModel, lambda: ouro.ouro_tiny(dtype="float32")),
 }
 #: the models whose cache is ONE latent buffer a layer: it never was a K and
 #: a V in one stacked array, so (c) has nothing to rebuild for them
@@ -60,6 +61,11 @@ LATENT = ("PanguUltraMoeModel", "LongcatFlashModel")
 #: the models whose cache holds entries of two kinds (pages, and state rows
 #: a sequence slot): no stacked array ever held them either
 RECURRENT = ("JambaModel", )
+#: the models whose stack runs several times a token: the passes' entries of
+#: one layer share ONE K and ONE V buffer, pass-major, which no stacked array
+#: of a layer's K and V ever held (tests/unit/inference/test_ouro.py holds
+#: every entry to the reference's K and V)
+LOOPED = ("OuroModel", )
 
 
 # a recording step and a storage-rebuilding step wrap the engine's step
@@ -236,7 +242,7 @@ def _engine(name, kv_dtype):
 @pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["fp", "int8"])
 @pytest.mark.parametrize("name", list(rf.RAGGED_FORWARDS))
 def test_layout_serves_what_the_stacked_array_served(name, kv_dtype):
-    if name in ("EvaByteModel", ) + LATENT + RECURRENT \
+    if name in ("EvaByteModel", ) + LATENT + RECURRENT + LOOPED \
             and kv_dtype is not None:
         with pytest.raises(NotImplementedError, match="kv_cache_dtype"):
             _engine(name, kv_dtype)
@@ -248,6 +254,14 @@ def test_layout_serves_what_the_stacked_array_served(name, kv_dtype):
         assert [len(layer) for layer in eng._kv] == \
             [1] * BlockedKVCache.entries_of(cfg)
         assert eng._kv[0][0].shape == (40, 8, 128)
+        return
+    if name in LOOPED:
+        eng = _engine(name, kv_dtype)
+        cfg = eng.model_config      # a K and a V buffer a LAYER, each the
+        # pages of all its passes
+        assert (len(eng._kv), cfg.kv_cache_entries) == (2, 6)
+        assert [leaf.shape for leaf in eng._kv[0]] == [(3 * 40, 8, 4, 16)] * 2
+        assert eng.kv_cache.page_layers == 6
         return
     if name in RECURRENT:
         eng = _engine(name, kv_dtype)

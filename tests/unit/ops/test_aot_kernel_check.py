@@ -40,7 +40,7 @@ def test_every_kernel_compiles_for_v5e(report):
     lines = [ln for ln in r.stdout.splitlines()
              if ln.startswith(("PASS", "FAIL"))]
     assert r.returncode == 0, "\n".join(lines) + r.stderr[-1500:]
-    assert len(lines) >= 32 and all(ln.startswith("PASS") for ln in lines)
+    assert len(lines) >= 34 and all(ln.startswith("PASS") for ln in lines)
     assert "TPU v5 lite" in r.stdout
     # the run-tiled paged kernel, every branch of its item (the block of
     # pages too), at the three serving cells' shapes and their bursts': a
@@ -62,6 +62,9 @@ def test_every_kernel_compiles_for_v5e(report):
                    # 20 query heads on one KV head, a page of token pairs
                    "paged_attention(MQA 20/1, the Jamba cell)",
                    "paged_attention(MQA 20/1, the Jamba cell's burst)",
+                   # one query head a KV head, 16 heads, a burst of 9 rows
+                   "paged_attention(MHA 16/16, the Ouro cell)",
+                   "paged_attention(MHA 16/16, the Ouro cell's burst)",
                    "selective_scan(16 x 5120, 257 slots, the Jamba cell's "
                    "step)",
                    "selective_scan(16 x 5120, 257 slots, a short step)",

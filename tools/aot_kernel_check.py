@@ -302,6 +302,10 @@ def main():
             # the Jamba cell's two attention layers, and its burst
             ("MQA 20/1, the Jamba cell", 2048, 20, 1, 128, 193, 0),
             ("MQA 20/1, the Jamba cell's burst", 257, 20, 1, 128, 193, 0),
+            # ONE query head a KV head at 16 heads (tiles of 64 rows, slabs
+            # of 8): the Ouro cell's 192 calls a step, and its burst of 9 rows
+            ("MHA 16/16, the Ouro cell", 512, 16, 16, 128, 11, 0),
+            ("MHA 16/16, the Ouro cell's burst", 9, 16, 16, 128, 11, 0),
             ("MHA 32/32 x 80: per token", 64, 32, 32, 80, 16, 0),
             ("MQA 71/1 x 64: per token", 64, 71, 1, 64, 16, 0)):
         # one KV head in 16 bits: a page holds two tokens a row (ragged.py)

@@ -36,6 +36,14 @@ FAULTS = {
         "e_weights_renormalised_over_the_chosen": {"norm_topk_prob": True},
         "f_held_experts_left_out": {"held_experts_part": False},
     },
+    "ouro": {
+        "a_three_passes_instead_of_four": {"passes_run": 3},
+        "b_final_norm_between_passes_left_out": {
+            "norm_between_passes": False},
+        "c_pass_reads_the_pass_befores_entries": {"pass_reads": "previous"},
+        "d_all_passes_share_one_entry_a_layer": {"pass_reads": "last"},
+        "e_post_sublayer_norms_left_out": {"post_sublayer_norms": False},
+    },
 }
 #: the comparison's lower-precision control: the reference in the nearest
 #: precision below the one the configuration serves in, which has to come out
@@ -43,6 +51,9 @@ FAULTS = {
 CONTROLS = {
     "longcat_flash": {
         "control_weights_in_8_bits": {"weight_mantissa_bits": 3},
+    },
+    "ouro": {
+        "f_control_weights_in_8_bits": {"weight_mantissa_bits": 3},
     },
 }
 

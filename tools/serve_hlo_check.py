@@ -35,6 +35,7 @@ import math
 import os
 import re
 import sys
+import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -195,7 +196,8 @@ def programs(config, sharding=None):
         dtype=jnp.bfloat16, window_size=cfg.window_size if eva else 0,
         chunk_size=cfg.chunk_size if eva else 0,
         latent_dim=getattr(cfg, "kv_latent_dim", 0),
-        recurrent=recurrent, max_seqs=seqs).layers)
+        recurrent=recurrent, max_seqs=seqs,
+        entries_a_buffer=getattr(cfg, "kv_entries_a_buffer", 1)).layers)
     cache = jax.tree.map(lambda s: sds(s.shape, s.dtype), cache)
     maxb = 64                       # the block table's width moves no page
     i32 = lambda *shape: sds(shape, jnp.int32)
@@ -243,8 +245,10 @@ def main():
         with open(os.path.join(ROOT, path)) as f:
             config = json.load(f)
         for name, (lowered, *rest) in programs(config, sharding).items():
+            t0 = time.perf_counter()
             compiled = lowered.compile()
-            row = check(compiled, *rest)
+            row = dict(check(compiled, *rest),
+                       compile_s=round(time.perf_counter() - t0, 1))
             if args.dump:
                 os.makedirs(args.dump, exist_ok=True)
                 with open(os.path.join(args.dump, os.path.basename(path)
