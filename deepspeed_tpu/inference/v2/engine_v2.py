@@ -268,6 +268,15 @@ class InferenceEngineV2:
             entries_a_buffer=looped)
         self.state_manager = DSStateManager(sm, self.kv_cache)
         self._budget = int(sm.max_ragged_batch_size)
+        #: the fewest iterations a decode burst is launched for.  A turn of
+        #: decode rows alone runs ``max_seqs`` rows an iteration as a burst
+        #: and ``max_ragged_batch_size`` rows as a ragged step, over the same
+        #: layers and the same weights read once: with no more slots than
+        #: budget rows a burst of ONE iteration is the smaller program.  A
+        #: configuration with more slots than budget rows keeps the ragged
+        #: step for such a turn
+        self.min_burst = 1 if self.state_manager.max_seqs <= self._budget \
+            else 2
         #: what the newest engine step held (``schedule_step`` or a decode
         #: burst): the counts of ``names.SERVE_STEP_COUNTS`` that the batch
         #: builder knows; the scheduler's ``ds:serve.step`` span carries them
@@ -821,8 +830,6 @@ class InferenceEngineV2:
             return None
         k = min(cap, min(max_new_tokens - len(produced[s.uid])
                          for s in seqs))
-        if k < 2:
-            return None
         step = self._launch_burst(seqs, k, sample, temperature, top_k, top_p,
                                   seed)
         return None if step is None else self.collect_step(step)
@@ -868,40 +875,42 @@ class InferenceEngineV2:
         cap = int(self._config.decode_burst or 0)
         if cap > 1:   # an explicit call may exceed a DISABLED config, not
             k = min(k, cap)   # a configured cap
-        if not seqs or k < 2:
+        if not seqs:
             return None
         if do_sample and isinstance(rng, np.random.Generator):
             raise ValueError("burst_decode sampling needs a seed, not a "
                              "numpy Generator (device PRNG stream)")
-        # None = the KV pool can't afford a burst right now; the caller's
-        # schedule_step path defers until blocks free
         return self._launch_burst(seqs, k, do_sample, temperature, top_k,
                                   top_p, rng)
+
+    def _burst_length(self, seqs, k):
+        """The iterations a burst over ``seqs`` that was asked for ``k`` runs,
+        0 where it is not launched: the ONE statement of that rule (the
+        scheduler, ``burst_decode`` and ``generate``'s loop all come here).
+        The ask, cut to the nearest window's end (a burst, like a step, ends
+        there) and halved until the SHARED free pool affords ``k`` more
+        positions a sequence; under ``min_burst`` the caller's ragged step
+        runs (and defers where the pool is dry); else the floor power of two:
+        each distinct static ``k`` is its own compiled program, so arbitrary
+        values would compile one a remaining-token count; a power of two
+        bounds the family to log2(cap) + 1."""
+        sm = self.state_manager
+        rooms = [sm.kv_cache.run_room(s.seen_tokens) for s in seqs]
+        k = min([k] + [r for r in rooms if r is not None])
+        while k > 0 and sum(
+                max(0, sm.kv_cache.blocks_for(s.seen_tokens + k)
+                    - len(s.blocks)) for s in seqs) > sm.free_blocks:
+            k //= 2
+        return 0 if k < self.min_burst else 1 << (k.bit_length() - 1)
 
     def _launch_burst(self, seqs, k, sample, temperature, top_k, top_p,
                       seed):
         sm = self.state_manager
-        # KV-pool pressure: a burst pre-allocates k positions per sequence
-        # from the SHARED free pool — shrink k until the total new-block
-        # demand fits, falling back to the per-step scheduler (which
-        # defers) below 2
-
-        def _new_blocks(kk):
-            return sum(
-                max(0, sm.kv_cache.blocks_for(s.seen_tokens + kk)
-                    - len(s.blocks)) for s in seqs)
-
-        # a burst, like a step, ends at the nearest window's end
-        rooms = [sm.kv_cache.run_room(s.seen_tokens) for s in seqs]
-        k = min([k] + [r for r in rooms if r is not None])
-        while k >= 2 and _new_blocks(k) > sm.free_blocks:
-            k //= 2
-        if k < 2:
+        k = self._burst_length(seqs, k)
+        if not k:
+            # the pool cannot afford a burst right now (or it would be no
+            # cheaper than a step): the caller's ragged step runs
             return None
-        # quantize to the floor power of two: each distinct static k is its
-        # own compiled program, so arbitrary k values would compile per
-        # remaining-token count — pow2 bounds the variants to log2(cap)
-        k = 1 << (k.bit_length() - 1)
         n = sm.max_seqs
         with _telemetry.scope(_names.SERVE_BUILD_BATCH):
             tok0 = np.zeros(n, np.int32)
@@ -957,7 +966,7 @@ class InferenceEngineV2:
                 covers, self._launches_owed = self._launches_owed + 1, 0
             from ...profiling import cost_model
             if cost_model.capturing():
-                # k is static (pow2-quantized above), so the burst variants
+                # k is static (a power of two), so the burst variants
                 # are a bounded program family worth tabulating per k
                 cost_model.capture_jit_call(
                     f"serve/decode_burst[k={k}]", decode_burst, burst_args,

@@ -83,6 +83,7 @@ class ServingScheduler:
         self.tokens_generated = 0
         self.peak_running = 0          # max concurrently admitted sequences
         self.steps_launched_ahead = 0  # engine steps launched on top of one
+        self.bursts_of_one = 0         # decode-only turns of ONE iteration
         # (LaunchedStep, its launch time): given to the device, its tokens
         # not yet fetched; at most one between turns
         self._in_flight = None
@@ -215,11 +216,14 @@ class ServingScheduler:
 
     # ----------------------------------------------------------------- steps
     def _launch_burst(self):
-        """Launch a fused multi-token decode when EVERY in-flight sequence
-        is in pure decode (same eligibility as ``generate``'s burst path).
-        Eligibility and ``k`` are counts: a sequence's one pending token may
-        be the one the step in flight is choosing.  Returns the engine's
-        :class:`LaunchedStep` or None (ineligible / pool too tight)."""
+        """Launch a fused decode when EVERY in-flight sequence is in pure
+        decode (same eligibility as ``generate``'s burst path): such a turn
+        never runs the budget-wide ragged step, a least remainder of one is a
+        burst of ONE iteration (``engine.min_burst``).  ``k`` is at most the
+        least remainder: no row ends inside a burst.  Eligibility and ``k``
+        are counts: a sequence's one pending token may be the one the step
+        in flight is choosing.  Returns the engine's :class:`LaunchedStep` or
+        None (ineligible / pool too tight)."""
         cap = int(self.engine._config.decode_burst or 0)
         if cap < 2 or not self._running:
             return None
@@ -238,7 +242,7 @@ class ServingScheduler:
                 return None
             k = min(k, req.remaining_tokens - seq.owed)
             uids.append(req.uid)
-        if k < 2 or not uids:
+        if not uids:
             return None
         return self.engine.launch_burst(
             uids, max_tokens=k, do_sample=cfg.do_sample,
@@ -337,6 +341,13 @@ class ServingScheduler:
                 telemetry.counter("serving/steps_launched_ahead",
                                   help="engine steps launched while the one "
                                   "before was still unfetched").inc()
+            if step.burst_k == 1:
+                self.bursts_of_one += 1
+                if telemetry.enabled:
+                    telemetry.counter("serving/bursts_of_one",
+                                      help="decode-only turns run as a burst "
+                                      "of one iteration, not as a ragged step "
+                                      "at the full token budget").inc()
             for seq in step.seqs:
                 req = self._running.get(seq.uid)
                 if req is not None and req.remaining_tokens <= seq.owed:
@@ -385,7 +396,8 @@ class ServingScheduler:
         """Book engine output into request records: streaming callbacks,
         lifecycle transitions, completion + immediate flush (blocks return
         to the pool the moment a request finishes).  Burst results arrive
-        k-at-a-time from one engine call; their timestamps interpolate over
+        as a list, k-at-a-time from one engine call (a list of one from a
+        burst of one); their timestamps interpolate over
         [t_launch, now] so the TBT accounting reflects per-token cost, not
         k−1 fabricated zero gaps plus one burst-sized one.  ``launch``: the
         id of the engine step whose output this is, on the
@@ -397,9 +409,9 @@ class ServingScheduler:
             req = self._running.get(uid)
             if req is None:      # flushed between schedule and dispatch
                 continue
-            if isinstance(toks, int):
+            burst = not isinstance(toks, int)
+            if not burst:
                 toks = [toks]
-            burst = len(toks) > 1
             out = emitted.setdefault(uid, [])
             for i, tok in enumerate(toks):
                 t_tok = (now if not burst else

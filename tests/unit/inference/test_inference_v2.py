@@ -425,6 +425,62 @@ def test_decode_burst_parity_with_per_step_loop():
     assert len(eng.state_manager.tracked_sequences) == 0
 
 
+@pytest.mark.parametrize("new", [2, 8, 14])
+def test_generate_runs_a_least_remainder_of_one_as_a_burst(new):
+    """ISSUE 55: once the prompts are in, every turn of ``generate`` holds
+    decode rows alone, and each is a burst: the remainder floored to a power
+    of two, down to a burst of ONE iteration (2: 1; 8: 4, 2, 1; 14: 4, 4, 4,
+    1).  No ragged step after the prefill's, and the per-step loop's
+    tokens."""
+    model, cfg, params = _model()
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, cfg.vocab_size, size=n).tolist()
+               for n in (6, 4)]                 # one budget: one prefill step
+    ref = _v2_burst(model, params, burst=0).generate(prompts,
+                                                     max_new_tokens=new)
+    eng = _v2_burst(model, params, burst=4)
+    kinds, launch = [], eng._launch_burst
+
+    def spy(seqs, k, *a):
+        step = launch(seqs, k, *a)
+        kinds.append(step.burst_k)
+        return step
+
+    eng._launch_burst = spy
+    assert eng.generate(prompts, max_new_tokens=new) == ref
+    assert kinds == {2: [1], 8: [4, 2, 1], 14: [4, 4, 4, 1]}[new]
+    assert eng.launches == 1 + len(kinds)       # the prefill step, and them
+
+
+@pytest.mark.parametrize("ask, free, want", [
+    (1, None, 1), (2, None, 2), (3, None, 2), (7, None, 4), (16, None, 16),
+    (17, None, 16),           # the floor power of two of the ask
+    (0, None, 0), (-1, None, 0),
+    (16, 2, 8),               # halved until the pool affords it: 2 seqs x 1
+    (16, 1, 0), (1, 1, 0),    # two rows at a block's end, one block: no burst
+    (16, 0, 0), (1, 0, 0)])
+def test_the_one_rule_of_a_bursts_length(ask, free, want):
+    """``_burst_length``: what the scheduler, ``burst_decode`` and
+    ``generate``'s loop all ask.  Two sequences of 8 tokens on blocks of 8:
+    one more position takes each a new block."""
+    model, cfg, params = _model()
+    eng = _v2_burst(model, params, burst=16)
+    rng = np.random.default_rng(7)
+    eng.put([0, 1], [rng.integers(0, cfg.vocab_size, size=8).tolist()
+                     for _ in range(2)])
+    for uid, tok in eng.schedule_step().items():
+        eng.state_manager.get_sequence(uid).tokens.append(tok)
+    seqs = [eng.state_manager.get_sequence(u) for u in (0, 1)]
+    assert [(s.seen_tokens, len(s.blocks)) for s in seqs] == [(8, 1)] * 2
+    if free is not None:
+        alloc = eng.kv_cache.allocator
+        alloc.allocate(alloc.free_blocks - free)
+        assert eng.state_manager.free_blocks == free
+    assert eng._burst_length(seqs, ask) == want
+    step = eng.launch_burst([0, 1], max_tokens=ask)
+    assert (step.burst_k if step else 0) == want
+
+
 def test_decode_burst_eos_truncation_parity():
     """EOS inside a burst window: overshoot tokens must be dropped from the
     output exactly as the per-step loop would stop."""

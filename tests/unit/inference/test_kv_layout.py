@@ -275,7 +275,8 @@ def test_layout_serves_what_the_stacked_array_served(name, kv_dtype):
     rng = np.random.default_rng(28)
     vocab = ZOO[name][1]().vocab_size
     # 29 + 12 tokens cross EvaByte's first window's end (32); a budget of
-    # 12 rows cuts the prompts into slabs, then single tokens and bursts
+    # 12 rows cuts the prompts into slabs (single tokens of the short prompt
+    # beside them), then bursts: since ISSUE 55 every decode-only turn is one
     prompts = [rng.integers(1, vocab, size=n).tolist() for n in (29, 5, 17)]
     runs = []
     for stacked in (False, True):
@@ -289,7 +290,7 @@ def test_layout_serves_what_the_stacked_array_served(name, kv_dtype):
                      getattr(eng, "burst_steps", 0)))
     (logits, toks, kv, bursts), (logits_s, toks_s, kv_s, bursts_s) = runs
     assert bursts == bursts_s >= 1
-    assert len(logits) == len(logits_s) >= 6       # slabs and single tokens
+    assert len(logits) == len(logits_s) >= 5       # slabs and single tokens
     for got, want in zip(logits, logits_s):
         np.testing.assert_array_equal(got, want)
     assert toks == toks_s

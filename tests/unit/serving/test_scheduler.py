@@ -259,6 +259,10 @@ def _preset(name):
             from deepspeed_tpu.models import evabyte
             cfg = evabyte.evabyte_tiny(dtype="float32")
             model = evabyte.EvaByteModel(cfg)
+        elif name == "jamba":           # recurrent state rows a slot
+            from deepspeed_tpu.models import jamba
+            cfg = jamba.jamba_tiny()
+            model = jamba.JambaModel(cfg)
         elif name == "cohere2_moe":     # 16 experts, 8 held: device counts
             from deepspeed_tpu.models import cohere2_moe
             cfg = cohere2_moe.cohere2_moe_tiny()
@@ -383,18 +387,31 @@ def test_eos_found_while_the_next_step_is_in_flight(tiny, burst):
             sum(c["live_tokens"] for c in serial_steps) + n_eos
 
 
-def test_an_end_by_length_gets_no_row_past_its_last_token(tiny):
+@pytest.mark.parametrize("burst", [0, 8], ids=["steps", "burst"])
+def test_an_end_by_length_gets_no_row_past_its_last_token(tiny, burst):
     """(c) Known from counts: the reply has exactly the asked length and the
-    engine never ran a row for a token past it, one new token included."""
+    engine never ran a row for a token past it, one new token included.
+    With bursts on, the replies of 2 and 3 tokens END on the one token of a
+    burst of ONE iteration (ISSUE 55 (d)): no row past it, and the blocks
+    come back."""
     requests = [(p, new) for p, new in zip(_prompts(4, seed=2),
                                            (1, 2, 3, 7))]
-    sched = ServingScheduler(_engine(tiny, decode_burst=0))
+    sched = ServingScheduler(_engine(tiny, decode_burst=burst))
     got, steps = _run(sched, requests)
     assert [len(s) for s in got] == [1, 2, 3, 7]
     assert sum(c["live_tokens"] for c in steps) == \
         sum(len(p) + new - 1 for p, new in requests)
     assert got == [_engine(tiny).generate([p], max_new_tokens=n)[0]
                    for p, n in requests]
+    eng = sched.engine
+    assert eng.state_manager.free_blocks == eng.kv_cache.num_blocks - 1
+    assert not eng.state_manager.block_table.any()
+    if burst:
+        # the prompts share the first step; then every turn is decode rows
+        # alone: least remainders 1, 1, 4
+        assert [(c["kind"], c["burst_k"]) for c in steps] == [
+            ("ragged", 0), ("burst", 1), ("burst", 1), ("burst", 4)]
+        assert sched.bursts_of_one == 2
 
 
 def test_exhaustion_with_a_step_in_flight_collects_before_it_preempts(tiny):
@@ -529,3 +546,157 @@ def test_the_engine_holds_one_step_back_and_no_more(tiny):
     nxt = eng.collect_step(second)[0]
     assert [tok, nxt] == _engine(tiny).generate(
         [_prompts(1)[0]], max_new_tokens=2)[0]
+
+
+# ------------------------------------------- a decode-only turn is a burst
+# A turn whose rows are all decode rows never runs the budget-wide ragged
+# step (ISSUE 55): the least remainder, floored to a power of two, is the
+# burst's length, and a least remainder of ONE is a burst of one iteration.
+def _state_snapshot(eng):
+    """Host copies of the cache's buffers (the device's are donated to the
+    next program), one tuple an entry."""
+    return [tuple(np.array(b, copy=True) for b in entry) for entry in eng._kv]
+
+
+@pytest.mark.parametrize("name", ["llama", "jamba", "evabyte"])
+def test_a_least_remainder_of_7_runs_as_bursts_of_4_2_and_1(name):
+    """(a), (b): replies of 8 and 20 tokens whose prompts share the first
+    step: the least remainder walks through 7, 3, 1 and the turns are bursts
+    of 4, 2, 1, then the longer reply alone (12: 8, 4).  NO ragged step
+    without a prefill row.  The streams are those of the same scheduler with
+    bursts off, request for request.  A one-iteration burst writes the rows
+    of its sequences and no other: a free slot's state row (Jamba), and every
+    buffer's part that belongs to no running sequence, read as before it."""
+    vocab = _preset(name)[2]
+    rng = np.random.default_rng(4)
+    requests = [(rng.integers(1, vocab, size=9).tolist(), 8),
+                (rng.integers(1, vocab, size=11).tolist(), 20)]
+    want, plain = _run(ServingScheduler(_preset_engine(name, burst=0)),
+                       requests)
+    assert {c["kind"] for c in plain} == {"ragged"}
+
+    sched = ServingScheduler(_preset_engine(name, burst=8))
+    eng, sm = sched.engine, sched.engine.state_manager
+    streams = [[] for _ in requests]
+    for i, (prompt, new) in enumerate(requests):
+        sched.submit(prompt, max_new_tokens=new,
+                     on_token=lambda t, d, i=i: streams[i].append(t))
+    steps, checked = [], 0
+    while not sched.idle:
+        before = _state_snapshot(eng)
+        sched.step()
+        counts = eng.last_step_counts
+        steps.append((counts["kind"], counts["burst_k"],
+                      counts["prefill_tokens"]))
+        if counts["burst_k"] != 1:
+            continue
+        # what the burst of one may write: its sequences' slots and blocks
+        live = list(sm.tracked_sequences.values())
+        slots = {s.slot for s in live}
+        blocks = {b for s in live for b in s.blocks}
+        for kind, old, new_ in zip(eng.kv_cache.kinds or
+                                   ["pages"] * len(before), before,
+                                   _state_snapshot(eng)):
+            if kind == "state":         # slot 0, the dead rows', included
+                keep = [i for i in range(sm.max_seqs) if i not in slots]
+            else:                       # the garbage block is dead rows'
+                keep = [i for i in range(1, old[0].shape[0])
+                        if i not in blocks]
+            for a, b in zip(old, new_):
+                # a state entry: taps [taps, slots, ..], state [slots, ..]
+                axis = int(kind == "state" and a.shape[0] != sm.max_seqs)
+                assert np.array_equal(np.take(a, keep, axis),
+                                      np.take(b, keep, axis))
+            checked += 1
+    assert streams == want and [len(s) for s in streams] == [8, 20]
+    assert steps == [("ragged", 0, 20), ("burst", 4, 0), ("burst", 2, 0),
+                     ("burst", 1, 0), ("burst", 8, 0), ("burst", 4, 0)]
+    assert sched.bursts_of_one == 1 and checked
+    assert sm.free_blocks == eng.kv_cache.num_blocks - 1
+    assert not sm.block_table.any()
+
+
+@pytest.mark.parametrize("second", ["step", "burst_of_one"])
+def test_a_burst_of_one_on_top_of_a_step_takes_its_token_on_the_device(
+        tiny, second):
+    """(c) Launched while a step is in flight, a burst of one has no id for
+    its row on the host: it takes the token that step chose on the device
+    (``take_from``), and the host's counts (``owed``, ``seen_tokens``) read
+    as after a ragged step launched in its place."""
+    prompt = _prompts(1, seed=6)[0]
+    eng = _engine(tiny)
+    eng.put([0], [prompt])
+    seq = eng.state_manager.get_sequence(0)
+    first = eng.launch_step()
+    if second == "step":
+        nxt = eng.launch_step()
+    else:
+        nxt = eng.launch_burst([0], max_tokens=1)
+        assert nxt.burst_k == 1 and eng.last_step_counts["burst_k"] == 1
+    assert not seq.pending()        # the row's id is on the device alone
+    assert (seq.owed, seq.seen_tokens, seq.n_pending) == \
+        (2, len(prompt) + 1, 1)
+    tok = eng.collect_step(first)[0]
+    seq.tokens.append(tok)
+    got = eng.collect_step(nxt)[0]
+    if second == "step":
+        seq.tokens.append(got)
+    else:
+        got, = got                  # a burst hands a list, and appends it
+    assert (seq.owed, seq.seen_tokens, seq.n_pending) == \
+        (0, len(prompt) + 1, 1)
+    assert seq.tokens == prompt + [tok, got]
+    assert [tok, got] == _engine(tiny).generate([prompt],
+                                                max_new_tokens=2)[0]
+
+
+def test_a_dry_pool_keeps_the_ragged_step_which_defers(tiny):
+    """(e) No free block for one more position a row: no burst, not even of
+    one; the ragged step runs what the pool affords and defers the rest, as
+    before, and the streams are the roomy pool's."""
+    # blocks of 8: a prompt of 8 fills one, its first decode row needs a
+    # second; 3 requests on 4 usable blocks
+    prompts = _prompts(3, seed=8)
+    ref = _engine(tiny, decode_burst=0).generate(prompts, max_new_tokens=4)
+    eng = _engine(tiny, num_blocks=5, block_size=8)
+    sched = ServingScheduler(eng, config=dict(kv_admit_reserve_tokens=0))
+    got, steps = _run(sched, [(p, 4) for p in prompts])
+    assert got == ref
+    # the turn after the prefill holds decode rows alone and one free
+    # block for three rows that each need one
+    assert (steps[1]["kind"], steps[1]["prefill_tokens"],
+            steps[1]["live_tokens"]) == ("ragged", 0, 1)
+    assert sched.preemptions >= 1 or any(
+        c["kind"] == "ragged" and not c["prefill_tokens"] for c in steps)
+    assert eng.state_manager.free_blocks == eng.kv_cache.num_blocks - 1
+
+
+def test_host_drawn_tokens_keep_the_ragged_step(tiny):
+    """(f) Where the host draws the tokens a decode-only turn stays a ragged
+    step: no burst, of one or longer."""
+    sched = ServingScheduler(_engine(tiny), config=dict(
+        do_sample=True, temperature=0.8, top_k=20, seed=11))
+    got, steps = _run(sched, [(p, 2) for p in _prompts(2, seed=7)])
+    assert [len(s) for s in got] == [2, 2]
+    assert [(c["kind"], c["prefill_tokens"]) for c in steps] == [
+        ("ragged", 16), ("ragged", 0)]
+    assert sched.bursts_of_one == 0
+
+
+def test_more_slots_than_budget_rows_keeps_the_ragged_step(tiny):
+    """The rule rests on ``max_seqs <= token budget`` (a burst iteration is
+    ``max_seqs`` rows, a ragged step the budget's): an engine built the
+    other way round keeps the ragged step for a least remainder of one."""
+    model, _, params = tiny
+    sm = dict(max_tracked_sequences=40, max_ragged_batch_size=16,
+              max_ragged_sequence_count=32, max_context=64, block_size=8,
+              num_blocks=96)
+    eng = InferenceEngineV2(model, params=params, config=dict(
+        dtype="float32", decode_burst=8, state_manager=sm))
+    assert eng.min_burst == 2 and _engine(tiny).min_burst == 1
+    sched = ServingScheduler(eng)
+    got, steps = _run(sched, [(p, 4) for p in _prompts(2, seed=7)])
+    assert [(c["kind"], c["burst_k"]) for c in steps] == [
+        ("ragged", 0), ("burst", 2), ("ragged", 0)]
+    assert got == _engine(tiny, decode_burst=0).generate(
+        _prompts(2, seed=7), max_new_tokens=4)

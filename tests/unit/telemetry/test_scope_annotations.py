@@ -177,7 +177,7 @@ def test_serving_steps_in_a_real_trace(tiny, tmp_path):
         # ragged steps and bursts both say how many loads were short items
         # (none here: heads of 16 stay on the per-token kernel)
         assert c["short_pages"] == 0
-        assert (c["burst_k"] >= 2) == (c["kind"] == names.KIND_BURST)
+        assert (c["burst_k"] >= 1) == (c["kind"] == names.KIND_BURST)
     assert kinds == {names.KIND_RAGGED, names.KIND_BURST}
     # live_tokens is what the engine step consumed: every prompt token and
     # every generated token but each request's last went through a step
@@ -304,7 +304,10 @@ _OLD_VALUES = {
          0, 0, 0),
         (4, "burst", 4, 0, 24, 8, 0, 8, 192, 25, 0, 2, 0, 1, 85, 13, 8, 0, 0,
          0),
-        (5, "ragged", 4, 0, 32, 4, 0, 4, 256, 13, 0, 0, 0, 1, 89, 13, 8, 0,
+        # a least remainder of ONE: a burst of one iteration over the 12 slot
+        # rows since ISSUE 55 (a ragged step over the budget's 32 before:
+        # token_budget 32, grid_pages 256)
+        (5, "burst", 4, 0, 12, 4, 0, 4, 96, 13, 0, 1, 0, 1, 89, 13, 8, 0,
          0, 0),
         (6, "burst", 4, 0, 24, 2, 0, 2, 192, 12, 0, 2, 0, 1, 45, 6, 8, 0, 0,
          0)]}
@@ -694,6 +697,30 @@ def test_enabled_recorder_gets_the_serving_step_with_its_counts(
         [None, 1, 3]
     children = {e["name"] for e in events} - {e["name"] for e in steps}
     assert set(names.SERVE_STEP_CHILDREN) <= children
+
+
+def test_the_registry_counts_the_bursts_of_one(tiny, tmp_path):
+    """ISSUE 55: a reply of 4 tokens after its prompt's step: 3 left, a
+    burst of 2 and a burst of ONE, which ``serving/bursts_of_one`` counts
+    (so a deployment sees without a trace how often a decode-only turn would
+    have run the budget-wide ragged step) and the recorder's spans show as
+    ``burst_k`` 1."""
+    class Cfg:
+        trace_dir = str(tmp_path)
+
+    rec, _ = telemetry.configure(Cfg())
+    try:
+        sched = _scheduler(tiny, decode_burst=4)
+        sched.submit(list(range(1, 20)), max_new_tokens=4)
+        sched.drain()
+        events = rec.chrome_trace()["traceEvents"]
+        ones = telemetry.counter("serving/bursts_of_one").value
+    finally:
+        telemetry.shutdown()
+    steps = [e["args"] for e in events if e["cat"] == "serve"]
+    assert [(a["kind"], a["burst_k"]) for a in steps] == [
+        ("ragged", 0), ("burst", 2), ("burst", 1)]
+    assert ones == sched.bursts_of_one == 1
 
 
 # ------------------------------------------------------------------ kernels

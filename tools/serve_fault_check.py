@@ -15,6 +15,14 @@ reads; then ``CONTROLS``: the reference with its weights in 8 bits).  One JSON l
 null`` is the sound reference, which must be caught by nothing.  The faults
 are planted in the reference because it has the switches; the comparison is
 symmetric.
+
+``ENGINE_FAULTS`` (ISSUE 55) are the other way round: planted in the ENGINE,
+whatever the architecture, the check requests streamed AGAIN with each and
+judged by the sound reference (``--only burst1_...`` names them like any
+other).  Their lines also carry ``reply_lengths`` (the job's
+``serve.check_tokens_prompt*`` compares them with ``check_new_tokens``) and
+``bursts_of_one`` (0: the fault had no burst of one to sit in), and
+``error`` where the stream did not end.
 """
 
 import argparse
@@ -56,6 +64,99 @@ CONTROLS = {
         "f_control_weights_in_8_bits": {"weight_mantissa_bits": 3},
     },
 }
+
+
+def _state_rows_not_written(sched):
+    """A burst of ONE iteration leaves every recurrent state row as it
+    found it (a cache without state rows: nothing planted)."""
+    import jax.numpy as jnp
+    from deepspeed_tpu.inference.v2 import ragged_forward as rf
+    real, kinds = rf.decode_burst, sched.engine.kv_cache.kinds
+
+    def burst(params, kv, *args, k, **kw):
+        kept = [tuple(jnp.copy(b) for b in entry)
+                if k == 1 and kind == "state" else None
+                for kind, entry in zip(kinds, kv)]
+        toks, kv = real(params, kv, *args, k=k, **kw)
+        return toks, tuple(old or new for old, new in zip(kept, kv))
+
+    rf.decode_burst = burst
+    return lambda: setattr(rf, "decode_burst", real)
+
+
+def _token_in_flight_from_the_hosts_copy(sched):
+    """A burst of ONE iteration launched on top of a step in flight gives a
+    row whose token that step is choosing the newest token the HOST holds
+    (the one before it), not the device's."""
+    import numpy as np
+    eng = sched.engine
+    launch, ids, stale = eng._launch_burst, eng._ids_on_device, {}
+
+    def _launch_burst(seqs, k, *args):
+        if eng._burst_length(seqs, k) == 1:
+            stale.update({s.slot: s.tokens[-1] for s in seqs})
+        try:
+            return launch(seqs, k, *args)
+        finally:
+            stale.clear()
+
+    def _ids_on_device(toks, take_from):
+        if stale:
+            toks = np.where(take_from > 0, [stale.get(i, 0) for i in
+                                            range(len(toks))], toks)
+            take_from = np.zeros_like(take_from)
+        return ids(toks.astype(np.int32), take_from)
+
+    eng._launch_burst, eng._ids_on_device = _launch_burst, _ids_on_device
+    return lambda: None
+
+
+def _seen_tokens_advanced_twice(sched):
+    """A burst of ONE iteration counts its position twice."""
+    eng = sched.engine
+    launch = eng._launch_burst
+
+    def _launch_burst(*args):
+        step = launch(*args)
+        if step is not None and step.burst_k == 1:
+            for seq in step.seqs:
+                seq.seen_tokens += 1
+        return step
+
+    eng._launch_burst = _launch_burst
+    return lambda: None
+
+
+#: faults planted in the engine: ``plant(sched)`` returns what takes it out
+ENGINE_FAULTS = {
+    "burst1_state_rows_not_written": _state_rows_not_written,
+    "burst1_token_in_flight_from_the_hosts_copy":
+        _token_in_flight_from_the_hosts_copy,
+    "burst1_seen_tokens_advanced_twice": _seen_tokens_advanced_twice,
+}
+
+
+def streamed(serve, ctx, model, params, prompts, new, plant=None):
+    """The check requests through a fresh scheduler, with ``plant``'s fault
+    in the engine: ``(the engine's params, the streams, facts of the run)``.
+    A stream that does not end (a fault may leave a row that never runs) is
+    an ``error``, with what was streamed until then."""
+    sched = serve.build_scheduler(ctx, model, params)
+    undo = plant(sched) if plant else lambda: None
+    out = [[] for _ in prompts]
+    for i, p in enumerate(prompts):
+        sched.submit(p, max_new_tokens=new,
+                     on_token=lambda t, done, i=i: out[i].append(t))
+    facts = {}
+    try:
+        sched.drain(max_steps=50 * new)
+    except Exception as e:          # noqa: BLE001 - reported, not handled
+        facts["error"] = f"{type(e).__name__}: {e}"[:300]
+    finally:
+        undo()
+    facts.update(reply_lengths=[len(o) for o in out],
+                 bursts_of_one=getattr(sched, "bursts_of_one", None))
+    return sched.engine.params, out, facts
 
 
 def judged(serve, ref, params, sizes, prompts, produced, tols):
@@ -102,27 +203,40 @@ def main():
     sizes = arch.reference_sizes(config, "serve")
     params = weights.seeded_weights(arch.param_shapes(model),
                                     harness.fold_seed(opts.seed))
-    sched = serve.build_scheduler(ctx, model, params)
     prompts = traffic_gen.check_requests(traffic, sizes["vocab_size"],
                                          opts.seed)
     new = int(traffic["check_new_tokens"])
-    produced = serve.stream(sched, [(p, new) for p in prompts])
-    params = sched.engine.params
-    sched = None                      # the cache goes; the weights stay
-    gc.collect()
 
     runs = [(None, {})] + list(FAULTS.get(config["arch"], {}).items()) \
-        + list(CONTROLS.get(config["arch"], {}).items())
+        + list(CONTROLS.get(config["arch"], {}).items()) \
+        + list(ENGINE_FAULTS.items())
     if opts.only:
         runs = [r for r in runs if r[0] is None or r[0] in
                 opts.only.split(",")]
+    # every stream before any judging: the engine's programs are compiled
+    # once, and each scheduler's cache goes before the next is built
+    streams = {}
+    for name, plant in [(None, None)] + [r for r in runs
+                                         if r[0] in ENGINE_FAULTS]:
+        params, produced, facts = streamed(serve, ctx, model, params,
+                                           prompts, new, plant)
+        streams[name] = produced, facts
+        gc.collect()
     lines = []
     for name, change in runs:
-        rows = judged(serve, ref, params, dict(sizes, **change), prompts,
-                      produced, tols)
+        in_engine = name in ENGINE_FAULTS
+        produced, facts = streams[name if in_engine else None]
+        # a reply cut short is judged as far as it goes
+        rows = judged(serve, ref, params,
+                      sizes if in_engine else dict(sizes, **change),
+                      prompts, [toks[:new] for toks in produced], tols) \
+            if all(produced) else []
+        short = [f"serve.check_tokens_prompt{len(p)}"
+                 for p, toks in zip(prompts, produced) if len(toks) != new]
         lines.append({"fault": name, "seed": opts.seed, "checks": rows,
-                      "caught_by": [r["check"] for r in rows
-                                    if not r["pass"]]})
+                      **(facts if in_engine or name is None else {}),
+                      "caught_by": short + [r["check"] for r in rows
+                                            if not r["pass"]]})
         print(json.dumps(lines[-1]), flush=True)
         gc.collect()
         jax.clear_caches()

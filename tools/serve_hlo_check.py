@@ -171,7 +171,9 @@ def check(compiled, n_params, n_cache, page_bytes, state_shapes=()):
 
 def programs(config, sharding=None):
     """``{program name: (lowered, flat params, cache buffers, page bytes)}``
-    of the configuration's ragged step and its widest burst."""
+    of the configuration's ragged step, its widest burst and its burst of
+    ONE iteration (what a turn of decode rows alone runs where the least
+    remainder is 1: ISSUE 55)."""
     from perfbench import loader
     from deepspeed_tpu.inference.v2.ragged import BlockedKVCache
     from deepspeed_tpu.inference.v2 import ragged_forward as rf
@@ -216,13 +218,14 @@ def programs(config, sharding=None):
         for leaf in entry}
     step = step_fn.lower(params, cache, i32(budget), i32(budget), i32(budget),
                          i32(seqs, maxb), i32(seqs), **kw)
-    burst = rf.decode_burst.lower(
+    burst, burst_of_one = (rf.decode_burst.lower(
         params, cache, i32(seqs), i32(seqs), sds((seqs, ), jnp.bool_),
-        i32(seqs, maxb), step_fn=step_fn, k=int(eng["decode_burst"]), **kw,
-        **burst_kw)
+        i32(seqs, maxb), step_fn=step_fn, k=k, **kw, **burst_kw)
+        for k in (int(eng["decode_burst"]), 1))
     rest = (n_params, n_cache, page_bytes, state_shapes)
     return {step_fn.__name__: (step, ) + rest,
-            rf.decode_burst.__name__: (burst, ) + rest}
+            rf.decode_burst.__name__: (burst, ) + rest,
+            rf.decode_burst.__name__ + "[k=1]": (burst_of_one, ) + rest}
 
 
 def main():
