@@ -243,9 +243,13 @@ class LlamaAttention(nn.Module):
                         param_dtype=jnp.float32)
         qkv = partial(nn.DenseGeneral, use_bias=cfg.attention_bias,
                       dtype=dtype, param_dtype=jnp.float32)
-        q = qkv(features=(H, Dh), name="q_proj")(x)
-        k = qkv(features=(Hkv, Dh), name="k_proj")(x)
-        v = qkv(features=(Hkv, Dh), name="v_proj")(x)
+        # the block's parts under names of their own (metadata: the ops
+        # keep ``self_attn`` in their path); perfbench/train_step_trace.py
+        # reads them
+        with jax.named_scope(_names.SCOPE_ATTN_PROJ):
+            q = qkv(features=(H, Dh), name="q_proj")(x)
+            k = qkv(features=(Hkv, Dh), name="k_proj")(x)
+            v = qkv(features=(Hkv, Dh), name="v_proj")(x)
 
         cos, sin = _rope_freqs(Dh, cfg.max_position_embeddings, cfg.rope_theta,
                                cfg.rope_scaling)
@@ -268,8 +272,9 @@ class LlamaAttention(nn.Module):
             out = decode_attention(q, k, v, start,
                                    window=cfg.sliding_window)
         else:
-            q = apply_rotary(q, cos, sin)
-            k = apply_rotary(k, cos, sin)
+            with jax.named_scope(_names.SCOPE_ATTN_ROTARY):
+                q = apply_rotary(q, cos, sin)
+                k = apply_rotary(k, cos, sin)
 
             if cfg.use_ulysses and cfg.sp_backend == "ring":
                 if cfg.sliding_window:
@@ -280,27 +285,32 @@ class LlamaAttention(nn.Module):
                 # ring at native KV width (repeating first would multiply
                 # every ppermute hop's bytes by H/Hkv)
                 from ..sequence.ring_attention import RingAttention
-                out = RingAttention()(q, k, v, causal=True)
+                with jax.named_scope(_names.SCOPE_ATTN_CORE):
+                    out = RingAttention()(q, k, v, causal=True)
             elif cfg.use_ulysses:
                 # kv at NATIVE width: DistributedAttention aligns GQA
                 # inside its reshard (a2a + local group-repeat, or routed
                 # a2a) — repeating to H first would multiply the kv a2a's
                 # wire bytes by H/Hkv
                 from ..sequence.layer import DistributedAttention
-                out = DistributedAttention()(q, k, v, causal=True,
-                                             window=cfg.sliding_window)
+                with jax.named_scope(_names.SCOPE_ATTN_CORE):
+                    out = DistributedAttention()(q, k, v, causal=True,
+                                                 window=cfg.sliding_window)
             else:
                 # GQA: repeat kv heads up to H for the local core
                 if Hkv != H:
                     rep = H // Hkv
-                    k = jnp.repeat(k, rep, axis=2)
-                    v = jnp.repeat(v, rep, axis=2)
+                    with jax.named_scope(_names.SCOPE_ATTN_KV_REPEAT):
+                        k = jnp.repeat(k, rep, axis=2)
+                        v = jnp.repeat(v, rep, axis=2)
                 from ..ops.attention import attention_core
-                out = attention_core(q, k, v, causal=True,
-                                     window=cfg.sliding_window)
+                with jax.named_scope(_names.SCOPE_ATTN_CORE):
+                    out = attention_core(q, k, v, causal=True,
+                                         window=cfg.sliding_window)
 
-        out = out.reshape(B, S, H * Dh)
-        return dense(features=D, axis=-1, name="o_proj")(out)
+        with jax.named_scope(_names.SCOPE_ATTN_PROJ):
+            return dense(features=D, axis=-1, name="o_proj")(
+                out.reshape(B, S, H * Dh))
 
 
 class LlamaMLP(nn.Module):

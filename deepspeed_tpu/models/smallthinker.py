@@ -146,23 +146,29 @@ class SmallThinkerAttention(nn.Module):
                       cfg.head_dim)
         dense = partial(nn.DenseGeneral, use_bias=False, dtype=dtype,
                         param_dtype=jnp.float32)
-        q = dense(features=(H, Dh), name="q_proj")(x)
-        k = dense(features=(Hkv, Dh), name="k_proj")(x)
-        v = dense(features=(Hkv, Dh), name="v_proj")(x)
+        # the block's parts under names of their own, as LlamaAttention's
+        with jax.named_scope(_names.SCOPE_ATTN_PROJ):
+            q = dense(features=(H, Dh), name="q_proj")(x)
+            k = dense(features=(Hkv, Dh), name="k_proj")(x)
+            v = dense(features=(Hkv, Dh), name="v_proj")(x)
         if self.rotary:
             cos, sin = _rope_freqs(Dh, cfg.max_position_embeddings,
                                    cfg.rope_theta)
             cos = jnp.asarray(cos, jnp.float32)
             sin = jnp.asarray(sin, jnp.float32)
-            q = apply_rotary(q, cos, sin)
-            k = apply_rotary(k, cos, sin)
+            with jax.named_scope(_names.SCOPE_ATTN_ROTARY):
+                q = apply_rotary(q, cos, sin)
+                k = apply_rotary(k, cos, sin)
         if Hkv != H:        # repeat kv heads up to H for the local core
-            k = jnp.repeat(k, H // Hkv, axis=2)
-            v = jnp.repeat(v, H // Hkv, axis=2)
+            with jax.named_scope(_names.SCOPE_ATTN_KV_REPEAT):
+                k = jnp.repeat(k, H // Hkv, axis=2)
+                v = jnp.repeat(v, H // Hkv, axis=2)
         from ..ops.attention import attention_core
-        out = attention_core(q, k, v, causal=True, window=self.window)
-        return dense(features=D, axis=-1, name="o_proj")(
-            out.reshape(B, S, H * Dh))
+        with jax.named_scope(_names.SCOPE_ATTN_CORE):
+            out = attention_core(q, k, v, causal=True, window=self.window)
+        with jax.named_scope(_names.SCOPE_ATTN_PROJ):
+            return dense(features=D, axis=-1, name="o_proj")(
+                out.reshape(B, S, H * Dh))
 
 
 class SmallThinkerMoe(nn.Module):

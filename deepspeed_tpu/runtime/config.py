@@ -420,9 +420,6 @@ class TelemetryConfig(DeepSpeedConfigModel):
     # block on the accelerator at phase boundaries: CPU-accurate phase
     # attribution at the cost of serializing async dispatch
     fence: bool = False
-    # accepted for old configs and ignored: spans are always written as
-    # jax.profiler annotations (telemetry.scope), enabled or not
-    device_profiler: bool = False
     metrics: TelemetryMetricsConfig = TelemetryMetricsConfig()
 
 

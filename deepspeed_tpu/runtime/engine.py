@@ -1320,10 +1320,14 @@ class DeepSpeedEngine:
         def micro(params, scale, inputs):
             (_, loss), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                 params, scale, inputs)
-            grads = jax.tree_util.tree_map(
-                lambda g, s: jax.lax.with_sharding_constraint(
-                    g.astype(self.grad_accum_dtype), s),
-                grads, self.plan.grad_shardings(params))
+            # the cast to the accumulator's dtype under a name of its own:
+            # its ops have no module in their path (1.2-1.4 ms a step on the
+            # embedding's and the head's gradients, PR 56)
+            with jax.named_scope(_names.SCOPE_GRAD_CAST):
+                grads = jax.tree_util.tree_map(
+                    lambda g, s: jax.lax.with_sharding_constraint(
+                        g.astype(self.grad_accum_dtype), s),
+                    grads, self.plan.grad_shardings(params))
             return loss, grads
 
         return micro

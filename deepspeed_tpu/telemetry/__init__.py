@@ -66,10 +66,9 @@ def get_registry():
 
 def configure(cfg, monitor=None, rank=0):
     """Enable telemetry from a ``TelemetryConfig``-shaped object (duck-typed:
-    ``trace_dir``/``trace_steps``/``fence`` plus a ``metrics`` sub-object;
-    ``device_profiler`` is accepted and does nothing — :func:`scope` always
-    annotates).  Reconfiguring tears the previous instance down first.
-    Returns (recorder, registry)."""
+    ``trace_dir``/``trace_steps``/``fence`` plus a ``metrics`` sub-object).
+    Reconfiguring tears the previous instance down first.  Returns
+    (recorder, registry)."""
     global enabled, _recorder, _registry, _sinks, _endpoint, _rank
     shutdown()
     _rank = int(rank)

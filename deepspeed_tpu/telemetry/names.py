@@ -220,6 +220,19 @@ SCOPE_EXIT_GATE = "ds.exit_gate"          # serving, a looped model: the exit
 #                                           exit distribution's bookkeeping
 SCOPE_EVA_SUMMARY = "ds.eva_summary"      # serving: pooling the chunks a step
 #                                           completes, and their scatter
+# training, inside the flax module ``self_attn`` (whose ops stay the class
+# ``attention``): the attention block's parts
+SCOPE_ATTN_PROJ = "ds.attn_proj"          # the q, k, v products and the o
+#                                           product
+SCOPE_ATTN_ROTARY = "ds.attn_rotary"      # both apply_rotary calls
+SCOPE_ATTN_KV_REPEAT = "ds.attn_kv_repeat"  # K/V repeated to the query heads
+SCOPE_ATTN_CORE = "ds.attn_core"          # attention_core (or the Ulysses /
+#                                           ring layer): layout changes,
+#                                           shard_map edges, the ds_flash_*
+#                                           kernels
+SCOPE_GRAD_CAST = "ds.grad_cast"          # training: the micro-step's cast of
+#                                           the gradients to the accumulator's
+#                                           dtype (runtime/engine.py)
 MODULE_ATTENTION = "self_attn"            # flax module name (training)
 MODULE_MLP = "mlp"
 

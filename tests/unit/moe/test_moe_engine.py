@@ -262,7 +262,6 @@ def test_routed_token_accounting_in_step_records(tmp_path):
             trace_dir = str(tmp_path)
             trace_steps = 0
             fence = False
-            device_profiler = False
             metrics = None
         tel.configure(TC())
         try:

@@ -218,8 +218,7 @@ def test_see_memory_usage_routes_gauges_through_registry(monkeypatch,
             "bytes_in_use": 600, "peak_bytes_in_use": 800,
             "bytes_limit": 1000, "largest_free_block_bytes": 100})
     cfg = type("C", (), {"trace_dir": str(tmp_path), "fence": False,
-                         "device_profiler": False, "trace_steps": 0,
-                         "metrics": None})()
+                         "trace_steps": 0, "metrics": None})()
     try:
         telemetry.configure(cfg)
         see_memory_usage("snap", force=True)

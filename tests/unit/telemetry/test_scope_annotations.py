@@ -87,7 +87,6 @@ def test_scope_is_an_annotation_with_counts_and_nesting(tmp_path):
 def test_enabled_scope_books_the_same_span_in_the_recorder(tmp_path):
     class Cfg:
         trace_dir = str(tmp_path / "tel")
-        device_profiler = True          # accepted, does nothing
 
     rec, _ = telemetry.configure(Cfg())
     try:
@@ -648,8 +647,7 @@ def test_engine_steps_in_a_real_trace_and_nothing_else_when_disabled(
 
 def test_enabled_recorder_gets_the_engine_spans_under_its_phase_names(
         tmp_path):
-    tel = {"telemetry": {"enabled": True, "trace_dir": str(tmp_path),
-                         "device_profiler": True}}
+    tel = {"telemetry": {"enabled": True, "trace_dir": str(tmp_path)}}
     engine, ids = _train_engine(tel)
     try:
         with_tel = _train(engine, ids, 4)

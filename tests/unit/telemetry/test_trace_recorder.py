@@ -145,7 +145,6 @@ def test_configure_shutdown_roundtrip(tmp_path):
         trace_dir = str(tmp_path)
         trace_steps = 0
         fence = False
-        device_profiler = False
         metrics = MC()
 
     rec, reg = telemetry.configure(Cfg())
