@@ -478,13 +478,24 @@ def tp_rules(config: LlamaConfig):
     hidden-dim sharding into the activations and full-rematerialize them back
     to (dp, sp) batch/seq sharding at every norm boundary (the round-1
     "involuntary full rematerialization" warnings).  q/k/v take it on the
-    head dim, o/gate/up/down on their output dim, embed/lm_head on vocab.
+    HEADS (``(tp, "zero")`` on dim 1: every zero axis that divides the heads
+    lands there, 8 query and 2 K/V heads a chip for Mistral at dp=4) and only
+    what is left over on the head dim (the trailing ``"zero"``; an axis is
+    placed once); o/gate/up/down on their output dim, embed/lm_head on
+    vocab.  Whole heads are whole lane tiles: on a four-chip host the TPU
+    compiler windows each product over the kernel's shards and writes every
+    partial product where the shard sits, so a shard of 32 of a head's 128
+    lanes, on the dim rotary splits next, cost q and k a
+    ``dynamic-update-slice`` and two layout copies a piece (60 ms of a
+    556 ms step, PERF.md section 6, PR 57); on the heads the pieces are
+    written in place, as the MLP's are (but for K's in the first forward,
+    2 of the 8 heads its output keeps on one sublane tile: 4 ms).
     """
     tp = "tp"
     return {
-        "q_proj/kernel": P(None, tp, "zero"),
-        "k_proj/kernel": P(None, tp, "zero"),
-        "v_proj/kernel": P(None, tp, "zero"),
+        "q_proj/kernel": P(None, (tp, "zero"), "zero"),
+        "k_proj/kernel": P(None, (tp, "zero"), "zero"),
+        "v_proj/kernel": P(None, (tp, "zero"), "zero"),
         "o_proj/kernel": P(tp, "zero"),
         "gate_proj/kernel": P(None, (tp, "zero")),
         "up_proj/kernel": P(None, (tp, "zero")),

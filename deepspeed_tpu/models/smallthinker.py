@@ -279,13 +279,14 @@ class SmallThinkerModel(nn.Module):
 
 def tp_rules(config: SmallThinkerConfig):
     """Sharding rules: attention, embedding and head as Llama's (the ZeRO
-    shard on a dim that is not contracted), the experts over "ep" on the
-    expert axis as Mixtral's."""
+    shard on a dim that is not contracted: q/k/v on the heads, what does
+    not divide them on the head dim), the experts over "ep" on the expert
+    axis as Mixtral's."""
     tp = "tp"
     return {
-        "q_proj/kernel": P(None, tp, "zero"),
-        "k_proj/kernel": P(None, tp, "zero"),
-        "v_proj/kernel": P(None, tp, "zero"),
+        "q_proj/kernel": P(None, (tp, "zero"), "zero"),
+        "k_proj/kernel": P(None, (tp, "zero"), "zero"),
+        "v_proj/kernel": P(None, (tp, "zero"), "zero"),
         "o_proj/kernel": P(tp, "zero"),
         "embed_tokens/embedding": P((tp, "zero"), None),
         "lm_head/kernel": P(None, (tp, "zero")),

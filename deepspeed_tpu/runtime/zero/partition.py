@@ -380,12 +380,18 @@ class ZeroPartitionPlan:
         """Expand ``"zero"`` placeholders in a rule spec and sanitize.
 
         Rules may pin where the ZeRO shard lands with the pseudo-axis
-        ``"zero"`` (e.g. ``P(None, 'tp', 'zero')`` puts it on the head dim).
-        Placement matters beyond memory balance: ZeRO-sharding a matmul's
-        *contracting* dim (or an embedding's hidden dim) makes GSPMD
-        propagate hidden-dim sharding into the activations and then
-        involuntarily full-rematerialize them back to batch/seq sharding at
-        every norm boundary.  ``zero_axes`` is the stage-dependent expansion
+        ``"zero"`` (e.g. llama's ``P(None, ('tp', 'zero'), 'zero')`` for a
+        ``[D, H, Dh]`` kernel: at dp=4, 32 or 8 heads take the axis,
+        ``P(None, ('tp', 'dp'), None)``; 2 heads cannot, and it falls to
+        the head dim, ``P(None, 'tp', 'dp')``; never to dim 0).
+        Placement matters beyond memory balance: a shard of whole lane tiles
+        is written in place by the product the TPU compiler windows over
+        the shards, one inside a tile (or on a dim the next op splits) is
+        written piece by piece (models/llama.py ``tp_rules``); and
+        ZeRO-sharding a matmul's *contracting* dim (or an embedding's hidden
+        dim) makes GSPMD propagate hidden-dim sharding into the activations
+        and then involuntarily full-rematerialize them back to batch/seq
+        sharding at every norm boundary.  ``zero_axes`` is the stage-dependent expansion
         of the placeholder (empty → dropped): params expand it only at
         stage ≥3, master at ≥1, grads at ≥2.
 

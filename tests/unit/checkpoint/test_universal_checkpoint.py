@@ -249,3 +249,53 @@ def test_local_fp32_set_preserves_params_offload():
         "params were restored although only master was written"
     np.testing.assert_allclose(
         safe_get_local_fp32_param(engine, "layer_0/b"), w + 2.0, rtol=1e-6)
+
+
+def test_stage3_checkpoint_resumes_across_the_qkv_placement(tmp_path):
+    """A stage-3 checkpoint written while q / k / v carried their ZeRO shard
+    on the head dimension (the rules before PR 57) loads under the rules that
+    put it on the heads, on the same mesh, and the run goes on where it was:
+    the same next loss."""
+    from jax.sharding import PartitionSpec as P
+    from deepspeed_tpu.models import llama
+    from deepspeed_tpu.utils import groups
+    import deepspeed_tpu.comm as dist
+
+    cfg = llama.llama_tiny(dtype="float32", remat=False)
+    new_rules = llama.tp_rules(cfg)
+    old_rules = {**new_rules, **{f"{p}_proj/kernel": P(None, "tp", "zero")
+                                 for p in "qkv"}}
+    ids = np.random.default_rng(0).integers(
+        0, cfg.vocab_size, size=(8, 16)).astype(np.int32)
+
+    def engine_with(rules):
+        groups.initialize_mesh(dp=4, devices=jax.devices()[:4])
+        engine, _, _, _ = deepspeed_tpu.initialize(
+            model=llama.LlamaModel(cfg), tp_rules=rules,
+            config={**_config(stage=3, mb=2), "mesh": {"dp": 4}})
+        engine.initialize_parameters(0, ids, ids)
+        return engine
+
+    def q_spec(engine):
+        return engine.params["layers_0"]["self_attn"]["q_proj"][
+            "kernel"].sharding.spec
+
+    def release():
+        groups.reset_mesh()
+        dist.destroy_process_group()
+
+    a = engine_with(old_rules)
+    assert q_spec(a) == P(None, "tp", "dp")
+    _train(a, [(ids, ids)], 2)
+    ckpt = str(tmp_path / "ckpt")
+    a.save_checkpoint(ckpt)
+    expected = _train(a, [(ids, ids)], 2)
+    release()
+
+    b = engine_with(new_rules)
+    assert q_spec(b) == P(None, ("tp", "dp"), None)
+    b.load_checkpoint(ckpt)
+    assert q_spec(b) == P(None, ("tp", "dp"), None)
+    resumed = _train(b, [(ids, ids)], 2)
+    release()
+    np.testing.assert_allclose(resumed, expected, rtol=2e-5)

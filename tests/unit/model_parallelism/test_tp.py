@@ -139,6 +139,146 @@ def test_zero_placeholder_divisibility_fallback():
     assert spec2 == P(None, None, "sp") or spec2 == P(None, None, ("sp", ))
 
 
+def _model_rules(name):
+    if name == "llama":
+        return llama.tp_rules(llama.llama_tiny())
+    from deepspeed_tpu.models import smallthinker
+    return smallthinker.tp_rules(smallthinker.smallthinker_tiny())
+
+
+@pytest.mark.parametrize("proj", ["q_proj", "k_proj", "v_proj"])
+@pytest.mark.parametrize("model", ["llama", "smallthinker"])
+def test_qkv_zero_shard_lands_on_the_heads(model, proj):
+    """The models' own rules put the ZeRO shard of a ``[D, H, Dh]`` kernel on
+    the HEADS wherever the axis divides them (whole heads = whole lane tiles:
+    on four chips the compiler windows the product over the shards and
+    writes each piece where the shard sits, PERF.md section 6, PR 57); what
+    does not divide the heads falls to the head dimension, and nothing ever
+    to dimension 0, the contracting one."""
+    from jax.sharding import Mesh, PartitionSpec as P
+    from deepspeed_tpu.runtime.zero.partition import ZeroPartitionPlan
+    rules = _model_rules(model)
+    path = f"layers_0/self_attn/{proj}/kernel"
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(4, 1), ("dp", "tp"))
+    plan = ZeroPartitionPlan(3, mesh, zero_axes=("dp", ), tp_rules=rules)
+    on_heads = P(None, ("tp", "dp"), None)
+    for shape in ((64, 32, 128), (64, 8, 128)):
+        assert plan.param_spec(shape, path) == on_heads
+        assert plan.master_spec(shape, path) == on_heads
+        assert plan.grad_spec(shape, path) == on_heads
+    # two heads over four chips: the head dimension takes the axis
+    assert plan.param_spec((64, 2, 128), path) == P(None, "tp", "dp")
+    # two zero axes: the heads take what divides them, Dh the rest
+    mesh2 = Mesh(np.array(jax.devices()[:8]).reshape(4, 2, 1),
+                 ("dp", "sp", "tp"))
+    plan2 = ZeroPartitionPlan(3, mesh2, zero_axes=("dp", "sp"),
+                              tp_rules=rules)
+    assert plan2.param_spec((64, 4, 16), path) == P(None, ("tp", "dp"), "sp")
+    # stages 1 and 2: compute params keep tp alone, master (and from stage 2
+    # the gradients) expand as stage 3's
+    tp_only = P(None, "tp", None)
+    for stage in (1, 2):
+        p = ZeroPartitionPlan(stage, mesh, zero_axes=("dp", ),
+                              tp_rules=rules)
+        assert p.param_spec((64, 8, 128), path) == tp_only
+        assert p.master_spec((64, 8, 128), path) == on_heads
+        assert p.grad_spec((64, 8, 128), path) == \
+            (on_heads if stage == 2 else tp_only)
+    # one device: every zero axis has size 1, the placeholder expands to
+    # nothing and the spec is what it was before the shard moved
+    one = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("dp", "tp"))
+    plan1 = ZeroPartitionPlan(3, one, zero_axes=("dp", ), tp_rules=rules)
+    for shape in ((64, 32, 128), (64, 8, 128), (64, 2, 128)):
+        assert plan1.param_spec(shape, path) == tp_only
+        assert plan1.master_spec(shape, path) == tp_only
+
+
+def _first_loss_and_grads(stage):
+    from deepspeed_tpu.utils import safe_get_full_grad
+    cfg = llama.llama_tiny(dtype="float32", remat=False)
+    groups.initialize_mesh(dp=4, devices=jax.devices()[:4])
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=llama.LlamaModel(cfg), tp_rules=llama.tp_rules(cfg),
+        config={"train_micro_batch_size_per_gpu": 2,
+                "optimizer": {"type": "adam", "params": {"lr": 1e-3}},
+                "zero_optimization": {"stage": stage},
+                "mesh": {"dp": 4}})
+    ids = np.random.default_rng(0).integers(
+        0, cfg.vocab_size, size=(8, 16)).astype(np.int32)
+    engine.initialize_parameters(0, ids, ids)
+    loss = engine(ids, ids)
+    engine.backward(loss)
+    grads = {n: safe_get_full_grad(engine, n)
+             for n in engine.parameter_names()}
+    attn = engine.params["layers_0"]["self_attn"]
+    specs = {p: attn[p]["kernel"].sharding.spec
+             for p in ("q_proj", "k_proj", "v_proj")}
+    import deepspeed_tpu.comm as dist
+    groups.reset_mesh()
+    dist.destroy_process_group()
+    return float(loss), grads, specs
+
+
+def test_zero3_on_the_heads_matches_zero0():
+    """A tiny Llama under ZeRO-3 over four devices (q's shard on its 4 heads,
+    k's and v's 2 heads do not divide and keep it on Dh): the first loss and
+    every gradient equal the unsharded run's."""
+    from jax.sharding import PartitionSpec as P
+    loss0, grads0, _ = _first_loss_and_grads(0)
+    loss3, grads3, specs = _first_loss_and_grads(3)
+    assert specs["q_proj"] == P(None, ("tp", "dp"), None)
+    assert specs["k_proj"] == specs["v_proj"] == P(None, "tp", "dp")
+    np.testing.assert_allclose(loss3, loss0, rtol=2e-4, atol=1e-5)
+    assert grads0.keys() == grads3.keys() and len(grads0) > 10
+    for name, g in grads0.items():
+        np.testing.assert_allclose(grads3[name], g, rtol=2e-4, atol=1e-5,
+                                   err_msg=name)
+
+
+def test_hlo_dump_counts_pieces_and_collectives_by_scope():
+    """``tools/train_hlo_dump.py`` reads, under each ``ds.*`` scope of an
+    optimised HLO, the ``dynamic-update-slice`` ops that stand OUTSIDE every
+    fusion (a product written piece by piece: the four-chip cell's q and k
+    before PR 57) and the collectives (an async pair once)."""
+    import importlib.util
+    import os
+    spec = importlib.util.spec_from_file_location(
+        "train_hlo_dump", os.path.join(
+            os.path.dirname(__file__), "..", "..", "..", "tools",
+            "train_hlo_dump.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+
+    def meta(scope, proj):
+        return ('metadata={op_name="jit(micro)/layers_0/self_attn/'
+                f'{scope}/{proj}/dot_general"}}')
+    raw = "\n".join([
+        "%fused_computation.1 (p0: bf16[1,8,64]) -> bf16[1,8,64] {",
+        "  ROOT %dynamic-update-slice.9 = bf16[1,8,64]{2,1,0} "
+        "dynamic-update-slice(%p0, %p1, %c, %c, %i), "
+        + meta("ds.attn_proj", "o_proj"),
+        "}",
+        "ENTRY %main (a: bf16[8,4,16]) -> bf16[8,4,16] {",
+        "  %fusion.1 = bf16[1,8,64]{2,1,0} fusion(%a), kind=kOutput, "
+        "calls=%fused_computation.1, " + meta("ds.attn_proj", "o_proj"),
+        "  %collective-permute-start.1 = (bf16[8,4,4], bf16[8,4,4]) "
+        "collective-permute-start(%a), " + meta("ds.attn_proj", "q_proj"),
+        "  %collective-permute-done.1 = bf16[8,4,4] "
+        "collective-permute-done(%collective-permute-start.1), "
+        + meta("ds.attn_proj", "q_proj"),
+        "  %dynamic-update-slice.1 = bf16[8,4,16]{0,2,1} "
+        "dynamic-update-slice(%b, %piece, %c, %c, %i), "
+        + meta("ds.attn_proj", "q_proj"),
+        "  %all-gather.3 = bf16[8,64]{1,0} all-gather(%w), dimensions={1}, "
+        + meta("ds.lm_head_loss", "lm_head"),
+        "}"])
+    assert tool.scope_counts(raw) == {
+        "ds.attn_proj": {"instructions": 5, "dynamic_update_slice": 1,
+                         "collectives": 1},
+        "ds.lm_head_loss": {"instructions": 1, "dynamic_update_slice": 0,
+                            "collectives": 1}}
+
+
 def test_inference_tp_rules_with_zero_placeholder():
     """init_inference-style sharding must tolerate rules carrying 'zero'."""
     from jax.sharding import Mesh
@@ -170,6 +310,11 @@ def test_dataflow_parser_matches_hand_rules():
     auto = derive_tp_rules_from_dataflow(
         lambda p, x: m.apply({"params": p}, x), params, ids)
     hand = llama.tp_rules(cfg)
+    # the parser still pins a [D, H, Dh] kernel's ZeRO shard on Dh; llama's
+    # own rules moved it to the heads in PR 57 (measured on four chips; the
+    # parser's pin runs in no cell: ROADMAP.md D14)
+    from jax.sharding import PartitionSpec as P
+    hand.update({f"{p}_proj/kernel": P(None, "tp", "zero") for p in "qkv"})
     for key, spec in hand.items():
         assert auto.get(key) == spec, (key, auto.get(key), spec)
 
