@@ -30,7 +30,8 @@ import jax.numpy as jnp
 
 from ... import telemetry as _telemetry
 from ...ops.pallas.paged_attention import (chunk_page_loads,
-                                           kernel_page_loads, latent_min_rows)
+                                           kernel_page_loads, latent_min_rows,
+                                           seen_keys)
 from ...telemetry import names as _names
 from ...utils.logging import logger
 from .config_v2 import RaggedInferenceEngineConfig
@@ -525,10 +526,10 @@ class InferenceEngineV2:
         A latent cache's are :meth:`_latent_page_counts`.  A looped model's
         step (several cache entries a buffer) also carries
         ``cache_token_bytes``: one token's bytes over ALL the entries."""
+        if self.kv_cache.latent_dim:
+            return self._latent_page_counts(pos, slots)
         windows = getattr(self.model_config, "layer_windows", None)
         if windows is None:
-            if self.kv_cache.latent_dim:
-                return self._latent_page_counts(pos, slots)
             counts = self._kind_page_counts(pos, slots, int(getattr(
                 self.model_config, "sliding_window", 0) or 0))
             if "state" in self.kv_cache.kinds:
@@ -560,7 +561,12 @@ class InferenceEngineV2:
         attention's call: two a layer where the model states two).  The
         expanded kernel's are ``expanded_keys``, its rows' pairs over the
         entries, and ``expanded_pages``, the latent pages one call of it
-        brings in."""
+        brings in.  For a model that states a window a layer
+        (``layer_windows``) the page counts, the pairs and
+        ``expanded_pages`` are summed over ALL its layers' calls, a window
+        layer's rows seeing their window alone, and ``grid_pages_window`` /
+        ``grid_pages_full`` are the absorbed kernel's loads by layer kind,
+        as :meth:`_page_counts`'s."""
         cfg, kv = self.model_config, self.kv_cache
         # a burst's [k, rows] has one row a slot: its program holds the
         # absorbed kernel alone (``slot_rows``)
@@ -571,13 +577,32 @@ class InferenceEngineV2:
             slots, pos, heads=cfg.num_attention_heads,
             block_size=kv.block_size, min_rows=min_rows)
         absorbed = (slots != 0) & ~expanded
-        return {**self._kind_page_counts(pos, np.where(expanded, 0, slots), 0),
-                _names.COUNT_LATENT_KEYS: int(
-                    (pos + 1)[absorbed].sum()) * kv.page_layers,
-                _names.COUNT_ABSORBED_ROWS: int(absorbed.sum()),
-                _names.COUNT_EXPANDED_ROWS: int(expanded.sum()),
-                _names.COUNT_EXPANDED_KEYS: keys * kv.page_layers,
-                _names.COUNT_EXPANDED_PAGES: pages}
+        rows = {_names.COUNT_ABSORBED_ROWS: int(absorbed.sum()),
+                _names.COUNT_EXPANDED_ROWS: int(expanded.sum())}
+        absorbed_slots = np.where(expanded, 0, slots)
+        windows = getattr(cfg, "layer_windows", None)
+        if windows is None:
+            return {**self._kind_page_counts(pos, absorbed_slots, 0), **rows,
+                    _names.COUNT_LATENT_KEYS: int(
+                        seen_keys(pos)[absorbed].sum()) * kv.page_layers,
+                    _names.COUNT_EXPANDED_KEYS: keys * kv.page_layers,
+                    _names.COUNT_EXPANDED_PAGES: pages}
+        total = dict.fromkeys(("grid_pages_window", "grid_pages_full"), 0)
+        for window in sorted(set(windows)):
+            layers = windows.count(window)
+            kind = self._kind_page_counts(pos, absorbed_slots, window)
+            _, keys, pages = chunk_page_loads(
+                slots, pos, heads=cfg.num_attention_heads,
+                block_size=kv.block_size, min_rows=min_rows, window=window)
+            kind.update({_names.COUNT_LATENT_KEYS: int(
+                             seen_keys(pos, window)[absorbed].sum()),
+                         _names.COUNT_EXPANDED_KEYS: keys,
+                         _names.COUNT_EXPANDED_PAGES: pages})
+            for name, n in kind.items():
+                total[name] = total.get(name, 0) + layers * n
+            total["grid_pages_window" if window else "grid_pages_full"] += \
+                layers * kind["grid_pages"]
+        return {**total, **rows}
 
     def _state_counts(self, pos, slots):
         """What a step's recurrent layers do, summed over them: the state

@@ -700,12 +700,13 @@ _ABSORBED_STRETCH_ROWS = 256
 
 
 def _latent_attention(q, pages, block_tables, seq_slots, positions,
-                      block_size, *, rank, scale, use_kernel=True):
+                      block_size, *, rank, scale, use_kernel=True, window=0):
     """Absorbed multi-head latent attention over the paged latent cache: q
     ``[T, H, row]`` (``(q_lat [rank] ; q_r ; zeros)`` a head, as long as the
     cache's row), pages ``[num_blocks, bs, row]`` -> ``[T, H, rank]``, the
     softmax-weighted sum of the first ``rank`` values of the rows at
-    positions ``<=`` the query's.  On a TPU the Pallas ``ds_paged_latent``
+    positions ``<=`` the query's (the last ``window`` of them where the layer
+    has a window).  On a TPU the Pallas ``ds_paged_latent``
     (``ops/pallas/paged_attention.paged_latent_attention``), elsewhere and
     for a shape it does not take an XLA gather of each row's block run."""
     from ...ops._use_kernels import use_pallas_kernels
@@ -714,21 +715,41 @@ def _latent_attention(q, pages, block_tables, seq_slots, positions,
     if use_kernel and use_pallas_kernels() and latent_tiled(
             q.shape[1], pages.dtype):
         return paged_latent_attention(q, pages, block_tables, seq_slots,
-                                      positions, rank=rank, scale=scale)
+                                      positions, rank=rank, scale=scale,
+                                      window=window)
     tables_t = block_tables[seq_slots]
     T, ctx = q.shape[0], tables_t.shape[1] * block_size
     rows = pages[tables_t].reshape(T, ctx, -1).astype(jnp.float32)
     scores = jnp.einsum("thl,tcl->thc", q.astype(jnp.float32), rows) * scale
     mask = jnp.arange(ctx)[None, None, :] <= positions[:, None, None]
+    if window:
+        mask &= jnp.arange(ctx)[None, None, :] > \
+            positions[:, None, None] - window
     probs = jax.nn.softmax(
         jnp.where(mask, scores, jnp.finfo(jnp.float32).min), axis=-1)
     return jnp.einsum("thc,tcr->thr", probs, rows[..., :rank]).astype(q.dtype)
 
 
+@jax.named_scope(_names.SCOPE_DIFF_ATTN)
+def _differential(o, lam):
+    """Grouped differential attention's subtraction on the heads' OUTPUTS: ``o
+    [n, H, w]`` holds, K/V group by K/V group, the group's signal heads and
+    then its ONE noise head; ``lam [n, S]`` (float32) one factor a signal
+    head.  Returns ``[n, S, w]``: ``o_s - lam_s o_noise(group of s)``.  It is
+    linear along ``w``, so it may be taken before an up-projection that the
+    group's heads share."""
+    n, H, w = o.shape
+    per_group = H // (H - lam.shape[1])        # heads a group, the noise head too
+    o = o.reshape(n, -1, per_group, w).astype(jnp.float32)
+    out = o[:, :, :-1] - lam.reshape(n, -1, per_group - 1, 1) * o[:, :, -1:]
+    return out.reshape(n, lam.shape[1], w)
+
+
 @jax.named_scope(_names.SCOPE_ATTENTION)
 def _mla_block(attn, h, kv_layer, blk, off, block_tables, seq_slots,
                positions, *, cfg, block_size, use_kernel, q_scale=1.0,
-               kv_scale=1.0, slot_rows=False):
+               kv_scale=1.0, slot_rows=False, window=0, lam=None,
+               out_gate=None):
     """Multi-head latent attention of one cache entry over the ragged buffer
     (``models/pangu_ultra_moe.py`` has the equations): the latent row ``(c ;
     k_r)`` of each token goes into the entry's one cache buffer, and a row
@@ -744,7 +765,18 @@ def _mla_block(attn, h, kv_layer, blk, off, block_tables, seq_slots,
     per-head key or value is kept, in the cache or in HBM.  ``q_scale`` /
     ``kv_scale``: ``mla_down``'s.  ``slot_rows``: the buffer has ONE row a
     slot (a burst's): every run is one row, and the program holds the
-    absorbed kernel alone.  Returns (attn_out [T, D], new kv_layer)."""
+    absorbed kernel alone.  Returns (attn_out [T, D], new kv_layer).
+
+    What grouped differential attention over a latent cache adds
+    (``models/motif.py``), each by an argument whose default leaves the
+    block as it was.  ``window``: the layer's sliding window, in both forms.
+    ``k_b_proj`` / ``v_b_proj`` of FEWER heads than the query's: K/V groups,
+    each read by ``H / G`` adjacent query heads.  ``lam [T, S]``: a group's
+    last head is its noise head, whose output is subtracted from the group's
+    signal heads' (:func:`_differential`): in the absorbed form on the LATENT
+    outputs, before the up-projection through the group's one ``W_uv``, so a
+    noise head's up-projection is never computed.  ``out_gate [T, S * dv]``
+    multiplies the heads' outputs before ``o_proj``."""
     from ...models.pangu_ultra_moe import mla_down
     from ...ops._use_kernels import use_pallas_kernels
     from ...ops.pallas.paged_attention import (
@@ -762,26 +794,42 @@ def _mla_block(attn, h, kv_layer, blk, off, block_tables, seq_slots,
     w_uk = attn["k_b_proj"]["kernel"].astype(dtype)
     w_uv = attn["v_b_proj"]["kernel"].astype(dtype)
     T = h.shape[0]
+    groups = w_uk.shape[1]
+    # one product a head where every head has its own W_uk / W_uv: the
+    # grouped product at one head a group is the same mathematics, and
+    # compiles to one more relayout ([T, H, 1, dv] -> [T, H, dv]) in a burst
+    grouped = groups != cfg.num_attention_heads
+    by_group = lambda a: a.reshape(a.shape[0], groups, -1, a.shape[-1])
+    heads = lambda a: a.reshape(a.shape[0], -1, a.shape[-1])
     # None: no row of a buffer this short, or of this shape, is expanded
     min_rows = latent_min_rows(cfg, pages.shape[-1], pages.dtype, T) \
         if use_kernel and use_pallas_kernels() and not slot_rows else None
 
-    def absorbed(q_n, q_r, slots, positions):
+    def absorbed(q_n, q_r, slots, positions, *lam):
         """The absorbed form of the rows given (the buffer's, or a stretch
-        of them): ``[n, H, v_head_dim]``, zero where ``slots`` is 0."""
+        of them): ``[n, heads out, v_head_dim]``, zero where ``slots`` is
+        0."""
         with jax.named_scope(_names.SCOPE_MLA_ABSORB):
-            q_lat = jnp.einsum("thn,chn->thc", q_n, w_uk)
+            q_lat = heads(jnp.einsum("tgqn,cgn->tgqc", by_group(q_n), w_uk)) \
+                if grouped else jnp.einsum("thn,chn->thc", q_n, w_uk)
             q = jnp.pad(jnp.concatenate([q_lat, q_r], axis=-1),
                         ((0, 0), (0, 0), (0, spare)))
         o_lat = _latent_attention(q, pages, block_tables, slots, positions,
                                   block_size, rank=rank,
                                   scale=cfg.softmax_scale,
-                                  use_kernel=use_kernel)
+                                  use_kernel=use_kernel, window=window)
+        if lam:
+            o_lat = _differential(o_lat, *lam).astype(dtype)
         with jax.named_scope(_names.SCOPE_MLA_ABSORB):
+            if grouped:
+                return heads(jnp.einsum("tgqc,cgv->tgqv", by_group(o_lat),
+                                        w_uv))
             return jnp.einsum("thc,chv->thv", o_lat, w_uv)
 
+    lam = () if lam is None else (lam, )
+
     if min_rows is None:
-        o = absorbed(q_n, q_r, seq_slots, positions)
+        o = absorbed(q_n, q_r, seq_slots, positions, *lam)
     else:
         # the absorbed form's three products are owed by ITS rows alone: a
         # stretch of the buffer that holds none of them (a chunk's rows, dead
@@ -791,19 +839,28 @@ def _mla_block(attn, h, kv_layer, blk, off, block_tables, seq_slots,
         n = T // _ABSORBED_STRETCH_ROWS if T % _ABSORBED_STRETCH_ROWS == 0 \
             else 1
         stretches = lambda a: a.reshape((n, T // n) + a.shape[1:])
-        shape = (T // n, q_n.shape[1], w_uv.shape[-1])
+        shape = (T // n, lam[0].shape[1] if lam else q_n.shape[1],
+                 w_uv.shape[-1])
         o = jax.lax.map(
             lambda rows: jax.lax.cond(
                 jnp.any(rows[2] != 0), lambda: absorbed(*rows),
                 lambda: jnp.zeros(shape, dtype)),
-            tuple(map(stretches, (q_n, q_r, slots, positions)))) \
+            tuple(map(stretches, (q_n, q_r, slots, positions) + lam))) \
             .reshape((T, ) + shape[1:])
         q = jnp.pad(jnp.concatenate([q_n, q_r], axis=-1),
                     ((0, 0), (0, 0), (0, spare)))
-        o = o + paged_mla_chunk_attention(
+        expanded = paged_mla_chunk_attention(
             q, pages, w_uk, w_uv, block_tables, seq_slots, positions,
-            rank=rank, scale=cfg.softmax_scale, min_rows=min_rows)
-    o = o.reshape(o.shape[0], -1) @ attn["o_proj"]["kernel"].astype(dtype)
+            rank=rank, scale=cfg.softmax_scale, min_rows=min_rows,
+            window=window)
+        if lam:
+            expanded = _differential(expanded, *lam).astype(dtype)
+        o = o + expanded
+    o = o.reshape(o.shape[0], -1)
+    if out_gate is not None:
+        with jax.named_scope(_names.SCOPE_DIFF_ATTN):
+            o = o * out_gate
+    o = o @ attn["o_proj"]["kernel"].astype(dtype)
     return o, (pages, )
 
 
@@ -1038,6 +1095,93 @@ def ouro_ragged_step(params, kv_data, token_ids, positions, seq_slots,
             jnp.round(256.0 * jnp.sum(jnp.where(live, expect, 0.0)))
             .astype(jnp.int32)])
     return _lm_head(params, x, last_token_idx, cfg), kv_data, counts
+
+
+# ---------------------------------------------------------------- Motif
+@_ragged_program("motif", step_counts=(
+    _names.COUNT_EXPERT_COPIES, _names.COUNT_EXPERT_ACTIVE), slot_rows=True)
+def motif_ragged_step(params, kv_data, token_ids, positions, seq_slots,
+                      block_tables, last_token_idx, *, cfg, block_size,
+                      use_kernel=True, kv_dtype=None, slot_rows=False):
+    """One ragged engine iteration for Motif-3 (``models/motif.py`` has the
+    equations): the residual of a row is ``mhc_expansion_rate`` STREAMS ``[T,
+    n, D]`` (bfloat16 between sublayers); each sublayer, attention and then
+    the feed-forward, reads a learned mix of them and writes back through
+    two more (``mhc_sublayer``: the three mappings and the Sinkhorn sweeps in
+    float32).  Attention is grouped differential attention over the LATENT
+    cache (``_mla_block`` with the layer's window, K/V groups, the
+    subtraction and the output gate; ``kv_data``: one ``(pages, )`` a layer),
+    the feed-forward PolyNorm-gated: dense in the leading layers, then the
+    held experts' part of the scaled routed sum beside the shared expert, as
+    ``pangu_ultra_moe_ragged_step``'s.  Every layer keeps every token in ONE
+    block table: a window layer's pages past its window are held and not
+    read.  ``slot_rows``: ``_mla_block``'s.
+
+    Returns ``(logits, new kv_data, counts)``; ``counts`` as
+    ``cohere2_moe_ragged_step``'s, over the routed layers."""
+    if kv_dtype is not None:
+        raise NotImplementedError("kv_cache_dtype with a latent cache")
+    from ...models.motif import (diff_gates, gated_poly, mhc_sublayer,
+                                 mla_view, moe_layer)
+    from ...ops._use_kernels import use_pallas_kernels
+
+    dtype = jnp.dtype(cfg.dtype)
+    eps = cfg.rms_norm_eps
+    live = seq_slots != 0
+    gmm_kernel = use_kernel and use_pallas_kernels()
+
+    with jax.named_scope(_names.SCOPE_EMBED):
+        x = params["embed_tokens"]["embedding"][token_ids].astype(dtype)
+        X = jnp.repeat(x[:, None], cfg.mhc_expansion_rate, axis=1)
+    blk = block_tables[seq_slots, positions // block_size]
+    off = positions % block_size
+
+    kv_data = list(kv_data)
+    counts = []
+    for l, window in enumerate(cfg.layer_windows):
+        lp = params[f"layers_{l}"]
+
+        def attention(h):
+            attn = mla_view(lp["self_attn"], dtype)
+            with jax.named_scope(_names.SCOPE_ATTENTION):
+                lam, gate = diff_gates(h, attn)
+            out, kv_data[l] = _mla_block(
+                attn, h, kv_data[l], blk, off, block_tables, seq_slots,
+                positions, cfg=cfg, block_size=block_size,
+                use_kernel=use_kernel, slot_rows=slot_rows, window=window,
+                lam=lam, out_gate=gate)
+            return out
+
+        def feed_forward(h):
+            with jax.named_scope(_names.SCOPE_MLP):
+                if not cfg.routed(l):
+                    return gated_poly(
+                        h, *(lp["mlp"][f"{n}_proj"]["kernel"].astype(dtype)
+                             for n in ("gate", "up", "down")),
+                        lp["mlp"]["poly"], cfg)
+                with jax.named_scope(_names.SCOPE_MOE_ROUTER):
+                    router_logits = h.astype(jnp.float32) @ lp["moe"][
+                        "gate"]["kernel"].astype(jnp.float32)
+                m, landed = moe_layer(h, router_logits, lp["moe"], cfg,
+                                      live=live, kernel=gmm_kernel)
+            counts.append(landed)
+            return m
+
+        X = mhc_sublayer(X, lp["attn_mhc"], lp["input_layernorm"]["weight"],
+                         attention, cfg)
+        X = mhc_sublayer(X, lp["mlp_mhc"],
+                         lp["post_attention_layernorm"]["weight"],
+                         feed_forward, cfg)
+
+    with jax.named_scope(_names.SCOPE_LM_HEAD):
+        xl = jnp.sum(X[last_token_idx].astype(jnp.float32), axis=1)
+        xl = _rmsnorm(xl.astype(dtype), params["norm"]["weight"], eps)
+        logits = jnp.einsum("td,dv->tv", xl,
+                            params["lm_head"]["kernel"].astype(dtype),
+                            preferred_element_type=jnp.float32)
+    counts = jnp.stack(counts)                        # [routed layers, held]
+    return logits, tuple(kv_data), jnp.stack(
+        [jnp.sum(counts), jnp.sum(counts > 0)])
 
 
 # ---------------------------------------------------------------- Jamba
@@ -1280,7 +1424,8 @@ RAGGED_FORWARDS = {"LlamaModel": llama_ragged_step,
                    "PanguUltraMoeModel": pangu_ultra_moe_ragged_step,
                    "LongcatFlashModel": longcat_flash_ragged_step,
                    "JambaModel": jamba_ragged_step,
-                   "OuroModel": ouro_ragged_step}
+                   "OuroModel": ouro_ragged_step,
+                   "MotifModel": motif_ragged_step}
 
 
 def _device_sample(logits, key, temperature, top_k, top_p):

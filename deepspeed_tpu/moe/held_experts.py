@@ -112,10 +112,12 @@ def grouped_matmul(x_sorted, w, group_sizes, kernel=False):
 
 
 def grouped_swiglu(x_sorted, group_sizes, w1, w2, w3, kernel=False,
-                   act=jax.nn.silu):
+                   act=jax.nn.silu, row_coef=None):
     """The gated feed-forward of each group's expert over rows sorted by
     group: ``w2 (act(w1 x) * w3 x)``, SwiGLU with the default ``act``, ReGLU
-    with ``jax.nn.relu``.
+    with ``jax.nn.relu``; with ``row_coef [C, n]`` (each row's own expert's
+    coefficients) ``act`` is a ROW-wise function ``act(gate, row_coef)``
+    between the grouped products (a normalising activation: PolyNorm).
 
     x_sorted: [C, D] (group g's rows contiguous, rows past ``sum(group_sizes)``
     in no group: mask what comes back for them); group_sizes: [E]; w1/w3:
@@ -123,19 +125,22 @@ def grouped_swiglu(x_sorted, group_sizes, w1, w2, w3, kernel=False,
     [C, D]."""
     gate = grouped_matmul(x_sorted, w1, group_sizes, kernel)
     up = grouped_matmul(x_sorted, w3, group_sizes, kernel)
-    return grouped_matmul(act(gate) * up, w2, group_sizes, kernel)
+    gate = act(gate) if row_coef is None else act(gate, row_coef)
+    return grouped_matmul(gate * up, w2, group_sizes, kernel)
 
 
-def padded_swiglu(x_blocks, w1, w2, w3, act=jax.nn.silu):
+def padded_swiglu(x_blocks, w1, w2, w3, act=jax.nn.silu, coef=None):
     """:func:`grouped_swiglu` over PER-EXPERT PADDED BLOCKS: three batched
     dense products over the expert axis, under a gradient six more of the
     same kind.  x_blocks: [E, R, D], expert e's rows in block e and rows of
     zeros after them (zeros in, zeros out: ``act(0) * 0 = 0``, and a row of
-    zeros gives no weight a gradient); w1/w3: [E, D, I]; w2: [E, I, D].
-    Returns [E, R, D]."""
+    zeros gives no weight a gradient); w1/w3: [E, D, I]; w2: [E, I, D];
+    ``coef [E, n]``: :func:`grouped_swiglu`'s ``row_coef``, an expert's for
+    every row of its block.  Returns [E, R, D]."""
     gate = jnp.einsum("erd,edi->eri", x_blocks, w1)
     up = jnp.einsum("erd,edi->eri", x_blocks, w3)
-    return jnp.einsum("eri,eid->erd", act(gate) * up, w2)
+    gate = act(gate) if coef is None else act(gate, coef[:, None])
+    return jnp.einsum("eri,eid->erd", gate * up, w2)
 
 
 def tier_rows(tokens, k, held, experts):
@@ -223,8 +228,8 @@ from_blocks.defvjp(lambda y, w, moves: (from_blocks(y, w, moves),
 
 
 @functools.partial(jax.jit, static_argnames=("rows", "act"))
-def _blocks_layer(x, topw, w1, w2, w3, here, key, order, counts, *, rows,
-                  act):
+def _blocks_layer(x, topw, w1, w2, w3, here, key, order, counts, coef=None,
+                  *, rows, act):
     """:func:`held_experts_apply`'s layer over ``H`` padded blocks of ``rows``
     slots: slot ``(e, j)`` is expert e's j-th sorted copy, a slot past its
     copies a row of zeros.  Rows move by GATHERS alone, forward and backward
@@ -243,13 +248,13 @@ def _blocks_layer(x, topw, w1, w2, w3, here, key, order, counts, *, rows,
     moves = (copy.reshape(-1), valid.reshape(-1),
              jnp.where(here, slot.reshape(T, k), 0), here)
     y = padded_swiglu(to_blocks(x, moves).reshape(H, rows, D), w1, w2, w3,
-                      act)
+                      act, coef)
     return from_blocks(y.reshape(-1, D), topw.astype(jnp.float32), moves)
 
 
 def held_experts_apply(x, topi, topw, w1, w2, w3, *, first_expert=0,
                        experts=None, live=None, kernel=False,
-                       act=jax.nn.silu):
+                       act=jax.nn.silu, act_coef=None):
     """The held experts' part of a top-k expert layer, exact.
 
     x: [T, D]; topi/topw: [T, k] each token's experts (ids over the router's
@@ -262,7 +267,9 @@ def held_experts_apply(x, topi, topw, w1, w2, w3, *, first_expert=0,
     :func:`padded_swiglu`, taken when the fullest expert's copies fit one;
     the worst case's buffer behind the ``lax.cond`` keeps ``ragged_dot``,
     which the chip's readings put ahead there; ``act``: the gate's
-    activation (:func:`grouped_swiglu`).  Returns
+    activation (:func:`grouped_swiglu`), element-wise, or with ``act_coef
+    [H, n]`` (one set of coefficients a held expert) row-wise and told each
+    copy's expert: ``act(gate, that expert's coefficients)``.  Returns
     ``(out [T, D] in x's type, counts [H] int32)``: the weighted sum over
     each row's experts that are held, and the copies that landed on each held
     expert."""
@@ -290,8 +297,11 @@ def held_experts_apply(x, topi, topw, w1, w2, w3, *, first_expert=0,
             # result before its weight multiplies it (the weight's gradient
             # is the result)
             in_group = (jnp.arange(rows) < landed)[:, None]
+            # a sorted copy's expert is its key (a row in no group: the last)
+            row_coef = None if act_coef is None else \
+                act_coef[jnp.minimum(key[copy], H - 1)]
             y = grouped_swiglu(jnp.where(in_group, x[token_of], 0), counts,
-                               w1, w2, w3, kernel, act)
+                               w1, w2, w3, kernel, act, row_coef)
             w = weights[copy].astype(y.dtype)
             y = jnp.where(in_group, y, 0) * w[:, None]
             return jnp.zeros((T, D), y.dtype).at[token_of].add(y)
@@ -309,6 +319,6 @@ def held_experts_apply(x, topi, topw, w1, w2, w3, *, first_expert=0,
         out = jax.lax.cond(
             in_blocks(counts, T, k, experts),
             lambda _: _blocks_layer(x, topw, w1, w2, w3, here, key, order,
-                                    counts, rows=rows, act=act),
+                                    counts, act_coef, rows=rows, act=act),
             part(T * k, False), None)
     return out.astype(x.dtype), counts

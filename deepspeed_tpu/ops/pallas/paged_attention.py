@@ -563,7 +563,7 @@ def paged_attention(q, k_cache, v_cache, block_tables, seq_slots, positions,
 def _latent_kernel(tables_ref, slot_ref, first_ref, npages_ref, slab_ref,
                    nblocks_ref, long_ref, total_ref, q_ref, pos_ref, rid_ref,
                    c_hbm, o_ref, c_buf, sem, acc_ref, m_ref, l_ref, *, tq,
-                   block_size, maxb, scale, rank, block):
+                   block_size, maxb, scale, rank, block, window=0):
     """One Q tile of the latent cache's reader: ``q_ref [1, M, L]`` (``M =
     tq * heads`` rows, row ``t * heads + h``, each ``(q_lat [rank] ; q_r)``
     in the cache's type), ``pos_ref``/``rid_ref [1, M, 1]``, against the
@@ -576,7 +576,9 @@ def _latent_kernel(tables_ref, slot_ref, first_ref, npages_ref, slab_ref,
     its run's slab of rows, or (-1) the tile.  A tile none of whose runs
     takes the whole tile (``long_ref``: a burst's every tile) walks its
     items in a loop of its own: beside the block item in one loop body a
-    slab item costs 7 % more (docs/kernels.md)."""
+    slab item costs 7 % more (docs/kernels.md).  ``window``: a row sees the
+    last ``window`` positions alone (0: all of them); its run's items start
+    at the first page one of its rows sees (:func:`run_plan`)."""
     i = pl.program_id(0)
     base = i * tq
     total = total_ref[i]
@@ -626,6 +628,8 @@ def _latent_kernel(tables_ref, slot_ref, first_ref, npages_ref, slab_ref,
         col = (first_ref[base + k] + p) * block_size + \
             jax.lax.broadcasted_iota(jnp.int32, (n, keys), 1)
         live = jnp.logical_and(rid == k, col <= pos)
+        if window:
+            live = jnp.logical_and(live, col > pos - window)
         page = c_buf[buf, :keys]                               # [keys, L]
         s = jax.lax.dot_general(
             q_ref[0, rows], page, (((1, ), (1, )), ((), ())),
@@ -711,9 +715,9 @@ def _latent_kernel(tables_ref, slot_ref, first_ref, npages_ref, slab_ref,
     o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("rank", "scale"))
+@functools.partial(jax.jit, static_argnames=("rank", "scale", "window"))
 def paged_latent_attention(q, c_cache, block_tables, seq_slots, positions, *,
-                           rank, scale):
+                           rank, scale, window=0):
     """Multi-head latent attention, absorbed, over a paged latent cache.
 
     q: ``[T, H, L]``, head ``h`` of row ``t`` as ``(q_n W_uk,h^T [rank] ;
@@ -721,9 +725,10 @@ def paged_latent_attention(q, c_cache, block_tables, seq_slots, positions, *,
     [rank] ; k_r)``, the same for every head; block_tables ``[max_seqs,
     maxb]``, seq_slots, positions ``[T]`` as :func:`paged_attention`'s.
     Returns ``[T, H, rank]``: ``sum_j softmax_j(q . row_j * scale) c_j`` over
-    the keys ``j <= positions[t]`` of the row's sequence (the caller takes
-    it through ``W_uv``).  A dead row (slot 0) comes back zero.  The shape
-    has to pass :func:`latent_tiled`."""
+    the keys ``j <= positions[t]`` of the row's sequence, and ``j >
+    positions[t] - window`` where the layer has a ``window`` (the caller
+    takes it through ``W_uv``).  A dead row (slot 0) comes back zero.  The
+    shape has to pass :func:`latent_tiled`."""
     T, H, L = q.shape
     _, bs, _ = c_cache.shape
     tq = tile_rows(H, 1, L, c_cache.dtype, T, latent=True)
@@ -734,7 +739,7 @@ def paged_latent_attention(q, c_cache, block_tables, seq_slots, positions, *,
     M = tq * H
     P = item_pages(1, L, c_cache.dtype, bs)
     pos, rid, run_slot, first_page, n_pages, slab, n_blocks = run_plan(
-        jnp, seq_slots, positions, tq, bs, 0, H, P)
+        jnp, seq_slots, positions, tq, bs, int(window), H, P)
     n = rid.shape[0]
     rows = lambda a: jnp.repeat(a, H, axis=1)[:, :, None]      # [n, M, 1]
     qt = jnp.pad(q.astype(c_cache.dtype), ((0, n * tq - T), (0, 0), (0, 0))) \
@@ -757,7 +762,8 @@ def paged_latent_attention(q, c_cache, block_tables, seq_slots, positions, *,
     )
     out = pl.pallas_call(
         functools.partial(_latent_kernel, tq=tq, block_size=bs, maxb=maxb,
-                          scale=float(scale), rank=int(rank), block=P),
+                          scale=float(scale), rank=int(rank), block=P,
+                          window=int(window)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, M, rank), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -887,35 +893,56 @@ def _chunk_runs(xp, seq_slots, positions, min_rows):
         of(last - first + 1), of(pos)
 
 
-def _chunk_blocks(xp, n_rows, pos0, block_size):
+def _chunk_blocks(xp, n_rows, pos0, block_size, window=0):
     """``(P, blocks)``: the pages of a block of the chunk kernel, and the
-    blocks each run of :func:`_chunk_runs` walks (its context's, from key
-    0)."""
+    blocks each run of :func:`_chunk_runs` walks: its context's, from key 0
+    or, under a ``window``, from the block that holds the first key its
+    first row sees (:func:`_chunk_first_block`)."""
     P = _CHUNK_BLOCK_KEYS // block_size or 1
-    return P, xp.where(n_rows > 0,
-                       (pos0 + n_rows - 1) // (P * block_size) + 1, 0)
+    keys = P * block_size
+    runs = n_rows > 0
+    blocks = (pos0 + n_rows - 1) // keys + 1
+    if window:
+        blocks = blocks - _chunk_first_block(xp, pos0, keys, window)
+    return P, xp.where(runs, blocks, 0)
 
 
-def chunk_page_loads(seq_slots, positions, *, heads, block_size, min_rows):
+def _chunk_first_block(xp, pos0, keys, window):
+    """The first block of ``keys`` keys that a run whose first row stands at
+    ``pos0`` walks under a ``window``: the one that holds position ``pos0 -
+    window + 1``."""
+    return xp.maximum(pos0 - window + 1, 0) // keys
+
+
+def seen_keys(positions, window=0):
+    """The keys a row at each of ``positions`` (numpy) attends: all up to its
+    own, or the last ``window`` of them."""
+    return np.minimum(positions + 1, window) if window else positions + 1
+
+
+def chunk_page_loads(seq_slots, positions, *, heads, block_size, min_rows,
+                     window=0):
     """Host-side (numpy) count of what :func:`paged_mla_chunk_attention`
     does for these rows (``[T]``, or ``[B, T]``: B calls): ``(expanded,
     keys, pages)``, the rows that take it (:func:`latent_row_forms`), the
-    (row, key) pairs they attend, and the latent pages its loops bring in:
-    a run's blocks of ``_CHUNK_BLOCK_KEYS`` keys, once a head."""
+    (row, key) pairs they attend (inside the layer's ``window``, if any),
+    and the latent pages its loops bring in: a run's blocks of
+    ``_CHUNK_BLOCK_KEYS`` keys, once a head."""
     slots, pos = (np.atleast_2d(np.asarray(a))
                   for a in (seq_slots, positions))
     if min_rows is None:
         return np.zeros(slots.shape, bool), 0, 0
     expanded, _, _, n_rows, pos0 = _chunk_runs(np, slots, pos, min_rows)
-    P, blocks = _chunk_blocks(np, n_rows, pos0, block_size)
-    return expanded, int((pos + 1)[expanded].sum()), \
+    P, blocks = _chunk_blocks(np, n_rows, pos0, block_size, window)
+    return expanded, int(seen_keys(pos, window)[expanded].sum()), \
         int(blocks.sum()) * P * heads
 
 
 def _mla_chunk_kernel(tables_ref, total_ref, slot_ref, row0_ref, nrows_ref,
                       pos0_ref, q_ref, wuk_ref, wuv_ref, c_hbm, o_ref, c_buf,
                       sem, k_ref, v_ref, acc_ref, m_ref, l_ref, *, sq,
-                      block_size, pages, maxb, scale, rank, max_runs):
+                      block_size, pages, maxb, scale, rank, max_runs,
+                      window=0):
     """One tile of ``tq`` buffer rows and one head: ``q_ref [tq, W]`` (a row
     ``(q_n [nope] ; q_r ; zeros)``, as long as ``nope`` plus a page row's
     columns past ``rank``), ``wuk_ref [rank, nope]``, ``wuv_ref [rank,
@@ -925,7 +952,10 @@ def _mla_chunk_kernel(tables_ref, total_ref, slot_ref, row0_ref, nrows_ref,
     from them into VMEM in the cache's type, and every stretch of ``sq``
     rows that holds rows of the run which see the block takes one
     online-softmax update: with a mask only where an edge (the run's first
-    or last row, the diagonal) crosses the stretch's square."""
+    or last row, the diagonal) crosses the stretch's square.  ``window``: a
+    row sees the last ``window`` positions alone; a run's blocks then start
+    at :func:`_chunk_first_block`, and the window's lower edge is one more
+    edge that may cross a square."""
     i = pl.program_id(0)
     base = i * max_runs
     tq = acc_ref.shape[0]
@@ -951,9 +981,17 @@ def _mla_chunk_kernel(tables_ref, total_ref, slot_ref, row0_ref, nrows_ref,
 
     start, wait = (lambda c: c.start()), (lambda c: c.wait())
 
+    def first_block(k):
+        """The first block run ``k`` walks (a run past the tile's last: the
+        last run's again; no item of it follows)."""
+        if not window:
+            return 0
+        return _chunk_first_block(
+            jnp, pos0_ref[base + jnp.minimum(k, max_runs - 1)], keys, window)
+
     @pl.when(total > 0)
     def _first():
-        copies(0, 0, 0, start)
+        copies(0, first_block(0), 0, start)
 
     def attend(k, j, r0, masked):
         """The rows ``r0 .. r0 + sq`` against the made block; ``masked``: an
@@ -971,6 +1009,8 @@ def _mla_chunk_kernel(tables_ref, total_ref, slot_ref, row0_ref, nrows_ref,
                 jnp.int32, (sq, keys), 1)
             live = (row >= 0) & (row < nrows_ref[base + k]) \
                 & (col <= pos0_ref[base + k] + row)
+            if window:
+                live &= col > pos0_ref[base + k] + row - window
             s = jnp.where(live, s, _NEG_INF)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             # a row of another run, or of none, keeps -inf: exp(-inf - 0)
@@ -995,7 +1035,8 @@ def _mla_chunk_kernel(tables_ref, total_ref, slot_ref, row0_ref, nrows_ref,
         row0, n_rows = row0_ref[base + k], nrows_ref[base + k]
         pos0 = pos0_ref[base + k]
         last = j == (pos0 + n_rows - 1) // keys
-        k_next, j_next = jnp.where(last, k + 1, k), jnp.where(last, 0, j + 1)
+        k_next = jnp.where(last, k + 1, k)
+        j_next = jnp.where(last, first_block(k_next), j + 1)
 
         @pl.when(it + 1 < total)
         def _prefetch():
@@ -1019,33 +1060,42 @@ def _mla_chunk_kernel(tables_ref, total_ref, slot_ref, row0_ref, nrows_ref,
             sees = (lo < hi) & (j * keys <= pos0 + hi - 1 - row0)
             inner = (row0 <= r0) & (row0 + n_rows >= r0 + sq) \
                 & ((j + 1) * keys - 1 <= pos0 + r0 - row0)
+            if window:      # the block's last key against the first row's
+                # window, its first against the last row's
+                sees &= (j + 1) * keys - 1 > pos0 + lo - row0 - window
+                inner &= j * keys > pos0 + r0 + sq - 1 - row0 - window
             pl.when(sees & inner)(
                 functools.partial(attend, k, j, r0, False))
             pl.when(sees & jnp.logical_not(inner))(
                 functools.partial(attend, k, j, r0, True))
         return k_next, j_next
 
-    jax.lax.fori_loop(0, total, item, (jnp.int32(0), ) * 2)
+    jax.lax.fori_loop(0, total, item,
+                      (jnp.int32(0), jnp.asarray(first_block(0), jnp.int32)))
 
     l = l_ref[:, :1]
     o_ref[...] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)) \
         .astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("rank", "scale", "min_rows"))
+@functools.partial(jax.jit, static_argnames=("rank", "scale", "min_rows",
+                                             "window"))
 def paged_mla_chunk_attention(q, c_cache, w_uk, w_uv, block_tables, seq_slots,
-                              positions, *, rank, scale, min_rows):
+                              positions, *, rank, scale, min_rows, window=0):
     """Multi-head latent attention in the EXPANDED form over the paged latent
     cache, for the rows of LONG runs (``ds_paged_mla_chunk``).
 
     q: ``[T, H, W]``, head ``h`` of row ``t`` as ``(q_n [nope] ; q_r ;
     zeros)`` with ``W = nope + (L - rank)``; c_cache ``[num_blocks, bs, L]``,
-    a token's row ``(c [rank] ; k_r ; zeros)``; w_uk ``[rank, H, nope]``,
-    w_uv ``[rank, H, value]``; block_tables, seq_slots, positions as
+    a token's row ``(c [rank] ; k_r ; zeros)``; w_uk ``[rank, G, nope]``,
+    w_uv ``[rank, G, value]`` with ``G`` = ``H``, or fewer: K/V GROUPS, each
+    read by ``H / G`` adjacent query heads; block_tables, seq_slots,
+    positions as
     :func:`paged_latent_attention`'s.  Returns ``[T, H, value]``: for every
     row of a run of ``min_rows`` rows or more (:func:`latent_row_forms`)
     ``sum_j softmax_j(q . (c_j W_uk,h ; k_r,j) * scale) c_j W_uv,h`` over
-    the keys ``j <= positions[t]`` of its sequence, the keys and values made
+    the keys ``j <= positions[t]`` of its sequence (and ``j > positions[t]
+    - window`` where the layer has a ``window``), the keys and values made
     from the latent pages in VMEM, a block of ``_CHUNK_BLOCK_KEYS`` keys at
     a time, in the cache's type with float32 sums; every other row (a
     shorter run's, a dead one) comes back zero.  The grid is (tile, head):
@@ -1054,6 +1104,7 @@ def paged_mla_chunk_attention(q, c_cache, w_uk, w_uv, block_tables, seq_slots,
     T, H, W = q.shape
     _, bs, L = c_cache.shape
     nope, value = w_uk.shape[2], w_uv.shape[2]
+    group = H // w_uk.shape[1]              # query heads a K/V group
     if W != nope + L - rank or not chunk_tiled(rank, nope, value, L,
                                                c_cache.dtype):
         raise ValueError(
@@ -1065,11 +1116,12 @@ def paged_mla_chunk_attention(q, c_cache, w_uk, w_uv, block_tables, seq_slots,
     _, run_slot, row0, n_rows, pos0 = _chunk_runs(
         jnp, seq_slots, positions, min_rows)
     n = row0.shape[0]
-    P, blocks = _chunk_blocks(jnp, n_rows, pos0, bs)
+    P, blocks = _chunk_blocks(jnp, n_rows, pos0, bs, int(window))
     qt = jnp.pad(q.astype(dtype).reshape(T, H * W), ((0, n * tq - T), (0, 0)))
     cols = lambda width: pl.BlockSpec((tq, width), lambda i, h, *_: (i, h))
-    weight = lambda width: pl.BlockSpec((rank, width),
-                                        lambda i, h, *_: (0, h))
+    weight = lambda width: pl.BlockSpec(
+        (rank, width), (lambda i, h, *_: (0, h)) if group == 1
+        else (lambda i, h, *_: (0, h // group)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=(n, H),
@@ -1089,7 +1141,7 @@ def paged_mla_chunk_attention(q, c_cache, w_uk, w_uv, block_tables, seq_slots,
     out = pl.pallas_call(
         functools.partial(_mla_chunk_kernel, sq=sq, block_size=bs, pages=P,
                           maxb=maxb, scale=float(scale), rank=int(rank),
-                          max_runs=R),
+                          max_runs=R, window=int(window)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n * tq, H * value), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -1099,8 +1151,8 @@ def paged_mla_chunk_attention(q, c_cache, w_uk, w_uv, block_tables, seq_slots,
         name="ds_paged_mla_chunk",
     )(block_tables.reshape(-1).astype(jnp.int32), blocks.sum(-1),
       run_slot.reshape(-1), row0.reshape(-1), n_rows.reshape(-1),
-      pos0.reshape(-1), qt, w_uk.astype(dtype).reshape(rank, H * nope),
-      w_uv.astype(dtype).reshape(rank, H * value), c_cache)
+      pos0.reshape(-1), qt, w_uk.astype(dtype).reshape(rank, -1),
+      w_uv.astype(dtype).reshape(rank, -1), c_cache)
     return out[:T].reshape(T, H, value)
 
 

@@ -133,7 +133,11 @@ COUNT_MICROS_COVERED = "micro_steps_covered"
 #: expanded one (``ds_paged_mla_chunk``); the (row, key) pairs each form's
 #: rows attend, summed over the cache's entries (``latent_keys``: the
 #: absorbed rows'); the latent pages one call of the expanded kernel brings
-#: in (the absorbed kernel's are ``grid_pages``)
+#: in (the absorbed kernel's are ``grid_pages``).  Where the model states a
+#: window a layer (``layer_windows``) the pairs, ``expanded_pages`` and the
+#: page counts are summed over the LAYERS' calls, each layer's rows seeing
+#: its own window, and ``grid_pages_window`` / ``grid_pages_full`` are the
+#: absorbed kernel's loads in the window layers and in the full ones
 COUNT_LATENT_KEYS = "latent_keys"
 COUNT_ABSORBED_ROWS = "absorbed_rows"
 COUNT_EXPANDED_ROWS = "expanded_rows"
@@ -199,6 +203,21 @@ SCOPE_MLA_DOWN = "ds.mla_down"            # serving, inside ds.attn: the two
 SCOPE_MLA_ABSORB = "ds.mla_absorb"        # serving, inside ds.attn: q_n into
 #                                           the latent space and the latent
 #                                           output out of it (W_uk, W_uv)
+SCOPE_DIFF_ATTN = "ds.diff_attn"          # serving, inside ds.attn: grouped
+#                                           differential attention's own
+#                                           parts: lambda's projection, the
+#                                           noise heads' outputs subtracted
+#                                           from the signal heads', the
+#                                           element-wise output gate
+SCOPE_MHC = "ds.mhc"                      # serving, a multi-stream residual
+#                                           (hyper-connections): a sublayer's
+#                                           three mappings, the Sinkhorn
+#                                           sweeps, the read of the streams
+#                                           and the write back to them
+SCOPE_POLYNORM = "ds.polynorm"            # serving, inside ds.mlp: PolyNorm
+#                                           on a feed-forward's gate (in an
+#                                           expert layer also under
+#                                           ds.moe_experts / ds.moe_shared)
 SCOPE_SSM = "ds.ssm"                      # serving: a Mamba mixer, the twin
 #                                           of ds.attn; inside it:
 SCOPE_SSM_PROJ = "ds.ssm_proj"            # in_proj, x_proj, the inner norms,

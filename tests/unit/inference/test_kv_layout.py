@@ -22,7 +22,7 @@ from deepspeed_tpu.inference.v2 import InferenceEngineV2
 from deepspeed_tpu.inference.v2 import ragged_forward as rf
 from deepspeed_tpu.inference.v2.ragged import BlockedKVCache
 from deepspeed_tpu.models import (cohere2_moe, evabyte, falcon, jamba, llama,
-                                  longcat_flash, mixtral, opt, ouro,
+                                  longcat_flash, mixtral, motif, opt, ouro,
                                   pangu_ultra_moe, phi)
 
 _spec = importlib.util.spec_from_file_location(
@@ -54,10 +54,12 @@ ZOO = {
     "LongcatFlashModel": (longcat_flash.LongcatFlashModel,
                           longcat_flash.longcat_flash_tiny),
     "OuroModel": (ouro.OuroModel, lambda: ouro.ouro_tiny(dtype="float32")),
+    "MotifModel": (motif.MotifModel, lambda: motif.motif_tiny(
+        num_hidden_layers=4, n_dense_first_layers=1)),
 }
 #: the models whose cache is ONE latent buffer a layer: it never was a K and
 #: a V in one stacked array, so (c) has nothing to rebuild for them
-LATENT = ("PanguUltraMoeModel", "LongcatFlashModel")
+LATENT = ("PanguUltraMoeModel", "LongcatFlashModel", "MotifModel")
 #: the models whose cache holds entries of two kinds (pages, and state rows
 #: a sequence slot): no stacked array ever held them either
 RECURRENT = ("JambaModel", )

@@ -40,7 +40,7 @@ def test_every_kernel_compiles_for_v5e(report):
     lines = [ln for ln in r.stdout.splitlines()
              if ln.startswith(("PASS", "FAIL"))]
     assert r.returncode == 0, "\n".join(lines) + r.stderr[-1500:]
-    assert len(lines) >= 34 and all(ln.startswith("PASS") for ln in lines)
+    assert len(lines) >= 38 and all(ln.startswith("PASS") for ln in lines)
     assert "TPU v5 lite" in r.stdout
     # the run-tiled paged kernel, every branch of its item (the block of
     # pages too), at the three serving cells' shapes and their bursts': a
@@ -74,6 +74,15 @@ def test_every_kernel_compiles_for_v5e(report):
                    "step)",
                    "paged_latent_attention(MLA 64 x 576, the LongCat cell's "
                    "burst)",
+                   # 80 heads in 16 K/V groups, a window layer and a full one
+                   "paged_latent_attention(GDLA 80 x 576, window 128, the "
+                   "Motif cell's step)",
+                   "paged_latent_attention(GDLA 80 x 576, window 128, the "
+                   "Motif cell's burst)",
+                   "paged_mla_chunk_attention(GDLA 80 in 16 x 576, window "
+                   "128, the Motif cell's step)",
+                   "paged_mla_chunk_attention(GDLA 80 in 16 x 576, a full "
+                   "layer, the Motif cell's step)",
                    "block_sparse_flash_attention"):
         assert any(kernel in ln for ln in lines), kernel
 

@@ -371,6 +371,33 @@ def main():
             sds((seqs, maxb), jnp.int32), sds((T, ), jnp.int32),
             sds((T, ), jnp.int32)))
 
+    # both readers at Motif-3's sizes: 80 query heads (a tile of 8 tokens, a
+    # decode token's slab of 80 rows) in 16 K/V groups; the absorbed one with
+    # a window of 128 (without, it is the kernel above at 80 heads), the
+    # expanded one on a window layer and on a full one
+    for name, T in (("the Motif cell's step", 1024),
+                    ("the Motif cell's burst", 65)):
+        results.append(checked(
+            f"paged_latent_attention(GDLA 80 x 576, window 128, {name})",
+            lambda q, c, t, s, l: paged_latent_attention(
+                q, c, t, s, l, rank=512, scale=192 ** -0.5, window=128),
+            sds((T, 80, 640), bf16), sds((64, 128, 640), bf16),
+            sds((65, 193), jnp.int32), sds((T, ), jnp.int32),
+            sds((T, ), jnp.int32), pages=item_pages(1, 640, bf16, 128)))
+    for window in (128, 0):
+        layer = f"window {window}" if window else "a full layer"
+        results.append(checked(
+            f"paged_mla_chunk_attention(GDLA 80 in 16 x 576, {layer}, the "
+            "Motif cell's step)",
+            lambda q, c, k, v, t, s, l, window=window:
+            paged_mla_chunk_attention(
+                q, c, k, v, t, s, l, rank=512, scale=192 ** -0.5,
+                min_rows=expanded_min_rows(512, 128, 64, 128), window=window),
+            sds((1024, 80, 256), bf16), sds((64, 128, 640), bf16),
+            sds((512, 16, 128), bf16), sds((512, 16, 128), bf16),
+            sds((65, 193), jnp.int32), sds((1024, ), jnp.int32),
+            sds((1024, ), jnp.int32)))
+
     # the Mamba-1 recurrence at the Jamba cell's shapes: a 2048-row step over
     # 257 slots' state of 16 x 5120 (bfloat16, aliased in and out)
     from deepspeed_tpu.ops.pallas.selective_scan import selective_scan

@@ -52,6 +52,15 @@ FAULTS = {
         "d_all_passes_share_one_entry_a_layer": {"pass_reads": "last"},
         "e_post_sublayer_norms_left_out": {"post_sublayer_norms": False},
     },
+    "motif": {
+        "a_lambda_zero_no_subtraction": {"differential": False},
+        "b_window_dropped_on_a_window_layer": {"window_dropped_on_layer": 4},
+        "c_window_put_on_a_full_layer": {"window_put_on_layer": 3},
+        "d_one_sinkhorn_sweep_for_twenty": {"mhc_sinkhorn_iters": 1},
+        "e_h_res_identity": {"mhc_identity_res": True},
+        "f_silu_for_polynorm": {"hidden_act": "silu"},
+        "g_cache_row_in_8_bits": {"cache_row_mantissa_bits": 3},
+    },
 }
 #: the comparison's lower-precision control: the reference in the nearest
 #: precision below the one the configuration serves in, which has to come out
@@ -62,6 +71,9 @@ CONTROLS = {
     },
     "ouro": {
         "f_control_weights_in_8_bits": {"weight_mantissa_bits": 3},
+    },
+    "motif": {
+        "h_control_weights_in_8_bits": {"weight_mantissa_bits": 3},
     },
 }
 
