@@ -295,6 +295,7 @@ class InferenceEngineV2:
         self._kv = self.kv_cache.layers
         token_bytes, seq_bytes = self.kv_cache.bytes_by_kind()
         self._state_row_bytes = seq_bytes     # a constant of the engine
+        self._rule_forms = bool(recurrent and recurrent.get("forms"))
         #: what a looped model's steps carry as ``cache_token_bytes``
         self._cache_token_bytes = token_bytes if looped > 1 else None
         n_pages = self.kv_cache.page_layers
@@ -610,17 +611,30 @@ class InferenceEngineV2:
         slot's row) and write (every run leaves its final state), and the
         tokens their scans walk; ``state_row_bytes``: one sequence's row over
         all of them.  A RUN is the contiguous rows of one sequence; in a
-        burst (``[k, rows]``) every live row of every iteration is one."""
+        burst (``[k, rows]``) every live row of every iteration is one.  A
+        model whose recurrent layers take a run in one of two FORMS by its
+        length (``recurrent_state["forms"]``: a gated delta rule) also gets
+        the tokens by form, ``rule_slot_tokens`` and ``rule_chunk_tokens``."""
         pos, slots = np.atleast_2d(pos), np.atleast_2d(slots)
         live = slots != 0
         start = live.copy()
         start[:, 1:] &= slots[:, 1:] != slots[:, :-1]
         layers = self.kv_cache.kinds.count("state")
-        return {_names.COUNT_STATE_ROWS_READ:
-                int((start & (pos > 0)).sum()) * layers,
-                _names.COUNT_STATE_ROWS_WRITTEN: int(start.sum()) * layers,
-                _names.COUNT_SCAN_TOKENS: int(live.sum()) * layers,
-                _names.COUNT_STATE_ROW_BYTES: self._state_row_bytes}
+        counts = {_names.COUNT_STATE_ROWS_READ:
+                  int((start & (pos > 0)).sum()) * layers,
+                  _names.COUNT_STATE_ROWS_WRITTEN: int(start.sum()) * layers,
+                  _names.COUNT_SCAN_TOKENS: int(live.sum()) * layers,
+                  _names.COUNT_STATE_ROW_BYTES: self._state_row_bytes}
+        if self._rule_forms:
+            # a run of one token is one update of its slot's row; a longer
+            # run's tokens go through the chunk form
+            end = live.copy()
+            end[:, :-1] &= slots[:, :-1] != slots[:, 1:]
+            single = int((start & end).sum())
+            counts[_names.COUNT_RULE_SLOT_TOKENS] = single * layers
+            counts[_names.COUNT_RULE_CHUNK_TOKENS] = \
+                (int(live.sum()) - single) * layers
+        return counts
 
     def _kind_page_counts(self, pos, slots, window):
         """``_page_counts`` of one call of a layer with this ``window``."""

@@ -47,8 +47,11 @@ Entries of a SECOND KIND (``recurrent``: a model with state-space layers states
 ``recurrent_state``, ``models/jamba.py``): a ``"state"`` layer keeps no pages
 but ``(conv_state [K - 1, slots, C], ssm_state [slots, S, C])``, a row a
 SEQUENCE SLOT (``DSSequenceDescriptor.slot``; slot 0 is the garbage row that
-padding writes to) and not a row a token, in the cache's type, threaded and
-donated exactly as pages are.  Its bytes are fixed by ``max_seqs``; the
+padding writes to) and not a row a token, threaded and donated exactly as
+pages are; in the cache's type, but where the model states a leaf's own
+(``recurrent_state["dtypes"]``: ``models/qwen3_next.py`` holds a Gated
+DeltaNet layer's matrix state ``[slots, Hv, 128, 128]`` in FLOAT32 beside
+bfloat16 convolution rows; ``bytes_by_kind`` counts the leaves as they are).  Its bytes are fixed by ``max_seqs``; the
 allocator, ``blocks_for`` and the claims count the pages of the ``"pages"``
 layers, which every such layer holds alike.  Nothing is cleared on the host: a
 run that starts at position 0 starts from zeros inside the step program, so a
@@ -266,9 +269,14 @@ class BlockedKVCache:
                         for _ in ("kv" if kv_dtype else ""))
             if recurrent:
                 (taps, chans), ssm = recurrent["conv"], recurrent["ssm"]
+                # a leaf's type is the model's to state (a matrix state a
+                # head is held in float32); the cache's own where it does not
+                types = recurrent.get("dtypes", {})
+                leaf_type = lambda leaf: jnp.dtype(types.get(leaf)
+                                                   or self.dtype)
             state = lambda: (
-                jnp.zeros((taps, int(max_seqs), chans), self.dtype),
-                jnp.zeros((int(max_seqs), ) + tuple(ssm), self.dtype))
+                jnp.zeros((taps, int(max_seqs), chans), leaf_type("conv")),
+                jnp.zeros((int(max_seqs), ) + tuple(ssm), leaf_type("ssm")))
             self.layers = tuple(pages() if kind == "pages" else state()
                                 for kind in self.kinds)
         self.allocator = BlockedAllocator(num_blocks)

@@ -200,7 +200,7 @@ def _ragged_attention_block(lp_attn, h, kv_layer, blk, off, block_tables,
                             rotary=True, rotary_dim=None,
                             use_kernel=True, kv_dtype=None,
                             row_positions=None, after_scatter=None,
-                            window=None):
+                            window=None, qk_norm=None, out_gate=None):
     """Shared attention sub-block: qkv → rotary → cache scatter → paged
     attention → output projection.  Returns (attn_out [T, D], new kv_layer).
     ``row_positions`` (default: ``positions``) are the positions inside the
@@ -219,12 +219,23 @@ def _ragged_attention_block(lp_attn, h, kv_layer, blk, off, block_tables,
     partial rotary (phi family); ``rotary`` may be the layer's own turn ``x
     [T, heads, Dh] -> x`` (interleaved pairs: ``cohere2_moe_ragged_step``).
     ``window`` is the LAYER's sliding window (0: none; default: the model's
-    one ``cfg.sliding_window``)."""
+    one ``cfg.sliding_window``).  A GATED attention
+    (``qwen3_next_ragged_step``): ``qk_norm`` ``(q, k) -> (q, k)`` is a
+    per-head norm of q and k BEFORE the rotary; with ``out_gate`` the rows of
+    ``q_proj`` are a head's ``[query | gate]``, ``2 Dh`` long, and the
+    attention's output is multiplied by ``sigmoid(gate)`` before ``o_proj``
+    (both under ``ds.attn_gate``).  Their defaults leave every other model's
+    block as it was."""
     dtype = jnp.dtype(cfg.dtype)
     H, Dh = cfg.num_attention_heads, cfg.head_dim
     q = _qkv(h, lp_attn["q_proj"], dtype)
     k = _qkv(h, lp_attn["k_proj"], dtype)
     v = _qkv(h, lp_attn["v_proj"], dtype)
+    if out_gate:
+        q, gate = q[..., :Dh], q[..., Dh:]
+    if qk_norm is not None:
+        with jax.named_scope(_names.SCOPE_ATTN_GATE):
+            q, k = qk_norm(q, k)
     if callable(rotary):
         q, k = rotary(q), rotary(k)
     elif rotary:
@@ -247,6 +258,10 @@ def _ragged_attention_block(lp_attn, h, kv_layer, blk, off, block_tables,
                            window=getattr(cfg, "sliding_window", 0)
                            if window is None else window,
                            use_kernel=use_kernel, kv_scales=kv_scales)
+    if out_gate:
+        with jax.named_scope(_names.SCOPE_ATTN_GATE):
+            out = out * jax.nn.sigmoid(gate.astype(jnp.float32)) \
+                .astype(out.dtype)
     o = out.reshape(out.shape[0], H * Dh)
     o = jnp.einsum("tf,fd->td", o, lp_attn["o_proj"]["kernel"].astype(dtype))
     if "bias" in lp_attn["o_proj"]:
@@ -1225,12 +1240,15 @@ def _conv_runs(x, conv_state, conv, plan):
     [T, C]``: a row's taps come from its run's earlier rows and, before the
     run's first row, from its slot's ``conv_state [K - 1, slots, C]`` (plane
     ``K - 2`` the newest; zeros for a run that starts at position 0).
-    Returns ``(conv + bias [T, C] float32, new conv_state)``: every run
-    leaves its last ``K - 1`` inputs, in the state's type."""
+    Returns ``(conv + bias [T, C] float32, new conv_state)`` (a convolution
+    without the leaf ``bias`` has none): every run leaves its last ``K - 1``
+    inputs, in the state's type."""
     w = conv["weight"].astype(jnp.float32)                 # [K, C]
     K, S = w.shape[0], conv_state.shape[1]
     x32 = x.astype(jnp.float32)
-    acc = w[K - 1] * x32 + conv["bias"].astype(jnp.float32)[:, 0]
+    acc = w[K - 1] * x32
+    if "bias" in conv:
+        acc = acc + conv["bias"].astype(jnp.float32)[:, 0]
     idx, slots = plan["idx"], plan["slots"]
     for j in range(1, K):                    # the run's own earlier rows
         back = jnp.pad(x32, ((j, 0), (0, 0)))[:-j]
@@ -1266,9 +1284,10 @@ def _conv_slots(x, conv_state, conv, plan):
     w = conv["weight"].astype(jnp.float32)
     K = w.shape[0]
     old = jnp.where(plan["fresh"][None, :, None], 0, conv_state)
-    acc = w[K - 1] * x.astype(jnp.float32) \
-        + conv["bias"].astype(jnp.float32)[:, 0] \
-        + sum(w[k] * old[k].astype(jnp.float32) for k in range(K - 1))
+    acc = w[K - 1] * x.astype(jnp.float32)
+    if "bias" in conv:
+        acc = acc + conv["bias"].astype(jnp.float32)[:, 0]
+    acc = acc + sum(w[k] * old[k].astype(jnp.float32) for k in range(K - 1))
     new = jnp.concatenate([old[1:], x[None].astype(conv_state.dtype)])
     return acc, jnp.where(plan["live"][None, :, None], new, conv_state)
 
@@ -1414,6 +1433,200 @@ def jamba_ragged_step(params, kv_data, token_ids, positions, seq_slots,
     return logits, tuple(kv_data)
 
 
+# ------------------------------------------------------------ Qwen3-Next
+def _rule_slots(q, k, v, g, beta, state, live, fresh):
+    """The delta rule's ONE-TOKEN form over a buffer of one row a slot (``q,
+    k, v [slots, Hv, 128]``, ``g, beta [slots, Hv]``; ``state [slots, Hv, 128,
+    128]`` float32, donated): one element-wise update of every live slot's
+    row, read and written in place.  Returns ``(o [slots, Hv, 128], new
+    state)``."""
+    from ...models.qwen3_next import delta_rule_token
+    with jax.named_scope(_names.SCOPE_GDN_SLOT):
+        old = jnp.where(fresh[:, None, None, None], 0,
+                        state.astype(jnp.float32))
+        o, new = delta_rule_token(q, k, v, g, beta, old)
+        return o, jnp.where(live[:, None, None, None],
+                            new.astype(state.dtype), state)
+
+
+def _rule_runs(q, k, v, g, beta, state, plan):
+    """The delta rule over the runs of a ragged buffer (``q, k, v [T, Hv,
+    128]``, ``g, beta [T, Hv]`` float32; ``state [slots, Hv, 128, 128]``
+    float32, a row a slot, donated).  A run of ONE token (a decode row beside
+    a chunk) takes the one-token form on its slot's row, all of them in one
+    update (:func:`_rule_slots`; skipped where the step holds none).  A longer
+    run is cut into chunks of ``GDN_CHUNK`` rows that never cross into its
+    neighbour, and a loop over the step's chunks takes each through the
+    matrix products of ``models/qwen3_next.delta_rule_chunk``: a run's first
+    chunk starts from its slot's row (zeros at position 0), the state is
+    carried from chunk to chunk in float32, and its last chunk leaves it in
+    the slot.  Returns ``(o [T, Hv, 128] float32, new state)``."""
+    from ...models.qwen3_next import GDN_CHUNK as C, delta_rule_chunk
+    T, n_slots = q.shape[0], state.shape[0]
+    slots, live = plan["slots"], plan["live"]
+    single = plan["has_run"] & (plan["run_len"] == 1)            # [slots]
+    rows = plan["last_row"]
+
+    def one_token(state):
+        o, state = _rule_slots(q[rows], k[rows], v[rows], g[rows], beta[rows],
+                               state, single, plan["fresh"])
+        return jnp.where((live & single[slots])[:, None, None], o[slots],
+                         0), state
+
+    out, state = jax.lax.cond(
+        jnp.any(single), one_token,
+        lambda state: (jnp.zeros(v.shape, jnp.float32), state), state)
+
+    with jax.named_scope(_names.SCOPE_GDN_CHUNK):
+        # the chunks of the step's longer runs, in the order of their slots
+        long = plan["has_run"] & (plan["run_len"] > 1)
+        n_chunks = jnp.where(long, -(-plan["run_len"] // C), 0)
+        ends = jnp.cumsum(n_chunks)                          # [slots]
+        first_row = rows - plan["run_len"] + 1
+        pad = lambda x: jnp.pad(x, ((0, C), ) + ((0, 0), ) * (x.ndim - 1))
+        qp, kp, vp, gp, bp = map(pad, (q, k, v, g, beta))
+
+        def chunk(c, carry):
+            out, state, s = carry
+            # the run this chunk belongs to: a compare and a sum over the
+            # slots (``searchsorted`` is a ``while`` of its own a trip)
+            slot = jnp.sum(ends <= c, dtype=jnp.int32)
+            j = c - (ends[slot] - n_chunks[slot])            # chunk of its run
+            r0 = first_row[slot] + j * C
+            valid = jnp.arange(C) < plan["run_len"][slot] - j * C
+            cut = lambda x: jax.lax.dynamic_slice_in_dim(x, r0, C)
+            keep = lambda x: jnp.where(
+                valid.reshape((C, ) + (1, ) * (x.ndim - 1)), x, 0)
+            row = jax.lax.dynamic_index_in_dim(state, slot, keepdims=False)
+            s = jnp.where(j > 0, s, jnp.where(plan["fresh"][slot], 0,
+                                              row.astype(jnp.float32)))
+            o, s = delta_rule_chunk(cut(qp), keep(cut(kp)), keep(cut(vp)),
+                                    keep(cut(gp)), keep(cut(bp)), s)
+            out = jax.lax.dynamic_update_slice_in_dim(
+                out, jnp.where(valid[:, None, None], o, cut(out)), r0, 0)
+            last = j == n_chunks[slot] - 1
+            state = jax.lax.dynamic_update_index_in_dim(
+                state, jnp.where(last, s.astype(state.dtype), row), slot, 0)
+            return out, state, s
+
+        out, state, _ = jax.lax.fori_loop(
+            0, ends[n_slots - 1], chunk,
+            (pad(out), state, jnp.zeros(state.shape[1:], jnp.float32)))
+    return out[:T], state
+
+
+@jax.named_scope(_names.SCOPE_GDN)
+def _gdn_block(mp, h, state, plan, *, cfg, slot_rows):
+    """The Gated DeltaNet mixer of one layer over the step's buffer
+    (``models/qwen3_next.py`` has the equations and the rule's two forms).
+    ``state``: the layer's entry of the cache, ``(conv_state [K - 1, slots,
+    C] in the model's dtype, rule_state [slots, Hv, 128, 128] FLOAT32)``,
+    donated buffers of their own; a run starts from ITS slot's rows (zeros at
+    position 0), never reads its neighbour's, and leaves its last state and
+    its last ``K - 1`` convolution inputs in the slot.  ``slot_rows`` (a
+    burst's buffer: row ``i`` is slot ``i``): every live row is a run of one
+    token, ONE update of the state buffer in place.  Returns (out [T, D], new
+    state)."""
+    from ...models.qwen3_next import (gdn_conv_out, gdn_gate_out, gdn_in_proj,
+                                      gdn_rule_inputs)
+    conv_state, rule_state = state
+    with jax.named_scope(_names.SCOPE_GDN_PROJ):
+        mixed, z, b, a = gdn_in_proj(h, mp, cfg)
+    with jax.named_scope(_names.SCOPE_GDN_CONV):
+        acc, conv_state = (_conv_slots if slot_rows else _conv_runs)(
+            mixed, conv_state, mp["conv1d"], plan)
+        u = gdn_conv_out(acc, cfg)
+    with jax.named_scope(_names.SCOPE_GDN_PROJ):
+        q, k, v, g, beta = gdn_rule_inputs(u, b, a, mp, cfg)
+    with jax.named_scope(_names.SCOPE_GDN_RULE):
+        if slot_rows:
+            o, rule_state = _rule_slots(q, k, v, g, beta, rule_state,
+                                        plan["live"], plan["fresh"])
+        else:
+            o, rule_state = _rule_runs(q, k, v, g, beta, rule_state, plan)
+    with jax.named_scope(_names.SCOPE_GDN_PROJ):
+        out = gdn_gate_out(o, z, mp, cfg)
+    return out, (conv_state, rule_state)
+
+
+@_ragged_program("qwen3_next", slot_rows=True, step_counts=(
+    _names.COUNT_EXPERT_COPIES, _names.COUNT_EXPERT_ACTIVE))
+def qwen3_next_ragged_step(params, kv_data, token_ids, positions, seq_slots,
+                           block_tables, last_token_idx, *, cfg, block_size,
+                           use_kernel=True, kv_dtype=None, slot_rows=False):
+    """One ragged engine iteration for Qwen3-Next (``models/qwen3_next.py``
+    has the layer's equations): three Gated DeltaNet layers in four beside a
+    gated softmax attention, an expert layer with a gated shared expert in
+    every layer, an untied head.
+
+    ``kv_data`` (donated) holds entries of TWO kinds (``ragged.py``): an
+    attention layer's ``(k_pages, v_pages)`` (2 KV heads of 256), scattered
+    in place and read by the paged kernel as every other model's, and a
+    DeltaNet layer's ``(conv_state, rule_state)``, a row a sequence SLOT, the
+    rule's in float32 (:func:`_gdn_block`).  The expert layer routes the LIVE
+    rows over the router's full width and computes the held experts' part
+    (``moe/held_experts.py``) beside the shared expert, as
+    :func:`cohere2_moe_ragged_step` does.  ``slot_rows``: the buffer has ONE
+    row a slot, row ``i`` slot ``i`` (a burst's).
+
+    Returns ``(logits, new kv_data, counts)``; ``counts`` (int32, the
+    program's ``step_counts``): the (row, expert) copies that landed on a
+    held expert and the held experts with at least one, summed over the
+    layers."""
+    if kv_dtype is not None:
+        raise NotImplementedError("kv_cache_dtype with recurrent state")
+    from ...models.qwen3_next import (head_norms, moe_layer, rms_norm,
+                                      rotary_half)
+    from ...ops._use_kernels import use_pallas_kernels
+    dtype = jnp.dtype(cfg.dtype)
+    eps = cfg.rms_norm_eps
+    live = seq_slots != 0
+    gmm_kernel = use_kernel and use_pallas_kernels()
+    turn = lambda x: rotary_half(x, positions, cfg.rope_theta, cfg.rotary_dim)
+
+    with jax.named_scope(_names.SCOPE_EMBED):
+        x = params["embed_tokens"]["embedding"][token_ids].astype(dtype)
+    blk = block_tables[seq_slots, positions // block_size]
+    off = positions % block_size
+    plan = _slot_plan(seq_slots, positions) if slot_rows else \
+        _run_plan(seq_slots, positions, block_tables.shape[0])
+
+    kv_data = list(kv_data)
+    counts = []
+    for l in range(cfg.num_hidden_layers):
+        lp = params[f"layers_{l}"]
+        with jax.named_scope(_names.SCOPE_NORM):
+            h = rms_norm(x, lp["input_layernorm"]["weight"], eps)
+        if cfg.is_attention(l):
+            ap = lp["self_attn"]
+            mixed, kv_data[l] = _ragged_attention_block(
+                ap, h, kv_data[l], blk, off, block_tables, seq_slots,
+                positions, None, None, cfg=cfg, block_size=block_size,
+                rotary=turn, use_kernel=use_kernel, window=0,
+                qk_norm=head_norms(ap, cfg), out_gate=True)
+        else:
+            mixed, kv_data[l] = _gdn_block(
+                lp["linear_attn"], h, kv_data[l], plan, cfg=cfg,
+                slot_rows=slot_rows)
+        x = x + mixed
+        with jax.named_scope(_names.SCOPE_NORM):
+            h2 = rms_norm(x, lp["post_attention_layernorm"]["weight"], eps)
+        with jax.named_scope(_names.SCOPE_MLP):
+            moe_out, landed = moe_layer(h2, lp["moe"], cfg, live=live,
+                                        kernel=gmm_kernel)
+        counts.append(landed)
+        x = x + moe_out
+
+    with jax.named_scope(_names.SCOPE_LM_HEAD):
+        # only each slot's last token reaches the head
+        xl = rms_norm(x[last_token_idx], params["norm"]["weight"], eps)
+        logits = jnp.dot(xl, params["lm_head"]["kernel"].astype(dtype),
+                         preferred_element_type=jnp.float32)
+    counts = jnp.stack(counts)                        # [layers, held]
+    return logits, tuple(kv_data), jnp.stack(
+        [jnp.sum(counts), jnp.sum(counts > 0)])
+
+
 RAGGED_FORWARDS = {"LlamaModel": llama_ragged_step,
                    "MixtralModel": mixtral_ragged_step,
                    "FalconModel": falcon_ragged_step,
@@ -1425,7 +1638,8 @@ RAGGED_FORWARDS = {"LlamaModel": llama_ragged_step,
                    "LongcatFlashModel": longcat_flash_ragged_step,
                    "JambaModel": jamba_ragged_step,
                    "OuroModel": ouro_ragged_step,
-                   "MotifModel": motif_ragged_step}
+                   "MotifModel": motif_ragged_step,
+                   "Qwen3NextModel": qwen3_next_ragged_step}
 
 
 def _device_sample(logits, key, temperature, top_k, top_p):

@@ -154,6 +154,13 @@ COUNT_STATE_ROWS_READ = "state_rows_read"
 COUNT_STATE_ROWS_WRITTEN = "state_rows_written"
 COUNT_SCAN_TOKENS = "scan_tokens"
 COUNT_STATE_ROW_BYTES = "state_row_bytes"
+#: a model whose recurrent layers are a GATED DELTA RULE (a matrix state a
+#: head, ``models/qwen3_next.py``) takes a run in one of two forms by its
+#: length: summed over those layers, the tokens of runs of ONE token (one
+#: update of the slot's row: every live row of a burst, a decode row beside a
+#: chunk) and the tokens of longer runs (matrix products over chunks)
+COUNT_RULE_SLOT_TOKENS = "rule_slot_tokens"
+COUNT_RULE_CHUNK_TOKENS = "rule_chunk_tokens"
 
 #: a LOOPED model (``models/ouro.py``: one stack of layers run several times
 #: a token): counted ON THE DEVICE, the live rows x the passes of the stack
@@ -229,6 +236,24 @@ SCOPE_SSM_SCAN = "ds.ssm_scan"            # the recurrence of either kind of
 #                                           step: the kernel ds_selective_scan
 #                                           or a burst's update of every
 #                                           slot, the state's read and write
+SCOPE_GDN = "ds.gdn"                      # serving: a Gated DeltaNet mixer,
+#                                           the twin of ds.attn; inside it:
+SCOPE_GDN_PROJ = "ds.gdn_proj"            # the two input products, the
+#                                           rule's inputs (L2 norms, g,
+#                                           beta), the gated norm, out_proj
+SCOPE_GDN_CONV = "ds.gdn_conv"            # the causal convolution over a
+#                                           run's rows and its slot's last
+#                                           rows, and their write-back
+SCOPE_GDN_RULE = "ds.gdn_rule"            # the delta rule, both forms, the
+#                                           state's read and write; inside:
+SCOPE_GDN_SLOT = "ds.gdn_slot"            # the one-token form: one update of
+#                                           every slot's row
+SCOPE_GDN_CHUNK = "ds.gdn_chunk"          # the chunk form: the loop over a
+#                                           step's chunks of 64 rows
+SCOPE_ATTN_GATE = "ds.attn_gate"          # serving, inside ds.attn: a gated
+#                                           attention's own parts: the
+#                                           per-head norms of q and k and the
+#                                           sigmoid gate on the output
 SCOPE_UT_PASS = "ds.ut_pass"              # serving, a looped model: ONE pass
 #                                           of the stack (all its layers);
 #                                           inside it:

@@ -23,7 +23,7 @@ from deepspeed_tpu.inference.v2 import ragged_forward as rf
 from deepspeed_tpu.inference.v2.ragged import BlockedKVCache
 from deepspeed_tpu.models import (cohere2_moe, evabyte, falcon, jamba, llama,
                                   longcat_flash, mixtral, motif, opt, ouro,
-                                  pangu_ultra_moe, phi)
+                                  pangu_ultra_moe, phi, qwen3_next)
 
 _spec = importlib.util.spec_from_file_location(
     "serve_hlo_check", os.path.join(
@@ -56,13 +56,16 @@ ZOO = {
     "OuroModel": (ouro.OuroModel, lambda: ouro.ouro_tiny(dtype="float32")),
     "MotifModel": (motif.MotifModel, lambda: motif.motif_tiny(
         num_hidden_layers=4, n_dense_first_layers=1)),
+    "Qwen3NextModel": (qwen3_next.Qwen3NextModel,
+                       lambda: qwen3_next.qwen3_next_tiny(
+                           num_hidden_layers=4)),
 }
 #: the models whose cache is ONE latent buffer a layer: it never was a K and
 #: a V in one stacked array, so (c) has nothing to rebuild for them
 LATENT = ("PanguUltraMoeModel", "LongcatFlashModel", "MotifModel")
 #: the models whose cache holds entries of two kinds (pages, and state rows
 #: a sequence slot): no stacked array ever held them either
-RECURRENT = ("JambaModel", )
+RECURRENT = ("JambaModel", "Qwen3NextModel")
 #: the models whose stack runs several times a token: the passes' entries of
 #: one layer share ONE K and ONE V buffer, pass-major, which no stacked array
 #: of a layer's K and V ever held (tests/unit/inference/test_ouro.py holds
@@ -269,10 +272,15 @@ def test_layout_serves_what_the_stacked_array_served(name, kv_dtype):
         eng = _engine(name, kv_dtype)
         kinds = eng.model_config.layer_kinds
         assert eng.kv_cache.kinds == kinds and kinds.count("pages") == 1
+        # a state entry as the model states it: Jamba's vector state a
+        # channel, Qwen3-Next's float32 matrix a value head
+        heads = eng.model_config.num_key_value_heads
+        state = eng.model_config.recurrent_state
         for entry, kind in zip(eng._kv, kinds):
             shapes = [leaf.shape for leaf in entry]
-            assert shapes == ([(40, 8, 1, 16)] * 2 if kind == "pages" else
-                              [(3, 5, 128), (5, 16, 128)]), (kind, shapes)
+            assert shapes == ([(40, 8, heads, 16)] * 2 if kind == "pages" else
+                              [(3, 5, 128), (5, ) + tuple(state["ssm"])]), \
+                (kind, shapes)
         return
     rng = np.random.default_rng(28)
     vocab = ZOO[name][1]().vocab_size

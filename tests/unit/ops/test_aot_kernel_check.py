@@ -65,6 +65,9 @@ def test_every_kernel_compiles_for_v5e(report):
                    # one query head a KV head, 16 heads, a burst of 9 rows
                    "paged_attention(MHA 16/16, the Ouro cell)",
                    "paged_attention(MHA 16/16, the Ouro cell's burst)",
+                   # 8 query heads on each of 2 KV heads of 256
+                   "paged_attention(GQA 16/2 x 256, Qwen3-Next's step)",
+                   "paged_attention(GQA 16/2 x 256, Qwen3-Next's burst)",
                    "selective_scan(16 x 5120, 257 slots, the Jamba cell's "
                    "step)",
                    "selective_scan(16 x 5120, 257 slots, a short step)",

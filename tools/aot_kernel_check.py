@@ -306,6 +306,11 @@ def main():
             # of 8): the Ouro cell's 192 calls a step, and its burst of 9 rows
             ("MHA 16/16, the Ouro cell", 512, 16, 16, 128, 11, 0),
             ("MHA 16/16, the Ouro cell's burst", 9, 16, 16, 128, 11, 0),
+            # 8 query heads on each of 2 KV heads of 256 (PR 60): the
+            # Qwen3-Next configuration's two gated-attention layers at its
+            # engine layout, a step and a burst
+            ("GQA 16/2 x 256, Qwen3-Next's step", 2048, 16, 2, 256, 193, 0),
+            ("GQA 16/2 x 256, Qwen3-Next's burst", 257, 16, 2, 256, 193, 0),
             ("MHA 32/32 x 80: per token", 64, 32, 32, 80, 16, 0),
             ("MQA 71/1 x 64: per token", 64, 71, 1, 64, 16, 0)):
         # one KV head in 16 bits: a page holds two tokens a row (ragged.py)

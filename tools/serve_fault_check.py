@@ -61,6 +61,19 @@ FAULTS = {
         "f_silu_for_polynorm": {"hidden_act": "silu"},
         "g_cache_row_in_8_bits": {"cache_row_mantissa_bits": 3},
     },
+    "qwen3_next": {
+        "a_no_decay": {"rule_decay": False},
+        "b_beta_one": {"rule_beta": False},
+        "c_q_k_not_l2_normalised": {"rule_l2_norm": False},
+        # 16: a burst's iterations; it divides a chunk of 64 and a step
+        "d_state_not_carried_past_16_tokens": {"state_reset_every": 16},
+        "e_conv_rows_not_carried_past_16_tokens": {"conv_reset_every": 16},
+        "f_state_held_in_bfloat16": {"state_held_in": "bfloat16"},
+        "g_attention_gate_dropped": {"attention_gate": False},
+        "h_w_for_one_plus_w": {"norm_one_plus_w": False},
+        "i_rotary_on_the_whole_head": {"rotary_whole_head": True},
+        "j_shared_expert_gate_dropped": {"shared_expert_gate": False},
+    },
 }
 #: the comparison's lower-precision control: the reference in the nearest
 #: precision below the one the configuration serves in, which has to come out
@@ -74,6 +87,9 @@ CONTROLS = {
     },
     "motif": {
         "h_control_weights_in_8_bits": {"weight_mantissa_bits": 3},
+    },
+    "qwen3_next": {
+        "k_control_weights_in_8_bits": {"weight_mantissa_bits": 3},
     },
 }
 

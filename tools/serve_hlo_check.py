@@ -212,8 +212,9 @@ def programs(config, sharding=None):
     n_cache = len(jax.tree.leaves(cache))
     page = cache[kinds.index("pages")][0]
     page_bytes = page.dtype.itemsize * math.prod(page.shape)
-    state_shapes = {        # a state row is held in the serving type
-        f"bf16[{','.join(map(str, leaf.shape))}]"
+    hlo_type = {"bfloat16": "bf16", "float32": "f32"}
+    state_shapes = {        # a state leaf in the type the cache holds it in
+        f"{hlo_type[leaf.dtype.name]}[{','.join(map(str, leaf.shape))}]"
         for entry, kind in zip(cache, kinds) if kind == "state"
         for leaf in entry}
     step = step_fn.lower(params, cache, i32(budget), i32(budget), i32(budget),
