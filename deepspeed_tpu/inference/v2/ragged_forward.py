@@ -1434,22 +1434,32 @@ def jamba_ragged_step(params, kv_data, token_ids, positions, seq_slots,
 
 
 # ------------------------------------------------------------ Qwen3-Next
-def _rule_slots(q, k, v, g, beta, state, live, fresh):
+def _rule_slots(q, k, v, g, beta, state, live, fresh, use_kernel=True):
     """The delta rule's ONE-TOKEN form over a buffer of one row a slot (``q,
     k, v [slots, Hv, 128]``, ``g, beta [slots, Hv]``; ``state [slots, Hv, 128,
-    128]`` float32, donated): one element-wise update of every live slot's
-    row, read and written in place.  Returns ``(o [slots, Hv, 128], new
-    state)``."""
+    128]`` float32, donated): one update of every live slot's row, read and
+    written in place: the Pallas ``ds_gated_delta_slot`` on a TPU (a live row
+    read ONCE for the two sums and the update, the others not at all),
+    element-wise XLA elsewhere (every row read twice).  Returns ``(o [slots,
+    Hv, 128], new state)``; ``o`` of a slot that is not live is zeros.  (The
+    XLA form gave such a slot the rule of its stale row until PR 61; the
+    kernel never reads that row, so the select below was ADDED to the XLA
+    form to give both one contract.  Nobody reads those rows: ``_rule_runs``
+    masks them, and a burst's dead slot yields no token.)"""
     from ...models.qwen3_next import delta_rule_token
+    from ...ops._use_kernels import use_pallas_kernels
+    from ...ops.pallas.gated_delta_rule import gated_delta_slot, head_block
     with jax.named_scope(_names.SCOPE_GDN_SLOT):
+        if use_kernel and use_pallas_kernels() and head_block(state):
+            return gated_delta_slot(q, k, v, g, beta, state, live, fresh)
         old = jnp.where(fresh[:, None, None, None], 0,
                         state.astype(jnp.float32))
         o, new = delta_rule_token(q, k, v, g, beta, old)
-        return o, jnp.where(live[:, None, None, None],
-                            new.astype(state.dtype), state)
+        return jnp.where(live[:, None, None], o, 0), jnp.where(
+            live[:, None, None, None], new.astype(state.dtype), state)
 
 
-def _rule_runs(q, k, v, g, beta, state, plan):
+def _rule_runs(q, k, v, g, beta, state, plan, use_kernel=True):
     """The delta rule over the runs of a ragged buffer (``q, k, v [T, Hv,
     128]``, ``g, beta [T, Hv]`` float32; ``state [slots, Hv, 128, 128]``
     float32, a row a slot, donated).  A run of ONE token (a decode row beside
@@ -1469,7 +1479,7 @@ def _rule_runs(q, k, v, g, beta, state, plan):
 
     def one_token(state):
         o, state = _rule_slots(q[rows], k[rows], v[rows], g[rows], beta[rows],
-                               state, single, plan["fresh"])
+                               state, single, plan["fresh"], use_kernel)
         return jnp.where((live & single[slots])[:, None, None], o[slots],
                          0), state
 
@@ -1516,7 +1526,7 @@ def _rule_runs(q, k, v, g, beta, state, plan):
 
 
 @jax.named_scope(_names.SCOPE_GDN)
-def _gdn_block(mp, h, state, plan, *, cfg, slot_rows):
+def _gdn_block(mp, h, state, plan, *, cfg, use_kernel, slot_rows):
     """The Gated DeltaNet mixer of one layer over the step's buffer
     (``models/qwen3_next.py`` has the equations and the rule's two forms).
     ``state``: the layer's entry of the cache, ``(conv_state [K - 1, slots,
@@ -1541,9 +1551,11 @@ def _gdn_block(mp, h, state, plan, *, cfg, slot_rows):
     with jax.named_scope(_names.SCOPE_GDN_RULE):
         if slot_rows:
             o, rule_state = _rule_slots(q, k, v, g, beta, rule_state,
-                                        plan["live"], plan["fresh"])
+                                        plan["live"], plan["fresh"],
+                                        use_kernel)
         else:
-            o, rule_state = _rule_runs(q, k, v, g, beta, rule_state, plan)
+            o, rule_state = _rule_runs(q, k, v, g, beta, rule_state, plan,
+                                       use_kernel)
     with jax.named_scope(_names.SCOPE_GDN_PROJ):
         out = gdn_gate_out(o, z, mp, cfg)
     return out, (conv_state, rule_state)
@@ -1607,7 +1619,7 @@ def qwen3_next_ragged_step(params, kv_data, token_ids, positions, seq_slots,
         else:
             mixed, kv_data[l] = _gdn_block(
                 lp["linear_attn"], h, kv_data[l], plan, cfg=cfg,
-                slot_rows=slot_rows)
+                use_kernel=use_kernel, slot_rows=slot_rows)
         x = x + mixed
         with jax.named_scope(_names.SCOPE_NORM):
             h2 = rms_norm(x, lp["post_attention_layernorm"]["weight"], eps)

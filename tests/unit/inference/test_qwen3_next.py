@@ -44,15 +44,14 @@ def as_published(params, seed=0):
     """``A_log``, ``dt_bias`` and the norms' weights as a trained model has
     them, none of them constant."""
     rng = np.random.default_rng(seed)
-    hv = CFG.linear_num_value_heads
 
     def change(path, x):
         name = jax.tree_util.keystr(path)
         if "A_log" in name:
-            return jnp.asarray(np.log(rng.uniform(0.5, 16, (1, hv))),
+            return jnp.asarray(np.log(rng.uniform(0.5, 16, x.shape)),
                                x.dtype)
         if "dt_bias" in name:
-            dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), (1, hv)))
+            dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), x.shape))
             return jnp.asarray(np.log(np.expm1(dt)), x.dtype)
         if "in_proj_ba" in name:
             return x * 0.1
@@ -62,14 +61,21 @@ def as_published(params, seed=0):
     return jax.tree_util.tree_map_with_path(change, params)
 
 
-@pytest.fixture(scope="module")
-def params():
+def shapes_of(cfg):
+    return jax.eval_shape(qn.Qwen3NextModel(cfg).init, jax.random.PRNGKey(0),
+                          jnp.zeros((1, 8), jnp.int32))["params"]
+
+
+def params_of(cfg):
     # drawn leaf by leaf from the shapes (an init would run the forward)
     from perfbench import weights
-    shapes = jax.eval_shape(qn.Qwen3NextModel(CFG).init, jax.random.PRNGKey(0),
-                            jnp.zeros((1, 8), jnp.int32))["params"]
-    return as_published(weights.seeded_weights(shapes, jax.random.PRNGKey(0),
-                                               jnp.float32))
+    return as_published(weights.seeded_weights(
+        shapes_of(cfg), jax.random.PRNGKey(0), jnp.float32))
+
+
+@pytest.fixture(scope="module")
+def params():
+    return params_of(CFG)
 
 
 def engine(params, dtype="float32", budget=96, burst=4, blocks=64, seqs=5,
@@ -173,12 +179,13 @@ def chunks_of_8(monkeypatch):
     jax.clear_caches()
 
 
-def _logits_step_by_step(params, lengths, budget):
+def _logits_step_by_step(params, lengths, budget, cfg=CFG, sizes=SIZES,
+                         steps=12):
     """Every row a step finishes (a sequence's last chunk, or a decode row)
     against the reference's full forward over the whole sequence at that
     position (a causal model: what the cache held then); returns how many
     were compared and the kinds of runs met."""
-    eng = engine(params, burst=0, budget=budget)
+    eng = engine(params, burst=0, budget=budget, cfg=cfg)
     inner, seen = eng._step_fn, []
 
     def spy(*args, **kw):
@@ -189,7 +196,7 @@ def _logits_step_by_step(params, lengths, budget):
     eng._step_fn = spy
     eng.put(list(range(len(lengths))), prompts_of(lengths))
     rows, forms = {uid: {} for uid in range(len(lengths))}, set()
-    for _ in range(12):
+    for _ in range(steps):
         out = eng.schedule_step()
         c = eng.last_step_counts
         forms |= {f for f in ("rule_slot_tokens", "rule_chunk_tokens")
@@ -204,7 +211,7 @@ def _logits_step_by_step(params, lengths, budget):
         ids = np.asarray(eng.state_manager.get_sequence(uid).tokens[:-1],
                          np.int32)
         at = sorted(got)
-        want = np.asarray(reference.logits_at(params, ids, at, SIZES))
+        want = np.asarray(reference.logits_at(params, ids, at, sizes))
         np.testing.assert_allclose(np.stack([got[p][0] for p in at]), want,
                                    atol=2e-3)
         assert [got[p][1] for p in at] == np.argmax(want, -1).tolist()
@@ -237,6 +244,76 @@ def test_the_engines_tokens_are_the_references(params, burst):
         prompts, max_new_tokens=12)
     assert worst_gap(params, prompts, produced) == (0.0, 1.0)
     assert min(len(set(t)) for t in produced) >= 6      # no repeated token
+
+
+# ------------------------------------- the one-token form through its kernel
+#: heads of 128 x 128, 8 value heads on 2 key heads, ONE Gated DeltaNet layer
+#: and one of attention: the smallest model of ``ds_gated_delta_slot``'s rule
+#: (the tiny configuration's heads of 16 stay on XLA whatever the gate says)
+WIDE = dict(linear_key_head_dim=128, linear_value_head_dim=128,
+            linear_num_key_heads=2, linear_num_value_heads=8,
+            num_hidden_layers=2, full_attention_interval=2)
+WIDE_CFG = qn.qwen3_next_tiny(**WIDE)
+
+
+@pytest.fixture(scope="module")
+def wide_params():
+    return params_of(WIDE_CFG)
+
+
+@pytest.fixture
+def kernels_on(monkeypatch):
+    """The step's kernels, interpreted on the CPU (the programs are traced
+    anew: the gate is read when they are)."""
+    jax.clear_caches()
+    monkeypatch.setenv("DS_TPU_FORCE_PALLAS", "1")
+    yield
+    jax.clear_caches()
+
+
+def step_args(cfg, slot_rows):
+    """A step's abstract arguments: 3 slots, 16 rows (3 in a burst's
+    layout)."""
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    cache = jax.eval_shape(lambda: BlockedKVCache(
+        cfg.num_hidden_layers, 6, 8, 2, 16, dtype=jnp.float32,
+        recurrent=cfg.recurrent_state,
+        max_seqs=3).layers)
+    rows = 3 if slot_rows else 16
+    return (shapes_of(cfg), cache, i32(rows), i32(rows), i32(rows),
+            i32(3, 4), i32(3))
+
+
+def _kernel_in_step(slot_rows):
+    return "ds_gated_delta_slot" in str(jax.make_jaxpr(
+        lambda *a: rf.qwen3_next_ragged_step(
+            *a, cfg=WIDE_CFG, block_size=8, slot_rows=slot_rows))(
+        *step_args(WIDE_CFG, slot_rows)))
+
+
+def test_a_ragged_steps_decode_rows_take_the_kernel(wide_params, kernels_on):
+    """A run of ONE token beside a chunk goes through ``ds_gated_delta_slot``
+    (behind the step's ``lax.cond``), the chunk through the chunk form:
+    logits of every finished row against the reference's full forward."""
+    assert _kernel_in_step(slot_rows=False)
+    compared, forms = _logits_step_by_step(wide_params, [5, 20, 100], 64,
+                                           WIDE_CFG, dict(SIZES, **WIDE),
+                                           steps=6)
+    assert compared >= 15
+    assert "both in one buffer" in forms
+
+
+def test_a_bursts_rows_take_the_kernel(wide_params, kernels_on):
+    """Bursts of 4 (every live row one call of the kernel on its slot's row,
+    the state carried in the buffer): every token is the reference's
+    argmax."""
+    assert _kernel_in_step(slot_rows=True)
+    prompts = prompts_of([70, 5, 20], seed=1)
+    produced = engine(wide_params, burst=4, budget=48,
+                      cfg=WIDE_CFG).generate(prompts, max_new_tokens=12)
+    assert worst_gap(wide_params, prompts, produced,
+                     dict(SIZES, **WIDE)) == (0.0, 1.0)
+    assert min(len(set(t)) for t in produced) >= 6
 
 
 def test_a_state_held_in_bfloat16_drifts_where_float32_does_not(params,
@@ -389,23 +466,22 @@ def test_the_attention_blocks_defaults_leave_it_as_it_was():
     assert names.SCOPE_ATTN_GATE not in text
 
 
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
 @pytest.mark.parametrize("slot_rows", [False, True])
-def test_the_mixers_scopes_reach_the_compiled_program(slot_rows):
+def test_the_mixers_scopes_reach_the_compiled_program(slot_rows, kernel,
+                                                      request):
     """``ds.gdn_proj``, ``ds.gdn_conv`` and ``ds.gdn_rule`` inside ``ds.gdn``,
     the two forms inside ``ds.gdn_rule``, ``ds.attn_gate`` inside ``ds.attn``,
     ``ds.moe_shared`` inside ``ds.mlp``: the scope paths of the compiled step
-    of either layout."""
-    model = qn.Qwen3NextModel(CFG)
-    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
-                            jnp.zeros((1, 8), jnp.int32))["params"]
-    cache = jax.eval_shape(lambda: BlockedKVCache(
-        4, 6, 8, 2, 16, dtype=jnp.float32, recurrent=CFG.recurrent_state,
-        max_seqs=3).layers)
-    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
-    rows = 3 if slot_rows else 16
+    of either layout, the one-token form in XLA or through its kernel (the
+    readers find ``ds_gated_delta_slot`` by ``ds.gdn_slot`` around it)."""
+    cfg = CFG
+    if kernel:
+        request.getfixturevalue("kernels_on")
+        cfg = WIDE_CFG
     text = rf.qwen3_next_ragged_step.lower(
-        shapes, cache, i32(rows), i32(rows), i32(rows), i32(3, 4), i32(3),
-        cfg=CFG, block_size=8, slot_rows=slot_rows).compile().as_text()
+        *step_args(cfg, slot_rows), cfg=cfg, block_size=8,
+        slot_rows=slot_rows).compile().as_text()
     paths = set(re.findall(r'op_name="([^"]*)"', text))
     inside = lambda outer, scope: any(
         f"/{outer}/{scope}/" in p or p.endswith(f"/{outer}/{scope}")

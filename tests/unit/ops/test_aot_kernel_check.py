@@ -40,7 +40,7 @@ def test_every_kernel_compiles_for_v5e(report):
     lines = [ln for ln in r.stdout.splitlines()
              if ln.startswith(("PASS", "FAIL"))]
     assert r.returncode == 0, "\n".join(lines) + r.stderr[-1500:]
-    assert len(lines) >= 38 and all(ln.startswith("PASS") for ln in lines)
+    assert len(lines) >= 40 and all(ln.startswith("PASS") for ln in lines)
     assert "TPU v5 lite" in r.stdout
     # the run-tiled paged kernel, every branch of its item (the block of
     # pages too), at the three serving cells' shapes and their bursts': a
@@ -71,6 +71,11 @@ def test_every_kernel_compiles_for_v5e(report):
                    "selective_scan(16 x 5120, 257 slots, the Jamba cell's "
                    "step)",
                    "selective_scan(16 x 5120, 257 slots, a short step)",
+                   # the delta rule's one-token form, float32 state in place
+                   "gated_delta_slot(32 heads of 128 x 128, 257 slots, the "
+                   "Qwen3-Next cell)",
+                   "gated_delta_slot(32 heads of 128 x 128, 2048 slots, the "
+                   "most SMEM holds)",
                    "paged_latent_attention(MLA 128 x 576, the cell's step)",
                    "paged_latent_attention(MLA 128 x 576, the cell's burst)",
                    "paged_latent_attention(MLA 64 x 576, the LongCat cell's "

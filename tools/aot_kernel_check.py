@@ -415,6 +415,22 @@ def main():
             sds((257, 16, 5120), bf16), sds((T, ), jnp.int32),
             sds((T, ), jnp.int32), sds((1, ), jnp.int32)))
 
+    # the gated delta rule's one-token form at the Qwen3-Next cell's shape:
+    # 257 slots' float32 state of 32 heads x 128 x 128 (aliased in and out),
+    # and at the most (slot, head) pairs its shape predicate lets through
+    # (e^g and beta of every pair lie in SMEM)
+    from deepspeed_tpu.ops.pallas.gated_delta_rule import (SMEM_PAIRS,
+                                                           gated_delta_slot)
+    for slots, what in ((257, "the Qwen3-Next cell"),
+                        (SMEM_PAIRS // 32, "the most SMEM holds")):
+        results.append(checked(
+            f"gated_delta_slot(32 heads of 128 x 128, {slots} slots, {what})",
+            gated_delta_slot,
+            *(sds((slots, 32, 128), f32), ) * 3,
+            *(sds((slots, 32), f32), ) * 2,
+            sds((slots, 32, 128, 128), f32),
+            *(sds((slots, ), jnp.bool_), ) * 2))
+
     from deepspeed_tpu.ops.pallas.grouped_matmul import gmm
     results.append(checked(
         "gmm(moe grouped matmul)", lambda a, b, s: gmm(a, b, s),
